@@ -12,8 +12,9 @@ Instead of Monarch's actor runtime, plain threads play the actor roles:
 - ``OrchestrationManager`` — wires the actors, waits for completion, and
   reports a summary (restarts per replica, final status)
 
-Demo (2 replica groups training the DDP example on virtual CPU chips, one
-injected kill):
+Demo (2 replica groups training the DDP example, one injected kill). The
+workers are started with ``--virtual-chips 1``: this is a CPU demonstration
+of supervision and restart and never touches an accelerator.
 
     python examples/orchestrator.py --replicas 2 --steps 40 --inject-kill-after 12
 """

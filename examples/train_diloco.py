@@ -207,11 +207,14 @@ if __name__ == "__main__":
     parser.add_argument("--fragment-sync-delay", type=int, default=0)
     parser.add_argument("--fragment-update-alpha", type=float, default=0.0)
     parser.add_argument("--virtual-chips", type=int, default=0,
-                        help="force N virtual CPU devices (local multi-process runs)")
+                        help="force N virtual CPU devices (CPU testing only: "
+                             "pins the platform to cpu, never a chip run)")
     parser.add_argument("--min-replica-size", type=int, default=1)
     parser.add_argument("--replica-id", type=int, default=0)
     parser.add_argument("--lighthouse", type=str, default="127.0.0.1:29510")
-    parser.add_argument("--demo", action="store_true")
+    parser.add_argument("--demo", action="store_true",
+                        help="CPU control-flow demo: replicas run with "
+                             "--virtual-chips 1 and touch no accelerator")
     parser.add_argument("--replicas", type=int, default=2)
     parser.add_argument("--kill-after", type=float, default=8.0)
     args = parser.parse_args()

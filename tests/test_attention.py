@@ -253,3 +253,38 @@ class TestDispatch:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(xla_attention(q, k, v, None)), rtol=1e-6
         )
+
+    def _qkv(self, S=128, hd=64):
+        ks = jax.random.split(jax.random.PRNGKey(4), 3)
+        return [jax.random.normal(k, (1, S, 2, hd), jnp.float32) for k in ks]
+
+    def test_unknown_choice_raises(self, monkeypatch):
+        """One spelling: the registered enum is auto|splash|flash|reference;
+        anything else (the old "xla" included) is an error, not flash."""
+        monkeypatch.setenv("TORCHFT_TPU_ATTENTION", "xla")
+        with pytest.raises(ValueError, match="TORCHFT_TPU_ATTENTION='xla'"):
+            causal_attention(*self._qkv(), None)
+
+    def test_reference_selects_xla_even_on_tpu(self, monkeypatch):
+        from torchft_tpu.ops import attention as A
+
+        monkeypatch.setenv("TORCHFT_TPU_ATTENTION", "reference")
+        monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        monkeypatch.setattr(
+            A, "flash_attention_tpu",
+            lambda *a: pytest.fail("reference must not select flash"),
+        )
+        causal_attention(*self._qkv(), None)
+        assert A.LAST_DISPATCH == "xla"
+
+    def test_untileable_shape_is_loud_on_tpu(self, monkeypatch):
+        """On a TPU a shape the kernels cannot tile raises; it never gives
+        way to materialized scores silently."""
+        from torchft_tpu.ops import attention as A
+
+        monkeypatch.delenv("TORCHFT_TPU_ATTENTION", raising=False)
+        monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        with pytest.raises(ValueError, match="does not tile"):
+            causal_attention(*self._qkv(S=96), None)
+        with pytest.raises(ValueError, match="does not tile"):
+            causal_attention(*self._qkv(hd=32), None)

@@ -172,8 +172,7 @@ class TestRingAttention:
         """Direct shard_map unit check against naive softmax attention."""
         from functools import partial
 
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_hsdp_mesh(dp=1, fsdp=1, tp=1, sp=8)
@@ -202,8 +201,7 @@ class TestRingAttention:
         """Ring attention with grouped KV heads (Hq != Hkv)."""
         from functools import partial
 
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_hsdp_mesh(dp=1, fsdp=1, tp=1, sp=4)
@@ -254,8 +252,7 @@ class TestUlyssesAttention:
     def test_unit_matches_naive(self):
         from functools import partial
 
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchft_tpu.parallel.ulysses import ulysses_attention
@@ -283,8 +280,7 @@ class TestUlyssesAttention:
     def test_gqa_ulysses(self):
         from functools import partial
 
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchft_tpu.parallel.ulysses import ulysses_attention
@@ -317,8 +313,7 @@ class TestUlyssesAttention:
         garbage (the documented ring-attention-instead case)."""
         from functools import partial
 
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from torchft_tpu.parallel.ulysses import ulysses_attention

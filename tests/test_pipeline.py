@@ -29,8 +29,7 @@ class TestPipelineApply:
     @pytest.mark.parametrize("pp,M", [(2, 2), (2, 4), (4, 4), (4, 8)])
     def test_matches_sequential_scan(self, pp, M):
         """The pipeline must compute exactly what the plain layer scan does."""
-        from torchft_tpu.utils import import_shard_map
-        shard_map = import_shard_map()
+        from jax import shard_map
 
         mesh = make_pp_mesh(pp)
         L, B, D = 4, 8, 16

@@ -337,8 +337,7 @@ def _quantize_leaf(a, plan, row: int):
     Sharded leaves never materialize off their mesh: shard_map runs the
     Pallas quantize kernel on each device's own shard, and the only D2H is
     np.asarray on the fp8 output."""
-    from torchft_tpu.utils import import_shard_map
-    shard_map = import_shard_map()
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if plan["kind"] == "sharded":
@@ -366,8 +365,7 @@ def _reconstruct_leaf(q_rows: np.ndarray, scales: np.ndarray, plan, row: int):
     leaf's own mesh (sharded H2D of compressed bytes, then a shard-local
     Pallas dequantize into the original spec)."""
     import jax
-    from torchft_tpu.utils import import_shard_map
-    shard_map = import_shard_map()
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from torchft_tpu.ops.quantization import _FP8
@@ -388,19 +386,13 @@ def _reconstruct_leaf(q_rows: np.ndarray, scales: np.ndarray, plan, row: int):
             flat = fused_dequantize_fp8(qv, sv, local_n, row)
             return flat.reshape(local_shape).astype(dtype)
 
-        out = shard_map(
+        return shard_map(
             local,
             mesh=sh.mesh,
             in_specs=(P(axes, None), P(axes, None)),
             out_specs=sh.spec,
             check_vma=False,
         )(dq, ds)
-        # older JAX canonicalizes trailing-None specs on shard_map outputs
-        # (P('x', None) -> P('x')); re-pin the caller's exact sharding so
-        # the leaf round-trips ==-equal (no resharding: specs are equivalent)
-        if out.sharding != sh:
-            out = jax.device_put(out, sh)
-        return out
 
     import jax.numpy as jnp
 

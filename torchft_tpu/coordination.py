@@ -47,29 +47,37 @@ _OK, _TIMEOUT, _ERROR, _NOT_FOUND, _INVALID, _UNAVAILABLE = range(6)
 
 
 def ensure_native_built() -> str:
-    """Build the native library if missing (requires g++ + make).
+    """Bring the native library up to date with ``native/*.cc`` (requires
+    g++ + make) and return its path.
 
-    Serialized across processes with a file lock so a multi-process launch on
-    a fresh checkout doesn't race the build.
+    Always runs ``make -C native``: a no-op when the library is current
+    (the Makefile tracks sources and headers), a rebuild when a copied or
+    stale ``.so`` is older than the sources it claims to come from.
+    Serialized across processes with a file lock so a multi-process launch
+    on a fresh checkout doesn't race the build.
     """
-    if not os.path.exists(_SO_PATH):
-        native_src = os.path.join(os.path.dirname(_NATIVE_DIR), "..", "native")
-        native_src = os.path.abspath(native_src)
-        if not os.path.isdir(native_src):
-            raise RuntimeError(
-                f"native library missing at {_SO_PATH} and no source tree found"
-            )
-        import fcntl
+    native_src = os.path.abspath(
+        os.path.join(os.path.dirname(_NATIVE_DIR), "..", "native")
+    )
+    if not os.path.isdir(native_src):
+        if os.path.exists(_SO_PATH):
+            return _SO_PATH  # installed without the source tree
+        raise RuntimeError(
+            f"native library missing at {_SO_PATH} and no source tree found"
+        )
+    import fcntl
 
-        os.makedirs(_NATIVE_DIR, exist_ok=True)
-        lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
-        with open(lock_path, "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            try:
-                if not os.path.exists(_SO_PATH):  # re-check under the lock
-                    subprocess.run(["make", "-C", native_src, "-j"], check=True)
-            finally:
-                fcntl.flock(lock, fcntl.LOCK_UN)
+    os.makedirs(_NATIVE_DIR, exist_ok=True)
+    lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
+    with open(lock_path, "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            subprocess.run(
+                ["make", "-C", native_src, "-j", "-s"], check=True,
+                stdout=subprocess.DEVNULL,
+            )
+        finally:
+            fcntl.flock(lock, fcntl.LOCK_UN)
     return _SO_PATH
 
 

@@ -15,6 +15,12 @@ CLI::
         --workers-per-replica 1 --max-restarts 3 -- --train-arg ...
 
 or programmatic: ``launch_replica_groups(cmd, num_groups, ...)``.
+
+A TPU chip belongs to one process at a time, so on a host whose chips are
+shared between replica groups pass ``--chips-per-group N``: every worker is
+handed its own chips through the environment (``chip_env``) and sees only
+those as ``jax.devices()``. The launcher itself never initialises a JAX
+backend, so it holds no chip.
 """
 
 from __future__ import annotations
@@ -31,16 +37,61 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from torchft_tpu.coordination import LighthouseServer
+from torchft_tpu.utils import compilation_cache_dir
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["ReplicaGroupSpec", "launch_replica_groups", "main"]
+__all__ = ["ReplicaGroupSpec", "chip_env", "launch_replica_groups", "main"]
 
 LIGHTHOUSE_ENV = "TORCHFT_LIGHTHOUSE"
 REPLICA_GROUP_ID_ENV = "REPLICA_GROUP_ID"
 NUM_REPLICA_GROUPS_ENV = "NUM_REPLICA_GROUPS"
 GROUP_RANK_ENV = "GROUP_RANK"
 GROUP_WORLD_SIZE_ENV = "GROUP_WORLD_SIZE"
+
+# libtpu's chip-grid shape for a process that owns n chips of one host
+# (x,y,z; the table jax's own multi-process TPU tests use)
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_env(
+    group: int, rank: int, chips_per_group: int, workers_per_group: int = 1
+) -> Dict[str, str]:
+    """Environment that hands worker ``rank`` of replica group ``group``
+    its own chips of this host: a pure function of its arguments.
+
+    Chips are numbered as libtpu numbers them (``TPU_VISIBLE_CHIPS``
+    ordinals); group g owns ``[g * chips_per_group, (g + 1) *
+    chips_per_group)`` and its workers split that range in rank order.
+    Each worker is a libtpu world of its own (``TPU_PROCESS_BOUNDS``
+    1,1,1): replica groups talk over the Manager's process group, not over
+    a shared runtime. Honoured by libtpu 0.0.34 on v5e 2x2 hosts (chip
+    runs, PR 21): one chip per worker always; two chips per worker on
+    three hosts of five — on the other two every such worker exited 1
+    before reaching the chip, silently (consecutive chip ordinals are
+    presumably not neighbours in y on every host; PERF.md, open question).
+    """
+    if chips_per_group % workers_per_group:
+        raise ValueError(
+            f"chips_per_group={chips_per_group} does not split over "
+            f"{workers_per_group} workers"
+        )
+    per_worker = chips_per_group // workers_per_group
+    if per_worker not in _CHIP_BOUNDS:
+        raise ValueError(
+            f"no chip-grid shape for {per_worker} chips per worker "
+            f"(known: {sorted(_CHIP_BOUNDS)})"
+        )
+    first = group * chips_per_group + rank * per_worker
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(c) for c in range(first, first + per_worker)
+        ),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[per_worker],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # several processes of one host each load libtpu for their own chips
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 @dataclass
@@ -52,13 +103,20 @@ class ReplicaGroupSpec:
     num_replica_groups: int
     workers_per_replica: int = 1
     env: Dict[str, str] = field(default_factory=dict)
+    chips_per_group: int = 0  # 0: no chip assignment (workers see every chip)
 
     def spawn(self, lighthouse_addr: str) -> List[subprocess.Popen]:
         procs = []
         for group_rank in range(self.workers_per_replica):
+            chips = (
+                chip_env(self.replica_group_id, group_rank,
+                         self.chips_per_group, self.workers_per_replica)
+                if self.chips_per_group else {}
+            )
             env = {
                 **os.environ,
                 **self.env,
+                **chips,
                 LIGHTHOUSE_ENV: lighthouse_addr,
                 REPLICA_GROUP_ID_ENV: str(self.replica_group_id),
                 NUM_REPLICA_GROUPS_ENV: str(self.num_replica_groups),
@@ -77,12 +135,16 @@ def launch_replica_groups(
     min_replicas: Optional[int] = None,
     max_restarts: int = 0,
     poll_interval: float = 1.0,
+    chips_per_group: int = 0,
 ) -> int:
     """Run ``cmd`` as ``num_groups`` replica groups; supervise + restart.
 
     Returns the exit code: 0 iff every group eventually exited cleanly.
     Starts an in-process lighthouse when ``lighthouse_addr`` is None.
+    ``chips_per_group > 0`` hands each worker its own chips (``chip_env``).
     """
+    # one persistent compile cache for every worker and every restart
+    compilation_cache_dir()
     own_lighthouse = None
     if lighthouse_addr is None:
         own_lighthouse = LighthouseServer(
@@ -98,6 +160,7 @@ def launch_replica_groups(
             replica_group_id=i,
             num_replica_groups=num_groups,
             workers_per_replica=workers_per_replica,
+            chips_per_group=chips_per_group,
         )
         for i in range(num_groups)
     ]
@@ -188,6 +251,10 @@ def main(argv: "list[str] | None" = None) -> None:
                         help="existing lighthouse addr; else start one")
     parser.add_argument("--min-replicas", type=int, default=None)
     parser.add_argument("--max-restarts", type=int, default=0)
+    parser.add_argument("--chips-per-group", type=int, default=0,
+                        help="TPU chips of this host each replica group "
+                             "owns; 0 (default) assigns none, right for one "
+                             "group that takes the whole host")
 
     # everything after a literal `--` goes verbatim to the worker script
     if argv is None:
@@ -207,6 +274,7 @@ def main(argv: "list[str] | None" = None) -> None:
         lighthouse_addr=ns.lighthouse,
         min_replicas=ns.min_replicas,
         max_restarts=ns.max_restarts,
+        chips_per_group=ns.chips_per_group,
     )
     sys.exit(code)
 

@@ -1788,8 +1788,8 @@ class Manager:
         # instead — _sync_device_world re-lands the user's own state
         # the same way at should_commit. LAZY on purpose: jax.devices()
         # initializes the backend, and a pure-host tree must never
-        # trigger that (a wedged accelerator plugin hangs init — the
-        # host plane has to keep working through exactly that state).
+        # trigger that (a process that only moves host arrays should not
+        # take the chip).
         live_client = [False]
 
         def _is_live(sharding) -> bool:

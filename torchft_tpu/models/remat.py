@@ -20,13 +20,8 @@ On TPU the interesting trade is HBM capacity vs backward-pass FLOPs:
   recompute is disproportionately expensive (a full Pallas flash forward),
   while the dense matmuls recompute at MXU speed from residuals already in
   HBM — so this keeps nearly full-remat's memory footprint but removes the
-  most expensive third of the recompute. History: the round-3 toolchain
-  wedged the TPU compiler on this policy with the splash kernel (>25 min,
-  never returned); the round-4 toolchain compiles and runs it fine but it
-  measures SLOWER than "full" on the bench config (0.436 vs 0.449 MFU) —
-  the step is HBM-bound, so keeping attention outputs resident costs more
-  bandwidth than their recompute costs FLOPs. Numerically pinned by the
-  grad-equivalence test.
+  most expensive third of the recompute. Not measured on the chip since
+  PR 21's bring-up. Numerically pinned by the grad-equivalence test.
 - "none": XLA saves all residuals.
 """
 

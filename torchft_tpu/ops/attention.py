@@ -3,10 +3,10 @@
 The reference has no attention kernels of its own (it trains via torchtitan,
 whose SDPA/flash comes from PyTorch); in a standalone TPU framework the
 attention kernel is ours to own. On TPU this dispatches to the Pallas
-flash-attention kernel (tiled online-softmax, never materializes the S x S
+splash/flash kernels (tiled online-softmax, never materializes the S x S
 score matrix in HBM — the O(S) memory path that makes long sequences and big
-batches fit); elsewhere (CPU tests, virtual-device dryruns) it falls back to
-a plain XLA implementation with identical semantics.
+batches fit); on the CPU test platform the plain XLA implementation with
+identical semantics runs.
 
 Layout contract matches torchft_tpu.models.llama: q [B, S, Hq, hd],
 k/v [B, S, Hkv, hd] (GQA: Hq a multiple of Hkv), causal, scaled by
@@ -164,9 +164,10 @@ def splash_attention_tpu(
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     S = qt.shape[2]
-    # block 1024 is the measured winner on v5e (0.457 vs 0.449 MFU at 512;
-    # 2048 fails to compile — round-4 sweep, docs/performance.md); larger
-    # tiles amortize the online-softmax bookkeeping until VMEM runs out
+    # the largest tile that divides S, up to 1024: larger tiles amortize
+    # the online-softmax bookkeeping until VMEM runs out — at 2048 the
+    # forward kernel is RESOURCE_EXHAUSTED in vmem on a v5e ([4, 2048,
+    # 16|8, 128] bf16, jax 0.9.0 / libtpu 0.0.34; chip run, PR 21)
     blk = next(b for b in (1024, 512, 256, 128) if S % b == 0)
     # benchmark escape hatch: benchmarks/mfu_sweep.py sweeps these to find
     # the best tiles for a given chip generation; training code leaves them
@@ -198,28 +199,42 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+ATTENTION_CHOICES = ("auto", "splash", "flash", "reference")
+
 # Which kernel the last causal_attention dispatch resolved to ("splash" /
-# "flash" / "xla"). Set at trace time; benchmarks record it so a silent
-# fallback to the slow path is visible in their artifacts, not just implied
-# by the requested mode.
+# "flash" / "xla"). Set at trace time; callers that report a speed record
+# it so the kernel behind the number is stated, not implied.
 LAST_DISPATCH: "str | None" = None
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.Array:
     """Backend-dispatching causal attention.
 
-    On TPU (sequence tiling permitting): splash attention when the model is
-    GQA/MQA (KV heads stay unrepeated — group-factor less HBM traffic),
-    plain flash otherwise. XLA fallback elsewhere. Override with
-    ``TORCHFT_TPU_ATTENTION=splash|flash|xla`` (benchmark escape hatch).
+    On TPU: splash attention when the model is GQA/MQA (KV heads stay
+    unrepeated — group-factor less HBM traffic), plain flash otherwise;
+    shapes the kernels cannot tile are an error there, never a quiet
+    switch to materialized scores. Off TPU (the CPU test platform) the XLA
+    reference runs. ``TORCHFT_TPU_ATTENTION=auto|splash|flash|reference``
+    pins a kernel; ``reference`` is the XLA implementation on any backend.
     """
     global LAST_DISPATCH
-    S, hd = q.shape[1], q.shape[-1]
-    tileable = S % 128 == 0 and hd in (64, 128, 256)
     choice = os.environ.get("TORCHFT_TPU_ATTENTION", "auto")
-    if choice == "xla" or not (_on_tpu() and tileable):
+    if choice not in ATTENTION_CHOICES:
+        raise ValueError(
+            f"TORCHFT_TPU_ATTENTION={choice!r}: expected one of "
+            f"{ATTENTION_CHOICES}"
+        )
+    if choice == "reference" or not _on_tpu():
         LAST_DISPATCH = "xla"
         return xla_attention(q, k, v, cfg)
+    S, hd = q.shape[1], q.shape[-1]
+    if S % 128 != 0 or hd not in (64, 128, 256):
+        raise ValueError(
+            f"attention shape seq_len={S} head_dim={hd} does not tile the "
+            "TPU kernels (seq_len % 128 == 0, head_dim in 64/128/256); set "
+            "TORCHFT_TPU_ATTENTION=reference to run the XLA reference "
+            "(materialized f32 scores) on purpose"
+        )
     if choice == "splash" or (choice == "auto" and q.shape[2] != k.shape[2]):
         LAST_DISPATCH = "splash"
         return splash_attention_tpu(q, k, v, cfg)

@@ -271,9 +271,19 @@ def jnp_f32():
 
 
 def _use_interpret() -> bool:
+    """Pallas interpret mode on the CPU test platform only; on a TPU the
+    kernels compile through Mosaic, and no other backend runs them."""
     import jax
 
-    return jax.default_backend() not in ("tpu",)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the fused fp8 kernels run on tpu (compiled) or cpu (interpret "
+        f"mode, tests); got backend {backend!r}"
+    )
 
 
 def fused_quantize_fp8(x, row: int = 512):
@@ -281,8 +291,8 @@ def fused_quantize_fp8(x, row: int = 512):
 
     Rows map onto the VPU lane layout; one grid step per row-block keeps the
     whole row in VMEM (see /opt/skills/guides/pallas_guide.md tiling rules).
-    Falls back to interpret mode off-TPU so the same code paths are testable
-    on the CPU mesh.
+    Compiled on a TPU; interpret mode on the CPU test platform so the same
+    code paths are testable on the CPU mesh.
     """
     import jax
     import jax.numpy as jnumpy

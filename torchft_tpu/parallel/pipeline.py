@@ -26,7 +26,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -140,9 +140,6 @@ def make_pp_llama_loss(cfg: Any, mesh: Mesh, num_microbatches: Optional[int] = N
     targets, per-stage activation residency without remat would hit the HBM
     ceiling.
     """
-    from torchft_tpu.utils import import_shard_map
-    shard_map = import_shard_map()
-
     from torchft_tpu.models.llama import _rmsnorm, make_llama_layer_body
     from torchft_tpu.models.remat import remat_wrap
 

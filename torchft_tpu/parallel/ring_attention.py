@@ -18,6 +18,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["ring_attention", "make_ring_attention_fn", "make_sp_attention_fn"]
@@ -92,9 +93,6 @@ def make_sp_attention_fn(mesh: Mesh, kernel):
     strategies: ``kernel(q, k, v, cfg)`` runs per shard under the one
     (dp, fsdp) x sp x tp sharding contract, so ring and ulysses cannot
     drift apart on specs."""
-    from torchft_tpu.utils import import_shard_map
-    shard_map = import_shard_map()
-
     qspec = P(("dp", "fsdp"), "sp", "tp", None)
 
     def attention_fn(q, k, v, cfg):

@@ -17,9 +17,14 @@ pass of the device plane (ProcessGroupXLA, local mode).
 There is no CPU mode: no TPU, no result, exit code 2. A chip belongs to one
 process at a time, so this parent never initialises a JAX backend; every leg
 that needs the chip is a child that exits before the next one starts. The
-last line of stdout is one JSON object; nothing of the kind is printed on
-failure. It measures nothing it claims: times in the output are single-run
-observations ("claim": null).
+last line of stdout is the result the driver reads, one JSON object with
+exactly these keys, the device as the child's JAX reported it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The line before it is the summary of the legs, also one JSON object. Neither
+is printed on failure. It measures nothing it claims: times in the summary
+are single-run observations ("claim": null).
 
 Positional arguments name the internal child entry points (``device``,
 ``kernels``, ``device_plane``); there are no options.
@@ -464,6 +469,13 @@ def leg_four(device: dict, steps: int, config: str = CONFIG,
 
 
 # ------------------------------------------------------------------ parent
+def result_line(device: dict) -> str:
+    """The last line of stdout: these keys and no others."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
 def main() -> int:
     global _tag
     # a terminated smoke still stops what it started (finally: Launch.stop)
@@ -512,8 +524,9 @@ def main() -> int:
     except LegFailed as e:
         say(f"FAIL: {e}")
         return 1
-    print(json.dumps({"ok": True, "device": device, "legs": legs,
-                      "claim": None}), flush=True)
+    say("summary: " + json.dumps({"device": device, "legs": legs,
+                                  "claim": None}))
+    print(result_line(device), flush=True)
     return 0
 
 

@@ -122,6 +122,22 @@ def test_chip_smoke_fails_without_a_tpu():
         assert not line.startswith("{"), f"printed a result: {line}"
 
 
+def test_chip_smoke_result_line_has_exactly_the_contract_keys():
+    """The driver reads the last stdout line: ok + device, nothing else."""
+    import importlib.util
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    line = mod.result_line({"platform": "tpu", "kind": "TPU v5 lite",
+                            "count": 1, "wall_s": 3.2})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
 def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     """The script without the program must fail too."""
     import shutil

@@ -526,13 +526,17 @@ def healthwatch(smoke: bool = False) -> None:
     }))
 
 
-def tracing_metrics(steps: int = 30, warmup: int = 5, batch_size: int = 8,
+def tracing_metrics(steps: int = 30, warmup: int = 5, batch_size: int = 4096,
                     scrapes: int = 10000) -> dict:
     """Tracing-plane steady-state cost + /metrics under load: the example
     trainer under a Manager with the span recorder on and the Prometheus
     endpoint serving, scraper threads hammering /metrics until the scrape
     budget lands, then the span record paths micro-timed directly.
-    CPU-pinned subprocess, same isolation policy as the other FT rows."""
+    CPU-pinned subprocess, same isolation policy as the other FT rows.
+    The batch makes the toy's step 0.1-0.2 s here (8 ms at a batch of 8):
+    since PR 24 a step records a span per piece of bucket-pipeline work
+    (about 20 with the toy's one bucket, 5 before), so the 1% gate needs a
+    step that is a step — still a fiftieth of the chip cells' (PERF.md)."""
     import json as _json
     import os
     import subprocess
@@ -560,6 +564,12 @@ def tracing_metrics(steps: int = 30, warmup: int = 5, batch_size: int = 8,
         f"tracing child failed rc={out.returncode}: "
         f"{(out.stderr or out.stdout)[-300:]}"
     )
+
+
+# a steady step of the one-bucket toy records 17: the bucket's tree is 9
+# (pack, wire, unpack and their six children) and the step's own are 8;
+# the first step's compiles and configure bring a short run's mean to ~20
+TRACING_MAX_SPANS_PER_STEP = 24
 
 
 def tracing(smoke: bool = False) -> None:
@@ -596,6 +606,12 @@ def tracing(smoke: bool = False) -> None:
         raise RuntimeError(
             "tracing: zero spans per step — the Manager's hot-loop "
             "instrumentation is no longer reaching the recorder"
+        )
+    if not metrics["tracing_spans_per_step"] < TRACING_MAX_SPANS_PER_STEP:
+        raise RuntimeError(
+            f"tracing: {metrics['tracing_spans_per_step']} spans per "
+            f"one-bucket step >= {TRACING_MAX_SPANS_PER_STEP} — the count "
+            "is the half of the cost the share of a longer step would hide"
         )
     if metrics["metrics_scrapes_failed"] != 0:
         raise RuntimeError(

@@ -15,7 +15,9 @@ measures that claim instead of asserting it, three ways in one run:
   (``span()`` context exit, ``record_rel``, ``instant``) timed in a tight
   loop; ``tracing_overhead_pct`` is per-span cost × observed spans/step
   as a share of the measured managed step — the number the <1% gate
-  holds. (An end-to-end A/B of two full loops would measure the 1-vCPU
+  holds, on a batch that makes the toy's step 0.1-0.2 s (since PR 24 a
+  step records about 20 spans with one bucket, not 5; ``bench.py`` caps
+  the count too). (An end-to-end A/B of two full loops would measure the 1-vCPU
   host's scheduler, not the machinery — same reasoning as
   healthwatch_bench.)
 - **coverage sanity**: the loop's spans must actually be in the ring
@@ -58,7 +60,7 @@ def _parse_prometheus(text: str) -> int:
     return n
 
 
-def run(steps: int = 30, warmup: int = 5, batch_size: int = 8,
+def run(steps: int = 30, warmup: int = 5, batch_size: int = 4096,
         scrapers: int = 4, scrapes: int = 10000,
         span_calls: int = 2000) -> dict:
     """Time the example trainer under a tracing+metrics Manager while

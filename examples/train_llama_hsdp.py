@@ -34,6 +34,11 @@ import os
 import sys
 import time
 
+# Start-up stamps (epoch microseconds), kept until the Manager exists and
+# then recorded as its startup/* spans and startup_*_s timings: what a
+# process start consists of, from inside the process.
+_STARTUP_US = {"main": time.time_ns() // 1000}
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
@@ -67,6 +72,7 @@ def train(args) -> None:
     )
     from torchft_tpu.parallel.ulysses import make_ulysses_attention_fn
     from torchft_tpu.process_group import ProcessGroupHost
+    from torchft_tpu.tracing import process_start_us
     from torchft_tpu.utils import enable_compilation_cache
 
     cache_dir = enable_compilation_cache()
@@ -87,7 +93,9 @@ def train(args) -> None:
     # The devices this process was given: every chip of the host, or the
     # ones the launcher assigned (--chips-per-group). Named on every run so
     # two groups sitting on the same chips is visible, not inferred.
+    _STARTUP_US["imports"] = time.time_ns() // 1000
     devices = jax.devices()
+    _STARTUP_US["backend_init"] = time.time_ns() // 1000
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices)}
     visible_chips = os.environ.get("TPU_VISIBLE_CHIPS")  # launcher.chip_env
@@ -139,6 +147,7 @@ def train(args) -> None:
         return optax.apply_updates(params, updates), opt_state
 
     state = {"params": params, "opt_state": opt_state}
+    _STARTUP_US["state_init"] = time.time_ns() // 1000
 
     def load_state(sd):
         def place(t, x):
@@ -250,6 +259,18 @@ def train(args) -> None:
 
     rng = np.random.RandomState(replica_id)
     B, S = args.batch_size, args.seq_len
+    # process creation -> this module's first statement -> the first
+    # jax.devices() (arguments, every import) -> the pid= line -> state on
+    # the chip -> Manager, transport (and DiLoCo, durable restore) up: the
+    # mesh line below. Contiguous, so they sum to the process's start-up.
+    _STARTUP_US["manager_init"] = time.time_ns() // 1000
+    t_prev = process_start_us()
+    for phase in ("spawn_to_main", "imports", "backend_init", "state_init",
+                  "manager_init"):
+        t_end = _STARTUP_US["main" if phase == "spawn_to_main" else phase]
+        if t_prev is not None:
+            manager.record_phase("startup", phase, t_prev, t_end)
+        t_prev = t_end
     print(f"[replica {replica_id}] mesh fsdp={args.fsdp} sp={args.sp} tp={args.tp} "
           f"diloco={bool(diloco)} starting at step {manager.current_step()}",
           flush=True)
@@ -289,66 +310,76 @@ def train(args) -> None:
     # checkpoint/manager teardown must run on SIGINT/preemption/exception
     # exits too, not just the clean path
     try:
+        tracer = manager.tracer
         while not done():
-            t_iter = time.monotonic()
-            batch = jax.device_put(
-                jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B, S))), tok_sharding
-            )
-            if diloco is not None:
-                # inner step: local grads + local adamw, no cross-group traffic
-                loss, grads = grad_step(state["params"], batch, batch)
-                state["params"], state["opt_state"] = update_step(
-                    state["params"], state["opt_state"], grads
+            # trainer/* spans: the loop's own phases in the Manager's ring
+            # (and, under jax.profiler.trace, in the profiler's trace)
+            with tracer.span("step", cat="trainer"):
+                t_iter = time.monotonic()
+                batch = jax.device_put(
+                    jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B, S))), tok_sharding
                 )
-                # on a heal, diloco.step re-reads state["params"] via get_params
-                # and returns the healed pytree
-                state["params"] = diloco.step(state["params"])
-                # resume/catch-up: committed quorums are the global clock
-                inner_step = max(inner_step + 1,
-                                 manager.current_step() * per_cycle)
-                tokens_done += B * S
-            else:
-                manager.start_quorum()
-                loss, grads = grad_step(state["params"], batch, batch)
-                reduced = manager.allreduce(grads).get_future().wait(
-                    timeout=args.timeout
-                )
-                del grads  # 1x params of HBM the next grad_step needs
-                run["reduced_on_device"] &= on_device(reduced)
-                if not manager.should_commit():
-                    run["discarded"] += 1
-                    run["discarded_after_first"] += bool(run["iter_s"])
-                    run["iter_s"].append(time.monotonic() - t_iter)
-                    print(f"[replica {replica_id}] step="
-                          f"{manager.current_step()} DISCARDED", flush=True)
-                    continue
-                state["params"], state["opt_state"] = update_step(
-                    state["params"], state["opt_state"], reduced
-                )
-                del reduced  # donated
-                tokens_done += B * S * manager.num_participants()
-                inner_step += 1
-                run["committed"] += 1
-            # gate on the count that actually advances every loop iteration:
-            # in DiLoCo mode manager.current_step is constant across a whole
-            # inner window (bursty/silent logs); inner_step is not
-            if ckpt is not None:
-                # lazy: the full registered composite (trainer + algorithm
-                # state) is only materialized on the save interval
-                ckpt.maybe_save(manager.current_step(), manager.user_state_dict,
-                                manager=manager)
-            run["losses"].append(loss)  # device scalar: no sync added here
-            run["iter_s"].append(time.monotonic() - t_iter)
-            if inner_step % args.log_every == 0:
-                dt = time.monotonic() - t0
-                print(
-                    f"[replica {replica_id}] step={manager.current_step()} "
-                    f"inner={inner_step} loss={float(loss):.4f} "
-                    f"participants={manager.num_participants()} "
-                    f"iter_s={run['iter_s'][-1]:.2f} "
-                    f"tok/s={tokens_done / max(dt, 1e-6):.0f}",
-                    flush=True,
-                )
+                if diloco is not None:
+                    # inner step: local grads + local adamw, no cross-group traffic
+                    loss, grads = grad_step(state["params"], batch, batch)
+                    state["params"], state["opt_state"] = update_step(
+                        state["params"], state["opt_state"], grads
+                    )
+                    # on a heal, diloco.step re-reads state["params"] via get_params
+                    # and returns the healed pytree
+                    state["params"] = diloco.step(state["params"])
+                    # resume/catch-up: committed quorums are the global clock
+                    inner_step = max(inner_step + 1,
+                                     manager.current_step() * per_cycle)
+                    tokens_done += B * S
+                else:
+                    manager.start_quorum()
+                    with tracer.span("grad_dispatch", cat="trainer"):
+                        loss, grads = grad_step(state["params"], batch, batch)
+                    work = manager.allreduce(grads)
+                    with tracer.span("allreduce_wait", cat="trainer"):
+                        reduced = work.get_future().wait(timeout=args.timeout)
+                    del grads  # 1x params of HBM the next grad_step needs
+                    run["reduced_on_device"] &= on_device(reduced)
+                    if not manager.should_commit():
+                        run["discarded"] += 1
+                        run["discarded_after_first"] += bool(run["iter_s"])
+                        run["iter_s"].append(time.monotonic() - t_iter)
+                        print(f"[replica {replica_id}] step="
+                              f"{manager.current_step()} DISCARDED", flush=True)
+                        continue
+                    with tracer.span("update", cat="trainer"):
+                        state["params"], state["opt_state"] = update_step(
+                            state["params"], state["opt_state"], reduced
+                        )
+                    del reduced  # donated
+                    tokens_done += B * S * manager.num_participants()
+                    inner_step += 1
+                    run["committed"] += 1
+                # gate on the count that actually advances every loop iteration:
+                # in DiLoCo mode manager.current_step is constant across a whole
+                # inner window (bursty/silent logs); inner_step is not
+                if ckpt is not None:
+                    # lazy: the full registered composite (trainer + algorithm
+                    # state) is only materialized on the save interval
+                    ckpt.maybe_save(manager.current_step(), manager.user_state_dict,
+                                    manager=manager)
+                run["losses"].append(loss)  # device scalar: no sync added here
+                run["iter_s"].append(time.monotonic() - t_iter)
+                if inner_step % args.log_every == 0:
+                    dt = time.monotonic() - t0
+                    # the fetch iter_s leaves out: it waits for everything
+                    # the device still owes this step's loss
+                    with tracer.span("loss_fetch", cat="trainer"):
+                        loss_now = float(loss)
+                    print(
+                        f"[replica {replica_id}] step={manager.current_step()} "
+                        f"inner={inner_step} loss={loss_now:.4f} "
+                        f"participants={manager.num_participants()} "
+                        f"iter_s={run['iter_s'][-1]:.2f} "
+                        f"tok/s={tokens_done / max(dt, 1e-6):.0f}",
+                        flush=True,
+                    )
 
         # One machine-readable line: what ran where, and whether the state
         # a peer would heal from is what the peers hold (checksum: wrapping
@@ -379,7 +410,8 @@ def train(args) -> None:
             # the last step's phase splits (Manager.timings())
             "timings": {k: round(v, 3) for k, v in manager.timings().items()
                         if k.endswith("_s") or k.startswith("heal_")
-                        or k in ("allreduce_buckets", "overlap_efficiency")},
+                        or k in ("allreduce_buckets", "overlap_efficiency",
+                                 "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
             "peak_hbm_bytes": max((p for p in peaks if p), default=None),
             "cache_dir": cache_dir, "cache": cache_events,

@@ -98,7 +98,8 @@ def test_bench_tracing_smoke_holds_cost_and_serves_metrics():
     # a silently-weakened tracing() still fails CI
     assert rec["tracing_overhead_pct"] < 1.0
     assert rec["tracing_span_cost_us"] > 0
-    assert rec["tracing_spans_per_step"] > 0
+    # the count is capped beside the share: a longer step must not hide it
+    assert 0 < rec["tracing_spans_per_step"] < 24
     # the hot loop's spans reached the ring with the taxonomy's categories
     assert {"quorum", "commit"} <= set(rec["trace_categories"])
     assert rec["trace_merged_events"] > 0

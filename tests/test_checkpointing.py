@@ -916,6 +916,46 @@ class TestResilientRecv:
             src.shutdown()
             dst.shutdown()
 
+    def test_fetch_and_place_are_timed_events(self):
+        """One heal_fetch per chunk (socket -> buffers, its bytes) and one
+        heal_place per leaf that is placed onto a device template, each
+        with perf_counter endpoints; a host template the socket streams
+        into directly is never placed."""
+        import jax.numpy as jnp
+
+        state = {"w": np.arange(65_536, dtype=np.float32),
+                 "b": np.arange(1_024, dtype=np.float32)}
+        for template, placed in (
+            ({"w": jnp.zeros(65_536, jnp.float32), "b": jnp.zeros(1_024, jnp.float32)}, 2),
+            ({"w": np.zeros(65_536, np.float32), "b": np.zeros(1_024, np.float32)}, 0),
+        ):
+            src = HTTPTransport(timeout=10.0, num_chunks=4)
+            dst = HTTPTransport(timeout=10.0, state_dict_template=lambda: template)
+            events = []
+            lock = threading.Lock()
+
+            def on_event(kind, **f):
+                with lock:
+                    events.append((kind, f))
+
+            try:
+                src.send_checkpoint([1], 5, state, 10.0)
+                out = dst.recv_checkpoint_multi(
+                    [("src", lambda: src.metadata())], step=5, timeout=10.0,
+                    on_event=on_event)
+            finally:
+                src.shutdown()
+                dst.shutdown()
+            np.testing.assert_array_equal(np.asarray(out["w"]), state["w"])
+            fetch = [f for k, f in events if k == "heal_fetch"]
+            place = [f for k, f in events if k == "heal_place"]
+            assert sorted(f["chunk"] for f in fetch) == [0, 1, 2, 3]
+            assert sum(f["bytes"] for f in fetch) == 4 * (65_536 + 1_024)
+            assert len(place) == placed
+            assert sorted(f["bytes"] for f in place) == sorted(
+                [4 * 1_024, 4 * 65_536][:placed])
+            assert all(f["t1_pc"] >= f["t0_pc"] for f in fetch + place)
+
     def test_source_stall_resumes_at_verified_offset(self):
         """A v3 source dropping the connection mid-chunk is re-fetched with
         a ranged request from the last verified byte, not from scratch."""

@@ -524,3 +524,103 @@ class TestManagerCompressedStreaming:
                     a, np.asarray(results[rid][-1][k])
                 )
             np.testing.assert_allclose(a, expected[k], rtol=0.2, atol=0.3)
+
+
+# ---------------------------------------------------------------------------
+# The pipeline's span tree (tracing.py): children where the work happens
+# ---------------------------------------------------------------------------
+class TestPipelineSpanTree:
+    """One streamed allreduce per step through a real ProcessGroupHost at a
+    world of one. Per bucket: pack > (d2h, [codec], dispatch), wire >
+    wire_run, unpack > ([decode], divide, h2d); the three old spans keep
+    their names, category and ``(quorum_id, step)``."""
+
+    STEPS = 3
+    # clocks: context spans stamp the wall clock at entry, the stage spans
+    # are perf_counter marks re-anchored to it when the op resolves
+    SLACK_US = 2_000
+    # what a stage may hold beside its children: the loop's own statements
+    # between two spans (checked on the calmest of STEPS steps, so that one
+    # descheduled thread on a loaded host does not fail the test)
+    REMAINDER_US = 5_000
+
+    def _ring(self, compress):
+        tracers = {}
+
+        def body(rid, manager, step):
+            tracers[rid] = manager.tracer
+            tree = _tree(np.random.RandomState(step), leaves=6, n=30_000)
+            manager.allreduce_streamed(tree).wait(timeout=60)
+
+        _run_manager_fleet(body, world=1, steps=self.STEPS, compress=compress,
+                           bucket_cap_bytes=2 * 30_000 * 4)
+        dump = tracers[0].export()
+        assert dump["dropped"] == 0
+        return dump["spans"]
+
+    @pytest.mark.parametrize("compress", [None, "fp8"])
+    def test_children_lie_inside_and_cover_their_stage(self, compress):
+        spans = self._ring(compress)
+        by_id = {s["id"]: s for s in spans}
+        assert len(by_id) == len(spans)                     # ids are unique
+        kids = {}
+        for s in spans:
+            kids.setdefault(s["parent"], []).append(s)
+        want = {
+            "pack": ["d2h"] + (["codec"] if compress else []) + ["dispatch"],
+            "wire": ["wire_run"],
+            "unpack": (["decode"] if compress else []) + ["divide", "h2d"],
+        }
+        names = {s["name"] for s in spans if s["cat"] == "allreduce"}
+        assert ("codec" in names) == ("decode" in names) == bool(compress)
+        remainder = {}                                      # step -> worst bucket
+        for stage, children in want.items():
+            stages = [s for s in spans if (s["cat"], s["name"]) == ("allreduce", stage)]
+            assert len(stages) == 3 * self.STEPS            # 3 buckets a step
+            for st in stages:
+                got = sorted(kids[st["id"]], key=lambda s: s["ts_us"])
+                assert [s["name"] for s in got] == children, (stage, got)
+                assert all(s["args"]["bucket"] == st["args"]["bucket"]
+                           and s["step"] == st["step"] for s in got)
+                lo, hi = st["ts_us"], st["ts_us"] + st["dur_us"]
+                for s in got:
+                    assert s["ts_us"] >= lo - self.SLACK_US, (stage, s, st)
+                    assert s["ts_us"] + s["dur_us"] <= hi + self.SLACK_US, (stage, s, st)
+                if stage != "wire":   # wire also holds the wait in the PG's queue
+                    left = st["dur_us"] - sum(s["dur_us"] for s in got)
+                    assert left >= -self.SLACK_US
+                    remainder[st["step"]] = max(remainder.get(st["step"], 0), left)
+                # the allreduce span is the stages' parent, and closes last
+                ar = by_id[st["parent"]]
+                assert (ar["cat"], ar["name"]) == ("allreduce", "allreduce")
+                assert ar["args"] == {"buckets": 3, "bytes": 6 * 30_000 * 4}
+        assert min(remainder.values()) <= self.REMAINDER_US, remainder
+        for s in spans:
+            if s["name"] == "wire_run":
+                a = s["args"]
+                assert a["world"] == 1 and a["queued_us"] >= 0
+                assert a["bytes"] == 2 * 30_000 * 4
+            if s["name"] == "d2h":
+                assert s["args"]["bytes"] == 2 * 30_000 * 4
+                assert ("queued_us" in s["args"]) == (s["args"]["bucket"] == 0)
+            if s["name"] in ("decode", "divide"):
+                first = "decode" if compress else "divide"
+                assert ("queued_us" in s["args"]) == (s["name"] == first)
+            if s["name"] == "codec":
+                assert s["args"]["bytes_out"] < s["args"]["bytes"]
+
+    def test_old_spans_keep_name_category_and_step(self):
+        spans = self._ring(None)
+        for step in range(self.STEPS):
+            mine = [s for s in spans if s["step"] == step]
+            got = sorted((s["cat"], s["name"]) for s in mine
+                         if s["name"] in ("pack", "wire", "unpack", "quorum_rpc",
+                                          "commit_vote"))
+            assert got == sorted(
+                [("allreduce", n) for n in ("pack", "wire", "unpack")] * 3
+                + [("quorum", "quorum_rpc"), ("commit", "commit_vote")]), step
+            assert all(s["quorum_id"] is not None for s in mine
+                       if s["cat"] in ("allreduce", "commit"))
+            # the whole-method spans the profiler-only annotations became
+            assert {("quorum", "async_quorum"), ("commit", "should_commit")} <= {
+                (s["cat"], s["name"]) for s in mine}

@@ -5,6 +5,7 @@ not-enough-participants, allreduce errors, pg.errored propagation,
 fixed-with-spares, quorum failure, max_retries).
 """
 
+import time
 from unittest.mock import MagicMock, patch
 
 import numpy as np
@@ -496,6 +497,45 @@ class TestHealing:
         # commit applies the pending state dict and restores step
         assert m.should_commit()
         assert m.current_step() == 4  # healed to 3, +1 on commit
+
+    def test_heal_pieces_are_spans_under_heal_recv_and_summed_timings(self):
+        """What the HTTP transport times of a receive (one chunk off the
+        socket, one leaf placed) reaches the ring as children of heal_recv
+        and timings() as sums over its fetch threads; the apply on the main
+        thread is heal/heal_apply."""
+        q = make_quorum(heal=True, max_step=3, max_replica_rank=None,
+                        max_world_size=1, recover_src_replica_rank=1)
+        m = make_manager(quorum=q, min_replica_size=1)
+        m._test_transport.supports_multi_source = True
+        m._test_transport.last_recv_timings.return_value = None
+
+        def recv(sources, step, timeout, on_event):
+            now = time.perf_counter()
+            on_event("heal_fetch", chunk=0, bytes=100, t0_pc=now - 0.5, t1_pc=now - 0.1)
+            on_event("heal_fetch", chunk=1, bytes=60, t0_pc=now - 0.5, t1_pc=now - 0.2)
+            on_event("heal_place", leaf=0, bytes=160, t0_pc=now - 0.1, t1_pc=now - 0.05)
+            on_event("heal_retry", chunk=1, source="x", attempt=1)   # the old kinds still count
+            return {"user": {"default": {"w": np.zeros(2)}},
+                    "torchft": {"step": 3, "batches_committed": 6}}
+
+        m._test_transport.recv_checkpoint_multi.side_effect = recv
+        with patch("torchft_tpu.manager.ManagerClient") as mc:
+            mc.return_value._checkpoint_metadata.return_value = "mock://peer"
+            m.start_quorum()
+            m.wait_quorum()
+        m.allreduce({"w": np.ones(2, dtype=np.float32)}).get_future().wait(10)
+        assert m.should_commit()
+        t = m.timings()
+        assert t["heal_fetch_s"] == pytest.approx(0.7, abs=1e-6)    # 0.4 + 0.3: a sum
+        assert t["heal_place_s"] == pytest.approx(0.05, abs=1e-6)
+        assert t["heal_apply_s"] >= 0 and t["heal_attempts"] == 2   # 1 + the retry
+        spans = m.tracer.export()["spans"]
+        recv_span = next(s for s in spans if s["name"] == "heal_recv")
+        kids = [s for s in spans if s["parent"] == recv_span["id"]]
+        assert sorted((s["name"], s["args"]["bytes"]) for s in kids) == [
+            ("heal_fetch", 60), ("heal_fetch", 100), ("heal_place", 160)]
+        assert all(s["cat"] == "heal" for s in kids)
+        assert [s["name"] for s in spans if s["cat"] == "heal"].count("heal_apply") == 1
 
     def test_sync_quorum_applies_state_eagerly(self):
         q = make_quorum(

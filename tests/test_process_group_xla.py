@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.coordination import KvStoreServer
+from torchft_tpu.tracing import SpanRecorder, TraceConfig
 from torchft_tpu.process_group import ReduceOp
 from torchft_tpu.process_group_xla import ProcessGroupXLA
 
@@ -266,7 +267,8 @@ class TestLocalMode:
                 self._logger = _Log()
 
             errored = lambda self: None
-            wait_quorum = lambda self: None
+            wait_quorum = lambda self, cat="quorum", parent=None: None
+            _tracer = SpanRecorder("stub", TraceConfig(enabled=False))
             num_participants = lambda self: world
             is_participating = lambda self: True
             report_error = lambda self, e: None
@@ -314,7 +316,8 @@ class TestLocalMode:
                 self._logger = _Log()
 
             errored = lambda self: None
-            wait_quorum = lambda self: None
+            wait_quorum = lambda self, cat="quorum", parent=None: None
+            _tracer = SpanRecorder("stub", TraceConfig(enabled=False))
             num_participants = lambda self: world
             is_participating = lambda self: True
             report_error = lambda self, e: None

@@ -245,6 +245,156 @@ class TestSpanRecorder:
         assert rec.dump(tmp_path) is None
 
 
+class TestSpanTree:
+    """ids, parents, args and the profiler annotation of the one span API."""
+
+    def test_ids_and_parents_on_one_thread(self):
+        rec = SpanRecorder("tree", _cfg())
+        with rec.span("step", cat="trainer") as outer:
+            assert rec.current() == outer.id
+            with rec.span("allreduce_wait", cat="trainer") as inner:
+                assert rec.current() == inner.id
+            with rec.span("update", cat="trainer"):
+                pass
+        assert rec.current() is None
+        spans = {s["name"]: s for s in rec.export()["spans"]}
+        assert spans["step"]["parent"] is None
+        assert spans["allreduce_wait"]["parent"] == spans["step"]["id"]
+        assert spans["update"]["parent"] == spans["step"]["id"]
+        assert len({s["id"] for s in spans.values()}) == 3
+
+    def test_parent_crosses_threads_in_the_closure(self):
+        """The staging, dispatch and unpack workers: the parent's id is
+        handed over; a thread's own stack never leaks into another."""
+        rec = SpanRecorder("threads", _cfg())
+        pack_id = rec.new_id()          # recorded after the fact, id known now
+
+        def worker(name):
+            assert rec.current() is None
+            with rec.span(name, cat="allreduce", parent=pack_id, bucket=0):
+                with rec.span(name + "_inner", cat="allreduce"):
+                    pass
+
+        with rec.span("allreduce_call", cat="trainer") as main:
+            with ThreadPoolExecutor(max_workers=3) as ex:
+                list(ex.map(worker, ["d2h", "wire_run", "h2d"]))
+        rec.record_rel("pack", "allreduce", time.perf_counter() - 0.01,
+                       time.perf_counter(), id=pack_id, parent=main.id, bucket=0)
+        spans = {s["name"]: s for s in rec.export()["spans"]}
+        for name in ("d2h", "wire_run", "h2d"):
+            assert spans[name]["parent"] == pack_id
+            assert spans[name + "_inner"]["parent"] == spans[name]["id"]
+        assert spans["pack"]["id"] == pack_id
+        assert spans["pack"]["parent"] == spans["allreduce_call"]["id"]
+
+    def test_args_fill_until_exit_and_export_gains_keys_only(self):
+        rec = SpanRecorder("args", _cfg())
+        rec.set_context(quorum_id=2, step=5)
+        with rec.span("d2h", cat="allreduce", bucket=1, queued_us=7) as sp:
+            sp.args["bytes"] = 4096
+        (span,) = rec.export()["spans"]
+        assert span["args"] == {"bucket": 1, "queued_us": 7, "bytes": 4096}
+        assert sorted(span) == ["args", "cat", "dur_us", "id", "name", "parent",
+                                "quorum_id", "step", "ts_us"]
+        # the merger and the CLI read a dump with the new keys unchanged
+        (ev,) = [e for e in merge_traces([rec.export()])["traceEvents"]
+                 if e["ph"] == "X"]
+        assert ev["name"] == "d2h" and ev["args"]["bytes"] == 4096
+
+    def test_a_span_belongs_to_the_step_it_started_in(self):
+        rec = SpanRecorder("steps", _cfg())
+        rec.set_context(step=4)
+        with rec.span("step", cat="trainer"):
+            rec.set_context(step=5)     # the commit advances the step
+            with rec.span("update", cat="trainer"):
+                pass
+        spans = {s["name"]: s["step"] for s in rec.export()["spans"]}
+        assert spans == {"step": 4, "update": 5}
+
+    def test_disabled_recorder_hands_out_a_writable_null_span(self):
+        rec = SpanRecorder("off", _cfg(enabled=False))
+        with rec.span("d2h", cat="allreduce") as sp:
+            sp.args["bytes"] = 1
+        assert sp.id is None and rec.export()["spans"] == []
+
+    def test_annotation_entered_once_per_span_named_as_the_benchmark_names_it(
+            self, monkeypatch):
+        from torchft_tpu import tracing
+
+        seen = []
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(tracing, "_trace_annotation", Fake)
+        rec = SpanRecorder("ann", _cfg())
+        with rec.span("step", cat="trainer"):
+            with rec.span("d2h", cat="allreduce", bucket=0):
+                pass
+        rec.record_rel("pack", "allreduce", 0.0, 1.0)   # after the fact: ring only
+        rec.instant("reroute", cat="rpc")
+        assert seen == [("enter", "manager.trainer.step"),
+                        ("enter", "manager.allreduce.d2h"),
+                        ("exit", "manager.allreduce.d2h"),
+                        ("exit", "manager.trainer.step")]
+        off = SpanRecorder("ann_off", _cfg(enabled=False))
+        with off.span("step", cat="trainer"):
+            pass
+        assert len(seen) == 4
+
+    def test_tracing_never_imports_jax(self):
+        """A process without jax records to the ring alone, and this module
+        is never the one to load it."""
+        import subprocess
+        import sys
+
+        code = (
+            "import sys\n"
+            "from torchft_tpu.tracing import SpanRecorder\n"
+            "rec = SpanRecorder('nojax')\n"
+            "with rec.span('step', cat='trainer'):\n"
+            "    with rec.span('d2h', cat='allreduce'):\n"
+            "        pass\n"
+            "assert len(rec.export()['spans']) == 2\n"
+            "assert 'jax' not in sys.modules, 'tracing imported jax'\n"
+        )
+        proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr
+
+    def test_compiles_are_spans_of_the_step_that_made_them(self):
+        import jax
+        import jax.numpy as jnp
+
+        rec = SpanRecorder("compile", _cfg(buffer=256))
+        off = SpanRecorder("compile_off", _cfg(enabled=False))
+        rec.set_context(step=9)
+        with rec.span("grad_dispatch", cat="trainer") as cause:
+            # a function nothing else in the process has compiled
+            jax.jit(lambda x: x * 3.25 + 0.125, inline=False)(
+                jnp.ones((3, 5))).block_until_ready()
+        spans = [s for s in rec.export()["spans"] if s["cat"] == "compile"]
+        assert [s["name"] for s in spans].count("backend_compile") >= 1
+        assert {s["name"] for s in spans} <= {"backend_compile", "cache_retrieval"}
+        assert all(s["step"] == 9 and s["parent"] == cause.id for s in spans)
+        made = sum(s["dur_us"] for s in spans if s["name"] == "backend_compile")
+        assert rec.compile_total_s() == pytest.approx(made / 1e6, abs=1e-3)
+        assert off.compile_total_s() == 0.0 and not off.export()["spans"]
+
+    def test_process_start_is_before_now_and_not_long_ago(self):
+        from torchft_tpu.tracing import process_start_us
+
+        start, now = process_start_us(), time.time_ns() // 1000
+        assert start is not None and 0 < now - start < 3600 * 1_000_000
+
+
 # -------------------------------------------------------------------- merge
 class TestMergeTraces:
     def _dump(self, rid, skew_ms, spans):
@@ -712,3 +862,82 @@ def test_fleet_chaos_merge_produces_skew_corrected_timeline(tmp_path):
         assert abs(corrected[0][s] - corrected[1][s]) < 1_000_000, (
             s, corrected, "corrected timeline did not line up"
         )
+
+
+def test_profiler_trace_of_the_managed_trainer_holds_the_programs_spans(tmp_path):
+    """The program's spans are in the profiler's own trace: a
+    ``jax.profiler.trace`` over the managed trainer (examples/
+    train_llama_hsdp.py under a lighthouse, three tiny steps on the CPU, no
+    benchmark harness) holds the bucket pipeline's and the trainer's
+    annotations under the names the benchmark gives the ring's spans. The
+    same run's SUMMARY carries the start-up and first-step timings, and its
+    ring dropped nothing at the default buffer."""
+    import os
+    import subprocess
+    import sys
+
+    from jax.profiler import ProfileData
+
+    from torchft_tpu.coordination import LighthouseServer
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    trainer = os.path.join(root, "examples", "train_llama_hsdp.py")
+    script = tmp_path / "profiled.py"
+    script.write_text(
+        "import runpy, sys\n"
+        "import jax\n"
+        "out, trainer = sys.argv[1], sys.argv[2]\n"
+        "sys.argv = [trainer, *sys.argv[3:]]\n"
+        "opts = jax.profiler.ProfileOptions()\n"
+        "opts.python_tracer_level = 0\n"
+        "jax.profiler.start_trace(out, profiler_options=opts)\n"
+        "try:\n"
+        "    runpy.run_path(trainer, run_name='__main__')\n"
+        "finally:\n"
+        "    jax.profiler.stop_trace()\n"
+    )
+    lh = LighthouseServer(bind="127.0.0.1:0", min_replicas=1,
+                          join_timeout_ms=200, quorum_tick_ms=20)
+    addr = f"127.0.0.1:{lh.port}"
+    try:
+        proc = subprocess.run(
+            [sys.executable, str(script), str(tmp_path / "prof"), trainer,
+             "--config", "tiny", "--steps", "3", "--batch-size", "2",
+             "--seq-len", "32", "--virtual-chips", "1"],
+            capture_output=True, text=True, timeout=300,
+            env=dict(os.environ, TORCHFT_LIGHTHOUSE=addr, REPLICA_GROUP_ID="0",
+                     JAX_PLATFORMS="cpu", PYTHONPATH=root,
+                     # several buckets a step, like the real sizes have
+                     TORCHFT_BUCKET_CAP_MB="0.5"),
+        )
+    finally:
+        lh.shutdown()
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    (pb,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    names = {}
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("manager."):
+                        names[e.name] = names.get(e.name, 0) + 1
+    assert names["manager.trainer.step"] == 3
+    assert names["manager.trainer.loss_fetch"] == 3
+    assert names["manager.allreduce.d2h"] >= 2 * 3      # per bucket, per step
+    assert names["manager.allreduce.d2h"] == names["manager.allreduce.h2d"] \
+        == names["manager.allreduce.dispatch"] == names["manager.allreduce.divide"]
+    for n in ("manager.allreduce.capture", "manager.allreduce.grad_wait",
+              "manager.quorum.quorum_rpc", "manager.commit.commit_vote",
+              "manager.commit.should_commit", "manager.quorum.async_quorum",
+              "manager.trainer.grad_dispatch", "manager.trainer.allreduce_wait",
+              "manager.trainer.update"):
+        assert names.get(n, 0) >= 3, (n, names)
+    assert not any("codec" in n or "decode" in n for n in names)   # no compress mode
+    line = next(ln for ln in proc.stdout.splitlines() if " SUMMARY " in ln)
+    timings = json.loads(line.split(" SUMMARY ", 1)[1])["timings"]
+    assert timings["trace_dropped"] == 0.0
+    for key in ("startup_spawn_to_main_s", "startup_imports_s",
+                "startup_backend_init_s", "startup_state_init_s",
+                "startup_manager_init_s", "first_step_compile_s"):
+        assert timings[key] >= 0.0, key
+    assert timings["startup_imports_s"] > 0 and timings["first_step_compile_s"] > 0

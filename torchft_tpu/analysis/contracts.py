@@ -28,6 +28,10 @@ DECLARED_TIMINGS: Dict[str, str] = {
     # heal plane
     "heal_send_s": "serving a live checkpoint to a peer",
     "heal_recv_s": "fetching + applying a live checkpoint",
+    "heal_fetch_s": "socket -> recv buffers, summed over fetch threads",
+    "heal_place_s": "placing healed leaves, summed over fetch threads",
+    "heal_apply_s": "load_state_dict of the healed state (main thread)",
+    "first_step_compile_s": "backend compiles (cache lookups included) up to the first commit",
     "heal_chunks": "chunks in the last heal stream",
     "heal_mb_per_s": "last heal stream throughput",
     "heal_attempts": "cumulative heal tries (incl. same-source retries)",

@@ -489,7 +489,7 @@ class HTTPTransport(CheckpointTransport[Any]):
                         "heal source %s plans %s, prior source planned %s; "
                         "restarting the receive from scratch", label, sig, rs.sig
                     )
-                rs = _RecvState(spec, num_chunks, self._template_fn)
+                rs = _RecvState(spec, num_chunks, self._template_fn, emit)
             try:
                 self._fetch_all(
                     rs, base, version, deadline, timeout_s, timings, emit, label
@@ -545,7 +545,8 @@ class HTTPTransport(CheckpointTransport[Any]):
                     )
                 try:
                     self._fetch_chunk_once(
-                        rs, st, base, version, min(timeout_s, remaining), timings
+                        rs, st, base, version, min(timeout_s, remaining),
+                        timings, emit,
                     )
                     return
                 except _ChunkCrcError as e:
@@ -592,6 +593,7 @@ class HTTPTransport(CheckpointTransport[Any]):
         version: int,
         timeout_s: float,
         timings: StreamTimings,
+        emit: Callable[..., None],
     ) -> None:
         """One streaming attempt at chunk ``st.i``: read range frames and
         stream payloads straight into the leaf recv buffers, resuming from
@@ -690,6 +692,9 @@ class HTTPTransport(CheckpointTransport[Any]):
                     attempt_bytes += n
                 st.pending.append((leaf_idx, nbytes))
                 st.cur = None
+        # socket -> recv buffers, this attempt (placement comes after)
+        emit("heal_fetch", chunk=st.i, bytes=attempt_bytes,
+             t0_pc=t0, t1_pc=time.perf_counter())
         # chunk verified (or v1/v2-complete): apply the deferred credits,
         # finalizing any leaves this chunk completed
         for leaf_idx, n in st.pending:
@@ -762,7 +767,14 @@ class _RecvState:
     leaf's last verified range — placement of completed leaves overlaps the
     wire transfer of the chunks still streaming."""
 
-    def __init__(self, spec: Any, num_chunks: int, template_fn: Any) -> None:
+    def __init__(
+        self,
+        spec: Any,
+        num_chunks: int,
+        template_fn: Any,
+        emit: Callable[..., None],
+    ) -> None:
+        self.emit = emit
         self.spec = spec
         self.num_chunks = num_chunks
         self.sig = (num_chunks, tuple(m.nbytes for m in spec.leaves))
@@ -824,7 +836,10 @@ class _RecvState:
             if not self.direct[leaf_idx] and self.template_leaves is not None:
                 # device template (device_put) or a mismatch
                 # (warns "in-place receive degraded")
+                t0 = time.perf_counter()
                 arr = place_leaf_like(arr, self.template_leaves[leaf_idx], logger)
+                self.emit("heal_place", leaf=leaf_idx, bytes=meta.nbytes,
+                          t0_pc=t0, t1_pc=time.perf_counter())
             self.payloads[leaf_idx] = arr
         else:
             self.payloads[leaf_idx] = bytes(arr)

@@ -78,7 +78,7 @@ REGISTRY: Dict[str, Knob] = {
         _k("TORCHFT_HOST", "str", "127.0.0.1", "api.md#process-groups", "tuning-env",
            "Hostname the XLA store/transport advertises (multi-host fleets)."),
         # --------------------------------------------------- data plane
-        _k("TORCHFT_BUCKET_CAP_MB", "float", "32", "performance.md#bucketing", "tuning-env",
+        _k("TORCHFT_BUCKET_CAP_MB", "float", "1024", "performance.md#bucketing", "tuning-env",
            "Allreduce flat-bucket cap in MB; 0 disables bucketing."),
         _k("TORCHFT_STREAM_BUCKETS", "bool", "1", "performance.md#streaming",
            "compress-env",

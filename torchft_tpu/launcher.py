@@ -211,9 +211,13 @@ def launch_replica_groups(
                                 )
                     if restarts[i] < max_restarts:
                         restarts[i] += 1
+                        # the epoch stamp is this clock's reading of what
+                        # the replacement reads of itself from /proc
+                        # (tracing.process_start_us: startup/spawn_to_main)
                         logger.warning(
-                            "replica group %d died (codes=%s); restart %d/%d",
-                            i, codes, restarts[i], max_restarts,
+                            "replica group %d died (codes=%s); restart %d/%d "
+                            "spawned at epoch %.3f",
+                            i, codes, restarts[i], max_restarts, time.time(),
                         )
                         groups[i] = specs[i].spawn(lighthouse_addr)
                     else:

@@ -60,8 +60,6 @@ from torchft_tpu.observability import (
     get_event_drain,
     log_error_event,
     log_quorum_event,
-    trace_span,
-    traced,
 )
 from torchft_tpu.ops.quantization import (
     compress_bucket,
@@ -584,12 +582,22 @@ class Manager:
 
         # fleet tracing plane: per-replica span recorder (tracing.py).
         # Constructor arg > TORCHFT_TRACE env (default on); spans are O(1)
-        # dict appends behind one lock, so the default-on cost holds the
-        # bench.py --tracing <1% line.
+        # dict appends behind one lock (what default-on costs on the chip:
+        # PERF.md section 6, PR 24).
         trace_cfg = TraceConfig.from_env()
         if tracing is not None:
             trace_cfg.enabled = bool(tracing)
         self._tracer = SpanRecorder(self._replica_id, trace_cfg)
+        # what is recorded before the first quorum (the trainer's start-up,
+        # the head of its first iteration) belongs to the step it leads to
+        self._tracer.set_context(step=self._step)
+        # heal_fetch / heal_place seconds of the receive in flight, summed
+        # over the transport's fetch threads (_on_heal_event), and the
+        # heal_recv span they are children of
+        self._heal_lock = threading.Lock()
+        self._heal_sums: Dict[str, float] = {}
+        self._heal_recv_span: Optional[int] = None
+        self._first_commit_seen = False
         # one-shot latch for the dropped_events warning (satellite: the
         # drain's drop count used to be silently discarded)
         self._dropped_events_warned = False
@@ -841,9 +849,19 @@ class Manager:
                 # recovery failed (error already reported); retry next quorum
                 self._healing = False
 
-    def wait_quorum(self) -> None:
+    def wait_quorum(
+        self, cat: str = "quorum", parent: Optional[int] = None
+    ) -> None:
+        # one span per wait that waits, under the category of the phase
+        # that does (``allreduce/wait_quorum`` is the one at the head of
+        # _allreduce); a quorum that is already there records nothing —
+        # num_participants() and its kin come through here several times
+        # a step
         assert self._quorum_future is not None, "must call start_quorum first"
-        with trace_span("torchft::manager::wait_quorum"):
+        if self._quorum_future.done():
+            self._quorum_future.result()
+            return
+        with self._tracer.span("wait_quorum", cat=cat, parent=parent):
             self._quorum_future.result()
 
     # ------------------------------------------------------------- policy
@@ -1022,7 +1040,6 @@ class Manager:
         )
         self.load_user_state_dict(host)
 
-    @traced("torchft::manager::_async_quorum")
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: float
     ) -> None:
@@ -1032,7 +1049,10 @@ class Manager:
         # the only piece that still serializes with the trainer)
         t0 = time.perf_counter()
         try:
-            self._async_quorum_body(allow_heal, shrink_only, quorum_timeout)
+            with self._tracer.span("async_quorum", cat="quorum"):
+                self._async_quorum_body(
+                    allow_heal, shrink_only, quorum_timeout
+                )
         finally:
             self._record_timing("quorum_overlap_s", time.perf_counter() - t0)
 
@@ -1105,8 +1125,7 @@ class Manager:
                 # state returns that swap as a commit callable which the
                 # main thread applies at the next safe point
                 t_prep = time.perf_counter()
-                with trace_span("torchft::manager::_pg::prepare_configure"), \
-                        self._tracer.span("configure_prepare", cat="quorum"):
+                with self._tracer.span("configure_prepare", cat="quorum"):
                     pg_commit = self._pg.prepare_configure(
                         store_prefixed_addr,
                         quorum.replica_rank,
@@ -1127,10 +1146,7 @@ class Manager:
                 # (no-op for address-based transports; PGTransport
                 # rendezvouses its recovery PG here). Distinct /recovery
                 # store namespace so the two meshes can't cross-wire.
-                with trace_span("torchft::manager::_transport::configure"), \
-                        self._tracer.span(
-                            "transport_configure", cat="quorum"
-                        ):
+                with self._tracer.span("transport_configure", cat="quorum"):
                     self._checkpoint_transport.configure(
                         f"{quorum.store_address}/torchft/{quorum.quorum_id}"
                         f"/recovery/{self._group_rank}",
@@ -1174,14 +1190,11 @@ class Manager:
                         f"peers need recovery from us {quorum.recover_dst_replica_ranks}"
                     )
                     t_send = time.perf_counter()
-                    with trace_span("torchft::manager::send_checkpoint"), \
-                            self._tracer.span(
-                                "heal_send",
-                                cat="heal",
-                                dst_ranks=list(
-                                    quorum.recover_dst_replica_ranks
-                                ),
-                            ):
+                    with self._tracer.span(
+                        "heal_send",
+                        cat="heal",
+                        dst_ranks=list(quorum.recover_dst_replica_ranks),
+                    ):
                         self._checkpoint_transport.send_checkpoint(
                             dst_ranks=quorum.recover_dst_replica_ranks,
                             step=quorum.max_step,
@@ -1241,12 +1254,25 @@ class Manager:
                     assert quorum.recover_src_replica_rank is not None
                     self._bump_counter("heal_attempts")
                     t_recv = time.perf_counter()
-                    with trace_span("torchft::manager::recv_checkpoint"), \
-                            self._tracer.span("heal_recv", cat="heal"):
+                    with self._heal_lock:
+                        self._heal_sums = {
+                            "heal_fetch_s": 0.0, "heal_place_s": 0.0,
+                        }
+                    with self._tracer.span("heal_recv", cat="heal") as recv:
+                        self._heal_recv_span = recv.id
                         self._pending_state_dict = self._recv_checkpoint(quorum)
                     self._record_timing(
                         "heal_recv_s", time.perf_counter() - t_recv
                     )
+                    # the parts of heal_recv the transport timed (the HTTP
+                    # transport: per chunk and per leaf). SUMS over its
+                    # fetch threads (up to 8 run at once), so the two can
+                    # add up to more than heal_recv_s
+                    with self._heal_lock:
+                        sums = dict(self._heal_sums)
+                    for key, total in sums.items():
+                        if total:
+                            self._record_timing(key, total)
                     stream = self._checkpoint_transport.last_recv_timings()
                     if stream is not None:
                         self._record_timing("heal_chunks", float(stream.num_chunks))
@@ -1305,6 +1331,20 @@ class Manager:
         bump the matching cumulative counter and leave a flight-recorder
         breadcrumb so a postmortem can reconstruct the heal's retry/
         failover sequence."""
+        if kind in ("heal_fetch", "heal_place"):
+            # a timed piece of the receive (one chunk off the socket, one
+            # leaf placed), on the transport's fetch thread
+            t0_pc, t1_pc = fields.pop("t0_pc"), fields.pop("t1_pc")
+            with self._heal_lock:
+                key = kind + "_s"
+                self._heal_sums[key] = (
+                    self._heal_sums.get(key, 0.0) + t1_pc - t0_pc
+                )
+            self._tracer.record_rel(
+                kind, "heal", t0_pc, t1_pc,
+                parent=self._heal_recv_span, **fields,
+            )
+            return
         counter = {
             "heal_retry": "heal_attempts",
             "heal_failover": "heal_failovers",
@@ -1467,12 +1507,15 @@ class Manager:
         pending = self._pending_state_dict
         assert pending is not None, "checkpoint was not staged"
         self._logger.info("applying pending state dict")
-        with self._state_dict_lock.w_lock():
+        t0 = time.perf_counter()
+        with self._tracer.span("heal_apply", cat="heal"), \
+                self._state_dict_lock.w_lock():
             user = pending["user"]
             for key, load_fn in self._load_state_dict_fns.items():
                 if key in user:
                     load_fn(user[key])
             self._pending_state_dict = None
+        self._record_timing("heal_apply_s", time.perf_counter() - t0)
         self._last_quorum_healed = True
         self._bump_metric("heals")
 
@@ -1488,8 +1531,7 @@ class Manager:
             return
         t0 = time.perf_counter()
         try:
-            with trace_span("torchft::manager::configure_commit"), \
-                    self._tracer.span("configure_commit", cat="quorum"):
+            with self._tracer.span("configure_commit", cat="quorum"):
                 commit()
         except Exception as e:  # noqa: BLE001
             # force the next quorum cycle to re-run prepare+commit even if
@@ -1726,7 +1768,6 @@ class Manager:
             stream = GradStream([fut], fut)
         return stream
 
-    @traced("torchft::manager::allreduce")
     def _allreduce(
         self,
         values: Any,
@@ -1744,6 +1785,13 @@ class Manager:
         t_allreduce0 = time.perf_counter()
         self._bump_metric("allreduces")
         leaves, treedef = jax.tree_util.tree_flatten(values)
+        tracer = self._tracer
+        # allreduce/allreduce runs from here to the resolve of the returned
+        # work, on another thread: recorded there (_time_allreduce), its
+        # id known now so the spans below can name it as their parent
+        ar_id = tracer.new_id()
+        ar_parent = tracer.current()  # the caller's span (trainer/step)
+        ar_args: Dict[str, Any] = {}
 
         # Bucketed path: pack a multi-leaf tree into a handful of flat
         # same-dtype buffers (shared bucketing.py; plan cached by tree
@@ -1833,11 +1881,14 @@ class Manager:
         if self.errored():
             return DummyWork(zeros()), None
 
-        self.wait_quorum()
+        self.wait_quorum(cat="allreduce", parent=ar_id)
         # a reconfigure that landed during the forward pass commits its
         # backend swap here, before the collective touches the PG — this
         # is the "next safe point" for steps that skip should_commit
-        self._commit_pending_configure()
+        with tracer.span(
+            "configure_commit_wait", cat="allreduce", parent=ar_id
+        ):
+            self._commit_pending_configure()
         if self.errored():
             return DummyWork(zeros()), None
         num_participants = self.num_participants()
@@ -1883,11 +1934,21 @@ class Manager:
             # submission → resolve wall clock of the most recent
             # collective, for the steady-state budget split
             # (ft_overhead harness; see timings())
-            self._record_timing(
-                "allreduce_s", time.perf_counter() - t_allreduce0
+            t1 = time.perf_counter()
+            self._record_timing("allreduce_s", t1 - t_allreduce0)
+            tracer.record_rel(
+                "allreduce", "allreduce", t_allreduce0, t1, id=ar_id,
+                parent=ar_parent, **ar_args,
             )
 
         try:
+            if plan is not None:
+                bucket_bytes = [
+                    size * np.dtype(dtype).itemsize
+                    for size, dtype in zip(plan.sizes, plan.dtypes)
+                ]
+                ar_args["buckets"] = len(plan)
+                ar_args["bytes"] = sum(bucket_bytes)
             if plan is not None and self._stream_buckets:
                 # ---------------- streaming bucket pipeline ----------------
                 # One PG collective PER BUCKET instead of one for the whole
@@ -1907,6 +1968,15 @@ class Manager:
                 # per-bucket (start, end) wall-clock marks per stage, for
                 # pack_s/wire_s/unpack_s + overlap_efficiency in timings()
                 marks: List[Dict[str, Any]] = [{} for _ in range(n_buckets)]
+                # ids of the three stage spans per bucket (recorded from
+                # the marks once the op resolves), for their children, and
+                # what the PG stamped on each bucket's op: (enqueued,
+                # fn started, fn ended) -> allreduce/wire_run
+                stage_ids = [
+                    {st: tracer.new_id() for st in ("pack", "wire", "unpack")}
+                    for _ in range(n_buckets)
+                ]
+                wire_runs: List[Any] = [None] * n_buckets
                 bucket_futs: List[Future] = [Future() for _ in range(n_buckets)]
                 # aggregate: every bucket landed -> reassembled pytree.
                 # final_fut is fed from the join but owned here so the
@@ -1952,22 +2022,50 @@ class Manager:
                     # partially-applied reduction.
                     try:
                         t0u = time.perf_counter()
+                        up_id = stage_ids[i]["unpack"]
+                        # the first child carries how long the bucket sat
+                        # behind earlier buckets on the one unpack worker
+                        # (device plane: unpack runs in the wire's callback)
+                        queued = {"queued_us": int(
+                            (t0u - marks[i]["wire"][1]) * 1e6
+                        )} if "wire" in marks[i] else {}
                         if is_compressed_wire(flat):
                             # the bucket rode the wire compressed; the codes
                             # carry the reduced SUM, restored here at the
                             # plan's bucket dtype so divide/slice/land below
                             # run the exact uncompressed expressions
-                            flat = decompress_bucket(flat)
+                            with tracer.span(
+                                "decode", cat="allreduce", parent=up_id,
+                                bucket=i, bytes=_payload_nbytes(flat),
+                                **queued,
+                            ):
+                                flat = decompress_bucket(flat)
+                            queued = {}
                         if reduce_op == ReduceOp.AVG and num_participants > 0:
-                            flat = (flat / num_participants).astype(
-                                _np_dtype(flat)
-                            )
-                        pairs = [
-                            (idx, place_leaf(leaves[idx], val))
-                            for idx, val in bucketing.unpack_bucket(
-                                flat, plan, i
-                            )
-                        ]
+                            with tracer.span(
+                                "divide", cat="allreduce", parent=up_id,
+                                bucket=i, bytes=_payload_nbytes(flat),
+                                **queued,
+                            ):
+                                flat = (flat / num_participants).astype(
+                                    _np_dtype(flat)
+                                )
+                            queued = {}
+                        # h2d: slice the flat and place each leaf where the
+                        # original lives. On a device leaf that is
+                        # jax.device_put: as far as it returns before the
+                        # bytes have moved, this span is the enqueue
+                        with tracer.span(
+                            "h2d", cat="allreduce", parent=up_id, bucket=i,
+                            bytes=_payload_nbytes(flat),
+                            leaves=len(plan.metas[i]), **queued,
+                        ):
+                            pairs = [
+                                (idx, place_leaf(leaves[idx], val))
+                                for idx, val in bucketing.unpack_bucket(
+                                    flat, plan, i
+                                )
+                            ]
                         marks[i]["unpack"] = (t0u, time.perf_counter())
                         if pooled_buf is not None and not any(
                             isinstance(v, np.ndarray)
@@ -2032,13 +2130,18 @@ class Manager:
                     # preserving cross-replica arrival order (the SPMD
                     # contract of the host exchange).
                     if participating:
-                        capture, pooled = bucketing.pack(
-                            leaves, plan, pool=pool
-                        )
+                        with tracer.span(
+                            "capture", cat="allreduce", parent=ar_id,
+                            bytes=ar_args["bytes"],
+                        ):
+                            capture, pooled = bucketing.pack(
+                                leaves, plan, pool=pool
+                            )
                     else:
                         capture, pooled = None, []
                     pooled_ids = {id(b) for b in pooled}
                     stage_timeout = self._timeout
+                    t_submit = time.perf_counter()
 
                     # wire compression: TORCHFT_COMPRESS / compress= knob,
                     # plus should_quantize callers who land here (streaming
@@ -2085,13 +2188,35 @@ class Manager:
                             final_fut.add_done_callback(lambda _f: cancel())
                             for i in range(n_buckets):
                                 t0b = time.perf_counter()
+                                pk_id = stage_ids[i]["pack"]
                                 if capture is None:
                                     host_flat = np.zeros(
                                         (plan.sizes[i],), plan.dtypes[i]
                                     )
                                     pooled_buf = None
                                 else:
-                                    host_flat = np.asarray(capture[i])
+                                    if hasattr(capture[i], "block_until_ready"):
+                                        # the wait np.asarray below would
+                                        # make anyway (the backward pass
+                                        # and the device concatenation
+                                        # still running), under its own
+                                        # name: d2h is then the copy alone
+                                        with tracer.span(
+                                            "grad_wait", cat="allreduce",
+                                            parent=pk_id, bucket=i,
+                                        ):
+                                            capture[i].block_until_ready()
+                                    # bucket 0 carries how long the staging
+                                    # worker took to get to this op
+                                    with tracer.span(
+                                        "d2h", cat="allreduce",
+                                        parent=pk_id, bucket=i,
+                                        **({"queued_us": int(
+                                            (t0b - t_submit) * 1e6
+                                        )} if i == 0 else {}),
+                                    ) as sp:
+                                        host_flat = np.asarray(capture[i])
+                                        sp.args["bytes"] = host_flat.nbytes
                                     pooled_buf = (
                                         capture[i]
                                         if id(capture[i]) in pooled_ids
@@ -2102,16 +2227,28 @@ class Manager:
                                     # quantize inside the pack stage so
                                     # pack_s absorbs the codec cost and
                                     # overlap accounting stays honest
-                                    payload = self._compress_bucket_ef(
-                                        host_flat,
-                                        bucket_modes[i],
-                                        plan.dtypes[i],
-                                        ef_store,
-                                        i,
+                                    with tracer.span(
+                                        "codec", cat="allreduce",
+                                        parent=pk_id, bucket=i,
+                                        bytes=host_flat.nbytes,
+                                    ) as sp:
+                                        payload = self._compress_bucket_ef(
+                                            host_flat,
+                                            bucket_modes[i],
+                                            plan.dtypes[i],
+                                            ef_store,
+                                            i,
+                                        )
+                                        sp.args["bytes_out"] = (
+                                            _payload_nbytes(payload)
+                                        )
+                                with tracer.span(
+                                    "dispatch", cat="allreduce",
+                                    parent=pk_id, bucket=i,
+                                ):
+                                    w = self._pg.allreduce(
+                                        [payload], pg_reduce_op
                                     )
-                                w = self._pg.allreduce(
-                                    [payload], pg_reduce_op
-                                )
                                 t1b = time.perf_counter()
                                 marks[i]["pack"] = (t0b, t1b)
 
@@ -2129,6 +2266,9 @@ class Manager:
                                         t0w,
                                         time.perf_counter(),
                                     )
+                                    # ProcessGroupHost leaves these on
+                                    # its op's future; another PG's has none
+                                    wire_runs[i] = getattr(f, "stamps", None)
                                     try:
                                         flat = f.value()[0]
                                     except Exception as e:  # noqa: BLE001
@@ -2191,7 +2331,9 @@ class Manager:
 
                 def _finalize_pipeline(_f: Future) -> None:
                     try:
-                        self._record_pipeline_timings(marks)
+                        self._record_pipeline_timings(
+                            marks, ar_id, stage_ids, wire_runs, bucket_bytes
+                        )
                     except Exception:  # noqa: BLE001
                         self._logger.exception(
                             "failed to record pipeline timings"
@@ -2260,9 +2402,13 @@ class Manager:
                         # concatenate into a fresh (donation-safe) buffer,
                         # host groups copy into a pool-recycled one — no
                         # second per-leaf copy
-                        capture, pooled = bucketing.pack(
-                            leaves, plan, pool=self._buffer_pool
-                        )
+                        with tracer.span(
+                            "capture", cat="allreduce", parent=ar_id,
+                            bytes=ar_args["bytes"],
+                        ):
+                            capture, pooled = bucketing.pack(
+                                leaves, plan, pool=self._buffer_pool
+                            )
                     else:
                         capture = [
                             jnp.copy(l) if isinstance(l, jax.Array)
@@ -2341,8 +2487,17 @@ class Manager:
                                 np.zeros(s, d) for s, d in zero_specs
                             ]
                         else:
-                            host_leaves = [np.asarray(l) for l in capture]
-                        w = self._pg.allreduce(host_leaves, pg_reduce_op)
+                            with tracer.span(
+                                "d2h", cat="allreduce", parent=ar_id
+                            ) as sp:
+                                host_leaves = [np.asarray(l) for l in capture]
+                                sp.args["bytes"] = sum(
+                                    h.nbytes for h in host_leaves
+                                )
+                        with tracer.span(
+                            "dispatch", cat="allreduce", parent=ar_id
+                        ):
+                            w = self._pg.allreduce(host_leaves, pg_reduce_op)
 
                         def _xfer(f: Future) -> None:
                             try:
@@ -2459,6 +2614,14 @@ class Manager:
     def tracer(self) -> SpanRecorder:
         """This replica's span recorder (see :mod:`torchft_tpu.tracing`)."""
         return self._tracer
+
+    def record_phase(self, cat: str, name: str, t0_us: int, t1_us: int) -> None:
+        """A one-off phase the CALLER timed (epoch microseconds), e.g. the
+        trainer's start-up before this Manager existed: a ring span
+        ``cat/name`` and ``timings()["<cat>_<name>_s"]``, so it reaches
+        both a span dump and whatever prints the timings."""
+        self._tracer.record(name, cat, t0_us, t1_us)
+        self._record_timing(f"{cat}_{name}_s", (t1_us - t0_us) / 1e6)
 
     def dump_trace(self, path: "str | Path | None" = None) -> Optional[Path]:
         """Write the span ring as a merge-ready JSON dump and return its
@@ -2691,7 +2854,14 @@ class Manager:
             )
         return wire
 
-    def _record_pipeline_timings(self, marks: List[Dict[str, Any]]) -> None:
+    def _record_pipeline_timings(
+        self,
+        marks: List[Dict[str, Any]],
+        parent: int,
+        stage_ids: List[Dict[str, int]],
+        wire_runs: List[Any],
+        bucket_bytes: List[int],
+    ) -> None:
         """Fold one streamed allreduce's per-bucket stage marks into
         timings(): summed ``allreduce_pack_s`` / ``allreduce_wire_s`` /
         ``allreduce_unpack_s``, the bucket count, and
@@ -2710,7 +2880,22 @@ class Manager:
                     continue
                 t0_pc, t1_pc = span
                 self._tracer.record_rel(
-                    stage, cat="allreduce", t0_pc=t0_pc, t1_pc=t1_pc, bucket=i
+                    stage, cat="allreduce", t0_pc=t0_pc, t1_pc=t1_pc,
+                    id=stage_ids[i][stage], parent=parent, bucket=i,
+                )
+            run = wire_runs[i]
+            if run is not None:
+                # what the PG's dispatch thread did for this bucket, from
+                # the stamps it left on the op's future: fn(comm) alone
+                # (a copy at a world of one, the ring otherwise); the time
+                # the op sat in its queue behind earlier buckets is an arg
+                t_enq, t_run0, t_run1 = run
+                self._tracer.record_rel(
+                    "wire_run", "allreduce", t_run0, t_run1,
+                    parent=stage_ids[i]["wire"], bucket=i,
+                    bytes=bucket_bytes[i],
+                    world=self._pg.size(),
+                    queued_us=int((t_run0 - t_enq) * 1e6),
                 )
         self._log_timing_snapshot(ALLREDUCE_PIPELINE_PHASE)
 
@@ -3198,11 +3383,14 @@ class Manager:
         return timed.then(callback)
 
     # ------------------------------------------------------------- commit
-    @traced("torchft::manager::should_commit")
     def should_commit(self, timeout: "float | timedelta | None" = None) -> bool:
         """Two-phase commit vote across the replica group; True iff every
         rank of this group is healthy and enough replicas participate
         (reference: manager.py:848-936)."""
+        with self._tracer.span("should_commit", cat="commit"):
+            return self._should_commit(timeout)
+
+    def _should_commit(self, timeout: "float | timedelta | None") -> bool:
         t_begin = time.perf_counter()
         # recovery (on the quorum thread) must finish before we decide
         if self._quorum_future is not None:
@@ -3304,6 +3492,14 @@ class Manager:
             self._batches_committed += self.num_participants()
             self._commit_failures = 0
             self._bump_metric("commits")
+            if not self._first_commit_seen:
+                # what this process compiled, or loaded from the persistent
+                # cache, on the way to its first committed step (a
+                # rejoiner: its heal step); the spans are compile/*
+                self._first_commit_seen = True
+                self._record_timing(
+                    "first_step_compile_s", self._tracer.compile_total_s()
+                )
         else:
             self._commit_failures += 1
             self._bump_metric("commit_failures")
@@ -3535,6 +3731,14 @@ def _covered_seconds(
     if cur_s is not None:
         total += cur_e - cur_s
     return total
+
+
+def _payload_nbytes(payload: Any) -> int:
+    """Bytes of one bucket as it is handled: an ndarray, or a compressed
+    wire (codes + scales)."""
+    if is_compressed_wire(payload):
+        return int(payload.payload.nbytes + payload.scales.nbytes)
+    return int(getattr(payload, "nbytes", 0))
 
 
 def _pipeline_overlap_stats(marks: List[Dict[str, Any]]) -> Dict[str, float]:

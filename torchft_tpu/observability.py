@@ -29,9 +29,9 @@ additionally exported over OTLP with resource attributes taken from
 ``TORCHFT_OTEL_RESOURCE_ATTRIBUTES_JSON``. The OTLP path is optional and
 degrades silently to console-only, matching the reference's opt-in design.
 
-``trace_span(name)`` provides the ``torch.profiler.record_function`` analog:
-a ``jax.profiler.TraceAnnotation`` visible in XLA/perfetto traces, falling
-back to a no-op when profiling is unavailable.
+Profiler spans (the ``torch.profiler.record_function`` analog) are not
+here: ``tracing.SpanRecorder.span`` enters a ``jax.profiler.TraceAnnotation``
+beside every ring span.
 """
 
 from __future__ import annotations
@@ -42,8 +42,7 @@ import os
 import queue
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 USE_OTEL_ENV = "TORCHFT_USE_OTEL"
 OTEL_RESOURCE_ATTRS_ENV = "TORCHFT_OTEL_RESOURCE_ATTRIBUTES_JSON"
@@ -291,35 +290,6 @@ def emit_event_async(stream: str, **fields: Any) -> bool:
     immediately. Use the synchronous ``log_*`` helpers for rare events
     whose loss at a crash would matter (errors)."""
     return get_event_drain().submit(stream, fields)
-
-
-def traced(name: str):
-    """Decorator form of ``trace_span`` for whole-method spans."""
-    import functools
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args: Any, **kwargs: Any) -> Any:
-            with trace_span(name):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco
-
-
-@contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Named span on the device timeline (``jax.profiler.TraceAnnotation``);
-    no-op if jax/profiling is unavailable. Use exactly like the reference's
-    ``torch.profiler.record_function``."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # noqa: BLE001
-        yield
-        return
-    with TraceAnnotation(name):
-        yield
 
 
 # ---------------------------------------------------------------- /metrics

@@ -1445,11 +1445,16 @@ class ProcessGroupHost(ProcessGroup):
             item = gen.queue.get()
             if item is None:
                 return
-            fn, fut = item
+            fn, fut, t_enqueued = item
+            t_run0 = time.perf_counter()
             try:
                 # the watchdog aborts THIS generation's mesh only
                 with context_timeout(gen.abort, self._timeout):
                     result = fn(gen.comm)
+                # (enqueued, fn started, fn ended) on the op's future, set
+                # before it resolves: what the Manager records as
+                # allreduce/wire_run and its queued_us (no recorder here)
+                fut.stamps = (t_enqueued, t_run0, time.perf_counter())
             except BaseException as e:  # noqa: BLE001
                 gen.error = e if isinstance(e, Exception) else RuntimeError(str(e))
                 try:
@@ -1478,7 +1483,7 @@ class ProcessGroupHost(ProcessGroup):
                 raise gen.error
             gen.claim_mode(mode)
             fut: Future[Any] = Future()
-            gen.queue.put((fn, fut))
+            gen.queue.put((fn, fut, time.perf_counter()))
             return FutureWork(fut)
 
     # -- collectives ------------------------------------------------------

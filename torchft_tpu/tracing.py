@@ -10,9 +10,16 @@ stalled bucket 7 of step 412" across a fleet. This module closes that gap:
   prepare / commit, per-bucket pack / wire / unpack, heal chunks, RPC
   retries, reroutes). Every span carries ``(quorum_id, step)`` and the
   recorder's ``replica_id``, so spans from different replicas of the same
-  step correlate without a global clock. Recording is an O(1) dict append
-  behind one lock — cheap enough to stay on by default (the
-  ``bench.py --tracing`` gate holds the <1% line).
+  step correlate without a global clock, and ``id`` / ``parent`` so one
+  step's spans form a tree (a thread-local stack gives the parent on one
+  thread; across threads the parent's id travels in the closure).
+  Recording is an O(1) dict append behind one lock — cheap enough to stay
+  on by default (PERF.md section 6, PR 24: ``TORCHFT_TRACE=0`` against
+  the default on the chip). A context span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``manager.<cat>.<name>``, so the
+  program's spans lie in a ``jax.profiler.trace`` on the device trace's
+  own clock; free while no profiler session runs, and this module never
+  imports jax itself (a process without jax records to the ring alone).
 - **Skew correction** — each export stamps the replica's clock-skew
   estimate vs the lighthouse (``ManagerServer.clock_skew()``: the beat
   loop's RPC round-trip midpoint minus the response ``server_ms`` —
@@ -43,10 +50,13 @@ Env knobs (read once per Manager via :meth:`TraceConfig.from_env`):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from pathlib import Path
@@ -67,6 +77,7 @@ __all__ = [
     "parse_history",
     "set_clock_offset_ms",
     "clear_clock_offsets",
+    "process_start_us",
 ]
 
 
@@ -145,27 +156,143 @@ def step_sampled(step: int, sample: float) -> bool:
 
 
 # ----------------------------------------------------------------- recorder
+_trace_annotation: Any = None  # jax.profiler.TraceAnnotation once jax is loaded
+_CURRENT = object()  # _append's default step: the recorder's context now
+
+
+def _annotation(cat: str, name: str) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation`` ``manager.<cat>.<name>``
+    (a TraceMe: a flag test while no profiler session runs), or None in a
+    process that has not loaded jax — this module is never the one to
+    import it."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 — a broken jax must not break spans
+            _trace_annotation = False
+            return None
+        _trace_annotation = TraceAnnotation
+    if _trace_annotation is False:
+        return None
+    ann = _trace_annotation(f"manager.{cat}.{name}")
+    ann.__enter__()
+    return ann
+
+
+def process_start_us() -> Optional[int]:
+    """Epoch microseconds at which the kernel created this process, from
+    ``/proc/self/stat`` (start time in clock ticks since boot) against the
+    boot-time clock; None where ``/proc`` does not say. Resolution is one
+    clock tick (10 ms)."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command name may hold spaces: fields count from the ")"
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_s = time.clock_gettime(time.CLOCK_BOOTTIME) - (
+            start_ticks / os.sysconf("SC_CLK_TCK")
+        )
+        return time.time_ns() // 1000 - int(age_s * 1e6)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+class _NullSpan:
+    """What a disabled recorder hands out: nothing recorded, nothing
+    annotated, ``args`` writable so call sites never branch."""
+
+    __slots__ = ("args",)
+    id = None
+
+    def __init__(self) -> None:
+        self.args: dict = {}
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
 class _SpanHandle:
-    """Context manager for an in-progress span; records on exit."""
+    """Context manager for an in-progress span; records on exit. ``id`` is
+    known from construction (hand it to another thread as ``parent=``);
+    ``args`` may be filled in until exit (``bytes`` known only after the
+    copy)."""
 
-    __slots__ = ("_rec", "name", "cat", "args", "_t0_us", "_t0_pc")
+    __slots__ = ("_rec", "name", "cat", "args", "id", "parent",
+                 "_t0_us", "_t0_pc", "_ann", "_step")
 
-    def __init__(self, rec: "SpanRecorder", name: str, cat: str, args: dict):
+    def __init__(self, rec: "SpanRecorder", name: str, cat: str, args: dict,
+                 parent: Optional[int]):
         self._rec = rec
         self.name = name
         self.cat = cat
         self.args = args
+        self.id = rec.new_id()
+        self.parent = parent
 
     def __enter__(self) -> "_SpanHandle":
+        stack = self._rec._stack()
+        if self.parent is None and stack:
+            self.parent = stack[-1]
+        stack.append(self.id)
+        # a span belongs to the step it STARTED in: trainer/step opens
+        # before the commit advances the Manager's step and closes after
+        self._step = self._rec._step
+        self._ann = _annotation(self.cat, self.name)
         self._t0_us = self._rec._now_us()
         self._t0_pc = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         dur_us = int((time.perf_counter() - self._t0_pc) * 1e6)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec._stack().pop()
         self._rec._append(
-            self.name, self.cat, self._t0_us, max(dur_us, 1), self.args
+            self.name, self.cat, self._t0_us, max(dur_us, 1), self.args,
+            self.id, self.parent, self._step,
         )
+
+
+# jax.monitoring duration events recorded as ``compile/<name>`` spans
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_compile_watchers: "weakref.WeakSet[SpanRecorder]" = weakref.WeakSet()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _watch_compiles(rec: "SpanRecorder") -> None:
+    """Feed ``rec`` the process's backend compiles and compilation-cache
+    retrievals. ONE ``jax.monitoring`` listener per process, installed with
+    the first enabled recorder that finds jax loaded (so never in a process
+    without a Manager, and never the reason jax is imported); every live
+    enabled recorder hears it."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        _compile_watchers.add(rec)
+        if _compile_listener_on or "jax" not in sys.modules:
+            return
+        try:
+            from jax import monitoring
+        except Exception:  # noqa: BLE001
+            return
+
+        def _on_duration(event: str, duration_secs: float, **kw: Any) -> None:
+            name = _COMPILE_EVENTS.get(event)
+            if name is None:
+                return
+            for r in list(_compile_watchers):
+                r._on_compile(name, duration_secs, kw.get("fun_name"))
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listener_on = True
 
 
 class SpanRecorder:
@@ -195,6 +322,13 @@ class SpanRecorder:
         self._skew_samples = 0
         self._dropped = 0
         self._recorded = 0
+        self._ids = itertools.count(1)
+        self._tls = threading.local()  # per-thread stack of open span ids
+        # seconds of backend compiles this process has made since the
+        # recorder was built (compile_total_s())
+        self._compile_s = 0.0
+        if self._config.enabled:
+            _watch_compiles(self)
 
     @property
     def enabled(self) -> bool:
@@ -236,8 +370,29 @@ class SpanRecorder:
         off = _offset_ms_for(self._replica_id)
         return time.time_ns() // 1000 + int(off * 1000)
 
+    def new_id(self) -> int:
+        """A span id of this recorder, for a span that will be recorded
+        after the fact (``record`` / ``record_rel`` with ``id=``) and whose
+        children need it as ``parent=`` before then."""
+        return next(self._ids)
+
+    def current(self) -> Optional[int]:
+        """Id of the innermost span open on THIS thread, or None: the
+        parent of whatever this thread is about to cause on another."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def _stack(self) -> List[int]:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
+
     def _append(
-        self, name: str, cat: str, ts_us: int, dur_us: int, args: dict
+        self, name: str, cat: str, ts_us: int, dur_us: int, args: dict,
+        span_id: Optional[int] = None, parent: Optional[int] = None,
+        step: Any = _CURRENT,
     ) -> None:
         if not self._config.enabled:
             return
@@ -253,15 +408,29 @@ class SpanRecorder:
                 "ts_us": ts_us,
                 "dur_us": dur_us,
                 "quorum_id": self._quorum_id,
-                "step": self._step,
+                "step": self._step if step is _CURRENT else step,
+                "id": span_id if span_id is not None else next(self._ids),
+                "parent": parent,
             }
             if args:
                 span["args"] = args
             self._spans.append(span)
 
-    def span(self, name: str, cat: str = "step", **args: Any) -> _SpanHandle:
-        """``with tracer.span("quorum", cat="quorum"): ...``"""
-        return _SpanHandle(self, name, cat, args)
+    def span(
+        self,
+        name: str,
+        cat: str = "step",
+        parent: Optional[int] = None,
+        **args: Any,
+    ) -> "_SpanHandle | _NullSpan":
+        """``with tracer.span("quorum_rpc", cat="quorum"): ...`` — a ring
+        span and, where jax is loaded, a profiler annotation
+        ``manager.<cat>.<name>`` around the same statements. ``parent``:
+        the id of the span that caused this one when that span is open on
+        ANOTHER thread (same thread: the innermost open span, by itself)."""
+        if not self._config.enabled:
+            return _NullSpan()
+        return _SpanHandle(self, name, cat, args, parent)
 
     def record(
         self,
@@ -269,10 +438,15 @@ class SpanRecorder:
         cat: str,
         t0_us: int,
         t1_us: int,
+        id: Optional[int] = None,  # noqa: A002 — the span's key in the dump
+        parent: Optional[int] = None,
         **args: Any,
     ) -> None:
-        """Record a completed interval given absolute epoch-us endpoints."""
-        self._append(name, cat, int(t0_us), max(int(t1_us - t0_us), 1), args)
+        """Record a completed interval given absolute epoch-us endpoints
+        (ring only: the profiler takes no span after the fact)."""
+        self._append(
+            name, cat, int(t0_us), max(int(t1_us - t0_us), 1), args, id, parent
+        )
 
     def record_rel(
         self,
@@ -280,6 +454,8 @@ class SpanRecorder:
         cat: str,
         t0_pc: float,
         t1_pc: float,
+        id: Optional[int] = None,  # noqa: A002
+        parent: Optional[int] = None,
         **args: Any,
     ) -> None:
         """Record a completed interval given ``time.perf_counter()``
@@ -290,11 +466,36 @@ class SpanRecorder:
         anchor_pc = time.perf_counter()
         t0_us = anchor_us + int((t0_pc - anchor_pc) * 1e6)
         t1_us = anchor_us + int((t1_pc - anchor_pc) * 1e6)
-        self._append(name, cat, t0_us, max(t1_us - t0_us, 1), args)
+        self._append(
+            name, cat, t0_us, max(t1_us - t0_us, 1), args, id, parent
+        )
 
     def instant(self, name: str, cat: str, **args: Any) -> None:
         """Zero-duration marker (RPC retry, reroute, heal chunk events)."""
         self._append(name, cat, self._now_us(), 1, args)
+
+    def _on_compile(self, name: str, dur_s: float, fun: Any) -> None:
+        """One backend compile or cache retrieval the process just ended
+        (the ``jax.monitoring`` listener's thread: whichever dispatched the
+        jitted call)."""
+        if name == "backend_compile":
+            with self._lock:
+                self._compile_s += dur_s
+        t1_us = self._now_us()
+        args = {"fun": str(fun)} if fun is not None else {}
+        # the listener runs on the thread that dispatched the jitted call:
+        # the span open there (trainer/grad_dispatch, ...) is the cause
+        self.record(
+            name, "compile", t1_us - int(dur_s * 1e6), t1_us,
+            parent=self.current(), **args,
+        )
+
+    def compile_total_s(self) -> float:
+        """Seconds this process spent in backend compiles since this
+        recorder was built. Each includes its persistent-cache lookup: the
+        ``compile/cache_retrieval`` spans say how much of it was loading."""
+        with self._lock:
+            return self._compile_s
 
     # ------------------------------------------------------------- exports
     def stats(self) -> Dict[str, float]:

@@ -143,6 +143,189 @@ class TestStreamedSerialEquality:
             )
 
 
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _landing_tree(dtype, kind):
+    """Six (8, 8) leaves of one dtype: numpy, jax, or both in turn. A jax
+    leaf is sharded over the 8 virtual devices (two ways) or alone on device
+    3, by the bucket it will fall into (a device bucket is one concatenate,
+    so its leaves share a device set)."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("a", "b"))
+    places = [
+        NamedSharding(mesh, P("b", None)),
+        NamedSharding(mesh, P("a", "b")),
+        jax.devices()[3],
+    ]
+    rng = np.random.RandomState(11)
+    tree = {}
+    for i in range(6):
+        host = (rng.randn(8, 8) * 3).astype(dtype)
+        on_device = kind == "jax" or (kind == "mixed" and i % 2 == 0)
+        tree[f"p{i}"] = (
+            jax.device_put(host, places[i // 2]) if on_device else host
+        )
+    return tree
+
+
+_DTYPES = {"bf16": "bfloat16", "f16": "float16", "f32": "float32"}
+
+
+class TestAverageWhereItLands:
+    """The AVG normalisation runs after the reduced slices have landed: a
+    device leaf is divided on its device by one jitted, input-donating
+    computation, a numpy leaf in numpy. The result is numpy's
+    ``(sum / n).astype(dtype)`` bit for bit either way (CPU backend)."""
+
+    @pytest.mark.parametrize("kind", ["jax", "numpy", "mixed"])
+    @pytest.mark.parametrize("n", [1, 2, 3, 4])
+    @pytest.mark.parametrize("dtype", list(_DTYPES))
+    def test_streamed_equals_numpy_and_serial(self, dtype, n, kind):
+        import jax
+        import ml_dtypes  # noqa: F401 — registers bfloat16 with numpy
+
+        np_dtype = np.dtype(_DTYPES[dtype])
+        tree = _landing_tree(np_dtype, kind)
+        cap = 2 * 64 * np_dtype.itemsize  # 2 leaves per bucket -> 3 buckets
+        quorum = dict(replica_world_size=n, max_world_size=n)
+        outs = {}
+        for name, kw in {
+            "streamed": dict(bucket_cap_bytes=cap, stream_buckets=True),
+            "serial": dict(bucket_cap_bytes=cap, stream_buckets=False),
+            "per_leaf": dict(bucket_cap_bytes=0, stream_buckets=False),
+        }.items():
+            m = make_manager(quorum=make_quorum(**quorum), min_replica_size=1,
+                             **kw)
+            outs[name] = _reduce(m, tree, streamed=kw["stream_buckets"])
+            m.shutdown(wait=False)  # 108 idle managers would starve the
+            # timing-sensitive tests that share this worker
+        for k, orig in tree.items():
+            # the dummy PG's SUM is the input itself
+            want = (np.asarray(orig) / n).astype(np_dtype)
+            for name, out in outs.items():
+                got = out[k]
+                assert type(got) is type(orig), (name, k)
+                assert got.dtype == np_dtype and got.shape == orig.shape
+                if isinstance(orig, jax.Array):
+                    assert got.sharding == orig.sharding, (name, k)
+                assert np.array_equal(_bits(got), _bits(want)), (name, k, n)
+
+    def test_pure_numpy_tree_never_initialises_the_backend(self):
+        """A process that only moves host arrays must not take the chip:
+        streamed and serial AVG over numpy leaves, in a fresh interpreter."""
+        import os
+        import subprocess
+        import sys
+
+        code = (
+            "import numpy as np, ml_dtypes\n"
+            "from jax._src import xla_bridge\n"
+            "from test_manager import make_manager, make_quorum\n"
+            "for stream in (True, False):\n"
+            "    m = make_manager(quorum=make_quorum(), bucket_cap_bytes=64,\n"
+            "                     stream_buckets=stream)\n"
+            "    m.start_quorum()\n"
+            "    tree = {f'p{i}': np.arange(16.).astype(ml_dtypes.bfloat16)\n"
+            "            for i in range(4)}\n"
+            "    out = m.allreduce_streamed(tree).wait(timeout=30)\n"
+            "    assert all(type(v) is np.ndarray for v in out.values())\n"
+            "    assert np.array_equal(out['p1'], tree['p1'] / 2)\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('HOST-ONLY-OK')\n"
+        )
+        here = os.path.dirname(os.path.abspath(__file__))
+        proc = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, cwd=here,
+            env=dict(os.environ, JAX_PLATFORMS="cpu",
+                     PYTHONPATH=os.pathsep.join([os.path.dirname(here), here])),
+        )
+        assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+        assert "HOST-ONLY-OK" in proc.stdout
+
+    def test_quorum_4_3_4_compiles_once_and_donates(self, monkeypatch):
+        """The divisor is a runtime scalar: after the first step a change of
+        the participant count compiles nothing. Every landed buffer is
+        donated to its quotient, and XLA could use every donation."""
+        import warnings
+
+        import jax
+
+        from torchft_tpu.manager import _average_on_device
+
+        tree = _landing_tree(np.dtype("bfloat16"), "jax")
+        landed = []
+        device_put = jax.device_put
+
+        def recording_put(x, *a, **kw):
+            out = device_put(x, *a, **kw)
+            if isinstance(x, np.ndarray):  # place_leaf's: a reduced slice
+                landed.append(out)
+            return out
+
+        m = make_manager(quorum=make_quorum(max_world_size=4),
+                         bucket_cap_bytes=2 * 64 * 2)
+        monkeypatch.setattr(jax, "device_put", recording_put)
+        sizes, compiles = [], []
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            for qid, n in enumerate([4, 3, 4], start=1):
+                m._test_client._quorum.return_value = make_quorum(
+                    quorum_id=qid, replica_world_size=n, max_world_size=n
+                )
+                out = _reduce(m, tree, streamed=True)
+                jax.block_until_ready(out)
+                assert m.num_participants() == n
+                for k, orig in tree.items():
+                    want = (np.asarray(orig) / n).astype(orig.dtype)
+                    assert np.array_equal(_bits(out[k]), _bits(want)), (k, n)
+                sizes.append(_average_on_device()._cache_size())
+                compiles.append(sum(
+                    s["cat"] == "compile" for s in m.tracer.export()["spans"]
+                ))
+                m.should_commit()
+        # one executable per leaf geometry (three placements of one shape),
+        # all of them built in the first step
+        m.shutdown(wait=False)
+        assert sizes[0] >= 1 and sizes == [sizes[0]] * 3, sizes
+        assert compiles == [compiles[0]] * 3, compiles
+        assert len(landed) == 3 * len(tree)
+        assert all(x.is_deleted() for x in landed)
+        assert not [w for w in caught if "onat" in str(w.message)], [
+            str(w.message) for w in caught
+        ]
+
+    @pytest.mark.parametrize("kind,where", [
+        ("jax", "device"), ("numpy", "host"), ("mixed", "mixed"),
+    ])
+    def test_divide_span_says_where(self, kind, where):
+        """allreduce/divide: one per bucket, after h2d, with where / leaves /
+        bytes; the unpack stage keeps two children a bucket."""
+        m = make_manager(quorum=make_quorum(), bucket_cap_bytes=2 * 64 * 4)
+        _reduce(m, _landing_tree(np.dtype("float32"), kind), streamed=True)
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline:  # stage spans land at resolve
+            spans = m.tracer.export()["spans"]
+            if sum(s["name"] == "unpack" for s in spans) == 3:
+                break
+            time.sleep(0.02)
+        m.shutdown(wait=False)
+        for up in (s for s in spans if s["name"] == "unpack"):
+            kids = sorted((s for s in spans if s["parent"] == up["id"]),
+                          key=lambda s: s["ts_us"])
+            assert [s["name"] for s in kids] == ["h2d", "divide"]
+            h2d, divide = (s["args"] for s in kids)
+            assert divide["where"] == where
+            assert divide["leaves"] == h2d["leaves"] == 2
+            assert divide["bytes"] == h2d["bytes"] == 2 * 64 * 4
+            assert "queued_us" in h2d and "queued_us" not in divide
+
+
 class TestPerBucketCollectives:
     def test_streamed_issues_one_collective_per_bucket(self):
         tree = _tree()

@@ -532,7 +532,8 @@ class TestManagerCompressedStreaming:
 class TestPipelineSpanTree:
     """One streamed allreduce per step through a real ProcessGroupHost at a
     world of one. Per bucket: pack > (d2h, [codec], dispatch), wire >
-    wire_run, unpack > ([decode], divide, h2d); the three old spans keep
+    wire_run, unpack > ([decode], h2d, divide: the average is taken where
+    the leaf has landed); the three old spans keep
     their names, category and ``(quorum_id, step)``."""
 
     STEPS = 3
@@ -569,7 +570,7 @@ class TestPipelineSpanTree:
         want = {
             "pack": ["d2h"] + (["codec"] if compress else []) + ["dispatch"],
             "wire": ["wire_run"],
-            "unpack": (["decode"] if compress else []) + ["divide", "h2d"],
+            "unpack": (["decode"] if compress else []) + ["h2d", "divide"],
         }
         names = {s["name"] for s in spans if s["cat"] == "allreduce"}
         assert ("codec" in names) == ("decode" in names) == bool(compress)
@@ -603,9 +604,11 @@ class TestPipelineSpanTree:
             if s["name"] == "d2h":
                 assert s["args"]["bytes"] == 2 * 30_000 * 4
                 assert ("queued_us" in s["args"]) == (s["args"]["bucket"] == 0)
-            if s["name"] in ("decode", "divide"):
-                first = "decode" if compress else "divide"
+            if s["name"] in ("decode", "h2d", "divide"):
+                first = "decode" if compress else "h2d"
                 assert ("queued_us" in s["args"]) == (s["name"] == first)
+            if s["name"] == "divide":   # numpy leaves: averaged in numpy
+                assert (a := s["args"])["where"] == "host" and a["leaves"] == 2
             if s["name"] == "codec":
                 assert s["args"]["bytes_out"] < s["args"]["bytes"]
 

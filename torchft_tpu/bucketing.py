@@ -274,14 +274,10 @@ def pack(
 def unpack(flats: Sequence[Any], plan: BucketPlan) -> List[Any]:
     """Slice the reduced flat buckets back into per-leaf arrays (views for
     numpy flats, lazy device slices for jax flats), in leaf order."""
-    import jax
-
     out: List[Optional[Any]] = [None] * plan.num_leaves
-    for flat, metas in zip(flats, plan.metas):
-        if not isinstance(flat, jax.Array):
-            flat = np.asarray(flat)
-        for (i, off, size, shape) in metas:
-            out[i] = flat[off : off + size].reshape(shape)
+    for bucket, flat in enumerate(flats):
+        for i, leaf in unpack_bucket(flat, plan, bucket):
+            out[i] = leaf
     assert all(o is not None for o in out)
     return out  # type: ignore[return-value]
 

@@ -22,6 +22,8 @@ JAX-flavored deviations from the reference, by design:
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import os
 import socket as _socket
@@ -1918,17 +1920,61 @@ class Manager:
                 raise ValueError("AVG allreduce requires floating point arrays")
             pg_reduce_op = ReduceOp.SUM
 
+        averaging = reduce_op == ReduceOp.AVG and num_participants > 0
+
+        def land_reduced(
+            flat: Any,
+            bucket: int,
+            span: Callable[..., Any] = lambda name, **args: (
+                contextlib.nullcontext()
+            ),
+        ) -> List[Any]:
+            # One reduced array — the plan's bucket ``bucket``, or with no
+            # plan the lone leaf of that index — to ``(leaf_index, leaf)``
+            # pairs: sliced (views), each slice placed where its original
+            # lives, and under AVG divided by the participants where it then
+            # is: a device leaf on its device (dispatched from here, nothing
+            # waits for it), a numpy leaf in numpy, so a pure-host tree still
+            # never initialises a backend. The serial path and every
+            # streamed bucket land through this one function, so they stay
+            # bit-identical on every backend. ``span(name, **args)``: the
+            # streamed path's allreduce/h2d and allreduce/divide; the serial
+            # path records none.
+            idxs = [bucket] if plan is None else plan.groups[bucket]
+            sized = {"bytes": _payload_nbytes(flat), "leaves": len(idxs)}
+            on_device_plane = isinstance(flat, jax.Array)
+            if averaging and on_device_plane:
+                # a device-native PG's result may be a buffer the PG (or the
+                # caller) still holds: divided where it is, into a fresh one
+                with span("divide", where="device", **sized):
+                    flat = _average(flat, num_participants)
+            pairs = (
+                [(bucket, flat)] if plan is None
+                else bucketing.unpack_bucket(flat, plan, bucket)
+            )
+            # on a device leaf place_leaf is jax.device_put: as far as it
+            # returns before the bytes have moved, h2d is the enqueue
+            with span("h2d", **sized):
+                pairs = [(i, place_leaf(leaves[i], v)) for i, v in pairs]
+            if averaging and not on_device_plane:
+                on_device = sum(isinstance(leaves[i], jax.Array) for i in idxs)
+                where = (
+                    "device" if on_device == len(idxs)
+                    else "mixed" if on_device else "host"
+                )
+                with span("divide", where=where, **sized):
+                    pairs = [
+                        (i, _average(v, num_participants, landed=True))
+                        for i, v in pairs
+                    ]
+            return pairs
+
         def normalize(f: Future) -> Any:
-            reduced = f.value()
-            if reduce_op == ReduceOp.AVG and num_participants > 0:
-                reduced = [
-                    (r / num_participants).astype(_np_dtype(r)) for r in reduced
-                ]
-            if plan is not None:
-                # slice the reduced flats back into per-leaf arrays; rebuild
-                # then restores each ORIGINAL leaf's device placement
-                reduced = bucketing.unpack(reduced, plan)
-            return rebuild(reduced)
+            out: List[Any] = [None] * len(leaves)
+            for b, flat in enumerate(f.value()):
+                for i, v in land_reduced(flat, b):
+                    out[i] = v
+            return jax.tree_util.tree_unflatten(treedef, out)
 
         def _time_allreduce(_f: Future) -> None:
             # submission → resolve wall clock of the most recent
@@ -1953,15 +1999,15 @@ class Manager:
                 # ---------------- streaming bucket pipeline ----------------
                 # One PG collective PER BUCKET instead of one for the whole
                 # plan, three stages per bucket: pack (D2H / device concat),
-                # wire (the PG's dispatch thread or XLA), unpack (divide +
-                # slice + land on device, on the dedicated unpack worker).
+                # wire (the PG's dispatch thread or XLA), unpack (slice + land
+                # on device + divide there, on the dedicated unpack worker).
                 # Bucket i+1 packs while bucket i rides the wire and bucket
                 # i−1 unpacks — no stage ever waits for the LAST bucket's
                 # wire, which is exactly what the monolithic path did.
                 # Numerics are bit-identical to the serial path: per-bucket
                 # collectives reduce each flat independently just like one
-                # call carrying the list, and divide/slice/land use the same
-                # expressions (normalize / unpack / place_leaf).
+                # call carrying the list, and slice/land/divide are the same
+                # function (land_reduced) on both paths.
                 import jax.numpy as jnp
 
                 n_buckets = len(plan)
@@ -2014,58 +2060,39 @@ class Manager:
                 pool = self._buffer_pool
 
                 def _land_bucket(i: int, flat: Any, pooled_buf: Any) -> None:
-                    # stage 3, off the PG dispatch thread: AVG divide +
-                    # slice + device landing for ONE bucket. A failure here
+                    # stage 3, off the PG dispatch thread: slice + landing +
+                    # AVG divide (land_reduced) for ONE bucket. A failure here
                     # fails the aggregate via the join; earlier buckets'
                     # landed slices are only reachable through the aggregate
                     # tree, so a mid-stream error can never leak a
                     # partially-applied reduction.
                     try:
                         t0u = time.perf_counter()
-                        up_id = stage_ids[i]["unpack"]
-                        # the first child carries how long the bucket sat
-                        # behind earlier buckets on the one unpack worker
-                        # (device plane: unpack runs in the wire's callback)
-                        queued = {"queued_us": int(
+                        # the bucket's first unpack child carries how long
+                        # it sat behind earlier buckets on the one unpack
+                        # worker (device plane: unpack runs in the wire's
+                        # callback)
+                        first = {"queued_us": int(
                             (t0u - marks[i]["wire"][1]) * 1e6
                         )} if "wire" in marks[i] else {}
+
+                        def span(name: str, **args: Any) -> Any:
+                            args.update(first)
+                            first.clear()
+                            return tracer.span(
+                                name, cat="allreduce",
+                                parent=stage_ids[i]["unpack"], bucket=i,
+                                **args,
+                            )
+
                         if is_compressed_wire(flat):
                             # the bucket rode the wire compressed; the codes
                             # carry the reduced SUM, restored here at the
-                            # plan's bucket dtype so divide/slice/land below
+                            # plan's bucket dtype so slice/land/divide below
                             # run the exact uncompressed expressions
-                            with tracer.span(
-                                "decode", cat="allreduce", parent=up_id,
-                                bucket=i, bytes=_payload_nbytes(flat),
-                                **queued,
-                            ):
+                            with span("decode", bytes=_payload_nbytes(flat)):
                                 flat = decompress_bucket(flat)
-                            queued = {}
-                        if reduce_op == ReduceOp.AVG and num_participants > 0:
-                            with tracer.span(
-                                "divide", cat="allreduce", parent=up_id,
-                                bucket=i, bytes=_payload_nbytes(flat),
-                                **queued,
-                            ):
-                                flat = (flat / num_participants).astype(
-                                    _np_dtype(flat)
-                                )
-                            queued = {}
-                        # h2d: slice the flat and place each leaf where the
-                        # original lives. On a device leaf that is
-                        # jax.device_put: as far as it returns before the
-                        # bytes have moved, this span is the enqueue
-                        with tracer.span(
-                            "h2d", cat="allreduce", parent=up_id, bucket=i,
-                            bytes=_payload_nbytes(flat),
-                            leaves=len(plan.metas[i]), **queued,
-                        ):
-                            pairs = [
-                                (idx, place_leaf(leaves[idx], val))
-                                for idx, val in bucketing.unpack_bucket(
-                                    flat, plan, i
-                                )
-                            ]
+                        pairs = land_reduced(flat, i, span)
                         marks[i]["unpack"] = (t0u, time.perf_counter())
                         if pooled_buf is not None and not any(
                             isinstance(v, np.ndarray)
@@ -3695,6 +3722,39 @@ class Manager:
 
 def _np_dtype(x: Any) -> Any:
     return np.asarray(x).dtype if not hasattr(x, "dtype") else x.dtype
+
+
+@functools.lru_cache(maxsize=None)
+def _average_on_device() -> Callable[[Any, Any], Any]:
+    """The AVG normalisation of one landed leaf as a jitted computation.
+
+    It donates its input, so the quotient reuses the buffer the H2D just
+    filled and the step's HBM peak does not grow by a leaf; the divisor is a
+    float32 runtime scalar, so a quorum that goes 4 -> 3 -> 4 compiles
+    nothing new (one executable per leaf geometry). Bit for bit numpy's
+    result, except that XLA flushes a subnormal input or quotient (under
+    2**-126) to zero where numpy keeps it. Built on first use: a process
+    that only moves host arrays never gets here."""
+    import jax
+
+    def average_landed_leaf(x: Any, n: Any) -> Any:  # its name in a trace
+        return (x / n).astype(x.dtype)
+
+    return jax.jit(average_landed_leaf, donate_argnums=0)
+
+
+def _average(x: Any, num_participants: int, landed: bool = False) -> Any:
+    """A reduced SUM divided by the participants, rounded once back to its
+    own dtype (bf16 / f16 in, float32 quotient): the one expression of the
+    AVG normalisation, run where ``x`` is. ``landed`` says ``x`` is a leaf
+    ``place_leaf`` has just put where its original lives, a private buffer:
+    on a device it is then divided there, in place. Anything else (a numpy
+    array; a device-native PG's own result) gets a fresh array."""
+    import jax
+
+    if landed and isinstance(x, jax.Array):
+        return _average_on_device()(x, np.float32(num_participants))
+    return (x / num_participants).astype(_np_dtype(x))
 
 
 def _is_float_dtype(dtype: Any) -> bool:

@@ -6,9 +6,13 @@ through the donated state, a value fetch at the end of the window), copied
 because the program may change and the yardstick may not; the optimizer is
 the trainer's (adamw, weight decay 0.1), weights and tokens come from
 ``--seed``. Before the window it compares the program's loss path (splash,
-remat full, bf16) with chipbench/reference.py on a fixed seeded sample; the
+remat full, bf16) with the plain reference on a fixed seeded sample; the
 reference's answers are computed once per checkout by a child process that
-holds the chip before this one touches JAX.
+holds the chip before this one touches JAX. What is one architecture's own
+(the program's init, loss and forward, its config object, its plain
+reference, the gradient leaves the check samples) comes from the
+configuration's adapter, chipbench/adapters/<name>.py: this file names no
+model.
 """
 
 import hashlib
@@ -20,16 +24,26 @@ import time
 
 import numpy as np
 
-from chipbench import reference
-from chipbench.worker import REPO, llama_config
+from chipbench.worker import REPO
+
+SEEDS = 2**31  # --seed may be larger than an int32 holds; a jitted argument is one
 
 
-def _reference_answers(cell, cache_dir: str) -> dict:
+def check_sample_of(cell, adapter) -> dict:
+    """The traffic file's check sample, with the adapter's gradient leaves
+    where its tree has other names than the traffic file's."""
+    sample = cell.traffic["check"]["sample"]
+    if adapter.GRAD_LEAVES:
+        sample = {**sample, "grad_leaves": adapter.GRAD_LEAVES}
+    return sample
+
+
+def _reference_answers(cell, adapter, sample: dict, cache_dir: str) -> dict:
     """The reference's answers for the check sample, from the checkout's
     cache or from a child that runs before this process takes the chip."""
-    sample = cell.traffic["check"]["sample"]
+    script = adapter.reference.__file__
     digest = hashlib.sha256(json.dumps(sample, sort_keys=True).encode())
-    for p in (cell.config_path, os.path.join(REPO, "chipbench", "reference.py")):
+    for p in (cell.config_path, script):
         with open(p, "rb") as f:
             digest.update(f.read())
     key = digest.hexdigest()[:16]
@@ -40,8 +54,8 @@ def _reference_answers(cell, cache_dir: str) -> dict:
         with open(spec, "w") as f:
             json.dump(sample, f)
         tmp = out[:-4] + ".tmp.npz"
-        subprocess.run([sys.executable, os.path.join(REPO, "chipbench", "reference.py"),
-                        cell.config_path, spec, tmp], check=True, cwd=REPO)
+        subprocess.run([sys.executable, script, cell.config_path, spec, tmp],
+                       check=True, cwd=REPO)
         os.replace(tmp, out)
     got = np.load(out)
     return {k: got[k] for k in got.files}
@@ -80,20 +94,20 @@ def compare(system: dict, ref: dict, tol: dict) -> dict:
     return out
 
 
-def system_answers(cfg: dict, sample: dict, seq: int) -> dict:
-    """The program's side of the check: ``llama_loss`` / ``llama_forward``
-    with the default attention dispatch and remat full, in the served dtype."""
+def system_answers(adapter, cfg: dict, sample: dict, seq: int) -> dict:
+    """The program's side of the check: the adapter's loss and forward with
+    the default attention dispatch and remat full, in the served dtype."""
     import jax
 
-    from torchft_tpu.models.llama import llama_forward, llama_init, llama_loss
-
-    lc = llama_config(cfg)
+    reference = adapter.reference
+    init_, loss_, forward_ = adapter.program()
+    pc = adapter.config(cfg)
     tokens, positions = reference.check_sample(cfg, sample, seq)
-    params = jax.jit(lambda: llama_init(jax.random.PRNGKey(sample["seed"]), lc))()
+    params = jax.jit(lambda: init_(jax.random.PRNGKey(sample["seed"]), pc))()
 
     def both(p):  # one program: XLA shares the forward pass between the two
-        return (llama_loss(p, tokens, tokens, lc, remat="full"),
-                llama_forward(p, tokens, lc, remat="full")[:, positions])
+        return (loss_(p, tokens, tokens, pc, remat="full"),
+                forward_(p, tokens, pc, remat="full")[:, positions])
 
     @jax.jit
     def run_(p):
@@ -110,14 +124,15 @@ def run(cell, seed: int, seconds: float, trace: bool, out_dir: str,
     cfg, tr = cell.config, cell.traffic
     recipe = cfg["recipe"]
     B, S = recipe["batch_size"], recipe["seq_len"]
-    ref = _reference_answers(cell, cache_dir)  # before JAX: the child's chip
+    adapter = cell.adapter()
+    sample = check_sample_of(cell, adapter)
+    ref = _reference_answers(cell, adapter, sample, cache_dir)  # before JAX: the child's chip
 
     import jax
     import jax.monitoring
     import optax
 
     from chipbench import xplane
-    from torchft_tpu.models.llama import llama_init, llama_loss
     from torchft_tpu.ops import attention as attention_ops
     from torchft_tpu.utils import enable_compilation_cache
 
@@ -138,27 +153,28 @@ def run(cell, seed: int, seconds: float, trace: bool, out_dir: str,
     if str(ref["platform"]) != "tpu":
         raise RuntimeError("the cached reference was not computed on a TPU")
     check = tr["check"]
-    verdict = compare(system_answers(cfg, check["sample"], S), ref,
+    verdict = compare(system_answers(adapter, cfg, sample, S), ref,
                       check["tolerances"])
     marks["check_s"] = time.monotonic() - t_start
 
-    lc = llama_config(cfg)
+    init_, loss_, _ = adapter.program()
+    pc = adapter.config(cfg)
     tx = optax.adamw(recipe["lr"], weight_decay=recipe["weight_decay"])
 
     @jax.jit
     def init(seed):  # an argument, not a constant: one cached program for every seed
-        params = llama_init(jax.random.PRNGKey(seed), lc)
+        params = init_(jax.random.PRNGKey(seed), pc)
         return params, tx.init(params)
 
     def step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(llama_loss)(
-            params, tokens, tokens, lc, remat=recipe["remat"])
+        loss, grads = jax.value_and_grad(loss_)(
+            params, tokens, tokens, pc, remat=recipe["remat"])
         updates, opt_state = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, loss
 
     jstep = jax.jit(step, donate_argnums=(0, 1))
-    params, opt_state = init(seed)
-    tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (B, S), 0,
+    params, opt_state = init(seed % SEEDS)
+    tokens = jax.random.randint(jax.random.PRNGKey((seed + 1) % SEEDS), (B, S), 0,
                                 cfg["vocab_size"])
     jax.block_until_ready(params)
     marks["init_s"] = time.monotonic() - t_start

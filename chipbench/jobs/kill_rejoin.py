@@ -2,7 +2,12 @@
 under the launcher; the victim is SIGKILLed the moment this process has read
 its commit line for step 2, the first step after the warm-up step (every
 group is at a step boundary and has compiled; where the lighthouse formed
-its first quorum from three groups, the fourth healed in during that step);
+its first quorum from three groups, the fourth healed in during that step).
+The victim is held at that boundary until the kill lands: its worker stops
+itself once the line is out (worker.py, ``--chipbench-freeze``), because a
+victim that runs on may have asked for the next quorum by the time a signal
+from here reaches it, and a quorum formed with a dead member costs the
+survivors a discarded step and a timeout, and this run its result;
 the survivors commit on, the launcher restarts the victim on its chip, it
 heals over the HTTP in-place transport and rejoins; the job ends by itself
 and every group prints its SUMMARY. The event runs to its end
@@ -78,14 +83,18 @@ def run(cell, seed: int, seconds: float, trace: bool, out_dir: str,
     trace_dir = os.path.join(out_dir, "trace") if trace else None
     log = os.path.join(out_dir, "launch.log")
 
+    frozen = os.path.join(out_dir, "frozen")  # made by the victim as it stops
     stale = None  # what the log of an attempt that is started again is kept as
     while True:
         if stale:
             os.replace(log, log + stale)
             if trace_dir:
                 shutil.rmtree(trace_dir, ignore_errors=True)
+        if os.path.exists(frozen):
+            os.remove(frozen)
         with launch.Launch(trainer_job.launcher_args(cell),
-                           trainer_job.worker_args(cell, steps, trace_dir),
+                           trainer_job.worker_args(cell, steps, trace_dir)
+                           + ["--chipbench-freeze", f"{victim}:{KILL_STEP}:{frozen}"],
                            log) as run_:
             t_launch = time.monotonic()
             t_pids = [run_.wait_for(
@@ -102,6 +111,7 @@ def run(cell, seed: int, seconds: float, trace: bool, out_dir: str,
                 if size(seen) > steps:  # the job would end before the rejoin
                     known, steps, stale = seen, size(seen), ".too_short"
                     continue  # leaving the block stops every process of it
+            open(frozen, "a").close()  # whatever happens, the replacement runs on
             os.kill(old_pid, signal.SIGKILL)
             t_kill = time.monotonic()
             summaries = run_.finish(tr["timeout_s"] + steps * 3 * known["step_s"])

@@ -21,6 +21,11 @@ STEP_LINE = re.compile(
 DIED_LINE = re.compile(r"replica group (\d+) died .*restart (\d+)/(\d+)")
 
 
+# where a line this module reads starts (a replica's, or the launcher's on
+# a restart), anywhere in what the pipe gave as one line
+LINE_MARK = re.compile(r"\[replica \d+\] |(?:WARNING|ERROR):[\w.]+:replica group \d+ died ")
+
+
 class Failed(Exception):
     pass
 
@@ -30,6 +35,21 @@ class Line:
 
     def __init__(self, t: float, replica, text: str) -> None:
         self.t, self.replica, self.text = t, replica, text
+
+
+def split_glued(text: str) -> "list[str]":
+    """The writers' lines in what was read as one. The pipe is shared by the
+    workers, their Managers' native servers and the lighthouse; a writer
+    whose text and newline are two writes (an unbuffered Python ``print``)
+    leaves a gap, and what another writer sends into it is glued to the text:
+    ``[replica 3] step=8 ... tok/s=1904[replica 1] step=8 ...`` (the chip,
+    PERF.md section 6, PR 27). worker.py's ``_Lines`` closes the gap at its
+    source; this is for a line of a writer that does not go through it."""
+    cuts = [m.start() for m in LINE_MARK.finditer(text)]
+    if not cuts or cuts == [0]:
+        return [text]
+    return ([text[:cuts[0]]] if cuts[0] else []) + [
+        text[a:b] for a, b in zip(cuts, cuts[1:] + [len(text)])]
 
 
 def parse_step(text: str):
@@ -59,16 +79,16 @@ class Launch:
     def _read(self) -> None:
         for raw in self.proc.stdout:
             t = time.monotonic()
-            text = raw.rstrip("\n")
-            m = REPLICA_LINE.match(text)
-            if not m and not DIED_LINE.search(text):
-                self.log.write(raw)  # native servers' RPC chatter: log only
-                continue
-            self.log.write(f"{t - self.t0:10.4f} {raw}")
-            with self._cond:
-                self.lines.append(Line(t, int(m[1]), m[2]) if m
-                                  else Line(t, None, text))
-                self._cond.notify_all()
+            for text in split_glued(raw.rstrip("\n")):
+                m = REPLICA_LINE.match(text)
+                if not m and not DIED_LINE.search(text):
+                    self.log.write(text + "\n")  # native servers' RPC chatter: log only
+                    continue
+                self.log.write(f"{t - self.t0:10.4f} {text}\n")
+                with self._cond:
+                    self.lines.append(Line(t, int(m[1]), m[2]) if m
+                                      else Line(t, None, text))
+                    self._cond.notify_all()
         with self._cond:
             self._cond.notify_all()
 
@@ -104,11 +124,7 @@ class Launch:
         self._reader.join(10)
         if rc != 0:
             raise Failed(f"launcher rc={rc} (log: {self.log.name})")
-        out: "dict[int, list[dict]]" = {}
-        for ln in self.lines:
-            if ln.replica is not None and ln.text.startswith("SUMMARY "):
-                out.setdefault(ln.replica, []).append(json.loads(ln.text[8:]))
-        return out
+        return summaries(self.lines)
 
     def stop(self) -> None:
         """Leave no process behind, and wait until each has ended: the
@@ -118,6 +134,9 @@ class Launch:
         to the group it makes the launcher restart workers it sees die."""
         if self.proc.poll() is None:
             try:
+                # a worker that stopped itself (worker.py, the failure cell's
+                # victim before its kill) takes no SIGTERM until it runs again
+                os.killpg(self.proc.pid, signal.SIGCONT)
                 self.proc.terminate()
                 self.proc.wait(timeout=45)
             except subprocess.TimeoutExpired:
@@ -145,6 +164,17 @@ def _running_in_group(pgid: int) -> int:
             continue
         n += int(pgrp) == pgid and state != "Z"
     return n
+
+
+def summaries(lines) -> "dict[int, list[dict]]":
+    """SUMMARY objects per replica, in order. ``raw_decode``: what another
+    writer glued behind the object (``split_glued``) is not the object's."""
+    out: "dict[int, list[dict]]" = {}
+    for ln in lines:
+        if ln.replica is not None and ln.text.startswith("SUMMARY "):
+            out.setdefault(ln.replica, []).append(
+                json.JSONDecoder().raw_decode(ln.text[8:])[0])
+    return out
 
 
 def pids(lines, replica: int) -> "list[tuple[float, int]]":

@@ -1,6 +1,6 @@
 """BENCHMARK.json and the data files it names: the only place that knows
-where a cell's configuration, traffic, job kind, layer metrics and reducers
-live. Everything is found by name under ``root``, so a later PR adds a cell
+where a cell's configuration, traffic, job kind, adapter, layer metrics and
+reducers live. Everything is found by name under ``root``, so a later PR adds a cell
 by adding files (chipbench/README.md) and a test can do so in a temporary
 directory."""
 
@@ -13,6 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+DEFAULT_ADAPTER = "llama"  # a configuration file without the key ``adapter``
 
 
 def _json(path: str) -> dict:
@@ -30,6 +31,22 @@ def load_module(root: str, kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def adapter_for(config_path: str, cfg: dict):
+    """The adapter the configuration file names under ``adapter`` (absent:
+    ``llama``): what is one architecture's own, chipbench/adapters/<name>.py.
+    Found under the root the file lives in (<root>/chipbench/configs/), so a
+    temporary root brings its own; a lone file elsewhere gets chipbench's."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(config_path))))
+    if not os.path.isdir(os.path.join(root, "chipbench", "adapters")):
+        root = ROOT
+    name = cfg.get("adapter", DEFAULT_ADAPTER)
+    try:
+        return load_module(root, "adapters", name)
+    except FileNotFoundError as e:
+        raise ValueError(f"{config_path}: key 'adapter': {e}") from None
 
 
 class Cell:
@@ -50,9 +67,16 @@ class Cell:
         here = lambda m: name in m.get("workloads", [name])  # noqa: E731
         self.end_to_end = [m for m in bench["end_to_end"] if here(m)]
         self.per_layer = [m for m in bench["per_layer"] if here(m)]
+        self._adapter = None
 
     def job(self):
         return load_module(self.root, "jobs", self.traffic["job"])
+
+    def adapter(self):
+        """What is the configuration's architecture's own, loaded once."""
+        if self._adapter is None:
+            self._adapter = adapter_for(self.config_path, self.config)
+        return self._adapter
 
     def layer_metric(self, name: str) -> dict:
         return _json(os.path.join(
@@ -69,7 +93,8 @@ def load(root: str = ROOT) -> dict:
 def problems(root: str = ROOT) -> "list[str]":
     """Everything wrong with the manifest that can be seen without a chip:
     missing files, names and units outside the contract's alphabet, more
-    four-chip cells than a quarter, a metric no cell reports."""
+    four-chip cells than a quarter, a metric no cell reports, a configuration
+    its adapter cannot express."""
     bench, out = load(root), []
     for key in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [e["name"] for e in bench[key]]
@@ -87,6 +112,7 @@ def problems(root: str = ROOT) -> "list[str]":
         try:
             cell = Cell(root, bench, w["name"])
             cell.job()
+            cell.adapter().config(cell.config)
         except (OSError, KeyError, ValueError) as e:
             out.append(f"{w['name']}: {e}")
             continue

@@ -1,8 +1,10 @@
 """Seconds per step of the device operations whose name matches ``pattern``
 (self time, summed over the traced chips' ops, divided by chips x steps).
-With ``roofline`` set to a kernel cost in chipbench/flops.py it returns
-instead the kernel's share of its roofline in percent: the least time the
-chip could take for the calls a step makes over the time they took."""
+With ``roofline`` set it returns instead the kernel's share of its roofline
+in percent: the least time the chip could take for the calls a step makes
+over the time they took. ``roofline["kernel"]`` (absent: ``attention``) names
+the kernel; its operations and bytes, and the layers that call it, are the
+configuration's adapter's (chipbench/flops.py for ``llama``)."""
 
 import re
 
@@ -20,10 +22,11 @@ def reduce(obs, cell, pattern, roofline=None):
     per_step = sum(hit) / t["chips_traced"] / n
     if roofline is None:
         return per_step
-    r, cfg = cell.config["recipe"], cell.config
+    r, cfg, adapter = cell.config["recipe"], cell.config, cell.adapter()
+    kernel = roofline.get("kernel", "attention")
     floor = 0.0
     for passes, calls in roofline["calls_per_layer"].items():
-        cost = flops.attention_kernel_cost(cfg, r["batch_size"], r["seq_len"], passes)
-        floor += calls * cfg["num_hidden_layers"] * flops.roofline_floor_s(
+        cost = adapter.KERNEL_COSTS[kernel](cfg, r["batch_size"], r["seq_len"], passes)
+        floor += calls * adapter.layers_with(cfg, kernel) * flops.roofline_floor_s(
             cost, obs["device"]["kind"])[0]
     return 100.0 * floor / per_step

@@ -5,7 +5,8 @@ bucket: the wait is the start of the second less the end of the first,
 summed over the buckets of a step, median over steps. ``to`` is a list in
 order of preference: the first name that has spans in the step is the
 stage (the unpack worker starts with ``decode`` only where a bucket rode
-the wire compressed, else with ``divide``). A step the window cut (the two
+the wire compressed, else with ``h2d``, and before PR 25 with ``divide``:
+the layer-metric file's list is in that order). A step the window cut (the two
 counts differ) is left out. A wait is arithmetic on the work's spans and
 not a span of its own, so the idle-gap attribution (xplane.attribute) keeps
 giving a gap to the work that causes it."""

@@ -1,10 +1,12 @@
-"""The process the launcher starts for each replica group: registers the
-cell's configuration in ``models.llama.CONFIGS`` under its name and then runs
-``examples/train_llama_hsdp.py`` as ``__main__`` — the user's path, no side
-script, no edit to the program.
+"""The process the launcher starts for each replica group: has the
+configuration's adapter (chipbench/adapters/; ``llama`` where the file names
+none) register the cell's configuration with the program under its name and
+then runs the adapter's trainer (``examples/train_llama_hsdp.py``) as
+``__main__`` — the user's path, no side script, no edit to the program.
 
     python3 chipbench/worker.py --chipbench-config <file> \
-        [--chipbench-trace <dir>] <the trainer's own arguments>
+        [--chipbench-trace <dir>] [--chipbench-freeze <group>:<step>:<file>] \
+        <the trainer's own arguments>
 
 With ``--chipbench-trace`` (a traced run only) it also (a) records the
 device trace of this process from its first step line to its SUMMARY line,
@@ -12,12 +14,22 @@ device trace of this process from its first step line to its SUMMARY line,
 allreduce, should_commit) on the epoch clock, from this file, around the
 call, and (c) asks the Manager for its span ring (``dump_trace``, a public
 method) before it shuts down. An untraced run touches none of this.
+
+With ``--chipbench-freeze`` (the failure cell only) replica group <group>
+stops itself (SIGSTOP) the moment its commit line for a step >= <step> has
+left it, once: <file> is made first, and a process that finds it does not
+stop, so the replacement the launcher starts with the same arguments runs
+on. The job kind kills the stopped process. A kill from outside alone races
+with the victim's request for the next quorum (``_Lines``, below).
 """
 
 import json
 import os
+import re
 import runpy
+import signal
 import sys
+import threading
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -146,10 +158,77 @@ class _Tee:
         return getattr(self.real, name)
 
 
+class _Lines:
+    """stdout whose every completed line leaves this process in ONE write.
+    The launcher runs its workers unbuffered, so a ``print`` is two writes,
+    the text and then the newline, on a pipe that four workers, their
+    Managers' native servers and the lighthouse share: whatever another
+    writer sends between the two is glued to the text, and the launcher's
+    reader (launch.py) saw one line where there were two. Five of the 434
+    replica lines in nine runs of the failure cell on the chip were glued so
+    (PERF.md section 6, PR 27); a SUMMARY line glued to anything is no JSON,
+    a step or SUMMARY line glued behind another replica's is lost, and
+    either ends the run with no result. A write of up to PIPE_BUF (4096)
+    bytes is atomic.
+
+    ``freeze=(step, marker)``: the scripted failure's victim. Once its commit
+    line for a step >= ``step`` is out, the process stops itself where it
+    stands, at the step boundary, and the job kind (jobs/kill_rejoin.py)
+    SIGKILLs it there. Killed from outside while it runs on, the victim has
+    some milliseconds to ask for the next quorum first; where it wins, the
+    survivors' next step is formed with a dead member, is discarded, and one
+    survivor sits out a timeout (60 s of 120 in the CPU rehearsal with the
+    kill 5 ms late; the chip cell's is 600 s): no result either way."""
+
+    COMMIT = re.compile(r"^\[replica \d+\] step=(\d+) inner=")
+
+    def __init__(self, real, freeze=None) -> None:
+        self.real, self.buf, self.lock = real, "", threading.Lock()
+        self.freeze = freeze
+
+    def write(self, s: str) -> int:
+        with self.lock:
+            self.buf += s
+            whole, nl, self.buf = self.buf.rpartition("\n")
+            if nl:
+                stop = self.freeze and any(
+                    (m := self.COMMIT.match(ln)) and int(m[1]) >= self.freeze[0]
+                    for ln in whole.split("\n"))
+                if stop:  # the marker before the line: the kill follows the line
+                    open(self.freeze[1], "w").close()
+                    self.freeze = None
+                self.real.write(whole + nl)
+                if stop:
+                    self.real.flush()
+                    os.kill(os.getpid(), signal.SIGSTOP)
+        return len(s)
+
+    def flush(self) -> None:
+        with self.lock:
+            if self.buf:  # a prompt: text that no newline has followed yet
+                self.real.write(self.buf)
+                self.buf = ""
+        self.real.flush()
+
+    def __getattr__(self, name):
+        return getattr(self.real, name)
+
+
+def freeze_of(flag: "str | None"):
+    """``<group>:<step>:<file>`` -> (step, file) in the process that is to
+    stop itself: replica group <group>, and <file> not there yet (the
+    replacement of a victim that did stop finds it)."""
+    if not flag:
+        return None
+    group, step, marker = flag.split(":", 2)
+    mine = int(os.environ.get("REPLICA_GROUP_ID", -1)) == int(group)
+    return (int(step), marker) if mine and not os.path.exists(marker) else None
+
+
 def main() -> None:
     argv = sys.argv[1:]
     own = {}
-    for flag in ("--chipbench-config", "--chipbench-trace"):
+    for flag in ("--chipbench-config", "--chipbench-trace", "--chipbench-freeze"):
         if flag in argv:
             i = argv.index(flag)
             own[flag] = argv[i + 1]
@@ -157,16 +236,20 @@ def main() -> None:
     sys.path.insert(0, REPO)
     with open(own["--chipbench-config"]) as f:
         cfg = json.load(f)
-    from torchft_tpu.models.llama import CONFIGS
+    from chipbench import manifest
 
-    CONFIGS[cfg["name"]] = llama_config(cfg)
+    # what is the architecture's own: its config object, its registration
+    # and its trainer (chipbench/adapters/<name>.py; absent key: llama)
+    trainer, select = manifest.adapter_for(own["--chipbench-config"], cfg).register(cfg)
+    # traced or not: what launch.py reads
+    sys.stdout = _Lines(sys.stdout, freeze_of(own.get("--chipbench-freeze")))
     if "--chipbench-trace" in own:
         os.makedirs(own["--chipbench-trace"], exist_ok=True)
         tracer = _Tracer(own["--chipbench-trace"])
         tracer.wrap_manager()
         sys.stdout = _Tee(sys.stdout, tracer)
-    sys.argv = [TRAINER, "--config", cfg["name"], *argv]
-    runpy.run_path(TRAINER, run_name="__main__")
+    sys.argv = [trainer, *select, *argv]
+    runpy.run_path(trainer, run_name="__main__")
 
 
 if __name__ == "__main__":

@@ -6,7 +6,10 @@ a trace directory, four steps, the bucket cap at 0.5 MB so that a step has
 eight buckets. Kept: the Manager's span ring (``dump_trace``) cut to the
 spans of steps 2 and 3, and the SUMMARY line's object. The times are a CPU's
 and mean nothing; the tests read structure (which span pairs with which)
-and arithmetic."""
+and arithmetic. ``ring.*`` is PR 24's program (the unpack worker divides, then
+lands: ``divide`` before ``h2d``); ``ring25.*`` was recorded by PR 27 with
+``python3 tests/chipbench/record_ring.py ring25`` on the program since PR 25
+(``h2d``, then the AVG of the landed leaves)."""
 
 import glob
 import json
@@ -21,7 +24,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
 
-def main() -> None:
+def main(name: str = "ring") -> None:
     from chipbench_helpers import TINY, read, write
     from torchft_tpu.coordination import LighthouseServer
 
@@ -49,11 +52,11 @@ def main() -> None:
     ring["spans"] = [s for s in ring["spans"] if s["step"] in (2, 3)]
     line = next(ln for ln in out.splitlines() if " SUMMARY " in ln)
     data = os.path.join(HERE, "data")
-    write(os.path.join(data, "ring.spans.json"), ring)
-    write(os.path.join(data, "ring.summary.json"),
+    write(os.path.join(data, f"{name}.spans.json"), ring)
+    write(os.path.join(data, f"{name}.summary.json"),
           json.loads(line.split(" SUMMARY ", 1)[1]))
     print(len(ring["spans"]), "spans")
 
 
 if __name__ == "__main__":
-    main()
+    main(*sys.argv[1:2])

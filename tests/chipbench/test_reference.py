@@ -12,6 +12,7 @@ from chipbench.worker import llama_config
 from torchft_tpu.models.llama import llama_init
 
 bare = manifest.load_module(ROOT, "jobs", "bare")
+llama = manifest.load_module(ROOT, "adapters", manifest.DEFAULT_ADAPTER)
 # the chip cell's own tolerances and gradient leaves, on a smaller sample
 CHECK = read(f"{ROOT}/chipbench/traffic/bare.json")["check"]
 TOL, SAMPLE = CHECK["tolerances"], {**CHECK["sample"], "sequences": 2, "positions": 8}
@@ -37,7 +38,7 @@ def _reference(cfg, seq=256, crush=None, **kw):
 
 
 def _both(cfg, seq=256):
-    return bare.system_answers(cfg, SAMPLE, seq), _reference(cfg, seq)
+    return bare.system_answers(llama, cfg, SAMPLE, seq), _reference(cfg, seq)
 
 
 @pytest.mark.parametrize("name", ["mistral-7b", "internlm2-1.8b"])
@@ -134,3 +135,68 @@ def test_unsupported_keys_are_refused():
         llama_config({**cfg, "sliding_window": 4096})
     with pytest.raises(ValueError):
         llama_config({**cfg, "head_dim": 64})
+
+
+def test_the_default_adapter_names_what_stood_there():
+    """``llama`` is the arithmetic that stood in worker.py, reference.py and
+    flops.py, named: the same objects, so the accepted cells' numbers and the
+    reference's cache key cannot move."""
+    import os
+
+    from chipbench import flops, worker
+
+    assert llama.reference is reference
+    assert llama.reference.__file__ == os.path.join(ROOT, "chipbench", "reference.py")
+    assert llama.GRAD_LEAVES is None
+    assert llama.train_flops_per_token is flops.train_flops_per_token
+    assert llama.num_params is flops.num_params
+    assert llama.KERNEL_COSTS == {"attention": flops.attention_kernel_cost}
+    cfg = _tiny("mistral-7b", "bfloat16")
+    assert llama.config(cfg) == llama_config(cfg) and llama.layers_with(cfg, "attention") == 2
+    script, select = llama.register(dict(cfg, name="named-by-llama"))
+    assert (script, select) == (worker.TRAINER, ["--config", "named-by-llama"])
+    from torchft_tpu.models.llama import CONFIGS, llama_forward, llama_loss
+
+    assert CONFIGS.pop("named-by-llama") == llama_config(cfg)
+    assert llama.program() == (llama_init, llama_loss, llama_forward)
+
+
+def test_the_reference_cache_key_is_the_parents(tmp_path):
+    """The key of ``mistral-7b.bare``'s cached answers: the sample as the
+    traffic file has it, the configuration file and reference.py, hashed as
+    before this PR, so a checkout that holds the parent's .chipbench_cache
+    starts no child."""
+    import hashlib
+    import json
+    import os
+
+    import numpy as np
+
+    cell = manifest.Cell(ROOT, manifest.load(ROOT), "mistral-7b.bare")
+    sample = bare.check_sample_of(cell, cell.adapter())
+    assert sample is cell.traffic["check"]["sample"]
+    digest = hashlib.sha256(json.dumps(CHECK["sample"], sort_keys=True).encode())
+    for p in (f"{ROOT}/chipbench/configs/mistral-7b.json", f"{ROOT}/chipbench/reference.py"):
+        digest.update(open(p, "rb").read())
+    parents = tmp_path / f"reference_mistral-7b_{digest.hexdigest()[:16]}.npz"
+    np.savez(parents, platform="tpu", loss=1.5)
+    got = bare._reference_answers(cell, cell.adapter(), sample, str(tmp_path))  # no child
+    assert float(got["loss"]) == 1.5 and os.listdir(tmp_path) == [parents.name]
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 - 1, 2147485001, 2**31 + 2**30])
+def test_a_seed_over_int32_reaches_the_jitted_init(seed):
+    """``--seed`` goes to a jitted function as an int32; the contract allows
+    a little more than 2**31. What the job hands on is inside int32, the same
+    for a seed that already was, and the key builds."""
+    import jax.numpy as jnp
+
+    for s in (seed % bare.SEEDS, (seed + 1) % bare.SEEDS):
+        assert 0 <= s < 2**31
+        key = jax.jit(lambda x: jax.random.PRNGKey(x))(s)
+        assert key.shape == (2,) and key.dtype == jnp.uint32
+    if seed < 2**31 - 1:
+        assert (seed % bare.SEEDS, (seed + 1) % bare.SEEDS) == (seed, seed + 1)
+    else:
+        with pytest.raises(OverflowError):
+            jax.jit(lambda x: jax.random.PRNGKey(x))(seed + 1)

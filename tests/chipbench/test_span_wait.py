@@ -27,11 +27,11 @@ def reduce(obs, **args):
         obs, cell(), **args)
 
 
-def ring_proc():
+def ring_proc(ring="ring"):
     """The recorded ring as hostspans.collect names it, window = all of it."""
     spans = [(f"manager.{s['cat']}.{s['name']}", s["ts_us"] * 1000,
               (s["ts_us"] + s["dur_us"]) * 1000, s["step"])
-             for s in read(os.path.join(DATA, "ring.spans.json"))["spans"]]
+             for s in read(os.path.join(DATA, f"{ring}.spans.json"))["spans"]]
     return {"replica": 0, "pid": 1, "spans": spans,
             "window": (min(s[1] for s in spans), max(s[2] for s in spans))}
 
@@ -87,13 +87,51 @@ def test_order_pairing_is_bucket_pairing_on_a_recorded_ring():
                    for st in steps for b in buckets)
 
 
+@pytest.mark.parametrize("metric", ["allreduce.unpack_wait_s",
+                                    "allreduce.unpack_wait_4g_s"])
+def test_the_files_list_pairs_wire_run_with_the_buckets_first_unpack_child(metric):
+    """Since PR 25 the unpack worker lands a bucket (``h2d``) and then
+    averages what landed (``divide``); before, it divided first. The
+    layer-metric file's ``to`` list, in its order, finds the first child on
+    the ring recorded since (ring25), so the metric is the wait alone; in
+    the order it had before PR 27 it read the wait plus ``h2d``."""
+    args = read(f"{ROOT}/chipbench/layer_metrics/{metric}.json")["args"]
+    assert args["frm"] == W and args["to"] == [DEC, H, DIV]
+    ring = read(os.path.join(DATA, "ring25.spans.json"))
+    assert ring["dropped"] == 0
+    kids, ran = {}, {}  # (step, bucket) -> the unpack children / wire_run's end
+    for s in ring["spans"]:
+        b = s.get("args", {}).get("bucket")
+        if s["cat"] == "allreduce" and s["name"] in ("decode", "h2d", "divide"):
+            kids.setdefault((s["step"], b), []).append((s["ts_us"], s["name"], s["dur_us"]))
+        elif s["cat"] == "allreduce" and s["name"] == "wire_run":
+            ran[s["step"], b] = s["ts_us"] + s["dur_us"]
+    assert len(kids) == len(ran) == 16
+    assert {min(k)[1] for k in kids.values()} == {"h2d"}   # lands, then averages
+    steps = sorted({k[0] for k in kids})
+    wait = median(sum(min(kids[k])[0] - ran[k] for k in kids if k[0] == st) / 1e6
+                  for st in steps)
+    obs = {"procs": [ring_proc("ring25")]}
+    assert reduce(obs, **args) == pytest.approx(wait, abs=1e-9) and wait > 0
+    # the order before: the second child, so the wait plus what h2d took
+    # (and the little between the two spans)
+    before = reduce(obs, frm=W, to=[DEC, DIV, H])
+    h2d = median(sum(min(kids[k])[2] for k in kids if k[0] == st) / 1e6 for st in steps)
+    assert before >= wait + h2d and before - wait < 1.5 * h2d
+    # on PR 24's ring the old order was the right one; a program is read by
+    # the list that fits it, and the benchmark reads the program that is there
+    old = {"procs": [ring_proc("ring")]}
+    assert reduce(old, frm=W, to=[DEC, DIV, H]) < reduce(old, **args)
+
+
+@pytest.mark.parametrize("ring", ["ring", "ring25"])
 @pytest.mark.parametrize("cell_name", ["mistral-7b.managed-1g",
                                        "internlm2-1.8b.kill-rejoin-4g"])
-def test_every_new_span_metric_reads_the_recorded_ring(cell_name):
+def test_every_new_span_metric_reads_the_recorded_ring(cell_name, ring):
     """Through run.layer_values, like a traced run: the metrics this ring can
     feed (reducers ``span`` and ``span_wait``) all come out as numbers."""
     c = cell(cell_name)
-    obs = {"procs": [ring_proc()], "summaries": {}, "phases": {}, "e2e": {},
+    obs = {"procs": [ring_proc(ring)], "summaries": {}, "phases": {}, "e2e": {},
            "steps": {}}
     mine = [m for m in c.per_layer
             if c.layer_metric(m["name"])["reducer"] in ("span", "span_wait")]

@@ -23,7 +23,11 @@ groups share a host, hands each its own chips (--chips-per-group):
         --seq-len 2048 --steps 8 --timeout 600
 
 The mesh is built from the devices the process was given; chip_smoke.py at
-the repo root drives exactly this line and checks what comes out. Pod use:
+the repo root drives exactly this line and checks what comes out. The model
+is whatever kind the ``--config`` preset is (torchft_tpu.models.model_fns):
+``--config olmoe_1b_7b`` trains the dropless 64-expert MoE through the same
+loop, and its SUMMARY carries ``model_stats`` (expert load, auxiliary loss).
+Pod use:
 --config llama3_8b --fsdp 16 --sp 4 --tp 4. Chaos-test with
 examples/punisher.py kill_loop.
 """
@@ -58,11 +62,10 @@ def train(args) -> None:
     from jax.sharding import NamedSharding
 
     from torchft_tpu.manager import Manager
-    from torchft_tpu.models.llama import CONFIGS, llama_init, llama_loss
+    from torchft_tpu.models import CONFIGS, model_fns
     from torchft_tpu.ops import attention as attention_ops
     from torchft_tpu.parallel.mesh import (
         batch_sharding,
-        llama_param_specs,
         make_hsdp_mesh,
         shard_params,
     )
@@ -89,6 +92,8 @@ def train(args) -> None:
     replica_id = int(os.environ.get("REPLICA_GROUP_ID", args.replica_id))
     lighthouse = os.environ.get("TORCHFT_LIGHTHOUSE", args.lighthouse)
     cfg = CONFIGS[args.config]
+    # what differs between models is the configuration object's kind
+    model_init, model_loss, param_specs = model_fns(cfg)
 
     # The devices this process was given: every chip of the host, or the
     # ones the launcher assigned (--chips-per-group). Named on every run so
@@ -107,7 +112,7 @@ def train(args) -> None:
     # In-group mesh: dp=1 (the replicated dim lives across groups, via the
     # manager), everything else in-graph over ICI.
     mesh = make_hsdp_mesh(devices, dp=1, fsdp=args.fsdp, sp=args.sp, tp=args.tp)
-    specs = llama_param_specs(cfg)
+    specs = param_specs(cfg)
     tok_sharding = batch_sharding(mesh)
     if args.sp == 1:
         # no sequence axis to exchange over: each shard runs the default
@@ -121,7 +126,7 @@ def train(args) -> None:
         attention_fn = make_ring_attention_fn(mesh)
 
     params = shard_params(
-        llama_init(jax.random.PRNGKey(replica_id), cfg), mesh, specs
+        model_init(jax.random.PRNGKey(replica_id), cfg), mesh, specs
     )
     tx = optax.adamw(args.lr, weight_decay=0.1)
     opt_state = tx.init(params)
@@ -132,7 +137,9 @@ def train(args) -> None:
     def grad_step(params, tokens, targets):
         # remat="full": the 8B seq-8192 target sits at the HBM edge; the
         # "dots" default is tuned for configs with headroom (see models/remat).
-        return jax.value_and_grad(llama_loss)(
+        # -> ((loss, stats), grads); stats: the model's own counters ({} for
+        # a dense model), device scalars fetched with the loss
+        return jax.value_and_grad(model_loss, has_aux=True)(
             params, tokens, targets, cfg, attention_fn=attention_fn, remat="full"
         )
 
@@ -296,7 +303,8 @@ def train(args) -> None:
     # (an error or a timeout swallowed into a False vote) exits 0 like any
     # other, so it is counted here and judged by whoever reads the line.
     run = {"committed": 0, "discarded": 0, "discarded_after_first": 0,
-           "losses": [], "iter_s": [], "reduced_on_device": True}
+           "losses": [], "iter_s": [], "reduced_on_device": True,
+           "model_stats": {}}
     platform = device["platform"]
 
     def on_device(tree) -> bool:
@@ -321,7 +329,7 @@ def train(args) -> None:
                 )
                 if diloco is not None:
                     # inner step: local grads + local adamw, no cross-group traffic
-                    loss, grads = grad_step(state["params"], batch, batch)
+                    (loss, stats), grads = grad_step(state["params"], batch, batch)
                     state["params"], state["opt_state"] = update_step(
                         state["params"], state["opt_state"], grads
                     )
@@ -335,7 +343,7 @@ def train(args) -> None:
                 else:
                     manager.start_quorum()
                     with tracer.span("grad_dispatch", cat="trainer"):
-                        loss, grads = grad_step(state["params"], batch, batch)
+                        (loss, stats), grads = grad_step(state["params"], batch, batch)
                     work = manager.allreduce(grads)
                     with tracer.span("allreduce_wait", cat="trainer"):
                         reduced = work.get_future().wait(timeout=args.timeout)
@@ -371,7 +379,12 @@ def train(args) -> None:
                     # the fetch iter_s leaves out: it waits for everything
                     # the device still owes this step's loss
                     with tracer.span("loss_fetch", cat="trainer"):
-                        loss_now = float(loss)
+                        loss_now, stats = float(loss), jax.device_get(stats)
+                    for name, values in stats.items():  # e.g. trainer/moe_stats
+                        values = {k: float(v) for k, v in values.items()}
+                        tracer.instant(name, cat="trainer", **values)
+                        for k, v in values.items():
+                            run["model_stats"].setdefault(k, []).append(v)
                     print(
                         f"[replica {replica_id}] step={manager.current_step()} "
                         f"inner={inner_step} loss={loss_now:.4f} "
@@ -501,7 +514,7 @@ if __name__ == "__main__":
     # choices from CONFIGS itself: the list can't drift when configs are
     # added, and a typo dies at argparse instead of as a KeyError in every
     # spawned replica (importing CONFIGS imports jax but no backend init)
-    from torchft_tpu.models.llama import CONFIGS
+    from torchft_tpu.models import CONFIGS
 
     parser.add_argument("--config", default="tiny", choices=sorted(CONFIGS),
                         help="model config (CONFIGS key)")

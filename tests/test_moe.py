@@ -22,7 +22,7 @@ class TestDispatch:
     def test_combine_weights_sum_to_one_under_capacity(self):
         T, E = 16, 4
         probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0), (T, E)), -1)
-        combine, dispatch, _aux = _top_k_dispatch(probs, top_k=2, capacity=T)
+        combine, dispatch = _top_k_dispatch(probs, top_k=2, capacity=T)
         # ample capacity: every token's two gates land, normalized to 1
         np.testing.assert_allclose(np.asarray(combine.sum(axis=(1, 2))), 1.0, rtol=1e-5)
         # each (expert, slot) holds at most one token
@@ -32,7 +32,7 @@ class TestDispatch:
         T, E = 8, 2
         # all tokens want expert 0
         probs = jnp.tile(jnp.array([[0.99, 0.01]], jnp.float32), (T, 1))
-        combine, dispatch, _ = _top_k_dispatch(probs, top_k=1, capacity=3)
+        combine, dispatch = _top_k_dispatch(probs, top_k=1, capacity=3)
         # only 3 tokens fit; the rest are dropped (zero combine weight)
         kept = np.asarray(combine.sum(axis=(1, 2)) > 0)
         assert kept.sum() == 3
@@ -44,9 +44,15 @@ class TestDispatch:
         skewed = jax.nn.softmax(
             jnp.tile(jnp.array([[5.0, 0.0, 0.0, 0.0]], jnp.float32), (T, 1)), -1
         )
-        _, _, aux_bal = _top_k_dispatch(balanced, 1, T)
-        _, _, aux_skew = _top_k_dispatch(skewed, 1, T)
-        assert float(aux_skew) > float(aux_bal)
+
+        def aux(probs):  # the model's own: every choice counted (here: one)
+            from torchft_tpu.models.moe import load_balancing_loss
+
+            first = jax.lax.top_k(probs, 1)[1][:, 0]
+            counts = jnp.sum(jax.nn.one_hot(first, E), axis=0)
+            return load_balancing_loss(counts[None], jnp.sum(probs, axis=0)[None], T)
+
+        assert float(aux(skewed)) > float(aux(balanced))
 
 
 class TestMoEFFN:
@@ -130,3 +136,206 @@ class TestExpertParallel:
         sharded = shard_params(params, mesh, moe_param_specs(cfg))
         ep = float(jax.jit(moe_loss, static_argnums=(3,))(sharded, toks, toks, cfg))
         assert abs(base - ep) < 1e-4, (base, ep)
+
+
+# ---- PR 28: OLMoE's block: dropless routing, gates not renormalised,
+# QK-norm, routing replay
+
+import dataclasses  # noqa: E402
+
+from torchft_tpu.models.moe import (  # noqa: E402
+    load_balancing_loss,
+    moe_forward,
+    moe_loss_and_stats,
+)
+
+OLMOE_TINY = dataclasses.replace(
+    MOE_CONFIGS["debug"], num_experts=8, top_k=4, capacity_factor=None,
+    norm_topk_prob=False, qk_norm=True,
+)
+
+
+def _layer_weights(cfg, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    d, h, E = cfg.dim, cfg.ffn_hidden, cfg.num_experts
+    return (jax.random.normal(k[0], (2, 16, d), jnp.float32),
+            jax.random.normal(k[1], (d, E), jnp.float32) / 4,
+            jax.random.normal(k[2], (E, d, h), jnp.float32) / 8,
+            jax.random.normal(k[3], (E, d, h), jnp.float32) / 8,
+            jax.random.normal(k[4], (E, h, d), jnp.float32) / 8)
+
+
+def _dense_masked_sum(x, router, wg, wu, wd, cfg):
+    """Every expert on every token, weighted by the token's gate for it
+    (zero where it was not chosen): the definition, with no dispatch."""
+    flat = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(flat @ router, axis=-1)
+    gates, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    weight = jnp.sum(jax.nn.one_hot(idx, cfg.num_experts) * gates[..., None], axis=1)
+    every = jnp.einsum("teh,ehd->ted", jax.nn.silu(jnp.einsum("td,edh->teh", flat, wg))
+                       * jnp.einsum("td,edh->teh", flat, wu), wd)
+    return jnp.sum(every * weight[..., None], axis=1).reshape(x.shape)
+
+
+class TestDropless:
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_equals_a_dense_masked_sum_forward_and_every_gradient(self, norm):
+        cfg = dataclasses.replace(OLMOE_TINY, norm_topk_prob=norm)
+        args = _layer_weights(cfg)
+        target = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+        def ours(*a):
+            return jnp.sum(moe_ffn(*a, cfg)[0] * target)
+
+        def plain(*a):
+            return jnp.sum(_dense_masked_sum(*a, cfg) * target)
+
+        np.testing.assert_allclose(np.asarray(moe_ffn(*args, cfg)[0]),
+                                   np.asarray(_dense_masked_sum(*args, cfg)),
+                                   rtol=1e-5, atol=1e-5)
+        got = jax.grad(ours, argnums=(0, 1, 2, 3, 4))(*args)
+        want = jax.grad(plain, argnums=(0, 1, 2, 3, 4))(*args)
+        for name, g, w in zip(("x", "router", "w_gate", "w_up", "w_down"), got, want):
+            scale = float(jnp.abs(w).max())
+            assert float(jnp.abs(g - w).max()) <= 1e-5 * max(scale, 1.0), name
+
+    def test_gates_are_not_renormalised_unless_asked(self):
+        """norm_topk_prob False: a token's output is its renormalised output
+        times the sum of its chosen probabilities (under 1)."""
+        args = _layer_weights(OLMOE_TINY)
+        raw, _ = moe_ffn(*args, OLMOE_TINY)
+        normed, _ = moe_ffn(*args, dataclasses.replace(OLMOE_TINY, norm_topk_prob=True))
+        probs = jax.nn.softmax(args[0].reshape(-1, args[0].shape[-1]) @ args[1], -1)
+        mass = jnp.sum(jax.lax.top_k(probs, OLMOE_TINY.top_k)[0], -1).reshape(2, 16, 1)
+        assert float(mass.max()) < 0.999
+        np.testing.assert_allclose(np.asarray(raw), np.asarray(normed * mass),
+                                   rtol=1e-5, atol=1e-6)
+
+    def test_every_token_to_one_expert_drops_nothing(self):
+        """All 32 tokens on expert 2: the dropless output is that expert's
+        for every token; the capacity path at the same load keeps 5."""
+        cfg = dataclasses.replace(OLMOE_TINY, top_k=1, norm_topk_prob=True)
+        x, router, wg, wu, wd = _layer_weights(cfg)
+        routing = jnp.full((32, 1), 2, jnp.int32)
+        out, stats = moe_ffn(x, router, wg, wu, wd, cfg, routing=routing)
+        dense = (jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(dense), rtol=1e-5, atol=1e-5)
+        assert stats["counts"].tolist() == [0, 0, 32, 0, 0, 0, 0, 0]
+        capped = dataclasses.replace(cfg, capacity_factor=1.25)
+        dropped, _ = moe_ffn(x, router, wg, wu, wd, capped, routing=routing)
+        kept = np.asarray(jnp.abs(dropped).sum(-1) > 0).reshape(-1)
+        assert kept.sum() == capped.capacity(32) == 5
+
+    def test_stats_count_every_choice(self):
+        args = _layer_weights(OLMOE_TINY)
+        _, stats = moe_ffn(*args, OLMOE_TINY)
+        assert float(stats["counts"].sum()) == 32 * OLMOE_TINY.top_k
+        assert stats["routing"].shape == (32, OLMOE_TINY.top_k)
+        assert bool((stats["p_kth"] >= stats["p_next"]).all())
+        np.testing.assert_allclose(float(stats["prob_sum"].sum()), 32.0, rtol=1e-5)
+
+    def test_load_balancing_loss_is_the_published_one(self):
+        """load_balancing_loss_func: layers concatenated, every choice
+        counted: E * sum_e mean(one_hot)[k, e] * mean(probs)[e]."""
+        rng = np.random.RandomState(0)
+        L, T, E, k = 3, 40, 8, 4
+        probs = jax.nn.softmax(jnp.asarray(rng.randn(L, T, E), jnp.float32), -1)
+        idx = jax.lax.top_k(probs, k)[1]
+        mask = jax.nn.one_hot(idx.reshape(L * T, k), E)
+        want = E * jnp.sum(jnp.mean(mask, 0) * jnp.mean(probs.reshape(L * T, E), 0)[None])
+        counts = jnp.sum(jax.nn.one_hot(idx, E), axis=(1, 2))
+        got = load_balancing_loss(counts, jnp.sum(probs, axis=1), T)
+        assert float(got) == pytest.approx(float(want), rel=1e-6)
+        even = load_balancing_loss(jnp.full((L, E), T * k / E), jnp.full((L, E), T / E), T)
+        assert float(even) == pytest.approx(k)
+
+
+class TestOlmoeModel:
+    def test_replaying_its_own_free_routing_is_bitwise_the_free_run(self):
+        cfg = OLMOE_TINY
+        params = moe_init(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+        logits, aux, stats = moe_forward(params, toks, cfg)
+        assert stats["routing"].shape == (cfg.n_layers, 32, cfg.top_k)
+        again, aux2, _ = moe_forward(params, toks, cfg, routing=stats["routing"])
+        assert bool((logits == again).all()) and float(aux) == float(aux2)
+        # another routing is another function
+        other = (stats["routing"] + 1) % cfg.num_experts
+        assert not bool((moe_forward(params, toks, cfg, routing=other)[0] == logits).all())
+
+    def test_replay_keeps_the_routers_gradient(self):
+        cfg = OLMOE_TINY
+        params = moe_init(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+        (_, stats), free = jax.value_and_grad(moe_loss_and_stats, has_aux=True)(
+            params, toks, toks, cfg)
+        replayed = jax.grad(moe_loss)(params, toks, toks, cfg, routing=stats["routing"])
+        np.testing.assert_array_equal(np.asarray(free["layers"]["router"]),
+                                      np.asarray(replayed["layers"]["router"]))
+        assert float(jnp.abs(free["layers"]["router"]).max()) > 0
+        assert float(stats["load_max_over_mean"]) >= 1.0
+        assert float(stats["aux_loss"]) >= cfg.top_k - 1e-4
+
+    def test_qk_norm_is_over_the_whole_projection(self):
+        """RMSNorm ignores a scale of its whole input, not of a part: with
+        QK-norm on, scaling all of wq leaves the logits, scaling one head's
+        columns moves them (a norm per head would not see it); with it off
+        both move them."""
+        cfg = dataclasses.replace(OLMOE_TINY, n_layers=1)
+        params = moe_init(jax.random.PRNGKey(0), cfg)
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+
+        def logits(p, c):
+            return moe_forward(p, toks, c, remat="none")[0]
+
+        def scaled(cols):
+            wq = params["layers"]["wq"].at[:, :, cols].multiply(3.0)
+            return {**params, "layers": {**params["layers"], "wq": wq}}
+
+        base = logits(params, cfg)
+        np.testing.assert_allclose(np.asarray(logits(scaled(slice(None)), cfg)),
+                                   np.asarray(base), rtol=2e-4, atol=2e-5)
+        one_head = logits(scaled(slice(0, cfg.head_dim)), cfg)
+        assert float(jnp.abs(one_head - base).max()) > 1e-3
+        off = dataclasses.replace(cfg, qk_norm=False)
+        assert "q_norm" not in moe_init(jax.random.PRNGKey(0), off)["layers"]
+        assert float(jnp.abs(logits(scaled(slice(None)), off) - logits(params, off)).max()) > 1e-3
+        assert set(moe_param_specs(cfg)["layers"]) == set(params["layers"])
+
+    def test_dropless_refuses_expert_parallelism(self):
+        from torchft_tpu.parallel.mesh import make_hsdp_mesh, shard_params
+
+        cfg = OLMOE_TINY
+        mesh = make_hsdp_mesh(dp=1, fsdp=1, ep=2, sp=1, tp=1)
+        with pytest.raises(ValueError, match="ep=1"):
+            moe_param_specs(cfg, mesh)
+        params = shard_params(moe_init(jax.random.PRNGKey(0), cfg), mesh, moe_param_specs(cfg))
+        toks = jnp.zeros((2, 16), jnp.int32)
+        with pytest.raises(ValueError, match="not sharded over ep"):
+            moe_loss(params, toks, toks, cfg)
+        assert moe_param_specs(cfg, make_hsdp_mesh(dp=1, fsdp=2, ep=1, sp=1, tp=1))
+        assert moe_param_specs(MOE_CONFIGS["debug"], mesh)  # the capacity path may
+
+    def test_the_published_preset_and_the_one_mapping(self):
+        from torchft_tpu.models import CONFIGS, model_fns
+        from torchft_tpu.models.llama import llama_init
+        from torchft_tpu.parallel.mesh import llama_param_specs
+
+        cfg = CONFIGS["olmoe_1b_7b"]
+        assert cfg is MOE_CONFIGS["olmoe_1b_7b"] and CONFIGS["moe_debug"] is MOE_CONFIGS["debug"]
+        assert (cfg.dim, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.ffn_hidden,
+                cfg.num_experts, cfg.top_k, cfg.vocab_size) == (2048, 16, 16, 16, 1024, 64, 8, 50304)
+        assert cfg.capacity_factor is None and not cfg.norm_topk_prob and cfg.qk_norm
+        assert 6.9e9 < cfg.num_params() < 6.93e9
+        init, loss, specs = model_fns(cfg)
+        assert init is moe_init and specs is moe_param_specs
+        init, dense_loss, specs = model_fns(CONFIGS["debug"])
+        assert init is llama_init and specs is llama_param_specs
+        tiny = CONFIGS["debug"]
+        toks = jnp.zeros((1, 8), jnp.int32)
+        value, stats = dense_loss(llama_init(jax.random.PRNGKey(0), tiny), toks, toks, tiny)
+        assert stats == {} and np.isfinite(float(value))
+        value, stats = loss(moe_init(jax.random.PRNGKey(0), OLMOE_TINY), toks, toks, OLMOE_TINY)
+        assert sorted(stats["moe_stats"]) == ["moe_aux_loss", "moe_load_max_over_mean"]

@@ -1,0 +1,48 @@
+"""The one trainer, examples/train_llama_hsdp.py, runs whatever kind the
+``--config`` preset is: the same line for a dense and for an MoE preset, two
+committed steps each under the launcher's lighthouse."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _train(config: str, tmp_path) -> dict:
+    env = {**os.environ, "PYTHONPATH": ROOT,
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jc")}
+    out = subprocess.run(
+        [sys.executable, "-m", "torchft_tpu.launcher",
+         os.path.join(ROOT, "examples", "train_llama_hsdp.py"),
+         "--replica-groups", "1", "--", "--config", config, "--batch-size", "2",
+         "--seq-len", "32", "--steps", "2", "--virtual-chips", "1"],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, (out.stdout + out.stderr)[-3000:]
+    line = next(ln for ln in out.stdout.splitlines() if " SUMMARY " in ln)
+    return json.loads(line.split(" SUMMARY ", 1)[1])
+
+
+@pytest.mark.parametrize("config,kind", [("moe_debug", "moe"), ("debug", "dense")])
+def test_two_committed_steps_under_a_lighthouse(config, kind, tmp_path):
+    s = _train(config, tmp_path)
+    assert s["config"] == config and s["committed"] == 2 and s["discarded"] == 0
+    assert s["state_on_device"] and s["reduced_on_device"]
+    assert len(s["losses"]) == 2 and all(5.0 < x < 7.0 for x in s["losses"])  # ln 256
+    if kind == "moe":
+        assert sorted(s["model_stats"]) == ["moe_aux_loss", "moe_load_max_over_mean"]
+        assert all(len(v) == 2 for v in s["model_stats"].values())
+        assert all(v >= 1.0 for v in s["model_stats"]["moe_load_max_over_mean"])
+    else:
+        assert s["model_stats"] == {}
+
+
+def test_the_trainer_names_no_model_function():
+    text = open(os.path.join(ROOT, "examples", "train_llama_hsdp.py")).read()
+    for name in ("llama_init", "llama_loss", "llama_param_specs", "moe_init",
+                 "moe_loss", "moe_param_specs"):
+        assert name not in text, name
+    assert "model_fns(cfg)" in text

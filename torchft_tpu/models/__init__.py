@@ -1,8 +1,53 @@
+from typing import Any, Callable, Dict, Tuple
+
 from torchft_tpu.models.llama import (
+    CONFIGS,
     LlamaConfig,
     llama_forward,
     llama_init,
     llama_loss,
 )
 
-__all__ = ["LlamaConfig", "llama_init", "llama_forward", "llama_loss"]
+__all__ = ["LlamaConfig", "llama_init", "llama_forward", "llama_loss",
+           "CONFIGS", "model_fns"]
+
+
+def model_fns(cfg: LlamaConfig) -> Tuple[Callable, Callable, Callable]:
+    """A configuration object's ``(init, loss, param_specs)``, by its kind:
+    the one place a trainer learns which model it runs.
+
+    ``init(key, cfg)`` -> parameter pytree; ``param_specs(cfg)`` -> its
+    PartitionSpecs; ``loss(params, tokens, targets, cfg, attention_fn=,
+    remat=)`` -> ``(loss, stats)`` for ``value_and_grad(has_aux=True)``,
+    where ``stats`` maps the name of a trace instant to the device scalars a
+    training loop fetches beside the loss ({} for a dense model)."""
+    from torchft_tpu.models.moe import (
+        MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs)
+    from torchft_tpu.parallel.mesh import llama_param_specs
+
+    if isinstance(cfg, MoEConfig):
+        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+            value, stats = moe_loss_and_stats(*args, **kw)
+            return value, {"moe_stats": {
+                "moe_load_max_over_mean": stats["load_max_over_mean"],
+                "moe_aux_loss": stats["aux_loss"]}}
+
+        return moe_init, loss, moe_param_specs
+
+    def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+        return llama_loss(*args, **kw), {}
+
+    return llama_init, loss, llama_param_specs
+
+
+def _register_moe_presets() -> None:
+    """``CONFIGS`` is the registry ``--config`` reads: the MoE presets stand
+    in it beside the dense ones (an MoEConfig is a LlamaConfig) under their
+    own names; ``debug`` is taken, so the MoE one is ``moe_debug``."""
+    from torchft_tpu.models.moe import MOE_CONFIGS
+
+    for name, cfg in MOE_CONFIGS.items():
+        CONFIGS.setdefault("moe_debug" if name == "debug" else name, cfg)
+
+
+_register_moe_presets()

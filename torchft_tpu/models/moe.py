@@ -6,17 +6,30 @@ the sparse-FFN transformer is the standard way to scale params without
 scaling per-token FLOPs, and TPU meshes make expert parallelism a natural
 axis.
 
+Two dispatch paths, chosen by ``MoEConfig.capacity_factor``, both with
+static shapes (nothing compiles twice when the load shifts):
+- **A number: the capacity path. It DROPS.** GShard-style: every expert
+  processes exactly ``capacity`` token slots per step, and a token that finds
+  its expert's queue full loses that expert's term (it falls through on the
+  residual). The presets ``debug`` and ``bench_moe`` and the ``ep`` axis use
+  it.
+- **``None``: the dropless path. It drops NOTHING, at any load.** The
+  ``T * top_k`` (token, choice) pairs are sorted by expert and the three
+  projections run as grouped matrix multiplications over those rows with the
+  per-expert counts as group sizes (OLMoE's block, ``olmoe_1b_7b``). All the
+  experts live on one device: ``ep`` > 1 is refused for it.
+
 TPU-first design:
-- **Static shapes everywhere.** GShard-style capacity-based dispatch: every
-  expert processes exactly ``capacity`` token slots per step; routing is
-  one-hot einsums (dense, MXU-tileable), never gather/scatter with
-  data-dependent shapes. Overflowing tokens fall through on the residual.
 - **Expert parallelism as a mesh axis.** Expert weights carry ``ep`` in
   their PartitionSpec (leading E dim); when the dispatched activations
   [E, C, d] are sharded over ``ep``, XLA inserts the all-to-alls — no manual
   collective code.
 - **Router in f32** (probabilities and cumsum position math need it),
   payload matmuls in bf16.
+- **Routing replay.** ``routing=`` (per layer ``[T, top_k]`` expert indices)
+  makes the block use the given experts with this model's own probabilities
+  at them as gates, so the router's gradient flows as in a free run; the
+  routing the model would have chosen freely comes back beside it.
 - Attention/norms/RoPE reuse the dense Llama blocks, including the Pallas
   flash-attention path.
 """
@@ -24,6 +37,8 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import math
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -40,22 +55,38 @@ __all__ = [
     "moe_init",
     "moe_forward",
     "moe_loss",
+    "moe_loss_and_stats",
     "moe_param_specs",
     "moe_ffn",
+    "load_balancing_loss",
 ]
+
+
+ROUTER_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(LlamaConfig):
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # slots per expert as a multiple of the even share; None: dropless
+    capacity_factor: Optional[float] = 1.25
     aux_loss_weight: float = 0.01
+    norm_topk_prob: bool = True  # gates renormalised over the chosen experts
+    qk_norm: bool = False  # RMSNorm over the whole q and k projections
 
     def capacity(self, tokens: int) -> int:
         """Slots per expert for a batch of ``tokens`` (static given shapes)."""
         c = int(self.capacity_factor * tokens * self.top_k / self.num_experts)
         return max(c, self.top_k)
+
+    def num_params(self) -> int:
+        d, h, v, L = self.dim, self.ffn_hidden, self.vocab_size, self.n_layers
+        kv = self.n_kv_heads * self.head_dim
+        per_layer = (2 * d * d + 2 * d * kv + 2 * d + d * self.num_experts
+                     + 3 * self.num_experts * d * h
+                     + (d + kv if self.qk_norm else 0))
+        return L * per_layer + 2 * v * d + d
 
 
 MOE_CONFIGS: Dict[str, MoEConfig] = {
@@ -68,6 +99,14 @@ MOE_CONFIGS: Dict[str, MoEConfig] = {
     "bench_moe": MoEConfig(
         vocab_size=32000, dim=1024, n_layers=24, n_heads=16, n_kv_heads=8,
         ffn_hidden=2816, max_seq_len=2048, num_experts=8, top_k=2,
+    ),
+    # allenai/OLMoE-1B-7B-0125-Instruct as published: 64 experts of width
+    # 1024, 8 a token, gates not renormalised, QK-norm, no token dropped
+    "olmoe_1b_7b": MoEConfig(
+        vocab_size=50304, dim=2048, n_layers=16, n_heads=16, n_kv_heads=16,
+        ffn_hidden=1024, max_seq_len=4096, rope_theta=10000.0,
+        num_experts=64, top_k=8, capacity_factor=None, norm_topk_prob=False,
+        qk_norm=True,
     ),
 }
 
@@ -99,6 +138,9 @@ def moe_init(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
         "w_up": dense_init(ks[6], (L, E, d, H), d),
         "w_down": dense_init(ks[7], (L, E, H, d), H),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, cfg.n_heads * hd), cfg.dtype)
+        layers["k_norm"] = jnp.ones((L, kvd), cfg.dtype)
     return {
         "embed": dense_init(k_emb, (cfg.vocab_size, d), d),
         "layers": layers,
@@ -107,50 +149,62 @@ def moe_init(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
     }
 
 
-def _route(
-    probs: jax.Array, top_k: int, capacity: int
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """GShard top-k routing with per-expert capacity.
+def _choose(
+    probs: jax.Array, cfg: MoEConfig, routing: Optional[jax.Array]
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """The experts each token uses and their gates. probs: [T, E] f32.
 
-    probs: [T, E] f32. Returns (gates [T,k] f32, idx [T,k] int32,
-    pos [T,k] int32 queue position, within [T,k] bool, aux_loss scalar).
-    Slot 0 has queue priority over slot 1, earlier tokens over later — all
-    dense cumsums/one-hots over [T, E], static shapes, no sorting. The
-    [T, E, C] routing tensors are never materialized (at training shapes
-    they would dwarf the activations); dispatch is scatter/gather in
-    :func:`moe_ffn`.
+    Returns (gates [T,k] f32, idx [T,k] int32, free). ``idx`` is ``routing``
+    where one is given (replay) and the top-k of ``probs`` otherwise; the
+    gates are this model's own probabilities at ``idx`` either way, so the
+    router's gradient flows the same. ``free``: what the model would have
+    chosen by itself (``routing`` [T,k]) and the probabilities of its k-th
+    and (k+1)-th choice (``p_kth``, ``p_next``: how near a tie the decision
+    was; equal where there is no (k+1)-th expert).
     """
-    T, E = probs.shape
-    gates, idx = jax.lax.top_k(probs, top_k)  # [T, k]
-    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+    k = cfg.top_k
+    top_p, top_i = jax.lax.top_k(probs, min(k + 1, cfg.num_experts))
+    free = {"routing": top_i[:, :k], "p_kth": top_p[:, k - 1],
+            "p_next": top_p[:, -1]}
+    idx = free["routing"] if routing is None else routing.astype(jnp.int32)
+    gates = jnp.take_along_axis(probs, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+    return gates, idx, free
 
+
+def _queue_positions(
+    idx: jax.Array, num_experts: int, capacity: int
+) -> Tuple[jax.Array, jax.Array]:
+    """Capacity path: each (token, choice)'s place in its expert's queue.
+
+    Returns (pos [T,k] int32, within [T,k] bool). Slot 0 has queue priority
+    over slot 1, earlier tokens over later — all dense cumsums/one-hots over
+    [T, E], static shapes, no sorting. The [T, E, C] routing tensors are
+    never materialized (at training shapes they would dwarf the
+    activations); dispatch is scatter/gather in :func:`moe_ffn`.
+    """
     pos_cols = []
-    within_cols = []
-    counts = jnp.zeros((E,), jnp.int32)
-    for j in range(top_k):  # static, small
-        mask = jax.nn.one_hot(idx[:, j], E, dtype=jnp.int32)  # [T, E]
+    counts = jnp.zeros((num_experts,), jnp.int32)
+    for j in range(idx.shape[1]):  # static, small
+        mask = jax.nn.one_hot(idx[:, j], num_experts, dtype=jnp.int32)  # [T, E]
         pos = jnp.cumsum(mask, axis=0) - 1 + counts[None, :]
         counts = counts + jnp.sum(mask, axis=0)
-        pos_tok = jnp.sum(pos * mask, axis=-1)  # [T]
-        pos_cols.append(pos_tok)
-        within_cols.append(pos_tok < capacity)
+        pos_cols.append(jnp.sum(pos * mask, axis=-1))  # [T]
     pos = jnp.stack(pos_cols, axis=1)
-    within = jnp.stack(within_cols, axis=1)
-
-    # Switch-style load-balancing loss: E * sum_e f_e * P_e
-    f = jnp.mean(jax.nn.one_hot(idx[:, 0], E), axis=0)  # dispatch fraction
-    p = jnp.mean(probs, axis=0)  # mean router prob
-    aux = E * jnp.sum(f * p)
-    return gates, idx, pos, within, aux
+    return pos, pos < capacity
 
 
 def _top_k_dispatch(
     probs: jax.Array, top_k: int, capacity: int
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Dense [T, E, C] combine/dispatch tensors built from :func:`_route` —
-    test/reference form only; the model uses the scatter/gather path."""
+) -> Tuple[jax.Array, jax.Array]:
+    """Dense [T, E, C] combine/dispatch tensors of GShard top-k routing with
+    per-expert capacity, gates renormalised — test/reference form only; the
+    model uses the scatter/gather path (:func:`_capacity_ffn`)."""
     T, E = probs.shape
-    gates, idx, pos, within, aux = _route(probs, top_k, capacity)
+    gates, idx = jax.lax.top_k(probs, top_k)  # [T, k]
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+    pos, within = _queue_positions(idx, E, capacity)
     combine = jnp.zeros((T, E, capacity), jnp.float32)
     for j in range(top_k):
         combine = combine + (
@@ -160,7 +214,104 @@ def _top_k_dispatch(
             * jax.nn.one_hot(pos[:, j], capacity)[:, None, :]
         )
     dispatch = (combine > 0).astype(jnp.float32)
-    return combine, dispatch, aux
+    return combine, dispatch
+
+
+def _capacity_ffn(flat, gates, idx, w_gate, w_up, w_down, capacity):
+    """The capacity path: scatter-add into the [E*C, d] expert slot buffer,
+    batched [E, C, d] x [E, d, h] expert matmuls on the MXU (the ``ep``
+    sharding of the E dim is where XLA inserts the all-to-alls), gather
+    back — O(T*d) routing memory. A choice past its expert's capacity is
+    dropped."""
+    (T, d), E, C = flat.shape, w_gate.shape[0], capacity
+    pos, within = _queue_positions(idx, E, C)
+    # slot id in the flattened [E*C] expert queue; out-of-capacity tokens are
+    # parked on slot 0 with zero weight (mode="drop" would also work, but an
+    # explicit zero weight keeps the gradient story obvious)
+    slots = idx * C + jnp.minimum(pos, C - 1)  # [T, k]
+    keep = within.astype(flat.dtype)  # [T, k]
+
+    buf = jnp.zeros((E * C, d), flat.dtype)
+    for j in range(idx.shape[1]):
+        buf = buf.at[slots[:, j]].add(flat * keep[:, j, None])
+    expert_in = buf.reshape(E, C, d)
+
+    h = jax.nn.silu(jnp.einsum("ecd,edh->ech", expert_in, w_gate)) * jnp.einsum(
+        "ecd,edh->ech", expert_in, w_up
+    )
+    expert_out = jnp.einsum("ech,ehd->ecd", h, w_down).reshape(E * C, d)
+
+    out = jnp.zeros((T, d), flat.dtype)
+    for j in range(idx.shape[1]):
+        w = (gates[:, j].astype(flat.dtype) * keep[:, j])[:, None]
+        out = out + expert_out[slots[:, j]] * w
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, take, back, fan):
+    """``x[take]`` whose backward pass is a gather too: ``back`` lists, for
+    each row of ``x`` in turn, the ``fan`` rows of the result that came from
+    it (for a permutation, its inverse), so the cotangent is gathered and
+    summed in groups of ``fan`` where XLA would scatter-add."""
+    return x[take]
+
+
+def _take_rows_fwd(x, take, back, fan):
+    return x[take], back
+
+
+def _take_rows_bwd(fan, back, g):
+    g = g[back]
+    if fan > 1:
+        g = g.reshape(-1, fan, g.shape[-1]).sum(axis=1)
+    return g, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _grouped_matmul(rows, weights, sizes):
+    """rows [M, a] sorted by group, weights [E, a, b], sizes [E] -> [M, b]:
+    each row times its group's matrix, float32 accumulation inside the
+    kernel. ``megablox.gmm`` (a Pallas kernel with its own VJP: one more
+    grouped product for the rows' cotangent, a transposed one for the
+    weights'); tiles of 512 rows x 1024 x 1024 measured 2.5x
+    ``lax.ragged_dot`` at OLMoE's shape on a v5e, and the kernel's default
+    128-cubed tiles 14x slower than these (PERF.md section 6, PR 28). Off the
+    TPU the kernel runs interpreted. Imported here: a dense model's process
+    never pays for loading Pallas' TPU kernels."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    (m, a), b = rows.shape, weights.shape[2]
+    tiling = (math.gcd(m, 512), min(a, 1024), min(b, 1024))
+    return gmm(rows, weights, sizes, rows.dtype, tiling,
+               interpret=jax.default_backend() != "tpu")
+
+
+def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
+    """The dropless path: the T*k (token, choice) pairs sorted by expert
+    (stable: an expert sees its tokens in order), one grouped matrix
+    multiplication per projection over the T*k rows with the per-expert
+    counts as group sizes, unsorted, weighted by the gates and summed over
+    the k choices. Every shape is static (T*k rows whatever the load); no
+    pair is dropped, also when every token picks the same expert.
+    ``sizes`` [E] int32: the pairs each expert was given."""
+    (T, d), k = flat.shape, idx.shape[1]
+    with jax.named_scope("moe/route"):
+        expert_of = idx.reshape(T * k)
+        order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32), unique_indices=True)
+    with jax.named_scope("moe/dispatch"):
+        rows = _take_rows(flat, order // k, inverse, k)  # [T*k, d]
+    with jax.named_scope("moe/experts"):
+        h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(
+            rows, w_up, sizes)
+        rows = _grouped_matmul(h, w_down, sizes)
+    with jax.named_scope("moe/combine"):
+        picked = _take_rows(rows, inverse, order, 1).reshape(T, k, d)
+        return jnp.sum(picked * gates[..., None].astype(flat.dtype), axis=1)
 
 
 def moe_ffn(
@@ -170,46 +321,50 @@ def moe_ffn(
     w_up: jax.Array,
     w_down: jax.Array,
     cfg: MoEConfig,
-) -> Tuple[jax.Array, jax.Array]:
-    """Sparse SwiGLU FFN. x: [B, S, d] -> ([B, S, d], aux_loss).
+    routing: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Sparse SwiGLU FFN. x: [B, S, d] -> ([B, S, d], stats).
 
-    Dispatch is a scatter-add into the [E*C, d] expert slot buffer and
-    combine is a gather back — O(T*d) routing memory (a dense [T, E, C]
-    one-hot einsum would be gigabytes at training shapes). The batched
-    [E, C, d] x [E, d, h] expert matmuls stay on the MXU, and the ``ep``
-    sharding of the E dim is where XLA inserts the all-to-alls.
+    ``routing`` ([T, k] expert indices, T = B*S): replay these choices
+    instead of the router's own (:func:`_choose`). ``stats``: ``counts``
+    [E] (pairs each expert was given under the routing in effect, dropped
+    ones included), ``prob_sum`` [E] (the router's probabilities summed
+    over tokens: the differentiable half of the auxiliary loss) and the
+    free routing with its margins (``routing``, ``p_kth``, ``p_next``).
     """
     B, S, d = x.shape
     T = B * S
-    C = cfg.capacity(T)
     flat = x.reshape(T, d)
 
-    logits = flat.astype(jnp.float32) @ router  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx, pos, within, aux = _route(probs, cfg.top_k, C)
+    with jax.named_scope("moe/route"):
+        # float32 in earnest: a TPU multiplies f32 matrices in one bf16 pass
+        # unless told otherwise, and a router's decisions sit on near-ties
+        logits = jnp.matmul(flat.astype(jnp.float32), router,
+                            precision=ROUTER_PRECISION)  # [T, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx, free = _choose(probs, cfg, routing)
+        sizes = jnp.zeros((cfg.num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+    payload = flat.astype(w_gate.dtype)  # the router saw x as it came
+    if cfg.capacity_factor is None:
+        out = _dropless_ffn(payload, gates, idx, sizes, w_gate, w_up, w_down)
+    else:
+        out = _capacity_ffn(payload, gates, idx, w_gate, w_up, w_down, cfg.capacity(T))
+    stats = {"counts": sizes.astype(jnp.float32), "prob_sum": jnp.sum(probs, axis=0),
+             **free}
+    return out.astype(x.dtype).reshape(B, S, d), stats
 
-    E = cfg.num_experts
-    # slot id in the flattened [E*C] expert queue; out-of-capacity tokens are
-    # parked on slot 0 with zero weight (mode="drop" would also work, but an
-    # explicit zero weight keeps the gradient story obvious)
-    slots = idx * C + jnp.minimum(pos, C - 1)  # [T, k]
-    keep = within.astype(x.dtype)  # [T, k]
 
-    buf = jnp.zeros((E * C, d), x.dtype)
-    for j in range(cfg.top_k):
-        buf = buf.at[slots[:, j]].add(flat * keep[:, j, None])
-    expert_in = buf.reshape(E, C, d)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edh->ech", expert_in, w_gate)) * jnp.einsum(
-        "ecd,edh->ech", expert_in, w_up
-    )
-    expert_out = jnp.einsum("ech,ehd->ecd", h, w_down).reshape(E * C, d)
-
-    out = jnp.zeros((T, d), x.dtype)
-    for j in range(cfg.top_k):
-        w = (gates[:, j].astype(x.dtype) * keep[:, j])[:, None]
-        out = out + expert_out[slots[:, j]] * w
-    return out.reshape(B, S, d), aux
+def load_balancing_loss(counts: jax.Array, prob_sum: jax.Array, tokens: int) -> jax.Array:
+    """``load_balancing_loss_func`` of the published Mixtral / OLMoE
+    modelling code: the layers' router outputs are concatenated, every one
+    of a token's k choices counts, and the value is E * sum_e f_e * P_e with
+    f_e the share of (layer, token) pairs that chose expert e among their k
+    (sums to k) and P_e the mean probability of e. counts, prob_sum: [L, E]
+    per layer; ``tokens`` per layer. Even load gives k."""
+    n = counts.shape[0] * tokens
+    f = jnp.sum(counts, axis=0) / n
+    p = jnp.sum(prob_sum, axis=0) / n
+    return counts.shape[1] * jnp.sum(jax.lax.stop_gradient(f) * p)
 
 
 def moe_forward(
@@ -218,22 +373,33 @@ def moe_forward(
     cfg: MoEConfig,
     attention_fn: Optional[Any] = None,
     remat: Any = True,
-) -> Tuple[jax.Array, jax.Array]:
-    """tokens int32 [B, S] -> (logits f32 [B, S, V], total aux loss).
+    routing: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """tokens int32 [B, S] -> (logits f32 [B, S, V], aux loss, stats).
 
     ``remat`` takes the shared modes ("none"/"dots"/"attn"/"full" or bool aliases;
     torchft_tpu.models.remat). Default full remat: MoE layers hold per-expert
-    activations, so the conservative mode is the safe default."""
+    activations, so the conservative mode is the safe default.
+
+    ``routing`` [L, B*S, k]: the experts to use in each layer (replay).
+    ``stats``, per layer: the routing the model would have chosen freely
+    (``routing`` [L,T,k], ``p_kth``, ``p_next`` [L,T]) and the ``counts``
+    [L,E] each expert was given (XLA drops what a caller does not use)."""
     attention = attention_fn or _attention
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     h = params["embed"][tokens]
+    _refuse_dropless_ep(cfg, _sharded_axes(params["layers"]["w_gate"]))
 
-    def layer(carry, layer_params):
-        h, aux_acc = carry
+    def layer(h, xs):
+        layer_params, replay = xs
         x = _rmsnorm(h, layer_params["attn_norm"], cfg.norm_eps)
-        q = (x @ layer_params["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
-        k = (x @ layer_params["wk"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        q, k = x @ layer_params["wq"], x @ layer_params["wk"]
+        if cfg.qk_norm:  # over the whole projection, before the heads split
+            q = _rmsnorm(q, layer_params["q_norm"], cfg.norm_eps)
+            k = _rmsnorm(k, layer_params["k_norm"], cfg.norm_eps)
+        q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
         v = (x @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
         q = _rope(q, cfg.rope_theta, positions)
         k = _rope(k, cfg.rope_theta, positions)
@@ -242,60 +408,107 @@ def moe_forward(
         ).reshape(B, S, cfg.n_heads * cfg.head_dim)
         h = h + attn @ layer_params["wo"]
         x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
-        moe_out, aux = moe_ffn(
+        moe_out, stats = moe_ffn(
             x,
             layer_params["router"],
             layer_params["w_gate"],
             layer_params["w_up"],
             layer_params["w_down"],
             cfg,
+            routing=replay,
         )
-        return (h + moe_out, aux_acc + aux), None
+        return h + moe_out, stats
 
     body = remat_wrap(layer, remat)
-    (h, aux_total), _ = jax.lax.scan(body, (h, jnp.zeros((), jnp.float32)), params["layers"])
+    h, stats = jax.lax.scan(body, h, (params["layers"], routing))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = (h @ params["lm_head"]).astype(jnp.float32)
-    return logits, aux_total / cfg.n_layers
+    aux = load_balancing_loss(stats["counts"], stats.pop("prob_sum"), B * S)
+    return logits, aux, stats
 
 
-def moe_loss(
+def _sharded_axes(x: Any) -> Tuple[str, ...]:
+    """Mesh axis names of size > 1 a concrete array is sharded over (none
+    for a tracer or an array without a named sharding)."""
+    sharding = getattr(x, "sharding", None)
+    spec = getattr(sharding, "spec", None) or ()
+    return tuple(a for part in spec if part
+                 for a in (part if isinstance(part, tuple) else (part,))
+                 if sharding.mesh.shape[a] > 1)
+
+
+def _refuse_dropless_ep(cfg: MoEConfig, axes: Any) -> None:
+    if cfg.capacity_factor is None and "ep" in axes:
+        raise ValueError(
+            "the dropless path (capacity_factor=None) keeps every expert on "
+            "one device: its grouped matrix multiplication is not sharded "
+            "over ep and nothing here imitates that. Give the mesh ep=1, or "
+            "the config a capacity_factor.")
+
+
+def moe_loss_and_stats(
     params: Dict[str, Any],
     tokens: jax.Array,
     targets: jax.Array,
     cfg: MoEConfig,
     attention_fn: Optional[Any] = None,
     remat: Any = True,
-) -> jax.Array:
-    """Cross-entropy (logsumexp form) + weighted load-balancing aux loss."""
-    logits, aux = moe_forward(
-        params, tokens, cfg, attention_fn=attention_fn, remat=remat
+    routing: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Cross-entropy (logsumexp form) + ``aux_loss_weight`` x the
+    load-balancing loss (:func:`load_balancing_loss`), and stats — for
+    ``value_and_grad(..., has_aux=True)``: :func:`moe_forward`'s free routing
+    and the two scalars a training loop logs: ``aux_loss`` and
+    ``load_max_over_mean`` (the busiest expert's pairs over the mean, the
+    maximum over layers: 1 is even, ``num_experts`` is everything on one
+    expert)."""
+    logits, aux, stats = moe_forward(
+        params, tokens, cfg, attention_fn=attention_fn, remat=remat,
+        routing=routing,
     )
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - tgt) + cfg.aux_loss_weight * aux
+    loss = jnp.mean(lse - tgt) + cfg.aux_loss_weight * aux
+    counts = stats.pop("counts")
+    stats["aux_loss"] = aux
+    stats["load_max_over_mean"] = jnp.max(
+        jnp.max(counts, axis=1) / jnp.mean(counts, axis=1))
+    return loss, stats
 
 
-def moe_param_specs(cfg: MoEConfig) -> Dict[str, Any]:
+def moe_loss(*args: Any, **kw: Any) -> jax.Array:
+    """:func:`moe_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
+    return moe_loss_and_stats(*args, **kw)[0]
+
+
+def moe_param_specs(cfg: MoEConfig, mesh: Optional[Any] = None) -> Dict[str, Any]:
     """PartitionSpecs for the MoE pytree: experts over ``ep``, within-expert
     dims over fsdp/tp (Megatron column/row), dense blocks as in the HSDP
-    Llama specs."""
+    Llama specs. ``mesh``, where the caller has one: a dropless config on a
+    mesh with ep > 1 is refused here, before anything is placed (inside a
+    jitted step :func:`moe_forward` sees tracers and cannot tell)."""
     from jax.sharding import PartitionSpec as P
 
+    if mesh is not None:
+        _refuse_dropless_ep(cfg, [a for a, n in mesh.shape.items() if n > 1])
+
+    layers = {
+        "attn_norm": P(None, None),
+        "wq": P(None, "fsdp", "tp"),
+        "wk": P(None, "fsdp", "tp"),
+        "wv": P(None, "fsdp", "tp"),
+        "wo": P(None, "tp", "fsdp"),
+        "ffn_norm": P(None, None),
+        "router": P(None, "fsdp", None),
+        "w_gate": P(None, "ep", "fsdp", "tp"),
+        "w_up": P(None, "ep", "fsdp", "tp"),
+        "w_down": P(None, "ep", "tp", "fsdp"),
+    }
+    if cfg.qk_norm:
+        layers["q_norm"] = layers["k_norm"] = P(None, None)
     return {
         "embed": P("fsdp", "tp"),
-        "layers": {
-            "attn_norm": P(None, None),
-            "wq": P(None, "fsdp", "tp"),
-            "wk": P(None, "fsdp", "tp"),
-            "wv": P(None, "fsdp", "tp"),
-            "wo": P(None, "tp", "fsdp"),
-            "ffn_norm": P(None, None),
-            "router": P(None, "fsdp", None),
-            "w_gate": P(None, "ep", "fsdp", "tp"),
-            "w_up": P(None, "ep", "fsdp", "tp"),
-            "w_down": P(None, "ep", "tp", "fsdp"),
-        },
+        "layers": layers,
         "final_norm": P(None),
         "lm_head": P("fsdp", "tp"),
     }

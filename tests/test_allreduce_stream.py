@@ -517,3 +517,274 @@ class TestOverlapStatsMath:
         )
         assert stats["allreduce_buckets"] == 2.0
         assert stats["allreduce_wire_s"] == pytest.approx(1.0)
+
+
+# ---------------------------------------------------------------------------
+# a device bucket is fetched, in pieces, into a buffer of the Manager's pool
+
+
+class CopyingPG(ProcessGroupDummy):
+    """World of one whose result is a copy of its input, as
+    ProcessGroupHost's is: the staging buffer is the input alone."""
+
+    def __init__(self):
+        super().__init__()
+        self.inputs = []  # every array a collective was handed, in order
+
+    def allreduce(self, arrays, op=ReduceOp.SUM):
+        arrays = list(arrays)
+        self.inputs.extend(arrays)
+        return super().allreduce([np.array(a, copy=True) for a in arrays], op)
+
+
+class GatedCopyingPG(CopyingPG):
+    """CopyingPG whose futures resolve only when the test says."""
+
+    def __init__(self):
+        super().__init__()
+        self.pending = []
+        self.dispatched = threading.Condition()
+
+    def allreduce(self, arrays, op=ReduceOp.SUM):
+        arrays = list(arrays)
+        self.inputs.extend(arrays)
+        fut = Future()
+        with self.dispatched:
+            self.pending.append(([np.array(a, copy=True) for a in arrays], fut))
+            self.dispatched.notify_all()
+        return FutureWork(fut)
+
+    def wait_dispatched(self, n):
+        with self.dispatched:
+            assert self.dispatched.wait_for(
+                lambda: len(self.pending) >= n, timeout=30
+            )
+
+    def release_all(self):
+        with self.dispatched:
+            pending, self.pending = list(self.pending), []
+        for arrays, fut in pending:
+            fut.set_result(arrays)
+
+
+_SIZES = (40, 40, 36, 36, 32, 32)
+_CAP3 = 2 * 40 * 4  # two leaves a bucket: three buckets, three pool keys
+_BUCKET_BYTES = [320, 288, 256]
+
+
+def _device_tree(dtype=np.float32, seed=5):
+    import jax
+
+    rng = np.random.RandomState(seed)
+    return {
+        f"p{i}": jax.device_put((rng.randn(size) * 3).astype(dtype))
+        for i, size in enumerate(_SIZES)
+    }
+
+
+def _pooled(m):
+    return sum(len(v) for v in m._buffer_pool._free.values())
+
+
+def _d2h_spans(m, n):
+    deadline = time.monotonic() + 5
+    while time.monotonic() < deadline:  # stage spans land at resolve
+        spans = m.tracer.export()["spans"]
+        if sum(s["name"] == "pack" for s in spans) >= n:
+            break
+        time.sleep(0.02)
+    return spans, [s for s in spans if s["name"] == "d2h"]
+
+
+class TestStagingThroughThePool:
+    def test_hit_share_is_one_from_the_second_step(self):
+        """Step 1 allocates one buffer a bucket; steps 2-6 take them back
+        from the pool: stage_pool_hit_share 1.0, BufferPool.misses still."""
+        tree = _device_tree()
+        m = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        pool = m._buffer_pool
+        want = {k: np.asarray(v) / 2 for k, v in tree.items()}
+        shares = []
+        for step in range(6):
+            out = _reduce(m, tree, streamed=True)
+            for k in tree:
+                assert np.array_equal(_bits(out[k]), _bits(want[k])), (step, k)
+            shares.append(m.timings()["stage_pool_hit_share"])
+            if step == 0:
+                assert (pool.hits, pool.misses) == (0, 3)
+            m.should_commit()
+        m.shutdown(wait=False)
+        assert shares == [0.0] + [1.0] * 5
+        assert (pool.hits, pool.misses) == (15, 3)
+        assert _pooled(m) == 3
+
+    def test_one_d2h_span_a_bucket_with_bytes_pieces_pooled(self):
+        """Pieces are arguments, never spans: the tree of a step keeps its
+        names, and a bucket of 256-320 bytes in pieces of 16 MiB is one."""
+        tree = _device_tree()
+        m = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        for _ in range(2):
+            _reduce(m, tree, streamed=True)
+            m.should_commit()
+        spans, d2h = _d2h_spans(m, 6)
+        m.shutdown(wait=False)
+        assert [s["args"]["bucket"] for s in d2h] == [0, 1, 2] * 2
+        assert [s["args"]["pooled"] for s in d2h] == [0] * 3 + [1] * 3
+        assert [s["args"]["bytes"] for s in d2h] == _BUCKET_BYTES * 2
+        assert all(s["args"]["pieces"] == 1 for s in d2h)
+        assert "queued_us" in d2h[0]["args"] and "queued_us" not in d2h[1]["args"]
+        packs = {s["id"] for s in spans if s["name"] == "pack"}
+        assert all(s["parent"] in packs for s in d2h)
+        assert {s["name"] for s in spans if s["cat"] == "allreduce"} <= {
+            "allreduce", "wait_quorum", "configure_commit_wait", "capture",
+            "pack", "grad_wait", "d2h", "dispatch", "wire", "wire_run",
+            "unpack", "h2d", "divide",
+        }
+
+    def test_many_pieces_are_still_one_span(self, monkeypatch):
+        monkeypatch.setattr(bucketing, "FETCH_PIECE_BYTES", 16 * 4)
+        tree = _device_tree()
+        m = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        out = _reduce(m, tree, streamed=True)
+        for k, v in tree.items():
+            assert np.array_equal(_bits(out[k]), _bits(np.asarray(v) / 2))
+        _spans, d2h = _d2h_spans(m, 3)
+        m.shutdown(wait=False)
+        assert [s["args"]["pieces"] for s in d2h] == [6, 6, 4]
+
+    def test_not_back_in_the_pool_while_wire_or_landing_runs(self, monkeypatch):
+        import jax
+
+        tree = _device_tree()
+        pg = GatedCopyingPG()
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        landing, may_land = threading.Event(), threading.Event()
+        device_put = jax.device_put
+
+        def slow_put(x, *a, **kw):
+            if isinstance(x, np.ndarray) and threading.current_thread().name.startswith(
+                "torchft_unpack"
+            ):
+                landing.set()
+                assert may_land.wait(30)
+            return device_put(x, *a, **kw)
+
+        m.start_quorum()
+        stream = m.allreduce_streamed(tree)
+        pg.wait_dispatched(3)  # every wire holds its staging buffer
+        assert _pooled(m) == 0
+        monkeypatch.setattr(jax, "device_put", slow_put)
+        pg.release_all()
+        assert landing.wait(30)  # bucket 0 is landing
+        assert _pooled(m) == 0
+        may_land.set()
+        stream.wait(timeout=30)
+        monkeypatch.undo()
+        m.shutdown(wait=False)
+        assert _pooled(m) == 3
+
+    def test_never_after_a_failed_bucket(self):
+        """Bucket 1's wire fails: its buffer is dropped, and so is bucket
+        2's, whose wire resolves after the op has failed. Bucket 0 landed
+        whole and may be back (it races the failure)."""
+        tree = _device_tree()
+        pg = FakeProcessGroupWrapper(CopyingPG())
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        m.start_quorum()
+        pg.report_future_error(RuntimeError("injected wire failure"),
+                               skip_ops=1)
+        out = m.allreduce_streamed(tree).wait(timeout=30)
+        assert all(not np.asarray(v).any() for v in out.values())
+        time.sleep(0.2)  # whatever lands after the failure
+        m.shutdown(wait=False)
+        back = {k for k, v in m._buffer_pool._free.items() if v}
+        assert back <= {("<f4", _BUCKET_BYTES[0] // 4)}, back
+
+    def test_never_after_a_timed_out_bucket(self):
+        """The wire resolves after the op has timed out: its buffer may
+        still be read by whatever was slow, and is dropped."""
+        tree = _device_tree()
+        pg = GatedCopyingPG()
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3,
+                         timeout=0.3)
+        m.start_quorum()
+        stream = m.allreduce_streamed(tree)
+        pg.wait_dispatched(3)
+        out = stream.wait(timeout=30)  # the stage deadline fires: zeros
+        assert all(not np.asarray(v).any() for v in out.values())
+        pg.release_all()
+        time.sleep(0.3)
+        m.shutdown(wait=False)
+        assert _pooled(m) == 0
+
+    def test_never_when_the_pg_returns_its_input(self):
+        """ProcessGroupDummy hands the staging buffer back as its result:
+        the landed leaves may alias it (a CPU device_put of a numpy view
+        copies nothing), so it stays out of the pool, every step."""
+        tree = _device_tree()
+        m = make_manager(quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        outs = []
+        for _ in range(3):
+            outs.append(_reduce(m, tree, streamed=True))
+            m.should_commit()
+        m.shutdown(wait=False)
+        assert _pooled(m) == 0 and m._buffer_pool.hits == 0
+        for out in outs:  # none overwritten by a later step
+            for k, v in tree.items():
+                assert np.array_equal(_bits(out[k]), _bits(np.asarray(v) / 2))
+
+    def test_deleting_the_leaves_after_the_call_changes_nothing(self, monkeypatch):
+        """Donation safety with the capture in pieces: the next jitted step
+        may donate (delete) the gradients as soon as allreduce() returns."""
+        monkeypatch.setattr(bucketing, "FETCH_PIECE_BYTES", 24 * 4)
+        tree = _device_tree()
+        want = {k: np.asarray(v) / 2 for k, v in tree.items()}
+        pg = GatedCopyingPG()
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        m.start_quorum()
+        stream = m.allreduce_streamed(tree)
+        for v in tree.values():
+            v.delete()
+        pg.wait_dispatched(3)
+        pg.release_all()
+        out = stream.wait(timeout=30)
+        m.shutdown(wait=False)
+        assert m.errored() is None
+        for k in want:
+            assert np.array_equal(_bits(out[k]), _bits(want[k]))
+
+    def test_two_steps_in_flight_share_no_buffer(self):
+        a, b = _device_tree(seed=1), _device_tree(seed=2)
+        pg = GatedCopyingPG()
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        # a first step fills the pool, so the two below draw from it
+        m.start_quorum()
+        warm = m.allreduce_streamed(a)
+        pg.wait_dispatched(3)
+        pg.release_all()
+        warm.wait(timeout=30)
+        m.should_commit()
+        pg.inputs.clear()
+        m.start_quorum()
+        s1 = m.allreduce_streamed(a)
+        s2 = m.allreduce_streamed(b)
+        pg.wait_dispatched(6)
+        held = list(pg.inputs)
+        assert len(held) == 6
+        for i, x in enumerate(held):
+            for y in held[i + 1:]:
+                assert not np.shares_memory(x, y)
+        # each still holds its own step's bytes
+        for x, leafs in zip(held, [("p0", "p1"), ("p2", "p3"), ("p4", "p5")] * 2):
+            src = a if x is held[0] or x is held[1] or x is held[2] else b
+            want = np.concatenate([np.asarray(src[k]) for k in leafs])
+            assert np.array_equal(x, want)
+        pg.release_all()
+        o1, o2 = s1.wait(timeout=30), s2.wait(timeout=30)
+        m.shutdown(wait=False)
+        for k in a:
+            assert np.array_equal(_bits(o1[k]), _bits(np.asarray(a[k]) / 2))
+            assert np.array_equal(_bits(o2[k]), _bits(np.asarray(b[k]) / 2))

@@ -171,3 +171,104 @@ class TestCollectiveCountGuard:
         tree = _many_leaf_tree(n=10)
         pg, _ = self._reduce(tree, bucket_cap_bytes=1 << 30)
         assert pg.total_arrays == 10
+
+
+# ---------------------------------------------------------------------------
+# the host plane's capture of a device bucket: pieces, fetched into a buffer
+
+
+def _device_leaves(shapes, dtype, seed=3):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    return [
+        jnp.asarray(np.asarray(rng.randn(*s) * 100, np.float32)).astype(dtype)
+        for s in shapes
+    ]
+
+
+# name -> (leaf shapes, piece size in ELEMENTS)
+_FETCH_CASES = {
+    "not_a_multiple_of_the_piece": ([(7,), (3, 50), (1000,), (5, 5)], 64),
+    "smaller_than_one_piece": ([(7,), (3, 5), ()], 1 << 10),
+    "exactly_one_piece": ([(16,), (4, 12)], 64),
+    "leaf_straddles_a_piece_boundary": ([(10,), (6, 40), (4, 6, 10), (9,)], 100),
+    "one_leaf_bucket": ([(40, 24)], 96),
+    "one_leaf_one_dimension": ([(1000,)], 96),
+    "row_wider_than_a_piece": ([(3, 500), (8,)], 128),
+    "an_empty_leaf": ([(0,), (33,), (2, 0), (70, 3)], 32),
+}
+
+
+class TestFetchInto:
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int32"])
+    @pytest.mark.parametrize("case", sorted(_FETCH_CASES))
+    def test_bitwise_np_asarray_of_the_concatenation(self, case, dtype):
+        """pack(piece_bytes=...) + fetch_into against np.asarray of the flat
+        that pack builds without it: same bytes, every piece within the
+        size, the pieces a partition of the bucket, each dropped once it
+        has landed."""
+        shapes, piece_elems = _FETCH_CASES[case]
+        dt = np.dtype(dtype)
+        leaves = _device_leaves(shapes, dt)
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        assert len(plan) == 1
+        want = np.asarray(bucketing.pack(leaves, plan)[0][0])
+        piece_bytes = piece_elems * dt.itemsize
+        flats, pooled = bucketing.pack(leaves, plan, piece_bytes=piece_bytes)
+        pieces = flats[0]
+        assert isinstance(pieces, bucketing.Pieces) and pooled == []
+        assert (pieces.size, pieces.dtype) == (want.size, want.dtype)
+        assert [a for a, _ in pieces.bounds] == [0] + [
+            b for _, b in pieces.bounds[:-1]
+        ]
+        assert pieces.bounds[-1][1] == want.size
+        assert all(0 < b - a <= piece_elems for a, b in pieces.bounds)
+        assert [int(x.size) for x in pieces.arrays] == [
+            b - a for a, b in pieces.bounds
+        ]
+        out = np.full(want.size, 99, dt)
+        n = bucketing.fetch_into(pieces.block_until_ready(), out)
+        assert n == len(pieces.bounds)
+        assert out.dtype == want.dtype and out.tobytes() == want.tobytes()
+        assert pieces.arrays == [None] * n
+
+    def test_pieces_are_private_copies(self):
+        """Deleting the leaves right after the capture (what a donating jit
+        step does) changes nothing: also for a one-dimensional leaf that is
+        a whole piece by itself, which the split hands through unsliced."""
+        leaves = _device_leaves([(64,), (4, 16), (5,)], np.float32)
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        want = np.asarray(bucketing.pack(leaves, plan)[0][0]).copy()
+        pieces = bucketing.pack(leaves, plan, piece_bytes=64 * 4)[0][0]
+        for leaf in leaves:
+            leaf.delete()
+        out = np.empty(want.size, want.dtype)
+        bucketing.fetch_into(pieces, out)
+        assert out.tobytes() == want.tobytes()
+
+    def test_a_large_leaf_is_cut_between_rows_and_whole_tiles(self):
+        """The device slices rows of the leaf as it lies: a cut inside a
+        row, or (where rows are many) inside a tile of 32 rows, would go
+        through a temporary the size of the leaf on the TPU."""
+        metas = [(0, 0, 1000 * 48, (1000, 48)), (1, 1000 * 48, 10, (10,))]
+        bounds = bucketing._piece_bounds(metas, 4, 100 * 48 * 4)
+        big = [(a, b) for a, b in bounds if b <= 1000 * 48]
+        assert all(a % (32 * 48) == 0 for a, _ in big)
+        assert all(b - a == 96 * 48 for a, b in big[:-1])
+        assert bounds[-1] == (1000 * 48, 1000 * 48 + 10)
+
+    def test_host_groups_are_packed_as_before(self):
+        tree = [np.arange(6, dtype=np.float32), np.ones(3, np.float32)]
+        plan = bucketing.build_plan(tree, 1 << 30)
+        pool = bucketing.BufferPool()
+        flats, pooled = bucketing.pack(tree, plan, pool=pool, piece_bytes=8)
+        assert isinstance(flats[0], np.ndarray) and pooled == [flats[0]]
+
+    def test_acquire_hit_says_whether_the_buffer_is_recycled(self):
+        pool = bucketing.BufferPool()
+        a, hit = pool.acquire_hit(8, np.float32)
+        assert not hit and (pool.hits, pool.misses) == (0, 1)
+        pool.release(a)
+        b, hit = pool.acquire_hit(8, np.float32)
+        assert hit and b is a and (pool.hits, pool.misses) == (1, 1)

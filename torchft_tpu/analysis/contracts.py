@@ -44,6 +44,10 @@ DECLARED_TIMINGS: Dict[str, str] = {
     "allreduce_unpack_s": "summed per-bucket unpack stage",
     "allreduce_buckets": "buckets in the last streamed allreduce",
     "overlap_efficiency": "fraction of wire time hidden behind other stages",
+    "stage_pool_hit_share": (
+        "device buckets of the last streamed allreduce fetched into a "
+        "recycled pool buffer, over all of them"
+    ),
     "collective_reroute": "cumulative mid-collective link reroutes",
     # control plane (two-level)
     "via_aggregator": "1 when control RPCs ride the pod aggregator",

@@ -24,6 +24,10 @@ Three pieces keep the steady-state step allocation-free:
   dispatch, no host round-trip — and the fresh buffer doubles as the
   donation-safe capture the Manager's staging path needs); any other group
   packs into a (pooled) numpy buffer.
+- :class:`Pieces` / :func:`fetch_into` — the host plane's capture of a device
+  group: the same bytes as the flat, cut on the device into pieces of at most
+  ``FETCH_PIECE_BYTES`` whose transfers start at the capture, and copied
+  piece by piece into a pooled host buffer whose pages are mapped.
 
 Bucketing is bitwise-transparent: an allreduce is elementwise across
 replicas, so packing leaves into flat buffers changes neither the reduction
@@ -33,6 +37,7 @@ bitwise green with it on or off.
 
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -45,6 +50,9 @@ __all__ = [
     "build_plan",
     "plan_for",
     "pack",
+    "Pieces",
+    "fetch_into",
+    "FETCH_PIECE_BYTES",
     "unpack",
     "unpack_bucket",
     "make_buckets",
@@ -208,15 +216,20 @@ class BufferPool:
         self.misses = 0
 
     def acquire(self, size: int, dtype: Any) -> np.ndarray:
+        return self.acquire_hit(size, dtype)[0]
+
+    def acquire_hit(self, size: int, dtype: Any) -> Tuple[np.ndarray, bool]:
+        """:meth:`acquire`, and whether the buffer is a recycled one (its
+        pages written before) rather than a new allocation."""
         dtype = np.dtype(dtype)
         key = (dtype.str, int(size))
         with self._lock:
             bucket = self._free.get(key)
             if bucket:
                 self.hits += 1
-                return bucket.pop()
+                return bucket.pop(), True
             self.misses += 1
-        return np.empty(int(size), dtype=dtype)
+        return np.empty(int(size), dtype=dtype), False
 
     def release(self, buf: np.ndarray) -> None:
         if not isinstance(buf, np.ndarray) or buf.ndim != 1:
@@ -228,10 +241,167 @@ class BufferPool:
                 bucket.append(buf)
 
 
+# A device bucket reaches the host as pieces of at most this many bytes,
+# every piece's transfer issued at the capture. TPU v5e, a 0.973 GB bf16
+# bucket into a buffer written before, GB/s (chip runs, PR 29; np.asarray of
+# the whole bucket: 0.63): with freed blocks kept mapped
+# (_keep_freed_blocks_mapped) 8 MiB 8.4-8.6, 16 MiB 8.7-9.6, 32 MiB (glibc
+# mmaps it again) 6.7; as glibc comes 2.1-3.3 at any size up to 32 MiB, and
+# 1.0 at 128 MiB.
+FETCH_PIECE_BYTES = 16 << 20
+# rows of the second-minor dimension in one TPU tile, at the narrowest dtype
+_TILE_ROWS = 32
+
+
+def _piece_bounds(
+    metas: Sequence[Meta], itemsize: int, piece_bytes: int
+) -> List[Tuple[int, int]]:
+    """Cut a bucket's flat element range into ``[a, b)`` pieces of at most
+    ``piece_bytes``. A leaf larger than a piece is cut on its own: between
+    rows of its last dimension where a row fits a piece (the device slices
+    whole rows of the leaf as it lies, and never builds its flat), between
+    elements otherwise. Smaller leaves share a piece with their neighbours."""
+    cap = max(1, piece_bytes // itemsize)
+    bounds: List[Tuple[int, int]] = []
+    start = end = 0  # the open run of whole small leaves
+    for _i, off, n, shape in metas:
+        if n > cap:
+            if end > start:
+                bounds.append((start, end))
+            row = shape[-1] if shape and shape[-1] <= cap else 1
+            rows = cap // row
+            if row > 1 and rows > _TILE_ROWS:
+                # whole tiles: a slice that starts inside one is relaid out
+                # through a temporary on the TPU
+                rows -= rows % _TILE_ROWS
+            step = rows * row
+            bounds.extend(
+                (a, min(a + step, off + n)) for a in range(off, off + n, step)
+            )
+            start = end = off + n
+            continue
+        if end - start + n > cap:
+            bounds.append((start, end))
+            start = off
+        end = off + n
+    if end > start:
+        bounds.append((start, end))
+    return bounds
+
+
+@functools.lru_cache(maxsize=64)
+def _splitter(
+    metas: Tuple[Meta, ...], dtype: str, piece_bytes: int
+) -> Tuple[Any, List[Tuple[int, int]]]:
+    """``(split, bounds)`` for one bucket of a plan (its ``metas``):
+    ``split(*leaves)`` is ONE jitted dispatch whose outputs are the bucket's
+    pieces, 1-D, in flat order: private copies (the leaves may be donated
+    right after), together the size of the flat that :func:`pack` would
+    build, which is never built."""
+    import jax
+    import jax.numpy as jnp
+
+    bounds = _piece_bounds(metas, np.dtype(dtype).itemsize, piece_bytes)
+
+    def split(*leaves: Any) -> List[Any]:
+        pieces = []
+        for a, b in bounds:
+            parts = []
+            for leaf, (_i, off, n, shape) in zip(leaves, metas):
+                lo, hi = max(a, off) - off, min(b, off + n) - off
+                if lo >= hi:
+                    continue
+                row = shape[-1] if len(shape) > 1 else 1
+                if lo % row or hi % row:
+                    row = 1
+                parts.append(
+                    leaf.reshape(-1, row)[lo // row : hi // row].reshape(-1)
+                )
+            pieces.append(parts[0] if len(parts) == 1 else jnp.concatenate(parts))
+        return pieces
+
+    return jax.jit(split), bounds
+
+
+@functools.lru_cache(maxsize=None)
+def _keep_freed_blocks_mapped() -> bool:
+    """Tell glibc, once a process, to serve every block under 32 MiB (the
+    most it takes) from its heap and never to trim the heap: the
+    destination the runtime allocates for each piece's transfer is then a
+    block that the last step's pieces freed, its pages still mapped. Without
+    it each one is a fresh ``mmap`` whose every 4 KiB page is faulted in by
+    the runtime's copy (same chip, same 0.973 GB bucket: 2.6 GB/s against
+    8.7; a piece alone 0.77 against 3.7). This holds for the arena of the
+    thread that ISSUES the transfers, which is why :func:`pack` issues them
+    on its caller's thread: the main thread's arena is one heap that is
+    never given back, while a worker thread's is made of 64 MiB heaps that
+    glibc unmaps whenever one is wholly free, whatever it was told (2.7
+    GB/s from a worker thread, same settings). Process-wide and one-way:
+    freed heap memory stays with the process (its high-water mark, about
+    the size of the gradients), blocks of 32 MiB and more are ``mmap``-ed
+    and returned as before. False where libc is not glibc; the fetch then
+    runs at the first rate."""
+    import ctypes
+
+    try:
+        mallopt = ctypes.CDLL("libc.so.6").mallopt
+    except (OSError, AttributeError):
+        return False
+    mallopt.argtypes, mallopt.restype = [ctypes.c_int, ctypes.c_int], ctypes.c_int
+    m_trim_threshold, m_mmap_threshold = -1, -3  # <malloc.h>
+    return bool(
+        mallopt(m_mmap_threshold, 32 << 20)
+        and mallopt(m_trim_threshold, 2**31 - 1)
+    )
+
+
+class Pieces:
+    """A device group's capture for the host plane: ``arrays[k]`` holds the
+    flat elements ``bounds[k]`` of a bucket of ``size`` elements, its
+    transfer to the host already issued. An entry is dropped (None) once
+    :func:`fetch_into` has it on the host, so the device memory goes back
+    piece by piece."""
+
+    __slots__ = ("arrays", "bounds", "size", "dtype")
+
+    def __init__(
+        self, arrays: List[Any], bounds: List[Tuple[int, int]],
+        size: int, dtype: np.dtype,
+    ) -> None:
+        self.arrays = arrays
+        self.bounds = bounds
+        self.size = size
+        self.dtype = dtype
+
+    def block_until_ready(self) -> "Pieces":
+        import jax
+
+        jax.block_until_ready(self.arrays)
+        return self
+
+
+def fetch_into(pieces: Pieces, out: np.ndarray) -> int:
+    """Copy a captured device bucket into ``out`` (1-D, ``pieces.size``
+    elements: afterwards bitwise ``np.asarray`` of the packed flat) and
+    return how many pieces it came in. Every piece's transfer has been in
+    flight since the capture, so this waits for each in turn, copies it to
+    its offset and drops it with its device buffer. What the runtime
+    allocated per transfer is piece-sized and reused from step to step
+    (:func:`_keep_freed_blocks_mapped`); the only bucket-sized host memory
+    is ``out``, which the caller takes from a :class:`BufferPool` so that
+    its pages are mapped from the second step on."""
+    arrays = pieces.arrays
+    for k, (a, b) in enumerate(pieces.bounds):
+        np.copyto(out[a:b], np.asarray(arrays[k]))
+        arrays[k] = None
+    return len(arrays)
+
+
 def pack(
     leaves: Sequence[Any],
     plan: BucketPlan,
     pool: Optional[BufferPool] = None,
+    piece_bytes: Optional[int] = None,
 ) -> Tuple[List[Any], List[np.ndarray]]:
     """Materialize the plan's buckets from ``leaves``.
 
@@ -241,6 +411,11 @@ def pack(
     ``jax.Array``) concatenate on device — a fresh buffer, so it is safe
     against the caller's next donating jit step; host groups copy into a
     pooled (or fresh) numpy buffer, which is likewise a private capture.
+    With ``piece_bytes`` (the host plane, whose buckets go through
+    :func:`fetch_into`) a device group comes back as :class:`Pieces`
+    instead of one flat: the same private capture, already cut, and every
+    piece's transfer to the host issued from this thread, to start as soon
+    as the device has computed the piece.
     """
     import jax
 
@@ -250,7 +425,14 @@ def pack(
         if all(isinstance(leaves[i], jax.Array) for i in g):
             import jax.numpy as jnp
 
-            if len(g) == 1:
+            if piece_bytes is not None:
+                split, bounds = _splitter(tuple(metas), dtype.name, piece_bytes)
+                arrays = list(split(*(leaves[i] for i in g)))
+                _keep_freed_blocks_mapped()
+                for piece in arrays:
+                    piece.copy_to_host_async()
+                flat = Pieces(arrays, bounds, size, dtype)
+            elif len(g) == 1:
                 # single-leaf bucket: reshape is a view-like device op, but
                 # the Manager's staging contract needs a private buffer —
                 # copy explicitly

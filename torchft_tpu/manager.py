@@ -2068,6 +2068,15 @@ class Manager:
                     # partially-applied reduction.
                     try:
                         t0u = time.perf_counter()
+                        # a PG that hands its input back as its result
+                        # (world-of-one short circuits): the landed leaves
+                        # may be views of, or transfers still reading,
+                        # the staging buffer
+                        passed_through = (
+                            pooled_buf is not None
+                            and isinstance(flat, np.ndarray)
+                            and np.shares_memory(flat, pooled_buf)
+                        )
                         # the bucket's first unpack child carries how long
                         # it sat behind earlier buckets on the one unpack
                         # worker (device plane: unpack runs in the wire's
@@ -2094,14 +2103,15 @@ class Manager:
                                 flat = decompress_bucket(flat)
                         pairs = land_reduced(flat, i, span)
                         marks[i]["unpack"] = (t0u, time.perf_counter())
-                        if pooled_buf is not None and not any(
-                            isinstance(v, np.ndarray)
-                            and np.shares_memory(v, pooled_buf)
-                            for _idx, v in pairs
+                        if (
+                            pooled_buf is not None
+                            and not passed_through
+                            and not final_fut.done()
                         ):
                             # recycle this bucket's staging buffer the
-                            # moment it lands (success only; never when the
-                            # PG passed it through as its own result)
+                            # moment it lands: on success only (an op that
+                            # already failed or timed out drops it), never
+                            # when the PG passed it through
                             pool.release(pooled_buf)
                         bucket_futs[i].set_result(pairs)
                     except Exception as e:  # noqa: BLE001
@@ -2161,12 +2171,17 @@ class Manager:
                             "capture", cat="allreduce", parent=ar_id,
                             bytes=ar_args["bytes"],
                         ):
-                            capture, pooled = bucketing.pack(
-                                leaves, plan, pool=pool
+                            # device groups come back cut into pieces
+                            # for fetch_into (one jitted dispatch a
+                            # bucket: private copies, donation-safe as
+                            # the flat was), their transfers issued from
+                            # this thread; host groups as before
+                            capture, _ = bucketing.pack(
+                                leaves, plan, pool=pool,
+                                piece_bytes=bucketing.FETCH_PIECE_BYTES,
                             )
                     else:
-                        capture, pooled = None, []
-                    pooled_ids = {id(b) for b in pooled}
+                        capture = None
                     stage_timeout = self._timeout
                     t_submit = time.perf_counter()
 
@@ -2213,6 +2228,9 @@ class Manager:
                                 _stage_deadline, stage_timeout
                             )
                             final_fut.add_done_callback(lambda _f: cancel())
+                            # pool buffers this op's device buckets took,
+                            # and how many of them were recycled ones
+                            acquired = hits = 0
                             for i in range(n_buckets):
                                 t0b = time.perf_counter()
                                 pk_id = stage_ids[i]["pack"]
@@ -2242,13 +2260,34 @@ class Manager:
                                             (t0b - t_submit) * 1e6
                                         )} if i == 0 else {}),
                                     ) as sp:
-                                        host_flat = np.asarray(capture[i])
+                                        cap = capture[i]
+                                        if isinstance(cap, bucketing.Pieces):
+                                            # a device bucket: its pieces,
+                                            # in flight since the capture,
+                                            # into a pool buffer (mapped
+                                            # pages from the second step
+                                            # on), device memory dropped
+                                            # as each lands
+                                            host_flat, hit = pool.acquire_hit(
+                                                cap.size, cap.dtype
+                                            )
+                                            acquired += 1
+                                            hits += hit
+                                            sp.args["pieces"] = (
+                                                bucketing.fetch_into(
+                                                    cap, host_flat
+                                                )
+                                            )
+                                            sp.args["pooled"] = int(hit)
+                                        else:
+                                            # a host group: packed into
+                                            # its pool buffer at capture
+                                            host_flat = cap
+                                        capture[i] = None
                                         sp.args["bytes"] = host_flat.nbytes
-                                    pooled_buf = (
-                                        capture[i]
-                                        if id(capture[i]) in pooled_ids
-                                        else None
-                                    )
+                                    # either way a pool buffer: back to
+                                    # the pool once the bucket has landed
+                                    pooled_buf = host_flat
                                 payload: Any = host_flat
                                 if bucket_modes[i] != "off":
                                     # quantize inside the pack stage so
@@ -2315,6 +2354,10 @@ class Manager:
                                             pass
 
                                 w.get_future().add_done_callback(_wire_done)
+                            if acquired:
+                                self._record_timing(
+                                    "stage_pool_hit_share", hits / acquired
+                                )
                         except Exception as e:  # noqa: BLE001
                             for bf in bucket_futs:
                                 try:
@@ -2937,8 +2980,11 @@ class Manager:
         reports chunk-stream stats. Streamed allreduces add
         ``allreduce_pack_s`` / ``allreduce_wire_s`` / ``allreduce_unpack_s``
         / ``allreduce_buckets`` / ``overlap_efficiency`` (see
-        :meth:`_record_pipeline_timings`). Keys appear once the phase has
-        run.
+        :meth:`_record_pipeline_timings`) and, on the host plane,
+        ``stage_pool_hit_share``: of the device buckets the last streamed
+        allreduce fetched, the share that went into a recycled buffer of
+        the pool (pages mapped) rather than a new allocation; 1.0 from a
+        plan's second step on. Keys appear once the phase has run.
 
         Also carries the CUMULATIVE resilience counters (present from
         construction, never reset): ``heal_attempts`` (initial heal tries

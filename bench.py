@@ -12,7 +12,7 @@ the 6N convention (attention FLOPs not counted) against the per-device_kind
 peak table in torchft_tpu/utils.py.
 
 The host-plane FT rows are their own modes (`--smoke`, `--ft-overhead`,
-`--allreduce-pipeline`, ...): CPU measurements in CPU-pinned children, never
+`--compressed-allreduce`, ...): CPU measurements in CPU-pinned children, never
 part of the chip record.
 
 `timed_train_step` is the single measurement harness — benchmarks/mfu_sweep.py
@@ -273,90 +273,6 @@ def healthwatch_metrics(steps: int = 30, warmup: int = 5,
         f"healthwatch child failed rc={out.returncode}: "
         f"{(out.stderr or out.stdout)[-300:]}"
     )
-
-
-def allreduce_pipeline_metrics(size_mb: float = 64, leaves: int = 16,
-                               cap_mb: float = 4, steps: int = 10,
-                               warmup: int = 3) -> dict:
-    """Streamed vs serial managed allreduce on the host loopback plane:
-    two live replica groups exchange the same multi-bucket gradient tree
-    through real Managers twice (stream_buckets off, then on) and report
-    the median step walls side by side plus the pipeline's per-bucket
-    stage splits and ``overlap_efficiency``. CPU-pinned subprocess, same
-    isolation policy as the other FT rows."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
-
-    child = (
-        "from torchft_tpu.utils import force_virtual_cpu_devices\n"
-        "force_virtual_cpu_devices(1)\n"
-        "import sys, json\n"
-        f"sys.path.insert(0, {os.path.join(os.path.dirname(os.path.abspath(__file__)), 'benchmarks')!r})\n"
-        "from allreduce_pipeline_bench import run\n"
-        f"print('ARPIPE ' + json.dumps(run(size_mb={size_mb}, "
-        f"leaves={leaves}, cap_mb={cap_mb}, steps={steps}, "
-        f"warmup={warmup})))\n"
-    )
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, "-c", child], capture_output=True, text=True,
-        timeout=420,
-        env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
-    )
-    for line in reversed(out.stdout.splitlines()):
-        if line.startswith("ARPIPE "):
-            return _json.loads(line[len("ARPIPE "):])
-    raise RuntimeError(
-        f"allreduce-pipeline child failed rc={out.returncode}: "
-        f"{(out.stderr or out.stdout)[-300:]}"
-    )
-
-
-def allreduce_pipeline(smoke: bool = False) -> None:
-    """``python bench.py --allreduce-pipeline [--smoke]``: one JSON line
-    with the serial vs streamed step walls, ``speedup_pct``, and the
-    per-bucket pipeline splits. Smoke mode shrinks the payload and asserts
-    every split key is present — the fast-tier CI gate that fails loudly
-    if the streaming pipeline's instrumentation (the allreduce_pipeline
-    timing snapshots) regresses."""
-    if smoke:
-        metrics = allreduce_pipeline_metrics(
-            size_mb=8, leaves=8, cap_mb=2, steps=4, warmup=1
-        )
-    else:
-        metrics = allreduce_pipeline_metrics()
-    required = [
-        "serial_step_s",
-        "streamed_step_s",
-        "speedup_pct",
-        "allreduce_pack_s",
-        "allreduce_wire_s",
-        "allreduce_unpack_s",
-        "allreduce_buckets",
-        "overlap_efficiency",
-    ]
-    missing = [k for k in required if metrics.get(k) is None]
-    if missing:
-        raise RuntimeError(f"allreduce-pipeline: missing splits: {missing}")
-    if not metrics["allreduce_buckets"] > 1:
-        raise RuntimeError(
-            "allreduce-pipeline: allreduce_buckets <= 1 — the plan no "
-            "longer splits into per-bucket collectives"
-        )
-    if not metrics["allreduce_wire_s"] > 0:
-        raise RuntimeError(
-            "allreduce-pipeline: allreduce_wire_s=0 — per-bucket wire "
-            "intervals are no longer recorded through Manager.timings()"
-        )
-    print(json.dumps({
-        "metric": "streamed vs serial managed allreduce (host loopback)",
-        "value": metrics["speedup_pct"],
-        "unit": "%",
-        "vs_baseline": 1,
-        **metrics,
-    }))
 
 
 def compressed_allreduce_metrics(size_mb: float = 64, leaves: int = 16,
@@ -1128,10 +1044,6 @@ if __name__ == "__main__":
     if "--ft-overhead" in sys.argv[1:]:
         # loud-failure gate, same policy as --smoke
         ft_overhead(smoke="--smoke" in sys.argv[1:])
-        sys.exit(0)
-    if "--allreduce-pipeline" in sys.argv[1:]:
-        # loud-failure gate, same policy as --smoke
-        allreduce_pipeline(smoke="--smoke" in sys.argv[1:])
         sys.exit(0)
     if "--compressed-allreduce" in sys.argv[1:]:
         # loud-failure gate, same policy as --smoke

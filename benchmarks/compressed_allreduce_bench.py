@@ -89,7 +89,6 @@ def _run_mode(mode: str, tree: dict, cap_bytes: int, steps: int,
                 lighthouse_addr=f"127.0.0.1:{lh.port}",
                 timeout=60.0,
                 bucket_cap_bytes=cap_bytes,
-                stream_buckets=True,
                 compress=mode,
             )
             for i in range(steps):

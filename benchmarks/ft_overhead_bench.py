@@ -100,7 +100,8 @@ def run(steps: int = 30, warmup: int = 5, batch_size: int = 8) -> dict:
         "allreduce_s": [],
         "should_commit_rpc_s": [],
         "bookkeeping_s": [],
-        # streamed-pipeline stage splits (see Manager._record_pipeline_timings)
+        # the bucket pipeline's stage splits
+        # (see bucketing.BucketPipeline.record_timings)
         "allreduce_wire_s": [],
         "overlap_efficiency": [],
         "allreduce_buckets": [],

@@ -1,8 +1,9 @@
-"""Streaming bucket pipeline (Manager.allreduce_streamed / GradStream).
+"""The bucket pipeline (bucketing.BucketPipeline behind Manager.allreduce /
+allreduce_streamed / GradStream).
 
-Pins the PR-3 contracts: streamed numerics are BIT-identical to the serial
-path on both planes, a plan of k buckets issues exactly k single-array
-collectives, the staging worker never blocks on a bucket's wire completion,
+Pins the PR-3 contracts: the pipeline's numerics are BIT-identical to the
+no-plan path (one collective for the whole tree) on both planes, a plan of k
+buckets issues exactly k single-array collectives, the staging worker never blocks on a bucket's wire completion,
 and a mid-stream bucket failure degrades to the swallowed-zeros +
 should_commit()==False story — never a partially-applied reduction.
 """
@@ -15,7 +16,7 @@ import pytest
 
 from test_manager import make_manager, make_quorum
 from torchft_tpu import bucketing
-from torchft_tpu.manager import _covered_seconds, _pipeline_overlap_stats
+from torchft_tpu.bucketing import _covered_seconds, _pipeline_overlap_stats
 from torchft_tpu.process_group import (
     FakeProcessGroupWrapper,
     ProcessGroupDummy,
@@ -77,49 +78,56 @@ def _reduce(m, tree, streamed, **kw):
 
 class TestStreamedSerialEquality:
     def test_host_plane_bitwise_identical(self):
-        """Same tree through stream_buckets on/off: every leaf bitwise
-        equal, same dtype — the pipeline may not change numerics at all."""
+        """Same tree through the pipeline (three buckets) and through the
+        no-plan path (a cap of 0: one collective carrying every leaf), and
+        numpy's own average: every leaf bitwise equal, same dtype — the
+        pipeline may not change numerics at all."""
         tree = _tree()
         cap = 2 * 9 * 4  # 2 leaves per bucket -> 3 buckets
-        serial = _reduce(
-            make_manager(quorum=make_quorum(), bucket_cap_bytes=cap,
-                         stream_buckets=False),
+        pg = CountingPG()
+        no_plan = _reduce(
+            make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=0),
             tree, streamed=False,
         )
+        assert pg.allreduce_calls == [len(tree)]
         streamed = _reduce(
-            make_manager(quorum=make_quorum(), bucket_cap_bytes=cap,
-                         stream_buckets=True),
+            make_manager(quorum=make_quorum(), bucket_cap_bytes=cap),
             tree, streamed=True,
         )
         for k in tree:
-            s, t = np.asarray(serial[k]), np.asarray(streamed[k])
+            s, t = np.asarray(no_plan[k]), np.asarray(streamed[k])
             assert s.dtype == t.dtype
             assert np.array_equal(s, t), f"leaf {k} diverged"
+            assert np.array_equal(t, (tree[k] / 2).astype(tree[k].dtype))
 
     def test_device_plane_bitwise_identical(self):
         """Device-native PGs take per-bucket jax arrays straight through;
-        the landed tree must still match the serial path bit for bit."""
+        the landed tree must still match the no-plan path, and numpy, bit
+        for bit."""
         import jax.numpy as jnp
 
-        class DeviceDummy(ProcessGroupDummy):
+        class DeviceDummy(CountingPG):
             device_native = True
 
         tree = {k: jnp.asarray(v) for k, v in _tree(n=5, size=8).items()}
         cap = 2 * 8 * 4
-        serial = _reduce(
-            make_manager(pg=DeviceDummy(), quorum=make_quorum(),
-                         bucket_cap_bytes=cap, stream_buckets=False),
+        pg = DeviceDummy()
+        no_plan = _reduce(
+            make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=0),
             tree, streamed=False,
         )
+        assert pg.allreduce_calls == [len(tree)]
+        pg = DeviceDummy()
         streamed = _reduce(
-            make_manager(pg=DeviceDummy(), quorum=make_quorum(),
-                         bucket_cap_bytes=cap, stream_buckets=True),
+            make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=cap),
             tree, streamed=True,
         )
+        assert pg.allreduce_calls == [1, 1, 1]
         for k in tree:
-            s, t = np.asarray(serial[k]), np.asarray(streamed[k])
+            s, t = np.asarray(no_plan[k]), np.asarray(streamed[k])
             assert s.dtype == t.dtype
             assert np.array_equal(s, t), f"leaf {k} diverged"
+            assert np.array_equal(t, np.asarray(tree[k]) / np.float32(2))
 
     def test_mixed_dtypes_survive_streaming(self):
         import jax.numpy as jnp
@@ -195,13 +203,12 @@ class TestAverageWhereItLands:
         quorum = dict(replica_world_size=n, max_world_size=n)
         outs = {}
         for name, kw in {
-            "streamed": dict(bucket_cap_bytes=cap, stream_buckets=True),
-            "serial": dict(bucket_cap_bytes=cap, stream_buckets=False),
-            "per_leaf": dict(bucket_cap_bytes=0, stream_buckets=False),
+            "streamed": dict(bucket_cap_bytes=cap),
+            "per_leaf": dict(bucket_cap_bytes=0),
         }.items():
             m = make_manager(quorum=make_quorum(**quorum), min_replica_size=1,
                              **kw)
-            outs[name] = _reduce(m, tree, streamed=kw["stream_buckets"])
+            outs[name] = _reduce(m, tree, streamed=name == "streamed")
             m.shutdown(wait=False)  # 108 idle managers would starve the
             # timing-sensitive tests that share this worker
         for k, orig in tree.items():
@@ -217,7 +224,8 @@ class TestAverageWhereItLands:
 
     def test_pure_numpy_tree_never_initialises_the_backend(self):
         """A process that only moves host arrays must not take the chip:
-        streamed and serial AVG over numpy leaves, in a fresh interpreter."""
+        the pipeline's and the no-plan path's AVG over numpy leaves, in a
+        fresh interpreter."""
         import os
         import subprocess
         import sys
@@ -226,9 +234,8 @@ class TestAverageWhereItLands:
             "import numpy as np, ml_dtypes\n"
             "from jax._src import xla_bridge\n"
             "from test_manager import make_manager, make_quorum\n"
-            "for stream in (True, False):\n"
-            "    m = make_manager(quorum=make_quorum(), bucket_cap_bytes=64,\n"
-            "                     stream_buckets=stream)\n"
+            "for cap in (64, 0):\n"
+            "    m = make_manager(quorum=make_quorum(), bucket_cap_bytes=cap)\n"
             "    m.start_quorum()\n"
             "    tree = {f'p{i}': np.arange(16.).astype(ml_dtypes.bfloat16)\n"
             "            for i in range(4)}\n"
@@ -256,7 +263,7 @@ class TestAverageWhereItLands:
 
         import jax
 
-        from torchft_tpu.manager import _average_on_device
+        from torchft_tpu.bucketing import _average_on_device
 
         tree = _landing_tree(np.dtype("bfloat16"), "jax")
         landed = []
@@ -327,38 +334,27 @@ class TestAverageWhereItLands:
 
 
 class TestPerBucketCollectives:
-    def test_streamed_issues_one_collective_per_bucket(self):
+    @pytest.mark.parametrize("streamed", [True, False])
+    def test_one_collective_per_bucket_through_either_call(self, streamed):
+        """allreduce() and allreduce_streamed() are one path: a tree with a
+        three-bucket plan issues three single-array collectives."""
         tree = _tree()
         cap = 2 * 9 * 4
         plan = bucketing.build_plan(list(tree.values()), cap)
         pg = CountingPG()
-        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=cap,
-                         stream_buckets=True)
-        _reduce(m, tree, streamed=True)
-        assert pg.allreduce_calls == [1] * len(plan)
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=cap)
+        _reduce(m, tree, streamed=streamed)
+        assert len(plan) == 3 and pg.allreduce_calls == [1, 1, 1]
 
-    def test_serial_issues_single_plan_collective(self):
-        tree = _tree()
-        cap = 2 * 9 * 4
-        plan = bucketing.build_plan(list(tree.values()), cap)
+    def test_no_plan_degenerates_to_a_one_bucket_stream(self):
+        """A cap of 0 is the no-plan path: one collective carrying every
+        leaf, and allreduce_streamed's handle covers the whole op."""
         pg = CountingPG()
-        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=cap,
-                         stream_buckets=False)
-        _reduce(m, tree, streamed=False)
-        assert pg.allreduce_calls == [len(plan)]
-
-    def test_env_knob_disables_streaming(self, monkeypatch):
-        monkeypatch.setenv("TORCHFT_STREAM_BUCKETS", "0")
-        pg = CountingPG()
-        m = make_manager(pg=pg, quorum=make_quorum(),
-                         bucket_cap_bytes=2 * 9 * 4)
-        assert m._stream_buckets is False
-        # allreduce_streamed degenerates to the serial path + 1-bucket stream
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=0)
         m.start_quorum()
         stream = m.allreduce_streamed(_tree())
         stream.wait(timeout=30)
-        assert len(pg.allreduce_calls) == 1 and pg.allreduce_calls[0] > 1
-        assert stream.num_buckets == 1
+        assert pg.allreduce_calls == [6] and stream.num_buckets == 1
 
 
 class TestStagingNeverBlocksOnWire:

@@ -3,10 +3,7 @@ plumbing: a tiny virtual-device FT row must produce the per-phase timing
 keys end to end (async quorum overlap, prepare/commit split, chunked
 heal). `--ft-overhead --smoke` is the gate for the steady-state overhead
 harness: the real example trainer under a live Manager must emit
-ft_overhead_pct plus the per-phase cost splits. `--allreduce-pipeline
---smoke` is the gate for the streaming bucket pipeline: serial vs
-streamed step walls plus the per-bucket stage splits and
-overlap_efficiency must survive end to end. `--healthwatch --smoke` is
+ft_overhead_pct plus the per-phase cost splits. `--healthwatch --smoke` is
 the gate for the health telemetry plane: the per-step publish+fold cost
 must stay under 1% of the managed step and /health must answer every
 poll made while the trainer is live. `--tracing --smoke` is the gate for
@@ -107,20 +104,6 @@ def test_bench_tracing_smoke_holds_cost_and_serves_metrics():
     assert rec["metrics_scrapes_ok"] >= 300
     assert rec["metrics_scrapes_failed"] == 0
     assert rec["metrics_series"] > 0
-
-
-def test_bench_allreduce_pipeline_smoke_emits_stage_splits():
-    rec = _run_bench("--allreduce-pipeline", "--smoke")
-    assert rec["serial_step_s"] > 0
-    assert rec["streamed_step_s"] > 0
-    assert rec["speedup_pct"] is not None
-    # the per-bucket stage splits prove the streaming pipeline's timing
-    # snapshots (Manager._record_pipeline_timings) measured real buckets
-    assert rec["allreduce_buckets"] > 1
-    assert rec["allreduce_wire_s"] > 0
-    assert rec["allreduce_pack_s"] >= 0
-    assert rec["allreduce_unpack_s"] >= 0
-    assert 0.0 <= rec["overlap_efficiency"] <= 1.0
 
 
 def test_bench_compressed_allreduce_smoke_emits_per_mode_splits():

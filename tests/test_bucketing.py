@@ -3,6 +3,7 @@ CI guard: a many-leaf pytree through Manager.allreduce must hit the process
 group with at most ceil(total_bytes / cap) flat arrays — the whole point of
 bucketing — and bitwise-identical values either way."""
 
+import contextlib
 import math
 
 import jax
@@ -10,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_allreduce_stream import CopyingPG
 from test_manager import make_manager, make_quorum
 from torchft_tpu import bucketing
 from torchft_tpu.process_group import ProcessGroupDummy, ReduceOp
@@ -272,3 +274,171 @@ class TestFetchInto:
         pool.release(a)
         b, hit = pool.acquire_hit(8, np.float32)
         assert hit and b is a and (pool.hits, pool.misses) == (1, 1)
+
+
+# ---------------------------------------------------------------------------
+# the seams of the data plane: stage, land_reduced, BucketPipeline. None of
+# these needs a Manager or a lighthouse.
+
+
+class TestStage:
+    def test_device_bucket_is_the_flat_bitwise_and_a_pool_hit_from_the_second_call(self):
+        leaves = _device_leaves([(7,), (3, 50), (33,)], np.float32)
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        want = np.asarray(bucketing.pack(leaves, plan)[0][0])
+        pool = bucketing.BufferPool()
+        for step, pooled in enumerate([0, 1, 1]):
+            captured = bucketing.capture(leaves, plan, pool)
+            assert isinstance(captured[0], bucketing.Pieces)
+            host_flat, pooled_buf, info = bucketing.stage(captured, plan, 0, pool)
+            assert host_flat.tobytes() == want.tobytes()
+            assert pooled_buf is host_flat and captured == [None]
+            assert info == {"pieces": 1, "pooled": pooled, "bytes": want.nbytes}
+            pool.release(pooled_buf)  # what the pipeline does once it landed
+        assert (pool.hits, pool.misses) == (2, 1)
+
+    def test_host_group_returns_the_pool_buffer_itself(self):
+        leaves = [np.arange(6, dtype=np.float32), np.ones(3, np.float32)]
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        pool = bucketing.BufferPool()
+        captured = bucketing.capture(leaves, plan, pool)
+        packed = captured[0]
+        assert (pool.hits, pool.misses) == (0, 1)
+        host_flat, pooled_buf, info = bucketing.stage(captured, plan, 0, pool)
+        assert host_flat is packed and pooled_buf is packed
+        assert info == {"bytes": 36} and (pool.hits, pool.misses) == (0, 1)
+        assert np.array_equal(host_flat, np.concatenate(leaves))
+
+    def test_non_participant_is_zeros_and_takes_nothing_from_the_pool(self):
+        import ml_dtypes
+
+        leaves = [np.ones((2, 3), ml_dtypes.bfloat16), np.ones(5, np.float32)]
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        pool = bucketing.BufferPool()
+        for i, (size, dtype) in enumerate(zip(plan.sizes, plan.dtypes)):
+            host_flat, pooled_buf, info = bucketing.stage(None, plan, i, pool)
+            assert host_flat.shape == (size,) and host_flat.dtype == dtype
+            assert not host_flat.any() and pooled_buf is None and info == {}
+        assert (pool.hits, pool.misses) == (0, 0)
+
+
+class TestLandReduced:
+    """The landing alone, against numpy: one reduced flat back to leaves
+    placed where the originals live and divided there."""
+
+    @pytest.mark.parametrize("kind", ["jax", "numpy", "mixed"])
+    def test_a_bucket_and_a_lone_leaf_against_numpy(self, kind):
+        rng = np.random.RandomState(2)
+        host = [(rng.randn(4, 6) * 3).astype(np.float32) for _ in range(3)]
+        leaves = [
+            jnp.asarray(h) if kind == "jax" or (kind == "mixed" and i == 1)
+            else h
+            for i, h in enumerate(host)
+        ]
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        place = bucketing.leaf_placer()
+        seen = []
+
+        @contextlib.contextmanager
+        def span(name, **args):
+            seen.append((name, args.get("where")))
+            yield
+
+        for bucket, flat in enumerate(bucketing.pack(leaves, plan)[0]):
+            pairs = bucketing.land_reduced(
+                np.asarray(flat), leaves, plan, bucket, 3, place, span
+            )
+            for i, got in pairs:
+                assert type(got) is type(leaves[i]), (kind, i)
+                assert got.shape == (4, 6) and got.dtype == np.float32
+                assert np.array_equal(np.asarray(got), host[i] / np.float32(3))
+        # the no-plan path: leaf 2 alone, a plain SUM (no divisor, no divide)
+        [(i, got)] = bucketing.land_reduced(
+            host[2].copy(), leaves, None, 2, None, place
+        )
+        assert i == 2 and type(got) is type(leaves[2])
+        assert np.array_equal(np.asarray(got), host[2])
+        assert [n for n, _ in seen] == ["h2d", "divide"] * len(plan)
+        assert {w for n, w in seen if n == "divide"} == {
+            {"jax": "device", "numpy": "host", "mixed": "mixed"}[kind]
+        }
+
+
+class TestPipelineWithoutAManager:
+    def test_three_buckets_with_a_pg_a_tracer_and_a_pool(self):
+        from torchft_tpu.tracing import SpanRecorder, TraceConfig
+
+        leaves = _device_leaves([(40,), (40,), (36,), (36,), (32,), (32,)],
+                                np.float32)
+        plan = bucketing.build_plan(leaves, 2 * 40 * 4)
+        tracer = SpanRecorder("no-manager", TraceConfig(enabled=True))
+        pool, stats = bucketing.BufferPool(), {}
+        pipeline = bucketing.BucketPipeline(
+            CopyingPG(), tracer, pool, stats.update
+        )
+        try:
+            for step in range(2):
+                op = pipeline.allreduce_buckets(
+                    leaves, plan, ReduceOp.SUM, participating=True,
+                    divisor=2, place=bucketing.leaf_placer(), timeout=30.0,
+                    parent=None,
+                )
+                out = op.final.wait(30)
+                assert all(f.done() for f in op.bucket_futs)
+                pipeline.record_timings(op)
+        finally:
+            pipeline.shutdown(wait=True)
+        for leaf, got in zip(leaves, out):
+            assert isinstance(got, jax.Array)
+            assert np.array_equal(np.asarray(got), np.asarray(leaf) / 2)
+        assert stats["stage_pool_hit_share"] == 1.0
+        assert stats["allreduce_buckets"] == 3.0
+        assert (pool.hits, pool.misses) == (3, 3)
+        names = [s["name"] for s in tracer.export()["spans"]]
+        for name, count in {"capture": 2, "grad_wait": 6, "d2h": 6,
+                            "dispatch": 6, "h2d": 6, "divide": 6,
+                            "pack": 6, "wire": 6, "unpack": 6}.items():
+            assert names.count(name) == count, (name, names)
+
+    @pytest.mark.parametrize("path", ["pipeline", "no_plan"])
+    def test_one_submit_arms_the_backstop_for_either_path(self, path):
+        """An op ahead wedges its stage forever: the stage of the op behind
+        it never runs, so its own deadline is never armed, and the backstop
+        of the one submit() fails it within (depth + 2) * timeout."""
+        import threading
+        import time
+
+        from torchft_tpu.tracing import SpanRecorder, TraceConfig
+        from torchft_tpu.work import Future
+
+        timeout = 0.3
+        pipeline = bucketing.BucketPipeline(
+            CopyingPG(), SpanRecorder("x", TraceConfig(enabled=False)),
+            bucketing.BufferPool(),
+        )
+        wedge, ahead = threading.Event(), Future()
+        leaves = [np.ones(8, np.float32), np.ones(8, np.float32)]
+        kw = dict(participating=True, divisor=None,
+                  place=bucketing.leaf_placer(), timeout=timeout)
+        try:
+            pipeline.submit(lambda: wedge.wait(60), ahead, timeout)
+            t0 = time.monotonic()
+            if path == "pipeline":
+                behind = pipeline.allreduce_buckets(
+                    leaves, bucketing.build_plan(leaves, 32), ReduceOp.SUM,
+                    **kw,
+                ).final
+            else:
+                behind = pipeline.allreduce_leaves(
+                    leaves, ReduceOp.SUM, quantize=False, **kw
+                )
+            # the op ahead is still pending at this submit: depth 1
+            with pytest.raises(TimeoutError, match="staging timed out"):
+                behind.wait(30)
+            took = time.monotonic() - t0
+            assert 3 * timeout * 0.9 <= took <= 3 * timeout + 2.0, took
+            with pytest.raises(TimeoutError, match="staging timed out"):
+                ahead.wait(5)  # its own stage-start deadline
+        finally:
+            wedge.set()
+            pipeline.shutdown(wait=False)

@@ -123,7 +123,7 @@ class TestResolveCompressMode:
 
 class TestDoctorCompressCheck:
     """doctor.py check_compress_env mirrors the Manager's own resolution:
-    same funnel, same rejection, plus the streaming-off footgun warning."""
+    same funnel, same rejection."""
 
     def test_default_off_passes(self, monkeypatch):
         from torchft_tpu.doctor import check_compress_env
@@ -140,25 +140,16 @@ class TestDoctorCompressCheck:
         assert status is False
         assert "fp4" in detail and "off/fp8/int8" in detail
 
-    def test_compress_on_with_streaming_off_warns(self, monkeypatch):
-        from torchft_tpu.doctor import check_compress_env
-
-        monkeypatch.setenv("TORCHFT_COMPRESS", "fp8")
-        monkeypatch.setenv("TORCHFT_STREAM_BUCKETS", "0")
-        status, detail = check_compress_env()
-        assert status is None and "TORCHFT_STREAM_BUCKETS" in detail
-
-    def test_compress_on_with_streaming_on_passes(self, monkeypatch):
+    def test_compress_on_passes(self, monkeypatch):
         from torchft_tpu.doctor import check_compress_env
 
         monkeypatch.setenv("TORCHFT_COMPRESS", "int8")
-        monkeypatch.delenv("TORCHFT_STREAM_BUCKETS", raising=False)
         status, detail = check_compress_env()
         assert status is True and "int8" in detail
 
 
 # ---------------------------------------------------------------------------
-# Error feedback: the residual math the Manager's _compress_bucket_ef runs
+# Error feedback: the residual math BucketPipeline._compress_bucket_ef runs
 # ---------------------------------------------------------------------------
 def _ef_stream(g: np.ndarray, mode: str, steps: int):
     """Reference EF loop: compress (grad + carried residual), accumulate

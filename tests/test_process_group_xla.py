@@ -252,110 +252,61 @@ class TestLocalMode:
                 np.testing.assert_allclose(np.asarray(out[0]), 0.0 + rank)
                 np.testing.assert_allclose(np.asarray(out[1]), 10.0 + rank)
 
-    def test_manager_allreduce_stays_on_device(self, store):
-        """Manager.allreduce with a device-native PG: no host staging, the
-        result pytree is jax.Arrays produced by the XLA reduction."""
-        from torchft_tpu.manager import Manager
+    @staticmethod
+    def _allreduce_avg(pg, leaf, world, quantize):
+        """What Manager.allreduce does with a device-native PG, less the
+        quorum: its data plane, driven with a PG, a span recorder and a
+        pool, and no Manager."""
+        from torchft_tpu import bucketing
 
+        pipeline = bucketing.BucketPipeline(
+            pg, SpanRecorder("stub", TraceConfig(enabled=False)),
+            bucketing.BufferPool(),
+        )
+        try:
+            return pipeline.allreduce_leaves(
+                [leaf], ReduceOp.SUM, quantize=quantize, participating=True,
+                divisor=world, place=bucketing.leaf_placer(), timeout=60.0,
+            ).wait(60)[0]
+        finally:
+            pipeline.shutdown(wait=False)
+
+    def test_manager_allreduce_stays_on_device(self, store):
+        """The managed allreduce's data plane with a device-native PG: no
+        host staging, the result is a jax.Array produced by the XLA
+        reduction."""
         world = 2
         pgs = make_pgs(store, world, quorum_id=5)
-
-        # the real Manager.allreduce over a minimal stub of its surface
-        class _Mgr:
-            def __init__(self, pg):
-                self._pg = pg
-                self._logger = _Log()
-
-            errored = lambda self: None
-            wait_quorum = lambda self, cat="quorum", parent=None: None
-            _tracer = SpanRecorder("stub", TraceConfig(enabled=False))
-            num_participants = lambda self: world
-            is_participating = lambda self: True
-            report_error = lambda self, e: None
-            _bump_metric = lambda self, name: None
-            _commit_pending_configure = lambda self: None
-            _record_timing = lambda self, key, value: None
-            _bucket_cap_bytes = 0
-            _stream_buckets = False
-
-            def wrap_future(self, fut, default, **kwargs):
-                return fut
-
-            allreduce = Manager.allreduce
-            _allreduce = Manager._allreduce
-
-        class _Log:
-            def exception(self, *a, **k):
-                pass
-
-        mgrs = [_Mgr(pgs[r]) for r in range(world)]
         outs = run_parallel(
             world,
-            lambda r: mgrs[r]
-            .allreduce({"g": jnp.full((4,), float(r + 1))})
-            .get_future()
-            .wait(30),
+            lambda r: self._allreduce_avg(
+                pgs[r], jnp.full((4,), float(r + 1)), world, quantize=False
+            ),
         )
         for out in outs:
-            assert isinstance(out["g"], jax.Array)
-            np.testing.assert_allclose(np.asarray(out["g"]), np.full(4, 1.5))
+            assert isinstance(out, jax.Array)
+            np.testing.assert_allclose(np.asarray(out), np.full(4, 1.5))
 
     def test_manager_quantized_allreduce_on_device(self, store):
         """should_quantize over a device-native PG: the fp8 pipeline packs
         the compressed wire into uint8 device arrays and ships it through
         the PG's own collectives (the gate that silently disabled this is
         gone)."""
-        from torchft_tpu.manager import Manager
-
         world = 2
         pgs = make_pgs(store, world, quorum_id=6)
-
-        class _Mgr:
-            def __init__(self, pg):
-                self._pg = pg
-                self._logger = _Log()
-
-            errored = lambda self: None
-            wait_quorum = lambda self, cat="quorum", parent=None: None
-            _tracer = SpanRecorder("stub", TraceConfig(enabled=False))
-            num_participants = lambda self: world
-            is_participating = lambda self: True
-            report_error = lambda self, e: None
-            _bump_metric = lambda self, name: None
-            _commit_pending_configure = lambda self: None
-            _record_timing = lambda self, key, value: None
-            _bucket_cap_bytes = 0
-            _stream_buckets = False
-
-            def wrap_future(self, fut, default, **kwargs):
-                return fut
-
-            allreduce = Manager.allreduce
-            _allreduce = Manager._allreduce
-
-        class _Log:
-            def exception(self, *a, **k):
-                pass
-
-            def warning(self, *a, **k):
-                pass
-
         rng = np.random.RandomState(5)
         base = rng.randn(600).astype(np.float32)
-        mgrs = [_Mgr(pgs[r]) for r in range(world)]
         outs = run_parallel(
             world,
-            lambda r: mgrs[r]
-            .allreduce({"g": jnp.asarray(base * (r + 1))},
-                       should_quantize=True)
-            .get_future()
-            .wait(60),
+            lambda r: self._allreduce_avg(
+                pgs[r], jnp.asarray(base * (r + 1)), world, quantize=True
+            ),
         )
         amax = float(np.abs(base).max())
         for out in outs:
-            assert isinstance(out["g"], jax.Array)
+            assert isinstance(out, jax.Array)
             np.testing.assert_allclose(
-                np.asarray(out["g"]), base * 1.5, rtol=0.15, atol=amax / 4
+                np.asarray(out), base * 1.5, rtol=0.15, atol=amax / 4
             )
 
 

@@ -3,7 +3,8 @@ modules must either ride :func:`torchft_tpu.retry.retry_call` or carry an
 explicit ``timeout=``.
 
 Scope is the modules whose threads sit on the training/serving hot path:
-``manager.py``, ``serving.py``, ``redundancy.py``, ``coordination.py``.
+``manager.py``, ``bucketing.py``, ``serving.py``, ``redundancy.py``,
+``coordination.py``.
 A bare ``urlopen(url)`` there blocks its thread for the kernel default
 (minutes) when a peer wedges — exactly the failure mode the paper's
 fault-tolerance plane exists to bound.
@@ -27,8 +28,8 @@ from typing import List, Set
 
 from torchft_tpu.analysis.core import Finding, Repo, dotted_name
 
-_SCOPED_MODULES = ("manager.py", "serving.py", "redundancy.py",
-                   "coordination.py")
+_SCOPED_MODULES = ("manager.py", "bucketing.py", "serving.py",
+                   "redundancy.py", "coordination.py")
 _BLOCKING_NAMES = {
     "urlopen", "create_connection", "HTTPConnection", "HTTPSConnection",
 }

@@ -27,7 +27,8 @@ _EMIT_METHODS = {"_record_timing", "_bump_counter", "_on_metric"}
 _SERIES_METHODS = {"gauge_set", "counter_set", "observe"}
 _TIMINGS_DICTS = {"_timings", "out"}
 # modules whose emissions land in Manager.timings() / manager /metrics
-_SCOPED_MODULES = ("manager.py", "redundancy.py")
+# (bucketing.py: the bucket pipeline's stage sums and pool hit share)
+_SCOPED_MODULES = ("manager.py", "redundancy.py", "bucketing.py")
 
 
 def _str_arg0(call: ast.Call) -> str | None:
@@ -207,7 +208,8 @@ def check(repo: Repo) -> List[Finding]:
                     key=key,
                     message=(
                         f"declared timings key {key!r} no longer appears "
-                        "in manager.py/redundancy.py — emission was removed "
+                        "in manager.py/redundancy.py/bucketing.py — emission "
+                        "was removed "
                         "without updating the contract"
                     ),
                 )

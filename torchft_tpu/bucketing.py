@@ -1,4 +1,4 @@
-"""Shared pytree bucketing for the managed data plane.
+"""Shared pytree bucketing, and the managed data plane built on it.
 
 One bucketing implementation for every consumer — ``Manager.allreduce``,
 ``ddp.PureDistributedDataParallel``, and DiLoCo's fragment sync
@@ -9,7 +9,24 @@ the host DCN plane — the same motivation as the reference's bucketized
 allreduce (local_sgd.py:498-566), minus the NCCL-launch angle which does
 not exist on TPU.
 
-Three pieces keep the steady-state step allocation-free:
+``Manager.allreduce`` keeps the state machine (quorum, participants, the
+error policy) and hands the data to :class:`BucketPipeline`, which takes a
+process group, a span recorder and a pool, and no Manager. Three decisions,
+one home each:
+
+- *the staging format*: :func:`capture` (the caller's thread) and
+  :func:`stage` (the staging thread): device bucket → :class:`Pieces` → pool
+  buffer; host group → pool buffer; non-participant → zeros of the plan's
+  size and dtype. Nothing outside this module names a piece.
+- *the landing*: :func:`land_reduced` beside :func:`unpack_bucket`: slice,
+  place where the leaf lives (:func:`leaf_placer`), divide there. The
+  pipeline's buckets and the no-plan path land through it alike.
+- *the schedule*: :class:`BucketPipeline`: which thread runs which stage,
+  the per-bucket futures and marks, the stage-start deadline and the
+  depth-aware backstop (one :meth:`BucketPipeline.submit`), the shutdown
+  sweep, when a pool buffer goes back.
+
+Underneath, these pieces keep the steady-state step allocation-free:
 
 - :func:`plan_for` — a cached flatten plan (:class:`BucketPlan`): bucket
   membership and unpack metadata are a pure function of the tree structure
@@ -37,11 +54,23 @@ bitwise green with it on or off.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import time
+import weakref
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from torchft_tpu.futures import arm_deadline
+from torchft_tpu.ops.quantization import (
+    compress_bucket,
+    decompress_bucket,
+    is_compressed_wire,
+)
+from torchft_tpu.work import Future, join_futures
 
 __all__ = [
     "DEFAULT_BUCKET_CAP_BYTES",
@@ -49,12 +78,19 @@ __all__ = [
     "BufferPool",
     "build_plan",
     "plan_for",
+    "leaf_dtype",
+    "is_float_dtype",
     "pack",
     "Pieces",
     "fetch_into",
     "FETCH_PIECE_BYTES",
+    "capture",
+    "stage",
     "unpack",
     "unpack_bucket",
+    "leaf_placer",
+    "land_reduced",
+    "BucketPipeline",
     "make_buckets",
     "pack_group",
     "unpack_buckets",
@@ -67,7 +103,7 @@ DEFAULT_BUCKET_CAP_BYTES = 1 << 30
 Meta = Tuple[int, int, int, Tuple[int, ...]]
 
 
-def _leaf_dtype(leaf: Any) -> np.dtype:
+def leaf_dtype(leaf: Any) -> np.dtype:
     """Leaf dtype without forcing a device→host transfer (jax.Array and
     ml_dtypes dtypes pass through np.dtype unchanged)."""
     dt = getattr(leaf, "dtype", None)
@@ -98,7 +134,7 @@ class BucketPlan:
     training loop over the same tree.
     """
 
-    # __weakref__ lets the Manager key per-bucket error-feedback residuals
+    # __weakref__ lets the pipeline key per-bucket error-feedback residuals
     # by plan identity (WeakKeyDictionary): residuals die with the plan when
     # the plan cache evicts, instead of leaking per-tree forever
     __slots__ = (
@@ -131,7 +167,7 @@ def build_plan(leaves: Sequence[Any], cap_bytes: int) -> BucketPlan:
     ``cap_bytes`` (a single leaf above the cap gets its own bucket)."""
     by_dtype: Dict[np.dtype, List[int]] = {}
     for i, leaf in enumerate(leaves):
-        by_dtype.setdefault(_leaf_dtype(leaf), []).append(i)
+        by_dtype.setdefault(leaf_dtype(leaf), []).append(i)
     groups: List[List[int]] = []
     dtypes: List[np.dtype] = []
     for dtype, idxs in by_dtype.items():
@@ -182,7 +218,7 @@ def plan_for(
     tree with different leaf geometry sharing a plan.
     """
     try:
-        spec = tuple((str(_leaf_dtype(l)), _leaf_shape(l)) for l in leaves)
+        spec = tuple((str(leaf_dtype(l)), _leaf_shape(l)) for l in leaves)
         key = (treedef, spec, cap_bytes)
         with _plan_cache_lock:
             plan = _plan_cache.get(key)
@@ -453,6 +489,53 @@ def pack(
     return flats, pooled
 
 
+def capture(
+    leaves: Sequence[Any], plan: BucketPlan, pool: BufferPool
+) -> List[Any]:
+    """The host plane's capture of a participant's leaves, one entry a
+    bucket, for :func:`stage` to turn into host memory. Runs on the
+    CALLER's thread, before ``allreduce()`` returns: the staging thread
+    reads the capture afterwards, by which time the caller's next jitted
+    step may have donated (deleted) the device buffers or overwritten a
+    reused numpy buffer. A device group comes back cut into :class:`Pieces`
+    (one jitted dispatch a bucket: private copies, their transfers issued
+    from this thread, whose arena keeps freed blocks mapped:
+    :func:`_keep_freed_blocks_mapped`); a host group is copied into a pool
+    buffer here."""
+    flats, _pooled = pack(
+        leaves, plan, pool=pool, piece_bytes=FETCH_PIECE_BYTES
+    )
+    return flats
+
+
+def stage(
+    captured: Optional[List[Any]], plan: BucketPlan, i: int, pool: BufferPool
+) -> Tuple[np.ndarray, Optional[np.ndarray], Dict[str, int]]:
+    """Bucket ``i`` of a :func:`capture` as host memory for the wire, on the
+    staging thread: ``(host_flat, pooled_buf, info)``. ``pooled_buf`` is the
+    pool buffer to give back once the bucket has LANDED (None: nothing was
+    taken); ``info`` is what the ``d2h`` span says of it (``bytes``, and for
+    a device bucket ``pieces`` and ``pooled``: 1 when the buffer is a
+    recycled one). ``captured`` is None for a non-participant: its
+    contribution is zeros of the plan's size and dtype, built from shapes
+    alone, and nothing comes out of the pool. The entry is dropped from
+    ``captured`` so that nothing holds the capture once it is staged."""
+    if captured is None:
+        return np.zeros((plan.sizes[i],), plan.dtypes[i]), None, {}
+    cap, captured[i] = captured[i], None
+    if isinstance(cap, Pieces):
+        # a device bucket: its pieces, in flight since the capture, into a
+        # pool buffer (mapped pages from the second step on), device memory
+        # dropped as each lands
+        host_flat, hit = pool.acquire_hit(cap.size, cap.dtype)
+        info = {"pieces": fetch_into(cap, host_flat), "pooled": int(hit)}
+    else:
+        # a host group: packed into its pool buffer at the capture
+        host_flat, info = cap, {}
+    info["bytes"] = host_flat.nbytes
+    return host_flat, host_flat, info
+
+
 def unpack(flats: Sequence[Any], plan: BucketPlan) -> List[Any]:
     """Slice the reduced flat buckets back into per-leaf arrays (views for
     numpy flats, lazy device slices for jax flats), in leaf order."""
@@ -479,6 +562,932 @@ def unpack_bucket(flat: Any, plan: BucketPlan, bucket: int) -> List[Tuple[int, A
         (i, flat[off : off + size].reshape(shape))
         for (i, off, size, shape) in plan.metas[bucket]
     ]
+
+
+# ---------------------------------------------------------------------------
+# the landing: one reduced array back to where its leaves live, averaged there
+
+
+def is_float_dtype(dtype: Any) -> bool:
+    """True for dtypes the wire codecs can compress (incl. ml_dtypes
+    bfloat16, which numpy does not class as np.floating)."""
+    return bool(
+        np.issubdtype(np.dtype(dtype), np.floating)
+        or "bfloat16" in str(dtype)
+    )
+
+
+def _payload_nbytes(payload: Any) -> int:
+    """Bytes of one bucket as it is handled: an ndarray, or a compressed
+    wire (codes + scales)."""
+    if is_compressed_wire(payload):
+        return int(payload.payload.nbytes + payload.scales.nbytes)
+    return int(getattr(payload, "nbytes", 0))
+
+
+@functools.lru_cache(maxsize=None)
+def _average_on_device() -> Callable[[Any, Any], Any]:
+    """The AVG normalisation of one landed leaf as a jitted computation.
+
+    It donates its input, so the quotient reuses the buffer the H2D just
+    filled and the step's HBM peak does not grow by a leaf; the divisor is a
+    float32 runtime scalar, so a quorum that goes 4 -> 3 -> 4 compiles
+    nothing new (one executable per leaf geometry). Bit for bit numpy's
+    result, except that XLA flushes a subnormal input or quotient (under
+    2**-126) to zero where numpy keeps it. Built on first use: a process
+    that only moves host arrays never gets here."""
+    import jax
+
+    def average_landed_leaf(x: Any, n: Any) -> Any:  # its name in a trace
+        return (x / n).astype(x.dtype)
+
+    return jax.jit(average_landed_leaf, donate_argnums=0)
+
+
+def _average(x: Any, num_participants: int, landed: bool = False) -> Any:
+    """A reduced SUM divided by the participants, rounded once back to its
+    own dtype (bf16 / f16 in, float32 quotient): the one expression of the
+    AVG normalisation, run where ``x`` is. ``landed`` says ``x`` is a leaf
+    ``place`` has just put where its original lives, a private buffer: on a
+    device it is then divided there, in place. Anything else (a numpy
+    array; a device-native PG's own result) gets a fresh array."""
+    import jax
+
+    if landed and isinstance(x, jax.Array):
+        return _average_on_device()(x, np.float32(num_participants))
+    return (x / num_participants).astype(x.dtype)
+
+
+def leaf_placer() -> Callable[[Any, Any], Any]:
+    """``place(orig, host)``: one reduced slice put where its original leaf
+    lives (a numpy leaf stays numpy). One placer an allreduce, shared by
+    every landing and by the zeros of its error path, so all of them land
+    leaves through identical expressions.
+
+    Staleness check at RESOLVE time: if the input leaf's sharding
+    references a device client that is no longer the live backend
+    (ProcessGroupXLA tore down + rejoined its per-quorum jax.distributed
+    world between the caller computing the values and this resolve), a
+    device_put onto it can SUCCEED and produce an array the next jitted
+    computation rejects as "incompatible devices". Such leaves land on the
+    live backend instead — _sync_device_world re-lands the user's own state
+    the same way at should_commit. LAZY on purpose: jax.devices()
+    initializes the backend, and a pure-host tree must never trigger that
+    (a process that only moves host arrays should not take the chip)."""
+    import jax
+
+    live_client = [False]
+
+    def _is_live(sharding: Any) -> bool:
+        if live_client[0] is False:
+            try:
+                live_client[0] = getattr(jax.devices()[0], "client", None)
+            except Exception:  # noqa: BLE001
+                live_client[0] = None
+        if live_client[0] is None:
+            return True
+        try:
+            dev = next(iter(sharding.device_set))
+            return getattr(dev, "client", None) is live_client[0]
+        except Exception:  # noqa: BLE001
+            return False
+
+    def place(orig: Any, host: Any) -> Any:
+        import jax.numpy as jnp
+
+        if isinstance(orig, jax.Array):
+            if _is_live(orig.sharding):
+                return jax.device_put(host, orig.sharding)
+            return jnp.asarray(np.asarray(host))
+        return np.asarray(host)
+
+    return place
+
+
+def _no_span(name: str, **args: Any) -> Any:
+    return contextlib.nullcontext()
+
+
+def land_reduced(
+    flat: Any,
+    leaves: Sequence[Any],
+    plan: Optional[BucketPlan],
+    bucket: int,
+    divisor: Optional[int],
+    place: Callable[[Any, Any], Any],
+    span: Callable[..., Any] = _no_span,
+) -> List[Tuple[int, Any]]:
+    """One reduced array — the plan's bucket ``bucket``, or with no plan the
+    lone leaf of that index — to ``(leaf_index, leaf)`` pairs: sliced
+    (views), each slice placed where its original lives
+    (:func:`leaf_placer`), and with a ``divisor`` (the participants, under
+    AVG) divided by it where it then is: a device leaf on its device
+    (dispatched from here, nothing waits for it), a numpy leaf in numpy, so
+    a pure-host tree still never initialises a backend. The no-plan path
+    and every bucket of the pipeline land through this one function, so
+    they stay bit-identical on every backend. ``span(name, **args)``: the
+    pipeline's allreduce/h2d and allreduce/divide; the no-plan path records
+    none."""
+    import jax
+
+    idxs = [bucket] if plan is None else plan.groups[bucket]
+    sized = {"bytes": _payload_nbytes(flat), "leaves": len(idxs)}
+    on_device_plane = isinstance(flat, jax.Array)
+    if divisor and on_device_plane:
+        # a device-native PG's result may be a buffer the PG (or the
+        # caller) still holds: divided where it is, into a fresh one
+        with span("divide", where="device", **sized):
+            flat = _average(flat, divisor)
+    pairs = (
+        [(bucket, flat)] if plan is None
+        else unpack_bucket(flat, plan, bucket)
+    )
+    # on a device leaf place is jax.device_put: as far as it returns before
+    # the bytes have moved, h2d is the enqueue
+    with span("h2d", **sized):
+        pairs = [(i, place(leaves[i], v)) for i, v in pairs]
+    if divisor and not on_device_plane:
+        on_device = sum(isinstance(leaves[i], jax.Array) for i in idxs)
+        where = (
+            "device" if on_device == len(idxs)
+            else "mixed" if on_device else "host"
+        )
+        with span("divide", where=where, **sized):
+            pairs = [(i, _average(v, divisor, landed=True)) for i, v in pairs]
+    return pairs
+
+
+# ---------------------------------------------------------------------------
+# the schedule: which thread runs which stage of which bucket
+
+
+def _settle(
+    fut: Future, result: Any = None, exc: Optional[BaseException] = None
+) -> None:
+    """Resolve ``fut`` unless somebody already did. The wire, the stage
+    deadline, the submission backstop and the shutdown sweep all race to
+    the same futures; the loser is a no-op."""
+    try:
+        if exc is not None:
+            fut.set_exception(exc)
+        else:
+            fut.set_result(result)
+    except RuntimeError:
+        pass
+
+
+def _settle_from(fut: Future, src: Future) -> None:
+    """:func:`_settle` ``fut`` with the outcome of the completed ``src``."""
+    exc = src.exception()
+    if exc is not None:
+        _settle(fut, exc=exc)
+    else:
+        _settle(fut, src.value())
+
+
+def _covered_seconds(
+    start: float, end: float, intervals: List[Any]
+) -> float:
+    """Length of ``[start, end]`` covered by the union of ``intervals``."""
+    if end <= start:
+        return 0.0
+    clipped = sorted(
+        (max(start, a), min(end, b))
+        for a, b in intervals
+        if b > start and a < end
+    )
+    total = 0.0
+    cur_s: Optional[float] = None
+    cur_e = 0.0
+    for a, b in clipped:
+        if cur_s is None:
+            cur_s, cur_e = a, b
+        elif a <= cur_e:
+            cur_e = max(cur_e, b)
+        else:
+            total += cur_e - cur_s
+            cur_s, cur_e = a, b
+    if cur_s is not None:
+        total += cur_e - cur_s
+    return total
+
+
+def _pipeline_overlap_stats(marks: List[Dict[str, Any]]) -> Dict[str, float]:
+    """Summarize one bucketed allreduce's per-bucket stage marks.
+
+    ``marks[i]`` maps stage name (``pack`` / ``wire`` / ``unpack``) to a
+    ``(start, end)`` perf_counter interval; stages a bucket never reached
+    (mid-stream failure, timeout) are simply absent. ``overlap_efficiency``
+    is Σᵢ |wireᵢ ∩ ∪ⱼ≠ᵢ(packⱼ ∪ wireⱼ ∪ unpackⱼ)| / Σᵢ |wireᵢ| — the
+    fraction of wire time hidden behind other buckets' pipeline stages
+    (a lower bound: overlap with caller compute is not observable here).
+    A single-bucket plan has nothing to hide behind and reports 0.0."""
+    pack_s = sum(e - s for m in marks if "pack" in m for s, e in [m["pack"]])
+    wire_s = sum(e - s for m in marks if "wire" in m for s, e in [m["wire"]])
+    unpack_s = sum(
+        e - s for m in marks if "unpack" in m for s, e in [m["unpack"]]
+    )
+    hidden = 0.0
+    for i, m in enumerate(marks):
+        if "wire" not in m:
+            continue
+        s, e = m["wire"]
+        others = [
+            iv
+            for j, mj in enumerate(marks)
+            if j != i
+            for iv in mj.values()
+        ]
+        hidden += _covered_seconds(s, e, others)
+    return {
+        "allreduce_pack_s": pack_s,
+        "allreduce_wire_s": wire_s,
+        "allreduce_unpack_s": unpack_s,
+        "allreduce_buckets": float(len(marks)),
+        "overlap_efficiency": (hidden / wire_s) if wire_s > 0 else 0.0,
+    }
+
+
+class _BucketOp:
+    """One bucketed allreduce in flight: what its stages, each on its own
+    thread, share. ``final`` resolves to the landed leaves in leaf order
+    once every bucket has landed; it is fed from the join of
+    ``bucket_futs`` but owned here, so that the stage deadline and the
+    shutdown sweep can fail it directly."""
+
+    def __init__(
+        self,
+        leaves: Sequence[Any],
+        plan: BucketPlan,
+        divisor: Optional[int],
+        place: Callable[[Any, Any], Any],
+        parent: Optional[int],
+        new_id: Callable[[], int],
+    ) -> None:
+        n = len(plan)
+        self.leaves, self.plan = leaves, plan
+        self.divisor, self.place, self.parent = divisor, place, parent
+        self.bucket_bytes = [
+            size * np.dtype(dtype).itemsize
+            for size, dtype in zip(plan.sizes, plan.dtypes)
+        ]
+        # per-bucket (start, end) wall-clock marks per stage, for
+        # pack_s/wire_s/unpack_s + overlap_efficiency in timings()
+        self.marks: List[Dict[str, Any]] = [{} for _ in range(n)]
+        # ids of the three stage spans per bucket (recorded from the marks
+        # once the op resolves), for their children, and what the PG
+        # stamped on each bucket's op: (enqueued, fn started, fn ended) ->
+        # allreduce/wire_run
+        self.stage_ids = [
+            {st: new_id() for st in ("pack", "wire", "unpack")}
+            for _ in range(n)
+        ]
+        self.wire_runs: List[Any] = [None] * n
+        self.bucket_futs: List[Future] = [Future() for _ in range(n)]
+        self.final: Future = Future()
+        join_futures(self.bucket_futs).then(self._assemble).add_done_callback(
+            functools.partial(_settle_from, self.final)
+        )
+
+    def _assemble(self, joined: Future) -> List[Any]:
+        placed: Dict[int, Any] = {}
+        for pairs in joined.value():
+            placed.update(pairs)
+        return [placed[i] for i in range(len(self.leaves))]
+
+
+class BucketPipeline:
+    """The data plane of a managed allreduce: a process group, a span
+    recorder and a :class:`BufferPool`, and which thread runs which stage.
+
+    A tree with a plan takes :meth:`allreduce_buckets`: one PG collective
+    PER BUCKET, three stages a bucket: pack (:func:`capture` on the caller's
+    thread, :func:`stage` on the one staging thread), wire (the PG's
+    dispatch thread, or XLA), unpack (:func:`land_reduced` on the one unpack
+    thread). Bucket i+1 packs while bucket i rides the wire and bucket i−1
+    unpacks; no stage ever waits for the LAST bucket's wire. A tree without
+    one (a single leaf, a cap of 0, the monolithic quantized exchange) takes
+    :meth:`allreduce_leaves`: one collective carrying every leaf, landed by
+    the same function. Numerics are bit-identical between the two:
+    per-bucket collectives reduce each flat independently just like one call
+    carrying the list.
+
+    Only a device-native PG (ProcessGroupXLA) bypasses the staging thread:
+    it takes jax.Arrays straight through (the collective runs on device
+    over ICI/DCN with no host staging, the quantized one too: the Pallas
+    kernels quantize there and the payload ships as packed uint8 device
+    arrays, collectives.py _pack_wire_device), and its ops rendezvous by
+    (kind, seq) so issue order across threads cannot mismatch. On a host PG
+    EVERYTHING — including the quantized exchange, whose alltoall/allgather
+    would otherwise be issued from an unordered helper thread — goes through
+    the one ordered staging thread: the host exchange matches messages
+    purely by arrival order, and cross-replica issue order is the contract.
+
+    ``on_timings(stats)`` receives what ``Manager.timings()`` shows of the
+    pipeline (``stage_pool_hit_share`` from the staging thread, the stage
+    sums from :meth:`record_timings`)."""
+
+    def __init__(
+        self,
+        pg: Any,
+        tracer: Any,
+        pool: BufferPool,
+        on_timings: Callable[[Dict[str, float]], None] = lambda stats: None,
+    ) -> None:
+        self._pg = pg
+        self._tracer = tracer
+        self._pool = pool
+        self._on_timings = on_timings
+        # one ordered worker for host-plane staging: D2H + wire dispatch off
+        # the train loop, issue order preserved across replicas
+        self._staging_executor = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="torchft_stage"
+        )
+        # stage 3: per-bucket unpack + device landing runs here so it
+        # neither blocks the PG's dispatch thread (which would serialize
+        # the NEXT bucket's wire behind this bucket's unpack) nor waits for
+        # the last bucket's wire
+        self._unpack_executor = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="torchft_unpack"
+        )
+        # (executor future, staged future) pairs still in flight: shutdown
+        # must fail the staged futures of cancelled tasks or their waiters
+        # stall for the full timeout. Guarded together with the shutdown
+        # flag so a submit can't race the shutdown sweep.
+        self._staged_pending: List[Any] = []
+        self._staged_lock = threading.Lock()
+        self._staging_down = False
+        # per-(plan, bucket) error-feedback residuals: what quantization
+        # rounded away this step is added back before quantizing the next
+        # step, so the compression error stays bounded instead of
+        # accumulating (LocalSGD/DiLoCo convergence depends on this).
+        # Keyed by plan identity via weakref so evicted plans drop their
+        # residual buffers with them; buffers come from the BufferPool.
+        self._ef_residuals: "weakref.WeakKeyDictionary" = (
+            weakref.WeakKeyDictionary()
+        )
+        self._ef_lock = threading.Lock()
+
+    @property
+    def device_native(self) -> bool:
+        return bool(getattr(self._pg, "device_native", False))
+
+    # ------------------------------------------------------------ schedule
+    def submit(
+        self, stage_fn: Callable[[], None], fut: Future, timeout: float
+    ) -> None:
+        """Queue ``stage_fn`` (stage and dispatch, never the wire) on the
+        staging thread, with ``fut``, the future its op resolves, bounded
+        twice. The tight deadline is armed when staging BEGINS (not at
+        submission: queue time behind an in-flight quantized sync must not
+        count against this op) and spans the WHOLE staged op — D2H,
+        dispatch, AND the wire phase the PG worker resolves via callback
+        after ``stage_fn`` returns; disarmed at dispatch it would leave a
+        never-resolving wire (hung peer whose abort path also fails)
+        unbounded. It is cancelled the moment ``fut`` settles.
+
+        The submission-time depth-aware BACKSTOP: if an op ahead of this
+        one wedges its stage forever (D2H against a hung device, a dispatch
+        that never returns), ``stage_fn`` never runs and the tight deadline
+        is never armed. Healthy queue time is bounded by one deadline per op
+        ahead (each stage blocks at most ``timeout``), so depth+2 slots
+        never fire on a healthy queue; both timers race to the same
+        :func:`_settle` and the loser is a no-op."""
+
+        def expire() -> None:
+            _settle(fut, exc=TimeoutError("allreduce staging timed out"))
+
+        def run() -> None:
+            try:
+                cancel = arm_deadline(expire, timeout)
+                fut.add_done_callback(lambda _f: cancel())
+                stage_fn()
+            except Exception as e:  # noqa: BLE001
+                _settle(fut, exc=e)
+
+        # submit + register atomically vs the shutdown sweep: a pair
+        # appended after the sweep would never have its staged future
+        # failed (full-timeout stall), and a submit after executor shutdown
+        # raises anyway
+        with self._staged_lock:
+            if self._staging_down:
+                raise RuntimeError("manager is shut down")
+            depth = len(self._staged_pending)
+            backstop_cancel = arm_deadline(expire, (depth + 2) * timeout)
+            fut.add_done_callback(lambda _f: backstop_cancel())
+            pair = (self._staging_executor.submit(run), fut)
+            self._staged_pending.append(pair)
+
+        def _unpin(_f: Future) -> None:
+            # release the (gradient-sized) result reference as soon as the
+            # wire resolves, not at the next allreduce
+            with self._staged_lock:
+                try:
+                    self._staged_pending.remove(pair)
+                except ValueError:
+                    pass
+
+        fut.add_done_callback(_unpin)
+
+    def shutdown(self, wait: bool = True) -> None:
+        """Stop both workers. On a non-waiting shutdown queued (not-yet-run)
+        staging tasks are cancelled: they would otherwise dispatch against
+        the PG after its shutdown, spuriously reporting errors on a
+        torn-down manager — and their staged futures are failed so any
+        waiter unblocks immediately instead of riding out the full
+        timeout."""
+        with self._staged_lock:
+            self._staging_down = True
+        self._staging_executor.shutdown(wait=wait, cancel_futures=not wait)
+        # cancelled bucket unpacks leave their bucket futures unresolved —
+        # the aggregate is bounded by the stage deadline / the sweep below,
+        # so no waiter stalls past the timeout
+        self._unpack_executor.shutdown(wait=wait, cancel_futures=not wait)
+        with self._staged_lock:
+            pending, self._staged_pending = self._staged_pending, []
+        for exec_fut, staged_fut in pending:
+            if exec_fut.cancelled():
+                _settle(
+                    staged_fut,
+                    exc=RuntimeError("manager shut down before dispatch"),
+                )
+
+    # ------------------------------------------------------ with a plan
+    def allreduce_buckets(
+        self,
+        leaves: Sequence[Any],
+        plan: BucketPlan,
+        pg_op: Any,
+        *,
+        participating: bool,
+        divisor: Optional[int],
+        place: Callable[[Any, Any], Any],
+        timeout: float,
+        compress: str = "off",
+        parent: Optional[int] = None,
+    ) -> _BucketOp:
+        """One collective a bucket of ``plan``. Returns the op in flight:
+        ``op.bucket_futs[i]`` resolves as bucket ``i`` lands, ``op.final`` to
+        the landed leaves, in leaf order (``divisor``: the AVG's
+        participants, None for a plain SUM; ``place``: :func:`leaf_placer`).
+        A non-participant contributes zeros. ``compress`` ("off" | "fp8" |
+        "int8") is the host plane's wire codec, with error feedback.
+        ``parent``: the span the stages hang under."""
+        op = _BucketOp(
+            leaves, plan, divisor, place, parent, self._tracer.new_id
+        )
+        if self.device_native:
+            self._issue_on_device(op, pg_op, participating)
+            return op
+        # host plane: capture on the caller thread, then ONE staging task
+        # walks the buckets — D2H bucket i, non-blocking dispatch, straight
+        # on to bucket i+1 while the PG's dispatch thread runs the wire. A
+        # single task keeps per-plan dispatch atomic across concurrent
+        # callers, preserving cross-replica arrival order (the SPMD
+        # contract of the host exchange).
+        captured = None
+        if participating:
+            with self._tracer.span(
+                "capture", cat="allreduce", parent=parent,
+                bytes=sum(op.bucket_bytes),
+            ):
+                captured = capture(leaves, plan, self._pool)
+        # Non-float buckets ride uncompressed — the decision depends only
+        # on the shared plan + mode, so it is SPMD-consistent across
+        # replicas. Non-participants compress their zero contribution too
+        # (the ring needs uniform wire geometry) but never touch the EF
+        # residuals.
+        modes = [
+            compress if is_float_dtype(dtype) else "off"
+            for dtype in plan.dtypes
+        ]
+        ef_store = (
+            self._bucket_residuals(plan)
+            if compress != "off" and participating
+            else None
+        )
+        self.submit(
+            functools.partial(
+                self._stage_buckets, op, captured, pg_op, modes, ef_store,
+                time.perf_counter(),
+            ),
+            op.final,
+            timeout,
+        )
+        return op
+
+    def _issue_on_device(
+        self, op: _BucketOp, pg_op: Any, participating: bool
+    ) -> None:
+        """Device plane: issue per-bucket collectives straight from the
+        caller thread — ProcessGroupXLA rendezvouses ops by (kind, seq), and
+        per-bucket ops let XLA overlap ICI transfers with adjacent
+        compute."""
+        import jax
+        import jax.numpy as jnp
+
+        plan = op.plan
+        t0p = time.perf_counter()
+        if participating:
+            up = [
+                l if isinstance(l, jax.Array) else jnp.asarray(l)
+                for l in op.leaves
+            ]
+            dev_flats, _ = pack(up, plan)
+        else:
+            # zero contribution, built directly at bucket shape (cheaper
+            # than zeroing per leaf then packing)
+            dev_flats = [
+                jnp.zeros(size, dtype)
+                for size, dtype in zip(plan.sizes, plan.dtypes)
+            ]
+        op.marks[0]["pack"] = (t0p, time.perf_counter())
+        for i, flat in enumerate(dev_flats):
+            t0w = time.perf_counter()
+            w = self._pg.allreduce([flat], pg_op)
+            w.get_future().add_done_callback(
+                functools.partial(self._wire_done, op, i, t0w, None)
+            )
+
+    def _stage_buckets(
+        self,
+        op: _BucketOp,
+        captured: Optional[List[Any]],
+        pg_op: Any,
+        modes: List[str],
+        ef_store: Optional[List[Any]],
+        t_submit: float,
+    ) -> None:
+        """Stages 1 and 2 of every bucket of a host-plane op, on the
+        staging thread: host memory (:func:`stage`), the codec, the
+        dispatch. It never waits for a wire."""
+        tracer, plan = self._tracer, op.plan
+        try:
+            # pool buffers this op's device buckets took, and how many of
+            # them were recycled ones
+            acquired = hits = 0
+            for i in range(len(plan)):
+                t0b = time.perf_counter()
+                pk_id = op.stage_ids[i]["pack"]
+                if captured is None:
+                    host_flat, pooled_buf, _info = stage(
+                        None, plan, i, self._pool
+                    )
+                else:
+                    if isinstance(captured[i], Pieces):
+                        # the wait the fetch below would make anyway (the
+                        # backward pass and the device split still running),
+                        # under its own name: d2h is then the copy alone
+                        with tracer.span(
+                            "grad_wait", cat="allreduce", parent=pk_id,
+                            bucket=i,
+                        ):
+                            captured[i].block_until_ready()
+                    # bucket 0 carries how long the staging worker took to
+                    # get to this op
+                    with tracer.span(
+                        "d2h", cat="allreduce", parent=pk_id, bucket=i,
+                        **({"queued_us": int((t0b - t_submit) * 1e6)}
+                           if i == 0 else {}),
+                    ) as sp:
+                        host_flat, pooled_buf, info = stage(
+                            captured, plan, i, self._pool
+                        )
+                        sp.args.update(info)
+                    if "pooled" in info:
+                        acquired += 1
+                        hits += info["pooled"]
+                payload: Any = host_flat
+                if modes[i] != "off":
+                    # quantize inside the pack stage so pack_s absorbs the
+                    # codec cost and overlap accounting stays honest
+                    with tracer.span(
+                        "codec", cat="allreduce", parent=pk_id, bucket=i,
+                        bytes=host_flat.nbytes,
+                    ) as sp:
+                        payload = self._compress_bucket_ef(
+                            host_flat, modes[i], plan.dtypes[i], ef_store, i
+                        )
+                        sp.args["bytes_out"] = _payload_nbytes(payload)
+                with tracer.span(
+                    "dispatch", cat="allreduce", parent=pk_id, bucket=i
+                ):
+                    w = self._pg.allreduce([payload], pg_op)
+                t1b = time.perf_counter()
+                op.marks[i]["pack"] = (t0b, t1b)
+                w.get_future().add_done_callback(
+                    functools.partial(self._wire_done, op, i, t1b, pooled_buf)
+                )
+            if acquired:
+                self._on_timings({"stage_pool_hit_share": hits / acquired})
+        except Exception as e:  # noqa: BLE001
+            for bf in op.bucket_futs:
+                _settle(bf, exc=e)
+
+    def _wire_done(
+        self, op: _BucketOp, i: int, t0w: float, pooled_buf: Any, f: Future
+    ) -> None:
+        """Bucket ``i``'s collective has resolved. On the host plane this
+        runs on the PG dispatch thread — keep it tiny: stamp, then hand
+        unpack to the unpack worker so the NEXT bucket's wire starts
+        immediately. The device plane lands where the callback runs."""
+        op.marks[i]["wire"] = (t0w, time.perf_counter())
+        host_plane = not self.device_native
+        if host_plane:
+            # ProcessGroupHost leaves these on its op's future; another
+            # PG's has none
+            op.wire_runs[i] = getattr(f, "stamps", None)
+        try:
+            flat = f.value()[0]
+        except Exception as e:  # noqa: BLE001
+            _settle(op.bucket_futs[i], exc=e)
+            return
+        if not host_plane:
+            self._land(op, i, flat, None)
+            return
+        try:
+            self._unpack_executor.submit(self._land, op, i, flat, pooled_buf)
+        except RuntimeError as e:  # shutdown
+            _settle(op.bucket_futs[i], exc=e)
+
+    def _land(
+        self, op: _BucketOp, i: int, flat: Any, pooled_buf: Any
+    ) -> None:
+        """Stage 3, off the PG dispatch thread: slice + landing + AVG divide
+        (:func:`land_reduced`) for ONE bucket. A failure here fails the
+        aggregate via the join; earlier buckets' landed slices are only
+        reachable through the aggregate, so a mid-stream error can never
+        leak a partially-applied reduction."""
+        try:
+            t0u = time.perf_counter()
+            # a PG that hands its input back as its result (world-of-one
+            # short circuits): the landed leaves may be views of, or
+            # transfers still reading, the staging buffer
+            passed_through = (
+                pooled_buf is not None
+                and isinstance(flat, np.ndarray)
+                and np.shares_memory(flat, pooled_buf)
+            )
+            # the bucket's first unpack child carries how long it sat
+            # behind earlier buckets on the one unpack worker (device
+            # plane: unpack runs in the wire's callback)
+            first = {"queued_us": int(
+                (t0u - op.marks[i]["wire"][1]) * 1e6
+            )} if "wire" in op.marks[i] else {}
+
+            def span(name: str, **args: Any) -> Any:
+                args.update(first)
+                first.clear()
+                return self._tracer.span(
+                    name, cat="allreduce",
+                    parent=op.stage_ids[i]["unpack"], bucket=i, **args,
+                )
+
+            if is_compressed_wire(flat):
+                # the bucket rode the wire compressed; the codes carry the
+                # reduced SUM, restored here at the plan's bucket dtype so
+                # slice/land/divide below run the exact uncompressed
+                # expressions
+                with span("decode", bytes=_payload_nbytes(flat)):
+                    flat = decompress_bucket(flat)
+            pairs = land_reduced(
+                flat, op.leaves, op.plan, i, op.divisor, op.place, span
+            )
+            op.marks[i]["unpack"] = (t0u, time.perf_counter())
+            if (
+                pooled_buf is not None
+                and not passed_through
+                and not op.final.done()
+            ):
+                # recycle this bucket's staging buffer the moment it lands:
+                # on success only (an op that already failed or timed out
+                # drops it: its wire thread may still read the buffer),
+                # never when the PG passed it through
+                self._pool.release(pooled_buf)
+            _settle(op.bucket_futs[i], pairs)
+        except Exception as e:  # noqa: BLE001
+            _settle(op.bucket_futs[i], exc=e)
+
+    def record_timings(self, op: _BucketOp) -> None:
+        """Fold one resolved op's per-bucket stage marks into
+        ``on_timings``: summed ``allreduce_pack_s`` /
+        ``allreduce_wire_s`` / ``allreduce_unpack_s``, the bucket count, and
+        ``overlap_efficiency`` — the fraction of total wire time that ran
+        concurrently with OTHER buckets' pipeline stages (a lower bound on
+        the real win: overlap with the caller's own compute, e.g. the next
+        microbatch's grad_fn, is invisible from here); and record the stage
+        spans, which are known only now, from the same marks."""
+        self._on_timings(_pipeline_overlap_stats(op.marks))
+        for i, mark in enumerate(op.marks):
+            for name in ("pack", "wire", "unpack"):
+                if name not in mark:
+                    continue
+                t0_pc, t1_pc = mark[name]
+                self._tracer.record_rel(
+                    name, cat="allreduce", t0_pc=t0_pc, t1_pc=t1_pc,
+                    id=op.stage_ids[i][name], parent=op.parent, bucket=i,
+                )
+            run = op.wire_runs[i]
+            if run is not None:
+                # what the PG's dispatch thread did for this bucket, from
+                # the stamps it left on the op's future: fn(comm) alone
+                # (a copy at a world of one, the ring otherwise); the time
+                # the op sat in its queue behind earlier buckets is an arg
+                t_enq, t_run0, t_run1 = run
+                self._tracer.record_rel(
+                    "wire_run", "allreduce", t_run0, t_run1,
+                    parent=op.stage_ids[i]["wire"], bucket=i,
+                    bytes=op.bucket_bytes[i],
+                    world=self._pg.size(),
+                    queued_us=int((t_run0 - t_enq) * 1e6),
+                )
+
+    # ------------------------------------------------------- compression
+    def _bucket_residuals(self, plan: BucketPlan) -> List[Any]:
+        """Per-bucket error-feedback residual slots for one plan.
+
+        Keyed by plan identity (plans are cached and reused every step, so
+        the same tree keeps the same slots); weakref-keyed so an evicted
+        plan drops its residual buffers with it. Slots start None and are
+        allocated from the BufferPool on first compression."""
+        with self._ef_lock:
+            store = self._ef_residuals.get(plan)
+            if store is None:
+                store = [None] * len(plan)
+                self._ef_residuals[plan] = store
+            return store
+
+    def _compress_bucket_ef(
+        self,
+        host_flat: np.ndarray,
+        mode: str,
+        out_dtype: Any,
+        store: Optional[List[Any]],
+        i: int,
+    ) -> Any:
+        """Quantize one packed bucket for the wire, with error feedback.
+
+        The residual — everything rowwise quantization rounded away this
+        step — is carried into the NEXT step's bucket before quantizing,
+        so the compression error stays bounded (standard EF-SGD) instead
+        of accumulating across LocalSGD/DiLoCo syncs. ``store`` is None
+        for non-participants (zero contribution, nothing to feed back).
+        Runs on the single staging worker, so residual updates for one
+        plan never race."""
+        resid = store[i] if store is not None else None
+        if resid is not None:
+            # one fused pass: the add IS the private f32 copy
+            work = host_flat + resid
+        else:
+            work = np.asarray(host_flat, dtype=np.float32)
+        wire = compress_bucket(work, mode, dtype=out_dtype)
+        if store is not None:
+            resid = store[i]
+            if resid is None:
+                resid = self._pool.acquire(work.size, np.float32)
+                store[i] = resid
+            np.subtract(
+                work, decompress_bucket(wire, np.float32), out=resid
+            )
+        return wire
+
+    # ---------------------------------------------------- without a plan
+    def allreduce_leaves(
+        self,
+        leaves: Sequence[Any],
+        pg_op: Any,
+        *,
+        quantize: bool,
+        participating: bool,
+        divisor: Optional[int],
+        place: Callable[[Any, Any], Any],
+        timeout: float,
+        parent: Optional[int] = None,
+    ) -> Future:
+        """The no-plan path: ONE collective carrying every leaf (or, with
+        ``quantize``, ``collectives.allreduce_quantized``: never
+        pre-bucketed — it already concatenates into one flat wire buffer,
+        and packing first would shift the fp8 rowwise-scale boundaries).
+        Resolves to the landed leaves, in leaf order, each through
+        :func:`land_reduced` as a bucket of the pipeline is."""
+        if self.device_native:
+            fut = self._issue_leaves_on_device(
+                leaves, pg_op, quantize, participating
+            )
+        else:
+            fut = Future()
+            self.submit(
+                self._leaf_stager(
+                    leaves, pg_op, quantize, participating, timeout, parent,
+                    fut,
+                ),
+                fut,
+                timeout,
+            )
+
+        def land(f: Future) -> List[Any]:
+            out: List[Any] = [None] * len(leaves)
+            for b, flat in enumerate(f.value()):
+                for i, v in land_reduced(flat, leaves, None, b, divisor, place):
+                    out[i] = v
+            return out
+
+        return fut.then(land)
+
+    def _issue_leaves_on_device(
+        self, leaves: Sequence[Any], pg_op: Any, quantize: bool,
+        participating: bool,
+    ) -> Future:
+        import jax
+        import jax.numpy as jnp
+
+        dev_leaves = [
+            l if isinstance(l, jax.Array) else jnp.asarray(l) for l in leaves
+        ]
+        if not participating:
+            dev_leaves = [jnp.zeros_like(h) for h in dev_leaves]
+        if quantize:
+            from torchft_tpu.collectives import allreduce_quantized
+
+            work = allreduce_quantized(dev_leaves, pg_op, self._pg)
+        else:
+            work = self._pg.allreduce(dev_leaves, pg_op)
+        return work.get_future()
+
+    def _leaf_stager(
+        self,
+        leaves: Sequence[Any],
+        pg_op: Any,
+        quantize: bool,
+        participating: bool,
+        timeout: float,
+        parent: Optional[int],
+        staged_fut: Future,
+    ) -> Callable[[], None]:
+        """Capture ``leaves`` now, on the caller's thread (the staging
+        thread reads them AFTER allreduce() returns, by which time the
+        caller's next jitted step may have donated the device buffers or
+        overwritten a reused numpy buffer), and return what the staging
+        thread runs. jax.Arrays get a device-side copy (HBM bandwidth, async
+        dispatch — far cheaper than blocking the train loop on the D2H
+        transfer); numpy leaves get a host memcpy. Non-participants skip
+        the capture entirely — they contribute zeros built from shapes
+        alone (the reference zeroes the buffer in place; arrays are
+        immutable here)."""
+        import jax
+        import jax.numpy as jnp
+
+        captured = (
+            [
+                jnp.copy(l) if isinstance(l, jax.Array)
+                else np.array(l, copy=True)
+                for l in leaves
+            ]
+            if participating
+            else None
+        )
+        # a non-participant's contribution: zeros, from shapes alone
+        zero_specs = (
+            None if participating
+            else [(np.shape(l), leaf_dtype(l)) for l in leaves]
+        )
+        tracer = self._tracer
+
+        def stage_leaves() -> None:
+            """D2H + dispatch only — the PG's own ordered worker runs the
+            wire, and the result chains in via callback. Blocking here
+            would serialize overlapped allreduces on this one thread and
+            charge queue time against later calls' deadlines. EXCEPTION:
+            the quantized exchange runs to completion here — its alltoall
+            and allgather must be issued in staged order (they would
+            otherwise race other staged ops from its helper thread), and
+            quantized syncs are rare boundary events (DiLoCo) where the
+            serialization is acceptable."""
+            if captured is None:
+                host_leaves = [np.zeros(s, d) for s, d in zero_specs]
+            elif quantize:
+                # jax copies stay as they are: single-device trees take
+                # the Pallas engine
+                host_leaves = captured
+            else:
+                with tracer.span(
+                    "d2h", cat="allreduce", parent=parent
+                ) as sp:
+                    host_leaves = [np.asarray(l) for l in captured]
+                    sp.args["bytes"] = sum(h.nbytes for h in host_leaves)
+            if quantize:
+                from torchft_tpu.collectives import allreduce_quantized
+
+                w = allreduce_quantized(host_leaves, pg_op, self._pg)
+                _settle(staged_fut, w.get_future().wait(timeout))
+                return
+            with tracer.span("dispatch", cat="allreduce", parent=parent):
+                w = self._pg.allreduce(host_leaves, pg_op)
+            w.get_future().add_done_callback(
+                functools.partial(_settle_from, staged_fut)
+            )
+
+        return stage_leaves
 
 
 # ---------------------------------------------------------------------------
@@ -513,7 +1522,7 @@ def pack_group(arrays: List[Any], idxs: List[int]) -> tuple:
 
         flat = jnp.concatenate([arrays[i].reshape(-1) for i in idxs])
     else:
-        flat = np.empty(offset, dtype=_leaf_dtype(arrays[idxs[0]]))
+        flat = np.empty(offset, dtype=leaf_dtype(arrays[idxs[0]]))
         for (i, off, size, _shape) in metas:
             flat[off : off + size] = np.asarray(arrays[i]).reshape(-1)
     return flat, metas

@@ -26,10 +26,10 @@ def ft_allreduce_gradients(
     Blocking convenience over the managed allreduce (reference comm-hook
     behavior, ddp.py:66-79): on communicator failure the step's gradients
     resolve to zeros and ``manager.should_commit()`` will discard the step.
-    Routes through the streaming bucket pipeline (bit-identical to the
-    serial path when uncompressed) so buckets unpack while later ones are
-    still on the wire. ``should_quantize=True`` streams too where the
-    Manager supports it (host PG, streaming on) — buckets ride the wire
+    Routes through the bucket pipeline (bit-identical to one collective
+    for the whole tree when uncompressed) so buckets unpack while later
+    ones are still on the wire. ``should_quantize=True`` streams too on a
+    host PG — buckets ride the wire
     fp8/int8-compressed with error feedback — and otherwise falls back to
     the monolithic quantized collective inside the Manager.
     """

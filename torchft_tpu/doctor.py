@@ -203,10 +203,7 @@ def check_health_env() -> Result:
 def check_compress_env() -> Result:
     """``TORCHFT_COMPRESS`` sanity: the value resolves to a known codec
     (funnelled through the same ``resolve_compress_mode`` the Manager
-    uses, so the doctor and the trainer reject identically), and if
-    compression is ON while bucket streaming is forced OFF the operator
-    is warned — compressed buckets ride the streaming pipeline, so the
-    knob silently does nothing for unquantized trees without it."""
+    uses, so the doctor and the trainer reject identically)."""
     try:
         from torchft_tpu.ops.quantization import resolve_compress_mode
 
@@ -218,14 +215,6 @@ def check_compress_env() -> Result:
         )
     if mode == "off":
         return True, "compression off (default wire, bit-identical path)"
-    stream_raw = os.environ.get("TORCHFT_STREAM_BUCKETS", "").strip().lower()
-    if stream_raw in ("0", "false", "no", "off"):
-        return None, (
-            f"TORCHFT_COMPRESS={mode} but TORCHFT_STREAM_BUCKETS="
-            f"{stream_raw!r} disables the streaming pipeline compression "
-            "rides — buckets will ship uncompressed; re-enable streaming "
-            "or unset TORCHFT_COMPRESS"
-        )
     return True, f"compression {mode} (rowwise codec, error feedback on)"
 
 

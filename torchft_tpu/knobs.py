@@ -80,9 +80,6 @@ REGISTRY: Dict[str, Knob] = {
         # --------------------------------------------------- data plane
         _k("TORCHFT_BUCKET_CAP_MB", "float", "1024", "performance.md#bucketing", "tuning-env",
            "Allreduce flat-bucket cap in MB; 0 disables bucketing."),
-        _k("TORCHFT_STREAM_BUCKETS", "bool", "1", "performance.md#streaming",
-           "compress-env",
-           "Per-bucket streamed allreduce pipeline (off = serial collectives)."),
         _k("TORCHFT_COMPRESS", "enum(off|fp8|int8)", "off",
            "performance.md#compressed-collectives", "compress-env",
            "Wire codec for streamed buckets, with per-bucket error feedback."),

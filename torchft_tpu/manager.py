@@ -22,8 +22,6 @@ JAX-flavored deviations from the reference, by design:
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import logging
 import os
 import socket as _socket
@@ -31,7 +29,6 @@ import threading
 import time
 import traceback
 import uuid
-import weakref
 from concurrent.futures import ThreadPoolExecutor
 from datetime import timedelta
 from pathlib import Path
@@ -63,12 +60,7 @@ from torchft_tpu.observability import (
     log_error_event,
     log_quorum_event,
 )
-from torchft_tpu.ops.quantization import (
-    compress_bucket,
-    decompress_bucket,
-    is_compressed_wire,
-    resolve_compress_mode,
-)
+from torchft_tpu.ops.quantization import resolve_compress_mode
 from torchft_tpu.process_group import ProcessGroup, ReduceOp
 from torchft_tpu.tracing import TRACE_BUFFER_ENV, SpanRecorder, TraceConfig
 from torchft_tpu.work import (
@@ -77,7 +69,6 @@ from torchft_tpu.work import (
     FutureWork,
     GradStream,
     Work,
-    join_futures,
 )
 
 T = TypeVar("T")
@@ -100,10 +91,7 @@ QUORUM_RETRIES_ENV = "TORCHFT_QUORUM_RETRIES"
 # bucket cap for the managed allreduce's bucketed path, in MiB; 0 disables
 # bucketing entirely (per-leaf collectives, the pre-bucketing behavior)
 BUCKET_CAP_MB_ENV = "TORCHFT_BUCKET_CAP_MB"
-# per-bucket streaming pipeline for the bucketed allreduce: "0"/"false"
-# forces the serial monolithic path (pack all → one collective → unpack all)
-STREAM_BUCKETS_ENV = "TORCHFT_STREAM_BUCKETS"
-# wire compression for streamed buckets ("off" | "fp8" | "int8"): resolved
+# wire compression for the pipeline's buckets ("off" | "fp8" | "int8"): resolved
 # in ops/quantization.resolve_compress_mode (env TORCHFT_COMPRESS >
 # constructor > "off") so doctor.py validates the same way the Manager does
 
@@ -238,7 +226,6 @@ class Manager:
         heartbeat_interval: "float | timedelta" = 0.1,
         hostname: str = "",
         bucket_cap_bytes: Optional[int] = None,
-        stream_buckets: Optional[bool] = None,
         compress: Optional[str] = None,
         tracing: Optional[bool] = None,
         metrics_port: Optional[int] = None,
@@ -412,38 +399,11 @@ class Manager:
             self._bucket_cap_bytes = int(bucket_cap_bytes)
         else:
             self._bucket_cap_bytes = bucketing.DEFAULT_BUCKET_CAP_BYTES
-        # host staging buffers recycle through the pool instead of
-        # allocating a gradient-sized buffer per step
         self._buffer_pool = bucketing.BufferPool()
-        # streaming bucket pipeline: env var > constructor > default ON.
-        # Off means the pre-pipeline behavior: one monolithic collective
-        # per plan, unpacked only after the LAST bucket's wire completes.
-        env_stream = os.environ.get(STREAM_BUCKETS_ENV)
-        if env_stream is not None:
-            self._stream_buckets = env_stream.strip().lower() not in (
-                "0",
-                "false",
-                "no",
-                "off",
-            )
-        elif stream_buckets is not None:
-            self._stream_buckets = bool(stream_buckets)
-        else:
-            self._stream_buckets = True
-        # wire compression for streamed buckets: TORCHFT_COMPRESS env >
+        # wire compression for the pipeline's buckets: TORCHFT_COMPRESS env >
         # constructor > "off". Raises on a bad value (same message the
         # doctor check surfaces) rather than training uncompressed silently.
         self._compress = resolve_compress_mode(compress)
-        # per-(plan, bucket) error-feedback residuals: what quantization
-        # rounded away this step is added back before quantizing the next
-        # step, so the compression error stays bounded instead of
-        # accumulating (LocalSGD/DiLoCo convergence depends on this).
-        # Keyed by plan identity via weakref so evicted plans drop their
-        # residual buffers with them; buffers come from the BufferPool.
-        self._ef_residuals: "weakref.WeakKeyDictionary" = (
-            weakref.WeakKeyDictionary()
-        )
-        self._ef_lock = threading.Lock()
 
         self._step = 0
         self._quorum_id = -1
@@ -559,25 +519,6 @@ class Manager:
         self._executor = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="torchft_quorum"
         )
-        # one ordered worker for host-plane allreduce staging: D2H + wire
-        # dispatch off the train loop, issue order preserved across replicas
-        self._staging_executor = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="torchft_stage"
-        )
-        # pipeline stage 3: per-bucket unpack + device landing runs here so
-        # it neither blocks the PG's dispatch thread (which would serialize
-        # the NEXT bucket's wire behind this bucket's unpack) nor waits for
-        # the last bucket's wire like the monolithic path did
-        self._unpack_executor = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="torchft_unpack"
-        )
-        # (executor future, staged future) pairs still in flight: shutdown
-        # must fail the staged futures of cancelled tasks or their waiters
-        # stall for the full timeout. Guarded together with the shutdown
-        # flag so a submit can't race the shutdown sweep.
-        self._staged_pending: List[Any] = []
-        self._staged_lock = threading.Lock()
-        self._staging_down = False
         self._quorum_future: Optional[Any] = None
 
         self._logger = _ManagerLogger(self, self._replica_id, group_rank)
@@ -593,6 +534,12 @@ class Manager:
         # what is recorded before the first quorum (the trainer's start-up,
         # the head of its first iteration) belongs to the step it leads to
         self._tracer.set_context(step=self._step)
+        # the data plane of allreduce(): capture, staging, wire, landing and
+        # the threads they run on; host staging buffers recycle through the
+        # pool instead of allocating a gradient-sized buffer per step
+        self._pipeline = bucketing.BucketPipeline(
+            pg, self._tracer, self._buffer_pool, self._update_timings
+        )
         # heal_fetch / heal_place seconds of the receive in flight, summed
         # over the transport's fetch threads (_on_heal_event), and the
         # heal_recv span they are children of
@@ -1752,10 +1699,10 @@ class Manager:
         ``wait()`` returns the reduced pytree directly.
         ``should_quantize=True`` streams the buckets COMPRESSED on a
         host-plane PG (fp8 unless ``TORCHFT_COMPRESS`` picks int8), with
-        per-bucket error feedback — quantization no longer forces the
-        serial monolithic path. When the tree cannot stream (single leaf,
-        bucketing or streaming disabled, device-native quantized), the
-        handle degenerates to one bucket covering the whole op.
+        per-bucket error feedback — quantization does not force the
+        monolithic exchange. When the tree has no plan (single leaf,
+        bucketing disabled, device-native quantized), the handle
+        degenerates to one bucket covering the whole op.
         ``bucket_cap_bytes`` overrides the manager's cap for this call
         (``PureDistributedDataParallel`` routes its own cap through here).
         """
@@ -1777,10 +1724,14 @@ class Manager:
         reduce_op: ReduceOp = ReduceOp.AVG,
         bucket_cap_bytes: Optional[int] = None,
     ) -> "tuple[Work, Optional[GradStream]]":
-        """Shared engine behind allreduce / allreduce_streamed.
+        """Shared engine behind allreduce / allreduce_streamed: the state
+        machine's half of it (quorum, participants, the error policy, the
+        choice of path); the data plane is ``self._pipeline``
+        (:class:`bucketing.BucketPipeline`).
 
-        Returns ``(work, stream)``; ``stream`` is a GradStream when the op
-        took the per-bucket streaming pipeline, else None (serial path).
+        Returns ``(work, stream)``; ``stream`` is a GradStream when the
+        tree had a bucket plan and went through the per-bucket pipeline,
+        else None (the no-plan path: one collective for the whole tree).
         """
         import jax
 
@@ -1788,6 +1739,7 @@ class Manager:
         self._bump_metric("allreduces")
         leaves, treedef = jax.tree_util.tree_flatten(values)
         tracer = self._tracer
+        pipeline = self._pipeline
         # allreduce/allreduce runs from here to the resolve of the returned
         # work, on another thread: recorded there (_time_allreduce), its
         # id known now so the spans below can name it as their parent
@@ -1798,28 +1750,21 @@ class Manager:
         # Bucketed path: pack a multi-leaf tree into a handful of flat
         # same-dtype buffers (shared bucketing.py; plan cached by tree
         # identity + leaf geometry) so the wire carries ceil(bytes/cap)
-        # collectives instead of one per leaf. The MONOLITHIC quantized
-        # path is never pre-bucketed — collectives.py already concatenates
-        # into one flat wire buffer, and packing first would shift the fp8
-        # rowwise-scale boundaries — but when the streaming pipeline is on
-        # and the PG is host-plane, a quantized tree streams as compressed
-        # buckets with error feedback instead (one codec boundary per
-        # bucket, carried per-bucket residuals; see stage() below).
+        # collectives instead of one per leaf. On a host-plane PG a
+        # quantized tree has a plan too: it rides the pipeline as compressed
+        # buckets with error feedback (one codec boundary per bucket,
+        # carried per-bucket residuals). A device-native PG's quantized
+        # exchange is the monolithic one, which is never pre-bucketed
+        # (BucketPipeline.allreduce_leaves says why).
         cap = (
             self._bucket_cap_bytes
             if bucket_cap_bytes is None
             else int(bucket_cap_bytes)
         )
-        # read before the plan gate: the gate and the compression mode both
-        # depend on which plane the collective runs on (full routing
-        # rationale on the comment further down)
-        device_native = getattr(self._pg, "device_native", False)
-        streamable_quant = (
-            should_quantize and self._stream_buckets and not device_native
-        )
+        device_native = pipeline.device_native
         plan: Optional[bucketing.BucketPlan] = None
         if (
-            (not should_quantize or streamable_quant)
+            not (should_quantize and device_native)
             and len(leaves) > 1
             and cap > 0
         ):
@@ -1828,57 +1773,16 @@ class Manager:
             except Exception:  # noqa: BLE001 — exotic leaves fall back per-leaf
                 plan = None
 
-        # Staleness check at RESOLVE time: if the input leaf's sharding
-        # references a device client that is no longer the live backend
-        # (ProcessGroupXLA tore down + rejoined its per-quorum
-        # jax.distributed world between the caller computing `values`
-        # and this resolve), a device_put onto it can SUCCEED and
-        # produce an array the next jitted computation rejects as
-        # "incompatible devices". Land such leaves on the live backend
-        # instead — _sync_device_world re-lands the user's own state
-        # the same way at should_commit. LAZY on purpose: jax.devices()
-        # initializes the backend, and a pure-host tree must never
-        # trigger that (a process that only moves host arrays should not
-        # take the chip).
-        live_client = [False]
+        place = bucketing.leaf_placer()
 
-        def _is_live(sharding) -> bool:
-            if live_client[0] is False:
-                try:
-                    live_client[0] = getattr(
-                        jax.devices()[0], "client", None
-                    )
-                except Exception:  # noqa: BLE001
-                    live_client[0] = None
-            if live_client[0] is None:
-                return True
-            try:
-                dev = next(iter(sharding.device_set))
-                return getattr(dev, "client", None) is live_client[0]
-            except Exception:  # noqa: BLE001
-                return False
-
-        def place_leaf(orig: Any, host: Any) -> Any:
-            # restore one reduced slice to its original leaf's placement —
-            # shared by the monolithic rebuild and the per-bucket pipeline
-            # so both paths land leaves through identical expressions
-            import jax.numpy as jnp
-
-            if isinstance(orig, jax.Array):
-                if _is_live(orig.sharding):
-                    return jax.device_put(host, orig.sharding)
-                return jnp.asarray(np.asarray(host))
-            return np.asarray(host)
-
-        def rebuild(host_leaves: List[np.ndarray]) -> Any:
-            out = [
-                place_leaf(orig, host)
-                for orig, host in zip(leaves, host_leaves)
-            ]
-            return jax.tree_util.tree_unflatten(treedef, out)
+        def unflatten(f: Future) -> Any:
+            return jax.tree_util.tree_unflatten(treedef, f.value())
 
         def zeros() -> Any:
-            return rebuild([np.zeros(np.shape(l), _np_dtype(l)) for l in leaves])
+            return jax.tree_util.tree_unflatten(treedef, [
+                place(l, np.zeros(np.shape(l), bucketing.leaf_dtype(l)))
+                for l in leaves
+            ])
 
         if self.errored():
             return DummyWork(zeros()), None
@@ -1895,86 +1799,20 @@ class Manager:
             return DummyWork(zeros()), None
         num_participants = self.num_participants()
 
-        # Device-native PGs (ProcessGroupXLA) take jax.Arrays straight
-        # through — the collective runs on device over ICI/DCN with no
-        # host staging (VERDICT weak #4: the D2H round-trip on the caller
-        # thread). The quantized path likewise keeps everything on device:
-        # the Pallas kernels quantize there and the compressed payload
-        # ships as packed uint8 device arrays through the PG's own
-        # collectives (collectives.py _pack_wire_device), so on hardware
-        # the fp8 exchange rides ICI with zero host staging. Host-plane
-        # PGs with plain numpy inputs get the numpy staging they require.
-        # Only a device-native PG (ProcessGroupXLA) bypasses the staging
-        # worker: its ops rendezvous by (kind, seq) so issue order across
-        # threads cannot mismatch. On a host PG EVERYTHING — including the
-        # quantized pipeline, whose alltoall/allgather would otherwise be
-        # issued from an unordered helper thread — goes through the one
-        # ordered staging worker (host exchange matches messages purely by
-        # arrival order; cross-replica issue order is the contract).
-        # (device_native itself is read above, before the plan gate.)
-
         pg_reduce_op = reduce_op
         if reduce_op == ReduceOp.AVG:
-            if not all(np.issubdtype(_np_dtype(l), np.floating) or
-                       "bfloat16" in str(_np_dtype(l)) for l in leaves):
+            if not all(
+                bucketing.is_float_dtype(bucketing.leaf_dtype(l))
+                for l in leaves
+            ):
                 raise ValueError("AVG allreduce requires floating point arrays")
             pg_reduce_op = ReduceOp.SUM
-
-        averaging = reduce_op == ReduceOp.AVG and num_participants > 0
-
-        def land_reduced(
-            flat: Any,
-            bucket: int,
-            span: Callable[..., Any] = lambda name, **args: (
-                contextlib.nullcontext()
-            ),
-        ) -> List[Any]:
-            # One reduced array — the plan's bucket ``bucket``, or with no
-            # plan the lone leaf of that index — to ``(leaf_index, leaf)``
-            # pairs: sliced (views), each slice placed where its original
-            # lives, and under AVG divided by the participants where it then
-            # is: a device leaf on its device (dispatched from here, nothing
-            # waits for it), a numpy leaf in numpy, so a pure-host tree still
-            # never initialises a backend. The serial path and every
-            # streamed bucket land through this one function, so they stay
-            # bit-identical on every backend. ``span(name, **args)``: the
-            # streamed path's allreduce/h2d and allreduce/divide; the serial
-            # path records none.
-            idxs = [bucket] if plan is None else plan.groups[bucket]
-            sized = {"bytes": _payload_nbytes(flat), "leaves": len(idxs)}
-            on_device_plane = isinstance(flat, jax.Array)
-            if averaging and on_device_plane:
-                # a device-native PG's result may be a buffer the PG (or the
-                # caller) still holds: divided where it is, into a fresh one
-                with span("divide", where="device", **sized):
-                    flat = _average(flat, num_participants)
-            pairs = (
-                [(bucket, flat)] if plan is None
-                else bucketing.unpack_bucket(flat, plan, bucket)
-            )
-            # on a device leaf place_leaf is jax.device_put: as far as it
-            # returns before the bytes have moved, h2d is the enqueue
-            with span("h2d", **sized):
-                pairs = [(i, place_leaf(leaves[i], v)) for i, v in pairs]
-            if averaging and not on_device_plane:
-                on_device = sum(isinstance(leaves[i], jax.Array) for i in idxs)
-                where = (
-                    "device" if on_device == len(idxs)
-                    else "mixed" if on_device else "host"
-                )
-                with span("divide", where=where, **sized):
-                    pairs = [
-                        (i, _average(v, num_participants, landed=True))
-                        for i, v in pairs
-                    ]
-            return pairs
-
-        def normalize(f: Future) -> Any:
-            out: List[Any] = [None] * len(leaves)
-            for b, flat in enumerate(f.value()):
-                for i, v in land_reduced(flat, b):
-                    out[i] = v
-            return jax.tree_util.tree_unflatten(treedef, out)
+        # the reduced SUM is divided by this where it lands; None: no AVG
+        divisor = (
+            num_participants
+            if reduce_op == ReduceOp.AVG and num_participants > 0
+            else None
+        )
 
         def _time_allreduce(_f: Future) -> None:
             # submission → resolve wall clock of the most recent
@@ -1988,681 +1826,57 @@ class Manager:
             )
 
         try:
+            op = None
             if plan is not None:
-                bucket_bytes = [
-                    size * np.dtype(dtype).itemsize
-                    for size, dtype in zip(plan.sizes, plan.dtypes)
-                ]
+                # wire compression: TORCHFT_COMPRESS / compress= knob, plus
+                # should_quantize callers who land here (host plane)
+                # defaulting to fp8
+                compress = self._compress
+                if should_quantize and compress == "off":
+                    compress = "fp8"
+                op = pipeline.allreduce_buckets(
+                    leaves, plan, pg_reduce_op,
+                    participating=self.is_participating(),
+                    divisor=divisor, place=place, timeout=self._timeout,
+                    compress=compress, parent=ar_id,
+                )
                 ar_args["buckets"] = len(plan)
-                ar_args["bytes"] = sum(bucket_bytes)
-            if plan is not None and self._stream_buckets:
-                # ---------------- streaming bucket pipeline ----------------
-                # One PG collective PER BUCKET instead of one for the whole
-                # plan, three stages per bucket: pack (D2H / device concat),
-                # wire (the PG's dispatch thread or XLA), unpack (slice + land
-                # on device + divide there, on the dedicated unpack worker).
-                # Bucket i+1 packs while bucket i rides the wire and bucket
-                # i−1 unpacks — no stage ever waits for the LAST bucket's
-                # wire, which is exactly what the monolithic path did.
-                # Numerics are bit-identical to the serial path: per-bucket
-                # collectives reduce each flat independently just like one
-                # call carrying the list, and slice/land/divide are the same
-                # function (land_reduced) on both paths.
-                import jax.numpy as jnp
-
-                n_buckets = len(plan)
-                # per-bucket (start, end) wall-clock marks per stage, for
-                # pack_s/wire_s/unpack_s + overlap_efficiency in timings()
-                marks: List[Dict[str, Any]] = [{} for _ in range(n_buckets)]
-                # ids of the three stage spans per bucket (recorded from
-                # the marks once the op resolves), for their children, and
-                # what the PG stamped on each bucket's op: (enqueued,
-                # fn started, fn ended) -> allreduce/wire_run
-                stage_ids = [
-                    {st: tracer.new_id() for st in ("pack", "wire", "unpack")}
-                    for _ in range(n_buckets)
-                ]
-                wire_runs: List[Any] = [None] * n_buckets
-                bucket_futs: List[Future] = [Future() for _ in range(n_buckets)]
-                # aggregate: every bucket landed -> reassembled pytree.
-                # final_fut is fed from the join but owned here so the
-                # staging watchdog / shutdown sweep can fail it directly.
-                final_fut: Future = Future()
-
-                def _assemble(f: Future) -> Any:
-                    placed: Dict[int, Any] = {}
-                    for pairs in f.value():
-                        for idx, v in pairs:
-                            placed[idx] = v
-                    return jax.tree_util.tree_unflatten(
-                        treedef, [placed[i] for i in range(len(leaves))]
-                    )
-
-                def _feed_final(f: Future) -> None:
-                    try:
-                        v = f.value()
-                    except Exception as e:  # noqa: BLE001
-                        try:
-                            final_fut.set_exception(e)
-                        except RuntimeError:
-                            pass
-                        return
-                    try:
-                        final_fut.set_result(v)
-                    except RuntimeError:
-                        pass
-
-                join_futures(bucket_futs).then(_assemble).add_done_callback(
-                    _feed_final
-                )
-
-                participating = self.is_participating()
-                pool = self._buffer_pool
-
-                def _land_bucket(i: int, flat: Any, pooled_buf: Any) -> None:
-                    # stage 3, off the PG dispatch thread: slice + landing +
-                    # AVG divide (land_reduced) for ONE bucket. A failure here
-                    # fails the aggregate via the join; earlier buckets'
-                    # landed slices are only reachable through the aggregate
-                    # tree, so a mid-stream error can never leak a
-                    # partially-applied reduction.
-                    try:
-                        t0u = time.perf_counter()
-                        # a PG that hands its input back as its result
-                        # (world-of-one short circuits): the landed leaves
-                        # may be views of, or transfers still reading,
-                        # the staging buffer
-                        passed_through = (
-                            pooled_buf is not None
-                            and isinstance(flat, np.ndarray)
-                            and np.shares_memory(flat, pooled_buf)
-                        )
-                        # the bucket's first unpack child carries how long
-                        # it sat behind earlier buckets on the one unpack
-                        # worker (device plane: unpack runs in the wire's
-                        # callback)
-                        first = {"queued_us": int(
-                            (t0u - marks[i]["wire"][1]) * 1e6
-                        )} if "wire" in marks[i] else {}
-
-                        def span(name: str, **args: Any) -> Any:
-                            args.update(first)
-                            first.clear()
-                            return tracer.span(
-                                name, cat="allreduce",
-                                parent=stage_ids[i]["unpack"], bucket=i,
-                                **args,
-                            )
-
-                        if is_compressed_wire(flat):
-                            # the bucket rode the wire compressed; the codes
-                            # carry the reduced SUM, restored here at the
-                            # plan's bucket dtype so slice/land/divide below
-                            # run the exact uncompressed expressions
-                            with span("decode", bytes=_payload_nbytes(flat)):
-                                flat = decompress_bucket(flat)
-                        pairs = land_reduced(flat, i, span)
-                        marks[i]["unpack"] = (t0u, time.perf_counter())
-                        if (
-                            pooled_buf is not None
-                            and not passed_through
-                            and not final_fut.done()
-                        ):
-                            # recycle this bucket's staging buffer the
-                            # moment it lands: on success only (an op that
-                            # already failed or timed out drops it), never
-                            # when the PG passed it through
-                            pool.release(pooled_buf)
-                        bucket_futs[i].set_result(pairs)
-                    except Exception as e:  # noqa: BLE001
-                        try:
-                            bucket_futs[i].set_exception(e)
-                        except RuntimeError:
-                            pass
-
-                if device_native:
-                    # device plane: issue per-bucket collectives straight
-                    # from the caller thread — ProcessGroupXLA rendezvouses
-                    # ops by (kind, seq), and per-bucket ops let XLA overlap
-                    # ICI transfers with adjacent compute
-                    t0p = time.perf_counter()
-                    if participating:
-                        up = [
-                            l if isinstance(l, jax.Array) else jnp.asarray(l)
-                            for l in leaves
-                        ]
-                        dev_flats, _ = bucketing.pack(up, plan)
-                    else:
-                        dev_flats = [
-                            jnp.zeros(size, dtype)
-                            for size, dtype in zip(plan.sizes, plan.dtypes)
-                        ]
-                    marks[0]["pack"] = (t0p, time.perf_counter())
-                    for i in range(n_buckets):
-                        t0w = time.perf_counter()
-                        w = self._pg.allreduce([dev_flats[i]], pg_reduce_op)
-
-                        def _wire_done(
-                            f: Future, i: int = i, t0w: float = t0w
-                        ) -> None:
-                            marks[i]["wire"] = (t0w, time.perf_counter())
-                            try:
-                                flat = f.value()[0]
-                            except Exception as e:  # noqa: BLE001
-                                try:
-                                    bucket_futs[i].set_exception(e)
-                                except RuntimeError:
-                                    pass
-                                return
-                            _land_bucket(i, flat, None)
-
-                        w.get_future().add_done_callback(_wire_done)
-                else:
-                    # host plane: capture on the caller thread (donation
-                    # safety, same as the serial path), then ONE staging
-                    # task walks the buckets — D2H bucket i, non-blocking
-                    # dispatch, straight on to bucket i+1 while the PG's
-                    # dispatch thread runs the wire. A single task keeps
-                    # per-plan dispatch atomic across concurrent callers,
-                    # preserving cross-replica arrival order (the SPMD
-                    # contract of the host exchange).
-                    if participating:
-                        with tracer.span(
-                            "capture", cat="allreduce", parent=ar_id,
-                            bytes=ar_args["bytes"],
-                        ):
-                            # device groups come back cut into pieces
-                            # for fetch_into (one jitted dispatch a
-                            # bucket: private copies, donation-safe as
-                            # the flat was), their transfers issued from
-                            # this thread; host groups as before
-                            capture, _ = bucketing.pack(
-                                leaves, plan, pool=pool,
-                                piece_bytes=bucketing.FETCH_PIECE_BYTES,
-                            )
-                    else:
-                        capture = None
-                    stage_timeout = self._timeout
-                    t_submit = time.perf_counter()
-
-                    # wire compression: TORCHFT_COMPRESS / compress= knob,
-                    # plus should_quantize callers who land here (streaming
-                    # on, host plane) defaulting to fp8. Non-float buckets
-                    # ride uncompressed — the decision depends only on the
-                    # shared plan + mode, so it is SPMD-consistent across
-                    # replicas. Non-participants compress their zero
-                    # contribution too (the ring needs uniform wire
-                    # geometry) but never touch the EF residuals.
-                    compress_mode = self._compress
-                    if should_quantize and compress_mode == "off":
-                        compress_mode = "fp8"
-                    if compress_mode != "off":
-                        bucket_modes = [
-                            compress_mode
-                            if _is_float_dtype(plan.dtypes[i])
-                            else "off"
-                            for i in range(n_buckets)
-                        ]
-                        ef_store = (
-                            self._bucket_residuals(plan)
-                            if participating
-                            else None
-                        )
-                    else:
-                        bucket_modes = ["off"] * n_buckets
-                        ef_store = None
-
-                    def _stage_deadline() -> None:
-                        try:
-                            final_fut.set_exception(
-                                TimeoutError("allreduce staging timed out")
-                            )
-                        except RuntimeError:
-                            pass
-
-                    def stage() -> None:
-                        try:
-                            from torchft_tpu.futures import arm_deadline
-
-                            cancel = arm_deadline(
-                                _stage_deadline, stage_timeout
-                            )
-                            final_fut.add_done_callback(lambda _f: cancel())
-                            # pool buffers this op's device buckets took,
-                            # and how many of them were recycled ones
-                            acquired = hits = 0
-                            for i in range(n_buckets):
-                                t0b = time.perf_counter()
-                                pk_id = stage_ids[i]["pack"]
-                                if capture is None:
-                                    host_flat = np.zeros(
-                                        (plan.sizes[i],), plan.dtypes[i]
-                                    )
-                                    pooled_buf = None
-                                else:
-                                    if hasattr(capture[i], "block_until_ready"):
-                                        # the wait np.asarray below would
-                                        # make anyway (the backward pass
-                                        # and the device concatenation
-                                        # still running), under its own
-                                        # name: d2h is then the copy alone
-                                        with tracer.span(
-                                            "grad_wait", cat="allreduce",
-                                            parent=pk_id, bucket=i,
-                                        ):
-                                            capture[i].block_until_ready()
-                                    # bucket 0 carries how long the staging
-                                    # worker took to get to this op
-                                    with tracer.span(
-                                        "d2h", cat="allreduce",
-                                        parent=pk_id, bucket=i,
-                                        **({"queued_us": int(
-                                            (t0b - t_submit) * 1e6
-                                        )} if i == 0 else {}),
-                                    ) as sp:
-                                        cap = capture[i]
-                                        if isinstance(cap, bucketing.Pieces):
-                                            # a device bucket: its pieces,
-                                            # in flight since the capture,
-                                            # into a pool buffer (mapped
-                                            # pages from the second step
-                                            # on), device memory dropped
-                                            # as each lands
-                                            host_flat, hit = pool.acquire_hit(
-                                                cap.size, cap.dtype
-                                            )
-                                            acquired += 1
-                                            hits += hit
-                                            sp.args["pieces"] = (
-                                                bucketing.fetch_into(
-                                                    cap, host_flat
-                                                )
-                                            )
-                                            sp.args["pooled"] = int(hit)
-                                        else:
-                                            # a host group: packed into
-                                            # its pool buffer at capture
-                                            host_flat = cap
-                                        capture[i] = None
-                                        sp.args["bytes"] = host_flat.nbytes
-                                    # either way a pool buffer: back to
-                                    # the pool once the bucket has landed
-                                    pooled_buf = host_flat
-                                payload: Any = host_flat
-                                if bucket_modes[i] != "off":
-                                    # quantize inside the pack stage so
-                                    # pack_s absorbs the codec cost and
-                                    # overlap accounting stays honest
-                                    with tracer.span(
-                                        "codec", cat="allreduce",
-                                        parent=pk_id, bucket=i,
-                                        bytes=host_flat.nbytes,
-                                    ) as sp:
-                                        payload = self._compress_bucket_ef(
-                                            host_flat,
-                                            bucket_modes[i],
-                                            plan.dtypes[i],
-                                            ef_store,
-                                            i,
-                                        )
-                                        sp.args["bytes_out"] = (
-                                            _payload_nbytes(payload)
-                                        )
-                                with tracer.span(
-                                    "dispatch", cat="allreduce",
-                                    parent=pk_id, bucket=i,
-                                ):
-                                    w = self._pg.allreduce(
-                                        [payload], pg_reduce_op
-                                    )
-                                t1b = time.perf_counter()
-                                marks[i]["pack"] = (t0b, t1b)
-
-                                def _wire_done(
-                                    f: Future,
-                                    i: int = i,
-                                    t0w: float = t1b,
-                                    pooled_buf: Any = pooled_buf,
-                                ) -> None:
-                                    # runs on the PG dispatch thread — keep
-                                    # it tiny: record, then hand unpack to
-                                    # the unpack worker so the NEXT bucket's
-                                    # wire starts immediately
-                                    marks[i]["wire"] = (
-                                        t0w,
-                                        time.perf_counter(),
-                                    )
-                                    # ProcessGroupHost leaves these on
-                                    # its op's future; another PG's has none
-                                    wire_runs[i] = getattr(f, "stamps", None)
-                                    try:
-                                        flat = f.value()[0]
-                                    except Exception as e:  # noqa: BLE001
-                                        try:
-                                            bucket_futs[i].set_exception(e)
-                                        except RuntimeError:
-                                            pass
-                                        return
-                                    try:
-                                        self._unpack_executor.submit(
-                                            _land_bucket, i, flat, pooled_buf
-                                        )
-                                    except RuntimeError as e:  # shutdown
-                                        try:
-                                            bucket_futs[i].set_exception(e)
-                                        except RuntimeError:
-                                            pass
-
-                                w.get_future().add_done_callback(_wire_done)
-                            if acquired:
-                                self._record_timing(
-                                    "stage_pool_hit_share", hits / acquired
-                                )
-                        except Exception as e:  # noqa: BLE001
-                            for bf in bucket_futs:
-                                try:
-                                    bf.set_exception(e)
-                                except RuntimeError:
-                                    pass
-
-                    from torchft_tpu.futures import arm_deadline as _arm
-
-                    # submit + register atomically vs the shutdown sweep,
-                    # with the same depth-aware submission backstop as the
-                    # serial path (a wedged op ahead of us means stage()
-                    # never runs and never arms the tight deadline)
-                    with self._staged_lock:
-                        if self._staging_down:
-                            raise RuntimeError("manager is shut down")
-                        depth = len(self._staged_pending)
-                        backstop_cancel = _arm(
-                            _stage_deadline, (depth + 2) * stage_timeout
-                        )
-                        final_fut.add_done_callback(
-                            lambda _f: backstop_cancel()
-                        )
-                        exec_fut = self._staging_executor.submit(stage)
-                        pair = (exec_fut, final_fut)
-                        self._staged_pending.append(pair)
-
-                    def _unpin(_f: Future) -> None:
-                        with self._staged_lock:
-                            try:
-                                self._staged_pending.remove(pair)
-                            except ValueError:
-                                pass
-
-                    final_fut.add_done_callback(_unpin)
-
-                wrapped = self.wrap_future(
-                    final_fut, zeros, arm_timeout=device_native
-                )
-                wrapped.add_done_callback(_time_allreduce)
-
-                def _finalize_pipeline(_f: Future) -> None:
-                    try:
-                        self._record_pipeline_timings(
-                            marks, ar_id, stage_ids, wire_runs, bucket_bytes
-                        )
-                    except Exception:  # noqa: BLE001
-                        self._logger.exception(
-                            "failed to record pipeline timings"
-                        )
-
-                wrapped.add_done_callback(_finalize_pipeline)
-                stream = GradStream(bucket_futs, wrapped)
-                return FutureWork(wrapped), stream
-
-            if device_native:
-                import jax.numpy as jnp
-
-                if plan is not None:
-                    if not self.is_participating():
-                        # zero contribution, built directly at bucket shape
-                        # (cheaper than zeroing per leaf then packing)
-                        dev_leaves = [
-                            jnp.zeros(size, dtype)
-                            for size, dtype in zip(plan.sizes, plan.dtypes)
-                        ]
-                    else:
-                        up = [
-                            l if isinstance(l, jax.Array) else jnp.asarray(l)
-                            for l in leaves
-                        ]
-                        dev_leaves, _ = bucketing.pack(up, plan)
-                else:
-                    dev_leaves = [
-                        l if isinstance(l, jax.Array) else jnp.asarray(l)
-                        for l in leaves
-                    ]
-                    if not self.is_participating():
-                        dev_leaves = [jnp.zeros_like(h) for h in dev_leaves]
-                if should_quantize:
-                    from torchft_tpu.collectives import allreduce_quantized
-
-                    work = allreduce_quantized(dev_leaves, pg_reduce_op, self._pg)
-                else:
-                    work = self._pg.allreduce(dev_leaves, pg_reduce_op)
-                fut = work.get_future()
+                ar_args["bytes"] = sum(op.bucket_bytes)
+                landed = op.final
             else:
-                # Host plane: the D2H of a full gradient pytree would block
-                # the train loop if staged on the caller thread (round-2
-                # verdict weak #4). Stage + dispatch on the ordered staging
-                # thread instead — one worker, so collectives still issue
-                # in caller order on every replica (the SPMD contract).
-                staged_fut: Future = Future()
-                fut = staged_fut
-                participating = self.is_participating()
-
-                # Capture on the caller thread: the staging thread reads
-                # these AFTER allreduce() returns, by which time the
-                # caller's next jitted step may have donated (deleted) the
-                # device buffers or overwritten a reused numpy buffer.
-                # jax.Arrays get a device-side copy (HBM bandwidth, async
-                # dispatch — far cheaper than blocking the train loop on
-                # the D2H transfer); numpy leaves get a host memcpy.
-                # Non-participants skip the capture entirely — they
-                # contribute zeros built from shapes alone (the reference
-                # zeroes the buffer in place; arrays are immutable here).
-                import jax.numpy as jnp
-
-                if participating:
-                    if plan is not None:
-                        # the packed flats ARE the capture: device groups
-                        # concatenate into a fresh (donation-safe) buffer,
-                        # host groups copy into a pool-recycled one — no
-                        # second per-leaf copy
-                        with tracer.span(
-                            "capture", cat="allreduce", parent=ar_id,
-                            bytes=ar_args["bytes"],
-                        ):
-                            capture, pooled = bucketing.pack(
-                                leaves, plan, pool=self._buffer_pool
-                            )
-                    else:
-                        capture = [
-                            jnp.copy(l) if isinstance(l, jax.Array)
-                            else np.array(l, copy=True)
-                            for l in leaves
-                        ]
-                        pooled = []
-                else:
-                    capture = None
-                    pooled = []
-                if plan is not None:
-                    zero_specs = [
-                        ((size,), dtype)
-                        for size, dtype in zip(plan.sizes, plan.dtypes)
-                    ]
-                else:
-                    zero_specs = [(np.shape(l), _np_dtype(l)) for l in leaves]
-                stage_timeout = self._timeout
-
-                def _stage_deadline() -> None:
-                    # fail-the-future watchdog armed when staging BEGINS
-                    # (not at submission: queue time behind an in-flight
-                    # quantized sync must not count against this op)
-                    try:
-                        staged_fut.set_exception(
-                            TimeoutError("allreduce staging timed out")
-                        )
-                    except RuntimeError:
-                        pass
-
-                def stage() -> None:
-                    """D2H + dispatch only — the PG's own ordered worker
-                    runs the wire, and the result chains in via callback.
-                    Blocking here would serialize overlapped allreduces on
-                    this one thread and charge queue time against later
-                    calls' wrap_future timeouts. EXCEPTION: the quantized
-                    pipeline runs to completion here — its alltoall and
-                    allgather must be issued in staged order (they would
-                    otherwise race other staged ops from its helper
-                    thread), and quantized syncs are rare boundary events
-                    (DiLoCo) where the serialization is acceptable."""
-                    try:
-                        from torchft_tpu.futures import arm_deadline
-
-                        # The tight deadline spans the WHOLE staged op —
-                        # D2H, dispatch, AND the wire phase the PG worker
-                        # resolves via callback after this function
-                        # returns. A `with` around just this frame would
-                        # disarm at dispatch, leaving a never-resolving
-                        # wire (hung peer whose abort path also fails)
-                        # unbounded. Cancelled the moment staged_fut
-                        # settles, so queue time behind an in-flight
-                        # quantized sync still never counts against it.
-                        cancel = arm_deadline(_stage_deadline, stage_timeout)
-                        staged_fut.add_done_callback(lambda _f: cancel())
-                        if should_quantize:
-                            from torchft_tpu.collectives import allreduce_quantized
-
-                            if capture is None:
-                                wire_leaves = [
-                                    np.zeros(s, d) for s, d in zero_specs
-                                ]
-                            else:
-                                # keep jax copies as-is: single-device
-                                # trees take the Pallas engine
-                                wire_leaves = capture
-                            w = allreduce_quantized(
-                                wire_leaves, pg_reduce_op, self._pg
-                            )
-                            staged_fut.set_result(
-                                w.get_future().wait(stage_timeout)
-                            )
-                            return
-                        if capture is None:
-                            host_leaves = [
-                                np.zeros(s, d) for s, d in zero_specs
-                            ]
-                        else:
-                            with tracer.span(
-                                "d2h", cat="allreduce", parent=ar_id
-                            ) as sp:
-                                host_leaves = [np.asarray(l) for l in capture]
-                                sp.args["bytes"] = sum(
-                                    h.nbytes for h in host_leaves
-                                )
-                        with tracer.span(
-                            "dispatch", cat="allreduce", parent=ar_id
-                        ):
-                            w = self._pg.allreduce(host_leaves, pg_reduce_op)
-
-                        def _xfer(f: Future) -> None:
-                            try:
-                                exc = f.exception()
-                                if exc is not None:
-                                    staged_fut.set_exception(exc)
-                                else:
-                                    staged_fut.set_result(f.value())
-                            except RuntimeError:
-                                pass
-
-                        w.get_future().add_done_callback(_xfer)
-                    except Exception as e:  # noqa: BLE001
-                        try:
-                            staged_fut.set_exception(e)
-                        except RuntimeError:
-                            pass
-
-                # submit + register atomically vs the shutdown sweep: a pair
-                # appended after the sweep would never have its staged
-                # future failed (full-timeout stall), and a submit after
-                # executor shutdown raises anyway
-                from torchft_tpu.futures import arm_deadline as _arm
-
-                with self._staged_lock:
-                    if self._staging_down:
-                        raise RuntimeError("manager is shut down")
-                    # Submission-time depth-aware BACKSTOP: if an op ahead
-                    # of us wedges its stage() forever (D2H against a hung
-                    # device, a dispatch that never returns), our stage()
-                    # never runs and the tight stage-start deadline is
-                    # never armed. Healthy queue time is bounded by one
-                    # deadline per op ahead (each stage() blocks at most
-                    # stage_timeout), so depth+2 slots never fire on a
-                    # healthy queue; both timers race to the same
-                    # set_exception and the loser is a no-op.
-                    depth = len(self._staged_pending)
-                    backstop_cancel = _arm(
-                        _stage_deadline, (depth + 2) * stage_timeout
-                    )
-                    staged_fut.add_done_callback(lambda _f: backstop_cancel())
-                    exec_fut = self._staging_executor.submit(stage)
-                    pair = (exec_fut, staged_fut)
-                    self._staged_pending.append(pair)
-
-                def _unpin(_f: Future) -> None:
-                    # release the (gradient-sized) result reference as soon
-                    # as the wire resolves, not at the next allreduce
-                    with self._staged_lock:
-                        try:
-                            self._staged_pending.remove(pair)
-                        except ValueError:
-                            pass
-
-                staged_fut.add_done_callback(_unpin)
-
-                if pooled:
-                    pool = self._buffer_pool
-
-                    def _recycle(f: Future) -> None:
-                        # Recycle pooled staging buffers once the wire is
-                        # done — but only on success (an errored/timed-out
-                        # op's wire thread may still read its buffer), and
-                        # never a buffer the PG passed through as its own
-                        # result (world-1 short circuits): the caller's
-                        # rebuilt tree may hold views into it.
-                        try:
-                            if f.exception() is not None:
-                                return
-                            out = f.value()
-                        except Exception:  # noqa: BLE001
-                            return
-                        for b in pooled:
-                            if any(
-                                isinstance(o, np.ndarray)
-                                and np.shares_memory(o, b)
-                                for o in out
-                            ):
-                                continue
-                            pool.release(b)
-
-                    staged_fut.add_done_callback(_recycle)
-
-            fut = fut.then(normalize)
-            # device path: submission-time timer (op starts immediately).
-            # host path: the stage-start watchdog above owns the deadline —
-            # a submission timer would charge queue time behind an
+                landed = pipeline.allreduce_leaves(
+                    leaves, pg_reduce_op, quantize=should_quantize,
+                    participating=self.is_participating(),
+                    divisor=divisor, place=place, timeout=self._timeout,
+                    parent=ar_id,
+                )
+            # device plane: submission-time timer (the op starts at once).
+            # host plane: the pipeline's stage-start deadline owns the
+            # bound — a submission timer would charge queue time behind an
             # in-flight quantized sync against this op.
-            fut = self.wrap_future(fut, zeros, arm_timeout=device_native)
+            fut = self.wrap_future(
+                landed.then(unflatten), zeros, arm_timeout=device_native
+            )
             fut.add_done_callback(_time_allreduce)
-            return FutureWork(fut), None
+            if op is None:
+                return FutureWork(fut), None
+
+            def _finalize_pipeline(_f: Future) -> None:
+                try:
+                    pipeline.record_timings(op)
+                    self._log_timing_snapshot(ALLREDUCE_PIPELINE_PHASE)
+                except Exception:  # noqa: BLE001
+                    self._logger.exception(
+                        "failed to record pipeline timings"
+                    )
+
+            fut.add_done_callback(_finalize_pipeline)
+            return FutureWork(fut), GradStream(op.bucket_futs, fut)
         except Exception as e:  # noqa: BLE001
             self._logger.exception(f"got exception in allreduce -- skipping remaining: {e}")
             self.report_error(e)
             return DummyWork(zeros()), None
+
 
     # ------------------------------------------------------------ metrics
     def _bump_metric(self, name: str) -> None:
@@ -2876,98 +2090,11 @@ class Manager:
             group_rank=self._group_rank,
         )
 
-    def _bucket_residuals(self, plan: "bucketing.BucketPlan") -> List[Any]:
-        """Per-bucket error-feedback residual slots for one plan.
-
-        Keyed by plan identity (plans are cached and reused every step, so
-        the same tree keeps the same slots); weakref-keyed so an evicted
-        plan drops its residual buffers with it. Slots start None and are
-        allocated from the BufferPool on first compression."""
-        with self._ef_lock:
-            store = self._ef_residuals.get(plan)
-            if store is None:
-                store = [None] * len(plan)
-                self._ef_residuals[plan] = store
-            return store
-
-    def _compress_bucket_ef(
-        self,
-        host_flat: np.ndarray,
-        mode: str,
-        out_dtype: Any,
-        store: Optional[List[Any]],
-        i: int,
-    ) -> Any:
-        """Quantize one packed bucket for the wire, with error feedback.
-
-        The residual — everything rowwise quantization rounded away this
-        step — is carried into the NEXT step's bucket before quantizing,
-        so the compression error stays bounded (standard EF-SGD) instead
-        of accumulating across LocalSGD/DiLoCo syncs. ``store`` is None
-        for non-participants (zero contribution, nothing to feed back).
-        Runs on the single staging worker, so residual updates for one
-        plan never race."""
-        resid = store[i] if store is not None else None
-        if resid is not None:
-            # one fused pass: the add IS the private f32 copy
-            work = host_flat + resid
-        else:
-            work = np.asarray(host_flat, dtype=np.float32)
-        wire = compress_bucket(work, mode, dtype=out_dtype)
-        if store is not None:
-            resid = store[i]
-            if resid is None:
-                resid = self._buffer_pool.acquire(work.size, np.float32)
-                store[i] = resid
-            np.subtract(
-                work, decompress_bucket(wire, np.float32), out=resid
-            )
-        return wire
-
-    def _record_pipeline_timings(
-        self,
-        marks: List[Dict[str, Any]],
-        parent: int,
-        stage_ids: List[Dict[str, int]],
-        wire_runs: List[Any],
-        bucket_bytes: List[int],
-    ) -> None:
-        """Fold one streamed allreduce's per-bucket stage marks into
-        timings(): summed ``allreduce_pack_s`` / ``allreduce_wire_s`` /
-        ``allreduce_unpack_s``, the bucket count, and
-        ``overlap_efficiency`` — the fraction of total wire time that ran
-        concurrently with OTHER buckets' pipeline stages (a lower bound on
-        the real win: overlap with the caller's own compute, e.g. the next
-        microbatch's grad_fn, is invisible from here). Emitted to the
-        ``torchft_timings`` stream through the bounded async drain."""
-        stats = _pipeline_overlap_stats(marks)
+    def _update_timings(self, stats: Dict[str, float]) -> None:
+        """What the bucket pipeline reports of an allreduce (last values,
+        see :meth:`timings`); its thread, not the caller's."""
         with self._metrics_lock:
             self._timings.update(stats)
-        for i, mark in enumerate(marks):
-            for stage in ("pack", "wire", "unpack"):
-                span = mark.get(stage)
-                if span is None:
-                    continue
-                t0_pc, t1_pc = span
-                self._tracer.record_rel(
-                    stage, cat="allreduce", t0_pc=t0_pc, t1_pc=t1_pc,
-                    id=stage_ids[i][stage], parent=parent, bucket=i,
-                )
-            run = wire_runs[i]
-            if run is not None:
-                # what the PG's dispatch thread did for this bucket, from
-                # the stamps it left on the op's future: fn(comm) alone
-                # (a copy at a world of one, the ring otherwise); the time
-                # the op sat in its queue behind earlier buckets is an arg
-                t_enq, t_run0, t_run1 = run
-                self._tracer.record_rel(
-                    "wire_run", "allreduce", t_run0, t_run1,
-                    parent=stage_ids[i]["wire"], bucket=i,
-                    bytes=bucket_bytes[i],
-                    world=self._pg.size(),
-                    queued_us=int((t_run0 - t_enq) * 1e6),
-                )
-        self._log_timing_snapshot(ALLREDUCE_PIPELINE_PHASE)
 
     def timings(self) -> Dict[str, float]:
         """Per-phase wall-clock of the most recent quorum cycle:
@@ -2977,11 +2104,11 @@ class Manager:
         reconfigure; commit is the only part that serializes with the
         trainer), and ``heal_send_s`` / ``heal_recv_s`` plus
         ``heal_chunks`` / ``heal_mb_per_s`` when the checkpoint transport
-        reports chunk-stream stats. Streamed allreduces add
+        reports chunk-stream stats. Bucketed allreduces add
         ``allreduce_pack_s`` / ``allreduce_wire_s`` / ``allreduce_unpack_s``
         / ``allreduce_buckets`` / ``overlap_efficiency`` (see
-        :meth:`_record_pipeline_timings`) and, on the host plane,
-        ``stage_pool_hit_share``: of the device buckets the last streamed
+        ``bucketing.BucketPipeline.record_timings``) and, on the host plane,
+        ``stage_pool_hit_share``: of the device buckets the last bucketed
         allreduce fetched, the share that went into a recycled buffer of
         the pool (pages mapped) rather than a new allocation; 1.0 from a
         plan's second step on. Keys appear once the phase has run.
@@ -3727,28 +2854,9 @@ class Manager:
         # never apply a backend swap during teardown — drop it
         with self._pending_commit_lock:
             self._pending_pg_commit = None
-        # cancel queued (not-yet-run) staging tasks on a non-waiting
-        # shutdown: they would otherwise dispatch against the PG after
-        # pg.shutdown below, spuriously reporting errors on a torn-down
-        # manager — and fail their staged futures so any waiter unblocks
-        # immediately instead of riding out the full timeout
-        with self._staged_lock:
-            self._staging_down = True
-        self._staging_executor.shutdown(wait=wait, cancel_futures=not wait)
-        # pipeline unpack worker: cancelled bucket unpacks leave their
-        # bucket futures unresolved — the aggregate is bounded by the stage
-        # watchdog / sweep below, so no waiter stalls past the timeout
-        self._unpack_executor.shutdown(wait=wait, cancel_futures=not wait)
-        with self._staged_lock:
-            pending, self._staged_pending = self._staged_pending, []
-        for exec_fut, staged_fut in pending:
-            if exec_fut.cancelled() and not staged_fut.done():
-                try:
-                    staged_fut.set_exception(
-                        RuntimeError("manager shut down before dispatch")
-                    )
-                except RuntimeError:
-                    pass
+        # before the PG goes: queued staging tasks must not dispatch against
+        # a PG that is shut down
+        self._pipeline.shutdown(wait=wait)
         self._pg.shutdown()
         # best-effort: land any commit/timing events still queued in the
         # async drain before the process (and its log handlers) go away
@@ -3764,120 +2872,3 @@ class Manager:
         """Rendezvous store address of this replica group (leader's store)."""
         assert self._store_addr is not None
         return self._store_addr
-
-
-def _np_dtype(x: Any) -> Any:
-    return np.asarray(x).dtype if not hasattr(x, "dtype") else x.dtype
-
-
-@functools.lru_cache(maxsize=None)
-def _average_on_device() -> Callable[[Any, Any], Any]:
-    """The AVG normalisation of one landed leaf as a jitted computation.
-
-    It donates its input, so the quotient reuses the buffer the H2D just
-    filled and the step's HBM peak does not grow by a leaf; the divisor is a
-    float32 runtime scalar, so a quorum that goes 4 -> 3 -> 4 compiles
-    nothing new (one executable per leaf geometry). Bit for bit numpy's
-    result, except that XLA flushes a subnormal input or quotient (under
-    2**-126) to zero where numpy keeps it. Built on first use: a process
-    that only moves host arrays never gets here."""
-    import jax
-
-    def average_landed_leaf(x: Any, n: Any) -> Any:  # its name in a trace
-        return (x / n).astype(x.dtype)
-
-    return jax.jit(average_landed_leaf, donate_argnums=0)
-
-
-def _average(x: Any, num_participants: int, landed: bool = False) -> Any:
-    """A reduced SUM divided by the participants, rounded once back to its
-    own dtype (bf16 / f16 in, float32 quotient): the one expression of the
-    AVG normalisation, run where ``x`` is. ``landed`` says ``x`` is a leaf
-    ``place_leaf`` has just put where its original lives, a private buffer:
-    on a device it is then divided there, in place. Anything else (a numpy
-    array; a device-native PG's own result) gets a fresh array."""
-    import jax
-
-    if landed and isinstance(x, jax.Array):
-        return _average_on_device()(x, np.float32(num_participants))
-    return (x / num_participants).astype(_np_dtype(x))
-
-
-def _is_float_dtype(dtype: Any) -> bool:
-    """True for dtypes the wire codecs can compress (incl. ml_dtypes
-    bfloat16, which numpy does not class as np.floating)."""
-    return bool(
-        np.issubdtype(np.dtype(dtype), np.floating)
-        or "bfloat16" in str(dtype)
-    )
-
-
-def _covered_seconds(
-    start: float, end: float, intervals: List[Any]
-) -> float:
-    """Length of ``[start, end]`` covered by the union of ``intervals``."""
-    if end <= start:
-        return 0.0
-    clipped = sorted(
-        (max(start, a), min(end, b))
-        for a, b in intervals
-        if b > start and a < end
-    )
-    total = 0.0
-    cur_s: Optional[float] = None
-    cur_e = 0.0
-    for a, b in clipped:
-        if cur_s is None:
-            cur_s, cur_e = a, b
-        elif a <= cur_e:
-            cur_e = max(cur_e, b)
-        else:
-            total += cur_e - cur_s
-            cur_s, cur_e = a, b
-    if cur_s is not None:
-        total += cur_e - cur_s
-    return total
-
-
-def _payload_nbytes(payload: Any) -> int:
-    """Bytes of one bucket as it is handled: an ndarray, or a compressed
-    wire (codes + scales)."""
-    if is_compressed_wire(payload):
-        return int(payload.payload.nbytes + payload.scales.nbytes)
-    return int(getattr(payload, "nbytes", 0))
-
-
-def _pipeline_overlap_stats(marks: List[Dict[str, Any]]) -> Dict[str, float]:
-    """Summarize one streamed allreduce's per-bucket stage marks.
-
-    ``marks[i]`` maps stage name (``pack`` / ``wire`` / ``unpack``) to a
-    ``(start, end)`` perf_counter interval; stages a bucket never reached
-    (mid-stream failure, timeout) are simply absent. ``overlap_efficiency``
-    is Σᵢ |wireᵢ ∩ ∪ⱼ≠ᵢ(packⱼ ∪ wireⱼ ∪ unpackⱼ)| / Σᵢ |wireᵢ| — the
-    fraction of wire time hidden behind other buckets' pipeline stages
-    (a lower bound: overlap with caller compute is not observable here).
-    A single-bucket plan has nothing to hide behind and reports 0.0."""
-    pack_s = sum(e - s for m in marks if "pack" in m for s, e in [m["pack"]])
-    wire_s = sum(e - s for m in marks if "wire" in m for s, e in [m["wire"]])
-    unpack_s = sum(
-        e - s for m in marks if "unpack" in m for s, e in [m["unpack"]]
-    )
-    hidden = 0.0
-    for i, m in enumerate(marks):
-        if "wire" not in m:
-            continue
-        s, e = m["wire"]
-        others = [
-            iv
-            for j, mj in enumerate(marks)
-            if j != i
-            for iv in mj.values()
-        ]
-        hidden += _covered_seconds(s, e, others)
-    return {
-        "allreduce_pack_s": pack_s,
-        "allreduce_wire_s": wire_s,
-        "allreduce_unpack_s": unpack_s,
-        "allreduce_buckets": float(len(marks)),
-        "overlap_efficiency": (hidden / wire_s) if wire_s > 0 else 0.0,
-    }

@@ -424,7 +424,8 @@ def train(args) -> None:
             "timings": {k: round(v, 3) for k, v in manager.timings().items()
                         if k.endswith("_s") or k.startswith("heal_")
                         or k in ("allreduce_buckets", "overlap_efficiency",
-                                 "stage_pool_hit_share", "trace_dropped")},
+                                 "stage_pool_hit_share",
+                                 "wire_passthrough_share", "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
             "peak_hbm_bytes": max((p for p in peaks if p), default=None),
             "cache_dir": cache_dir, "cache": cache_events,

@@ -17,9 +17,11 @@ import pytest
 from test_manager import make_manager, make_quorum
 from torchft_tpu import bucketing
 from torchft_tpu.bucketing import _covered_seconds, _pipeline_overlap_stats
+from torchft_tpu.coordination import KvStoreServer
 from torchft_tpu.process_group import (
     FakeProcessGroupWrapper,
     ProcessGroupDummy,
+    ProcessGroupHost,
     ReduceOp,
 )
 from torchft_tpu.work import Future, FutureWork, GradStream, join_futures
@@ -39,7 +41,7 @@ class CountingPG(ProcessGroupDummy):
         super().__init__()
         self.allreduce_calls = []
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         arrays = list(arrays)
         self.allreduce_calls.append(len(arrays))
         return super().allreduce(arrays, op)
@@ -55,7 +57,7 @@ class GatedPG(ProcessGroupDummy):
         self.pending = []  # (arrays, fut) in dispatch order
         self.dispatched = threading.Condition()
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         fut = Future()
         with self.dispatched:
             self.pending.append(([np.asarray(a).copy() for a in arrays], fut))
@@ -325,8 +327,9 @@ class TestAverageWhereItLands:
         for up in (s for s in spans if s["name"] == "unpack"):
             kids = sorted((s for s in spans if s["parent"] == up["id"]),
                           key=lambda s: s["ts_us"])
-            assert [s["name"] for s in kids] == ["h2d", "divide"]
-            h2d, divide = (s["args"] for s in kids)
+            # (the default PG hands the staging buffer back: recycle)
+            assert [s["name"] for s in kids] == ["h2d", "divide", "recycle"]
+            h2d, divide, _recycle = (s["args"] for s in kids)
             assert divide["where"] == where
             assert divide["leaves"] == h2d["leaves"] == 2
             assert divide["bytes"] == h2d["bytes"] == 2 * 64 * 4
@@ -527,26 +530,31 @@ class CopyingPG(ProcessGroupDummy):
         super().__init__()
         self.inputs = []  # every array a collective was handed, in order
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         arrays = list(arrays)
         self.inputs.extend(arrays)
         return super().allreduce([np.array(a, copy=True) for a in arrays], op)
 
 
 class GatedCopyingPG(CopyingPG):
-    """CopyingPG whose futures resolve only when the test says."""
+    """CopyingPG whose futures resolve only when the test says; with
+    ``copy=False`` to the very arrays it was handed (a world of one that
+    was given a donated buffer)."""
 
-    def __init__(self):
+    def __init__(self, copy=True):
         super().__init__()
+        self.copy = copy
         self.pending = []
         self.dispatched = threading.Condition()
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         arrays = list(arrays)
         self.inputs.extend(arrays)
         fut = Future()
         with self.dispatched:
-            self.pending.append(([np.array(a, copy=True) for a in arrays], fut))
+            self.pending.append(
+                ([np.array(a, copy=self.copy) for a in arrays], fut)
+            )
             self.dispatched.notify_all()
         return FutureWork(fut)
 
@@ -582,6 +590,39 @@ def _pooled(m):
     return sum(len(v) for v in m._buffer_pool._free.values())
 
 
+def _parked(m):
+    with m._pipeline._parked_lock:
+        return list(m._pipeline._parked)
+
+
+def _let_landed_leaves_finish(m):
+    """The pipeline asks a parked buffer's tokens and never waits for them;
+    a test that counts hits waits here, as a real step's backward pass
+    does."""
+    import jax
+
+    jax.block_until_ready([t for _buf, tokens in _parked(m) for t in tokens])
+
+
+@pytest.fixture()
+def host_of_one():
+    """A real ProcessGroupHost that forms a world of one whatever the
+    (mocked) quorum says: the last replica group standing."""
+    store = KvStoreServer("127.0.0.1:0")
+
+    class HostOfOne(ProcessGroupHost):
+        def configure(self, store_addr, replica_rank, replica_world_size,
+                      quorum_id=0):
+            super().configure(
+                f"127.0.0.1:{store.port}/one", 0, 1, quorum_id=quorum_id
+            )
+
+    pg = HostOfOne(timeout=10.0)
+    yield pg
+    pg.shutdown()
+    store.shutdown()
+
+
 def _d2h_spans(m, n):
     deadline = time.monotonic() + 5
     while time.monotonic() < deadline:  # stage spans land at resolve
@@ -614,6 +655,8 @@ class TestStagingThroughThePool:
         assert shares == [0.0] + [1.0] * 5
         assert (pool.hits, pool.misses) == (15, 3)
         assert _pooled(m) == 3
+        # every result was a copy: nothing came back as its staging buffer
+        assert m.timings()["wire_passthrough_share"] == 0.0
 
     def test_one_d2h_span_a_bucket_with_bytes_pieces_pooled(self):
         """Pieces are arguments, never spans: the tree of a step keeps its
@@ -636,7 +679,7 @@ class TestStagingThroughThePool:
         assert {s["name"] for s in spans if s["cat"] == "allreduce"} <= {
             "allreduce", "wait_quorum", "configure_commit_wait", "capture",
             "pack", "grad_wait", "d2h", "dispatch", "wire", "wire_run",
-            "unpack", "h2d", "divide",
+            "unpack", "h2d", "divide", "recycle",
         }
 
     def test_many_pieces_are_still_one_span(self, monkeypatch):
@@ -682,12 +725,14 @@ class TestStagingThroughThePool:
         m.shutdown(wait=False)
         assert _pooled(m) == 3
 
-    def test_never_after_a_failed_bucket(self):
+    @pytest.mark.parametrize("inner", [CopyingPG, ProcessGroupDummy])
+    def test_never_after_a_failed_bucket(self, inner):
         """Bucket 1's wire fails: its buffer is dropped, and so is bucket
         2's, whose wire resolves after the op has failed. Bucket 0 landed
-        whole and may be back (it races the failure)."""
+        whole and may be back (it races the failure), or parked where the
+        PG handed it back."""
         tree = _device_tree()
-        pg = FakeProcessGroupWrapper(CopyingPG())
+        pg = FakeProcessGroupWrapper(inner())
         m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
         m.start_quorum()
         pg.report_future_error(RuntimeError("injected wire failure"),
@@ -697,13 +742,16 @@ class TestStagingThroughThePool:
         time.sleep(0.2)  # whatever lands after the failure
         m.shutdown(wait=False)
         back = {k for k, v in m._buffer_pool._free.items() if v}
+        back |= {(b.dtype.str, b.size) for b, _tokens in _parked(m)}
         assert back <= {("<f4", _BUCKET_BYTES[0] // 4)}, back
 
-    def test_never_after_a_timed_out_bucket(self):
+    @pytest.mark.parametrize("copy", [True, False])
+    def test_never_after_a_timed_out_bucket(self, copy):
         """The wire resolves after the op has timed out: its buffer may
-        still be read by whatever was slow, and is dropped."""
+        still be read by whatever was slow, and is dropped: whether the
+        result is a copy or the buffer itself."""
         tree = _device_tree()
-        pg = GatedCopyingPG()
+        pg = GatedCopyingPG(copy=copy)
         m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3,
                          timeout=0.3)
         m.start_quorum()
@@ -714,23 +762,160 @@ class TestStagingThroughThePool:
         pg.release_all()
         time.sleep(0.3)
         m.shutdown(wait=False)
-        assert _pooled(m) == 0
+        assert _pooled(m) == 0 and _parked(m) == []
 
-    def test_never_when_the_pg_returns_its_input(self):
-        """ProcessGroupDummy hands the staging buffer back as its result:
-        the landed leaves may alias it (a CPU device_put of a numpy view
-        copies nothing), so it stays out of the pool, every step."""
-        tree = _device_tree()
-        m = make_manager(quorum=make_quorum(), bucket_cap_bytes=_CAP3)
-        outs = []
-        for _ in range(3):
+    @pytest.mark.parametrize("kind", ["host_of_one", "dummy"])
+    def test_a_passed_through_buffer_recycles_and_every_step_stays_intact(
+        self, kind, host_of_one
+    ):
+        """The result is the staging buffer (a real ProcessGroupHost at a
+        world of one, given its donated input; ProcessGroupDummy always):
+        nothing is copied, the buffer still comes back once the leaves
+        landed from it are done with it, and no later step's fetch writes
+        over an earlier step's output."""
+        pg = host_of_one if kind == "host_of_one" else ProcessGroupDummy()
+        m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        ref = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                           bucket_cap_bytes=_CAP3)
+        pool = m._buffer_pool
+        outs, wants, hit, passed = [], [], [], []
+        for step in range(5):
+            tree = _device_tree(seed=step)
             outs.append(_reduce(m, tree, streamed=True))
+            wants.append(_reduce(ref, tree, streamed=True))
+            hit.append(m.timings()["stage_pool_hit_share"])
+            passed.append(m.timings()["wire_passthrough_share"])
+            # (a sweep before a later bucket's fetch may already have
+            # returned an earlier bucket's)
+            assert _pooled(m) + len(_parked(m)) == 3
+            _let_landed_leaves_finish(m)
+            m.should_commit()
+            ref.should_commit()
+        spans = m.tracer.export()["spans"]
+        m.shutdown(wait=False)
+        ref.shutdown(wait=False)
+        assert hit == [0.0] + [1.0] * 4
+        assert passed == [1.0] * 5
+        assert (pool.hits, pool.misses) == (12, 3)
+        for step, (out, want) in enumerate(zip(outs, wants)):
+            for k in want:  # still what it was when it resolved
+                assert np.array_equal(_bits(out[k]), _bits(want[k])), (step, k)
+        h2d = [s for s in spans if s["name"] == "h2d"]
+        assert len(h2d) == 15
+        assert all(s["args"]["passed_through"] == 1 for s in h2d)
+        unpacks = {s["id"] for s in spans if s["name"] == "unpack"}
+        recycle = [s for s in spans if s["name"] == "recycle"]
+        assert len(recycle) == 15
+        assert all(s["parent"] in unpacks and s["args"]["leaves"] == 2
+                   for s in recycle)
+        if kind == "host_of_one":  # the PG's thread still stamps the op
+            runs = [s for s in spans if s["name"] == "wire_run"]
+            assert len(runs) == 15 and all(s["args"]["world"] == 1 for s in runs)
+
+    def test_parked_until_the_landed_leaves_are_ready(
+        self, host_of_one, monkeypatch
+    ):
+        """While a transfer may still read the buffer it is in neither the
+        pool nor anybody's hands: a second step draws buffers of its own
+        and the first step's output stays what it was. Once ready, back."""
+
+        class Gate:
+            ready = False
+
+            def is_ready(self):
+                return self.ready
+
+        gates = []
+
+        def gated_token():
+            return lambda leaf: gates.append(Gate()) or gates[-1]
+
+        monkeypatch.setattr(bucketing, "_landed_token", gated_token)
+        a, b = _device_tree(seed=1), _device_tree(seed=2)
+        m = make_manager(pg=host_of_one, quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        pool = m._buffer_pool
+        out_a = _reduce(m, a, streamed=True)
+        m.should_commit()
+        held_a = [buf for buf, _tokens in _parked(m)]
+        assert len(gates) == 6 and len(held_a) == 3 and _pooled(m) == 0
+        out_b = _reduce(m, b, streamed=True)  # a's transfers "in flight"
+        m.should_commit()
+        assert (pool.hits, pool.misses) == (0, 6) and _pooled(m) == 0
+        held_b = [buf for buf, _tokens in _parked(m) if not any(
+            buf is x for x in held_a)]
+        assert len(held_b) == 3
+        for x in held_a:
+            for y in held_b:
+                assert not np.shares_memory(x, y)
+        for k in a:
+            assert np.array_equal(_bits(out_a[k]), _bits(np.asarray(a[k]) / 2))
+        for g in gates[:6]:  # a's leaves are ready, b's not yet
+            g.ready = True
+        out_c = _reduce(m, a, streamed=True)
+        m.should_commit()
+        m.shutdown(wait=False)
+        assert (pool.hits, pool.misses) == (3, 6)
+        assert m.timings()["stage_pool_hit_share"] == 1.0
+        parked = [buf for buf, _tokens in _parked(m)]
+        assert len(parked) == 6 and all(
+            any(buf is x for x in held_a + held_b) for buf in parked
+        )
+        for k in a:
+            assert np.array_equal(_bits(out_b[k]), _bits(np.asarray(b[k]) / 2))
+            assert np.array_equal(_bits(out_c[k]), _bits(np.asarray(a[k]) / 2))
+
+    @pytest.mark.parametrize("reduce_op,back", [
+        (ReduceOp.SUM, False), (ReduceOp.AVG, True),
+    ])
+    def test_numpy_leaves_that_are_views_keep_their_buffer(
+        self, host_of_one, reduce_op, back
+    ):
+        """Under SUM a landed numpy leaf IS a slice of the passed-through
+        buffer: the caller's for good, never refilled. An AVG's quotient
+        is memory of its own and nothing reads the buffer any more."""
+        m = make_manager(pg=host_of_one, quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        outs, wants = [], []
+        for step in range(3):
+            tree = {k: np.asarray(v) for k, v in _device_tree(seed=step).items()}
+            wants.append({
+                k: v / 2 if reduce_op == ReduceOp.AVG else v.copy()
+                for k, v in tree.items()
+            })
+            outs.append(_reduce(m, tree, streamed=True, reduce_op=reduce_op))
             m.should_commit()
         m.shutdown(wait=False)
-        assert _pooled(m) == 0 and m._buffer_pool.hits == 0
-        for out in outs:  # none overwritten by a later step
-            for k, v in tree.items():
-                assert np.array_equal(_bits(out[k]), _bits(np.asarray(v) / 2))
+        assert _parked(m) == []
+        assert (_pooled(m), m._buffer_pool.hits) == ((3, 6) if back else (0, 0))
+        assert "wire_passthrough_share" not in m.timings()  # no device bucket
+        for out, want in zip(outs, wants):
+            for k in want:
+                assert np.array_equal(_bits(out[k]), _bits(want[k]))
+
+    def test_a_device_leaf_that_is_a_slice_of_the_buffer_keeps_it(self):
+        """A CPU backend's device_put of aligned memory copies nothing: with
+        no divide after it the landed leaf IS the buffer. After the
+        (donating) divide it is not, and a token says when it was read."""
+        import jax
+
+        raw = np.zeros(4096 + 16, np.float32)
+        start = (-raw.ctypes.data % 64) // 4
+        buf = raw[start:start + 4096]
+        buf[:] = np.arange(4096)
+        placed = jax.device_put(buf[:1024].reshape(32, 32))
+        if placed.unsafe_buffer_pointer() != buf.ctypes.data:
+            pytest.skip("this backend copied")
+        assert bucketing.readers_of(buf, [placed]) is None
+        assert bucketing.readers_of(buf, [np.ones(3), placed]) is None
+        halved = bucketing._average(placed, 2, landed=True)
+        (token,) = bucketing.readers_of(buf, [np.ones(3), halved])
+        jax.block_until_ready(token)
+        assert token.is_ready()
+        halved.delete()  # the caller's next step donated it
+        assert token.is_ready()
+        assert bucketing.readers_of(buf, [np.ones(3)]) == []
+        assert bucketing.readers_of(buf, [buf[5:9]]) is None
 
     def test_deleting_the_leaves_after_the_call_changes_nothing(self, monkeypatch):
         """Donation safety with the capture in pieces: the next jitted step
@@ -752,9 +937,10 @@ class TestStagingThroughThePool:
         for k in want:
             assert np.array_equal(_bits(out[k]), _bits(want[k]))
 
-    def test_two_steps_in_flight_share_no_buffer(self):
+    @pytest.mark.parametrize("copy", [True, False])
+    def test_two_steps_in_flight_share_no_buffer(self, copy):
         a, b = _device_tree(seed=1), _device_tree(seed=2)
-        pg = GatedCopyingPG()
+        pg = GatedCopyingPG(copy=copy)
         m = make_manager(pg=pg, quorum=make_quorum(), bucket_cap_bytes=_CAP3)
         # a first step fills the pool, so the two below draw from it
         m.start_quorum()

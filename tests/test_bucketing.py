@@ -114,7 +114,7 @@ class CountingPG(ProcessGroupDummy):
         super().__init__()
         self.allreduce_calls = []
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         arrays = list(arrays)
         self.allreduce_calls.append(len(arrays))
         return super().allreduce(arrays, op)

@@ -343,7 +343,7 @@ def test_slow_rendezvous_timeout_discards_step_then_heals(caplog):
             super().__init__(timeout=30.0, mode="local")
             self.calls = 0
 
-        def allreduce(self, arrays, op=ReduceOp.SUM):
+        def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
             self.calls += 1
             if self.calls == stall_step:
                 time.sleep(6.0)

@@ -524,7 +524,7 @@ class TestPipelineSpanTree:
     """One streamed allreduce per step through a real ProcessGroupHost at a
     world of one. Per bucket: pack > (d2h, [codec], dispatch), wire >
     wire_run, unpack > ([decode], h2d, divide: the average is taken where
-    the leaf has landed); the three old spans keep
+    the leaf has landed; uncompressed, recycle); the three old spans keep
     their names, category and ``(quorum_id, step)``."""
 
     STEPS = 3
@@ -561,7 +561,10 @@ class TestPipelineSpanTree:
         want = {
             "pack": ["d2h"] + (["codec"] if compress else []) + ["dispatch"],
             "wire": ["wire_run"],
-            "unpack": (["decode"] if compress else []) + ["h2d", "divide"],
+            # uncompressed, the world of one hands the donated staging
+            # buffer back as the result: recycle decides when it may refill
+            "unpack": (["decode"] if compress else []) + ["h2d", "divide"]
+            + ([] if compress else ["recycle"]),
         }
         names = {s["name"] for s in spans if s["cat"] == "allreduce"}
         assert ("codec" in names) == ("decode" in names) == bool(compress)

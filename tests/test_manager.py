@@ -131,7 +131,7 @@ class TestQuorumHappyPath:
             def configure(self, *a, **k):
                 pass
 
-            def allreduce(self, arrays, op=ReduceOp.SUM):
+            def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
                 release.wait(5)  # occupy the staging worker
                 from torchft_tpu.work import DummyWork
 
@@ -187,7 +187,7 @@ class TestQuorumHappyPath:
             def configure(self, *a, **k):
                 pass
 
-            def allreduce(self, arrays, op=ReduceOp.SUM):
+            def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
                 return FutureWork(Future())  # dispatches, never resolves
 
             def errored(self):
@@ -239,7 +239,7 @@ class TestQuorumHappyPath:
             def configure(self, *a, **k):
                 pass
 
-            def allreduce(self, arrays, op=ReduceOp.SUM):
+            def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
                 unstick.wait(60)  # wedge the single staging worker
                 return DummyWork(list(arrays))
 
@@ -294,7 +294,7 @@ class TestQuorumHappyPath:
             def configure(self, *a, **k):
                 pass
 
-            def allreduce(self, arrays, op=ReduceOp.SUM):
+            def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
                 gate.wait(5)  # hold the op until the caller donated
                 return DummyWork([np.asarray(a) for a in arrays])
 

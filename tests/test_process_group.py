@@ -172,6 +172,64 @@ class TestProcessGroupHost:
         )
         pg.shutdown()
 
+    @pytest.mark.parametrize("donate", [True, False])
+    def test_world_of_one_allreduce_hands_back_what_was_donated(
+        self, store, donate
+    ):
+        """Nothing to reduce: a donated ndarray IS the result (no copy, no
+        fresh pages); without the caller's word the result is memory of its
+        own, as at any larger world."""
+        (pg,) = make_pgs(store, 1)
+        x, y = np.arange(6.0), np.arange(4, dtype=np.int32)
+        fut = pg.allreduce([x, y], ReduceOp.SUM, donate=donate).get_future()
+        out = fut.wait(timeout=10)
+        pg.shutdown()
+        assert (out[0] is x, out[1] is y) == (donate, donate)
+        assert np.shares_memory(out[0], x) == donate
+        np.testing.assert_array_equal(out[0], np.arange(6.0))
+        np.testing.assert_array_equal(out[1], np.arange(4))
+        # the dispatch thread still stamps the op: allreduce/wire_run
+        enqueued, run0, run1 = fut.stamps
+        assert enqueued <= run0 <= run1
+
+    @pytest.mark.parametrize("payload", ["tuple", "compressed"])
+    def test_world_of_one_donated_wires_are_still_copied(self, store, payload):
+        """The quantized wire formats keep their independent copies, donated
+        or not: their callers decode in place."""
+        from torchft_tpu.ops.quantization import compress_bucket
+
+        (pg,) = make_pgs(store, 1)
+        flat = np.linspace(-3, 3, 1024, dtype=np.float32)
+        if payload == "compressed":
+            wire = compress_bucket(flat, "int8")
+            parts = lambda w: (w.payload, w.scales)
+        else:
+            wire = (np.arange(8, dtype=np.uint8), np.ones(2, np.float32), 8)
+            parts = lambda w: (w[0], w[1])
+        (out,) = pg.allreduce([wire], donate=True).get_future().wait(timeout=10)
+        pg.shutdown()
+        assert type(out) is type(wire)
+        for got, given in zip(parts(out), parts(wire)):
+            np.testing.assert_array_equal(got, given)
+            assert not np.shares_memory(got, given)
+
+    _ONE = {
+        "allgather": lambda pg, x: pg.allgather([x]),
+        "broadcast": lambda pg, x: pg.broadcast([x]),
+        "reduce_scatter": lambda pg, x: pg.reduce_scatter([[x]]),
+        "alltoall": lambda pg, x: pg.alltoall([x]),
+    }
+
+    @pytest.mark.parametrize("collective", sorted(_ONE))
+    def test_world_of_one_other_collectives_still_copy(self, store, collective):
+        (pg,) = make_pgs(store, 1)
+        x = np.arange(5.0)
+        out = self._ONE[collective](pg, x).get_future().wait(timeout=10)
+        pg.shutdown()
+        got = out[0][0] if collective == "allgather" else out[0]
+        np.testing.assert_array_equal(got, x)
+        assert not np.shares_memory(got, x)
+
     # per-collective issue fns for the resiliency matrix (reference
     # process_group_test.py:963-1027 parametrizes its resiliency harness
     # over every collective; an abort must fail and a reconfigure must
@@ -403,6 +461,26 @@ class TestWrappers:
         # Reconfigure clears the error.
         pg.configure("ignored:0/x", 0, 1)
         assert pg.error() is None
+
+    @pytest.mark.parametrize("wrap", [
+        ErrorSwallowingProcessGroupWrapper,
+        FakeProcessGroupWrapper,
+        lambda pg: ErrorSwallowingProcessGroupWrapper(
+            FakeProcessGroupWrapper(pg)
+        ),
+    ], ids=["swallowing", "fake", "swallowing_over_fake"])
+    @pytest.mark.parametrize("donate", [True, False])
+    def test_wrappers_forward_donate(self, store, wrap, donate):
+        (inner,) = make_pgs(store, 1)
+        pg = wrap(inner)
+        x = np.arange(6.0)
+        (out,) = pg.allreduce([x], ReduceOp.SUM, donate=donate).get_future().wait(
+            timeout=10
+        )
+        inner.shutdown()
+        assert (out is x) == donate
+        assert np.shares_memory(out, x) == donate
+        assert pg.errored() is None
 
     def test_fake_wrapper_injects_future_error(self):
         pg = FakeProcessGroupWrapper(ProcessGroupDummy())

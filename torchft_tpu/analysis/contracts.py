@@ -48,6 +48,10 @@ DECLARED_TIMINGS: Dict[str, str] = {
         "device buckets of the last streamed allreduce fetched into a "
         "recycled pool buffer, over all of them"
     ),
+    "wire_passthrough_share": (
+        "device buckets of the last streamed allreduce whose collective "
+        "resolved to the staging buffer it was given, over all of them"
+    ),
     "collective_reroute": "cumulative mid-collective link reroutes",
     # control plane (two-level)
     "via_aggregator": "1 when control RPCs ride the pod aggregator",

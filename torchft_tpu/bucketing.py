@@ -513,8 +513,8 @@ def stage(
 ) -> Tuple[np.ndarray, Optional[np.ndarray], Dict[str, int]]:
     """Bucket ``i`` of a :func:`capture` as host memory for the wire, on the
     staging thread: ``(host_flat, pooled_buf, info)``. ``pooled_buf`` is the
-    pool buffer to give back once the bucket has LANDED (None: nothing was
-    taken); ``info`` is what the ``d2h`` span says of it (``bytes``, and for
+    pool buffer to give back once the bucket has LANDED, and what landed
+    from it has read it (None: nothing was taken); ``info`` is what the ``d2h`` span says of it (``bytes``, and for
     a device bucket ``pieces`` and ``pooled``: 1 when the buffer is a
     recycled one). ``captured`` is None for a non-participant: its
     contribution is zeros of the plan's size and dtype, built from shapes
@@ -717,6 +717,49 @@ def land_reduced(
     return pairs
 
 
+@functools.lru_cache(maxsize=None)
+def _landed_token() -> Callable[[Any], Any]:
+    """``token(leaf)``: a device scalar that is ready once ``leaf`` is,
+    dispatched and never waited for. It is the pipeline's own, so it can
+    still be asked after the caller has donated ``leaf`` to its next step (a
+    deleted array says nothing of whether the transfer that filled it is
+    over). ``keep_unused``: the runtime starts a program once every argument
+    it was handed is defined, read or not."""
+    import jax
+
+    def landed_token(leaf: Any) -> Any:  # its name in a trace
+        return np.int32(0)
+
+    return jax.jit(landed_token, keep_unused=True)
+
+
+def readers_of(buf: np.ndarray, leaves: Sequence[Any]) -> Optional[List[Any]]:
+    """What still reads the staging buffer ``buf`` after ``leaves`` landed
+    from it (the collective handed ``buf`` back as its own result): one
+    token (``is_ready()``) a device leaf, whose transfer out of ``buf`` may
+    be in flight; nothing for a numpy leaf in memory of its own (an AVG's
+    quotient). None where a leaf IS a slice of ``buf`` (a numpy leaf under
+    SUM; a CPU backend's ``device_put`` of aligned memory, which copies
+    nothing, with no divide after it): that buffer is the caller's now."""
+    import jax
+
+    lo = buf.ctypes.data
+    hi = lo + buf.nbytes
+    tokens = []
+    for leaf in leaves:
+        if isinstance(leaf, jax.Array):
+            if any(
+                s.device.platform == "cpu"
+                and lo <= s.data.unsafe_buffer_pointer() < hi
+                for s in leaf.addressable_shards
+            ):
+                return None
+            tokens.append(_landed_token()(leaf))
+        elif not isinstance(leaf, np.ndarray) or np.shares_memory(leaf, buf):
+            return None
+    return tokens
+
+
 # ---------------------------------------------------------------------------
 # the schedule: which thread runs which stage of which bucket
 
@@ -843,6 +886,11 @@ class _BucketOp:
             for _ in range(n)
         ]
         self.wire_runs: List[Any] = [None] * n
+        # which buckets were fetched from the device into a pool buffer, and
+        # which got that buffer back as the collective's result
+        # (wire_passthrough_share)
+        self.from_device = [False] * n
+        self.passed_through = [False] * n
         self.bucket_futs: List[Future] = [Future() for _ in range(n)]
         self.final: Future = Future()
         join_futures(self.bucket_futs).then(self._assemble).add_done_callback(
@@ -885,7 +933,7 @@ class BucketPipeline:
 
     ``on_timings(stats)`` receives what ``Manager.timings()`` shows of the
     pipeline (``stage_pool_hit_share`` from the staging thread, the stage
-    sums from :meth:`record_timings`)."""
+    sums and ``wire_passthrough_share`` from :meth:`record_timings`)."""
 
     def __init__(
         self,
@@ -927,6 +975,11 @@ class BucketPipeline:
             weakref.WeakKeyDictionary()
         )
         self._ef_lock = threading.Lock()
+        # staging buffers that came back as their collective's result, each
+        # with the tokens of the device leaves landed from it: back to the
+        # pool once those are ready (_recycle parks, _sweep_parked releases)
+        self._parked: List[Tuple[np.ndarray, List[Any]]] = []
+        self._parked_lock = threading.Lock()
 
     @property
     def device_native(self) -> bool:
@@ -1143,6 +1196,9 @@ class BucketPipeline:
                             bucket=i,
                         ):
                             captured[i].block_until_ready()
+                    # what earlier landings have finished with goes back
+                    # to the pool before this bucket draws from it
+                    self._sweep_parked()
                     # bucket 0 carries how long the staging worker took to
                     # get to this op
                     with tracer.span(
@@ -1155,6 +1211,7 @@ class BucketPipeline:
                         )
                         sp.args.update(info)
                     if "pooled" in info:
+                        op.from_device[i] = True
                         acquired += 1
                         hits += info["pooled"]
                 payload: Any = host_flat
@@ -1169,10 +1226,15 @@ class BucketPipeline:
                             host_flat, modes[i], plan.dtypes[i], ef_store, i
                         )
                         sp.args["bytes_out"] = _payload_nbytes(payload)
+                # a buffer drawn from the pool for this bucket is the
+                # pipeline's own and is not touched again before it lands:
+                # the group may hand it back as the result (a world of one
+                # has nothing to reduce), and _land sees that it did
+                donate = pooled_buf is not None and modes[i] == "off"
                 with tracer.span(
                     "dispatch", cat="allreduce", parent=pk_id, bucket=i
                 ):
-                    w = self._pg.allreduce([payload], pg_op)
+                    w = self._pg.allreduce([payload], pg_op, donate=donate)
                 t1b = time.perf_counter()
                 op.marks[i]["pack"] = (t0b, t1b)
                 w.get_future().add_done_callback(
@@ -1220,14 +1282,15 @@ class BucketPipeline:
         leak a partially-applied reduction."""
         try:
             t0u = time.perf_counter()
-            # a PG that hands its input back as its result (world-of-one
-            # short circuits): the landed leaves may be views of, or
-            # transfers still reading, the staging buffer
+            # a PG that hands its input back as its result (a world of
+            # one, given a donated buffer): the landed leaves may be views
+            # of, or transfers still reading, the staging buffer
             passed_through = (
                 pooled_buf is not None
                 and isinstance(flat, np.ndarray)
                 and np.shares_memory(flat, pooled_buf)
             )
+            op.passed_through[i] = passed_through
             # the bucket's first unpack child carries how long it sat
             # behind earlier buckets on the one unpack worker (device
             # plane: unpack runs in the wire's callback)
@@ -1238,6 +1301,8 @@ class BucketPipeline:
             def span(name: str, **args: Any) -> Any:
                 args.update(first)
                 first.clear()
+                if name == "h2d":
+                    args["passed_through"] = int(passed_through)
                 return self._tracer.span(
                     name, cat="allreduce",
                     parent=op.stage_ids[i]["unpack"], bucket=i, **args,
@@ -1253,20 +1318,51 @@ class BucketPipeline:
             pairs = land_reduced(
                 flat, op.leaves, op.plan, i, op.divisor, op.place, span
             )
+            if pooled_buf is not None and not op.final.done():
+                # recycle this bucket's staging buffer: on success only (an
+                # op that already failed or timed out drops it: its wire
+                # thread may still read the buffer). The moment it lands
+                # where the result is memory of its own; where the PG passed
+                # the buffer through, once what landed from it has read it
+                if passed_through:
+                    with span("recycle", leaves=len(pairs)):
+                        self._recycle(pooled_buf, [v for _, v in pairs])
+                else:
+                    self._pool.release(pooled_buf)
             op.marks[i]["unpack"] = (t0u, time.perf_counter())
-            if (
-                pooled_buf is not None
-                and not passed_through
-                and not op.final.done()
-            ):
-                # recycle this bucket's staging buffer the moment it lands:
-                # on success only (an op that already failed or timed out
-                # drops it: its wire thread may still read the buffer),
-                # never when the PG passed it through
-                self._pool.release(pooled_buf)
             _settle(op.bucket_futs[i], pairs)
         except Exception as e:  # noqa: BLE001
             _settle(op.bucket_futs[i], exc=e)
+
+    def _recycle(self, buf: np.ndarray, landed: Sequence[Any]) -> None:
+        """``buf``, which its collective handed back as the result, has
+        landed as ``landed``: into the pool now if nothing reads it any
+        more, parked while a transfer may (:func:`readers_of`), dropped if a
+        landed leaf is a slice of it. Before the bucket's future settles,
+        while the leaves are still the pipeline's alone; it dispatches and
+        does not wait."""
+        tokens = readers_of(buf, landed)
+        if tokens is None:
+            return
+        if not tokens:
+            self._pool.release(buf)
+            return
+        with self._parked_lock:
+            self._parked.append((buf, tokens))
+
+    def _sweep_parked(self) -> None:
+        """Give back to the pool every parked buffer whose landed leaves
+        are ready: the transfers that read it are over. It asks and never
+        waits, so a buffer still being read stays parked, and the bucket
+        that wanted it allocates (a second step in flight)."""
+        done: List[Tuple[np.ndarray, List[Any]]] = []
+        with self._parked_lock:
+            parked, self._parked = self._parked, []
+            for entry in parked:
+                ready = all(t.is_ready() for t in entry[1])
+                (done if ready else self._parked).append(entry)
+        for buf, _tokens in done:
+            self._pool.release(buf)
 
     def record_timings(self, op: _BucketOp) -> None:
         """Fold one resolved op's per-bucket stage marks into
@@ -1277,7 +1373,14 @@ class BucketPipeline:
         the real win: overlap with the caller's own compute, e.g. the next
         microbatch's grad_fn, is invisible from here); and record the stage
         spans, which are known only now, from the same marks."""
-        self._on_timings(_pipeline_overlap_stats(op.marks))
+        stats = _pipeline_overlap_stats(op.marks)
+        if any(op.from_device):
+            # of the device buckets, those whose collective resolved to the
+            # staging buffer it was given: nothing was copied on the wire
+            stats["wire_passthrough_share"] = sum(
+                p for p, d in zip(op.passed_through, op.from_device) if d
+            ) / sum(op.from_device)
+        self._on_timings(stats)
         for i, mark in enumerate(op.marks):
             for name in ("pack", "wire", "unpack"):
                 if name not in mark:
@@ -1291,7 +1394,8 @@ class BucketPipeline:
             if run is not None:
                 # what the PG's dispatch thread did for this bucket, from
                 # the stamps it left on the op's future: fn(comm) alone
-                # (a copy at a world of one, the ring otherwise); the time
+                # (at a world of one the donated buffer handed back, or a
+                # copy of what was not donated; the ring otherwise); the time
                 # the op sat in its queue behind earlier buckets is an arg
                 t_enq, t_run0, t_run1 = run
                 self._tracer.record_rel(

@@ -205,8 +205,20 @@ class ProcessGroup(ABC):
 
     # -- collectives ------------------------------------------------------
     @abstractmethod
-    def allreduce(self, arrays: Sequence[Any], op: ReduceOp = ReduceOp.SUM) -> Work:
-        """Future resolves to the reduced arrays (same structure as input)."""
+    def allreduce(
+        self,
+        arrays: Sequence[Any],
+        op: ReduceOp = ReduceOp.SUM,
+        donate: bool = False,
+    ) -> Work:
+        """Future resolves to the reduced arrays (same structure as input).
+
+        The results are the caller's own: they share no memory with
+        ``arrays``, unless the call ``donate``s them. ``donate=True`` is the
+        caller's word that the arrays are its own and that it will not touch
+        them again: a group may then hand them back as the result where
+        nothing is to be reduced (:class:`ProcessGroupHost` at a world of
+        one does, for plain ndarrays). A group may ignore it."""
 
     @abstractmethod
     def allgather(self, arrays: Sequence[Any]) -> Work:
@@ -269,7 +281,7 @@ class ProcessGroupDummy(ProcessGroup):
     def rank(self) -> int:
         return self._rank
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         return DummyWork(list(arrays))
 
     def allgather(self, arrays):
@@ -1487,7 +1499,7 @@ class ProcessGroupHost(ProcessGroup):
             return FutureWork(fut)
 
     # -- collectives ------------------------------------------------------
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         from torchft_tpu.ops.quantization import CompressedWire
 
         host = [_to_host(a) for a in arrays]
@@ -1514,11 +1526,18 @@ class ProcessGroupHost(ProcessGroup):
                     )
                 ]
             if comm.world == 1:
-                # independent copies: at world >= 2 results never alias the
-                # inputs (the ring/exchange paths allocate), and the
-                # degraded single-replica fleet must honor the same
-                # contract. _copy_payload is tuple-safe (quantized wire).
-                return [_copy_payload(h) for h in host]
+                # nothing to reduce. A donated ndarray is its own result
+                # (the caller gave it up: no copy, no fresh pages). Anything
+                # else gets an independent copy: at world >= 2 results never
+                # alias the inputs (the ring/exchange paths allocate), and
+                # the degraded single-replica fleet honors the same contract
+                # towards every caller that did not donate. _copy_payload is
+                # tuple-safe (quantized wire), and tuples are always copied.
+                return [
+                    h if donate and isinstance(h, np.ndarray)
+                    else _copy_payload(h)
+                    for h in host
+                ]
             # Large ndarray payloads ride the ring (per-rank traffic ~2x
             # payload, world-size-independent); small or non-ndarray ones
             # (quantized tuples) use the one-round full-mesh exchange.
@@ -2071,7 +2090,8 @@ class ProcessGroupBaby(ProcessGroup):
         return FutureWork(fut)
 
     # -- collectives ------------------------------------------------------
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
+        # the arrays cross a pipe to the child: nothing to hand back
         return self._submit("allreduce", [_to_host(a) for a in arrays], op)
 
     def allgather(self, arrays):
@@ -2230,9 +2250,9 @@ class ErrorSwallowingProcessGroupWrapper(ProcessGroup):
             self.report_error(e)
             return DummyWork(default_fn())
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         return self._guard(
-            lambda: self._pg.allreduce(arrays, op),
+            lambda: self._pg.allreduce(arrays, op, donate=donate),
             lambda: [_to_host(a) for a in arrays],
         )
 
@@ -2441,8 +2461,8 @@ class FakeProcessGroupWrapper(ProcessGroup):
             return FutureWork(fut)
         return work
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
-        return self._maybe_fail(self._pg.allreduce(arrays, op))
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
+        return self._maybe_fail(self._pg.allreduce(arrays, op, donate=donate))
 
     def allgather(self, arrays):
         return self._maybe_fail(self._pg.allgather(arrays))
@@ -2472,7 +2492,7 @@ class ManagedProcessGroup(ProcessGroup):
         super().__init__()
         self._manager = manager
 
-    def allreduce(self, arrays, op=ReduceOp.SUM):
+    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
         return self._manager.allreduce(list(arrays), reduce_op=op)
 
     def size(self) -> int:

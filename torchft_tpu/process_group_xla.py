@@ -923,7 +923,14 @@ class ProcessGroupXLA(ProcessGroup):
         return world.reduce_fn(op)(per_leaf)
 
     # ----------------------------------------------------------- collectives
-    def allreduce(self, arrays: Sequence[Any], op: ReduceOp = ReduceOp.SUM) -> Work:
+    def allreduce(
+        self,
+        arrays: Sequence[Any],
+        op: ReduceOp = ReduceOp.SUM,
+        donate: bool = False,
+    ) -> Work:
+        # ``donate`` is ignored: the collective's result is a new device
+        # array whatever the world's size
         world = self._require_world()
         rank = self._rank
         leaves = [world.place(rank, a) for a in arrays]

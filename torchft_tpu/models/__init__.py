@@ -21,9 +21,18 @@ def model_fns(cfg: LlamaConfig) -> Tuple[Callable, Callable, Callable]:
     remat=)`` -> ``(loss, stats)`` for ``value_and_grad(has_aux=True)``,
     where ``stats`` maps the name of a trace instant to the device scalars a
     training loop fetches beside the loss ({} for a dense model)."""
+    from torchft_tpu.models.jamba import (
+        JambaConfig, jamba_init, jamba_loss_and_stats, jamba_param_specs)
     from torchft_tpu.models.moe import (
         MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs)
     from torchft_tpu.parallel.mesh import llama_param_specs
+
+    if isinstance(cfg, JambaConfig):
+        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+            value, stats = jamba_loss_and_stats(*args, **kw)
+            return value, {"ssm_stats": stats}
+
+        return jamba_init, loss, jamba_param_specs
 
     if isinstance(cfg, MoEConfig):
         def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
@@ -40,14 +49,18 @@ def model_fns(cfg: LlamaConfig) -> Tuple[Callable, Callable, Callable]:
     return llama_init, loss, llama_param_specs
 
 
-def _register_moe_presets() -> None:
-    """``CONFIGS`` is the registry ``--config`` reads: the MoE presets stand
-    in it beside the dense ones (an MoEConfig is a LlamaConfig) under their
-    own names; ``debug`` is taken, so the MoE one is ``moe_debug``."""
+def _register_presets() -> None:
+    """``CONFIGS`` is the registry ``--config`` reads: the MoE and the
+    hybrid presets stand in it beside the dense ones (an MoEConfig and a
+    JambaConfig are LlamaConfigs) under their own names; ``debug`` is taken,
+    so the MoE one is ``moe_debug``."""
+    from torchft_tpu.models.jamba import JAMBA_CONFIGS
     from torchft_tpu.models.moe import MOE_CONFIGS
 
     for name, cfg in MOE_CONFIGS.items():
         CONFIGS.setdefault("moe_debug" if name == "debug" else name, cfg)
+    for name, cfg in JAMBA_CONFIGS.items():
+        CONFIGS.setdefault(name, cfg)
 
 
-_register_moe_presets()
+_register_presets()

@@ -7,7 +7,10 @@ here the model is in-tree because the rebuild is a standalone framework).
 Design for the TPU:
 - params and activations in bfloat16, RMSNorm/softmax accumulation in f32
   (MXU-friendly matmuls, VPU-safe reductions)
-- GQA attention with RoPE; SwiGLU MLP; pre-norm; weight-tied off by default
+- GQA attention with RoPE; SwiGLU MLP; pre-norm; the head is never tied here
+  (``lm_head`` is a leaf of its own and ``num_params`` counts it): the tied
+  case belongs to the hybrid's config object (models/jamba.py), which reads
+  ``embed`` twice through :func:`head_loss`
 - pure functions of a params pytree: `jit`/`pjit` them under any Mesh; the
   sharding rules for tp/fsdp axes live in torchft_tpu/parallel/mesh.py
 - no data-dependent Python control flow — everything traces once
@@ -32,6 +35,7 @@ __all__ = [
     "llama_hidden",
     "llama_forward",
     "llama_loss",
+    "head_loss",
     "CONFIGS",
 ]
 
@@ -275,25 +279,31 @@ def llama_loss(
     lm_head matmul per chunk in backward for vocab-sized activation memory —
     the standard trade for big-vocab models at the HBM edge.
     """
+    h = llama_hidden(
+        params, tokens, cfg, attention_fn=attention_fn, remat=remat
+    )
+    return head_loss(h, params["lm_head"], targets, loss_chunk)
+
+
+def head_loss(
+    h: jax.Array, lm_head: jax.Array, targets: jax.Array, loss_chunk: int = 0
+) -> jax.Array:
+    """The cross-entropy of :func:`llama_loss` from final-norm hidden states
+    h [B, S, dim] and a head matrix [dim, vocab] (a model with a tied head
+    passes ``embed.T``: one leaf read twice, its gradient the sum of both)."""
     if loss_chunk <= 0:
-        logits = llama_forward(
-            params, tokens, cfg, attention_fn=attention_fn, remat=remat
-        )
+        logits = (h @ lm_head).astype(jnp.float32)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         return jnp.mean(lse - tgt)
 
-    B, S = tokens.shape
+    B, S = targets.shape
     if S % loss_chunk != 0:
         raise ValueError(f"loss_chunk {loss_chunk} must divide seq len {S}")
-    h = llama_hidden(
-        params, tokens, cfg, attention_fn=attention_fn, remat=remat
-    )
     n = S // loss_chunk
     # [n, B, chunk, ...]: scan over sequence chunks
     h_c = jnp.swapaxes(h.reshape(B, n, loss_chunk, -1), 0, 1)
     t_c = jnp.swapaxes(targets.reshape(B, n, loss_chunk), 0, 1)
-    lm_head = params["lm_head"]
 
     def chunk_sum(hc, tc):
         logits = (hc @ lm_head).astype(jnp.float32)

@@ -63,6 +63,7 @@ def train(args) -> None:
 
     from torchft_tpu.manager import Manager
     from torchft_tpu.models import CONFIGS, model_fns
+    from torchft_tpu.models.staged import staged_value_and_grad
     from torchft_tpu.ops import attention as attention_ops
     from torchft_tpu.parallel.mesh import (
         batch_sharding,
@@ -93,7 +94,7 @@ def train(args) -> None:
     lighthouse = os.environ.get("TORCHFT_LIGHTHOUSE", args.lighthouse)
     cfg = CONFIGS[args.config]
     # what differs between models is the configuration object's kind
-    model_init, model_loss, param_specs = model_fns(cfg)
+    model = model_fns(cfg)
 
     # The devices this process was given: every chip of the host, or the
     # ones the launcher assigned (--chips-per-group). Named on every run so
@@ -112,7 +113,7 @@ def train(args) -> None:
     # In-group mesh: dp=1 (the replicated dim lives across groups, via the
     # manager), everything else in-graph over ICI.
     mesh = make_hsdp_mesh(devices, dp=1, fsdp=args.fsdp, sp=args.sp, tp=args.tp)
-    specs = param_specs(cfg)
+    specs = model.param_specs(cfg)
     tok_sharding = batch_sharding(mesh)
     if args.sp == 1:
         # no sequence axis to exchange over: each shard runs the default
@@ -126,31 +127,37 @@ def train(args) -> None:
         attention_fn = make_ring_attention_fn(mesh)
 
     params = shard_params(
-        model_init(jax.random.PRNGKey(replica_id), cfg), mesh, specs
+        model.init(jax.random.PRNGKey(replica_id), cfg), mesh, specs
     )
     tx = optax.adamw(args.lr, weight_decay=0.1)
     opt_state = tx.init(params)
 
     # FT split of the train step: grads in-graph (reduced over fsdp/sp by
-    # XLA), FT allreduce across groups on the host plane, then update.
-    @jax.jit
-    def grad_step(params, tokens, targets):
-        # remat="full": the 8B seq-8192 target sits at the HBM edge; the
-        # "dots" default is tuned for configs with headroom (see models/remat).
-        # -> ((loss, stats), grads); stats: the model's own counters ({} for
-        # a dense model), device scalars fetched with the loss
-        return jax.value_and_grad(model_loss, has_aux=True)(
-            params, tokens, targets, cfg, attention_fn=attention_fn, remat="full"
-        )
+    # XLA), FT allreduce across groups on the host plane, then update. The
+    # gradient is a CHAIN of programs (models/staged.py): grad_step hands
+    # each part of it to ``emit`` between two dispatches, so a part's
+    # allreduce (capture, transfers to the host) is queued on the device in
+    # front of the next segment's backward pass and runs under it.
+    # remat="full": the 8B seq-8192 target sits at the HBM edge; the "dots"
+    # default is tuned for configs with headroom (see models/remat).
+    # -> (loss, stats); stats: the model's own counters ({} for a dense
+    # model), device scalars fetched with the loss
+    grad_step, assemble = staged_value_and_grad(
+        model.stages and model.stages(cfg, attention_fn),
+        partial(model.loss, cfg=cfg, attention_fn=attention_fn, remat="full"),
+        shardings=jax.tree_util.tree_map(lambda x: x.sharding, params),
+    )
 
     # Donated: the old params/moments and the reduced grads die here, so
     # the update runs in place. Without it the step holds two copies of
     # the optimizer state beside two of the gradients — at bench_1b that
     # is ~17 GB on a 16 GB chip. Safe under live healing: a heal is staged
     # (host copy) on the quorum thread before allreduce returns.
+    # ``parts``: the gradient as grad_step handed it out (or each part's
+    # reduced copy), put together inside the program.
     @partial(jax.jit, donate_argnums=(0, 1, 2))
-    def update_step(params, opt_state, grads):
-        updates, opt_state = tx.update(grads, opt_state, params)
+    def update_step(params, opt_state, parts):
+        updates, opt_state = tx.update(assemble(parts), opt_state, params)
         return optax.apply_updates(params, updates), opt_state
 
     state = {"params": params, "opt_state": opt_state}
@@ -328,11 +335,15 @@ def train(args) -> None:
                     jnp.asarray(rng.randint(0, cfg.vocab_size, size=(B, S))), tok_sharding
                 )
                 if diloco is not None:
-                    # inner step: local grads + local adamw, no cross-group traffic
-                    (loss, stats), grads = grad_step(state["params"], batch, batch)
+                    # inner step: local grads + local adamw, no cross-group
+                    # traffic: the same chain, its parts kept as they come
+                    parts = []
+                    loss, stats = grad_step(
+                        state["params"], batch, batch, parts.append)
                     state["params"], state["opt_state"] = update_step(
-                        state["params"], state["opt_state"], grads
+                        state["params"], state["opt_state"], parts
                     )
+                    del parts  # donated
                     # on a heal, diloco.step re-reads state["params"] via get_params
                     # and returns the healed pytree
                     state["params"] = diloco.step(state["params"])
@@ -342,12 +353,20 @@ def train(args) -> None:
                     tokens_done += B * S
                 else:
                     manager.start_quorum()
+                    # one allreduce a part, issued as the part's program is
+                    # dispatched: the same parts in the same order on every
+                    # group, which the host exchange requires. The op
+                    # captures the part; nothing here keeps it (1x params
+                    # of HBM the later programs need)
+                    works = []
                     with tracer.span("grad_dispatch", cat="trainer"):
-                        (loss, stats), grads = grad_step(state["params"], batch, batch)
-                    work = manager.allreduce(grads)
+                        loss, stats = grad_step(
+                            state["params"], batch, batch,
+                            lambda part: works.append(manager.allreduce(part)))
                     with tracer.span("allreduce_wait", cat="trainer"):
-                        reduced = work.get_future().wait(timeout=args.timeout)
-                    del grads  # 1x params of HBM the next grad_step needs
+                        reduced = [w.get_future().wait(timeout=args.timeout)
+                                   for w in works]
+                    del works
                     run["reduced_on_device"] &= on_device(reduced)
                     if not manager.should_commit():
                         run["discarded"] += 1
@@ -423,8 +442,9 @@ def train(args) -> None:
             # the last step's phase splits (Manager.timings())
             "timings": {k: round(v, 3) for k, v in manager.timings().items()
                         if k.endswith("_s") or k.startswith("heal_")
-                        or k in ("allreduce_buckets", "overlap_efficiency",
-                                 "stage_pool_hit_share",
+                        or k in ("allreduce_buckets", "allreduce_ops",
+                                 "overlap_efficiency", "stage_pool_hit_share",
+                                 "d2h_under_backward_share",
                                  "wire_passthrough_share", "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
             "peak_hbm_bytes": max((p for p in peaks if p), default=None),

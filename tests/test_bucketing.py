@@ -378,6 +378,7 @@ class TestPipelineWithoutAManager:
         )
         try:
             for step in range(2):
+                pipeline.begin_step()
                 op = pipeline.allreduce_buckets(
                     leaves, plan, ReduceOp.SUM, participating=True,
                     divisor=2, place=bucketing.leaf_placer(), timeout=30.0,
@@ -442,3 +443,31 @@ class TestPipelineWithoutAManager:
         finally:
             wedge.set()
             pipeline.shutdown(wait=False)
+
+
+def test_glibc_is_told_to_keep_the_heap_through_a_trim(monkeypatch):
+    """mmap threshold 32 MiB, trim threshold and top pad both the largest a
+    C int holds: a step that frees more than 2 GiB of pieces (Mistral-7B at
+    four layers) still keeps 2 GiB of them mapped (PERF.md section 6,
+    PR 34); no libc.so.6, or one without mallopt: False and nothing set."""
+    import ctypes
+
+    calls = []
+
+    class Libc:
+        class mallopt:  # noqa: N801 — ctypes' function object, by attribute
+            argtypes = restype = None
+
+            def __new__(cls, param, value):
+                calls.append((param, value))
+                return 1
+
+    monkeypatch.setattr(ctypes, "CDLL", lambda name: Libc)
+    assert bucketing._keep_freed_blocks_mapped.__wrapped__() is True
+    assert calls == [(-3, 32 << 20), (-1, 2**31 - 1), (-2, 2**31 - 1)]
+
+    def missing(name):
+        raise OSError(name)
+
+    monkeypatch.setattr(ctypes, "CDLL", missing)
+    assert bucketing._keep_freed_blocks_mapped.__wrapped__() is False

@@ -588,7 +588,8 @@ class TestPipelineSpanTree:
                 # the allreduce span is the stages' parent, and closes last
                 ar = by_id[st["parent"]]
                 assert (ar["cat"], ar["name"]) == ("allreduce", "allreduce")
-                assert ar["args"] == {"buckets": 3, "bytes": 6 * 30_000 * 4}
+                assert ar["args"] == {"buckets": 3, "bytes": 6 * 30_000 * 4,
+                                      "segment": 0}  # one op a step
         assert min(remainder.values()) <= self.REMAINDER_US, remainder
         for s in spans:
             if s["name"] == "wire_run":

@@ -329,10 +329,10 @@ class TestOlmoeModel:
                 cfg.num_experts, cfg.top_k, cfg.vocab_size) == (2048, 16, 16, 16, 1024, 64, 8, 50304)
         assert cfg.capacity_factor is None and not cfg.norm_topk_prob and cfg.qk_norm
         assert 6.9e9 < cfg.num_params() < 6.93e9
-        init, loss, specs = model_fns(cfg)
-        assert init is moe_init and specs is moe_param_specs
-        init, dense_loss, specs = model_fns(CONFIGS["debug"])
-        assert init is llama_init and specs is llama_param_specs
+        init, loss, specs, stages = model_fns(cfg)
+        assert init is moe_init and specs is moe_param_specs and stages
+        init, dense_loss, specs, stages = model_fns(CONFIGS["debug"])
+        assert init is llama_init and specs is llama_param_specs and stages
         tiny = CONFIGS["debug"]
         toks = jnp.zeros((1, 8), jnp.int32)
         value, stats = dense_loss(llama_init(jax.random.PRNGKey(0), tiny), toks, toks, tiny)

@@ -74,6 +74,7 @@ from torchft_tpu.work import Future, join_futures
 
 __all__ = [
     "DEFAULT_BUCKET_CAP_BYTES",
+    "SEGMENT_FLOOR_BYTES",
     "BucketPlan",
     "BufferPool",
     "build_plan",
@@ -99,6 +100,14 @@ __all__ = [
 # 1 GiB default bucket cap (reference: local_sgd.py:176)
 DEFAULT_BUCKET_CAP_BYTES = 1 << 30
 
+# The least gradient bytes a trainer hands ONE allreduce of a step's several
+# (models/staged.py: a segment of whole layers an op). Each op pays a fixed
+# toll beside its fetch: the capture's dispatch 3-10 ms, the landing's
+# enqueue 9-18 ms a bucket (PERF.md section 5). At the 6-9 GB/s a fetch runs
+# at, 128 MiB is 15-22 ms of fetch: under it the tolls are the op. A constant
+# and no option: a segment is whole layers, so most are several times this.
+SEGMENT_FLOOR_BYTES = 1 << 27
+
 # metas entry: (leaf_index, offset_elems, size_elems, shape)
 Meta = Tuple[int, int, int, Tuple[int, ...]]
 
@@ -117,6 +126,25 @@ def _leaf_size(leaf: Any) -> int:
     if size is not None:
         return int(size)
     return int(np.asarray(leaf).size)
+
+
+def leaf_stand_in(leaf: Any) -> Any:
+    """What an op in flight keeps of a leaf it has CAPTURED: for a device
+    leaf its shape, dtype and sharding (all that a landing, or the zeros of
+    the error path, reads of the original), so that the leaf's device memory
+    is the caller's alone to drop; a numpy leaf as it is."""
+    import jax
+
+    if isinstance(leaf, jax.Array):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=leaf.sharding)
+    return leaf
+
+
+def lives_on_device(leaf: Any) -> bool:
+    """A device leaf, or the :func:`leaf_stand_in` of one."""
+    import jax
+
+    return isinstance(leaf, (jax.Array, jax.ShapeDtypeStruct))
 
 
 def _leaf_shape(leaf: Any) -> Tuple[int, ...]:
@@ -375,8 +403,15 @@ def _keep_freed_blocks_mapped() -> bool:
     GB/s from a worker thread, same settings). Process-wide and one-way:
     freed heap memory stays with the process (its high-water mark, about
     the size of the gradients), blocks of 32 MiB and more are ``mmap``-ed
-    and returned as before. False where libc is not glibc; the fetch then
-    runs at the first rate."""
+    and returned as before. The trim threshold is a C int: 2 GiB at most.
+    A step that frees more than that (Mistral-7B at four layers: 2.28 GB of
+    pieces) leaves a free top over the threshold whenever nothing else
+    happens to sit in it, and glibc then gives back all of it but the top
+    pad, every step: 6 of 7 processes ran so, at half the speed, until the
+    pad was set to the same 2 GiB, which keeps that much through a trim
+    (PERF.md section 6, PR 34). The pad is address space asked for ahead,
+    not memory. False where libc is not glibc; the fetch then runs at the
+    first rate."""
     import ctypes
 
     try:
@@ -384,10 +419,12 @@ def _keep_freed_blocks_mapped() -> bool:
     except (OSError, AttributeError):
         return False
     mallopt.argtypes, mallopt.restype = [ctypes.c_int, ctypes.c_int], ctypes.c_int
-    m_trim_threshold, m_mmap_threshold = -1, -3  # <malloc.h>
+    m_trim_threshold, m_top_pad, m_mmap_threshold = -1, -2, -3  # <malloc.h>
+    most = 2**31 - 1  # the parameters are C ints
     return bool(
         mallopt(m_mmap_threshold, 32 << 20)
-        and mallopt(m_trim_threshold, 2**31 - 1)
+        and mallopt(m_trim_threshold, most)
+        and mallopt(m_top_pad, most)
     )
 
 
@@ -415,8 +452,19 @@ class Pieces:
         jax.block_until_ready(self.arrays)
         return self
 
+    def is_ready(self) -> bool:
+        """Whether the device has computed the pieces (it asks, never
+        waits): false while the program that makes the gradients, or the
+        one that cuts them, still runs. They are one program's outputs and
+        ready together, so the last one answers for all."""
+        last = self.arrays[-1] if self.arrays else None
+        return last is None or last.is_ready()
 
-def fetch_into(pieces: Pieces, out: np.ndarray) -> int:
+
+def fetch_into(
+    pieces: Pieces, out: np.ndarray,
+    after_piece: Optional[Callable[[], None]] = None,
+) -> int:
     """Copy a captured device bucket into ``out`` (1-D, ``pieces.size``
     elements: afterwards bitwise ``np.asarray`` of the packed flat) and
     return how many pieces it came in. Every piece's transfer has been in
@@ -425,11 +473,15 @@ def fetch_into(pieces: Pieces, out: np.ndarray) -> int:
     allocated per transfer is piece-sized and reused from step to step
     (:func:`_keep_freed_blocks_mapped`); the only bucket-sized host memory
     is ``out``, which the caller takes from a :class:`BufferPool` so that
-    its pages are mapped from the second step on."""
+    its pages are mapped from the second step on. ``after_piece()`` is
+    called as each piece has landed (the pipeline looks up from its copying
+    there: is the device still computing gradients?)."""
     arrays = pieces.arrays
     for k, (a, b) in enumerate(pieces.bounds):
         np.copyto(out[a:b], np.asarray(arrays[k]))
         arrays[k] = None
+        if after_piece is not None:
+            after_piece()
     return len(arrays)
 
 
@@ -509,7 +561,8 @@ def capture(
 
 
 def stage(
-    captured: Optional[List[Any]], plan: BucketPlan, i: int, pool: BufferPool
+    captured: Optional[List[Any]], plan: BucketPlan, i: int, pool: BufferPool,
+    after_piece: Optional[Callable[[], None]] = None,
 ) -> Tuple[np.ndarray, Optional[np.ndarray], Dict[str, int]]:
     """Bucket ``i`` of a :func:`capture` as host memory for the wire, on the
     staging thread: ``(host_flat, pooled_buf, info)``. ``pooled_buf`` is the
@@ -528,7 +581,8 @@ def stage(
         # pool buffer (mapped pages from the second step on), device memory
         # dropped as each lands
         host_flat, hit = pool.acquire_hit(cap.size, cap.dtype)
-        info = {"pieces": fetch_into(cap, host_flat), "pooled": int(hit)}
+        info = {"pieces": fetch_into(cap, host_flat, after_piece),
+                "pooled": int(hit)}
     else:
         # a host group: packed into its pool buffer at the capture
         host_flat, info = cap, {}
@@ -655,7 +709,7 @@ def leaf_placer() -> Callable[[Any, Any], Any]:
     def place(orig: Any, host: Any) -> Any:
         import jax.numpy as jnp
 
-        if isinstance(orig, jax.Array):
+        if lives_on_device(orig):
             if _is_live(orig.sharding):
                 return jax.device_put(host, orig.sharding)
             return jnp.asarray(np.asarray(host))
@@ -707,7 +761,7 @@ def land_reduced(
     with span("h2d", **sized):
         pairs = [(i, place(leaves[i], v)) for i, v in pairs]
     if divisor and not on_device_plane:
-        on_device = sum(isinstance(leaves[i], jax.Array) for i in idxs)
+        on_device = sum(lives_on_device(leaves[i]) for i in idxs)
         where = (
             "device" if on_device == len(idxs)
             else "mixed" if on_device else "host"
@@ -851,6 +905,56 @@ def _pipeline_overlap_stats(marks: List[Dict[str, Any]]) -> Dict[str, float]:
     }
 
 
+class _StepTally:
+    """What the allreduces of ONE step add up to (a step runs from one
+    :meth:`BucketPipeline.begin_step` to the next: ``Manager.start_quorum``
+    calls it). A trainer that hands the gradients over in several ops a step
+    reads its ``timings()`` of the step, not of the step's last op."""
+
+    def __init__(self) -> None:
+        self.ops = 0  # allreduces begun (timings()["allreduce_ops"])
+        # of the ops resolved so far (record_timings, under the lock): the
+        # stage sums, the wire seconds hidden behind an op's other buckets,
+        # device buckets and those that came back as their staging buffer
+        self.lock = threading.Lock()
+        self.stage_sums: Dict[str, float] = {}
+        self.hidden_s = 0.0
+        self.from_device = self.passed_through = 0
+        # the newest device capture of the step (the caller's thread sets
+        # it): while ITS pieces are not ready, gradients are being computed
+        self.newest: Optional[Pieces] = None
+        # the staging thread's alone: device buckets it fetched, those into
+        # a recycled buffer, each fetch's (start, end), and the last moment
+        # it saw the device still computing this step's gradients
+        self.acquired = self.hits = 0
+        self.d2h: List[Tuple[float, float]] = []
+        self.computing_until: Optional[float] = None
+
+    def still_computing(self) -> None:
+        """Asked by the staging thread after each fetched piece: gradients
+        that a later op of the step has captured are not there yet."""
+        newest = self.newest
+        if newest is not None and not newest.is_ready():
+            self.computing_until = time.perf_counter()
+
+    def staging_shares(self) -> Dict[str, float]:
+        """``stage_pool_hit_share``, and ``d2h_under_backward_share``: of
+        the step's d2h seconds so far, the part that ran while the device
+        was seen computing the step's gradients: before the last grad_wait
+        that had to wait returned, or a later op's capture was found not
+        ready after a piece. A lower bound, to a piece (a backward pass that
+        ends between two looks is seen at the earlier one); 0.0 where one op
+        carries the whole tree: every fetch follows its one wait."""
+        total = sum(t1 - t0 for t0, t1 in self.d2h)
+        until = self.computing_until
+        under = 0.0 if until is None else sum(
+            max(0.0, min(t1, until) - t0) for t0, t1 in self.d2h)
+        return {
+            "stage_pool_hit_share": self.hits / self.acquired,
+            "d2h_under_backward_share": under / total if total > 0 else 0.0,
+        }
+
+
 class _BucketOp:
     """One bucketed allreduce in flight: what its stages, each on its own
     thread, share. ``final`` resolves to the landed leaves in leaf order
@@ -866,10 +970,15 @@ class _BucketOp:
         place: Callable[[Any, Any], Any],
         parent: Optional[int],
         new_id: Callable[[], int],
+        tally: _StepTally,
+        segment: int,
     ) -> None:
         n = len(plan)
         self.leaves, self.plan = leaves, plan
         self.divisor, self.place, self.parent = divisor, place, parent
+        # the step this op belongs to, and which of its ops it is: every
+        # allreduce/* span of the op says ``segment=``
+        self.tally, self.segment = tally, segment
         self.bucket_bytes = [
             size * np.dtype(dtype).itemsize
             for size, dtype in zip(plan.sizes, plan.dtypes)
@@ -932,8 +1041,10 @@ class BucketPipeline:
     purely by arrival order, and cross-replica issue order is the contract.
 
     ``on_timings(stats)`` receives what ``Manager.timings()`` shows of the
-    pipeline (``stage_pool_hit_share`` from the staging thread, the stage
-    sums and ``wire_passthrough_share`` from :meth:`record_timings`)."""
+    pipeline, each value over the ops of the step so far
+    (:meth:`begin_step`): ``allreduce_ops``; from the staging thread
+    ``stage_pool_hit_share`` and ``d2h_under_backward_share``; the stage
+    sums and ``wire_passthrough_share`` from :meth:`record_timings`."""
 
     def __init__(
         self,
@@ -980,10 +1091,24 @@ class BucketPipeline:
         # pool once those are ready (_recycle parks, _sweep_parked releases)
         self._parked: List[Tuple[np.ndarray, List[Any]]] = []
         self._parked_lock = threading.Lock()
+        self._tally = _StepTally()
 
     @property
     def device_native(self) -> bool:
         return bool(getattr(self._pg, "device_native", False))
+
+    def begin_step(self) -> None:
+        """A new step: the next op is the step's segment 0, and what
+        ``on_timings`` hears from here on adds up over this step's ops."""
+        self._tally = _StepTally()
+
+    def next_segment(self) -> int:
+        """Count one more allreduce of this step (``allreduce_ops``) and
+        return which one it is, from 0."""
+        tally = self._tally
+        tally.ops += 1
+        self._on_timings({"allreduce_ops": float(tally.ops)})
+        return tally.ops - 1
 
     # ------------------------------------------------------------ schedule
     def submit(
@@ -1078,6 +1203,7 @@ class BucketPipeline:
         timeout: float,
         compress: str = "off",
         parent: Optional[int] = None,
+        segment: int = 0,
     ) -> _BucketOp:
         """One collective a bucket of ``plan``. Returns the op in flight:
         ``op.bucket_futs[i]`` resolves as bucket ``i`` lands, ``op.final`` to
@@ -1085,9 +1211,13 @@ class BucketPipeline:
         participants, None for a plain SUM; ``place``: :func:`leaf_placer`).
         A non-participant contributes zeros. ``compress`` ("off" | "fp8" |
         "int8") is the host plane's wire codec, with error feedback.
-        ``parent``: the span the stages hang under."""
+        ``parent``: the span the stages hang under; ``segment``: which of
+        its step's ops this is (:meth:`next_segment`). Once a participant's
+        leaves are captured the op holds stand-ins for them
+        (:func:`leaf_stand_in`): a caller that drops its tree frees it."""
         op = _BucketOp(
-            leaves, plan, divisor, place, parent, self._tracer.new_id
+            leaves, plan, divisor, place, parent, self._tracer.new_id,
+            self._tally, segment,
         )
         if self.device_native:
             self._issue_on_device(op, pg_op, participating)
@@ -1102,9 +1232,13 @@ class BucketPipeline:
         if participating:
             with self._tracer.span(
                 "capture", cat="allreduce", parent=parent,
-                bytes=sum(op.bucket_bytes),
+                bytes=sum(op.bucket_bytes), segment=segment,
             ):
                 captured = capture(leaves, plan, self._pool)
+            op.leaves = [leaf_stand_in(l) for l in leaves]
+            op.tally.newest = next(
+                (c for c in reversed(captured) if isinstance(c, Pieces)),
+                op.tally.newest)
         # Non-float buckets ride uncompressed — the decision depends only
         # on the shared plan + mode, so it is SPMD-consistent across
         # replicas. Non-participants compress their zero contribution too
@@ -1174,11 +1308,9 @@ class BucketPipeline:
         """Stages 1 and 2 of every bucket of a host-plane op, on the
         staging thread: host memory (:func:`stage`), the codec, the
         dispatch. It never waits for a wire."""
-        tracer, plan = self._tracer, op.plan
+        tracer, plan, tally = self._tracer, op.plan, op.tally
+        seg = {"segment": op.segment}
         try:
-            # pool buffers this op's device buckets took, and how many of
-            # them were recycled ones
-            acquired = hits = 0
             for i in range(len(plan)):
                 t0b = time.perf_counter()
                 pk_id = op.stage_ids[i]["pack"]
@@ -1191,11 +1323,14 @@ class BucketPipeline:
                         # the wait the fetch below would make anyway (the
                         # backward pass and the device split still running),
                         # under its own name: d2h is then the copy alone
+                        waits = not captured[i].is_ready()
                         with tracer.span(
                             "grad_wait", cat="allreduce", parent=pk_id,
-                            bucket=i,
+                            bucket=i, **seg,
                         ):
                             captured[i].block_until_ready()
+                        if waits:
+                            tally.computing_until = time.perf_counter()
                     # what earlier landings have finished with goes back
                     # to the pool before this bucket draws from it
                     self._sweep_parked()
@@ -1203,24 +1338,28 @@ class BucketPipeline:
                     # get to this op
                     with tracer.span(
                         "d2h", cat="allreduce", parent=pk_id, bucket=i,
+                        **seg,
                         **({"queued_us": int((t0b - t_submit) * 1e6)}
                            if i == 0 else {}),
                     ) as sp:
+                        t0d = time.perf_counter()
                         host_flat, pooled_buf, info = stage(
-                            captured, plan, i, self._pool
+                            captured, plan, i, self._pool,
+                            tally.still_computing,
                         )
                         sp.args.update(info)
                     if "pooled" in info:
                         op.from_device[i] = True
-                        acquired += 1
-                        hits += info["pooled"]
+                        tally.acquired += 1
+                        tally.hits += info["pooled"]
+                        tally.d2h.append((t0d, time.perf_counter()))
                 payload: Any = host_flat
                 if modes[i] != "off":
                     # quantize inside the pack stage so pack_s absorbs the
                     # codec cost and overlap accounting stays honest
                     with tracer.span(
                         "codec", cat="allreduce", parent=pk_id, bucket=i,
-                        bytes=host_flat.nbytes,
+                        bytes=host_flat.nbytes, **seg,
                     ) as sp:
                         payload = self._compress_bucket_ef(
                             host_flat, modes[i], plan.dtypes[i], ef_store, i
@@ -1232,7 +1371,8 @@ class BucketPipeline:
                 # has nothing to reduce), and _land sees that it did
                 donate = pooled_buf is not None and modes[i] == "off"
                 with tracer.span(
-                    "dispatch", cat="allreduce", parent=pk_id, bucket=i
+                    "dispatch", cat="allreduce", parent=pk_id, bucket=i,
+                    **seg,
                 ):
                     w = self._pg.allreduce([payload], pg_op, donate=donate)
                 t1b = time.perf_counter()
@@ -1240,8 +1380,9 @@ class BucketPipeline:
                 w.get_future().add_done_callback(
                     functools.partial(self._wire_done, op, i, t1b, pooled_buf)
                 )
-            if acquired:
-                self._on_timings({"stage_pool_hit_share": hits / acquired})
+            if tally.acquired:
+                # the step's device buckets so far, this op's among them
+                self._on_timings(tally.staging_shares())
         except Exception as e:  # noqa: BLE001
             for bf in op.bucket_futs:
                 _settle(bf, exc=e)
@@ -1305,7 +1446,8 @@ class BucketPipeline:
                     args["passed_through"] = int(passed_through)
                 return self._tracer.span(
                     name, cat="allreduce",
-                    parent=op.stage_ids[i]["unpack"], bucket=i, **args,
+                    parent=op.stage_ids[i]["unpack"], bucket=i,
+                    segment=op.segment, **args,
                 )
 
             if is_compressed_wire(flat):
@@ -1365,21 +1507,35 @@ class BucketPipeline:
             self._pool.release(buf)
 
     def record_timings(self, op: _BucketOp) -> None:
-        """Fold one resolved op's per-bucket stage marks into
+        """Fold one resolved op's per-bucket stage marks into its step's
+        (the ops of a step are described together) and those into
         ``on_timings``: summed ``allreduce_pack_s`` /
         ``allreduce_wire_s`` / ``allreduce_unpack_s``, the bucket count, and
         ``overlap_efficiency`` — the fraction of total wire time that ran
-        concurrently with OTHER buckets' pipeline stages (a lower bound on
+        concurrently with OTHER buckets' pipeline stages of the same op (a lower bound on
         the real win: overlap with the caller's own compute, e.g. the next
         microbatch's grad_fn, is invisible from here); and record the stage
         spans, which are known only now, from the same marks."""
-        stats = _pipeline_overlap_stats(op.marks)
-        if any(op.from_device):
-            # of the device buckets, those whose collective resolved to the
-            # staging buffer it was given: nothing was copied on the wire
-            stats["wire_passthrough_share"] = sum(
-                p for p, d in zip(op.passed_through, op.from_device) if d
-            ) / sum(op.from_device)
+        tally = op.tally
+        mine = _pipeline_overlap_stats(op.marks)
+        hidden_s = mine.pop("overlap_efficiency") * mine["allreduce_wire_s"]
+        with tally.lock:
+            for key, value in mine.items():
+                tally.stage_sums[key] = tally.stage_sums.get(key, 0.0) + value
+            tally.hidden_s += hidden_s
+            tally.from_device += sum(op.from_device)
+            tally.passed_through += sum(
+                p for p, d in zip(op.passed_through, op.from_device) if d)
+            stats = dict(tally.stage_sums)
+            wire_s = stats["allreduce_wire_s"]
+            stats["overlap_efficiency"] = (
+                tally.hidden_s / wire_s if wire_s > 0 else 0.0)
+            if tally.from_device:
+                # of the device buckets, those whose collective resolved to
+                # the staging buffer it was given: nothing was copied on
+                # the wire
+                stats["wire_passthrough_share"] = (
+                    tally.passed_through / tally.from_device)
         self._on_timings(stats)
         for i, mark in enumerate(op.marks):
             for name in ("pack", "wire", "unpack"):
@@ -1389,6 +1545,7 @@ class BucketPipeline:
                 self._tracer.record_rel(
                     name, cat="allreduce", t0_pc=t0_pc, t1_pc=t1_pc,
                     id=op.stage_ids[i][name], parent=op.parent, bucket=i,
+                    segment=op.segment,
                 )
             run = op.wire_runs[i]
             if run is not None:
@@ -1401,7 +1558,7 @@ class BucketPipeline:
                 self._tracer.record_rel(
                     "wire_run", "allreduce", t_run0, t_run1,
                     parent=op.stage_ids[i]["wire"], bucket=i,
-                    bytes=op.bucket_bytes[i],
+                    segment=op.segment, bytes=op.bucket_bytes[i],
                     world=self._pg.size(),
                     queued_us=int((t_run0 - t_enq) * 1e6),
                 )
@@ -1467,6 +1624,7 @@ class BucketPipeline:
         place: Callable[[Any, Any], Any],
         timeout: float,
         parent: Optional[int] = None,
+        segment: int = 0,
     ) -> Future:
         """The no-plan path: ONE collective carrying every leaf (or, with
         ``quantize``, ``collectives.allreduce_quantized``: never
@@ -1483,7 +1641,7 @@ class BucketPipeline:
             self.submit(
                 self._leaf_stager(
                     leaves, pg_op, quantize, participating, timeout, parent,
-                    fut,
+                    segment, fut,
                 ),
                 fut,
                 timeout,
@@ -1526,6 +1684,7 @@ class BucketPipeline:
         participating: bool,
         timeout: float,
         parent: Optional[int],
+        segment: int,
         staged_fut: Future,
     ) -> Callable[[], None]:
         """Capture ``leaves`` now, on the caller's thread (the staging
@@ -1575,7 +1734,7 @@ class BucketPipeline:
                 host_leaves = captured
             else:
                 with tracer.span(
-                    "d2h", cat="allreduce", parent=parent
+                    "d2h", cat="allreduce", parent=parent, segment=segment
                 ) as sp:
                     host_leaves = [np.asarray(l) for l in captured]
                     sp.args["bytes"] = sum(h.nbytes for h in host_leaves)
@@ -1585,7 +1744,9 @@ class BucketPipeline:
                 w = allreduce_quantized(host_leaves, pg_op, self._pg)
                 _settle(staged_fut, w.get_future().wait(timeout))
                 return
-            with tracer.span("dispatch", cat="allreduce", parent=parent):
+            with tracer.span(
+                "dispatch", cat="allreduce", parent=parent, segment=segment
+            ):
                 w = self._pg.allreduce(host_leaves, pg_op)
             w.get_future().add_done_callback(
                 functools.partial(_settle_from, staged_fut)

@@ -764,6 +764,9 @@ class Manager:
         self._errored = None
         self._healing = False
         self._last_quorum_healed = False
+        # the allreduces from here to the next start_quorum are one step's:
+        # segment 0, 1, ..., and timings() adds them up
+        self._pipeline.begin_step()
 
         # a degrade staged since the last safe point lands here, AFTER the
         # per-step error reset and BEFORE the new prepare is submitted: the
@@ -799,7 +802,8 @@ class Manager:
                 self._healing = False
 
     def wait_quorum(
-        self, cat: str = "quorum", parent: Optional[int] = None
+        self, cat: str = "quorum", parent: Optional[int] = None,
+        **span_args: Any,
     ) -> None:
         # one span per wait that waits, under the category of the phase
         # that does (``allreduce/wait_quorum`` is the one at the head of
@@ -810,7 +814,9 @@ class Manager:
         if self._quorum_future.done():
             self._quorum_future.result()
             return
-        with self._tracer.span("wait_quorum", cat=cat, parent=parent):
+        with self._tracer.span(
+            "wait_quorum", cat=cat, parent=parent, **span_args
+        ):
             self._quorum_future.result()
 
     # ------------------------------------------------------------- policy
@@ -1745,7 +1751,10 @@ class Manager:
         # id known now so the spans below can name it as their parent
         ar_id = tracer.new_id()
         ar_parent = tracer.current()  # the caller's span (trainer/step)
-        ar_args: Dict[str, Any] = {}
+        # which of the step's allreduces this is: a trainer that hands the
+        # gradients over segment by segment makes several (models/staged.py)
+        segment = pipeline.next_segment()
+        ar_args: Dict[str, Any] = {"segment": segment}
 
         # Bucketed path: pack a multi-leaf tree into a handful of flat
         # same-dtype buffers (shared bucketing.py; plan cached by tree
@@ -1787,12 +1796,13 @@ class Manager:
         if self.errored():
             return DummyWork(zeros()), None
 
-        self.wait_quorum(cat="allreduce", parent=ar_id)
+        self.wait_quorum(cat="allreduce", parent=ar_id, segment=segment)
         # a reconfigure that landed during the forward pass commits its
         # backend swap here, before the collective touches the PG — this
         # is the "next safe point" for steps that skip should_commit
         with tracer.span(
-            "configure_commit_wait", cat="allreduce", parent=ar_id
+            "configure_commit_wait", cat="allreduce", parent=ar_id,
+            segment=segment,
         ):
             self._commit_pending_configure()
         if self.errored():
@@ -1838,8 +1848,11 @@ class Manager:
                     leaves, plan, pg_reduce_op,
                     participating=self.is_participating(),
                     divisor=divisor, place=place, timeout=self._timeout,
-                    compress=compress, parent=ar_id,
+                    compress=compress, parent=ar_id, segment=segment,
                 )
+                # the op holds stand-ins for what it captured: so do the
+                # zeros of the error path, and the caller alone the leaves
+                leaves = op.leaves
                 ar_args["buckets"] = len(plan)
                 ar_args["bytes"] = sum(op.bucket_bytes)
                 landed = op.final
@@ -1848,7 +1861,7 @@ class Manager:
                     leaves, pg_reduce_op, quantize=should_quantize,
                     participating=self.is_participating(),
                     divisor=divisor, place=place, timeout=self._timeout,
-                    parent=ar_id,
+                    parent=ar_id, segment=segment,
                 )
             # device plane: submission-time timer (the op starts at once).
             # host plane: the pipeline's stage-start deadline owns the
@@ -2104,14 +2117,23 @@ class Manager:
         reconfigure; commit is the only part that serializes with the
         trainer), and ``heal_send_s`` / ``heal_recv_s`` plus
         ``heal_chunks`` / ``heal_mb_per_s`` when the checkpoint transport
-        reports chunk-stream stats. Bucketed allreduces add
-        ``allreduce_pack_s`` / ``allreduce_wire_s`` / ``allreduce_unpack_s``
-        / ``allreduce_buckets`` / ``overlap_efficiency`` (see
+        reports chunk-stream stats. The allreduces of a step
+        (:meth:`start_quorum` to the next) are described TOGETHER:
+        ``allreduce_ops`` says how many there were (a trainer that hands
+        the gradients over segment by segment makes several), and bucketed
+        ones add ``allreduce_pack_s`` / ``allreduce_wire_s`` /
+        ``allreduce_unpack_s`` / ``allreduce_buckets`` /
+        ``overlap_efficiency`` (see
         ``bucketing.BucketPipeline.record_timings``) and, on the host plane,
-        ``stage_pool_hit_share``: of the device buckets the last bucketed
-        allreduce fetched, the share that went into a recycled buffer of
+        ``stage_pool_hit_share``: of the device buckets the step's
+        allreduces fetched, the share that went into a recycled buffer of
         the pool (pages mapped) rather than a new allocation; 1.0 from a
-        plan's second step on. Keys appear once the phase has run.
+        plan's second step on; and ``d2h_under_backward_share``: of the
+        seconds those fetches took, the part that ran before the staging
+        thread's last wait for gradients still being computed returned,
+        i.e. under the backward pass (0.0 where one op carries the whole
+        tree: its fetch follows its wait). Keys appear once the phase has
+        run.
 
         Also carries the CUMULATIVE resilience counters (present from
         construction, never reset): ``heal_attempts`` (initial heal tries

@@ -1,4 +1,4 @@
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 from torchft_tpu.models.llama import (
     CONFIGS,
@@ -9,22 +9,37 @@ from torchft_tpu.models.llama import (
 )
 
 __all__ = ["LlamaConfig", "llama_init", "llama_forward", "llama_loss",
-           "CONFIGS", "model_fns"]
+           "CONFIGS", "ModelFns", "model_fns"]
 
 
-def model_fns(cfg: LlamaConfig) -> Tuple[Callable, Callable, Callable]:
-    """A configuration object's ``(init, loss, param_specs)``, by its kind:
-    the one place a trainer learns which model it runs.
+class ModelFns(NamedTuple):
+    """What :func:`model_fns` hands out for one kind of configuration."""
+
+    init: Callable[..., Any]
+    loss: Callable[..., Any]
+    param_specs: Callable[..., Any]
+    # (cfg, attention_fn) -> models.staged.Stages, or None for a kind whose
+    # gradient is one program (staged_value_and_grad's degenerate chain)
+    stages: Optional[Callable[..., Any]]
+
+
+def model_fns(cfg: LlamaConfig) -> ModelFns:
+    """A configuration object's ``(init, loss, param_specs, stages)``, by its
+    kind: the one place a trainer learns which model it runs.
 
     ``init(key, cfg)`` -> parameter pytree; ``param_specs(cfg)`` -> its
     PartitionSpecs; ``loss(params, tokens, targets, cfg, attention_fn=,
     remat=)`` -> ``(loss, stats)`` for ``value_and_grad(has_aux=True)``,
     where ``stats`` maps the name of a trace instant to the device scalars a
-    training loop fetches beside the loss ({} for a dense model)."""
+    training loop fetches beside the loss ({} for a dense model);
+    ``stages(cfg, attention_fn)`` -> the same loss (at ``remat="full"``) as
+    the stage functions ``models.staged.staged_value_and_grad`` composes into
+    a chain of programs, with the same ``stats``."""
     from torchft_tpu.models.jamba import (
         JambaConfig, jamba_init, jamba_loss_and_stats, jamba_param_specs)
+    from torchft_tpu.models.llama import llama_stages
     from torchft_tpu.models.moe import (
-        MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs)
+        MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs, moe_stages)
     from torchft_tpu.parallel.mesh import llama_param_specs
 
     if isinstance(cfg, JambaConfig):
@@ -32,21 +47,33 @@ def model_fns(cfg: LlamaConfig) -> Tuple[Callable, Callable, Callable]:
             value, stats = jamba_loss_and_stats(*args, **kw)
             return value, {"ssm_stats": stats}
 
-        return jamba_init, loss, jamba_param_specs
+        return ModelFns(jamba_init, loss, jamba_param_specs, None)
 
     if isinstance(cfg, MoEConfig):
-        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
-            value, stats = moe_loss_and_stats(*args, **kw)
-            return value, {"moe_stats": {
+        def named(stats: Dict[str, Any]) -> Dict[str, Any]:
+            return {"moe_stats": {
                 "moe_load_max_over_mean": stats["load_max_over_mean"],
                 "moe_aux_loss": stats["aux_loss"]}}
 
-        return moe_init, loss, moe_param_specs
+        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+            value, stats = moe_loss_and_stats(*args, **kw)
+            return value, named(stats)
+
+        def stages(*args: Any, **kw: Any) -> Any:
+            s = moe_stages(*args, **kw)
+
+            def head(*a: Any) -> Tuple[Any, Dict[str, Any]]:
+                value, stats = s.head(*a)
+                return value, named(stats)
+
+            return s._replace(head=head)
+
+        return ModelFns(moe_init, loss, moe_param_specs, stages)
 
     def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
         return llama_loss(*args, **kw), {}
 
-    return llama_init, loss, llama_param_specs
+    return ModelFns(llama_init, loss, llama_param_specs, llama_stages)
 
 
 def _register_presets() -> None:

@@ -28,6 +28,7 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.models.staged import Stages
 
 __all__ = [
     "LlamaConfig",
@@ -35,6 +36,7 @@ __all__ = [
     "llama_hidden",
     "llama_forward",
     "llama_loss",
+    "llama_stages",
     "head_loss",
     "CONFIGS",
 ]
@@ -232,6 +234,19 @@ def llama_hidden(
     unroll = int(os.environ.get("TORCHFT_TPU_SCAN_UNROLL", "1"))
     h, _ = jax.lax.scan(body, h, params["layers"], unroll=unroll)
     return _rmsnorm(h, params["final_norm"], cfg.norm_eps)
+
+
+def llama_stages(cfg: LlamaConfig, attention_fn: Optional[Any] = None) -> Stages:
+    """:func:`llama_loss` in the three stages a staged gradient composes
+    (models/staged.py): the embedding, the ONE layer body, final norm with
+    head and loss."""
+
+    def head(head_params, h, emitted, targets):
+        h = _rmsnorm(h, head_params["final_norm"], cfg.norm_eps)
+        return head_loss(h, head_params["lm_head"], targets), {}
+
+    return Stages(lambda embed, tokens: embed[tokens],
+                  make_llama_layer_body(cfg, attention_fn), head)
 
 
 def llama_forward(

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
 
 from torchft_tpu.models.llama import LlamaConfig, _attention, _rmsnorm, _rope
+from torchft_tpu.models.staged import Stages
 
 __all__ = [
     "MoEConfig",
@@ -57,6 +58,7 @@ __all__ = [
     "moe_loss",
     "moe_loss_and_stats",
     "moe_param_specs",
+    "moe_stages",
     "moe_ffn",
     "load_balancing_loss",
 ]
@@ -367,6 +369,39 @@ def load_balancing_loss(counts: jax.Array, prob_sum: jax.Array, tokens: int) -> 
     return counts.shape[1] * jnp.sum(jax.lax.stop_gradient(f) * p)
 
 
+def _moe_layer(cfg, attention, positions, h, xs):
+    """The ONE scanned MoE layer body (:func:`moe_forward`'s scan and the
+    staged gradient's segment programs, :func:`moe_stages`): ``xs`` is
+    ``(layer_params, replay)``; -> ``(h, moe_ffn's stats)``."""
+    layer_params, replay = xs
+    B, S = h.shape[0], h.shape[1]
+    x = _rmsnorm(h, layer_params["attn_norm"], cfg.norm_eps)
+    q, k = x @ layer_params["wq"], x @ layer_params["wk"]
+    if cfg.qk_norm:  # over the whole projection, before the heads split
+        q = _rmsnorm(q, layer_params["q_norm"], cfg.norm_eps)
+        k = _rmsnorm(k, layer_params["k_norm"], cfg.norm_eps)
+    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    v = (x @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+    q = _rope(q, cfg.rope_theta, positions)
+    k = _rope(k, cfg.rope_theta, positions)
+    attn = jax.ad_checkpoint.checkpoint_name(
+        attention(q, k, v, cfg), ATTN_OUT_NAME
+    ).reshape(B, S, cfg.n_heads * cfg.head_dim)
+    h = h + attn @ layer_params["wo"]
+    x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
+    moe_out, stats = moe_ffn(
+        x,
+        layer_params["router"],
+        layer_params["w_gate"],
+        layer_params["w_up"],
+        layer_params["w_down"],
+        cfg,
+        routing=replay,
+    )
+    return h + moe_out, stats
+
+
 def moe_forward(
     params: Dict[str, Any],
     tokens: jax.Array,
@@ -390,35 +425,7 @@ def moe_forward(
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     h = params["embed"][tokens]
     _refuse_dropless_ep(cfg, _sharded_axes(params["layers"]["w_gate"]))
-
-    def layer(h, xs):
-        layer_params, replay = xs
-        x = _rmsnorm(h, layer_params["attn_norm"], cfg.norm_eps)
-        q, k = x @ layer_params["wq"], x @ layer_params["wk"]
-        if cfg.qk_norm:  # over the whole projection, before the heads split
-            q = _rmsnorm(q, layer_params["q_norm"], cfg.norm_eps)
-            k = _rmsnorm(k, layer_params["k_norm"], cfg.norm_eps)
-        q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        v = (x @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
-        attn = jax.ad_checkpoint.checkpoint_name(
-            attention(q, k, v, cfg), ATTN_OUT_NAME
-        ).reshape(B, S, cfg.n_heads * cfg.head_dim)
-        h = h + attn @ layer_params["wo"]
-        x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
-        moe_out, stats = moe_ffn(
-            x,
-            layer_params["router"],
-            layer_params["w_gate"],
-            layer_params["w_up"],
-            layer_params["w_down"],
-            cfg,
-            routing=replay,
-        )
-        return h + moe_out, stats
-
+    layer = partial(_moe_layer, cfg, attention, positions)
     body = remat_wrap(layer, remat)
     h, stats = jax.lax.scan(body, h, (params["layers"], routing))
     h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
@@ -466,14 +473,45 @@ def moe_loss_and_stats(
         params, tokens, cfg, attention_fn=attention_fn, remat=remat,
         routing=routing,
     )
+    loss, scalars = _loss_and_scalars(
+        logits, targets, aux, stats.pop("counts"), cfg)
+    return loss, {**stats, **scalars}
+
+
+def _loss_and_scalars(logits, targets, aux, counts, cfg):
+    """The loss of :func:`moe_loss_and_stats` from logits, the auxiliary loss
+    and the per-layer ``counts`` [L,E], and the two scalars it logs."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     loss = jnp.mean(lse - tgt) + cfg.aux_loss_weight * aux
-    counts = stats.pop("counts")
-    stats["aux_loss"] = aux
-    stats["load_max_over_mean"] = jnp.max(
-        jnp.max(counts, axis=1) / jnp.mean(counts, axis=1))
-    return loss, stats
+    return loss, {
+        "aux_loss": aux,
+        "load_max_over_mean": jnp.max(
+            jnp.max(counts, axis=1) / jnp.mean(counts, axis=1))}
+
+
+def moe_stages(cfg: MoEConfig, attention_fn: Optional[Any] = None) -> Stages:
+    """:func:`moe_loss_and_stats` in the three stages a staged gradient
+    composes (models/staged.py). A layer emits its ``counts`` and
+    ``prob_sum``: the head turns them into the auxiliary loss, whose
+    cotangent reaches each layer's router through ``prob_sum``."""
+    attention = attention_fn or _attention
+
+    def layer(h, layer_params):
+        positions = jnp.broadcast_to(jnp.arange(h.shape[1]), h.shape[:2])
+        h, stats = _moe_layer(cfg, attention, positions, h, (layer_params, None))
+        return h, {"counts": stats["counts"], "prob_sum": stats["prob_sum"]}
+
+    def head(head_params, h, emitted, targets):
+        h = _rmsnorm(h, head_params["final_norm"], cfg.norm_eps)
+        logits = (h @ head_params["lm_head"]).astype(jnp.float32)
+        aux = load_balancing_loss(
+            emitted["counts"], emitted["prob_sum"], targets.size)
+        loss, scalars = _loss_and_scalars(
+            logits, targets, aux, emitted["counts"], cfg)
+        return loss, scalars
+
+    return Stages(lambda embed, tokens: embed[tokens], layer, head)
 
 
 def moe_loss(*args: Any, **kw: Any) -> jax.Array:

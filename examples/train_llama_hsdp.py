@@ -62,7 +62,7 @@ def train(args) -> None:
     from jax.sharding import NamedSharding
 
     from torchft_tpu.manager import Manager
-    from torchft_tpu.models import CONFIGS, model_fns
+    from torchft_tpu.models import CONFIGS, model_fns, split_frozen
     from torchft_tpu.models.staged import staged_value_and_grad
     from torchft_tpu.ops import attention as attention_ops
     from torchft_tpu.parallel.mesh import (
@@ -130,7 +130,10 @@ def train(args) -> None:
         model.init(jax.random.PRNGKey(replica_id), cfg), mesh, specs
     )
     tx = optax.adamw(args.lr, weight_decay=0.1)
-    opt_state = tx.init(params)
+    # a kind's frozen leaves (model.frozen: state, not parameters) stay in
+    # ``params``, so the state dict, a heal and the checksum hold them, and
+    # out of everything the optimizer sees: no moments, no update, no decay
+    opt_state = tx.init(split_frozen(params, model.frozen)[0])
 
     # FT split of the train step: grads in-graph (reduced over fsdp/sp by
     # XLA), FT allreduce across groups on the host plane, then update. The
@@ -146,6 +149,7 @@ def train(args) -> None:
         model.stages and model.stages(cfg, attention_fn),
         partial(model.loss, cfg=cfg, attention_fn=attention_fn, remat="full"),
         shardings=jax.tree_util.tree_map(lambda x: x.sharding, params),
+        frozen=model.frozen,
     )
 
     # Donated: the old params/moments and the reduced grads die here, so
@@ -157,8 +161,9 @@ def train(args) -> None:
     # reduced copy), put together inside the program.
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def update_step(params, opt_state, parts):
-        updates, opt_state = tx.update(assemble(parts), opt_state, params)
-        return optax.apply_updates(params, updates), opt_state
+        trainable, held = split_frozen(params, model.frozen)
+        updates, opt_state = tx.update(assemble(parts), opt_state, trainable)
+        return {**optax.apply_updates(trainable, updates), **held}, opt_state
 
     state = {"params": params, "opt_state": opt_state}
     _STARTUP_US["state_init"] = time.time_ns() // 1000
@@ -447,6 +452,10 @@ def train(args) -> None:
                                  "d2h_under_backward_share",
                                  "wire_passthrough_share", "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
+            # the kind's frozen leaves alone (None: it has none): state that
+            # no step may move and a heal must carry
+            "frozen_checksum": int(checksum(split_frozen(
+                state["params"], model.frozen)[1])) if model.frozen else None,
             "peak_hbm_bytes": max((p for p in peaks if p), default=None),
             "cache_dir": cache_dir, "cache": cache_events,
         }), flush=True)

@@ -57,9 +57,10 @@ def test_presets_stand_in_the_trainers_registry_and_model_fns_knows_the_kind(tin
     assert (PUBLISHED.dim, PUBLISHED.n_heads, PUBLISHED.n_kv_heads, PUBLISHED.head_dim,
             PUBLISHED.ffn_hidden, PUBLISHED.vocab_size, PUBLISHED.d_inner,
             PUBLISHED.mamba_dt_rank) == (2560, 20, 1, 128, 8192, 65536, 5120, 160)
-    init, loss, specs, stages = model_fns(DEBUG)
+    init, loss, specs, stages, frozen = model_fns(DEBUG)
     assert init is jamba_init and specs is jamba_param_specs
     assert stages is None  # the hybrid's gradient is one program
+    assert frozen == ()  # every leaf a parameter
     params, tokens = tiny
     value, stats = loss(params, tokens, tokens, DEBUG)
     assert sorted(stats["ssm_stats"]) == ["ssm_dt_max", "ssm_y_absmax"]

@@ -329,13 +329,167 @@ class TestOlmoeModel:
                 cfg.num_experts, cfg.top_k, cfg.vocab_size) == (2048, 16, 16, 16, 1024, 64, 8, 50304)
         assert cfg.capacity_factor is None and not cfg.norm_topk_prob and cfg.qk_norm
         assert 6.9e9 < cfg.num_params() < 6.93e9
-        init, loss, specs, stages = model_fns(cfg)
+        init, loss, specs, stages, frozen = model_fns(cfg)
         assert init is moe_init and specs is moe_param_specs and stages
-        init, dense_loss, specs, stages = model_fns(CONFIGS["debug"])
+        assert frozen == ()  # every leaf a parameter
+        init, dense_loss, specs, stages, frozen = model_fns(CONFIGS["debug"])
         assert init is llama_init and specs is llama_param_specs and stages
+        assert frozen == ()
         tiny = CONFIGS["debug"]
         toks = jnp.zeros((1, 8), jnp.int32)
         value, stats = dense_loss(llama_init(jax.random.PRNGKey(0), tiny), toks, toks, tiny)
         assert stats == {} and np.isfinite(float(value))
         value, stats = loss(moe_init(jax.random.PRNGKey(0), OLMOE_TINY), toks, toks, OLMOE_TINY)
         assert sorted(stats["moe_stats"]) == ["moe_aux_loss", "moe_load_max_over_mean"]
+
+
+# ---- PR 35: one router, the configuration's scoring: sigmoid scores, a
+# selection bias that decides and does not gate, the published epsilon
+
+from torchft_tpu.models.moe import _choose, _dropless_ffn  # noqa: E402
+
+SIGMOID_TINY = dataclasses.replace(
+    MOE_CONFIGS["debug"], num_experts=8, top_k=4, capacity_factor=None,
+    norm_topk_prob=True, router_score="sigmoid", gate_eps=1e-6)
+BIASES = {
+    "none": None,
+    "small": 0.05 * jax.random.normal(jax.random.PRNGKey(5), (8,)),
+    "one_expert_always": jnp.zeros((8,)).at[3].set(10.0),
+}
+
+
+def _scores(args, cfg):
+    flat = args[0].reshape(-1, args[0].shape[-1])
+    logits = flat @ args[1]
+    return jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid" else \
+        jax.nn.softmax(logits, axis=-1)
+
+
+def _by_definition(args, cfg, idx, gates):
+    """Every expert on every token, weighted by ``gates`` at ``idx``."""
+    x, _, wg, wu, wd = args
+    flat = x.reshape(-1, x.shape[-1])
+    weight = jnp.sum(jax.nn.one_hot(idx, cfg.num_experts) * gates[..., None], axis=1)
+    every = jnp.einsum("teh,ehd->ted", jax.nn.silu(jnp.einsum("td,edh->teh", flat, wg))
+                       * jnp.einsum("td,edh->teh", flat, wu), wd)
+    return jnp.sum(every * weight[..., None], axis=1).reshape(x.shape)
+
+
+class TestSigmoidRouterUnderABias:
+    @pytest.mark.parametrize("name", sorted(BIASES))
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_decisions_follow_scores_plus_bias_and_gates_the_scores(self, name, norm):
+        cfg = dataclasses.replace(SIGMOID_TINY, norm_topk_prob=norm)
+        args, bias = _layer_weights(cfg), BIASES[name]
+        out, stats = moe_ffn(*args, cfg, bias=bias)
+        s = _scores(args, cfg)
+        decide = s if bias is None else s + bias
+        top_p, top_i = jax.lax.top_k(decide, cfg.top_k + 1)
+        np.testing.assert_array_equal(np.asarray(stats["routing"]), np.asarray(top_i[:, :4]))
+        # the margins are of what decided
+        np.testing.assert_array_equal(np.asarray(stats["p_kth"]), np.asarray(top_p[:, 3]))
+        np.testing.assert_array_equal(np.asarray(stats["p_next"]), np.asarray(top_p[:, 4]))
+        gates = jnp.take_along_axis(s, top_i[:, :4], axis=-1)  # WITHOUT the bias
+        if norm:
+            gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_by_definition(args, cfg, top_i[:, :4], gates)),
+            rtol=1e-5, atol=1e-5)
+        if name == "one_expert_always":
+            assert bool(jnp.all(jnp.any(stats["routing"] == 3, axis=-1)))
+            assert float(stats["bias_moved"]) > 0.3
+        assert ("bias_moved" in stats) == (bias is not None)
+
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_a_bias_that_swaps_the_fourth_and_fifth_expert_changes_exactly_their_terms(
+            self, norm):
+        cfg = dataclasses.replace(SIGMOID_TINY, norm_topk_prob=norm)
+        x, *w = _layer_weights(cfg)
+        args = (x[:1, :1], *w)  # one token: a bias moves no other decision
+        s = _scores(args, cfg)[0]
+        order = jnp.argsort(-s)
+        fourth, fifth = int(order[3]), int(order[4])
+        bias = jnp.zeros((8,)).at[fifth].set(float(s[fourth] - s[fifth]) + 1e-3)
+        plain, _ = moe_ffn(*args, cfg)
+        moved, stats = moe_ffn(*args, cfg, bias=bias)
+        assert sorted(np.asarray(stats["routing"][0])) == sorted(
+            [int(e) for e in order[:3]] + [fifth])
+        assert float(stats["bias_moved"]) == 1.0
+        one = lambda e, g: _by_definition(  # noqa: E731
+            args, cfg, jnp.asarray([[e]]), jnp.asarray([[g]], jnp.float32))
+        if not norm:  # the gates are the raw scores: nothing else changes
+            want = plain - one(fourth, float(s[fourth])) + one(fifth, float(s[fifth]))
+            np.testing.assert_allclose(np.asarray(moved), np.asarray(want),
+                                       rtol=1e-5, atol=1e-6)
+        else:  # renormalised over the new four, by the scores and not the bias
+            idx = jnp.asarray([[int(e) for e in order[:3]] + [fifth]])
+            g = s[idx[0]] / (jnp.sum(s[idx[0]]) + 1e-6)
+            np.testing.assert_allclose(
+                np.asarray(moved), np.asarray(_by_definition(args, cfg, idx, g[None])),
+                rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("name", ["none", "small"])
+    def test_replay_under_a_bias_uses_the_given_experts_and_hands_back_the_free_ones(
+            self, name):
+        args, bias = _layer_weights(SIGMOID_TINY), BIASES[name]
+        _, free = moe_ffn(*args, SIGMOID_TINY, bias=bias)
+        given = (free["routing"] + 1) % 8  # other experts than it would choose
+        out, stats = moe_ffn(*args, SIGMOID_TINY, routing=given, bias=bias)
+        np.testing.assert_array_equal(np.asarray(stats["routing"]),
+                                      np.asarray(free["routing"]))
+        s = _scores(args, SIGMOID_TINY)
+        gates = jnp.take_along_axis(s, given, axis=-1)
+        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_by_definition(args, SIGMOID_TINY, given, gates)),
+            rtol=1e-5, atol=1e-5)
+        # the router's gradient flows through the gates of the given experts
+        g = jax.grad(lambda r: jnp.sum(moe_ffn(
+            args[0], r, *args[2:], SIGMOID_TINY, routing=given, bias=bias)[0]))(args[1])
+        assert float(jnp.abs(g).max()) > 0
+
+    def test_the_bias_takes_no_gradient(self):
+        args, bias = _layer_weights(SIGMOID_TINY), BIASES["small"]
+        g = jax.grad(lambda b: jnp.sum(moe_ffn(*args, SIGMOID_TINY, bias=b)[0]))(bias)
+        assert float(jnp.abs(g).max()) == 0.0
+
+    @pytest.mark.parametrize("norm", [False, True])
+    def test_the_softmax_unbiased_path_is_bitwise_what_it_was(self, norm):
+        """PR 28's ``_choose`` written out (softmax, top-k of the
+        probabilities, the 1e-9) in front of the same dropless block: the
+        new arguments at their defaults change no bit, and the block
+        traces no sigmoid."""
+        cfg = dataclasses.replace(OLMOE_TINY, norm_topk_prob=norm)
+        assert (cfg.router_score, cfg.gate_eps) == ("softmax", 1e-9)
+        x, router, wg, wu, wd = args = _layer_weights(cfg)
+
+        @jax.jit
+        def was(x, router, wg, wu, wd):
+            flat = x.reshape(-1, x.shape[-1])
+            probs = jax.nn.softmax(jnp.matmul(
+                flat, router, precision=jax.lax.Precision.HIGHEST), axis=-1)
+            top_p, top_i = jax.lax.top_k(probs, cfg.top_k + 1)
+            idx = top_i[:, :cfg.top_k]
+            gates = jnp.take_along_axis(probs, idx, axis=-1)
+            if norm:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+            sizes = jnp.zeros((cfg.num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+            return _dropless_ffn(flat, gates, idx, sizes, wg, wu, wd).reshape(x.shape)
+
+        now = jax.jit(lambda *a: moe_ffn(*a, cfg)[0])
+        np.testing.assert_array_equal(np.asarray(now(*args)), np.asarray(was(*args)))
+        # the experts' SiLU is a logistic too: the sigmoid router is one more
+        count = lambda c: str(jax.make_jaxpr(  # noqa: E731
+            lambda *a: moe_ffn(*a, c)[0])(*args)).count("logistic")
+        assert count(dataclasses.replace(cfg, router_score="sigmoid")) == count(cfg) + 1
+
+    def test_chosen_gates_sum_to_one_and_an_unknown_scoring_is_refused(self):
+        s = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(2), (5, 8)))
+        g1, i1, _ = _choose(s, SIGMOID_TINY, None)
+        g2, i2, _ = _choose(s, dataclasses.replace(SIGMOID_TINY, norm_topk_prob=False), None)
+        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+        np.testing.assert_array_equal(
+            np.asarray(g2), np.take_along_axis(np.asarray(s), np.asarray(i2), -1))
+        np.testing.assert_allclose(np.asarray(jnp.sum(g1, -1)), 1.0, atol=1e-5)
+        with pytest.raises(ValueError, match="router_score"):
+            dataclasses.replace(SIGMOID_TINY, router_score="tanh")

@@ -215,16 +215,18 @@ def _feed_forward(h: jax.Array, w: Dict[str, jax.Array], cfg: JambaConfig) -> ja
     return h + (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
-def _causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array]) -> jax.Array:
-    """silu of the depthwise causal convolution, as shifted multiply-adds
-    summed in float32. x [B,T,di], w [k,di] (``w[k-1]`` weighs the current
-    position), b [di]."""
+def _causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
+                 activation: Optional[Any] = jax.nn.silu) -> jax.Array:
+    """``activation`` (Mamba's silu; None: none, models/lfm2.py's short
+    convolution) of the depthwise causal convolution, as shifted
+    multiply-adds summed in float32. x [B,T,di], w [k,di] (``w[k-1]`` weighs
+    the current position), b [di] or None."""
     k, T = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
     out = sum(padded[:, j:j + T] * w[j].astype(jnp.float32) for j in range(k))
     if b is not None:
         out = out + b.astype(jnp.float32)
-    return jax.nn.silu(out).astype(x.dtype)
+    return (activation(out) if activation else out).astype(x.dtype)
 
 
 def _mamba_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: JambaConfig

@@ -24,10 +24,19 @@ TPU-first design:
   their PartitionSpec (leading E dim); when the dispatched activations
   [E, C, d] are sharded over ``ep``, XLA inserts the all-to-alls — no manual
   collective code.
-- **Router in f32** (probabilities and cumsum position math need it),
-  payload matmuls in bf16.
+- **Router in f32** (scores and cumsum position math need it), payload
+  matmuls in bf16.
+- **One router, the configuration's scoring.** A token's scores over the
+  experts are ``MoEConfig.router_score`` of the router's logits: ``softmax``
+  (Mixtral, OLMoE) or ``sigmoid``, each expert by itself. The DECISION is the
+  top-k of ``scores + bias`` where the caller hands :func:`moe_ffn` a
+  selection bias ([E]; state of the model and no parameter: it takes no
+  gradient) and of the scores alone where not; the GATES are the scores at
+  the chosen experts, never the biased ones, renormalised over the chosen
+  where ``norm_topk_prob`` (over their sum + ``gate_eps``). The margins
+  handed back (``p_kth``, ``p_next``) are of what decided: ``scores + bias``.
 - **Routing replay.** ``routing=`` (per layer ``[T, top_k]`` expert indices)
-  makes the block use the given experts with this model's own probabilities
+  makes the block use the given experts with this model's own scores
   at them as gates, so the router's gradient flows as in a free run; the
   routing the model would have chosen freely comes back beside it.
 - Attention/norms/RoPE reuse the dense Llama blocks, including the Pallas
@@ -76,6 +85,13 @@ class MoEConfig(LlamaConfig):
     aux_loss_weight: float = 0.01
     norm_topk_prob: bool = True  # gates renormalised over the chosen experts
     qk_norm: bool = False  # RMSNorm over the whole q and k projections
+    router_score: str = "softmax"  # or "sigmoid": each expert scored by itself
+    gate_eps: float = 1e-9  # beside the chosen gates' sum where renormalised
+
+    def __post_init__(self) -> None:
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score={self.router_score!r}: models/moe.py "
+                             "scores by 'softmax' or 'sigmoid'")
 
     def capacity(self, tokens: int) -> int:
         """Slots per expert for a batch of ``tokens`` (static given shapes)."""
@@ -152,26 +168,30 @@ def moe_init(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
 
 
 def _choose(
-    probs: jax.Array, cfg: MoEConfig, routing: Optional[jax.Array]
+    scores: jax.Array, cfg: MoEConfig, routing: Optional[jax.Array],
+    bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """The experts each token uses and their gates. probs: [T, E] f32.
+    """The experts each token uses and their gates. scores: [T, E] f32, the
+    configuration's scoring of the router's logits; bias: [E] f32 or None.
 
     Returns (gates [T,k] f32, idx [T,k] int32, free). ``idx`` is ``routing``
-    where one is given (replay) and the top-k of ``probs`` otherwise; the
-    gates are this model's own probabilities at ``idx`` either way, so the
-    router's gradient flows the same. ``free``: what the model would have
-    chosen by itself (``routing`` [T,k]) and the probabilities of its k-th
-    and (k+1)-th choice (``p_kth``, ``p_next``: how near a tie the decision
-    was; equal where there is no (k+1)-th expert).
+    where one is given (replay) and the top-k of ``scores + bias`` otherwise;
+    the gates are this model's own ``scores`` (without the bias) at ``idx``
+    either way, so the router's gradient flows the same. ``free``: what the
+    model would have chosen by itself (``routing`` [T,k]) and what decided
+    it, ``scores + bias`` of its k-th and (k+1)-th choice (``p_kth``,
+    ``p_next``: how near a tie the decision was; equal where there is no
+    (k+1)-th expert).
     """
     k = cfg.top_k
-    top_p, top_i = jax.lax.top_k(probs, min(k + 1, cfg.num_experts))
+    decide = scores if bias is None else scores + bias
+    top_p, top_i = jax.lax.top_k(decide, min(k + 1, cfg.num_experts))
     free = {"routing": top_i[:, :k], "p_kth": top_p[:, k - 1],
             "p_next": top_p[:, -1]}
     idx = free["routing"] if routing is None else routing.astype(jnp.int32)
-    gates = jnp.take_along_axis(probs, idx, axis=-1)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + cfg.gate_eps)
     return gates, idx, free
 
 
@@ -324,15 +344,19 @@ def moe_ffn(
     w_down: jax.Array,
     cfg: MoEConfig,
     routing: Optional[jax.Array] = None,
+    bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Sparse SwiGLU FFN. x: [B, S, d] -> ([B, S, d], stats).
 
     ``routing`` ([T, k] expert indices, T = B*S): replay these choices
-    instead of the router's own (:func:`_choose`). ``stats``: ``counts``
+    instead of the router's own (:func:`_choose`). ``bias`` ([E] f32): added
+    to the scores for the decision alone. ``stats``: ``counts``
     [E] (pairs each expert was given under the routing in effect, dropped
-    ones included), ``prob_sum`` [E] (the router's probabilities summed
-    over tokens: the differentiable half of the auxiliary loss) and the
-    free routing with its margins (``routing``, ``p_kth``, ``p_next``).
+    ones included), ``prob_sum`` [E] (the router's scores summed
+    over tokens: the differentiable half of the auxiliary loss), the
+    free routing with its margins (``routing``, ``p_kth``, ``p_next``: of
+    ``scores + bias``, what decided) and, under a bias, ``bias_moved``: the
+    share of tokens whose k experts would be others without it.
     """
     B, S, d = x.shape
     T = B * S
@@ -343,8 +367,13 @@ def moe_ffn(
         # unless told otherwise, and a router's decisions sit on near-ties
         logits = jnp.matmul(flat.astype(jnp.float32), router,
                             precision=ROUTER_PRECISION)  # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx, free = _choose(probs, cfg, routing)
+        probs = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
+        gates, idx, free = _choose(probs, cfg, routing, bias)
+        if bias is not None:
+            unbiased = jax.lax.top_k(probs, cfg.top_k)[1]
+            kept = jnp.any(free["routing"][:, :, None] == unbiased[:, None, :], axis=-1)
+            free["bias_moved"] = 1.0 - jnp.mean(jnp.all(kept, axis=-1).astype(jnp.float32))
         sizes = jnp.zeros((cfg.num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
     payload = flat.astype(w_gate.dtype)  # the router saw x as it came
     if cfg.capacity_factor is None:

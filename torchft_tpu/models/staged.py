@@ -85,6 +85,7 @@ def staged_value_and_grad(
     loss_fn: Callable[..., Any],
     shardings: Any = None,
     floor_bytes: int = SEGMENT_FLOOR_BYTES,
+    frozen: Tuple[str, ...] = (),
 ) -> Tuple[Callable[..., Any], Callable[[Sequence[Any]], Any]]:
     """-> ``(run, assemble)``.
 
@@ -104,16 +105,27 @@ def staged_value_and_grad(
     equals ``jax.value_and_grad(loss_fn, has_aux=True)`` of, and what runs,
     as one program and one part, where ``stages`` is None. ``shardings``: a
     tree like the parameters of the sharding each gradient leaf is pinned to
-    (a stacked leaf's holds for any number of layers)."""
+    (a stacked leaf's holds for any number of layers). ``frozen``: top-level
+    keys of ``params`` that are state and not parameters
+    (``models.ModelFns.frozen``): the loss reads them, no part holds a
+    gradient for them; a kind with stages has none."""
     if stages is None:
-        whole = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
+        from torchft_tpu.models import split_frozen
 
-        def run_whole(params, tokens, targets, emit):
-            out, grads = whole(params, tokens, targets)
+        @jax.jit
+        def trainable_grad(trainable, held, tokens, targets):
+            return jax.value_and_grad(
+                lambda t: loss_fn({**t, **held}, tokens, targets), has_aux=True)(trainable)
+
+        def run_trainable(params, tokens, targets, emit):
+            out, grads = trainable_grad(*split_frozen(params, frozen), tokens, targets)
             emit(grads)
             return out
 
-        return run_whole, lambda parts: parts[0]
+        return run_trainable, lambda parts: parts[0]
+    if frozen:
+        raise ValueError(f"frozen leaves {frozen} with stages: a staged chain "
+                         "differentiates every leaf of its three stages")
 
     def pin(tree: Any, where: Callable[[Any], Any]) -> Any:
         if shardings is None:

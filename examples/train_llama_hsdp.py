@@ -450,6 +450,7 @@ def train(args) -> None:
                         or k in ("allreduce_buckets", "allreduce_ops",
                                  "overlap_efficiency", "stage_pool_hit_share",
                                  "d2h_under_backward_share",
+                                 "d2h_concurrency",
                                  "wire_passthrough_share", "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
             # the kind's frozen leaves alone (None: it has none): state that

@@ -299,3 +299,112 @@ class TestD2hUnderBackwardShare:
         assert m.timings()["d2h_under_backward_share"] == 0.0
         assert m.timings()["allreduce_ops"] == 1.0
         m.shutdown(wait=False)
+
+
+@pytest.fixture
+def slow_transfers(monkeypatch):
+    """Every device bucket in pieces of four elements whose transfers take
+    2 ms each to wait for (``np.asarray`` sleeps): a bucket of 16 to 20
+    pieces, so the fetchers have something to share."""
+    from test_bucketing import _HostPiece
+
+    real = bucketing.capture
+
+    def capture(leaves, plan, pool):
+        captured = real(leaves, plan, pool)
+        for cap in captured:
+            cap.arrays[:] = [_HostPiece(np.array(a), 0.002)
+                             for a in cap.arrays]
+        return captured
+
+    monkeypatch.setattr(bucketing, "FETCH_PIECE_BYTES", 4 * 4)
+    monkeypatch.setattr(bucketing, "capture", capture)
+
+
+def _cores(monkeypatch, n):
+    monkeypatch.setattr(
+        bucketing.os, "sched_getaffinity", lambda _pid: set(range(n)))
+
+
+class TestTheFetchersUnderAStepOfSeveralOps:
+    @pytest.mark.parametrize("cores", [1, 13], ids=["one_core", "13_cores"])
+    def test_the_pg_sees_the_steps_buckets_in_the_same_order(
+        self, monkeypatch, slow_transfers, cores
+    ):
+        """One core is the loop as it was (no helper gets a piece); with
+        thirteen the copies of a bucket run on several threads: the PG is
+        handed the same flats in the same order either way, each bitwise
+        ``np.asarray`` of its packed bucket."""
+        _cores(monkeypatch, cores)
+        seen = []
+
+        class RecordingPG(CopyingPG):
+            def allreduce(self, arrays, *a, **kw):
+                # now: a pool buffer is another bucket's a moment later
+                seen.extend(np.asarray(x).tobytes() for x in arrays)
+                return super().allreduce(arrays, *a, **kw)
+
+        m = _manager(pg=RecordingPG())
+        trees = [_device_tree(seed=s) for s in range(3)]
+        want = []
+        for tree in trees:
+            leaves = jax.tree_util.tree_leaves(tree)
+            plan = bucketing.build_plan(leaves, _CAP3)
+            want += [np.asarray(f).tobytes()
+                     for f in bucketing.pack(leaves, plan)[0]]
+        m.start_quorum()
+        for w in [m.allreduce(t) for t in trees]:
+            w.get_future().wait(timeout=30)
+        assert m.should_commit() is True
+        spans = [s for s in m.tracer.export()["spans"] if s["name"] == "d2h"]
+        m.shutdown(wait=False)
+        assert seen == want
+        assert len(spans) == 9
+        width = min(bucketing.FETCH_WIDTH, cores)
+        assert {s["args"]["fetchers"] for s in spans} == {width}
+        assert [s["args"]["pieces"] for s in spans] == [20, 18, 16] * 3
+
+    def test_timings_say_how_many_copied_at_once_and_how_fast(
+        self, monkeypatch, slow_transfers
+    ):
+        _cores(monkeypatch, 13)
+        m = _manager()
+        for _step in range(2):
+            m.start_quorum()
+            for w in [m.allreduce(_device_tree(seed=s)) for s in range(3)]:
+                w.get_future().wait(timeout=30)
+            assert m.should_commit() is True
+        _settled(m, "allreduce_buckets", 9.0)
+        t = m.timings()
+        spans = [s for s in m.tracer.export()["spans"] if s["name"] == "d2h"]
+        m.shutdown(wait=False)
+        # 54 waits of 2 ms a op, shared by FETCH_WIDTH threads: well over one
+        # at a time, and never more than the threads there are
+        assert 1.5 < t["d2h_concurrency"] <= bucketing.FETCH_WIDTH
+        assert t["d2h_gb_s"] > 0
+        assert t["stage_pool_hit_share"] == 1.0  # the second step's
+        last = spans[-9:]  # the second step's nine buckets
+        for s in last:
+            a = s["args"]
+            assert a["fetchers"] == bucketing.FETCH_WIDTH
+            assert a["busy_us"] >= a["pieces"] * 2000 > a["copy_us"] >= 0
+        # the counter is the spans' own arithmetic
+        assert t["d2h_concurrency"] == pytest.approx(
+            sum(s["args"]["busy_us"] for s in last)
+            / sum(s["dur_us"] for s in last), rel=0.2)
+        assert t["d2h_gb_s"] == pytest.approx(
+            sum(s["args"]["bytes"] for s in last)
+            / sum(s["dur_us"] for s in last) / 1e3, rel=0.2)
+
+    def test_one_core_reads_one_at_a_time(self, monkeypatch, slow_transfers):
+        """``d2h_concurrency`` near 1.0 with many pieces: the pool did not
+        engage (here: one core in the affinity mask)."""
+        _cores(monkeypatch, 1)
+        m = _manager()
+        m.start_quorum()
+        for w in [m.allreduce(_device_tree(seed=s)) for s in range(2)]:
+            w.get_future().wait(timeout=30)
+        t = m.timings()
+        m.shutdown(wait=False)
+        assert 0.5 < t["d2h_concurrency"] <= 1.0
+        assert t["d2h_gb_s"] > 0

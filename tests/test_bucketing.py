@@ -5,6 +5,8 @@ bucketing — and bitwise-identical values either way."""
 
 import contextlib
 import math
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -276,6 +278,147 @@ class TestFetchInto:
         assert hit and b is a and (pool.hits, pool.misses) == (1, 1)
 
 
+class _HostPiece:
+    """A piece whose transfer takes ``seconds`` to wait for, or fails on
+    the threads whose name starts with ``fails_on``."""
+
+    def __init__(self, data, seconds=0.0, fails_on=None):
+        self.data, self.seconds, self.fails_on = data, seconds, fails_on
+        self.size = data.size
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, dtype=None, copy=None):
+        name = threading.current_thread().name
+        if self.fails_on is not None and name.startswith(self.fails_on):
+            raise RuntimeError(f"transfer failed on {name}")
+        time.sleep(self.seconds)
+        return self.data
+
+
+def _slow_pieces(n, elems=8, seconds=0.005, dtype=np.float32):
+    import ml_dtypes  # noqa: F401 — np.dtype("bfloat16")
+
+    flat = (np.arange(n * elems) % 251).astype(np.dtype(dtype))
+    arrays = [_HostPiece(flat[k * elems:(k + 1) * elems], seconds)
+              for k in range(n)]
+    bounds = [(k * elems, (k + 1) * elems) for k in range(n)]
+    return bucketing.Pieces(arrays, bounds, flat.size, flat.dtype), flat
+
+
+@pytest.fixture
+def fetchers():
+    from concurrent.futures import ThreadPoolExecutor
+
+    pool = ThreadPoolExecutor(max_workers=8, thread_name_prefix="test_fetch")
+    yield pool
+    pool.shutdown(wait=True)
+
+
+class TestFetchers:
+    """fetch_into with a pool: the caller and its helpers take the pieces in
+    flat order; the bytes, the drops and the look after each piece are what
+    the one loop's were."""
+
+    @pytest.mark.parametrize("short_last", [False, True],
+                             ids=["whole_last", "short_last"])
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("n_pieces", [1, 2, 7, 64])
+    def test_bitwise_np_asarray_of_the_packed_flat(
+        self, fetchers, n_pieces, dtype, short_last
+    ):
+        dt = np.dtype(dtype)
+        piece_elems = 96
+        # a leaf that is cut into whole pieces, and one that is the last
+        shapes = [((n_pieces - 1) * piece_elems,),
+                  (piece_elems - (5 if short_last else 0),)]
+        leaves = _device_leaves(shapes[n_pieces == 1:], dt)
+        plan = bucketing.build_plan(leaves, 1 << 30)
+        want = np.asarray(bucketing.pack(leaves, plan)[0][0])
+        pieces = bucketing.pack(
+            leaves, plan, piece_bytes=piece_elems * dt.itemsize)[0][0]
+        sizes = [b - a for a, b in pieces.bounds]
+        assert len(sizes) == n_pieces
+        if short_last and n_pieces > 1:
+            assert sizes[-1] < min(sizes[:-1])
+        seen = []
+        out = np.full(want.size, 99, dt)
+        n = bucketing.fetch_into(
+            pieces, out, lambda: seen.append(1), fetchers)
+        assert n == n_pieces == len(seen)
+        assert out.dtype == want.dtype and out.tobytes() == want.tobytes()
+        assert pieces.arrays == [None] * n
+
+    @pytest.mark.parametrize("width", [1, 2, 3, 8])
+    def test_after_piece_is_called_once_a_piece_whatever_the_width(
+        self, fetchers, monkeypatch, width
+    ):
+        monkeypatch.setattr(bucketing, "FETCH_WIDTH", width)
+        monkeypatch.setattr(
+            bucketing.os, "sched_getaffinity", lambda _pid: set(range(13)))
+        pieces, flat = _slow_pieces(24)
+        out, threads = np.zeros_like(flat), []
+        got, busy_s, copy_s = bucketing._fetch(
+            pieces, out,
+            lambda: threads.append(threading.current_thread().name), fetchers)
+        assert got == width and len(threads) == 24
+        assert out.tobytes() == flat.tobytes()
+        assert pieces.arrays == [None] * 24
+        # this thread is one of the fetchers; the others are the pool's
+        mine = threading.current_thread().name
+        assert 1 <= len(set(threads)) <= width
+        assert set(threads) - {mine} <= {t.name for t in fetchers._threads}
+        if width > 1:
+            assert len(set(threads)) > 1  # 5 ms a piece: the helpers got some
+        # 24 waits of 5 ms, however many threads shared them
+        assert busy_s >= 24 * 0.005 > copy_s >= 0.0
+
+    def test_the_width_is_capped_by_the_pieces_and_by_the_cores(self, monkeypatch):
+        monkeypatch.setattr(
+            bucketing.os, "sched_getaffinity", lambda _pid: set(range(13)))
+        width = bucketing.FETCH_WIDTH
+        assert 1 < width <= 8
+        assert [bucketing._fetch_width(n) for n in (1, 2, 64)] == [
+            1, 2, width]
+        monkeypatch.setattr(
+            bucketing.os, "sched_getaffinity", lambda _pid: {0, 5})
+        assert [bucketing._fetch_width(n) for n in (1, 2, 64)] == [1, 2, 2]
+        monkeypatch.setattr(
+            bucketing.os, "sched_getaffinity", lambda _pid: {3})
+        assert bucketing._fetch_width(64) == 1
+
+    def test_a_bucket_of_one_piece_is_copied_here_with_no_hand_off(self):
+        class NoHandOff:
+            def submit(self, *a, **kw):
+                raise AssertionError("a lone piece was handed off")
+
+        pieces, flat = _slow_pieces(1, seconds=0.0)
+        out = np.zeros_like(flat)
+        assert bucketing._fetch(pieces, out, None, NoHandOff())[0] == 1
+        assert out.tobytes() == flat.tobytes()
+
+    @pytest.mark.parametrize("raises_on", ["test_fetch", "MainThread"])
+    def test_a_fetcher_that_raises_stops_the_others_and_raises_here(
+        self, fetchers, raises_on
+    ):
+        """Whoever takes a piece from the second on fails if it is a helper
+        (or: if it is the caller): the exception comes out of fetch_into
+        once every fetcher has stopped, and the pieces not yet taken stay."""
+        assert threading.current_thread().name == "MainThread"
+        pieces, flat = _slow_pieces(48)
+        for piece in pieces.arrays[1:]:
+            piece.fails_on = raises_on
+        out = np.zeros_like(flat)
+        with pytest.raises(RuntimeError, match="transfer failed on " + raises_on):
+            bucketing.fetch_into(pieces, out, None, fetchers)
+        left = [k for k, a in enumerate(pieces.arrays) if a is not None]
+        assert left and len(left) < 48
+        before = list(pieces.arrays)
+        time.sleep(0.05)  # nobody is still at it
+        assert pieces.arrays == before
+
+
 # ---------------------------------------------------------------------------
 # the seams of the data plane: stage, land_reduced, BucketPipeline. None of
 # these needs a Manager or a lighthouse.
@@ -400,6 +543,56 @@ class TestPipelineWithoutAManager:
                             "dispatch": 6, "h2d": 6, "divide": 6,
                             "pack": 6, "wire": 6, "unpack": 6}.items():
             assert names.count(name) == count, (name, names)
+
+    @pytest.mark.parametrize("raises_on", ["torchft_fetch", "torchft_stage"])
+    def test_a_fetcher_that_raises_fails_every_bucket_future_of_the_op(
+        self, monkeypatch, raises_on
+    ):
+        """A piece of the op's first bucket fails on a fetcher thread (or on
+        the staging thread, which is one): every bucket future of the op
+        and its final fail with that exception, nothing was dispatched, the
+        buffer drawn for the bucket does not come back to the pool, and
+        shutdown leaves none of the pipeline's threads behind."""
+        from torchft_tpu.tracing import SpanRecorder, TraceConfig
+
+        leaves = _device_leaves([(40,), (40,), (36,), (36,), (32,), (32,)],
+                                np.float32)
+        plan = bucketing.build_plan(leaves, 2 * 40 * 4)
+        monkeypatch.setattr(bucketing, "FETCH_PIECE_BYTES", 4 * 4)
+        real = bucketing.capture
+
+        def capture(leaves, plan, pool):
+            captured = real(leaves, plan, pool)
+            first = captured[0]
+            assert len(first.arrays) == 20
+            first.arrays[:] = [
+                _HostPiece(np.array(a), 0.005, raises_on if k else None)
+                for k, a in enumerate(first.arrays)]
+            return captured
+
+        monkeypatch.setattr(bucketing, "capture", capture)
+        pg, pool = CopyingPG(), bucketing.BufferPool()
+        pipeline = bucketing.BucketPipeline(
+            pg, SpanRecorder("x", TraceConfig(enabled=False)), pool)
+        try:
+            pipeline.begin_step()
+            op = pipeline.allreduce_buckets(
+                leaves, plan, ReduceOp.SUM, participating=True, divisor=2,
+                place=bucketing.leaf_placer(), timeout=30.0)
+            with pytest.raises(RuntimeError, match="transfer failed on " + raises_on):
+                op.final.wait(30)
+            for fut in op.bucket_futs:  # (the first to fail failed final)
+                with pytest.raises(RuntimeError, match="transfer failed"):
+                    fut.wait(5)
+            assert pg.inputs == []
+            assert (pool.hits, pool.misses) == (0, 1) and not any(
+                pool._free.values())
+        finally:
+            pipeline.shutdown(wait=True)
+        executors = (pipeline._staging_executor, pipeline._fetch_executor,
+                     pipeline._unpack_executor)
+        assert pipeline._fetch_executor._threads  # the helpers did run
+        assert not any(t.is_alive() for e in executors for t in e._threads)
 
     @pytest.mark.parametrize("path", ["pipeline", "no_plan"])
     def test_one_submit_arms_the_backstop_for_either_path(self, path):

@@ -56,10 +56,11 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 import threading
 import time
 import weakref
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait as wait_all
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -85,6 +86,7 @@ __all__ = [
     "Pieces",
     "fetch_into",
     "FETCH_PIECE_BYTES",
+    "FETCH_WIDTH",
     "capture",
     "stage",
     "unpack",
@@ -313,6 +315,24 @@ class BufferPool:
 # mmaps it again) 6.7; as glibc comes 2.1-3.3 at any size up to 32 MiB, and
 # 1.0 at 128 MiB.
 FETCH_PIECE_BYTES = 16 << 20
+# The pieces of a bucket are copied into its pool buffer by this many threads
+# at most, the staging thread among them (_fetch_width caps it by the pieces
+# and the process's cores). TPU v5e host of 13 cores, 0.537 GB in 32 pieces,
+# from the moment the producing program is ready, GB/s behind an idle chip /
+# under a running program (benchmarks/d2h_under_compute_check.py, chip runs,
+# PR 36): the transfers alone (np.asarray, no copy) 13.9 / 13.9; one thread
+# 6.6 / 6.4; 2 threads 8.8 / 8.6; 3: 10.1 / 9.8; 4: 10.3 / 10.3; 6: 11.1 /
+# 10.8; 8: 11.7 / 11.4. The copies alone (transfers long over) run at 11.4
+# on one thread and 35 on eight, so the threads mostly WAIT: a transfer makes
+# no progress while a copy runs (one thread that only waits and one that only
+# copies: 6.3), and the fetch takes the transfers' 39 ms plus the copies'
+# seconds over the width. In the managed cells (traced runs at 4 / 6 / 8):
+# d2h seconds a step 0.191 / 0.194 / 0.183 (OLMoE), 0.228 / 0.220 / 0.218
+# (Mistral), while the landing beside it slows with every thread (unpack
+# 0.103 / 0.121 / 0.133 and 0.197 / 0.208 / 0.223) and the chip's idle share
+# is lowest at 4: the smallest width within 5% of the best there and under a
+# running program.
+FETCH_WIDTH = 4
 # rows of the second-minor dimension in one TPU tile, at the narrowest dtype
 _TILE_ROWS = 32
 
@@ -461,28 +481,108 @@ class Pieces:
         return last is None or last.is_ready()
 
 
+class _InOrder:
+    """The indices ``0 .. n-1``, each handed out once and in order to
+    whichever fetcher asks next; nothing more after :meth:`stop`."""
+
+    def __init__(self, n: int) -> None:
+        self._lock = threading.Lock()
+        self._next, self._n = 0, n
+
+    def __iter__(self) -> "_InOrder":
+        return self
+
+    def __next__(self) -> int:
+        with self._lock:
+            if self._next >= self._n:
+                raise StopIteration
+            self._next += 1
+            return self._next - 1
+
+    def stop(self) -> None:
+        with self._lock:
+            self._n = 0
+
+
+def _fetch_width(pieces: int) -> int:
+    """How many threads copy a bucket of ``pieces`` pieces: ``FETCH_WIDTH``,
+    never more than the pieces there are or the cores this process may run
+    on (four trainers share the failure cell's host; a container of two
+    cores gets two)."""
+    return max(1, min(FETCH_WIDTH, pieces, len(os.sched_getaffinity(0))))
+
+
+def _fetch(
+    pieces: Pieces, out: np.ndarray,
+    after_piece: Optional[Callable[[], None]],
+    fetchers: Optional[ThreadPoolExecutor],
+) -> Tuple[int, float, float]:
+    """:func:`fetch_into`, and how it went: ``(width, busy_s, copy_s)``, the
+    threads that copied (the caller among them) and, summed over them, the
+    seconds inside a piece (the wait for its transfer and its copy) and
+    inside its copy alone."""
+    arrays, bounds = pieces.arrays, pieces.bounds
+    todo = _InOrder(len(arrays))
+
+    def share() -> Tuple[float, float]:
+        busy_s = copy_s = 0.0
+        try:
+            for k in todo:
+                a, b = bounds[k]
+                t0 = time.perf_counter()
+                host = np.asarray(arrays[k])
+                t1 = time.perf_counter()
+                np.copyto(out[a:b], host)
+                arrays[k] = host = None
+                t2 = time.perf_counter()
+                busy_s += t2 - t0
+                copy_s += t2 - t1
+                if after_piece is not None:
+                    after_piece()
+        except BaseException:
+            todo.stop()  # the others finish the piece they hold, no more
+            raise
+        return busy_s, copy_s
+
+    width = 1 if fetchers is None else _fetch_width(len(arrays))
+    helpers: List[Any] = []
+    try:
+        for _ in range(width - 1):
+            helpers.append(fetchers.submit(share))
+        shares = [share()]
+    finally:
+        # nobody writes ``out`` or holds a piece once this returns or raises
+        wait_all(helpers)
+    shares.extend(h.result() for h in helpers)
+    return width, sum(s[0] for s in shares), sum(s[1] for s in shares)
+
+
 def fetch_into(
     pieces: Pieces, out: np.ndarray,
     after_piece: Optional[Callable[[], None]] = None,
+    fetchers: Optional[ThreadPoolExecutor] = None,
 ) -> int:
     """Copy a captured device bucket into ``out`` (1-D, ``pieces.size``
     elements: afterwards bitwise ``np.asarray`` of the packed flat) and
     return how many pieces it came in. Every piece's transfer has been in
-    flight since the capture, so this waits for each in turn, copies it to
-    its offset and drops it with its device buffer. What the runtime
-    allocated per transfer is piece-sized and reused from step to step
-    (:func:`_keep_freed_blocks_mapped`); the only bucket-sized host memory
-    is ``out``, which the caller takes from a :class:`BufferPool` so that
-    its pages are mapped from the second step on. ``after_piece()`` is
-    called as each piece has landed (the pipeline looks up from its copying
-    there: is the device still computing gradients?)."""
-    arrays = pieces.arrays
-    for k, (a, b) in enumerate(pieces.bounds):
-        np.copyto(out[a:b], np.asarray(arrays[k]))
-        arrays[k] = None
-        if after_piece is not None:
-            after_piece()
-    return len(arrays)
+    flight since the capture, so a piece is waited for, copied to its offset
+    and dropped with its device buffer. The pieces are disjoint ranges of
+    ``out`` and nothing orders their copies among themselves: with
+    ``fetchers`` (a thread pool; the pipeline's) the caller and up to
+    ``FETCH_WIDTH - 1`` of its threads take the pieces in flat order, each
+    the next one not yet taken, and this returns when all have landed; a
+    bucket of one piece, or no pool, is copied here with no hand-off. A
+    fetcher that raises stops the others at their next piece and its
+    exception is raised here, after all of them have stopped. What the
+    runtime allocated per transfer is piece-sized and reused from step to
+    step (:func:`_keep_freed_blocks_mapped`); the only bucket-sized host
+    memory is ``out``, which the caller takes from a :class:`BufferPool` so
+    that its pages are mapped from the second step on. ``after_piece()`` is
+    called as each piece has landed, on the thread that copied it (the
+    pipeline looks up from its copying there: is the device still computing
+    gradients?)."""
+    _fetch(pieces, out, after_piece, fetchers)
+    return len(pieces.arrays)
 
 
 def pack(
@@ -563,13 +663,17 @@ def capture(
 def stage(
     captured: Optional[List[Any]], plan: BucketPlan, i: int, pool: BufferPool,
     after_piece: Optional[Callable[[], None]] = None,
+    fetchers: Optional[ThreadPoolExecutor] = None,
 ) -> Tuple[np.ndarray, Optional[np.ndarray], Dict[str, int]]:
     """Bucket ``i`` of a :func:`capture` as host memory for the wire, on the
     staging thread: ``(host_flat, pooled_buf, info)``. ``pooled_buf`` is the
     pool buffer to give back once the bucket has LANDED, and what landed
     from it has read it (None: nothing was taken); ``info`` is what the ``d2h`` span says of it (``bytes``, and for
     a device bucket ``pieces`` and ``pooled``: 1 when the buffer is a
-    recycled one). ``captured`` is None for a non-participant: its
+    recycled one; with ``fetchers``, the pipeline's pool for
+    :func:`fetch_into`, also ``fetchers``, the threads that copied, and
+    summed over them ``busy_us``, inside a piece, and ``copy_us``, inside
+    its copy). ``captured`` is None for a non-participant: its
     contribution is zeros of the plan's size and dtype, built from shapes
     alone, and nothing comes out of the pool. The entry is dropped from
     ``captured`` so that nothing holds the capture once it is staged."""
@@ -581,8 +685,11 @@ def stage(
         # pool buffer (mapped pages from the second step on), device memory
         # dropped as each lands
         host_flat, hit = pool.acquire_hit(cap.size, cap.dtype)
-        info = {"pieces": fetch_into(cap, host_flat, after_piece),
-                "pooled": int(hit)}
+        width, busy_s, copy_s = _fetch(cap, host_flat, after_piece, fetchers)
+        info = {"pieces": len(cap.arrays), "pooled": int(hit)}
+        if fetchers is not None:
+            info.update(fetchers=width, busy_us=int(busy_s * 1e6),
+                        copy_us=int(copy_s * 1e6))
     else:
         # a host group: packed into its pool buffer at the capture
         host_flat, info = cap, {}
@@ -924,15 +1031,21 @@ class _StepTally:
         # it): while ITS pieces are not ready, gradients are being computed
         self.newest: Optional[Pieces] = None
         # the staging thread's alone: device buckets it fetched, those into
-        # a recycled buffer, each fetch's (start, end), and the last moment
-        # it saw the device still computing this step's gradients
+        # a recycled buffer, each fetch's (start, end), their bytes, and the
+        # seconds its fetchers spent inside a piece
         self.acquired = self.hits = 0
         self.d2h: List[Tuple[float, float]] = []
+        self.fetched_bytes = 0
+        self.fetch_busy_s = 0.0
+        # the last moment the device was seen still computing this step's
+        # gradients (the staging thread at a grad_wait, a fetcher after a
+        # piece: a float, stored whole)
         self.computing_until: Optional[float] = None
 
     def still_computing(self) -> None:
-        """Asked by the staging thread after each fetched piece: gradients
-        that a later op of the step has captured are not there yet."""
+        """Asked after each fetched piece, by the fetcher that copied it:
+        gradients that a later op of the step has captured are not there
+        yet."""
         newest = self.newest
         if newest is not None and not newest.is_ready():
             self.computing_until = time.perf_counter()
@@ -944,14 +1057,20 @@ class _StepTally:
         that had to wait returned, or a later op's capture was found not
         ready after a piece. A lower bound, to a piece (a backward pass that
         ends between two looks is seen at the earlier one); 0.0 where one op
-        carries the whole tree: every fetch follows its one wait."""
+        carries the whole tree: every fetch follows its one wait. Over the
+        same d2h seconds, ``d2h_concurrency``: the seconds the fetchers
+        spent inside a piece (1.0: one at a time; towards ``FETCH_WIDTH``
+        when the pool is busy), and ``d2h_gb_s``: the bytes fetched."""
         total = sum(t1 - t0 for t0, t1 in self.d2h)
         until = self.computing_until
         under = 0.0 if until is None else sum(
             max(0.0, min(t1, until) - t0) for t0, t1 in self.d2h)
+        per_second = 1.0 / total if total > 0 else 0.0
         return {
             "stage_pool_hit_share": self.hits / self.acquired,
-            "d2h_under_backward_share": under / total if total > 0 else 0.0,
+            "d2h_under_backward_share": under * per_second,
+            "d2h_concurrency": self.fetch_busy_s * per_second,
+            "d2h_gb_s": self.fetched_bytes * per_second / 1e9,
         }
 
 
@@ -1019,7 +1138,10 @@ class BucketPipeline:
 
     A tree with a plan takes :meth:`allreduce_buckets`: one PG collective
     PER BUCKET, three stages a bucket: pack (:func:`capture` on the caller's
-    thread, :func:`stage` on the one staging thread), wire (the PG's
+    thread, :func:`stage` on the one staging thread, which shares the copies
+    of a device bucket's pieces with its fetcher threads, waits for the
+    bucket, and alone decides the order of buckets and ops: a fetcher
+    copies and never dispatches), wire (the PG's
     dispatch thread, or XLA), unpack (:func:`land_reduced` on the one unpack
     thread). Bucket i+1 packs while bucket i rides the wire and bucket i−1
     unpacks; no stage ever waits for the LAST bucket's wire. A tree without
@@ -1043,7 +1165,8 @@ class BucketPipeline:
     ``on_timings(stats)`` receives what ``Manager.timings()`` shows of the
     pipeline, each value over the ops of the step so far
     (:meth:`begin_step`): ``allreduce_ops``; from the staging thread
-    ``stage_pool_hit_share`` and ``d2h_under_backward_share``; the stage
+    ``stage_pool_hit_share``, ``d2h_under_backward_share``,
+    ``d2h_concurrency`` and ``d2h_gb_s``; the stage
     sums and ``wire_passthrough_share`` from :meth:`record_timings`."""
 
     def __init__(
@@ -1061,6 +1184,12 @@ class BucketPipeline:
         # the train loop, issue order preserved across replicas
         self._staging_executor = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="torchft_stage"
+        )
+        # the staging thread's helpers for the copies of a device bucket's
+        # pieces (fetch_into); threads start when a bucket first needs them
+        self._fetch_executor = ThreadPoolExecutor(
+            max_workers=max(1, FETCH_WIDTH - 1),
+            thread_name_prefix="torchft_fetch",
         )
         # stage 3: per-bucket unpack + device landing runs here so it
         # neither blocks the PG's dispatch thread (which would serialize
@@ -1168,7 +1297,7 @@ class BucketPipeline:
         fut.add_done_callback(_unpin)
 
     def shutdown(self, wait: bool = True) -> None:
-        """Stop both workers. On a non-waiting shutdown queued (not-yet-run)
+        """Stop the workers. On a non-waiting shutdown queued (not-yet-run)
         staging tasks are cancelled: they would otherwise dispatch against
         the PG after its shutdown, spuriously reporting errors on a
         torn-down manager — and their staged futures are failed so any
@@ -1177,6 +1306,9 @@ class BucketPipeline:
         with self._staged_lock:
             self._staging_down = True
         self._staging_executor.shutdown(wait=wait, cancel_futures=not wait)
+        # after the staging thread, which is the only one that hands the
+        # fetchers work: a stage cut off here fails at its next hand-off
+        self._fetch_executor.shutdown(wait=wait, cancel_futures=not wait)
         # cancelled bucket unpacks leave their bucket futures unresolved —
         # the aggregate is bounded by the stage deadline / the sweep below,
         # so no waiter stalls past the timeout
@@ -1306,8 +1438,9 @@ class BucketPipeline:
         t_submit: float,
     ) -> None:
         """Stages 1 and 2 of every bucket of a host-plane op, on the
-        staging thread: host memory (:func:`stage`), the codec, the
-        dispatch. It never waits for a wire."""
+        staging thread: host memory (:func:`stage`; the fetchers help with
+        a device bucket's copies and are done when it returns), the codec,
+        the dispatch. It never waits for a wire."""
         tracer, plan, tally = self._tracer, op.plan, op.tally
         seg = {"segment": op.segment}
         try:
@@ -1345,7 +1478,7 @@ class BucketPipeline:
                         t0d = time.perf_counter()
                         host_flat, pooled_buf, info = stage(
                             captured, plan, i, self._pool,
-                            tally.still_computing,
+                            tally.still_computing, self._fetch_executor,
                         )
                         sp.args.update(info)
                     if "pooled" in info:
@@ -1353,6 +1486,8 @@ class BucketPipeline:
                         tally.acquired += 1
                         tally.hits += info["pooled"]
                         tally.d2h.append((t0d, time.perf_counter()))
+                        tally.fetched_bytes += info["bytes"]
+                        tally.fetch_busy_s += info["busy_us"] / 1e6
                 payload: Any = host_flat
                 if modes[i] != "off":
                     # quantize inside the pack stage so pack_s absorbs the

@@ -2132,8 +2132,12 @@ class Manager:
         seconds those fetches took, the part that ran before the staging
         thread's last wait for gradients still being computed returned,
         i.e. under the backward pass (0.0 where one op carries the whole
-        tree: its fetch follows its wait). Keys appear once the phase has
-        run.
+        tree: its fetch follows its wait); ``d2h_concurrency``: over those
+        same seconds, the seconds the pipeline's fetcher threads spent
+        inside a piece (1.0: one copy at a time; towards
+        ``bucketing.FETCH_WIDTH`` when the buckets have pieces enough and
+        the process cores enough), and ``d2h_gb_s``: the bytes fetched over
+        them, in GB/s. Keys appear once the phase has run.
 
         Also carries the CUMULATIVE resilience counters (present from
         construction, never reset): ``heal_attempts`` (initial heal tries

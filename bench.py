@@ -482,9 +482,11 @@ def tracing_metrics(steps: int = 30, warmup: int = 5, batch_size: int = 4096,
     )
 
 
-# a steady step of the one-bucket toy records 17: the bucket's tree is 9
-# (pack, wire, unpack and their six children) and the step's own are 8;
-# the first step's compiles and configure bring a short run's mean to ~20
+# a steady step of the one-bucket toy records 19: the bucket's tree is 9
+# (pack, wire, unpack and their six children), the step's own are 8 and
+# the watcher's device/forward and device/landed 2 (the toy's update is
+# not the trainer's: no device/update); the first step's compiles and
+# configure bring a short run's mean to 22.6
 TRACING_MAX_SPANS_PER_STEP = 24
 
 

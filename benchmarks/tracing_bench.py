@@ -16,7 +16,7 @@ measures that claim instead of asserting it, three ways in one run:
   loop; ``tracing_overhead_pct`` is per-span cost × observed spans/step
   as a share of the measured managed step — the number the <1% gate
   holds, on a batch that makes the toy's step 0.1-0.2 s (since PR 24 a
-  step records about 20 spans with one bucket, not 5; ``bench.py`` caps
+  step records about 23 spans with one bucket, not 5; ``bench.py`` caps
   the count too). (An end-to-end A/B of two full loops would measure the 1-vCPU
   host's scheduler, not the machinery — same reasoning as
   healthwatch_bench.)

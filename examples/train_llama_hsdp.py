@@ -165,6 +165,12 @@ def train(args) -> None:
         updates, opt_state = tx.update(assemble(parts), opt_state, trainable)
         return {**optax.apply_updates(trainable, updates), **held}, opt_state
 
+    # A scalar of the trainer's own that is ready once ``leaf`` is: what the
+    # span recorder's watcher is handed for the update (``device/update``).
+    # The new state itself is donated to the next step before anyone could
+    # ask it. ``keep_unused``: a program starts once its arguments are there.
+    updated = jax.jit(lambda leaf: np.int32(0), keep_unused=True)
+
     state = {"params": params, "opt_state": opt_state}
     _STARTUP_US["state_init"] = time.time_ns() // 1000
 
@@ -384,6 +390,11 @@ def train(args) -> None:
                         state["params"], state["opt_state"] = update_step(
                             state["params"], state["opt_state"], reduced
                         )
+                        if tracer.enabled:
+                            # the device, from the step's last landing to
+                            # the new parameters
+                            tracer.when_ready("update", "device", updated(
+                                jax.tree_util.tree_leaves(state["params"])[0]))
                     del reduced  # donated
                     tokens_done += B * S * manager.num_participants()
                     inner_step += 1

@@ -325,9 +325,11 @@ class TestAverageWhereItLands:
             time.sleep(0.02)
         m.shutdown(wait=False)
         for up in (s for s in spans if s["name"] == "unpack"):
-            kids = sorted((s for s in spans if s["parent"] == up["id"]),
+            kids = sorted((s for s in spans if s["parent"] == up["id"]
+                           and s["cat"] == "allreduce"),
                           key=lambda s: s["ts_us"])
-            # (the default PG hands the staging buffer back: recycle)
+            # (the default PG hands the staging buffer back: recycle; the
+            # watcher's device/landed instant hangs here too)
             assert [s["name"] for s in kids] == ["h2d", "divide", "recycle"]
             h2d, divide, _recycle = (s["args"] for s in kids)
             assert divide["where"] == where
@@ -970,3 +972,112 @@ class TestStagingThroughThePool:
         for k in a:
             assert np.array_equal(_bits(o1[k]), _bits(np.asarray(a[k]) / 2))
             assert np.array_equal(_bits(o2[k]), _bits(np.asarray(b[k]) / 2))
+
+
+def _device_spans(m, n):
+    """The watcher's ``device/*`` spans, once it has recorded ``n``."""
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        spans = m.tracer.export()["spans"]
+        got = [s for s in spans if s["cat"] == "device"]
+        if len(got) >= n:
+            break
+        time.sleep(0.01)
+    return spans, got
+
+
+class TestDeviceMilestonesOfAStep:
+    """What the device finished, and when, for the recorder's watcher
+    (``SpanRecorder.when_ready``): registered by the pipeline, on threads
+    that are there anyway, none of which waits."""
+
+    def _step_of_two_ops(self, m):
+        head, layers = _device_tree(seed=1), _device_tree(seed=2)
+        m.start_quorum()
+        works = [m.allreduce(head), m.allreduce(layers)]
+        out = [w.get_future().wait(timeout=30) for w in works]
+        m.should_commit()
+        return (head, layers), out
+
+    @pytest.mark.parametrize("pg", [ProcessGroupDummy, CopyingPG])
+    def test_forward_backward_and_a_landing_a_bucket(self, pg, monkeypatch):
+        """Segment 0's last piece is ``device/forward``, a later segment's
+        ``device/backward``, and every landed bucket an instant: on the last
+        of the tokens that recycling the passed-through staging buffer made
+        anyway, or, where the result was a copy, on one token of its own."""
+        tokens = []
+        real = bucketing._landed_token()
+
+        def counting():
+            def token(leaf):
+                tokens.append(leaf)
+                return real(leaf)
+            return token
+
+        monkeypatch.setattr(bucketing, "_landed_token", counting)
+        m = make_manager(pg=pg(), quorum=make_quorum(), bucket_cap_bytes=_CAP3)
+        trees, outs = self._step_of_two_ops(m)
+        spans, dev = _device_spans(m, 8)
+        m.shutdown(wait=False)
+        for tree, out in zip(trees, outs):  # the gradients are what they were
+            for k, v in tree.items():
+                assert np.array_equal(_bits(out[k]), _bits(np.asarray(v) / 2))
+        # (the first call compiles: its buckets may land, on the unpack
+        # thread, before this thread registers the second op's)
+        ops = [s for s in dev if s["name"] != "landed"]
+        assert [(s["name"], s["args"]["segment"]) for s in ops] == [
+            ("forward", 0), ("backward", 1)]
+        landed = [s for s in dev if s["name"] == "landed"]
+        assert len(landed) == 6
+        assert [(s["args"]["segment"], s["args"]["bucket"]) for s in landed] \
+            == [(0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (1, 2)]
+        assert all(s["dur_us"] == 1 for s in landed)
+        assert all({"waited_us", "late"} <= set(s["args"]) for s in dev)
+        passed_through = pg is ProcessGroupDummy
+        # six leaves an op: one token a leaf where the buffer is recycled
+        # and none more for the milestone; one a bucket where it is not
+        assert len(tokens) == (12 if passed_through else 6)
+        # each hangs where its cause does: the op's allreduce span, the
+        # bucket's unpack stage
+        by_id = {s["id"]: s for s in spans}
+        assert all(by_id[s["parent"]]["name"] == "allreduce" for s in ops)
+        assert [by_id[s["parent"]]["args"]["segment"] for s in ops] == [0, 1]
+        assert all(by_id[s["parent"]]["name"] == "unpack"
+                   and by_id[s["parent"]]["args"]["bucket"] == s["args"]["bucket"]
+                   for s in landed)
+        assert len({s["step"] for s in dev}) == 1
+
+    def test_a_host_tree_and_a_disabled_recorder_register_nothing(
+        self, monkeypatch
+    ):
+        tokens = []
+        real = bucketing._landed_token()
+        monkeypatch.setattr(
+            bucketing, "_landed_token",
+            lambda: lambda leaf: tokens.append(leaf) or real(leaf))
+        m = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        _reduce(m, _tree(), streamed=False)
+        m.should_commit()
+        off = make_manager(pg=CopyingPG(), quorum=make_quorum(),
+                           bucket_cap_bytes=_CAP3, tracing=False)
+        out = _reduce(off, _device_tree(), streamed=False)
+        off.should_commit()
+        assert np.array_equal(
+            _bits(out["p0"]), _bits(np.asarray(_device_tree()["p0"]) / 2))
+        spans, dev = _device_spans(m, 0)
+        assert dev == [] and m.tracer._watcher is None
+        assert off.tracer._watcher is None and off.tracer._watched.empty()
+        assert tokens == []  # a copied result and no recorder: none dispatched
+        m.shutdown(wait=False)
+        off.shutdown(wait=False)
+
+    def test_manager_shutdown_ends_the_watcher(self):
+        m = make_manager(pg=ProcessGroupDummy(), quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        self._step_of_two_ops(m)
+        watcher = m.tracer._watcher
+        assert watcher is not None and watcher.is_alive()
+        m.shutdown(wait=False)
+        watcher.join(5)
+        assert not watcher.is_alive()

@@ -1,9 +1,10 @@
 """Fleet tracing plane: span recorder, skew-corrected merge, /metrics,
 and the recorded-history parity pin.
 
-Covers the tracing module bottom-up — config env parsing, deterministic
-step sampling, the bounded ring's drop accounting, perf-counter
-anchoring — then the cross-replica guarantees that only hold end to end:
+Covers the tracing module bottom-up — config env parsing, the bounded
+ring's drop accounting and what it holds at its default size, perf-counter
+anchoring, the watcher thread's device milestones (``when_ready``) — then
+the cross-replica guarantees that only hold end to end:
 
 - **skew correction** (``merge_traces``): replicas with injected clock
   offsets (``EventInjector.skew_clock``) produce raw timestamps that
@@ -47,7 +48,6 @@ from torchft_tpu.tracing import (
     merge_traces,
     parse_history,
     set_clock_offset_ms,
-    step_sampled,
 )
 
 LR = 0.05
@@ -59,11 +59,9 @@ def _clean_clock_offsets():
     clear_clock_offsets()
 
 
-def _cfg(buffer: int = 64, sample: float = 1.0, enabled: bool = True,
+def _cfg(buffer: int = 64, enabled: bool = True,
          dump_dir: str = "") -> TraceConfig:
-    return TraceConfig(
-        enabled=enabled, buffer=buffer, sample=sample, dump_dir=dump_dir
-    )
+    return TraceConfig(enabled=enabled, buffer=buffer, dump_dir=dump_dir)
 
 
 def _parse_prometheus(text: str) -> dict:
@@ -86,12 +84,11 @@ def _bare_names(series: dict) -> set:
 class TestTraceConfig:
     def test_defaults(self, monkeypatch):
         for env in ("TORCHFT_TRACE", "TORCHFT_TRACE_BUFFER",
-                    "TORCHFT_TRACE_SAMPLE", "TORCHFT_TRACE_DIR"):
+                    "TORCHFT_TRACE_DIR"):
             monkeypatch.delenv(env, raising=False)
         cfg = TraceConfig.from_env()
         assert cfg.enabled is True
-        assert cfg.buffer == 4096
-        assert cfg.sample == 1.0
+        assert cfg.buffer == 65536
         assert cfg.dump_dir == ""
 
     @pytest.mark.parametrize("val,expect", [
@@ -106,34 +103,11 @@ class TestTraceConfig:
         monkeypatch.setenv("TORCHFT_TRACE_BUFFER", "4")
         assert TraceConfig.from_env().buffer == 16  # floor, not crash
         monkeypatch.setenv("TORCHFT_TRACE_BUFFER", "lots")
-        assert TraceConfig.from_env().buffer == 4096
-
-    def test_sample_clamped_and_garbage(self, monkeypatch):
-        monkeypatch.setenv("TORCHFT_TRACE_SAMPLE", "1.7")
-        assert TraceConfig.from_env().sample == 1.0
-        monkeypatch.setenv("TORCHFT_TRACE_SAMPLE", "-0.3")
-        assert TraceConfig.from_env().sample == 0.0
-        monkeypatch.setenv("TORCHFT_TRACE_SAMPLE", "half")
-        assert TraceConfig.from_env().sample == 1.0
+        assert TraceConfig.from_env().buffer == 65536
 
     def test_dump_dir(self, monkeypatch, tmp_path):
         monkeypatch.setenv("TORCHFT_TRACE_DIR", str(tmp_path))
         assert TraceConfig.from_env().dump_dir == str(tmp_path)
-
-
-class TestStepSampled:
-    def test_extremes(self):
-        assert all(step_sampled(s, 1.0) for s in range(100))
-        assert not any(step_sampled(s, 0.0) for s in range(100))
-
-    def test_deterministic_and_roughly_proportional(self):
-        # identical on every call (no RNG) — the property that keeps all
-        # replicas keeping/dropping the SAME steps
-        first = [step_sampled(s, 0.5) for s in range(10000)]
-        second = [step_sampled(s, 0.5) for s in range(10000)]
-        assert first == second
-        frac = sum(first) / len(first)
-        assert 0.4 < frac < 0.6, frac
 
 
 # ----------------------------------------------------------------- recorder
@@ -170,18 +144,6 @@ class TestSpanRecorder:
         rec.instant("y", cat="rpc")
         rec.record_rel("z", cat="allreduce", t0_pc=0.0, t1_pc=1.0)
         assert rec.stats() == {"spans": 0.0, "recorded": 0.0, "dropped": 0.0}
-
-    def test_sampling_follows_step_sampled(self):
-        sample = 0.5
-        on = next(s for s in range(100) if step_sampled(s, sample))
-        off = next(s for s in range(100) if not step_sampled(s, sample))
-        rec = SpanRecorder("samp", _cfg(sample=sample))
-        rec.set_context(step=off)
-        rec.instant("dropped_by_sampling", cat="rpc")
-        rec.set_context(step=on)
-        rec.instant("kept", cat="rpc")
-        spans = rec.export()["spans"]
-        assert [s["name"] for s in spans] == ["kept"]
 
     def test_record_rel_anchors_to_wall_clock(self):
         rec = SpanRecorder("rel", _cfg())
@@ -243,6 +205,193 @@ class TestSpanRecorder:
         rec = SpanRecorder("safe", _cfg())
         # target is a directory -> open() fails -> None, no exception
         assert rec.dump(tmp_path) is None
+
+
+class _Handle:
+    """What ``when_ready`` takes: ready when the test says (or from the
+    start), or failing like a donated array."""
+
+    def __init__(self, ready=False, raises=False):
+        self._ready = threading.Event()
+        self.raises = raises
+        if ready:
+            self._ready.set()
+
+    def set(self):
+        self._ready.set()
+
+    def is_ready(self):
+        if self.raises:
+            raise RuntimeError("Array has been deleted.")
+        return self._ready.is_set()
+
+    def block_until_ready(self):
+        if self.raises:
+            raise RuntimeError("Array has been deleted.")
+        assert self._ready.wait(10)
+
+
+def _milestones(rec, n, timeout=10.0):
+    """The ``device/*`` spans, once the watcher has recorded ``n``."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        got = [s for s in rec.export()["spans"] if s["cat"] == "device"]
+        if len(got) >= n:
+            return got
+        time.sleep(0.005)
+    raise AssertionError(f"{len(got)} of {n} milestones after {timeout} s")
+
+
+class TestDeviceMilestones:
+    """``when_ready``: one watcher thread a recorder blocks on the handles
+    in the order they were registered."""
+
+    def test_fifo_order_registering_step_and_the_chain_through_an_instant(self):
+        rec = SpanRecorder("dev", _cfg())
+        fwd, landed, upd = _Handle(), _Handle(), _Handle()
+        rec.set_context(quorum_id=4, step=7)
+        with rec.span("grad_dispatch", cat="trainer") as host:
+            rec.when_ready("forward", "device", fwd, segment=0)
+        t_fwd_registered = time.time_ns() // 1000
+        rec.when_ready("landed", "device", landed, span=False, parent=99,
+                       bucket=2, segment=0)
+        rec.set_context(step=8)  # the commit: the update is the next number's
+        rec.when_ready("update", "device", upd)
+        # the device's order is the order of registration: set them so
+        for h in (fwd, landed, upd):
+            time.sleep(0.02)
+            h.set()
+        got = _milestones(rec, 3)
+        rec.close()
+        assert [(s["name"], s["step"]) for s in got] == [
+            ("forward", 7), ("landed", 7), ("update", 8)]
+        f, l, u = got
+        assert f["parent"] == host.id and l["parent"] == 99 and u["parent"] is None
+        assert f["quorum_id"] == 4
+        assert f["args"]["segment"] == 0 and l["args"]["bucket"] == 2
+        # nothing was pending when forward was registered: from there
+        assert abs(f["ts_us"] - t_fwd_registered) < 5_000
+        assert 15_000 < f["dur_us"] < 2_000_000
+        assert all(s["args"]["late"] == 0 and s["args"]["waited_us"] > 5_000
+                   for s in got)
+        # an instant covers nothing and is a link all the same: the update
+        # reaches from the landing's readiness, not from the forward's
+        assert l["dur_us"] == 1
+        assert l["ts_us"] >= f["ts_us"] + f["dur_us"]
+        assert u["ts_us"] == l["ts_us"]
+        assert 15_000 < u["dur_us"] < 2_000_000
+
+    def test_late_is_a_handle_that_was_ready_when_the_watcher_came(self):
+        rec = SpanRecorder("late", _cfg())
+        first, second = _Handle(), _Handle(ready=True)
+        rec.when_ready("backward", "device", first, segment=1)
+        rec.when_ready("backward", "device", second, segment=2)
+        time.sleep(0.02)
+        first.set()
+        one, two = _milestones(rec, 2)
+        rec.close()
+        assert (one["args"]["late"], two["args"]["late"]) == (0, 1)
+        assert two["args"]["waited_us"] < 5_000
+        # still a link: it starts where the one before it ended
+        assert two["ts_us"] == one["ts_us"] + one["dur_us"]
+
+    def test_a_span_starts_at_its_registration_if_nothing_was_pending(self):
+        rec = SpanRecorder("idle", _cfg())
+        rec.when_ready("update", "device", _Handle(ready=True))
+        (upd,) = _milestones(rec, 1)
+        time.sleep(0.05)  # the device has nothing of ours: no span over it
+        t_reg = time.time_ns() // 1000
+        fwd = _Handle()
+        rec.when_ready("forward", "device", fwd)
+        fwd.set()
+        got = _milestones(rec, 2)
+        rec.close()
+        assert got[1]["ts_us"] >= upd["ts_us"] + upd["dur_us"] + 40_000
+        assert abs(got[1]["ts_us"] - t_reg) < 5_000
+
+    def test_a_handle_that_raises_is_dropped_and_the_chain_starts_anew(self):
+        rec = SpanRecorder("raise", _cfg())
+        before, after = _Handle(), _Handle()
+        rec.when_ready("forward", "device", before)
+        rec.when_ready("backward", "device", _Handle(raises=True))
+        before.set()
+        _milestones(rec, 1)
+        time.sleep(0.03)
+        t_reg = time.time_ns() // 1000
+        rec.when_ready("update", "device", after)
+        after.set()
+        got = _milestones(rec, 2)
+        rec.close()
+        assert [s["name"] for s in got] == ["forward", "update"]
+        assert abs(got[1]["ts_us"] - t_reg) < 5_000  # not from forward's end
+
+    def test_close_with_handles_pending_ends_the_thread_and_drops_them(self):
+        rec = SpanRecorder("close", _cfg())
+        held, never = _Handle(), _Handle()
+        rec.when_ready("forward", "device", held)
+        rec.when_ready("backward", "device", never)
+        watcher = rec._watcher
+        assert watcher.is_alive() and watcher.daemon
+        threading.Timer(0.05, held.set).start()
+        rec.close()  # waits a moment for the handle being watched
+        watcher.join(5)
+        assert not watcher.is_alive()
+        assert rec._watched.empty()
+        assert [s["name"] for s in rec.export()["spans"]] == ["forward"]
+        rec.when_ready("update", "device", never)  # registers nothing now
+        assert rec._watcher is None and rec._watched.empty()
+        never.set()
+        rec.instant("still_records", cat="rpc")
+        assert len(rec.export()["spans"]) == 2
+
+    def test_a_disabled_recorder_has_no_watcher_and_keeps_no_handle(self):
+        import sys
+
+        rec = SpanRecorder("off", _cfg(enabled=False))
+        handle = _Handle()
+        refs = sys.getrefcount(handle)
+        rec.when_ready("forward", "device", handle)
+        assert rec._watcher is None and rec._watched.empty()
+        assert sys.getrefcount(handle) == refs
+        assert not any(t.name == "torchft_trace_watch"
+                       for t in threading.enumerate())
+        rec.close()
+
+    def test_the_watcher_lets_go_of_a_handle_once_it_is_ready(self):
+        import weakref
+
+        rec = SpanRecorder("drop", _cfg())
+        handle = _Handle(ready=True)
+        gone = weakref.ref(handle)
+        rec.when_ready("forward", "device", handle)
+        del handle
+        _milestones(rec, 1)
+        deadline = time.monotonic() + 5
+        while gone() is not None and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert gone() is None  # while the watcher waits for its next handle
+        rec.close()
+
+
+class TestDefaultRing:
+    def test_it_holds_500_steps_of_a_five_op_step(self):
+        """The densest benchmark cell's step: 13 spans of the step itself,
+        4 an allreduce and 10 a bucket (docs/observability.md's tree), the
+        watcher's forward, four backward, a landed a bucket and the update:
+        a 48 s window is 82 such steps."""
+        rec = SpanRecorder("full", TraceConfig())
+        a_step = 13 + 5 * 4 + 5 * 10 + 1 + 4 + 5 + 1
+        for step in range(500):
+            rec.set_context(step=step)
+            for i in range(a_step):
+                rec.instant("span", cat="allreduce", bucket=i, segment=i % 5)
+        stats = rec.stats()
+        assert stats["recorded"] == 500 * a_step == 47_000
+        assert stats["dropped"] == 0.0
+        spans = rec.export()["spans"]
+        assert len(spans) == 47_000 and spans[0]["step"] == 0
+        assert set(spans[0]) == {"name", "cat", "ts_us", "dur_us", "quorum_id",
+                                 "step", "id", "parent", "args"}
 
 
 class TestSpanTree:
@@ -864,30 +1013,33 @@ def test_fleet_chaos_merge_produces_skew_corrected_timeline(tmp_path):
         )
 
 
-def test_profiler_trace_of_the_managed_trainer_holds_the_programs_spans(tmp_path):
-    """The program's spans are in the profiler's own trace: a
-    ``jax.profiler.trace`` over the managed trainer (examples/
-    train_llama_hsdp.py under a lighthouse, three tiny steps on the CPU, no
-    benchmark harness) holds the bucket pipeline's and the trainer's
-    annotations under the names the benchmark gives the ring's spans. The
-    same run's SUMMARY carries the start-up and first-step timings, and its
-    ring dropped nothing at the default buffer."""
+@pytest.fixture(scope="module")
+def profiled_trainer(tmp_path_factory):
+    """The managed trainer (examples/train_llama_hsdp.py under a lighthouse,
+    three tiny steps on the CPU, no benchmark harness) under a
+    ``jax.profiler.trace``, its span ring dumped as its Manager shuts down:
+    ``(finished process, profile directory, the ring's dump)``."""
     import os
     import subprocess
     import sys
 
-    from jax.profiler import ProfileData
-
     from torchft_tpu.coordination import LighthouseServer
 
+    tmp_path = tmp_path_factory.mktemp("profiled")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     trainer = os.path.join(root, "examples", "train_llama_hsdp.py")
     script = tmp_path / "profiled.py"
     script.write_text(
         "import runpy, sys\n"
         "import jax\n"
+        "from torchft_tpu.manager import Manager\n"
         "out, trainer = sys.argv[1], sys.argv[2]\n"
         "sys.argv = [trainer, *sys.argv[3:]]\n"
+        "shutdown = Manager.shutdown\n"
+        "def dump_then_shutdown(self, *a, **kw):\n"
+        "    self.dump_trace(out + '.ring.json')\n"
+        "    return shutdown(self, *a, **kw)\n"
+        "Manager.shutdown = dump_then_shutdown\n"
         "opts = jax.profiler.ProfileOptions()\n"
         "opts.python_tracer_level = 0\n"
         "jax.profiler.start_trace(out, profiler_options=opts)\n"
@@ -913,7 +1065,72 @@ def test_profiler_trace_of_the_managed_trainer_holds_the_programs_spans(tmp_path
     finally:
         lh.shutdown()
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    (pb,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    with open(str(tmp_path / "prof") + ".ring.json") as f:
+        ring = json.load(f)
+    return proc, tmp_path / "prof", ring
+
+
+def test_the_managed_trainer_leaves_the_devices_chain_in_its_ring(profiled_trainer):
+    """What the device finished, and when, in a step of the real trainer
+    (two ops a step at the tiny preset): one ``device/forward``, one
+    ``device/backward``, a ``device/landed`` a bucket and one
+    ``device/update``, in that order on the clock, and each inside its
+    ``trainer/step`` by its ancestors (the update's end is the device's, and
+    may lie past the step's: the loop does not wait for it)."""
+    _proc, _prof, ring = profiled_trainer
+    spans = ring["spans"]
+    by_id = {s["id"]: s for s in spans}
+    dev = [s for s in spans if s["cat"] == "device"]
+    steps = [s for s in spans if (s["cat"], s["name"]) == ("trainer", "step")]
+    assert len(steps) == 3
+
+    def step_of(s):
+        while s is not None and (s["cat"], s["name"]) != ("trainer", "step"):
+            s = by_id.get(s["parent"])
+        return s
+
+    for n, step in enumerate(sorted(steps, key=lambda s: s["ts_us"])):
+        # in the order the watcher recorded them: that of registration
+        mine = sorted((s for s in dev if step_of(s) is step),
+                      key=lambda s: s["id"])
+        buckets = [s for s in spans if s["name"] == "unpack"
+                   and step_of(s) is step]
+        assert len(buckets) >= 4
+        chain = ["forward", "backward"] + ["landed"] * len(buckets) + ["update"]
+        assert sorted(s["name"] for s in mine) == sorted(chain)
+        assert [s["ts_us"] + s["dur_us"] for s in mine] == sorted(
+            s["ts_us"] + s["dur_us"] for s in mine)
+        if n == 0:
+            # a compile between two dispatches: the head's buckets may land
+            # before the layers' program is even enqueued
+            continue
+        assert [s["name"] for s in mine] == chain
+        fwd, bwd, upd = mine[0], mine[1], mine[-1]
+        assert fwd["ts_us"] >= step["ts_us"]
+        assert bwd["ts_us"] == fwd["ts_us"] + fwd["dur_us"]  # a chain
+        assert upd["ts_us"] >= bwd["ts_us"] + bwd["dur_us"]
+        assert all(s["dur_us"] == 1 for s in mine[2:-1])
+        # the registering step: the update follows the commit
+        assert {s["step"] for s in mine[:-1]} == {step["step"]}
+        assert upd["step"] == step["step"] + 1
+    assert len(dev) == sum(len([s for s in dev if step_of(s) is st])
+                           for st in steps)
+    assert ring["dropped"] == 0
+
+
+def test_profiler_trace_of_the_managed_trainer_holds_the_programs_spans(
+    profiled_trainer,
+):
+    """The program's spans are in the profiler's own trace: a
+    ``jax.profiler.trace`` over the managed trainer holds the bucket
+    pipeline's and the trainer's annotations under the names the benchmark
+    gives the ring's spans. The same run's SUMMARY carries the start-up and
+    first-step timings, and its ring dropped nothing at the default
+    buffer."""
+    from jax.profiler import ProfileData
+
+    proc, prof, _ring = profiled_trainer
+    (pb,) = prof.glob("plugins/profile/*/*.xplane.pb")
     names = {}
     for plane in ProfileData.from_file(str(pb)).planes:
         if plane.name.startswith("/host:"):

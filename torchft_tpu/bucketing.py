@@ -1368,9 +1368,21 @@ class BucketPipeline:
             ):
                 captured = capture(leaves, plan, self._pool)
             op.leaves = [leaf_stand_in(l) for l in leaves]
-            op.tally.newest = next(
+            newest = next(
                 (c for c in reversed(captured) if isinstance(c, Pieces)),
-                op.tally.newest)
+                None)
+            if newest is not None:
+                op.tally.newest = newest
+                if newest.arrays:
+                    # the device's milestone of this op: its last piece is
+                    # ready once the program that made these gradients and
+                    # the split are done (segment 0: the forward pass and
+                    # the head; later: that segment's backward pass). The
+                    # watcher drops the piece then, before its fetch can end
+                    self._tracer.when_ready(
+                        "backward" if segment else "forward", "device",
+                        newest.arrays[-1], parent=parent, segment=segment,
+                    )
         # Non-float buckets ride uncompressed — the decision depends only
         # on the shared plan + mode, so it is SPMD-consistent across
         # replicas. Non-participants compress their zero contribution too
@@ -1558,6 +1570,7 @@ class BucketPipeline:
         leak a partially-applied reduction."""
         try:
             t0u = time.perf_counter()
+            tokens: Optional[List[Any]] = None
             # a PG that hands its input back as its result (a world of
             # one, given a donated buffer): the landed leaves may be views
             # of, or transfers still reading, the staging buffer
@@ -1603,29 +1616,49 @@ class BucketPipeline:
                 # the buffer through, once what landed from it has read it
                 if passed_through:
                     with span("recycle", leaves=len(pairs)):
-                        self._recycle(pooled_buf, [v for _, v in pairs])
+                        tokens = self._recycle(
+                            pooled_buf, [v for _, v in pairs])
                 else:
                     self._pool.release(pooled_buf)
+            if self._tracer.enabled and not self.device_native:
+                # the device's milestone of this bucket (an instant: a link
+                # of the watcher's chain): its leaves, divided, are in HBM.
+                # Tokens are dispatched in leaf order, so the last answers
+                # for the bucket; where recycling made none, one of this
+                # line's own on the last landed device leaf (the caller's
+                # update donates the leaf itself)
+                token = tokens[-1] if tokens else next(
+                    (_landed_token()(v) for _, v in reversed(pairs)
+                     if lives_on_device(v)), None)
+                if token is not None:
+                    self._tracer.when_ready(
+                        "landed", "device", token, span=False,
+                        parent=op.stage_ids[i]["unpack"], bucket=i,
+                        segment=op.segment,
+                    )
             op.marks[i]["unpack"] = (t0u, time.perf_counter())
             _settle(op.bucket_futs[i], pairs)
         except Exception as e:  # noqa: BLE001
             _settle(op.bucket_futs[i], exc=e)
 
-    def _recycle(self, buf: np.ndarray, landed: Sequence[Any]) -> None:
+    def _recycle(
+        self, buf: np.ndarray, landed: Sequence[Any]
+    ) -> Optional[List[Any]]:
         """``buf``, which its collective handed back as the result, has
         landed as ``landed``: into the pool now if nothing reads it any
-        more, parked while a transfer may (:func:`readers_of`), dropped if a
-        landed leaf is a slice of it. Before the bucket's future settles,
-        while the leaves are still the pipeline's alone; it dispatches and
-        does not wait."""
+        more, parked while a transfer may (:func:`readers_of`, whose tokens
+        it returns), dropped if a landed leaf is a slice of it. Before the
+        bucket's future settles, while the leaves are still the pipeline's
+        alone; it dispatches and does not wait."""
         tokens = readers_of(buf, landed)
         if tokens is None:
-            return
+            return None
         if not tokens:
             self._pool.release(buf)
-            return
+            return tokens
         with self._parked_lock:
             self._parked.append((buf, tokens))
+        return tokens
 
     def _sweep_parked(self) -> None:
         """Give back to the pool every parked buffer whose landed leaves

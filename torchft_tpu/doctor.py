@@ -326,7 +326,6 @@ def check_trace_env() -> Result:
         TRACE_BUFFER_ENV,
         TRACE_DIR_ENV,
         TRACE_ENV,
-        TRACE_SAMPLE_ENV,
         TraceConfig,
     )
 
@@ -344,19 +343,6 @@ def check_trace_env() -> Result:
                 f"{TRACE_BUFFER_ENV}={buf} below the floor of 16 — clamped; "
                 "a ring that small drops most of a step's spans"
             )
-    raw_sample = os.environ.get(TRACE_SAMPLE_ENV, "")
-    if raw_sample:
-        try:
-            sample = float(raw_sample)
-        except ValueError:
-            return False, (
-                f"{TRACE_SAMPLE_ENV}={raw_sample!r} is not a float — the "
-                "Manager silently falls back to sampling every step"
-            )
-        if not 0.0 <= sample <= 1.0:
-            return None, (
-                f"{TRACE_SAMPLE_ENV}={sample} outside [0, 1] — clamped"
-            )
     cfg = TraceConfig.from_env()
     if cfg.dump_dir:
         try:
@@ -371,7 +357,7 @@ def check_trace_env() -> Result:
                 "postmortem trace auto-dumps will be lost"
             )
     detail = (
-        f"enabled={cfg.enabled} buffer={cfg.buffer} sample={cfg.sample} "
+        f"enabled={cfg.enabled} buffer={cfg.buffer} "
         f"dump_dir={cfg.dump_dir or '(flight-recorder fallback)'}"
     )
     if not cfg.enabled:

@@ -128,10 +128,8 @@ REGISTRY: Dict[str, Knob] = {
         # ------------------------------------------------ observability
         _k("TORCHFT_TRACE", "bool", "1", "observability.md#span-taxonomy",
            "trace-env", "Span recorder on/off (on by default, <1% overhead)."),
-        _k("TORCHFT_TRACE_BUFFER", "int", "4096", "observability.md#span-taxonomy",
+        _k("TORCHFT_TRACE_BUFFER", "int", "65536", "observability.md#span-taxonomy",
            "trace-env", "Span ring capacity (floor 16; overflow is counted)."),
-        _k("TORCHFT_TRACE_SAMPLE", "float", "1.0", "observability.md#span-taxonomy",
-           "trace-env", "Fraction of steps traced (deterministic by step hash)."),
         _k("TORCHFT_TRACE_DIR", "str", "", "observability.md#span-taxonomy",
            "trace-env", "Trace dump directory (empty = beside flight-recorder dumps)."),
         _k("TORCHFT_METRICS_PORT", "int", "", "observability.md#metrics-reference",

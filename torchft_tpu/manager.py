@@ -2884,6 +2884,7 @@ class Manager:
         # a PG that is shut down
         self._pipeline.shutdown(wait=wait)
         self._pg.shutdown()
+        self._tracer.close()  # the watcher thread; the ring stays readable
         # best-effort: land any commit/timing events still queued in the
         # async drain before the process (and its log handlers) go away
         try:

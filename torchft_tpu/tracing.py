@@ -13,13 +13,17 @@ stalled bucket 7 of step 412" across a fleet. This module closes that gap:
   step correlate without a global clock, and ``id`` / ``parent`` so one
   step's spans form a tree (a thread-local stack gives the parent on one
   thread; across threads the parent's id travels in the closure).
-  Recording is an O(1) dict append behind one lock — cheap enough to stay
-  on by default (PERF.md section 6, PR 24: ``TORCHFT_TRACE=0`` against
-  the default on the chip). A context span also enters a
+  Recording is an O(1) tuple append behind one lock — cheap enough to stay
+  on by default (PERF.md section 6, PR 24 and PR 37: ``TORCHFT_TRACE=0``
+  against the default on the chip). A context span also enters a
   ``jax.profiler.TraceAnnotation`` named ``manager.<cat>.<name>``, so the
   program's spans lie in a ``jax.profiler.trace`` on the device trace's
   own clock; free while no profiler session runs, and this module never
   imports jax itself (a process without jax records to the ring alone).
+  :meth:`SpanRecorder.when_ready` adds what no host thread's work shows:
+  the moment the DEVICE finished something, taken by one watcher thread a
+  recorder that blocks on the registered handles in the order they were
+  registered, each span reaching from the handle before it.
 - **Skew correction** — each export stamps the replica's clock-skew
   estimate vs the lighthouse (``ManagerServer.clock_skew()``: the beat
   loop's RPC round-trip midpoint minus the response ``server_ms`` —
@@ -41,9 +45,9 @@ stalled bucket 7 of step 412" across a fleet. This module closes that gap:
 Env knobs (read once per Manager via :meth:`TraceConfig.from_env`):
 
 - ``TORCHFT_TRACE``: ``1``/``0`` — master switch (default on).
-- ``TORCHFT_TRACE_BUFFER``: ring capacity in spans (default 4096).
-- ``TORCHFT_TRACE_SAMPLE``: fraction of steps traced, deterministic by
-  step hash so all replicas keep/drop the SAME steps (default 1.0).
+- ``TORCHFT_TRACE_BUFFER``: ring capacity in spans (default 65536: the
+  densest benchmark cell's 48 s window eight times over; a full ring is
+  tens of MB, CHANGES.md, PR 37).
 - ``TORCHFT_TRACE_DIR``: auto-dump directory; empty falls back next to
   the flight-recorder dump path (``TORCHFT_FR_BASE_PATH``).
 """
@@ -53,6 +57,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import queue
 import sys
 import threading
 import time
@@ -60,14 +65,13 @@ import weakref
 from collections import deque
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Deque, Dict, Iterable, List, Optional
+from typing import Any, Deque, Dict, Iterable, List, Optional, Tuple
 
 TRACE_ENV = "TORCHFT_TRACE"
 TRACE_BUFFER_ENV = "TORCHFT_TRACE_BUFFER"
-TRACE_SAMPLE_ENV = "TORCHFT_TRACE_SAMPLE"
 TRACE_DIR_ENV = "TORCHFT_TRACE_DIR"
 
-_DEFAULT_BUFFER = 4096
+_DEFAULT_BUFFER = 1 << 16
 
 __all__ = [
     "TraceConfig",
@@ -120,7 +124,6 @@ def _offset_ms_for(replica_id: str) -> float:
 class TraceConfig:
     enabled: bool = True
     buffer: int = _DEFAULT_BUFFER
-    sample: float = 1.0
     dump_dir: str = ""
 
     @classmethod
@@ -133,26 +136,8 @@ class TraceConfig:
             cfg.buffer = max(16, int(os.environ.get(TRACE_BUFFER_ENV, "")))
         except ValueError:
             cfg.buffer = _DEFAULT_BUFFER
-        try:
-            cfg.sample = min(
-                1.0, max(0.0, float(os.environ.get(TRACE_SAMPLE_ENV, "")))
-            )
-        except ValueError:
-            cfg.sample = 1.0
         cfg.dump_dir = os.environ.get(TRACE_DIR_ENV, "")
         return cfg
-
-
-def step_sampled(step: int, sample: float) -> bool:
-    """Deterministic per-step sampling decision, identical on every
-    replica (Knuth multiplicative hash — no RNG, no cross-replica skew in
-    WHICH steps are kept, so sampled steps still merge into full fleet
-    timelines)."""
-    if sample >= 1.0:
-        return True
-    if sample <= 0.0:
-        return False
-    return ((step * 2654435761) % (1 << 32)) / float(1 << 32) < sample
 
 
 # ----------------------------------------------------------------- recorder
@@ -295,6 +280,12 @@ def _watch_compiles(rec: "SpanRecorder") -> None:
         _compile_listener_on = True
 
 
+# a span's keys in a dump, in the order its tuple in the ring holds them;
+# the tuple's last element is ``args`` (a dump has the key only if non-empty)
+_SPAN_KEYS = ("name", "cat", "ts_us", "dur_us", "quorum_id", "step", "id",
+              "parent")
+
+
 class SpanRecorder:
     """Bounded ring of structured spans for ONE replica.
 
@@ -312,11 +303,13 @@ class SpanRecorder:
     ) -> None:
         self._replica_id = replica_id
         self._config = config if config is not None else TraceConfig.from_env()
-        self._spans: Deque[Dict[str, Any]] = deque(maxlen=self._config.buffer)
+        # a span is a tuple in the order of _SPAN_KEYS with its args dict
+        # (or None) last: 400-420 bytes a span at a full ring where a dict
+        # of the keys took 605; export() gives it its keys back
+        self._spans: Deque[Tuple[Any, ...]] = deque(maxlen=self._config.buffer)
         self._lock = threading.Lock()
         self._quorum_id: Optional[int] = None
         self._step: Optional[int] = None
-        self._step_on = True  # sampling decision for the current step
         self._skew_ms = 0.0
         self._rtt_ms = 0.0
         self._skew_samples = 0
@@ -327,6 +320,11 @@ class SpanRecorder:
         # seconds of backend compiles this process has made since the
         # recorder was built (compile_total_s())
         self._compile_s = 0.0
+        # when_ready: the handles the watcher thread has not taken yet, and
+        # the thread, started with the first of them
+        self._watched: "queue.SimpleQueue[Any]" = queue.SimpleQueue()
+        self._watcher: Optional[threading.Thread] = None
+        self._closed = False
         if self._config.enabled:
             _watch_compiles(self)
 
@@ -344,14 +342,12 @@ class SpanRecorder:
         quorum_id: Optional[int] = None,
         step: Optional[int] = None,
     ) -> None:
-        """Update the ``(quorum_id, step)`` stamped into subsequent spans;
-        re-evaluates the per-step sampling decision on a step change."""
+        """Update the ``(quorum_id, step)`` stamped into subsequent spans."""
         with self._lock:
             if quorum_id is not None:
                 self._quorum_id = quorum_id
-            if step is not None and step != self._step:
+            if step is not None:
                 self._step = step
-                self._step_on = step_sampled(step, self._config.sample)
 
     def set_skew(
         self, skew_ms: float, rtt_ms: float = 0.0, samples: int = 0
@@ -397,24 +393,15 @@ class SpanRecorder:
         if not self._config.enabled:
             return
         with self._lock:
-            if not self._step_on:
-                return
             if len(self._spans) == self._spans.maxlen:
                 self._dropped += 1
             self._recorded += 1
-            span: Dict[str, Any] = {
-                "name": name,
-                "cat": cat,
-                "ts_us": ts_us,
-                "dur_us": dur_us,
-                "quorum_id": self._quorum_id,
-                "step": self._step if step is _CURRENT else step,
-                "id": span_id if span_id is not None else next(self._ids),
-                "parent": parent,
-            }
-            if args:
-                span["args"] = args
-            self._spans.append(span)
+            self._spans.append((
+                name, cat, ts_us, dur_us, self._quorum_id,
+                self._step if step is _CURRENT else step,
+                span_id if span_id is not None else next(self._ids),
+                parent, args or None,
+            ))
 
     def span(
         self,
@@ -474,6 +461,104 @@ class SpanRecorder:
         """Zero-duration marker (RPC retry, reroute, heal chunk events)."""
         self._append(name, cat, self._now_us(), 1, args)
 
+    def when_ready(
+        self,
+        name: str,
+        cat: str,
+        handle: Any,
+        span: bool = True,
+        parent: Optional[int] = None,
+        **args: Any,
+    ) -> None:
+        """A milestone of the DEVICE: record ``cat/name`` once ``handle``
+        (anything with ``block_until_ready()``, e.g. a jax.Array; its
+        ``is_ready()`` is asked too where it has one) is ready. Returns at
+        once: one daemon thread a recorder, started here at first use, takes
+        the handles in the order they were registered and blocks on each, so
+        register them in the order the device reaches them. The handles make
+        a chain. ``span=True``: a ring span from the moment the handle
+        registered BEFORE this one became ready, or from this registration
+        if that was earlier still (nothing was pending: the device had
+        nothing of ours to do), to this one's readiness: what the device did
+        between the two. ``span=False``: an instant at readiness, a link of
+        the chain all the same. Both carry the step and the span open on
+        this thread (``parent`` overrides it) at REGISTRATION, ``waited_us``
+        (how long the watcher blocked) and ``late`` (1: the handle was ready
+        when the watcher reached it, so the stamp is an upper bound: the
+        order of registration was not the device's, or the watcher was
+        behind). A handle that raises (a donated array, a failed program) is
+        dropped and the chain starts anew. The watcher lets go of a handle
+        the moment it is ready. A disabled recorder keeps no reference and
+        starts no thread."""
+        if not self._config.enabled:
+            return
+        if parent is None:
+            parent = self.current()
+        with self._lock:
+            if self._closed:
+                return
+            if self._watcher is None:
+                self._watcher = threading.Thread(
+                    target=self._watch, name="torchft_trace_watch", daemon=True
+                )
+                self._watcher.start()
+            step = self._step
+        self._watched.put(
+            (name, cat, handle, span, parent, args, step, self._now_us())
+        )
+
+    def _watch(self) -> None:
+        """The watcher thread: see :meth:`when_ready`."""
+        ready_us: Optional[int] = None  # when the chain's last link was ready
+        while True:
+            item = self._watched.get()
+            if item is None:
+                return
+            name, cat, handle, span, parent, args, step, registered_us = item
+            del item
+            try:
+                late = int(getattr(handle, "is_ready", bool)())
+                t0 = time.perf_counter()
+                handle.block_until_ready()
+                waited_us = int((time.perf_counter() - t0) * 1e6)
+            except Exception:  # noqa: BLE001 — whatever the handle raises
+                ready_us = None
+                continue
+            finally:
+                del handle
+            now_us = self._now_us()
+            args.update(waited_us=waited_us, late=late)
+            if not span:
+                start_us = now_us
+            elif ready_us is None or ready_us < registered_us:
+                start_us = registered_us
+            else:
+                start_us = ready_us
+            self._append(
+                name, cat, start_us, max(now_us - start_us, 1), args, None,
+                parent, step,
+            )
+            ready_us = now_us
+
+    def close(self) -> None:
+        """End the watcher thread (``Manager.shutdown``). Handles it has
+        not taken are dropped unrecorded, and :meth:`when_ready` registers
+        nothing from here on; the ring and every other method stay."""
+        with self._lock:
+            self._closed = True
+            watcher, self._watcher = self._watcher, None
+        if watcher is None:
+            return
+        try:
+            while True:
+                self._watched.get_nowait()
+        except queue.Empty:
+            pass
+        self._watched.put(None)
+        # the handle it may be blocked on is the device's to finish: a
+        # daemon thread, not waited for beyond a moment
+        watcher.join(timeout=1.0)
+
     def _on_compile(self, name: str, dur_s: float, fun: Any) -> None:
         """One backend compile or cache retrieval the process just ended
         (the ``jax.monitoring`` listener's thread: whichever dispatched the
@@ -509,15 +594,22 @@ class SpanRecorder:
     def export(self) -> Dict[str, Any]:
         """One replica's span dump: merge-ready, skew-stamped."""
         with self._lock:
-            return {
+            out = {
                 "replica_id": self._replica_id,
                 "clock": "epoch_us",
                 "skew_ms": self._skew_ms + _offset_ms_for(self._replica_id),
                 "rtt_ms": self._rtt_ms,
                 "skew_samples": self._skew_samples,
                 "dropped": self._dropped,
-                "spans": list(self._spans),
             }
+            spans = list(self._spans)
+        # the dump's format, outside the lock: recording goes on meanwhile
+        out["spans"] = [
+            dict(zip(_SPAN_KEYS, s), args=s[-1]) if s[-1]
+            else dict(zip(_SPAN_KEYS, s))
+            for s in spans
+        ]
+        return out
 
     def dump(self, path: "str | Path | None" = None) -> Optional[Path]:
         """Write :meth:`export` as JSON; never raises (dumps run on

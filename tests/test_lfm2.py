@@ -240,3 +240,32 @@ def test_a_model_of_dense_layers_alone_has_no_bias_leaf_and_no_routing():
     value, stats = lfm2_loss_and_stats(params, tokens, tokens, cfg)
     assert np.isfinite(float(value)) and stats == {}
     assert split_frozen(params, LFM2_FROZEN)[1] == {}
+
+
+def test_the_combines_own_backward_pass_moves_no_bit_of_any_gradient(tiny, monkeypatch):
+    """PR 39: the loss and every gradient leaf of the whole model (biased
+    sigmoid router, remat full) with ``models/moe._combine`` as it is and
+    as autodiff was given it before."""
+    from torchft_tpu.models import moe
+
+    def as_it_was(rows, weights, inverse, order):
+        (T, k), d = weights.shape, rows.shape[-1]
+        picked = moe._take_rows(rows, inverse, order, 1).reshape(T, k, d)
+        return jnp.sum(picked * weights[..., None], axis=1)
+
+    params, tokens = tiny
+    trained, _ = split_frozen(params, LFM2_FROZEN)
+
+    def run():
+        def loss(trained):
+            return lfm2_loss({**params, **trained}, tokens, tokens, DEBUG, remat="full")
+        return jax.jit(jax.value_and_grad(loss))(trained)
+
+    value, grads = run()
+    monkeypatch.setattr(moe, "_combine", as_it_was)
+    value_was, grads_was = run()
+    assert float(value) == float(value_was)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(grads_was)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=jax.tree_util.keystr(path))

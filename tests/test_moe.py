@@ -493,3 +493,39 @@ class TestSigmoidRouterUnderABias:
         np.testing.assert_allclose(np.asarray(jnp.sum(g1, -1)), 1.0, atol=1e-5)
         with pytest.raises(ValueError, match="router_score"):
             dataclasses.replace(SIGMOID_TINY, router_score="tanh")
+
+
+# ---- PR 39: the combine has a backward pass of its own (the rows' cotangent
+# gathered out of the [T, d] cotangent, no [T*k, d] permuted): no bit moves
+
+from torchft_tpu.models import moe as moe_module  # noqa: E402
+
+
+def combine_as_it_was(rows, weights, inverse, order):
+    """What autodiff was given until PR 39: the unsort a ``_take_rows``
+    whose cotangent is gathered at ``order``."""
+    (T, k), d = weights.shape, rows.shape[-1]
+    picked = moe_module._take_rows(rows, inverse, order, 1).reshape(T, k, d)
+    return jnp.sum(picked * weights[..., None], axis=1)
+
+
+@pytest.mark.parametrize("bias", ["none", "small"])
+def test_the_block_and_its_gradients_are_bitwise_what_they_were(monkeypatch, bias):
+    cfg = OLMOE_TINY if bias == "none" else SIGMOID_TINY
+    args = _layer_weights(cfg)
+    target = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def run():
+        def loss(x, router, wg, wu, wd):
+            block = jax.checkpoint(lambda *a: moe_ffn(*a, cfg, bias=BIASES[bias])[0])
+            out = block(x, router, wg, wu, wd)
+            return jnp.sum(out * target), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(*args)
+
+    (_, out), grads = run()
+    monkeypatch.setattr(moe_module, "_combine", combine_as_it_was)
+    (_, out_was), grads_was = run()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_was))
+    for name, g, w in zip(("x", "router", "w_gate"), grads, grads_was):
+        assert float(jnp.abs(w).max()) > 0, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=name)

@@ -144,3 +144,55 @@ def test_the_kernels_compile_for_a_v5e_at_jambas_widths(one_chip, monkeypatch):
     text = compiled.as_text()
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * T * di * 4  # 2.7 GB is 16x
+
+
+def test_the_expert_blocks_small_gathers_find_their_source_in_vmem(one_chip, monkeypatch):
+    """PR 39, compiled for the described v5e at OLMoE's rows (8,192 tokens,
+    8 choices, d 2048; 16 narrow experts keep the compile short): the
+    dispatch's gather and its rematerialised copy each follow a
+    ``take_rows_stage`` and take XLA's fast path (``integer_config`` 0: a
+    source in VMEM); of the five gathers of ``[T*k, d]`` rows a layer runs,
+    only the two permutations are sure to be left on the slow one (128; the
+    combine's backward pass reads ``[T, d]`` too and is fast where XLA
+    prefetches its source, which it is not told to: staging that one as
+    well made XLA's memory-space assignment fail, two staged buffers alive
+    in one backward pass)."""
+    import dataclasses
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.models import moe
+    from torchft_tpu.ops import take_rows
+
+    T, d, k = 8192, 2048, 8
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # gmm: no interpreter
+    monkeypatch.setattr(take_rows, "applies", lambda x: x.shape == (T, d))
+    cfg = dataclasses.replace(moe.MOE_CONFIGS["olmoe_1b_7b"], ffn_hidden=256,
+                              num_experts=16, top_k=k)
+
+    def loss(x, router, wg, wu, wd, target):
+        block = jax.checkpoint(lambda *a: moe.moe_ffn(*a, cfg)[0])
+        return jnp.sum((block(x, router, wg, wu, wd) * target).astype(jnp.float32))
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (sd((1, T, d), jnp.bfloat16), sd((d, 16), jnp.float32),
+            sd((16, d, 256), jnp.bfloat16), sd((16, d, 256), jnp.bfloat16),
+            sd((16, 256, d), jnp.bfloat16), sd((1, T, d), jnp.bfloat16))
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert len(re.findall(r"%take_rows_stage[.\d]* = ", text)) == 2
+    paths = re.findall(
+        rf"= bf16\[{T * k},{d}\]\S* fusion\([^\n]*kind=kCustom[^\n]*?op_name=\"[^\"]*/gather\""
+        r"[^\n]*?\"integer_config\":\{\"integer\":\"(\d+)\"", text)
+    assert len(paths) == 5 and 2 <= paths.count("0") <= 3, paths
+    by_scope = dict(re.findall(
+        rf"= bf16\[{T * k},{d}\]\S* fusion\([^\n]*kind=kCustom[^\n]*?"
+        r"op_name=\"jit\(loss\)/jvp\((moe/\w+)\)/gather\"[^\n]*?\"integer_config\":\{\"integer\":\"(\d+)\"", text))
+    assert by_scope == {"moe/dispatch": "0", "moe/combine": "128"}, by_scope

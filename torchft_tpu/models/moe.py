@@ -58,6 +58,7 @@ from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
 
 from torchft_tpu.models.llama import LlamaConfig, _attention, _rmsnorm, _rope
 from torchft_tpu.models.staged import Stages
+from torchft_tpu.ops.take_rows import take_rows
 
 __all__ = [
     "MoEConfig",
@@ -167,6 +168,25 @@ def moe_init(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
     }
 
 
+def _scores_at(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """scores [T, E], idx [T, k] -> scores[t, idx[t, j]] [T, k], as the
+    maximum over the experts of the one score a mask lets through: exact,
+    its gradient too, and one fused pass where ``take_along_axis`` is XLA's
+    gather of T*k scalars at 10 ns each (0.66 ms a call at OLMoE's shape;
+    PERF.md section 6, PR 39). A maximum and no sum: XLA merges a sum over
+    the experts with the renormalisation's sum over the k choices, and the
+    merged sum adds in another order."""
+    hot = idx[:, :, None] == jnp.arange(scores.shape[1], dtype=idx.dtype)
+    return jnp.max(jnp.where(hot, scores[:, None, :], -jnp.inf), axis=-1)
+
+
+def _counts(idx: jax.Array, num_experts: int) -> jax.Array:
+    """idx [T, k] -> [E] int32: the (token, choice) pairs each expert has
+    (a masked sum again: the scatter-add of T*k ones was 0.57 ms a call)."""
+    hot = idx.reshape(-1, 1) == jnp.arange(num_experts, dtype=idx.dtype)
+    return jnp.sum(hot, axis=0, dtype=jnp.int32)
+
+
 def _choose(
     scores: jax.Array, cfg: MoEConfig, routing: Optional[jax.Array],
     bias: Optional[jax.Array] = None,
@@ -189,7 +209,7 @@ def _choose(
     free = {"routing": top_i[:, :k], "p_kth": top_p[:, k - 1],
             "p_next": top_p[:, -1]}
     idx = free["routing"] if routing is None else routing.astype(jnp.int32)
-    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = _scores_at(scores, idx)
     if cfg.norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + cfg.gate_eps)
     return gates, idx, free
@@ -275,12 +295,13 @@ def _take_rows(x, take, back, fan):
     """``x[take]`` whose backward pass is a gather too: ``back`` lists, for
     each row of ``x`` in turn, the ``fan`` rows of the result that came from
     it (for a permutation, its inverse), so the cotangent is gathered and
-    summed in groups of ``fan`` where XLA would scatter-add."""
-    return x[take]
+    summed in groups of ``fan`` where XLA would scatter-add. The rows move
+    through ``ops/take_rows.py``: on a TPU ``x`` is put into VMEM first."""
+    return take_rows(x, take)
 
 
 def _take_rows_fwd(x, take, back, fan):
-    return x[take], back
+    return take_rows(x, take), back
 
 
 def _take_rows_bwd(fan, back, g):
@@ -291,6 +312,48 @@ def _take_rows_bwd(fan, back, g):
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _permuted(v, back):
+    """``v[at]`` for v [M] and the permutation ``at`` whose inverse is
+    ``back``, as a sort of ``v`` by ``back`` (0.1 ms for 65,536 scalars where
+    XLA's gather of them takes 0.52)."""
+    return jax.lax.sort_key_val(back, v)[1]
+
+
+@jax.custom_vjp
+def _combine(rows, weights, inverse, order):
+    """rows [T*k, d] in expert order, weights [T, k] -> [T, d]: each token's
+    k rows brought back (``rows[inverse]``), weighted and summed over the k
+    choices. ``order`` is ``inverse``'s inverse, for the backward pass: the
+    rows' cotangent is the token's cotangent times the row's weight, so it
+    is gathered from the ``[T, d]`` cotangent at ``order // k`` where
+    autodiff would build the ``[T*k, d]`` products in token order and permute
+    them, and the weights' cotangent is taken against ``rows`` as they lie
+    and its T*k scalars permuted, where autodiff would gather the rows once
+    more. XLA's gather moves rows out of a source that small (it fits the
+    chip's fast memory) five times as fast as it permutes ``[T*k, d]``
+    (PERF.md section 6, PR 39). The same products and sums either way."""
+    (T, k), d = weights.shape, rows.shape[-1]
+    return jnp.sum(rows[inverse].reshape(T, k, d) * weights[..., None], axis=1)
+
+
+def _combine_fwd(rows, weights, inverse, order):
+    return _combine(rows, weights, inverse, order), (rows, weights, inverse, order)
+
+
+def _combine_bwd(saved, g):
+    rows, weights, inverse, order = saved
+    at_rows = g[order // weights.shape[1]]  # [T*k, d]: a row's token's cotangent
+    # the product's own pullback, so that both cotangents are the primitives
+    # autodiff would have emitted (the weights': a reduce_sum in rows' dtype)
+    _, pullback = jax.vjp(lambda r, w: r * w[:, None], rows,
+                          _permuted(weights.reshape(-1), inverse))  # [order]
+    d_rows, d_weights = pullback(at_rows)
+    return d_rows, _permuted(d_weights, order).reshape(weights.shape), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _grouped_matmul(rows, weights, sizes):
@@ -319,7 +382,7 @@ def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
     the k choices. Every shape is static (T*k rows whatever the load); no
     pair is dropped, also when every token picks the same expert.
     ``sizes`` [E] int32: the pairs each expert was given."""
-    (T, d), k = flat.shape, idx.shape[1]
+    T, k = flat.shape[0], idx.shape[1]
     with jax.named_scope("moe/route"):
         expert_of = idx.reshape(T * k)
         order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
@@ -332,8 +395,7 @@ def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
             rows, w_up, sizes)
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
-        picked = _take_rows(rows, inverse, order, 1).reshape(T, k, d)
-        return jnp.sum(picked * gates[..., None].astype(flat.dtype), axis=1)
+        return _combine(rows, gates.astype(flat.dtype), inverse, order)
 
 
 def moe_ffn(
@@ -374,7 +436,7 @@ def moe_ffn(
             unbiased = jax.lax.top_k(probs, cfg.top_k)[1]
             kept = jnp.any(free["routing"][:, :, None] == unbiased[:, None, :], axis=-1)
             free["bias_moved"] = 1.0 - jnp.mean(jnp.all(kept, axis=-1).astype(jnp.float32))
-        sizes = jnp.zeros((cfg.num_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+        sizes = _counts(idx, cfg.num_experts)
     payload = flat.astype(w_gate.dtype)  # the router saw x as it came
     if cfg.capacity_factor is None:
         out = _dropless_ffn(payload, gates, idx, sizes, w_gate, w_up, w_down)

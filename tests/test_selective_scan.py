@@ -196,3 +196,34 @@ def test_the_expert_blocks_small_gathers_find_their_source_in_vmem(one_chip, mon
         rf"= bf16\[{T * k},{d}\]\S* fusion\([^\n]*kind=kCustom[^\n]*?"
         r"op_name=\"jit\(loss\)/jvp\((moe/\w+)\)/gather\"[^\n]*?\"integer_config\":\{\"integer\":\"(\d+)\"", text))
     assert by_scope == {"moe/dispatch": "0", "moe/combine": "128"}, by_scope
+
+
+def test_the_delta_rule_kernels_compile_for_a_v5e_at_lings_widths(one_chip, monkeypatch):
+    """PR 40: Mosaic takes ``ops/kda.py``'s pair at batch 1, T 32,768, 32
+    heads of 128 (the sub-block slices, the in-kernel pullback's transposed
+    products and the 17 MB of its unrolled temporaries are what interpret
+    mode cannot show), and what the pair keeps in HBM beside its arguments
+    and results is the state at every block's start, 256 MB, not one a
+    position (69 GB) nor a [T, 1] column padded to 128 lanes."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    T, H, d = 32768, 32, 128
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = (sd((1, T, H, d), jnp.bfloat16),) * 3 + (
+        sd((1, T, H, d), jnp.float32), sd((1, T, H), jnp.float32))
+    try:
+        grad = jax.grad(lambda *a: jnp.sum(kda.kda(*a).astype(jnp.float32)),
+                        argnums=range(5))
+        compiled = jax.jit(grad).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "kda_fwd" in text and "kda_bwd" in text
+    states = H * (T // kda.BLOCK) * d * d * 4
+    assert states == 2**28 and compiled.memory_analysis().temp_size_in_bytes < 8 * states

@@ -53,10 +53,21 @@ def model_fns(cfg: LlamaConfig) -> ModelFns:
         JambaConfig, jamba_init, jamba_loss_and_stats, jamba_param_specs)
     from torchft_tpu.models.lfm2 import (
         LFM2_FROZEN, Lfm2Config, lfm2_init, lfm2_loss_and_stats, lfm2_param_specs)
+    from torchft_tpu.models.ling import (
+        LING_FROZEN, LingConfig, ling_init, ling_loss_and_stats, ling_param_specs)
     from torchft_tpu.models.llama import llama_stages
     from torchft_tpu.models.moe import (
         MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs, moe_stages)
     from torchft_tpu.parallel.mesh import llama_param_specs
+
+    if isinstance(cfg, LingConfig):  # before MoEConfig: it is one
+        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+            value, stats = ling_loss_and_stats(*args, **kw)
+            return value, {"moe_stats": {"moe_" + k: stats[k] for k in (
+                "load_max_over_mean", "bias_moved_share", "held_pair_share",
+                "overflow_pairs", "groups_hit_mean") if k in stats}}
+
+        return ModelFns(ling_init, loss, ling_param_specs, None, LING_FROZEN)
 
     if isinstance(cfg, Lfm2Config):  # before MoEConfig: it is one
         def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
@@ -104,15 +115,16 @@ def model_fns(cfg: LlamaConfig) -> ModelFns:
 def _register_presets() -> None:
     """``CONFIGS`` is the registry ``--config`` reads: the MoE and the
     hybrid presets stand in it beside the dense ones (an MoEConfig and a
-    JambaConfig are LlamaConfigs, an Lfm2Config an MoEConfig) under their own
+    JambaConfig are LlamaConfigs, an Lfm2Config and a LingConfig MoEConfigs) under their own
     names; ``debug`` is taken, so the MoE one is ``moe_debug``."""
     from torchft_tpu.models.jamba import JAMBA_CONFIGS
     from torchft_tpu.models.lfm2 import LFM2_CONFIGS
+    from torchft_tpu.models.ling import LING_CONFIGS
     from torchft_tpu.models.moe import MOE_CONFIGS
 
     for name, cfg in MOE_CONFIGS.items():
         CONFIGS.setdefault("moe_debug" if name == "debug" else name, cfg)
-    for name, cfg in {**JAMBA_CONFIGS, **LFM2_CONFIGS}.items():
+    for name, cfg in {**JAMBA_CONFIGS, **LFM2_CONFIGS, **LING_CONFIGS}.items():
         CONFIGS.setdefault(name, cfg)
 
 

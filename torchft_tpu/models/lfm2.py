@@ -84,6 +84,19 @@ _PUBLISHED_LAYER_TYPES = tuple(
     "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv" for i in range(24))
 
 
+def runs_of(kinds: List[Tuple[str, str]]) -> List[Tuple[str, Tuple[str, str], int]]:
+    """(mixer, feed-forward) of every layer -> the runs of like layers in
+    order: (the name of the run's stack, its kind, layers). Dense layers of
+    one mixer run together; an expert layer runs alone."""
+    out: List[Tuple[str, Tuple[str, str], int]] = []
+    for kind in kinds:
+        if out and out[-1][1] == kind and kind[1] == "dense":
+            out[-1] = (out[-1][0], kind, out[-1][2] + 1)
+        else:
+            out.append((f"{len(out):02d}_{kind[0]}_{kind[1]}", kind, 1))
+    return out
+
+
 @dataclasses.dataclass(frozen=True)
 class Lfm2Config(MoEConfig):
     # ``ffn_hidden`` is the dense layers' SwiGLU width (``intermediate_size``)
@@ -133,13 +146,7 @@ class Lfm2Config(MoEConfig):
         """Runs of like layers in order: (name of the run's stack under
         ``params["layers"]``, its (mixer, feed-forward), layers). Dense layers
         of one mixer run together; an expert layer runs alone."""
-        out: List[Tuple[str, Tuple[str, str], int]] = []
-        for kind in self.kinds():
-            if out and out[-1][1] == kind and kind[1] == "dense":
-                out[-1] = (out[-1][0], kind, out[-1][2] + 1)
-            else:
-                out.append((f"{len(out):02d}_{kind[0]}_{kind[1]}", kind, 1))
-        return out
+        return runs_of(self.kinds())
 
     def num_params(self) -> int:
         """Every leaf, ``expert_bias`` among them; the tied embedding once."""

@@ -1,0 +1,487 @@
+"""Linear-attention / latent-attention decoder with a share of its routed
+experts (inclusionAI's Ling 3.0 family, ``model_type`` ``bailing_hybrid``) as
+a fifth kind of the one trainer's model: most layers mix the sequence with
+KDA, a gated delta rule with a per-channel decay (``ops/kda.py``), some
+(``layer_types``) with MLA, attention whose keys and values are expanded
+from one normalised low-rank latent; the first ``num_dense_layers`` end in a
+SwiGLU, the others in ``num_experts`` sigmoid-routed experts chosen
+``top_k`` a token inside ``topk_group`` of ``n_group`` groups, beside one
+shared expert.
+
+Every layer: ``h = x + mixer(rmsnorm(x))``, then ``h + ffn(rmsnorm(h))``.
+
+The ``kda`` mixer (H heads of ``kda_head_dim``)::
+
+    q, k, v = silu(conv4(u @ wq)), silu(conv4(u @ wk)), silu(conv4(u @ wv))
+    q, k    = l2norm(q) / sqrt(d_k), l2norm(k)                  # a head at a time
+    g       = kda_lower_bound * sigmoid(exp(A_log) * (u @ w_f + dt_bias))   # (-5, 0)
+    beta    = sigmoid(u @ w_beta)                               # one a head
+    o       = kda(q, k, v, g, beta)                             # ops/kda.py
+    out     = (rmsnorm_head(o) * sigmoid(u @ w_g)) @ wo         # gate: one a head
+
+The ``mla`` mixer: ``q = u @ wq`` split a head into 128 without position
+and 64 rotary; ``c, k_r = split(u @ w_kva)``, ``c`` RMS-normalised, ``k_n, v
+= split(c @ w_kvb)``; RoPE on ``q``'s 64 and on ``k_r`` (which all heads
+share), their stored pairs interleaved (``rope_interleave``); causal
+attention at ``1 / sqrt(192)`` through the dispatcher every kind uses, the
+192-wide queries and keys padded with zeros to 256 (the kernels tile 64, 128
+or 256) and the values left at 128; the same head-wise sigmoid gate; ``wo``.
+
+The expert feed-forward is ``models/moe.py``'s dropless block told what this
+configuration adds to it: the group limit, ``routed_scaling``, the shared
+expert and, where ``held_experts`` says so, that this chip holds a SHARE of
+the experts: the router and the decision over all of them, the held experts'
+part of the sum computed, the rest computed by nobody (``moe_ffn``).
+``expert_bias`` ([expert layers, num_experts] float32, a top-level leaf) is
+state and not a parameter, exactly as ``models/lfm2.py``'s and for its
+reasons: ``model_fns`` names it under ``frozen``, nothing moves it, and it
+is initialised as ``0.01 * normal`` so that a check can see that the
+selection reads it.
+
+The vocabulary may be a slice too (``vocab_size`` rows of embedding and of
+head: ids, logits and loss over the slice); the head is untied. The family's
+multi-token-prediction layer is not built: its published loss weight is 0.
+
+As in ``models/lfm2.py`` the parameters are one stack per RUN of like layers
+(dense layers of one mixer together, an expert layer alone; the names sort
+in layer order) and the forward pass scans each run under one remat policy.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import jax.ad_checkpoint
+import jax.numpy as jnp
+
+from torchft_tpu.models.lfm2 import runs_of
+from torchft_tpu.models.llama import _attention, _rmsnorm, _rope, head_loss
+from torchft_tpu.models.moe import MoEConfig, moe_ffn
+from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.ops.kda import kda
+
+__all__ = [
+    "LingConfig",
+    "LING_CONFIGS",
+    "LING_FROZEN",
+    "ling_init",
+    "ling_hidden",
+    "ling_forward",
+    "ling_loss",
+    "ling_loss_and_stats",
+    "ling_param_specs",
+]
+
+# the top-level leaves that are state and not parameters
+LING_FROZEN = ("expert_bias",)
+BIAS_INIT_SCALE = 0.01
+L2_EPS = 1e-6
+_F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class LingConfig(MoEConfig):
+    # ``ffn_hidden`` is the dense layers' SwiGLU width (``intermediate_size``);
+    # ``n_kv_heads`` is not read: MLA expands keys and values for every head
+    layer_types: Tuple[str, ...] = ()  # "kda" | "mla"
+    num_dense_layers: int = 1
+    kda_head_dim: int = 128  # d_k = d_v of a KDA head; n_heads of them
+    kda_conv: int = 4  # taps of the short convolutions
+    kda_lower_bound: float = -5.0  # ops/kda.py is finite down to -5
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    moe_intermediate_size: int = 768  # one expert's width, the shared one's too
+    num_experts: int = 512
+    top_k: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling: float = 2.5
+    capacity_factor: Optional[float] = None  # dropless
+    aux_loss_weight: float = 0.0
+    norm_topk_prob: bool = True
+    router_score: str = "sigmoid"
+    gate_eps: float = 1e-20
+    loss_chunk: int = 0  # as ``Lfm2Config.loss_chunk``
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if len(self.layer_types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
+                             f"n_layers is {self.n_layers}")
+        other = sorted(set(self.layer_types) - {"kda", "mla"})
+        if other:
+            raise ValueError(f"layer_types {other}: models/ling.py mixes with "
+                             "'kda' or 'mla'")
+        if self.capacity_factor is not None or self.aux_loss_weight:
+            raise ValueError("capacity_factor / aux_loss_weight: the family's "
+                             "expert block drops nothing and has no auxiliary loss")
+        if not 0 <= self.num_dense_layers <= self.n_layers:
+            raise ValueError(f"num_dense_layers={self.num_dense_layers} of "
+                             f"{self.n_layers} layers")
+        if not -5.0 <= self.kda_lower_bound < 0:
+            raise ValueError(f"kda_lower_bound={self.kda_lower_bound}: ops/kda.py "
+                             "takes decays in [-5, 0)")
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.num_dense_layers
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def kinds(self) -> List[Tuple[str, str]]:
+        """(mixer, feed-forward) of every layer: ("kda" | "mla", "dense" |
+        "moe")."""
+        return [(t, "dense" if i < self.num_dense_layers else "moe")
+                for i, t in enumerate(self.layer_types)]
+
+    def runs(self) -> List[Tuple[str, Tuple[str, str], int]]:
+        """Runs of like layers in order, as :meth:`Lfm2Config.runs`."""
+        return runs_of(self.kinds())
+
+    def num_params(self) -> int:
+        """Every leaf this chip holds, ``expert_bias`` among them."""
+        d, H = self.dim, self.n_heads
+        kd = H * self.kda_head_dim
+        mixer = {"kda": 4 * d * kd + kd * d + 2 * d * H + 3 * self.kda_conv * kd
+                        + kd + H + self.kda_head_dim,
+                 "mla": d * H * self.qk_head_dim
+                        + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                        + self.kv_lora_rank
+                        + self.kv_lora_rank * H * (self.qk_nope_head_dim + self.v_head_dim)
+                        + d * H + H * self.v_head_dim * d}
+        one = 3 * d * self.moe_intermediate_size
+        ffn = {"dense": 3 * d * self.ffn_hidden,
+               "moe": (self.n_held + 1) * one + d * self.num_experts + self.num_experts}
+        return (sum(mixer[m] + ffn[f] + 2 * d for m, f in self.kinds())
+                + 2 * self.vocab_size * d + d)
+
+
+LING_CONFIGS: Dict[str, LingConfig] = {
+    # every kind of layer: a dense KDA layer, then KDA, MLA and KDA layers
+    # with a share of 16 experts in 4 groups; bf16 like the published one, so
+    # the float32 routers, decays and bias sit among bf16 leaves in a
+    # trainer's bucket plan. The share has room for every pair: a toy batch
+    # swings far from the even share.
+    "ling_debug": LingConfig(
+        vocab_size=256, dim=64, n_layers=4, n_heads=4, ffn_hidden=128,
+        max_seq_len=128, rope_theta=6e6, norm_eps=1e-6,
+        layer_types=("kda", "kda", "mla", "kda"), num_dense_layers=1,
+        kda_head_dim=16, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, moe_intermediate_size=32, num_experts=16, top_k=4,
+        n_group=4, topk_group=2, held_experts=(4, 4), share_room=4.0,
+    ),
+    # inclusionAI/Ling-3.0-flash, one chip's share of the first seven
+    # published layers in a deployment of sixteen chips a layer: one leading
+    # dense layer, one period KDA KDA KDA MLA KDA KDA of expert layers, 32 of
+    # the 512 experts (the first half of the first group), an eighth of the
+    # vocabulary
+    "ling_3_0_flash_share": LingConfig(
+        vocab_size=19648, dim=2560, n_layers=7, n_heads=32, n_kv_heads=32,
+        ffn_hidden=6144, max_seq_len=131072, rope_theta=6e6, norm_eps=1e-6,
+        layer_types=("kda", "kda", "kda", "kda", "mla", "kda", "kda"),
+        held_experts=(0, 32), share_room=6.0, loss_chunk=2048,
+    ),
+}
+
+
+def ling_init(key: jax.Array, cfg: LingConfig) -> Dict[str, Any]:
+    """Parameter pytree: ``embed``, ``lm_head``, ``final_norm``, ``layers``
+    (one stack per run of like layers, :meth:`LingConfig.runs`; the expert
+    leaves ``[1, held, ...]``, the router ``[1, dim, num_experts]``) and,
+    where there are expert layers, ``expert_bias`` [expert layers,
+    num_experts] float32 (state: ``LING_FROZEN``)."""
+    k_emb, k_head, k_bias, k_layers = jax.random.split(key, 4)
+    d, f, H = cfg.dim, cfg.ffn_hidden, cfg.n_heads
+    kd, E, held, W = H * cfg.kda_head_dim, cfg.num_experts, cfg.n_held, cfg.moe_intermediate_size
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, _F32) / jnp.sqrt(fan_in)).astype(cfg.dtype)
+
+    def mixer(kind, keys, L):
+        if kind == "kda":
+            taps = lambda k: dense(k, (L, cfg.kda_conv, kd), cfg.kda_conv)  # noqa: E731
+            return {"wq": dense(keys[0], (L, d, kd), d), "wk": dense(keys[1], (L, d, kd), d),
+                    "wv": dense(keys[2], (L, d, kd), d), "w_f": dense(keys[3], (L, d, kd), d),
+                    "conv_q": taps(keys[4]), "conv_k": taps(keys[5]), "conv_v": taps(keys[6]),
+                    # the decay's own leaves in float32: they sit in an exponent
+                    "A_log": jnp.log(jax.random.uniform(keys[7], (L, H), _F32, 1.0, 2.0)),
+                    "dt_bias": jax.random.normal(keys[8], (L, kd), _F32) - 3.0,
+                    "w_beta": dense(keys[9], (L, d, H), d),
+                    "o_norm": jnp.ones((L, cfg.kda_head_dim), cfg.dtype),
+                    "w_g": dense(keys[10], (L, d, H), d),
+                    "wo": dense(keys[11], (L, kd, d), kd)}
+        r, hv = cfg.kv_lora_rank, H * cfg.v_head_dim
+        return {"wq": dense(keys[0], (L, d, H * cfg.qk_head_dim), d),
+                "w_kva": dense(keys[1], (L, d, r + cfg.qk_rope_head_dim), d),
+                "kv_norm": jnp.ones((L, r), cfg.dtype),
+                "w_kvb": dense(keys[2], (L, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)), r),
+                "w_g": dense(keys[3], (L, d, H), d),
+                "wo": dense(keys[4], (L, hv, d), hv)}
+
+    def ffn(kind, keys, L):
+        if kind == "dense":
+            return {"w_gate": dense(keys[0], (L, d, f), d),
+                    "w_up": dense(keys[1], (L, d, f), d),
+                    "w_down": dense(keys[2], (L, f, d), f)}
+        return {  # router in f32: its scores drive routing decisions
+            "router": jax.random.normal(keys[3], (L, d, E), _F32) / jnp.sqrt(d),
+            "w_gate": dense(keys[0], (L, held, d, W), d),
+            "w_up": dense(keys[1], (L, held, d, W), d),
+            "w_down": dense(keys[2], (L, held, W, d), W),
+            "shared_gate": dense(keys[4], (L, d, W), d),
+            "shared_up": dense(keys[5], (L, d, W), d),
+            "shared_down": dense(keys[6], (L, W, d), W)}
+
+    def run(key, kind, L):
+        ks = jax.random.split(key, 20)
+        return {"norm": jnp.ones((L, d), cfg.dtype), **mixer(kind[0], ks[:12], L),
+                "ffn_norm": jnp.ones((L, d), cfg.dtype), **ffn(kind[1], ks[12:], L)}
+
+    runs = cfg.runs()
+    params = {
+        "embed": dense(k_emb, (cfg.vocab_size, d), d),
+        "layers": {name: run(k, kind, L) for (name, kind, L), k
+                   in zip(runs, jax.random.split(k_layers, len(runs)))},
+        "final_norm": jnp.ones((d,), cfg.dtype),
+        "lm_head": dense(k_head, (d, cfg.vocab_size), d)}
+    if cfg.n_moe_layers:
+        params["expert_bias"] = BIAS_INIT_SCALE * jax.random.normal(
+            k_bias, (cfg.n_moe_layers, E), _F32)
+    return params
+
+
+def _head_gate(o: jax.Array, u: jax.Array, w_g: jax.Array) -> jax.Array:
+    """o [B,S,H,dv] times the sigmoid of one value a head -> [B,S,H*dv]."""
+    gate = jax.nn.sigmoid(jnp.matmul(u, w_g, preferred_element_type=_F32))
+    return (o * gate.astype(o.dtype)[..., None]).reshape(*o.shape[:2], -1)
+
+
+def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """SiLU of the depthwise causal convolution, ``models/jamba.py``'s
+    ``_causal_conv`` with the sequence padded before it is widened to
+    float32: at 32k three float32 copies of [T, 4096] were 1.5 GB of a
+    layer's backward pass. x [B,T,di], w [k,di] (``w[k-1]`` weighs the
+    current position)."""
+    k, T = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = sum(padded[:, j:j + T].astype(_F32) * w[j].astype(_F32) for j in range(k))
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    x32 = x.astype(_F32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS)
+
+
+def _kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig) -> jax.Array:
+    (B, S, _), H, dk = u.shape, cfg.n_heads, cfg.kda_head_dim
+    heads = lambda m: m.reshape(B, S, H, dk)  # noqa: E731
+    with jax.named_scope("kda/in_proj"):
+        q, k, v = u @ w["wq"], u @ w["wk"], u @ w["wv"]
+    with jax.named_scope("kda/conv"):
+        q, k, v = (_short_conv(m, w[c])
+                   for m, c in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    with jax.named_scope("kda/gate"):
+        # the decay sits in an exponent and sums over positions: float32
+        # from the product on, as the selective scan's step size
+        f = jnp.matmul(u, w["w_f"], preferred_element_type=_F32) + w["dt_bias"]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            heads(f) * jnp.exp(w["A_log"])[:, None])
+        beta = jax.nn.sigmoid(jnp.matmul(u, w["w_beta"], preferred_element_type=_F32))
+        q = (_l2norm(heads(q)) * dk ** -0.5).astype(u.dtype)
+        k = _l2norm(heads(k)).astype(u.dtype)
+    with jax.named_scope("kda/scan"):
+        o = kda(q, k, heads(v), g, beta)
+    with jax.named_scope("kda/out"):
+        return _head_gate(_rmsnorm(o, w["o_norm"], cfg.norm_eps), u, w["w_g"]) @ w["wo"]
+
+
+def _pairs_apart(x: jax.Array) -> jax.Array:
+    """``rope_interleave``: the stored rotary values pair (0, 1), (2, 3)...;
+    -> the first of every pair, then the second, which is how ``_rope``
+    pairs them."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _mla_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig,
+               attention: Any) -> jax.Array:
+    (B, S, _), H = u.shape, cfg.n_heads
+    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                     cfg.kv_lora_rank)
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    rope = lambda m: _rope(_pairs_apart(m), cfg.rope_theta, positions)  # noqa: E731
+    with jax.named_scope("mla/q"):
+        q = (u @ w["wq"]).reshape(B, S, H, dn + dr)
+        q_r = rope(q[..., dn:])
+    with jax.named_scope("mla/kv"):
+        ckr = u @ w["w_kva"]
+        c = _rmsnorm(ckr[..., :r], w["kv_norm"], cfg.norm_eps)
+        k_r = rope(ckr[..., None, r:])  # [B,S,1,dr]: one for all heads
+        kv = (c @ w["w_kvb"]).reshape(B, S, H, dn + dv)
+    with jax.named_scope("mla/attn"):
+        # the dispatcher scales by 1 / sqrt(the width it is given)
+        width = next(n for n in (64, 128, 256) if n >= dn + dr)  # what the kernels tile
+        zeros = jnp.zeros((B, S, H, width - dn - dr), u.dtype)
+        scale = jnp.asarray(math.sqrt(width / (dn + dr)), u.dtype)
+        qq = jnp.concatenate([q[..., :dn], q_r, zeros], axis=-1) * scale
+        kk = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr)), zeros], axis=-1)
+        attn = jax.ad_checkpoint.checkpoint_name(
+            attention(qq, kk, kv[..., dn:], cfg), ATTN_OUT_NAME)
+    with jax.named_scope("mla/out"):
+        return _head_gate(attn, u, w["w_g"]) @ w["wo"]
+
+
+def _layer_body(cfg: LingConfig, kind: Tuple[str, str], attention: Any):
+    """The scanned body of a run of ``kind``, as ``models/lfm2.py``'s:
+    ``(h, (w, bias, replay)) -> (h, stats)``."""
+    mixer, ffn = kind
+
+    def layer(h, xs):
+        w, bias, replay = xs
+        u = _rmsnorm(h, w["norm"], cfg.norm_eps)
+        h = h + (_kda_mixer(u, w, cfg) if mixer == "kda"
+                 else _mla_mixer(u, w, cfg, attention))
+        x = _rmsnorm(h, w["ffn_norm"], cfg.norm_eps)
+        if ffn == "dense":
+            return h + (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"], None
+        out, stats = moe_ffn(
+            x, w["router"], w["w_gate"], w["w_up"], w["w_down"], cfg,
+            routing=replay, bias=bias,
+            shared=(w["shared_gate"], w["shared_up"], w["shared_down"]))
+        stats.pop("prob_sum")  # no auxiliary loss reads it
+        return h + out, stats
+
+    return layer
+
+
+def ling_hidden(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    cfg: LingConfig,
+    attention_fn: Optional[Any] = None,
+    remat: Any = "full",
+    routing: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim],
+    ``moe_ffn``'s stats of the expert layers, each stacked over them).
+    ``routing`` [expert layers, B*S, k]: the experts to use (replay)."""
+    attention = attention_fn or _attention
+    h = params["embed"][tokens]
+    stats, at = [], 0  # ``at``: expert layers before this run
+    for name, kind, L in cfg.runs():
+        body = remat_wrap(_layer_body(cfg, kind, attention), remat)
+        rows = slice(at, at + L) if kind[1] == "moe" else None
+        xs = (params["layers"][name],
+              None if rows is None else params["expert_bias"][rows],
+              None if routing is None or rows is None else routing[rows])
+        h, out = jax.lax.scan(body, h, xs)
+        if rows is not None:
+            stats.append(out)
+            at += L
+    stats = (jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
+             if stats else {})
+    return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
+
+
+def ling_forward(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    cfg: LingConfig,
+    attention_fn: Optional[Any] = None,
+    remat: Any = "full",
+    routing: Optional[jax.Array] = None,
+) -> jax.Array:
+    """tokens int32 [B, S] -> logits f32 [B, S, vocab_size] (the slice's)."""
+    h, _ = ling_hidden(params, tokens, cfg, attention_fn=attention_fn,
+                       remat=remat, routing=routing)
+    return (h @ params["lm_head"]).astype(_F32)
+
+
+def ling_loss_and_stats(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    targets: jax.Array,
+    cfg: LingConfig,
+    attention_fn: Optional[Any] = None,
+    remat: Any = "full",
+    loss_chunk: int = 0,
+    routing: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Mean next-token cross-entropy over the vocabulary this chip holds
+    (``loss_chunk`` as ``lfm2_loss_and_stats``') and stats: the expert
+    layers' free routing with its margins (``routing`` [L,T,k], ``p_kth``,
+    ``p_next`` [L,T]) and the scalars a training loop logs:
+    ``load_max_over_mean`` (over the HELD experts), ``bias_moved_share``,
+    ``held_pair_share`` (the pairs that reached a held expert over T * k:
+    the even share is held / num_experts), ``overflow_pairs`` (held pairs
+    that found the share's buffer full, summed over layers: computed by
+    nobody, so anything but 0 is a wrong step) and ``groups_hit_mean`` (the
+    groups a token's k experts lie in: at most ``topk_group``)."""
+    h, stats = ling_hidden(params, tokens, cfg, attention_fn=attention_fn,
+                           remat=remat, routing=routing)
+    S = tokens.shape[1]
+    if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
+        loss_chunk = cfg.loss_chunk
+    loss = head_loss(h, params["lm_head"], targets, loss_chunk)
+    if stats:
+        counts = stats.pop("counts")
+        stats["load_max_over_mean"] = jnp.max(
+            jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts, axis=1), 1e-9))
+        stats["bias_moved_share"] = jnp.mean(stats.pop("bias_moved"))
+        if "groups_hit" in stats:
+            stats["groups_hit_mean"] = jnp.mean(stats.pop("groups_hit"))
+        if "held_pairs" in stats:
+            stats["held_pair_share"] = jnp.mean(
+                stats.pop("held_pairs").astype(_F32)) / (tokens.size * cfg.top_k)
+            stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
+    return loss, stats
+
+
+def ling_loss(*args: Any, **kw: Any) -> jax.Array:
+    """:func:`ling_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
+    return ling_loss_and_stats(*args, **kw)[0]
+
+
+def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, Any]:
+    """PartitionSpecs for the pytree: the mixers' and the feed-forwards'
+    matrices over fsdp and tp as the dense decoder's, the experts as
+    ``moe_param_specs``' (the dropless block keeps its experts on one device:
+    ``ep`` > 1 is refused, a share is one chip's), the small leaves, the
+    decay's and ``expert_bias`` replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from torchft_tpu.models.moe import _refuse_dropless_ep
+
+    if mesh is not None:
+        _refuse_dropless_ep(cfg, [a for a, n in mesh.shape.items() if n > 1])
+    col, row, rep2, rep3 = (P(None, "fsdp", "tp"), P(None, "tp", "fsdp"),
+                            P(None, None), P(None, None, None))
+    mixer = {
+        "kda": {"wq": col, "wk": col, "wv": col, "w_f": col, "conv_q": rep3,
+                "conv_k": rep3, "conv_v": rep3, "A_log": rep2, "dt_bias": rep2,
+                "w_beta": P(None, "fsdp", None), "o_norm": rep2,
+                "w_g": P(None, "fsdp", None), "wo": row},
+        "mla": {"wq": col, "w_kva": P(None, "fsdp", None), "kv_norm": rep2,
+                "w_kvb": P(None, None, "tp"), "w_g": P(None, "fsdp", None), "wo": row}}
+    ffn = {
+        "dense": {"w_gate": col, "w_up": col, "w_down": row},
+        "moe": {"router": P(None, "fsdp", None),
+                "w_gate": P(None, "ep", "fsdp", "tp"),
+                "w_up": P(None, "ep", "fsdp", "tp"),
+                "w_down": P(None, "ep", "tp", "fsdp"),
+                "shared_gate": col, "shared_up": col, "shared_down": row}}
+    specs = {"embed": P("fsdp", "tp"),
+             "layers": {name: {"norm": rep2, **mixer[m], "ffn_norm": rep2, **ffn[f]}
+                        for name, (m, f), _ in cfg.runs()},
+             "final_norm": P(None), "lm_head": P("fsdp", "tp")}
+    if cfg.n_moe_layers:
+        specs["expert_bias"] = rep2
+    return specs

@@ -88,11 +88,46 @@ class MoEConfig(LlamaConfig):
     qk_norm: bool = False  # RMSNorm over the whole q and k projections
     router_score: str = "softmax"  # or "sigmoid": each expert scored by itself
     gate_eps: float = 1e-9  # beside the chosen gates' sum where renormalised
+    # group-limited choice: the experts lie in ``n_group`` equal groups, a
+    # token keeps the ``topk_group`` best (a group scored by the sum of its
+    # best two) and chooses inside them; 1 group: a plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0  # a factor on every gate
+    # (first, count): this chip holds experts first .. first + count - 1 of
+    # the router's ``num_experts`` and computes their part alone (the
+    # dropless path; the expert leaves are ``[count, ...]``); None: all
+    held_experts: Optional[Tuple[int, int]] = None
+    # rows the share's buffer has, over the even share T * top_k * count /
+    # num_experts; pairs beyond it are counted (``overflow``) and computed
+    # by nobody
+    share_room: float = 1.5
 
     def __post_init__(self) -> None:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score={self.router_score!r}: models/moe.py "
                              "scores by 'softmax' or 'sigmoid'")
+        if self.num_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(f"n_group={self.n_group}, topk_group={self.topk_group} "
+                             f"of {self.num_experts} experts")
+        if self.held_experts is not None:
+            first, count = self.held_experts
+            if self.capacity_factor is not None or first < 0 or count < 1 \
+                    or first + count > self.num_experts:
+                raise ValueError(f"held_experts={self.held_experts}: a share of the "
+                                 f"{self.num_experts} experts, on the dropless path")
+
+    @property
+    def n_held(self) -> int:
+        """Experts this chip holds (all of them unless ``held_experts``)."""
+        return self.num_experts if self.held_experts is None else self.held_experts[1]
+
+    def share_rows(self, tokens: int) -> int:
+        """Rows of the share's buffer for a batch of ``tokens`` (static)."""
+        pairs = tokens * self.top_k
+        rows = math.ceil(self.share_room * pairs * self.n_held / self.num_experts)
+        tile = 512 if rows >= 512 else 8
+        return min(-(-rows // tile) * tile, -(-pairs // 8) * 8)
 
     def capacity(self, tokens: int) -> int:
         """Slots per expert for a batch of ``tokens`` (static given shapes)."""
@@ -187,6 +222,23 @@ def _counts(idx: jax.Array, num_experts: int) -> jax.Array:
     return jnp.sum(hot, axis=0, dtype=jnp.int32)
 
 
+def _within_groups(decide: jax.Array, cfg: MoEConfig):
+    """The group limit. decide [T, E] -> (``decide`` with the experts of
+    every group but the token's ``topk_group`` best at -inf, the score of
+    its last kept group, that of its best dropped one (equal where no group
+    is dropped)). A group's score is the sum of its best two."""
+    T, E = decide.shape
+    groups = cfg.n_group
+    best_two = jax.lax.top_k(decide.reshape(T, groups, E // groups), 2)[0]
+    top_g, top_i = jax.lax.top_k(jnp.sum(best_two, axis=-1),
+                                 min(cfg.topk_group + 1, groups))
+    kept = jnp.any(top_i[:, :cfg.topk_group, None]
+                   == jnp.arange(groups, dtype=top_i.dtype), axis=1)  # [T, groups]
+    kept = jnp.repeat(kept, E // groups, axis=1)
+    return (jnp.where(kept, decide, -jnp.inf), top_g[:, cfg.topk_group - 1],
+            top_g[:, -1])
+
+
 def _choose(
     scores: jax.Array, cfg: MoEConfig, routing: Optional[jax.Array],
     bias: Optional[jax.Array] = None,
@@ -201,17 +253,29 @@ def _choose(
     model would have chosen by itself (``routing`` [T,k]) and what decided
     it, ``scores + bias`` of its k-th and (k+1)-th choice (``p_kth``,
     ``p_next``: how near a tie the decision was; equal where there is no
-    (k+1)-th expert).
+    (k+1)-th expert; under a group limit ``p_next`` is raised to ``p_kth``
+    times the best dropped group's score over the last kept one's where that
+    is nearer: the nearer tie of the two, relative to its larger side).
     """
     k = cfg.top_k
     decide = scores if bias is None else scores + bias
+    if cfg.n_group > 1:
+        decide, g_kth, g_next = _within_groups(decide, cfg)
     top_p, top_i = jax.lax.top_k(decide, min(k + 1, cfg.num_experts))
     free = {"routing": top_i[:, :k], "p_kth": top_p[:, k - 1],
             "p_next": top_p[:, -1]}
+    if cfg.n_group > 1:
+        # the nearer tie of the two decisions taken: a token whose groups
+        # change chooses other experts however clear its k-th expert was.
+        # ``p_next`` is moved up to where the experts' tie is as near as the
+        # groups' (continuous in the scores: no flag to flip at a tie of ties)
+        free["p_next"] = jnp.maximum(free["p_next"], free["p_kth"] * (g_next / g_kth))
     idx = free["routing"] if routing is None else routing.astype(jnp.int32)
     gates = _scores_at(scores, idx)
     if cfg.norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + cfg.gate_eps)
+    if cfg.routed_scaling != 1.0:
+        gates = gates * cfg.routed_scaling
     return gates, idx, free
 
 
@@ -398,6 +462,53 @@ def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
         return _combine(rows, gates.astype(flat.dtype), inverse, order)
 
 
+def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
+    """The dropless path of a chip that holds ``cfg.held_experts`` alone:
+    of the T*k (token, choice) pairs those whose expert is held, sorted by
+    expert, in a buffer of ``cfg.share_rows(T)`` rows (the even share with
+    room, never T*k); the pairs of absent experts are computed by nobody,
+    and so are held pairs beyond the buffer (``overflow``). -> (the held
+    experts' part of the block's output [T, d], stats: ``counts`` [held],
+    ``held_pairs``, ``overflow``).
+
+    The rows leave and come back by XLA's gather and scatter-add of the
+    buffer's rows (a sixteenth of T*k at the published cut): the custom
+    pullbacks of the whole-layer path gather T*k rows."""
+    (T, d), k = flat.shape, idx.shape[1]
+    first, held = cfg.held_experts
+    rows_n = cfg.share_rows(T)
+    with jax.named_scope("moe/route"):
+        local = idx.reshape(T * k) - first
+        local = jnp.where((local >= 0) & (local < held), local, held)  # absent: last
+        counts = _counts(local[:, None], held)
+        pairs = jnp.sum(counts)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n]
+        valid = (jnp.arange(rows_n) < pairs)[:, None]
+        # every row of the buffer belongs to a group: what the held pairs
+        # leave free goes to the last expert as rows of zeros
+        ends = jnp.minimum(jnp.cumsum(counts), rows_n).at[-1].set(rows_n)
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        weights = jnp.where(valid, gates.reshape(T * k, 1)[order], 0.0)
+    with jax.named_scope("moe/dispatch"):
+        rows = jnp.where(valid, flat[order // k], 0)
+    with jax.named_scope("moe/experts"):
+        h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(
+            rows, w_up, sizes)
+        rows = _grouped_matmul(h, w_down, sizes)
+    with jax.named_scope("moe/combine"):
+        out = jnp.zeros((T, d), flat.dtype).at[order // k].add(
+            rows * weights.astype(flat.dtype))
+    return out, {"counts": counts, "held_pairs": pairs,
+                 "overflow": jnp.maximum(pairs - rows_n, 0)}
+
+
+def _groups_hit(idx: jax.Array, cfg: MoEConfig) -> jax.Array:
+    """idx [T, k] -> the mean number of groups a token's k experts lie in."""
+    group = idx // (cfg.num_experts // cfg.n_group)
+    hit = jnp.any(group[:, :, None] == jnp.arange(cfg.n_group, dtype=idx.dtype), axis=1)
+    return jnp.mean(jnp.sum(hit, axis=-1).astype(jnp.float32))
+
+
 def moe_ffn(
     x: jax.Array,
     router: jax.Array,
@@ -407,6 +518,7 @@ def moe_ffn(
     cfg: MoEConfig,
     routing: Optional[jax.Array] = None,
     bias: Optional[jax.Array] = None,
+    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Sparse SwiGLU FFN. x: [B, S, d] -> ([B, S, d], stats).
 
@@ -419,6 +531,18 @@ def moe_ffn(
     free routing with its margins (``routing``, ``p_kth``, ``p_next``: of
     ``scores + bias``, what decided) and, under a bias, ``bias_moved``: the
     share of tokens whose k experts would be others without it.
+
+    What the configuration may add, each absent unless it says so:
+    ``cfg.n_group`` > 1 limits a token's choice to its ``topk_group`` best
+    groups (:func:`_within_groups`; the margin is then the nearer tie of
+    the two decisions, and ``groups_hit`` the mean number of groups a token
+    uses); ``cfg.routed_scaling`` multiplies the gates;
+    ``cfg.held_experts`` makes this a chip's SHARE of the layer: the router
+    and the decision over all ``num_experts``, the expert leaves
+    ``[held, ...]``, the output the held experts' part alone
+    (:func:`_share_ffn`; ``counts`` are then the held experts', beside
+    ``held_pairs`` and ``overflow``); ``shared`` (gate, up, down) is one
+    SwiGLU every token passes, added to the output ungated.
     """
     B, S, d = x.shape
     T = B * S
@@ -433,15 +557,27 @@ def moe_ffn(
                  else jax.nn.softmax(logits, axis=-1))
         gates, idx, free = _choose(probs, cfg, routing, bias)
         if bias is not None:
-            unbiased = jax.lax.top_k(probs, cfg.top_k)[1]
+            unbiased = (jax.lax.top_k(probs, cfg.top_k)[1] if cfg.n_group == 1
+                        else _choose(probs, cfg, None)[2]["routing"])
             kept = jnp.any(free["routing"][:, :, None] == unbiased[:, None, :], axis=-1)
             free["bias_moved"] = 1.0 - jnp.mean(jnp.all(kept, axis=-1).astype(jnp.float32))
-        sizes = _counts(idx, cfg.num_experts)
+        if cfg.n_group > 1:
+            free["groups_hit"] = _groups_hit(idx, cfg)
+        if cfg.held_experts is None:
+            sizes = _counts(idx, cfg.num_experts)
     payload = flat.astype(w_gate.dtype)  # the router saw x as it came
-    if cfg.capacity_factor is None:
+    if cfg.held_experts is not None:
+        out, share = _share_ffn(payload, gates, idx, cfg, w_gate, w_up, w_down)
+        sizes = share.pop("counts")
+        free.update(share)
+    elif cfg.capacity_factor is None:
         out = _dropless_ffn(payload, gates, idx, sizes, w_gate, w_up, w_down)
     else:
         out = _capacity_ffn(payload, gates, idx, w_gate, w_up, w_down, cfg.capacity(T))
+    if shared is not None:
+        with jax.named_scope("moe/shared"):
+            s_gate, s_up, s_down = shared
+            out = out + (jax.nn.silu(payload @ s_gate) * (payload @ s_up)) @ s_down
     stats = {"counts": sizes.astype(jnp.float32), "prob_sum": jnp.sum(probs, axis=0),
              **free}
     return out.astype(x.dtype).reshape(B, S, d), stats

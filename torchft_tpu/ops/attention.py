@@ -211,7 +211,9 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.
     """Backend-dispatching causal attention.
 
     On TPU: splash attention when the model is GQA/MQA (KV heads stay
-    unrepeated — group-factor less HBM traffic), plain flash otherwise;
+    unrepeated — group-factor less HBM traffic) or its values are narrower
+    than its keys (latent attention: the flash kernel takes one width),
+    plain flash otherwise;
     shapes the kernels cannot tile are an error there, never a quiet
     switch to materialized scores. Off TPU (the CPU test platform) the XLA
     reference runs. ``TORCHFT_TPU_ATTENTION=auto|splash|flash|reference``
@@ -235,7 +237,8 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.
             "TORCHFT_TPU_ATTENTION=reference to run the XLA reference "
             "(materialized f32 scores) on purpose"
         )
-    if choice == "splash" or (choice == "auto" and q.shape[2] != k.shape[2]):
+    if choice == "splash" or (choice == "auto" and (
+            q.shape[2] != k.shape[2] or v.shape[-1] != hd)):
         LAST_DISPATCH = "splash"
         return splash_attention_tpu(q, k, v, cfg)
     LAST_DISPATCH = "flash"

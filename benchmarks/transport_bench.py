@@ -14,6 +14,12 @@
 
     python benchmarks/transport_bench.py --transport allreduce --size-mb 64
 
+   and where one ring's time goes, with the ranks as processes and a
+   step's buckets as the trainer sends them (bf16, back to back):
+
+    python benchmarks/transport_bench.py --transport allreduce --world 4 \
+        --elements 189532160,315367424 --donate
+
 Prints one JSON line per run.
 """
 
@@ -464,6 +470,128 @@ def bench_allreduce(size_mb: int, timeout: float) -> None:
             }), flush=True)
 
 
+def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
+                     chunk_mb: float, timeout: float) -> None:
+    """One step's buckets through the plain ring, ``world`` ranks as
+    processes on this host: the step's wall time and, per rank, where the
+    ring's dispatch thread spent it (see :func:`_ring_child`)."""
+    import subprocess
+
+    from torchft_tpu.coordination import KvStoreServer
+
+    store = KvStoreServer("127.0.0.1:0")
+    spec = json.dumps({
+        "addr": f"127.0.0.1:{store.port}/bench_ring", "world": world,
+        "elements": elements, "donate": donate, "iters": iters,
+        "chunk_mb": chunk_mb, "timeout": timeout,
+    })
+    try:
+        procs = [
+            subprocess.Popen(
+                [sys.executable, os.path.abspath(__file__),
+                 "--_ring-child", spec, "--_ring-rank", str(r)],
+                stdout=subprocess.PIPE, text=True,
+            )
+            for r in range(world)
+        ]
+        ranks = [json.loads(p.communicate(timeout=timeout * (iters + 2))[0]
+                            .strip().splitlines()[-1]) for p in procs]
+    finally:
+        store.shutdown()
+    assert len({r["crc"] for r in ranks}) == 1, "ranks disagree"
+    keys = ("step_s", "ring_s", "recv_s", "accum_s", "rest_s", "send_s")
+    nbytes = 2 * sum(elements)
+    print(json.dumps({
+        "transport": "allreduce", "algo": "ring_split", "world": world,
+        "dtype": "bfloat16", "elements": elements, "donate": donate,
+        "chunk_mb": ranks[0]["chunk_mb"], "iters": iters,
+        # each the median over iterations of one rank, then the slowest rank
+        **{k: round(max(r[k] for r in ranks), 4) for k in keys},
+        "gbit_per_s": round(
+            nbytes * 8 / max(r["step_s"] for r in ranks) / 1e9, 2),
+        "inplace": ranks[0]["inplace"],
+        "result_crc": ranks[0]["crc"],
+    }), flush=True)
+
+
+def _ring_child(spec: dict, rank: int) -> None:
+    """One rank of :func:`bench_ring_split`. The split is read off the
+    functions both the old ring and the streamed one call, so one command
+    sizes either: ``recv_s`` is the dispatch thread inside
+    ``recv_raw_into`` (the hops' socket time, waiting for the peer
+    included), ``accum_s`` whichever thread inside ``_accum``, ``rest_s``
+    what is left of the dispatch thread's ``_ring_allreduce`` beside its
+    own receives and accumulates (the old ring's allocation, pack and
+    result copies; the streamed one's waits for its fold worker and its
+    writer); ``send_s`` is the writer thread inside ``send_raw``."""
+    import statistics
+    import threading
+    import zlib
+
+    import ml_dtypes
+
+    import torchft_tpu.process_group as pg_mod
+    from torchft_tpu.process_group import ProcessGroupHost, ReduceOp
+
+    if spec["chunk_mb"] and hasattr(pg_mod, "_RING_CHUNK_BYTES"):
+        pg_mod._RING_CHUNK_BYTES = int(spec["chunk_mb"] * 2**20)
+    spent = dict.fromkeys(
+        ("ring_s", "recv_s", "accum_s", "accum_own_s", "send_s"), 0.0)
+
+    def timed(fn, key, own_key=None):
+        """``own_key``: the part of ``key`` spent on the dispatch thread."""
+        def wrapper(*args, **kwargs):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                dt = time.perf_counter() - t0
+                spent[key] += dt
+                if own_key and threading.current_thread().name.startswith(
+                        "pg_host_dispatch"):
+                    spent[own_key] += dt
+        return wrapper
+
+    pg_mod._ring_allreduce = timed(pg_mod._ring_allreduce, "ring_s")
+    pg_mod._accum = timed(pg_mod._accum, "accum_s", "accum_own_s")
+    pg_mod._Comm.recv_raw_into = timed(pg_mod._Comm.recv_raw_into, "recv_s")
+    pg_mod._Comm.send_raw = timed(pg_mod._Comm.send_raw, "send_s")
+
+    pg = ProcessGroupHost(timeout=spec["timeout"])
+    pg.configure(spec["addr"], rank, spec["world"], quorum_id=1)
+    rng = np.random.default_rng(rank)
+    grads = [
+        (rng.standard_normal(n, np.float32) * 0.01).astype(ml_dtypes.bfloat16)
+        for n in spec["elements"]
+    ]
+    # the staging buffers of a pool: made once, warm, refilled every step
+    bufs = [np.empty_like(g) for g in grads]
+    rows, info, outs = [], {}, []
+    for _ in range(spec["iters"] + 1):  # the first warms up
+        for b, g in zip(bufs, grads):
+            np.copyto(b, g)
+        pg.barrier().wait(spec["timeout"])
+        for k in spent:
+            spent[k] = 0.0
+        t0 = time.perf_counter()
+        futs = [
+            pg.allreduce([b], ReduceOp.SUM, donate=spec["donate"]).get_future()
+            for b in bufs
+        ]
+        outs = [f.wait(spec["timeout"])[0] for f in futs]
+        rows.append({"step_s": time.perf_counter() - t0, **spent})
+        info = getattr(futs[0], "ring", {})
+    pg.shutdown()
+    rows = rows[1:]
+    row = {k: statistics.median(r[k] for r in rows) for k in rows[0]}
+    row["rest_s"] = row["ring_s"] - row["recv_s"] - row.pop("accum_own_s")
+    row["inplace"] = info.get("inplace", 0)
+    row["chunk_mb"] = getattr(pg_mod, "_RING_CHUNK_BYTES", 0) / 2**20
+    row["crc"] = "%08x" % zlib.crc32(b"".join(
+        o.view(np.uint16).tobytes()[:1 << 24] for o in outs))
+    print(json.dumps(row), flush=True)
+
+
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
@@ -500,8 +628,29 @@ def main() -> None:
                         help="two-process: heal the same pair N times; "
                              "rounds >1 report the steady state (round 1 "
                              "pays this host's first-touch paging tax)")
+    parser.add_argument("--elements", default="",
+                        help="allreduce: a step's bf16 bucket sizes, comma "
+                             "separated; runs the ring alone with the ranks "
+                             "as processes and prints its split")
+    parser.add_argument("--world", type=int, default=4,
+                        help="allreduce --elements: ranks (processes)")
+    parser.add_argument("--donate", action="store_true",
+                        help="allreduce --elements: donate the buckets, as "
+                             "the bucket pipeline does its staging buffers")
+    parser.add_argument("--iters", type=int, default=3,
+                        help="allreduce --elements: measured steps")
+    parser.add_argument("--chunk-mb", type=float, default=0.0,
+                        help="allreduce --elements: the ring's frame size "
+                             "(default: the module's)")
     parser.add_argument("--_recv-child", default="", help=argparse.SUPPRESS)
+    parser.add_argument("--_ring-child", default="", help=argparse.SUPPRESS)
+    parser.add_argument("--_ring-rank", type=int, default=0,
+                        help=argparse.SUPPRESS)
     args = parser.parse_args()
+
+    if args._ring_child:
+        _ring_child(json.loads(args._ring_child), args._ring_rank)
+        return
 
     if args.check and not args.two_process:
         # the single-process bench shares one address space (~2x RSS by
@@ -522,6 +671,12 @@ def main() -> None:
         else:
             _recv_child(args._recv_child, args.size_mb, args.num_chunks,
                         args.timeout, args.inplace, args.repeat)
+        return
+    if args.transport == "allreduce" and args.elements:
+        bench_ring_split(
+            args.world, [int(n) for n in args.elements.split(",")],
+            args.donate, args.iters, args.chunk_mb, args.timeout,
+        )
         return
     if args.transport == "allreduce":
         bench_allreduce(args.size_mb, args.timeout)

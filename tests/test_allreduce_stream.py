@@ -10,6 +10,7 @@ should_commit()==False story — never a partially-applied reduction.
 
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 import pytest
@@ -1081,3 +1082,133 @@ class TestDeviceMilestonesOfAStep:
         m.shutdown(wait=False)
         watcher.join(5)
         assert not watcher.is_alive()
+
+
+_RING_SIZES = (20_000, 20_000, 18_000, 18_000, 17_001, 17_001)
+_RING_CAP = 2 * 20_000 * 4  # two leaves a bucket, each over the ring's floor
+
+
+def _ring_tree(rank, step):
+    import jax
+
+    rng = np.random.RandomState(100 * step + rank)
+    return {
+        f"p{i}": jax.device_put((rng.randn(size) * 3).astype(np.float32))
+        for i, size in enumerate(_RING_SIZES)
+    }
+
+
+@pytest.fixture()
+def world_of_two():
+    """``make(rank, **manager_kwargs)``: a Manager over a real
+    ProcessGroupHost that joins a world of two on a real store, whatever
+    the (mocked) quorum's store address says."""
+    store = KvStoreServer("127.0.0.1:0")
+
+    class HostOfTwo(ProcessGroupHost):
+        def configure(self, store_addr, replica_rank, replica_world_size,
+                      quorum_id=0):
+            super().configure(f"127.0.0.1:{store.port}/two", replica_rank, 2,
+                              quorum_id=quorum_id)
+
+    made = []
+
+    def make(rank, timeout=10.0, **kwargs):
+        pg = HostOfTwo(timeout=timeout)
+        made.append(pg)
+        if kwargs.pop("bare", False):
+            return pg
+        return make_manager(pg=pg, quorum=make_quorum(replica_rank=rank),
+                            bucket_cap_bytes=_RING_CAP, timeout=timeout,
+                            **kwargs)
+
+    yield make
+    for pg in made:
+        pg.shutdown()
+    store.shutdown()
+
+
+class TestTheRingWorksInTheStagingBuffer:
+    def test_a_world_of_two_copies_nothing_and_every_step_is_right(
+        self, world_of_two, monkeypatch
+    ):
+        """Behind a real ring the donated pool buffer comes back reduced:
+        `wire_passthrough_share` reads 1.0 as it does at a world of one,
+        the buffers recycle, and six steps' trees are bit for bit the mean
+        of the two groups'."""
+        import torchft_tpu.process_group as pg_mod
+
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 32 * 1024)
+        ms = [world_of_two(rank) for rank in range(2)]
+        steps = 6
+
+        def run(rank):
+            m, outs, shares = ms[rank], [], []
+            for step in range(steps):
+                outs.append(_reduce(m, _ring_tree(rank, step), streamed=True))
+                shares.append((m.timings()["stage_pool_hit_share"],
+                               m.timings()["wire_passthrough_share"]))
+                assert _pooled(m) + len(_parked(m)) == 3
+                _let_landed_leaves_finish(m)
+                m.should_commit()
+            return outs, shares
+
+        with ThreadPoolExecutor(2) as ex:
+            results = list(ex.map(run, range(2)))
+        spans = [m.tracer.export()["spans"] for m in ms]
+        for m in ms:
+            m.shutdown(wait=False)
+        for rank, (outs, shares) in enumerate(results):
+            assert shares == [(0.0, 1.0)] + [(1.0, 1.0)] * (steps - 1)
+            for step, out in enumerate(outs):
+                a, b = _ring_tree(0, step), _ring_tree(1, step)
+                for k in a:  # still what it was when it resolved
+                    want = (np.asarray(a[k]) + np.asarray(b[k])) / np.float32(2)
+                    assert np.array_equal(_bits(out[k]), _bits(want)), (step, k)
+            h2d = [s for s in spans[rank] if s["name"] == "h2d"]
+            assert len(h2d) == 3 * steps
+            assert all(s["args"]["passed_through"] == 1 for s in h2d)
+            runs = [s for s in spans[rank] if s["name"] == "wire_run"]
+            assert len(runs) == 3 * steps
+            for s in runs:
+                frames = -(-(s["args"]["bytes"] // 2) // (32 * 1024))
+                assert (s["args"]["world"], s["args"]["inplace"],
+                        s["args"]["chunks"]) == (2, 1, frames)
+
+    def test_a_ring_that_fails_half_way_gives_no_buffer_back(
+        self, world_of_two, monkeypatch
+    ):
+        """The peer closes after its second frame: the step is discarded
+        (zeros, no commit) and the half-reduced staging buffer is dropped,
+        not recycled."""
+        import torchft_tpu.process_group as pg_mod
+
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 16 * 1024)
+        m = world_of_two(0, timeout=3.0)
+        peer = world_of_two(1, timeout=3.0, bare=True)
+        joined = threading.Thread(target=peer.configure,
+                                  args=("", 1, 2, 1))
+        joined.start()
+        m.start_quorum()
+        m.wait_quorum()
+        joined.join(10)
+        comm = peer._gen.comm
+        recv, seen = comm.recv_raw_into, []
+
+        def dying_recv(frm, out):
+            seen.append(frm)
+            if len(seen) == 2:
+                peer.abort()
+            return recv(frm, out)
+
+        comm.recv_raw_into = dying_recv
+        theirs = peer.allreduce(
+            [np.ones(2 * 20_000, np.float32)], ReduceOp.SUM, donate=True)
+        out = m.allreduce_streamed(_ring_tree(0, 0)).wait(timeout=30)
+        with pytest.raises(Exception):
+            theirs.get_future().wait(timeout=10)
+        assert all(not np.asarray(v).any() for v in out.values())
+        assert not m.should_commit()
+        time.sleep(0.2)  # whatever lands after the failure
+        m.shutdown(wait=False)
+        assert _pooled(m) == 0 and _parked(m) == []

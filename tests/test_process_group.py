@@ -7,10 +7,14 @@ collective works again (:894-950).
 """
 
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 
+import ml_dtypes
 import numpy as np
 import pytest
+
+import torchft_tpu.process_group as pg_mod
 
 from torchft_tpu.coordination import KvStoreServer
 from torchft_tpu.process_group import (
@@ -442,6 +446,243 @@ class TestRingAllreduce:
             store, world, lambda r: [np.ones(8, np.float32)], ReduceOp.SUM
         )
         np.testing.assert_allclose(outs[0][0], np.full(8, 2.0))
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+
+
+def _ring_reference(vals, op):
+    """What the ring computes, written out: segment ``j`` (cut at multiples
+    of ``ceil(n / world)``) starts as rank ``j``'s and is accumulated, in
+    the input dtype, into rank ``j + 1``'s, that into rank ``j + 2``'s and
+    so on round the ring; AVG divides at the end."""
+    world, n = len(vals), vals[0].size
+    seg = -(-n // world)
+    out = np.empty_like(vals[0])
+    for j in range(world):
+        cut = slice(min(j * seg, n), min((j + 1) * seg, n))
+        acc = vals[j][cut].copy()
+        for i in range(1, world):
+            own = vals[(j + i) % world][cut].copy()
+            if op == ReduceOp.MAX:
+                np.maximum(own, acc, out=own)
+            else:
+                own += acc
+            acc = own
+        out[cut] = acc
+    if op == ReduceOp.AVG:
+        if np.issubdtype(out.dtype, np.integer):
+            return out / world
+        out /= world
+    return out
+
+
+def _ring_values(dtype, n, world, seed=0):
+    rng = np.random.default_rng(seed)
+    if np.issubdtype(dtype, np.integer):
+        return [rng.integers(-1000, 1000, n).astype(dtype)
+                for _ in range(world)]
+    return [rng.standard_normal(n).astype(dtype) for _ in range(world)]
+
+
+@pytest.fixture(scope="module")
+def meshes():
+    """One ProcessGroupHost mesh a world size, shared by the ring's cases
+    (a case is one collective; building a mesh is the slow part)."""
+    store = KvStoreServer("127.0.0.1:0")
+    made = {}
+
+    def get(world):
+        if world not in made:
+            made[world] = make_pgs(store, world, quorum_id=100 + world,
+                                   timeout=20.0, prefix="ring")
+        return made[world]
+
+    yield get
+    for pgs in made.values():
+        for pg in pgs:
+            pg.shutdown()
+    store.shutdown()
+
+
+_CHUNK = 24 * 1024  # a test's frame: several a segment at these lengths
+
+
+def _ring_len(dtype, length):
+    if length == "min":  # exactly the ring's threshold
+        return pg_mod._RING_MIN_BYTES // np.dtype(dtype).itemsize
+    return length
+
+
+class TestRingInPlaceAndStreamed:
+    """The ring reduces in what was donated and leaves alone what was not,
+    in frames of ``_RING_CHUNK_BYTES``: bit for bit the accumulation in
+    ring order, on every rank alike."""
+
+    @pytest.mark.parametrize("donate", [True, False], ids=["donated", "kept"])
+    # neither the world nor the frame divides them; "min" is a segment
+    # shorter than a frame at a world of three or four
+    @pytest.mark.parametrize("length", ["min", 100_003, 250_007])
+    @pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVG, ReduceOp.MAX],
+                             ids=lambda o: o.value)
+    @pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float32, np.int32],
+                             ids=["bf16", "f32", "i32"])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_bitwise_the_ring_order_reduction(
+        self, meshes, monkeypatch, world, dtype, op, length, donate
+    ):
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        pgs = meshes(world)
+        vals = _ring_values(dtype, _ring_len(dtype, length), world)
+        want = _ring_reference(vals, op)
+        ins = [v.copy() for v in vals]
+
+        def step(rank):
+            fut = pgs[rank].allreduce([ins[rank]], op, donate=donate).get_future()
+            return fut.wait(30)[0], fut.ring
+
+        outs = run_parallel(world, step)
+        keeps_dtype = not (op == ReduceOp.AVG and dtype is np.int32)
+        frames = -(-(-(-vals[0].size // world)) * vals[0].itemsize // _CHUNK)
+        for rank, (out, info) in enumerate(outs):
+            assert out.dtype == want.dtype and out.shape == want.shape
+            assert np.array_equal(_bits(out), _bits(want)), rank
+            assert info == {"inplace": int(donate and keeps_dtype),
+                            "chunks": frames}
+            if donate and keeps_dtype:
+                assert out is ins[rank]
+            else:  # left as it was, and the result is memory of its own
+                assert np.array_equal(_bits(ins[rank]), _bits(vals[rank]))
+                assert not np.shares_memory(out, ins[rank])
+        assert not any(
+            np.shares_memory(a[0], b[0])
+            for i, a in enumerate(outs) for b in outs[i + 1:]
+        )
+
+    @pytest.mark.parametrize("donate", [True, False], ids=["donated", "kept"])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_leaves_of_several_shapes_and_dtypes_ride_as_one_buffer_a_dtype(
+        self, meshes, monkeypatch, world, donate
+    ):
+        """A dtype's leaves are reduced as if laid end to end (segments and
+        frames cross their boundaries), each in its own memory; a leaf of
+        no elements and one of no dimensions ride along."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        pgs = meshes(world)
+        shapes = [((257, 129), np.float32), ((50_001,), np.int64),
+                  ((0, 3), np.float32), ((33, 3, 7), np.float32),
+                  ((), np.float32), ((5,), np.int64)]
+        vals = [
+            [_ring_values(dt, int(np.prod(shape)), 1, seed=7 * r + i)[0]
+             .reshape(shape) for i, (shape, dt) in enumerate(shapes)]
+            for r in range(world)
+        ]
+        ins = [[v.copy() for v in leaves] for leaves in vals]
+        outs = run_parallel(world, lambda r: pgs[r].allreduce(
+            ins[r], ReduceOp.SUM, donate=donate).get_future().wait(30))
+        for dt in (np.float32, np.int64):
+            idxs = [i for i, (_s, d) in enumerate(shapes) if d is dt]
+            want = _ring_reference(
+                [np.concatenate([vals[r][i].reshape(-1) for i in idxs])
+                 for r in range(world)], ReduceOp.SUM)
+            for r in range(world):
+                got = np.concatenate([outs[r][i].reshape(-1) for i in idxs])
+                assert np.array_equal(_bits(got), _bits(want)), (r, dt)
+        for r in range(world):
+            for i, (shape, dt) in enumerate(shapes):
+                assert outs[r][i].shape == shape and outs[r][i].dtype == dt
+                assert (outs[r][i] is ins[r][i]) == donate
+                if not donate:
+                    assert np.array_equal(_bits(ins[r][i]), _bits(vals[r][i]))
+                    assert not np.shares_memory(outs[r][i], ins[r][i])
+
+    @pytest.mark.parametrize("how", ["strided", "read_only"])
+    def test_a_donated_leaf_the_ring_cannot_work_in_is_copied(
+        self, meshes, how
+    ):
+        """Donation is honoured where the memory allows it: a leaf that is
+        not C-contiguous, or not writable, is left as it was."""
+        world = 2
+        pgs = meshes(world)
+        vals = _ring_values(np.float32, 40_000, world)
+        want = _ring_reference(vals, ReduceOp.SUM)
+
+        def leaf(rank):
+            if how == "strided":
+                wide = np.zeros((40_000, 2), np.float32)
+                wide[:, 0] = vals[rank]
+                return wide[:, 0]
+            a = vals[rank].copy()
+            a.flags.writeable = False
+            return a
+
+        ins = [leaf(r) for r in range(world)]
+
+        def step(rank):
+            fut = pgs[rank].allreduce(
+                [ins[rank]], ReduceOp.SUM, donate=True).get_future()
+            return fut.wait(30)[0], fut.ring
+
+        for rank, (out, info) in enumerate(run_parallel(world, step)):
+            assert np.array_equal(_bits(out), _bits(want))
+            assert info["inplace"] == 0
+            assert not np.shares_memory(out, ins[rank])
+            assert np.array_equal(ins[rank], vals[rank])
+
+    @pytest.mark.parametrize("donate", [True, False], ids=["donated", "kept"])
+    def test_a_peer_that_closes_mid_ring_fails_every_survivor_in_time(
+        self, store, monkeypatch, donate
+    ):
+        """Rank 2 dies after its third frame. Rank 0 reads the closed
+        socket; rank 1, whose frames come from rank 0, is failed by its
+        socket's timeout. What was donated may be left half-reduced (the
+        caller gave it up); what was not is left as it was. The survivors
+        then form a world of two, and its ring is right."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        world, timeout = 3, 2.0
+        pgs = make_pgs(store, world, quorum_id=1, timeout=timeout,
+                       prefix="midring")
+        vals = _ring_values(np.float32, 250_007, world)
+        ins = [v.copy() for v in vals]
+        comm = pgs[2]._gen.comm
+        recv, seen = comm.recv_raw_into, []
+
+        def dying_recv(peer, out):
+            seen.append(peer)
+            if len(seen) == 3:
+                pgs[2].abort()
+            return recv(peer, out)
+
+        comm.recv_raw_into = dying_recv
+
+        def step(rank):
+            t0 = time.monotonic()
+            with pytest.raises(Exception):
+                pgs[rank].allreduce(
+                    [ins[rank]], ReduceOp.SUM, donate=donate
+                ).get_future().wait(timeout=10)
+            return time.monotonic() - t0
+
+        took = run_parallel(world, step)
+        assert max(took) < timeout + 2.0, took
+        assert pgs[0].errored() is not None and pgs[1].errored() is not None
+        if not donate:
+            for rank in range(world):
+                assert np.array_equal(ins[rank], vals[rank])
+
+        def again(rank):
+            pgs[rank].configure(
+                f"127.0.0.1:{store.port}/midring", rank, 2, quorum_id=2)
+            return pgs[rank].allreduce(
+                [vals[rank].copy()], ReduceOp.SUM, donate=donate
+            ).get_future().wait(timeout=10)[0]
+
+        want = _ring_reference(vals[:2], ReduceOp.SUM)
+        for out in run_parallel(2, again):
+            assert np.array_equal(_bits(out), _bits(want))
+        for pg in pgs:
+            pg.shutdown()
 
 
 class TestWrappers:

@@ -1108,12 +1108,14 @@ class _BucketOp:
         # ids of the three stage spans per bucket (recorded from the marks
         # once the op resolves), for their children, and what the PG
         # stamped on each bucket's op: (enqueued, fn started, fn ended) ->
-        # allreduce/wire_run
+        # allreduce/wire_run, and what its ring left beside them (inplace,
+        # chunks): that span's args
         self.stage_ids = [
             {st: new_id() for st in ("pack", "wire", "unpack")}
             for _ in range(n)
         ]
         self.wire_runs: List[Any] = [None] * n
+        self.wire_rings: List[Dict[str, int]] = [{} for _ in range(n)]
         # which buckets were fetched from the device into a pool buffer, and
         # which got that buffer back as the collective's result
         # (wire_passthrough_share)
@@ -1515,7 +1517,8 @@ class BucketPipeline:
                 # a buffer drawn from the pool for this bucket is the
                 # pipeline's own and is not touched again before it lands:
                 # the group may hand it back as the result (a world of one
-                # has nothing to reduce), and _land sees that it did
+                # has nothing to reduce, the ring reduces in it), and _land
+                # sees that it did
                 donate = pooled_buf is not None and modes[i] == "off"
                 with tracer.span(
                     "dispatch", cat="allreduce", parent=pk_id, bucket=i,
@@ -1547,6 +1550,7 @@ class BucketPipeline:
             # ProcessGroupHost leaves these on its op's future; another
             # PG's has none
             op.wire_runs[i] = getattr(f, "stamps", None)
+            op.wire_rings[i] = getattr(f, "ring", None) or {}
         try:
             flat = f.value()[0]
         except Exception as e:  # noqa: BLE001
@@ -1720,8 +1724,10 @@ class BucketPipeline:
                 # what the PG's dispatch thread did for this bucket, from
                 # the stamps it left on the op's future: fn(comm) alone
                 # (at a world of one the donated buffer handed back, or a
-                # copy of what was not donated; the ring otherwise); the time
-                # the op sat in its queue behind earlier buckets is an arg
+                # copy of what was not donated; the ring otherwise, which
+                # says whether it reduced in the donated buffer and in how
+                # many frames a hop); the time the op sat in its queue
+                # behind earlier buckets is an arg
                 t_enq, t_run0, t_run1 = run
                 self._tracer.record_rel(
                     "wire_run", "allreduce", t_run0, t_run1,
@@ -1729,6 +1735,7 @@ class BucketPipeline:
                     segment=op.segment, bytes=op.bucket_bytes[i],
                     world=self._pg.size(),
                     queued_us=int((t_run0 - t_enq) * 1e6),
+                    **op.wire_rings[i],
                 )
 
     # ------------------------------------------------------- compression

@@ -48,6 +48,10 @@ REPLICA_GROUP_ID_ENV = "REPLICA_GROUP_ID"
 NUM_REPLICA_GROUPS_ENV = "NUM_REPLICA_GROUPS"
 GROUP_RANK_ENV = "GROUP_RANK"
 GROUP_WORLD_SIZE_ENV = "GROUP_WORLD_SIZE"
+# how long groups too few to form a quorum may run on after the others have
+# finished (their own last step and epilogue end within seconds of the
+# others'), before launch_replica_groups stops them
+ORPHAN_GRACE_S = 30.0
 
 # libtpu's chip-grid shape for a process that owns n chips of one host
 # (x,y,z; the table jax's own multi-process TPU tests use)
@@ -139,17 +143,26 @@ def launch_replica_groups(
 ) -> int:
     """Run ``cmd`` as ``num_groups`` replica groups; supervise + restart.
 
-    Returns the exit code: 0 iff every group eventually exited cleanly.
+    Returns the exit code: 0 iff every group eventually exited cleanly, or
+    was stopped because the job had ended without it (below).
     Starts an in-process lighthouse when ``lighthouse_addr`` is None.
     ``chips_per_group > 0`` hands each worker its own chips (``chip_env``).
+
+    Under its own lighthouse every group is this launcher's, so once groups
+    have finished and fewer than ``min_replicas`` are left, no quorum can
+    form again: the job is over, and a replacement that came up after the
+    others' last step would wait out its quorum timeout. Where all that is
+    left are groups this launcher restarted, they are given
+    ``ORPHAN_GRACE_S`` to finish by themselves, then stopped.
     """
     # one persistent compile cache for every worker and every restart
     compilation_cache_dir()
     own_lighthouse = None
+    quorum_size = 0  # known only of a lighthouse of our own
     if lighthouse_addr is None:
+        quorum_size = min_replicas if min_replicas is not None else num_groups
         own_lighthouse = LighthouseServer(
-            bind="0.0.0.0:0",
-            min_replicas=min_replicas if min_replicas is not None else num_groups,
+            bind="0.0.0.0:0", min_replicas=quorum_size,
         )
         lighthouse_addr = own_lighthouse.address()
         logger.info("launcher lighthouse at %s", lighthouse_addr)
@@ -168,6 +181,7 @@ def launch_replica_groups(
     restarts = [0] * num_groups
     done = [False] * num_groups
     failed = False
+    orphaned_at: Optional[float] = None
 
     stop = threading.Event()
     prev_handlers = {}
@@ -227,6 +241,20 @@ def launch_replica_groups(
                         )
                         done[i] = True
                         failed = True
+            left = [i for i in range(num_groups) if not done[i]]
+            if (not left or len(left) >= quorum_size or failed
+                    or not all(restarts[i] for i in left)):
+                orphaned_at = None
+            elif orphaned_at is None:
+                orphaned_at = time.monotonic()
+            elif time.monotonic() - orphaned_at > ORPHAN_GRACE_S:
+                logger.warning(
+                    "replica group(s) %s stopped: the others have finished "
+                    "and fewer than %d are left, so no quorum can form",
+                    left, quorum_size,
+                )
+                for i in left:
+                    done[i] = True  # the finally below stops its workers
     finally:
         for procs in groups:
             for p in procs:

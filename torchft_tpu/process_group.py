@@ -30,6 +30,7 @@ rendezvouses a new one via the KV store under a per-quorum prefix
 from __future__ import annotations
 
 import enum
+import itertools
 import logging
 import pickle
 import queue
@@ -216,9 +217,11 @@ class ProcessGroup(ABC):
         The results are the caller's own: they share no memory with
         ``arrays``, unless the call ``donate``s them. ``donate=True`` is the
         caller's word that the arrays are its own and that it will not touch
-        them again: a group may then hand them back as the result where
-        nothing is to be reduced (:class:`ProcessGroupHost` at a world of
-        one does, for plain ndarrays). A group may ignore it."""
+        them again: a group may then reduce in them and hand them back as
+        the result (:class:`ProcessGroupHost` does at every world size, for
+        plain ndarrays: at a world of one untouched, behind the ring reduced
+        in place; a ring that fails leaves them half-reduced). A group may
+        ignore it."""
 
     @abstractmethod
     def allgather(self, arrays: Sequence[Any]) -> Work:
@@ -355,14 +358,16 @@ class _Comm:
         # one socket
         self._send_locks: Dict[int, threading.Lock] = {}
         self._p2p_queues: Dict[int, "queue.Queue"] = {}
-        # persistent collective-writer worker (lazily started): ring hops and
-        # full-mesh exchanges need a concurrent writer so symmetric
-        # send/send never deadlocks on full TCP buffers, but spawning a
-        # thread PER HOP charges every collective ~2 thread creations —
-        # ruinous for the per-bucket streaming pipeline where a 16-bucket
-        # plan is 16 ops instead of one. One long-lived worker fed by a
-        # queue keeps the same concurrency at a queue-handoff price.
-        self._coll_q: Optional["queue.Queue"] = None
+        # persistent collective workers (lazily started), one queue and one
+        # thread a lane. "collwr": ring hops and full-mesh exchanges need a
+        # concurrent writer so symmetric send/send never deadlocks on full
+        # TCP buffers, but spawning a thread PER HOP charges every
+        # collective ~2 thread creations — ruinous for the per-bucket
+        # streaming pipeline where a 16-bucket plan is 16 ops instead of
+        # one. One long-lived worker fed by a queue keeps the same
+        # concurrency at a queue-handoff price. "fold": the plain ring's
+        # accumulate, beside the dispatch thread's receive.
+        self._coll_qs: Dict[str, "queue.Queue"] = {}
         # traffic accounting (benchmarks/transport_bench.py asserts the ring
         # path's world-size-independent per-rank bytes from these)
         self.bytes_sent = 0
@@ -388,6 +393,9 @@ class _Comm:
         # a topology that avoids them instead of re-discovering the failure
         # (a dead link stays avoided for the life of the generation)
         self.cring_dead: set = set()
+        # the plain ring's receive scratch (ring_scratch): two chunks, made
+        # by the first ring and warm for every one after it
+        self._ring_scratch: Optional[np.ndarray] = None
 
         # store_addr is "host:port/prefix"; the prefix (set per-quorum and
         # per-group-rank by the Manager, reference manager.py:703-705) plus the
@@ -453,39 +461,62 @@ class _Comm:
         self.bytes_recv += len(payload) + _HDR.size
         return pickle.loads(payload)
 
-    def send_raw(self, peer: int, buf: Any) -> None:
-        """Frame a raw buffer (no pickle, no concat copy): length header,
-        then the bytes straight from the caller's memory. Typed ndarrays go
+    @staticmethod
+    def _frame_views(buf: Any) -> List[memoryview]:
+        """The byte views of one frame's payload: a buffer, or a list of
+        them that travel end to end under one header. Typed ndarrays go
         through a uint8 view — memoryview can't export extended dtypes like
         ml_dtypes.bfloat16 (the dominant TPU gradient dtype)."""
-        if isinstance(buf, np.ndarray):
-            buf = buf.reshape(-1).view(np.uint8)  # reshape first: 0-d safe
-        mv = memoryview(buf).cast("B")
+        return [
+            memoryview(
+                b.reshape(-1).view(np.uint8)  # reshape first: 0-d safe
+                if isinstance(b, np.ndarray) else b
+            ).cast("B")
+            for b in (buf if isinstance(buf, list) else [buf])
+        ]
+
+    def send_raw(self, peer: int, buf: Any) -> None:
+        """Frame a raw buffer (no pickle, no concat copy): length header,
+        then the bytes straight from the caller's memory."""
+        mvs = self._frame_views(buf)
+        length = sum(len(mv) for mv in mvs)
         sock = self.peers[peer]
         with self._send_locks[peer]:
             t0 = time.perf_counter()
-            sock.sendall(_HDR.pack(len(mv)))
-            sock.sendall(mv)
+            sock.sendall(_HDR.pack(length))
+            for mv in mvs:
+                sock.sendall(mv)
             self.wire_busy_s += time.perf_counter() - t0
-            self.bytes_sent += len(mv) + _HDR.size
+            self.bytes_sent += length + _HDR.size
 
     def recv_raw_into(self, peer: int, out: Any) -> None:
-        """Receive one frame directly into a writable buffer (zero staging
-        copies on the receive side)."""
+        """Receive one frame directly into a writable buffer, or into a
+        list of them in turn (zero staging copies on the receive side)."""
         sock = self.peers[peer]
         (length,) = _HDR.unpack(_recv_exact(sock, _HDR.size))
-        if isinstance(out, np.ndarray):
-            out = out.reshape(-1).view(np.uint8)
-        mv = memoryview(out).cast("B")
-        if length != len(mv):
-            raise ValueError(f"frame size {length} != buffer size {len(mv)}")
-        got = 0
-        while got < length:
-            n = sock.recv_into(mv[got:], min(length - got, 1 << 20))
-            if n == 0:
-                raise ConnectionError("peer closed connection")
-            got += n
+        mvs = self._frame_views(out)
+        if length != sum(len(mv) for mv in mvs):
+            raise ValueError(
+                f"frame size {length} != buffer size "
+                f"{sum(len(mv) for mv in mvs)}"
+            )
+        for mv in mvs:
+            got = 0
+            while got < len(mv):
+                n = sock.recv_into(mv[got:], min(len(mv) - got, 1 << 20))
+                if n == 0:
+                    raise ConnectionError("peer closed connection")
+                got += n
         self.bytes_recv += length + _HDR.size
+
+    def ring_scratch(self, nbytes: int) -> np.ndarray:
+        """This comm's two chunks of receive scratch, ``[2, >= nbytes]``
+        bytes: a ring hop's incoming frame waits in one to be accumulated
+        while the next arrives in the other. Asked once a ring pass, for
+        its largest frame; one op at a time."""
+        if self._ring_scratch is None or self._ring_scratch.shape[1] < nbytes:
+            self._ring_scratch = np.empty((2, nbytes), np.uint8)
+        return self._ring_scratch
 
     def check_link_fault(self, a: int, b: int, hop: int) -> None:
         """Raise ConnectionError if an injected fault covers link (a, b) at
@@ -531,24 +562,26 @@ class _Comm:
             finally:
                 done.set()
 
-    def submit_write(self, job: Callable[[], None]):
-        """Run ``job`` on the persistent collective-writer thread; returns
-        ``(done_event, err_list)``. Sentinel-safe vs abort: the aborted
-        check and the enqueue share ``_lock`` with ``abort``'s sentinel
-        post, so a job can never land behind the shutdown sentinel and
-        leave its waiter blocked forever."""
+    def submit_write(self, job: Callable[[], None], lane: str = "collwr"):
+        """Run ``job`` on the persistent collective-writer thread (or on
+        another ``lane``'s); returns ``(done_event, err_list)``.
+        Sentinel-safe vs abort: the aborted check and the enqueue share
+        ``_lock`` with ``abort``'s sentinel post, so a job can never land
+        behind the shutdown sentinel and leave its waiter blocked
+        forever."""
         done = threading.Event()
         err: List[BaseException] = []
         with self._lock:
             if self.aborted:
                 raise RuntimeError("communicator aborted")
-            if self._coll_q is None:
-                self._coll_q = queue.Queue()
+            q = self._coll_qs.get(lane)
+            if q is None:
+                q = self._coll_qs[lane] = queue.Queue()
                 threading.Thread(
-                    target=self._coll_writer_loop, args=(self._coll_q,),
-                    daemon=True, name=f"pg_host_collwr_r{self.rank}",
+                    target=self._coll_writer_loop, args=(q,),
+                    daemon=True, name=f"pg_host_{lane}_r{self.rank}",
                 ).start()
-            self._coll_q.put((job, done, err))
+            q.put((job, done, err))
         return done, err
 
     def exchange(self, payloads: Dict[int, Any]) -> Dict[int, Any]:
@@ -621,8 +654,8 @@ class _Comm:
             self.aborted = True
             for q in self._p2p_queues.values():
                 q.put(None)
-            if self._coll_q is not None:
-                self._coll_q.put(None)
+            for q in self._coll_qs.values():
+                q.put(None)
             for s in self.peers.values():
                 try:
                     s.shutdown(socket.SHUT_RDWR)
@@ -641,88 +674,198 @@ class _Comm:
 # Payloads at or above this take the bandwidth-optimal ring; below it the
 # full-mesh exchange wins on latency (one round-trip vs 2*(world-1)).
 _RING_MIN_BYTES = 64 * 1024
+# A segment crosses a ring hop as a train of frames of at most this many
+# bytes: the accumulate of one overlaps the wire of the next, and a hop can
+# forward a frame while the rest of its segment is still arriving.
+_RING_CHUNK_BYTES = 4 * 1024 * 1024
 
 
-def _ring_step(comm: "_Comm", right: int, left: int,
-               send_buf: np.ndarray, recv_buf: np.ndarray) -> None:
-    """One ring hop: stream our segment to the right neighbour while
-    draining the left neighbour's into ``recv_buf``. The write rides the
-    comm's persistent collective-writer worker because both sides send
-    first — with synchronous sockets and multi-MB segments that would
-    deadlock on full TCP buffers."""
-    done, err = comm.submit_write(lambda: comm.send_raw(right, send_buf))
-    comm.recv_raw_into(left, recv_buf)
-    done.wait()
-    if err:
-        raise err[0]
+class _RingCount:
+    """A count of the ring's frames that one of its threads is done with,
+    in receive order, for another to wait on."""
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._done = 0
+        self._failed = False
+
+    def advance(self) -> None:
+        with self._cond:
+            self._done += 1
+            self._cond.notify_all()
+
+    def fail(self) -> None:
+        with self._cond:
+            self._failed = True
+            self._cond.notify_all()
+
+    def wait_past(self, frame: int) -> None:
+        """Until frame number ``frame`` is done. The receiving thread
+        always ends, by finishing or by its socket's timeout or the op's
+        watchdog closing the socket, and fails the counts when it fails:
+        this needs no clock."""
+        with self._cond:
+            while self._done <= frame and not self._failed:
+                self._cond.wait()
+            if self._failed:
+                raise ConnectionError("the ring failed on another thread")
 
 
-def _ring_allreduce(comm: "_Comm", leaves: List[np.ndarray], op: ReduceOp) -> List[np.ndarray]:
-    """Bandwidth-optimal allreduce: ring reduce-scatter + ring allgather.
+def _ring_pass(comm: "_Comm", parts: List[np.ndarray], op: ReduceOp) -> int:
+    """Ring reduce-scatter + allgather over ``parts``, 1-D arrays of one
+    dtype that are reduced where they lie, as if laid end to end. Returns
+    the frames of a hop.
 
-    Per-rank traffic is 2*(world-1)/world * payload — independent of world
-    size — versus the full-mesh exchange's (world-1) * payload (the
-    round-1 data plane's O(world x bytes) weakness). Segments move as raw
-    frames straight out of the flat working buffer: no pickling, and the
-    same bytes are never serialized twice.
-
-    Leaves are packed per dtype into one flat buffer each (gradients are
-    almost always a single dtype, so this is one ring in practice), split
-    into ``world`` segments, and unpacked at the end. Matches
-    ``_reduce_np``'s semantics: accumulate in the input dtype, AVG divides
-    by world at the end.
+    The ``world`` segments are ranges of that concatenation at multiples of
+    ``ceil(len / world)`` (the last ones shorter, or empty). Hop ``h`` of
+    ``2 * (world - 1)`` sends segment ``rank - h`` and receives segment
+    ``rank - h - 1``, a frame of ``_RING_CHUNK_BYTES`` at a time. Three
+    threads share a pass. This one, the PG's dispatch thread, receives: for
+    the first ``world - 1`` hops into the two scratch chunks in turn, after
+    that straight into the segment. The comm's "fold" worker accumulates a
+    scratch chunk into its segment while the next frame arrives in the
+    other. The comm's collective writer sends every hop's frames (both
+    sides send first: with synchronous sockets that would deadlock on full
+    TCP buffers), each as soon as the hop before is done with it: the hops
+    overlap each other, and the accumulate the wire.
     """
     world, rank = comm.world, comm.rank
     right, left = (rank + 1) % world, (rank - 1) % world
-    out: List[Optional[np.ndarray]] = [None] * len(leaves)
+    dtype = parts[0].dtype
+    ends = np.cumsum([p.size for p in parts])
+    total = int(ends[-1])
+    seg_len = -(-total // world)
+    step = max(1, _RING_CHUNK_BYTES // dtype.itemsize)
+
+    def frames(seg: int) -> List[Tuple[int, int]]:
+        lo, hi = min(seg * seg_len, total), min((seg + 1) * seg_len, total)
+        return [(a, min(a + step, hi)) for a in range(lo, hi, step)]
+
+    seg_frames = [frames(seg) for seg in range(world)]
+
+    def views(a: int, b: int) -> List[np.ndarray]:
+        out = []
+        i = int(np.searchsorted(ends, a, side="right"))
+        while a < b:
+            start, stop = int(ends[i]) - parts[i].size, min(b, int(ends[i]))
+            if stop > a:  # a leaf of no elements has no bytes to frame
+                out.append(parts[i][a - start:stop - start])
+            a, i = stop, i + 1
+        return out
+
+    scratch = comm.ring_scratch(step * dtype.itemsize)
+
+    def waiting(k: int, a: int, b: int) -> np.ndarray:
+        """Where frame ``k`` of the reduce-scatter waits to be folded."""
+        return scratch[k % 2, :(b - a) * dtype.itemsize].view(dtype)
+
+    hops = 2 * (world - 1)
+    recv_frames = [seg_frames[(rank - h - 1) % world] for h in range(hops)]
+    # the number, in receive order, of each hop's first frame
+    first = [0, *itertools.accumulate(len(f) for f in recv_frames)]
+    to_fold = [ab for f in recv_frames[:world - 1] for ab in f]
+    # frames in receive order: those that have arrived in the scratch, and
+    # those that are where they belong (folded, or received in place)
+    arrived, done = _RingCount(), _RingCount()
+
+    def _writes() -> None:
+        for h in range(hops):
+            for k, (a, b) in enumerate(seg_frames[(rank - h) % world]):
+                if h:
+                    done.wait_past(first[h - 1] + k)
+                comm.send_raw(right, views(a, b))
+
+    def _folds() -> None:
+        try:
+            for k, (a, b) in enumerate(to_fold):
+                arrived.wait_past(k)
+                got = waiting(k, a, b)
+                for v in views(a, b):
+                    _accum(op, v, got[:v.size])
+                    got = got[v.size:]
+                done.advance()
+        except BaseException:
+            done.fail()  # the receiver and the writer may be waiting
+            raise
+
+    try:
+        wrote, write_err = comm.submit_write(_writes)
+        folded, fold_err = comm.submit_write(_folds, lane="fold")
+        for k, (a, b) in enumerate(to_fold):
+            if k >= 2:
+                done.wait_past(k - 2)  # what waited in this chunk is folded
+            comm.recv_raw_into(left, waiting(k, a, b))
+            arrived.advance()
+        folded.wait()
+        if fold_err:
+            raise fold_err[0]
+        for h in range(world - 1, hops):
+            for a, b in recv_frames[h]:
+                comm.recv_raw_into(left, views(a, b))
+                done.advance()
+    except BaseException:
+        arrived.fail()
+        done.fail()
+        raise
+    wrote.wait()
+    if write_err:
+        raise write_err[0]
+    return len(recv_frames[0])
+
+
+def _ring_allreduce(
+    comm: "_Comm",
+    leaves: List[np.ndarray],
+    op: ReduceOp,
+    donate: bool = False,
+    info: Optional[Dict[str, int]] = None,
+) -> List[np.ndarray]:
+    """Bandwidth-optimal allreduce: ring reduce-scatter + ring allgather
+    (:func:`_ring_pass`), in the leaves' own memory where the caller gave
+    it up.
+
+    Per-rank traffic is 2*(world-1)/world * payload — independent of world
+    size — versus the full-mesh exchange's (world-1) * payload (the
+    round-1 data plane's O(world x bytes) weakness). Frames are raw bytes
+    straight out of the arrays: no pickling, and nothing is packed.
+
+    A leaf is reduced in place, and is its own result, when the call
+    ``donate``s it and it is a writable C-contiguous array whose reduction
+    keeps its dtype (everything but AVG of integers). Any other leaf is
+    left as it was: the ring works in one private copy of it, which is the
+    result. Leaves of one dtype ride one ring (gradients are almost always
+    a single dtype, so this is one ring in practice). Matches
+    ``_reduce_np``'s semantics: accumulate in the input dtype, AVG divides
+    by world at the end. ``info`` receives ``inplace`` (1 when no leaf was
+    copied) and ``chunks`` (frames a hop).
+    """
+    out: List[np.ndarray] = []
+    for a in leaves:
+        keeps_dtype = not (
+            op == ReduceOp.AVG and np.issubdtype(a.dtype, np.integer)
+        )
+        if (donate and keeps_dtype and a.flags.c_contiguous
+                and a.flags.writeable):
+            out.append(a)
+        else:
+            out.append(np.array(a, order="C", copy=True))
+    info = {} if info is None else info
+    info["inplace"] = int(all(o is a for o, a in zip(out, leaves)))
+    info["chunks"] = 0
 
     groups: Dict[Any, List[int]] = {}
-    for i, a in enumerate(leaves):
+    for i, a in enumerate(out):
         groups.setdefault(a.dtype, []).append(i)
-
-    for dtype, idxs in sorted(groups.items(), key=lambda kv: str(kv[0])):
-        flat_len = sum(leaves[i].size for i in idxs)
-        seg_len = max(1, -(-flat_len // world))
-        buf = np.zeros(seg_len * world, dtype)
-        ofs = 0
-        for i in idxs:
-            n = leaves[i].size
-            buf[ofs:ofs + n] = leaves[i].ravel()
-            ofs += n
-        segs = buf.reshape(world, seg_len)
-        recv_buf = np.empty(seg_len, dtype)
-
-        # reduce-scatter: after world-1 hops, this rank holds the fully
-        # reduced segment (rank+1) % world
-        for step in range(world - 1):
-            s_idx = (rank - step) % world
-            r_idx = (rank - step - 1) % world
-            _ring_step(comm, right, left, segs[s_idx], recv_buf)
-            _accum(op, segs[r_idx], recv_buf)
-
-        # allgather: circulate the reduced segments
-        for step in range(world - 1):
-            s_idx = (rank + 1 - step) % world
-            r_idx = (rank - step) % world
-            _ring_step(comm, right, left, segs[s_idx], segs[r_idx])
-
+    for _dtype, idxs in sorted(groups.items(), key=lambda kv: str(kv[0])):
+        info["chunks"] += _ring_pass(
+            comm, [out[i].reshape(-1) for i in idxs], op)
         if op == ReduceOp.AVG:
-            if np.issubdtype(buf.dtype, np.integer):
-                buf = buf / world  # float result, matching _reduce_np
-            else:
-                buf /= world
-
-        ofs = 0
-        for i in idxs:
-            n = leaves[i].size
-            # copy: returned leaves must be independent arrays (the exchange
-            # path's contract) — views into the shared flat buffer would
-            # alias each other under callers' in-place updates and pin the
-            # whole padded buffer alive
-            out[i] = buf[ofs:ofs + n].reshape(leaves[i].shape).copy()
-            ofs += n
-
-    return out  # type: ignore[return-value]
+            for i in idxs:
+                if np.issubdtype(out[i].dtype, np.integer):
+                    out[i] = out[i] / comm.world  # float, as _reduce_np
+                else:
+                    out[i] /= comm.world
+    return out
 
 
 class _LinkFailure(Exception):
@@ -1483,7 +1626,8 @@ class ProcessGroupHost(ProcessGroup):
                     pass
 
     def _submit(self, fn: Callable[["_Comm"], Any], name: str = "op",
-                mode: str = "collective") -> Work:
+                mode: str = "collective",
+                ring: Optional[Dict[str, int]] = None) -> Work:
         _fr.recorder.record(
             "collective", op=name, rank=self._rank, world=self._world
         )
@@ -1495,6 +1639,10 @@ class ProcessGroupHost(ProcessGroup):
                 raise gen.error
             gen.claim_mode(mode)
             fut: Future[Any] = Future()
+            if ring is not None:
+                # what the op's ring will say of itself (inplace, chunks),
+                # beside the dispatch thread's stamps: allreduce/wire_run
+                fut.ring = ring
             gen.queue.put((fn, fut, time.perf_counter()))
             return FutureWork(fut)
 
@@ -1503,6 +1651,7 @@ class ProcessGroupHost(ProcessGroup):
         from torchft_tpu.ops.quantization import CompressedWire
 
         host = [_to_host(a) for a in arrays]
+        info: Dict[str, int] = {}  # filled by the ring, if one runs
 
         def _run(comm):
             # compressed buckets always ride the self-healing ring: it is
@@ -1527,12 +1676,13 @@ class ProcessGroupHost(ProcessGroup):
                 ]
             if comm.world == 1:
                 # nothing to reduce. A donated ndarray is its own result
-                # (the caller gave it up: no copy, no fresh pages). Anything
-                # else gets an independent copy: at world >= 2 results never
-                # alias the inputs (the ring/exchange paths allocate), and
-                # the degraded single-replica fleet honors the same contract
-                # towards every caller that did not donate. _copy_payload is
-                # tuple-safe (quantized wire), and tuples are always copied.
+                # (the caller gave it up: no copy, no fresh pages), as it
+                # is behind the ring. Anything else gets an independent
+                # copy: at world >= 2 the results of what was not donated
+                # never alias the inputs (the ring copies, the exchange
+                # allocates), and the degraded single-replica fleet honors
+                # the same contract. _copy_payload is tuple-safe (quantized
+                # wire), and tuples are always copied.
                 return [
                     h if donate and isinstance(h, np.ndarray)
                     else _copy_payload(h)
@@ -1544,7 +1694,7 @@ class ProcessGroupHost(ProcessGroup):
             if all(isinstance(h, np.ndarray) for h in host) and (
                 sum(h.nbytes for h in host) >= _RING_MIN_BYTES
             ):
-                return _ring_allreduce(comm, host, op)
+                return _ring_allreduce(comm, host, op, donate, info)
             payload = {r: host for r in range(comm.world) if r != comm.rank}
             gathered = comm.exchange({**payload, comm.rank: host})
             return [
@@ -1552,7 +1702,7 @@ class ProcessGroupHost(ProcessGroup):
                 for i in range(len(host))
             ]
 
-        return self._submit(_run, "allreduce")
+        return self._submit(_run, "allreduce", ring=info)
 
     def allgather(self, arrays):
         host = [_to_host(a) for a in arrays]

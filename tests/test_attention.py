@@ -163,7 +163,7 @@ class TestSplashBlockEnv:
             monkeypatch.setenv(k, v)
         seen = {}
 
-        def fake_kernel(n_q_heads, seq_len, block, block_kv, interpret):
+        def fake_kernel(n_q_heads, seq_len, block, block_kv, interpret, window=None):
             seen.update(block=block, block_kv=block_kv)
             raise _Stop()
 

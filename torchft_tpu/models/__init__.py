@@ -56,6 +56,8 @@ def model_fns(cfg: LlamaConfig) -> ModelFns:
     from torchft_tpu.models.ling import (
         LING_FROZEN, LingConfig, ling_init, ling_loss_and_stats, ling_param_specs)
     from torchft_tpu.models.llama import llama_stages
+    from torchft_tpu.models.mellum import (
+        MellumConfig, mellum_init, mellum_loss_and_stats, mellum_param_specs)
     from torchft_tpu.models.moe import (
         MoEConfig, moe_init, moe_loss_and_stats, moe_param_specs, moe_stages)
     from torchft_tpu.parallel.mesh import llama_param_specs
@@ -68,6 +70,18 @@ def model_fns(cfg: LlamaConfig) -> ModelFns:
                 "overflow_pairs", "groups_hit_mean") if k in stats}}
 
         return ModelFns(ling_init, loss, ling_param_specs, None, LING_FROZEN)
+
+    if isinstance(cfg, MellumConfig):  # before MoEConfig: it is one
+        def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
+            value, stats = mellum_loss_and_stats(*args, **kw)
+            return value, {
+                "moe_stats": {"moe_" + k: stats[k] for k in (
+                    "load_max_over_mean", "held_pair_share", "overflow_pairs")
+                    if k in stats},
+                "attn_stats": {"attn_" + k: stats[k] for k in (
+                    "window_layers", "full_layers", "window_block_share")}}
+
+        return ModelFns(mellum_init, loss, mellum_param_specs, None)
 
     if isinstance(cfg, Lfm2Config):  # before MoEConfig: it is one
         def loss(*args: Any, **kw: Any) -> Tuple[Any, Dict[str, Any]]:
@@ -115,16 +129,19 @@ def model_fns(cfg: LlamaConfig) -> ModelFns:
 def _register_presets() -> None:
     """``CONFIGS`` is the registry ``--config`` reads: the MoE and the
     hybrid presets stand in it beside the dense ones (an MoEConfig and a
-    JambaConfig are LlamaConfigs, an Lfm2Config and a LingConfig MoEConfigs) under their own
+    JambaConfig are LlamaConfigs, an Lfm2Config, a LingConfig and a MellumConfig
+    MoEConfigs) under their own
     names; ``debug`` is taken, so the MoE one is ``moe_debug``."""
     from torchft_tpu.models.jamba import JAMBA_CONFIGS
     from torchft_tpu.models.lfm2 import LFM2_CONFIGS
     from torchft_tpu.models.ling import LING_CONFIGS
+    from torchft_tpu.models.mellum import MELLUM_CONFIGS
     from torchft_tpu.models.moe import MOE_CONFIGS
 
     for name, cfg in MOE_CONFIGS.items():
         CONFIGS.setdefault("moe_debug" if name == "debug" else name, cfg)
-    for name, cfg in {**JAMBA_CONFIGS, **LFM2_CONFIGS, **LING_CONFIGS}.items():
+    for name, cfg in {**JAMBA_CONFIGS, **LFM2_CONFIGS, **LING_CONFIGS,
+                      **MELLUM_CONFIGS}.items():
         CONFIGS.setdefault(name, cfg)
 
 

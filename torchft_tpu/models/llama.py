@@ -175,13 +175,15 @@ def _rope(x: jax.Array, theta: float, positions: jax.Array) -> jax.Array:
 
 
 def _attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, cfg: LlamaConfig
+    q: jax.Array, k: jax.Array, v: jax.Array, cfg: LlamaConfig,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Default causal GQA attention: Pallas flash kernel on TPU, XLA
-    elsewhere (torchft_tpu/ops/attention.py)."""
+    elsewhere (torchft_tpu/ops/attention.py); ``window``: over each query's
+    last ``window`` positions (a kind with window layers passes it)."""
     from torchft_tpu.ops.attention import causal_attention
 
-    return causal_attention(q, k, v, cfg)
+    return causal_attention(q, k, v, cfg, window=window)
 
 
 def make_llama_layer_body(

@@ -11,6 +11,14 @@ identical semantics runs.
 Layout contract matches torchft_tpu.models.llama: q [B, S, Hq, hd],
 k/v [B, S, Hkv, hd] (GQA: Hq a multiple of Hkv), causal, scaled by
 1/sqrt(hd). Output [B, S, Hq, hd].
+
+``window`` (None: every earlier position) makes it a causal WINDOW: query
+``i`` sees the keys ``j <= i`` with ``i - j < window``, its own among them
+(``transformers``' ``kv_idx > q_idx - sliding_window``). The splash kernel
+is built with a local mask and never visits a key/value block that lies
+wholly outside the window, so its time follows the window's work and not the
+sequence's (:func:`window_block_share`); the XLA path masks; the flash
+kernel has no such mask and refuses.
 """
 
 from __future__ import annotations
@@ -18,7 +26,7 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +36,7 @@ __all__ = [
     "xla_attention",
     "flash_attention_tpu",
     "splash_attention_tpu",
+    "window_block_share",
 ]
 
 
@@ -39,27 +48,39 @@ def _repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
     return k, v
 
 
-def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.Array:
-    """Plain XLA causal GQA attention (materialized scores, f32 softmax)."""
+def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any,
+                  window: Optional[int] = None) -> jax.Array:
+    """Plain XLA causal GQA attention (materialized scores, f32 softmax),
+    over the last ``window`` positions where one is given."""
     hd = q.shape[-1]
     k, v = _repeat_kv(q, k, v)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     scores = scores / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     S = q.shape[1]
     mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((S, S), jnp.bool_), -window)
     scores = jnp.where(mask[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def flash_attention_tpu(
-    q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any
+    q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Pallas flash attention (TPU only; full custom-vjp fwd+bwd)."""
+    """Pallas flash attention (TPU only; full custom-vjp fwd+bwd). Causal
+    over the whole sequence and nothing else: a ``window`` is refused."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes,
         flash_attention,
     )
+
+    if window is not None:
+        raise ValueError(
+            f"flash attention has no window mask (window={window}): the "
+            "splash kernel runs a causal window (TORCHFT_TPU_ATTENTION=auto "
+            "or splash)")
 
     hd = q.shape[-1]
     k, v = _repeat_kv(q, k, v)
@@ -96,11 +117,65 @@ def flash_attention_tpu(
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
 
+# the splash kernel's tile a side under a window (see _splash_tile)
+WINDOW_TILE = 512
+
+
+def _splash_tile(seq_len: int, window: Optional[int]) -> int:
+    """The splash kernel's tile, rows of queries and of keys alike, at
+    ``seq_len``.
+
+    Causal over the whole sequence: the largest tile that divides S, up to
+    1024: larger tiles amortize the online-softmax bookkeeping until VMEM
+    runs out — at 2048 the forward kernel is RESOURCE_EXHAUSTED in vmem on a
+    v5e ([4, 2048, 16|8, 128] bf16, jax 0.9.0 / libtpu 0.0.34; chip run,
+    PR 21).
+
+    Under a window the tile also decides how much the kernel computes
+    outside it: a tile is visited whole or not at all, so a query tile of
+    ``b`` rows under a window of ``w`` visits ``w / b + 1`` key/value tiles
+    (one 1,024 tile a side under a 1,024 window computes twice the
+    in-window work, 512 a side half as much again). Read on a v5e at
+    [1, 32768, 32 | 4, 128] bf16 under a window of 1,024 (chip run, PR 43),
+    forward / forward and backward: tiles of 512 8.49 / 30.9 ms, of 1,024
+    10.3 / 35.2, of 256 14.2 / 48.1, 512 x 1,024 10.5 / 36.6, 1,024 x 512
+    10.2 / 36.3, 256 x 512 9.8 / 38.1; the causal kernel there 67.8 / 251.7
+    at 1,024 and 74.3 / 301.3 at 512. So a window takes ``WINDOW_TILE`` =
+    512 a side and the whole sequence keeps 1,024; no knob.
+    """
+    tile = next(b for b in (1024, 512, 256, 128) if seq_len % b == 0)
+    return tile if window is None else min(tile, WINDOW_TILE)
+
+
+def _visited(seq_len: int, tile: int, window: Optional[int]) -> int:
+    """The (i, j) score entries a splash kernel of ``tile`` visits: whole
+    tiles, those that hold an allowed pair (``j <= i`` and, under a window,
+    ``i - j < window``). The others it skips."""
+    tiles = 0
+    for lo in range(0, seq_len, tile):  # a tile of queries lo .. lo + tile - 1
+        first = 0 if window is None else max(lo - window + 1, 0)
+        tiles += (lo + tile - 1) // tile - first // tile + 1
+    return tiles * tile * tile
+
+
+def window_block_share(seq_len: int, window: Optional[int]) -> float:
+    """What the splash kernel built for ``window`` at ``seq_len`` visits,
+    over what the causal kernel visits there: what is left of a full
+    layer's kernel work (1.0: nothing is skipped). Known when the kernel is
+    built; off the TPU it says what the kernel WOULD skip."""
+    if window is None or seq_len % 128:
+        return 1.0
+    return (_visited(seq_len, _splash_tile(seq_len, window), window)
+            / _visited(seq_len, _splash_tile(seq_len, None), None))
+
+
 @functools.lru_cache(maxsize=16)
 def _splash_kernel(n_q_heads: int, seq_len: int, block: int, block_kv: int,
-                   interpret: bool):
+                   interpret: bool, window: Optional[int] = None):
     """Build (and cache) a splash-attention kernel: mask construction and
-    kernel specialization are trace-time work worth amortizing.
+    kernel specialization are trace-time work worth amortizing. ``window``
+    (None: causal over the whole sequence) makes the mask a causal local
+    one, whose out-of-window blocks the kernel never visits.
 
     ``block`` tiles the query dimension, ``block_kv`` the key/value
     dimension (asymmetric tiles let a sweep trade VMEM pressure on the KV
@@ -116,9 +191,9 @@ def _splash_kernel(n_q_heads: int, seq_len: int, block: int, block_kv: int,
         splash_attention_mask as sm,
     )
 
-    mask = sm.MultiHeadMask(
-        [sm.CausalMask((seq_len, seq_len))] * n_q_heads
-    )
+    one = (sm.CausalMask((seq_len, seq_len)) if window is None else
+           sm.LocalMask((seq_len, seq_len), window_size=(window - 1, 0), offset=0))
+    mask = sm.MultiHeadMask([one] * n_q_heads)
     block = min(block, seq_len)
     block_kv = min(block_kv, seq_len)
     bs = sk.BlockSizes(
@@ -147,8 +222,10 @@ def splash_attention_tpu(
     v: jax.Array,
     cfg: Any,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """GQA-native splash attention (fwd+bwd Pallas kernels).
+    """GQA-native splash attention (fwd+bwd Pallas kernels), causal over
+    the whole sequence or over the last ``window`` positions.
 
     Unlike `flash_attention_tpu` this never materializes the repeated K/V
     heads: the kernel maps query-head groups onto shared KV heads directly,
@@ -164,11 +241,7 @@ def splash_attention_tpu(
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     S = qt.shape[2]
-    # the largest tile that divides S, up to 1024: larger tiles amortize
-    # the online-softmax bookkeeping until VMEM runs out — at 2048 the
-    # forward kernel is RESOURCE_EXHAUSTED in vmem on a v5e ([4, 2048,
-    # 16|8, 128] bf16, jax 0.9.0 / libtpu 0.0.34; chip run, PR 21)
-    blk = next(b for b in (1024, 512, 256, 128) if S % b == 0)
+    blk = _splash_tile(S, window)
     # benchmark escape hatch: benchmarks/mfu_sweep.py sweeps these to find
     # the best tiles for a given chip generation; training code leaves them
     # unset. BLOCK sets both dimensions, BLOCK_KV overrides the kv side.
@@ -188,7 +261,7 @@ def splash_attention_tpu(
                 f"TORCHFT_TPU_SPLASH_BLOCK_KV={blk_kv} does not divide "
                 f"seq_len {S}"
             )
-    kernel = _splash_kernel(qt.shape[1], S, blk, blk_kv, interpret)
+    kernel = _splash_kernel(qt.shape[1], S, blk, blk_kv, interpret, window)
     out = jax.vmap(kernel)(qt, kt, vt)  # [B, Hq, S, hd]
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)
 
@@ -207,13 +280,16 @@ ATTENTION_CHOICES = ("auto", "splash", "flash", "reference")
 LAST_DISPATCH: "str | None" = None
 
 
-def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.Array:
-    """Backend-dispatching causal attention.
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any,
+                     window: Optional[int] = None) -> jax.Array:
+    """Backend-dispatching causal attention, over the whole sequence or
+    (``window``) over each query's last ``window`` positions.
 
     On TPU: splash attention when the model is GQA/MQA (KV heads stay
-    unrepeated — group-factor less HBM traffic) or its values are narrower
-    than its keys (latent attention: the flash kernel takes one width),
-    plain flash otherwise;
+    unrepeated — group-factor less HBM traffic), its values are narrower
+    than its keys (latent attention: the flash kernel takes one width) or
+    the layer has a window (the flash kernel has no such mask: pinned to
+    ``flash`` a window is an error), plain flash otherwise;
     shapes the kernels cannot tile are an error there, never a quiet
     switch to materialized scores. Off TPU (the CPU test platform) the XLA
     reference runs. ``TORCHFT_TPU_ATTENTION=auto|splash|flash|reference``
@@ -228,18 +304,19 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg: Any) -> jax.
         )
     if choice == "reference" or not _on_tpu():
         LAST_DISPATCH = "xla"
-        return xla_attention(q, k, v, cfg)
+        return xla_attention(q, k, v, cfg, window)
     S, hd = q.shape[1], q.shape[-1]
     if S % 128 != 0 or hd not in (64, 128, 256):
         raise ValueError(
-            f"attention shape seq_len={S} head_dim={hd} does not tile the "
-            "TPU kernels (seq_len % 128 == 0, head_dim in 64/128/256); set "
+            f"attention shape seq_len={S} head_dim={hd} window={window} does "
+            "not tile the TPU kernels (seq_len % 128 == 0, head_dim in "
+            "64/128/256; a window is splash's local mask at any length); set "
             "TORCHFT_TPU_ATTENTION=reference to run the XLA reference "
             "(materialized f32 scores) on purpose"
         )
     if choice == "splash" or (choice == "auto" and (
-            q.shape[2] != k.shape[2] or v.shape[-1] != hd)):
+            q.shape[2] != k.shape[2] or v.shape[-1] != hd or window is not None)):
         LAST_DISPATCH = "splash"
-        return splash_attention_tpu(q, k, v, cfg)
+        return splash_attention_tpu(q, k, v, cfg, window=window)
     LAST_DISPATCH = "flash"
-    return flash_attention_tpu(q, k, v, cfg)
+    return flash_attention_tpu(q, k, v, cfg, window)

@@ -92,12 +92,14 @@ def make_sp_attention_fn(mesh: Mesh, kernel):
     """Shared shard_map wrapper for the sequence-parallel attention
     strategies: ``kernel(q, k, v, cfg)`` runs per shard under the one
     (dp, fsdp) x sp x tp sharding contract, so ring and ulysses cannot
-    drift apart on specs."""
+    drift apart on specs. What a layer asks for beyond that (``window=``)
+    goes to the kernel as it came: the dispatcher of ops/attention.py takes
+    it, the ring and ulysses kernels have no window and say so."""
     qspec = P(("dp", "fsdp"), "sp", "tp", None)
 
-    def attention_fn(q, k, v, cfg):
+    def attention_fn(q, k, v, cfg, **asked):
         fn = shard_map(
-            partial(kernel, cfg=cfg),
+            partial(kernel, cfg=cfg, **asked),
             mesh=mesh,
             in_specs=(qspec, qspec, qspec),
             out_specs=qspec,

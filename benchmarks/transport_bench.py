@@ -18,7 +18,16 @@
    step's buckets as the trainer sends them (bf16, back to back):
 
     python benchmarks/transport_bench.py --transport allreduce --world 4 \
-        --elements 189532160,315367424 --donate
+        --elements 189532160,315367424 --donate [--lanes 1|2|4|8]
+
+   (``--lanes``: how many connections a ring neighbour the frames ride, the
+   sweep that sets ``process_group._RING_LANES``), and the two readings
+   under it, with no ring: what N loopback streams carry between the ranks,
+   and what the fold's add does on several threads:
+
+    python benchmarks/transport_bench.py --transport streams --world 4 \
+        --size-mb 1536
+    python benchmarks/transport_bench.py --transport bf16_add
 
 Prints one JSON line per run.
 """
@@ -470,43 +479,57 @@ def bench_allreduce(size_mb: int, timeout: float) -> None:
             }), flush=True)
 
 
-def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
-                     chunk_mb: float, timeout: float) -> None:
-    """One step's buckets through the plain ring, ``world`` ranks as
-    processes on this host: the step's wall time and, per rank, where the
-    ring's dispatch thread spent it (see :func:`_ring_child`)."""
+def _spawn_ranks(flag: str, spec: dict, world: int, timeout: float) -> list:
+    """``world`` copies of this script as ranks of ``spec`` (each gets the
+    KV store's address in it); their last stdout lines, parsed."""
     import subprocess
 
     from torchft_tpu.coordination import KvStoreServer
 
     store = KvStoreServer("127.0.0.1:0")
-    spec = json.dumps({
-        "addr": f"127.0.0.1:{store.port}/bench_ring", "world": world,
-        "elements": elements, "donate": donate, "iters": iters,
-        "chunk_mb": chunk_mb, "timeout": timeout,
-    })
+    text = json.dumps({**spec, "addr": f"127.0.0.1:{store.port}/bench",
+                       "world": world})
     try:
         procs = [
             subprocess.Popen(
                 [sys.executable, os.path.abspath(__file__),
-                 "--_ring-child", spec, "--_ring-rank", str(r)],
+                 flag, text, "--_ring-rank", str(r)],
                 stdout=subprocess.PIPE, text=True,
             )
             for r in range(world)
         ]
-        ranks = [json.loads(p.communicate(timeout=timeout * (iters + 2))[0]
-                            .strip().splitlines()[-1]) for p in procs]
+        return [json.loads(p.communicate(timeout=timeout)[0]
+                           .strip().splitlines()[-1]) for p in procs]
     finally:
         store.shutdown()
+
+
+def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
+                     chunk_mb: float, lanes: int, python_frames: bool,
+                     dtype: str, timeout: float) -> None:
+    """One step's buckets through the plain ring, ``world`` ranks as
+    processes on this host: the step's wall time and, per rank and lane,
+    where the ring's threads spent it (see :func:`_ring_child`)."""
+    ranks = _spawn_ranks("--_ring-child", {
+        "elements": elements, "donate": donate, "iters": iters,
+        "chunk_mb": chunk_mb, "lanes": lanes, "python_frames": python_frames,
+        "dtype": dtype, "timeout": timeout,
+    }, world, timeout * (iters + 2))
     assert len({r["crc"] for r in ranks}) == 1, "ranks disagree"
-    keys = ("step_s", "ring_s", "recv_s", "accum_s", "rest_s", "send_s")
-    nbytes = 2 * sum(elements)
+    nbytes = ranks[0]["itemsize"] * sum(elements)
+    by_lane = ("recv_s", "fold_s", "send_s")
     print(json.dumps({
         "transport": "allreduce", "algo": "ring_split", "world": world,
-        "dtype": "bfloat16", "elements": elements, "donate": donate,
-        "chunk_mb": ranks[0]["chunk_mb"], "iters": iters,
+        "dtype": dtype, "elements": elements, "donate": donate,
+        "chunk_mb": ranks[0]["chunk_mb"], "lanes": ranks[0]["lanes"],
+        "native_fold": ranks[0]["native_fold"],
+        "native_frames": ranks[0]["native_frames"], "iters": iters,
         # each the median over iterations of one rank, then the slowest rank
-        **{k: round(max(r[k] for r in ranks), 4) for k in keys},
+        **{k: round(max(r[k] for r in ranks), 4) for k in ("step_s", "ring_s")},
+        # seconds a step inside the socket receive, the fold and the socket
+        # send, a list by lane (a lane's three are three threads)
+        **{k: [round(max(r[k][lane] for r in ranks), 4)
+               for lane in range(len(ranks[0][k]))] for k in by_lane},
         "gbit_per_s": round(
             nbytes * 8 / max(r["step_s"] for r in ranks) / 1e9, 2),
         "inplace": ranks[0]["inplace"],
@@ -516,14 +539,13 @@ def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
 
 def _ring_child(spec: dict, rank: int) -> None:
     """One rank of :func:`bench_ring_split`. The split is read off the
-    functions both the old ring and the streamed one call, so one command
-    sizes either: ``recv_s`` is the dispatch thread inside
-    ``recv_raw_into`` (the hops' socket time, waiting for the peer
-    included), ``accum_s`` whichever thread inside ``_accum``, ``rest_s``
-    what is left of the dispatch thread's ``_ring_allreduce`` beside its
-    own receives and accumulates (the old ring's allocation, pack and
-    result copies; the streamed one's waits for its fold worker and its
-    writer); ``send_s`` is the writer thread inside ``send_raw``."""
+    functions the ring's threads call: ``recv_s`` a lane's receiver inside
+    ``recv_raw_into`` (socket time, waiting for the peer included),
+    ``fold_s`` its fold inside ``_fold``, ``send_s`` its writer inside
+    ``send_raw``; ``ring_s`` the dispatch thread inside ``_ring_allreduce``.
+    ``lanes`` and ``chunk_mb`` are the bench's own arguments: they set the
+    module's constants in this process, the library takes none."""
+    import re
     import statistics
     import threading
     import zlib
@@ -533,35 +555,57 @@ def _ring_child(spec: dict, rank: int) -> None:
     import torchft_tpu.process_group as pg_mod
     from torchft_tpu.process_group import ProcessGroupHost, ReduceOp
 
-    if spec["chunk_mb"] and hasattr(pg_mod, "_RING_CHUNK_BYTES"):
+    if spec["chunk_mb"]:
         pg_mod._RING_CHUNK_BYTES = int(spec["chunk_mb"] * 2**20)
-    spent = dict.fromkeys(
-        ("ring_s", "recv_s", "accum_s", "accum_own_s", "send_s"), 0.0)
+    if spec["lanes"]:
+        pg_mod._RING_LANES = spec["lanes"]
+    pg_mod._RING_LANE_FLOOR_BYTES = (
+        2 * pg_mod._RING_LANES * pg_mod._RING_CHUNK_BYTES)
+    lanes = pg_mod._RING_LANES
+    spent = {"ring_s": 0.0, **{
+        (k, lane): 0.0 for k in ("recv_s", "fold_s", "send_s")
+        for lane in range(lanes)}}
 
-    def timed(fn, key, own_key=None):
-        """``own_key``: the part of ``key`` spent on the dispatch thread."""
+    real_native, in_frame = pg_mod._native_ring, threading.local()
+
+    def timed(fn, key):
+        """A key by lane has one thread writing it: the argument ``lane``,
+        or the digits before a ring worker's ``_r<rank>``."""
         def wrapper(*args, **kwargs):
+            in_frame.on = key in ("recv_s", "send_s")
             t0 = time.perf_counter()
             try:
                 return fn(*args, **kwargs)
             finally:
-                dt = time.perf_counter() - t0
-                spent[key] += dt
-                if own_key and threading.current_thread().name.startswith(
-                        "pg_host_dispatch"):
-                    spent[own_key] += dt
+                in_frame.on = False
+                k = key
+                if key != "ring_s":
+                    lane = kwargs.get("lane")
+                    if lane is None:
+                        lane = int(re.search(
+                            r"(\d*)_r\d+$",
+                            threading.current_thread().name).group(1) or 0)
+                    k = (key, lane)
+                spent[k] += time.perf_counter() - t0
         return wrapper
 
+    if spec["python_frames"]:
+        # inside send_raw / recv_raw_into the library is told it has no
+        # native calls; the lanes and the fold keep theirs
+        pg_mod._native_ring = (
+            lambda: None if getattr(in_frame, "on", False) else real_native())
+
     pg_mod._ring_allreduce = timed(pg_mod._ring_allreduce, "ring_s")
-    pg_mod._accum = timed(pg_mod._accum, "accum_s", "accum_own_s")
+    pg_mod._fold = timed(pg_mod._fold, "fold_s")
     pg_mod._Comm.recv_raw_into = timed(pg_mod._Comm.recv_raw_into, "recv_s")
     pg_mod._Comm.send_raw = timed(pg_mod._Comm.send_raw, "send_s")
 
     pg = ProcessGroupHost(timeout=spec["timeout"])
     pg.configure(spec["addr"], rank, spec["world"], quorum_id=1)
     rng = np.random.default_rng(rank)
+    dtype = np.dtype(getattr(ml_dtypes, spec["dtype"], spec["dtype"]))
     grads = [
-        (rng.standard_normal(n, np.float32) * 0.01).astype(ml_dtypes.bfloat16)
+        (rng.standard_normal(n, np.float32) * 0.01).astype(dtype)
         for n in spec["elements"]
     ]
     # the staging buffers of a pool: made once, warm, refilled every step
@@ -580,22 +624,153 @@ def _ring_child(spec: dict, rank: int) -> None:
         ]
         outs = [f.wait(spec["timeout"])[0] for f in futs]
         rows.append({"step_s": time.perf_counter() - t0, **spent})
-        info = getattr(futs[0], "ring", {})
+        info = getattr(futs[-1], "ring", {})
     pg.shutdown()
     rows = rows[1:]
-    row = {k: statistics.median(r[k] for r in rows) for k in rows[0]}
-    row["rest_s"] = row["ring_s"] - row["recv_s"] - row.pop("accum_own_s")
+    med = {k: statistics.median(r[k] for r in rows) for k in rows[0]}
+    row = {"step_s": med["step_s"], "ring_s": med["ring_s"]}
+    for k in ("recv_s", "fold_s", "send_s"):
+        row[k] = [med[k, lane] for lane in range(lanes)]
     row["inplace"] = info.get("inplace", 0)
-    row["chunk_mb"] = getattr(pg_mod, "_RING_CHUNK_BYTES", 0) / 2**20
+    row["lanes"] = info.get("lanes", 1)
+    row["native_fold"] = int(real_native() is not None)
+    row["native_frames"] = int(
+        real_native() is not None and not spec["python_frames"])
+    row["chunk_mb"] = pg_mod._RING_CHUNK_BYTES / 2**20
+    row["itemsize"] = dtype.itemsize
     row["crc"] = "%08x" % zlib.crc32(b"".join(
-        o.view(np.uint16).tobytes()[:1 << 24] for o in outs))
+        o.view(np.uint8).tobytes()[:1 << 24] for o in outs))
     print(json.dumps(row), flush=True)
+
+
+def bench_streams(world: int, size_mb: int, timeout: float) -> None:
+    """What the host's loopback carries between ring neighbours, with no
+    ring: every rank (a process) sends ``size_mb`` to its right neighbour
+    and receives as much from its left at once, over 1 / 2 / 4 / 8 sockets,
+    each with a sender and a receiver thread and 4 MiB ``sendall`` /
+    ``recv_into`` calls. GB/s a direction a rank, the slowest rank's."""
+    for socks in (1, 2, 4, 8):
+        ranks = _spawn_ranks("--_streams-child", {
+            "size_mb": size_mb, "socks": socks, "timeout": timeout,
+        }, world, timeout)
+        print(json.dumps({
+            "transport": "streams", "world": world, "sockets": socks,
+            "size_mb": size_mb,
+            "seconds": round(max(r["seconds"] for r in ranks), 4),
+            "gb_per_s_a_direction": round(
+                size_mb / 1024 / max(r["seconds"] for r in ranks), 3),
+        }), flush=True)
+
+
+def _streams_child(spec: dict, rank: int) -> None:
+    import socket
+    import threading
+
+    from torchft_tpu.coordination import KvClient
+
+    world, socks, timeout = spec["world"], spec["socks"], spec["timeout"]
+    host_port, _, prefix = spec["addr"].partition("/")
+    kv = KvClient(host_port, connect_timeout=timeout)
+    listener = socket.socket()
+    listener.bind(("127.0.0.1", 0))
+    listener.listen(socks)
+    key = f"{prefix}/s{socks}"
+    kv.set(f"{key}/addr_{rank}", str(listener.getsockname()[1]),
+           timeout=timeout)
+    port = int(kv.get(f"{key}/addr_{(rank + 1) % world}", timeout=timeout))
+    outs = [socket.create_connection(("127.0.0.1", port)) for _ in range(socks)]
+    ins = [listener.accept()[0] for _ in range(socks)]
+    frame = 4 << 20
+    each = spec["size_mb"] * 2**20 // socks // frame * frame
+    src = np.ones(each, np.uint8)
+    dsts = [np.empty(each, np.uint8) for _ in range(socks)]
+
+    def send(sock):
+        mv = memoryview(src)
+        for a in range(0, each, frame):
+            sock.sendall(mv[a:a + frame])
+
+    def recv(sock, dst):
+        mv, got = memoryview(dst), 0
+        while got < each:
+            got += sock.recv_into(mv[got:], min(each - got, 1 << 20))
+
+    def once():
+        threads = [threading.Thread(target=send, args=(s,)) for s in outs]
+        threads += [threading.Thread(target=recv, args=(s, d))
+                    for s, d in zip(ins, dsts)]
+        t0 = time.perf_counter()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        return time.perf_counter() - t0
+
+    took = []
+    for i in range(4):  # the first warms the pages
+        kv.set(f"{key}/go{i}_{rank}", "1", timeout=timeout)
+        for r in range(world):
+            kv.get(f"{key}/go{i}_{r}", timeout=timeout)
+        took.append(once())
+    print(json.dumps({"seconds": sorted(took[1:])[1]}), flush=True)
+
+
+def bench_bf16_add(frame_mb: int = 4, total_mb: int = 512) -> None:
+    """The fold's add over bf16 frames of ``frame_mb`` on 1 / 2 / 4 threads
+    of one process: ml_dtypes' ``dst += src`` (holds the interpreter's lock)
+    against the native loop (lets go of it). G elements a second, all
+    threads together."""
+    import threading
+
+    import ml_dtypes
+
+    import torchft_tpu.process_group as pg_mod
+    from torchft_tpu.process_group import ReduceOp
+
+    n = frame_mb * 2**20 // 2
+    reps = total_mb // frame_mb
+    native = pg_mod._native_ring()
+    for how in ("ml_dtypes", "native"):
+        if how == "native" and native is None:
+            continue
+        for threads in (1, 2, 4):
+            bufs = [
+                (np.full(n, 0.5, np.float32).astype(ml_dtypes.bfloat16),
+                 np.full(n, 1e-3, np.float32).astype(ml_dtypes.bfloat16))
+                for _ in range(threads)
+            ]
+
+            def work(dst, src):
+                for _ in range(reps // threads):
+                    if how == "native":
+                        pg_mod._fold(ReduceOp.SUM, dst, src)
+                    else:
+                        pg_mod._accum(ReduceOp.SUM, dst, src)
+
+            took = []
+            for _ in range(4):  # the first warms up
+                ts = [threading.Thread(target=work, args=b) for b in bufs]
+                t0 = time.perf_counter()
+                for t in ts:
+                    t.start()
+                for t in ts:
+                    t.join()
+                took.append(time.perf_counter() - t0)
+            dt = sorted(took[1:])[1]
+            print(json.dumps({
+                "transport": "bf16_add", "how": how, "threads": threads,
+                "frame_mb": frame_mb,
+                "g_elements_per_s": round(
+                    reps // threads * threads * n / dt / 1e9, 3),
+            }), flush=True)
 
 
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument(
-        "--transport", choices=["http", "pg", "allreduce"], default="http"
+        "--transport",
+        choices=["http", "pg", "allreduce", "streams", "bf16_add"],
+        default="http",
     )
     parser.add_argument("--size-mb", type=int, default=256)
     parser.add_argument("--num-chunks", type=int, default=8,
@@ -642,7 +817,21 @@ def main() -> None:
     parser.add_argument("--chunk-mb", type=float, default=0.0,
                         help="allreduce --elements: the ring's frame size "
                              "(default: the module's)")
+    parser.add_argument("--lanes", type=int, default=0,
+                        help="allreduce --elements: the ring's lanes, "
+                             "connections a neighbour (default: the "
+                             "module's); the sweep that sets the constant")
+    parser.add_argument("--dtype", default="bfloat16",
+                        choices=["bfloat16", "float32"],
+                        help="allreduce --elements: the buckets' dtype (the "
+                             "native fold is bfloat16's; float32 is numpy's)")
+    parser.add_argument("--python-frames", action="store_true",
+                        help="allreduce --elements: move the frames with "
+                             "Python's sendall / recv_into, not the native "
+                             "calls (what the ring falls back to)")
     parser.add_argument("--_recv-child", default="", help=argparse.SUPPRESS)
+    parser.add_argument("--_streams-child", default="",
+                        help=argparse.SUPPRESS)
     parser.add_argument("--_ring-child", default="", help=argparse.SUPPRESS)
     parser.add_argument("--_ring-rank", type=int, default=0,
                         help=argparse.SUPPRESS)
@@ -650,6 +839,15 @@ def main() -> None:
 
     if args._ring_child:
         _ring_child(json.loads(args._ring_child), args._ring_rank)
+        return
+    if args._streams_child:
+        _streams_child(json.loads(args._streams_child), args._ring_rank)
+        return
+    if args.transport == "streams":
+        bench_streams(args.world, args.size_mb, args.timeout)
+        return
+    if args.transport == "bf16_add":
+        bench_bf16_add()
         return
 
     if args.check and not args.two_process:
@@ -675,7 +873,8 @@ def main() -> None:
     if args.transport == "allreduce" and args.elements:
         bench_ring_split(
             args.world, [int(n) for n in args.elements.split(",")],
-            args.donate, args.iters, args.chunk_mb, args.timeout,
+            args.donate, args.iters, args.chunk_mb, args.lanes,
+            args.python_frames, args.dtype, args.timeout,
         )
         return
     if args.transport == "allreduce":

@@ -462,7 +462,8 @@ def train(args) -> None:
                                  "overlap_efficiency", "stage_pool_hit_share",
                                  "d2h_under_backward_share",
                                  "d2h_concurrency",
-                                 "wire_passthrough_share", "trace_dropped")},
+                                 "wire_passthrough_share", "ring_lanes",
+                                 "trace_dropped")},
             "param_checksum": int(checksum(state["params"])),
             # the kind's frozen leaves alone (None: it has none): state that
             # no step may move and a heal must carry

@@ -16,7 +16,9 @@
 #include "kvstore.h"
 #include "lighthouse.h"
 #include "manager_server.h"
+#include "net.h"
 #include "quorum.h"
+#include "reduce.h"
 #include "wire.h"
 
 using namespace tft;
@@ -62,6 +64,23 @@ static int status_of(const RpcError& e) {
   }
 
 void tft_free(char* p) { free(p); }
+
+// ---- host ring frames (process_group.py) ----
+// A whole buffer over a Python socket's fd, outside the interpreter: 0
+// done, 1 the peer made no progress for idle_ms, 2 closed, else -errno.
+int tft_fd_send_all(int fd, const void* data, size_t len, int64_t idle_ms,
+                    int more) {
+  return fd_send_all(fd, data, len, idle_ms, more != 0);
+}
+int tft_fd_recv_all(int fd, void* data, size_t len, int64_t idle_ms) {
+  return fd_recv_all(fd, data, len, idle_ms);
+}
+
+// ---- host ring reductions (process_group.py) ----
+// dst += src over n bfloat16 elements, bit for bit ml_dtypes' add.
+void tft_bf16_add(void* dst, const void* src, size_t n) {
+  bf16_add(static_cast<uint16_t*>(dst), static_cast<const uint16_t*>(src), n);
+}
 
 // ---------------------------------------------------------------- lighthouse
 int tft_lighthouse_new(const char* bind, int64_t min_replicas,

@@ -275,6 +275,58 @@ std::pair<std::string, int> split_host_port(const std::string& addr) {
   return {a.substr(0, colon), std::stoi(a.substr(colon + 1))};
 }
 
+namespace {
+
+// 0 ready, kFdTimedOut, or -errno.
+int wait_fd_idle(int fd, short events, int64_t idle_ms) {
+  while (true) {
+    struct pollfd pfd{fd, events, 0};
+    int rc = poll(&pfd, 1, idle_ms < 0 ? -1 : static_cast<int>(
+        std::min<int64_t>(idle_ms, 1 << 30)));
+    if (rc > 0) return 0;
+    if (rc == 0) return kFdTimedOut;
+    if (errno != EINTR) return -errno;
+  }
+}
+
+}  // namespace
+
+int fd_send_all(int fd, const void* data, size_t len, int64_t idle_ms,
+                bool more) {
+  const char* p = static_cast<const char*>(data);
+  const int flags = MSG_NOSIGNAL | MSG_DONTWAIT | (more ? MSG_MORE : 0);
+  size_t sent = 0;
+  while (sent < len) {
+    ssize_t n = ::send(fd, p + sent, len - sent, flags);
+    if (n > 0) {
+      sent += static_cast<size_t>(n);
+      continue;
+    }
+    if (n < 0 && errno == EPIPE) return kFdClosed;
+    if (n < 0 && errno != EAGAIN && errno != EWOULDBLOCK && errno != EINTR)
+      return -errno;
+    if (int rc = wait_fd_idle(fd, POLLOUT, idle_ms)) return rc;
+  }
+  return 0;
+}
+
+int fd_recv_all(int fd, void* data, size_t len, int64_t idle_ms) {
+  char* p = static_cast<char*>(data);
+  size_t got = 0;
+  while (got < len) {
+    ssize_t n = ::recv(fd, p + got, len - got, MSG_DONTWAIT);
+    if (n > 0) {
+      got += static_cast<size_t>(n);
+      continue;
+    }
+    if (n == 0) return kFdClosed;
+    if (errno != EAGAIN && errno != EWOULDBLOCK && errno != EINTR)
+      return -errno;
+    if (int rc = wait_fd_idle(fd, POLLIN, idle_ms)) return rc;
+  }
+  return 0;
+}
+
 std::string local_hostname() {
   char buf[256];
   if (gethostname(buf, sizeof(buf)) != 0) return "localhost";

@@ -75,4 +75,14 @@ std::pair<std::string, int> split_host_port(const std::string& addr);
 
 std::string local_hostname();
 
+// A whole buffer over a socket the caller owns (a Python socket's fd, in
+// either blocking mode: the host ring's frames, process_group.py), in one
+// call outside the interpreter. idle_ms: how long the peer may make no
+// progress (negative: for ever). more: further bytes of the same message
+// follow (the frame's header). Returns 0 when done, kFdTimedOut,
+// kFdClosed (the peer closed, or shut down under us), or -errno.
+constexpr int kFdTimedOut = 1, kFdClosed = 2;
+int fd_send_all(int fd, const void* data, size_t len, int64_t idle_ms, bool more);
+int fd_recv_all(int fd, void* data, size_t len, int64_t idle_ms);
+
 }  // namespace tft

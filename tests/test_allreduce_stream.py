@@ -788,6 +788,8 @@ class TestStagingThroughThePool:
             wants.append(_reduce(ref, tree, streamed=True))
             hit.append(m.timings()["stage_pool_hit_share"])
             passed.append(m.timings()["wire_passthrough_share"])
+            if kind == "host_of_one":
+                assert m.timings()["ring_lanes"] == 1.0
             # (a sweep before a later bucket's fetch may already have
             # returned an earlier bucket's)
             assert _pooled(m) + len(_parked(m)) == 3
@@ -814,6 +816,9 @@ class TestStagingThroughThePool:
         if kind == "host_of_one":  # the PG's thread still stamps the op
             runs = [s for s in spans if s["name"] == "wire_run"]
             assert len(runs) == 15 and all(s["args"]["world"] == 1 for s in runs)
+            # no neighbour, no ring: one lane, and the step's timings say so
+            assert all(s["args"]["lanes"] == 1 for s in runs)
+            assert "inplace" not in runs[0]["args"]
 
     def test_parked_until_the_landed_leaves_are_ready(
         self, host_of_one, monkeypatch
@@ -1129,16 +1134,21 @@ def world_of_two():
 
 
 class TestTheRingWorksInTheStagingBuffer:
+    @pytest.mark.parametrize("lanes", [1, 4])
     def test_a_world_of_two_copies_nothing_and_every_step_is_right(
-        self, world_of_two, monkeypatch
+        self, world_of_two, monkeypatch, lanes
     ):
         """Behind a real ring the donated pool buffer comes back reduced:
         `wire_passthrough_share` reads 1.0 as it does at a world of one,
         the buffers recycle, and six steps' trees are bit for bit the mean
-        of the two groups'."""
+        of the two groups', on one lane (buckets under the lane floor) and
+        on the ring's four; `wire_run` and `timings()` say which."""
         import torchft_tpu.process_group as pg_mod
 
         monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 32 * 1024)
+        assert pg_mod._RING_LANES == 4
+        if lanes == 4:
+            monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
         ms = [world_of_two(rank) for rank in range(2)]
         steps = 6
 
@@ -1148,6 +1158,7 @@ class TestTheRingWorksInTheStagingBuffer:
                 outs.append(_reduce(m, _ring_tree(rank, step), streamed=True))
                 shares.append((m.timings()["stage_pool_hit_share"],
                                m.timings()["wire_passthrough_share"]))
+                assert m.timings()["ring_lanes"] == float(lanes)
                 assert _pooled(m) + len(_parked(m)) == 3
                 _let_landed_leaves_finish(m)
                 m.should_commit()
@@ -1173,7 +1184,8 @@ class TestTheRingWorksInTheStagingBuffer:
             for s in runs:
                 frames = -(-(s["args"]["bytes"] // 2) // (32 * 1024))
                 assert (s["args"]["world"], s["args"]["inplace"],
-                        s["args"]["chunks"]) == (2, 1, frames)
+                        s["args"]["chunks"], s["args"]["lanes"]) == (
+                            2, 1, frames, lanes)
 
     def test_a_ring_that_fails_half_way_gives_no_buffer_back(
         self, world_of_two, monkeypatch

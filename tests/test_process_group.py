@@ -6,8 +6,10 @@ harness: crash a rank, expect errors on survivors, reconfigure, verify the
 collective works again (:894-950).
 """
 
+import sys
 import threading
 import time
+import types
 from concurrent.futures import ThreadPoolExecutor
 
 import ml_dtypes
@@ -549,7 +551,7 @@ class TestRingInPlaceAndStreamed:
             assert out.dtype == want.dtype and out.shape == want.shape
             assert np.array_equal(_bits(out), _bits(want)), rank
             assert info == {"inplace": int(donate and keeps_dtype),
-                            "chunks": frames}
+                            "chunks": frames, "lanes": 1}
             if donate and keeps_dtype:
                 assert out is ins[rank]
             else:  # left as it was, and the result is memory of its own
@@ -681,6 +683,546 @@ class TestRingInPlaceAndStreamed:
         want = _ring_reference(vals[:2], ReduceOp.SUM)
         for out in run_parallel(2, again):
             assert np.array_equal(_bits(out), _bits(want))
+        for pg in pgs:
+            pg.shutdown()
+
+
+@pytest.fixture(scope="module")
+def lane_meshes():
+    """One mesh a (world, lanes): ``_RING_LANES`` is read when a generation
+    connects, so it is set around ``configure`` alone."""
+    store = KvStoreServer("127.0.0.1:0")
+    made = {}
+
+    def get(world, lanes):
+        if (world, lanes) not in made:
+            before, pg_mod._RING_LANES = pg_mod._RING_LANES, lanes
+            try:
+                made[world, lanes] = make_pgs(
+                    store, world, quorum_id=10 * world + lanes, timeout=20.0,
+                    prefix="lanes")
+            finally:
+                pg_mod._RING_LANES = before
+        return made[world, lanes]
+
+    yield get
+    for pgs in made.values():
+        for pg in pgs:
+            pg.shutdown()
+    store.shutdown()
+
+
+def _ring_threads():
+    """The process group's live threads (the module's shared meshes keep
+    theirs: a test compares with what was there before it)."""
+    return {t for t in threading.enumerate() if t.name.startswith("pg_host_")}
+
+
+def _wait_no_ring_threads(but, timeout=5.0):
+    deadline = time.monotonic() + timeout
+    while _ring_threads() - but and time.monotonic() < deadline:
+        time.sleep(0.02)
+    left = sorted(t.name for t in _ring_threads() - but)
+    assert not left, f"threads left alive: {left}"
+
+
+class TestRingLanes:
+    """A segment's frames ride several connections to each ring neighbour,
+    frame ``k`` on lane ``k % lanes``, each lane with its own receiver, fold
+    and writer: the bits are the one-lane ring's, which are the
+    reference's."""
+
+    # neither the world, the frame nor the lane count divides them; the
+    # short one is a segment of fewer frames than four lanes
+    @pytest.mark.parametrize("donate", [True, False], ids=["donated", "kept"])
+    @pytest.mark.parametrize("length", [40_009, 250_007])
+    @pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVG, ReduceOp.MAX],
+                             ids=lambda o: o.value)
+    @pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16, np.float32, np.int32],
+                             ids=["bf16", "f32", "i32"])
+    @pytest.mark.parametrize("lanes", [1, 2, 4])
+    @pytest.mark.parametrize("world", [2, 3, 4])
+    def test_bitwise_the_ring_order_reduction_on_every_lane_count(
+        self, lane_meshes, monkeypatch, world, lanes, dtype, op, length, donate
+    ):
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
+        pgs = lane_meshes(world, lanes)
+        assert all(pg._gen.comm.lanes == lanes for pg in pgs)
+        vals = _ring_values(dtype, length, world, seed=lanes)
+        want = _ring_reference(vals, op)
+        ins = [v.copy() for v in vals]
+
+        def step(rank):
+            fut = pgs[rank].allreduce([ins[rank]], op, donate=donate).get_future()
+            return fut.wait(30)[0], fut.ring
+
+        keeps_dtype = not (op == ReduceOp.AVG and dtype is np.int32)
+        frames = -(-(-(-length // world)) * vals[0].itemsize // _CHUNK)
+        if length == 40_009 and world == 4:
+            assert frames < 4
+        for rank, (out, info) in enumerate(run_parallel(world, step)):
+            assert out.dtype == want.dtype and out.shape == want.shape
+            assert np.array_equal(_bits(out), _bits(want)), rank
+            assert info == {"inplace": int(donate and keeps_dtype),
+                            "chunks": frames, "lanes": lanes}
+            if donate and keeps_dtype:
+                assert out is ins[rank]
+            else:
+                assert np.array_equal(_bits(ins[rank]), _bits(vals[rank]))
+                assert not np.shares_memory(out, ins[rank])
+
+    def test_a_segment_under_the_floor_rides_lane_0_alone(
+        self, lane_meshes, monkeypatch
+    ):
+        """The floor is bytes a segment: twice as many frames as lanes at
+        the module's sizes. Under it the pass is the one-lane pass, on a
+        mesh that has the lanes."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        assert pg_mod._RING_LANE_FLOOR_BYTES == (
+            2 * pg_mod._RING_LANES * 4 * 2**20)
+        world, lanes = 3, 4
+        pgs = lane_meshes(world, lanes)
+        vals = _ring_values(np.float32, 250_007, world)
+        seg_bytes = -(-250_007 // world) * 4
+
+        def ride(floor):
+            monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", floor)
+
+            def step(rank):
+                fut = pgs[rank].allreduce(
+                    [vals[rank].copy()], ReduceOp.SUM).get_future()
+                return fut.wait(30)[0], fut.ring["lanes"]
+            return run_parallel(world, step)
+
+        want = _ring_reference(vals, ReduceOp.SUM)
+        for floor, rode in ((seg_bytes + 1, 1), (seg_bytes, lanes)):
+            for out, got in ride(floor):
+                assert got == rode
+                assert np.array_equal(_bits(out), _bits(want))
+
+    def test_leaves_cross_lanes_and_the_counters_cover_every_lane(
+        self, lane_meshes, monkeypatch
+    ):
+        """Frames cut across leaf boundaries on every lane, and a rank's
+        traffic is what one lane's would be: ``2 (world - 1) / world`` of
+        the payload and a header a frame."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
+        world, lanes = 4, 4
+        pgs = lane_meshes(world, lanes)
+        shapes = [(257, 129), (50_001,), (0, 3), (33, 3, 7), (), (5,)]
+        vals = [[_ring_values(np.float32, int(np.prod(shape)), 1,
+                              seed=7 * r + i)[0].reshape(shape)
+                 for i, shape in enumerate(shapes)] for r in range(world)]
+        comms = [pg._gen.comm for pg in pgs]
+        before = [(c.bytes_sent, c.bytes_recv) for c in comms]
+        # 48 threads count into four comms: switch between them as often as
+        # the interpreter can, so an unlocked read-modify-write would lose
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            outs = run_parallel(world, lambda r: pgs[r].allreduce(
+                [v.copy() for v in vals[r]], ReduceOp.SUM
+            ).get_future().wait(30))
+        finally:
+            sys.setswitchinterval(interval)
+        want = _ring_reference(
+            [np.concatenate([v.reshape(-1) for v in vals[r]])
+             for r in range(world)], ReduceOp.SUM)
+        total = want.size
+        seg = -(-total // world)
+        for r in range(world):
+            got = np.concatenate([o.reshape(-1) for o in outs[r]])
+            assert np.array_equal(_bits(got), _bits(want)), r
+            sent = comms[r].bytes_sent - before[r][0]
+            recv = comms[r].bytes_recv - before[r][1]
+            segs = [max(0, min(total, (s + 1) * seg) - s * seg)
+                    for s in range(world)]
+            frames = [-(-4 * n // _CHUNK) for n in segs]
+            out_segs = [(r - h) % world for h in range(2 * (world - 1))]
+            in_segs = [(r - h - 1) % world for h in range(2 * (world - 1))]
+            assert sent == sum(4 * segs[s] + 8 * frames[s] for s in out_segs)
+            assert recv == sum(4 * segs[s] + 8 * frames[s] for s in in_segs)
+
+    def test_a_world_of_one_opens_no_lane(self, store):
+        (pg,) = make_pgs(store, 1, prefix="one")
+        comm = pg._gen.comm
+        assert comm.lanes == 1 and comm.lane_socks == [{}] and not comm.peers
+        x = np.ones(100_000, np.float32)
+        fut = pg.allreduce([x], ReduceOp.SUM, donate=True).get_future()
+        assert fut.wait(10)[0] is x and fut.ring == {"lanes": 1}
+        pg.shutdown()
+
+    def test_a_world_of_two_shares_a_lanes_socket_both_ways(self, lane_meshes):
+        a, b = (pg._gen.comm for pg in lane_meshes(2, 4))
+        assert a.lane_socks[0] is a.peers
+        assert [list(s) for s in a.lane_socks] == [[1]] * 4
+        assert [list(s) for s in b.lane_socks] == [[0]] * 4
+        for lane in range(4):
+            assert (a.lane_socks[lane][1].getsockname()
+                    == b.lane_socks[lane][0].getpeername())
+
+    @pytest.mark.parametrize("how", ["lane_socket_closed", "abort"])
+    @pytest.mark.parametrize("donate", [True, False], ids=["donated", "kept"])
+    def test_a_lane_that_dies_mid_pass_fails_every_rank_and_leaves_no_thread(
+        self, store, monkeypatch, how, donate
+    ):
+        """Rank 2's lane 1 loses its socket after its third frame (or rank
+        2 is aborted there). Its receiver fails every count of every lane
+        of the pass; the peers read closed sockets or starve into their
+        sockets' timeout. Each op raises once, with every thread of its
+        pass ended, and a shut-down group leaves no thread alive."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
+        monkeypatch.setattr(pg_mod, "_RING_LANES", 4)
+        before = _ring_threads()
+        world, timeout = 3, 2.0
+        pgs = make_pgs(store, world, quorum_id=1, timeout=timeout,
+                       prefix="midlane")
+        vals = _ring_values(ml_dtypes.bfloat16, 500_009, world)
+        ins = [v.copy() for v in vals]
+        comm = pgs[2]._gen.comm
+        assert comm.lanes == 4
+        recv, seen, names = comm.recv_raw_into, [], set()
+
+        def dying_recv(peer, out, lane=0):
+            if lane == 1:
+                seen.append(peer)
+                if len(seen) == 3:
+                    # the dispatch thread may still be starting lane 3
+                    for _ in range(100):
+                        names.update(
+                            t.name for t in _ring_threads() - before)
+                        if "pg_host_recv3_r2" in names:
+                            break
+                        time.sleep(0.01)
+                    if how == "abort":
+                        pgs[2].abort()
+                    else:
+                        sock = comm.lane_socks[1][peer]
+                        sock.shutdown(2)
+                        sock.close()
+            return recv(peer, out, lane)
+
+        comm.recv_raw_into = dying_recv
+
+        def step(rank):
+            t0 = time.monotonic()
+            with pytest.raises(Exception) as err:
+                pgs[rank].allreduce(
+                    [ins[rank]], ReduceOp.SUM, donate=donate
+                ).get_future().wait(timeout=10)
+            assert not isinstance(err.value, pg_mod._RingFailed)
+            return time.monotonic() - t0
+
+        took = run_parallel(world, step)
+        assert max(took) < timeout + 2.0, took
+        assert all(pg.errored() is not None for pg in pgs)
+        if not donate:
+            for rank in range(world):
+                assert np.array_equal(_bits(ins[rank]), _bits(vals[rank]))
+        # mid-pass every lane had its three threads
+        for lane in (1, 2, 3):
+            for kind in ("recv", "fold", "collwr"):
+                assert f"pg_host_{kind}{lane}_r2" in names, names
+        for pg in pgs:
+            pg.shutdown()
+        _wait_no_ring_threads(before)
+
+
+@pytest.mark.parametrize("lanes", [1, 2])
+def test_the_transport_bench_sweeps_lanes_and_splits_a_step_by_lane(lanes):
+    """``transport_bench.py --transport allreduce --elements ... --lanes N``:
+    ranks as processes, the bench's own argument sets the module's constant
+    in them; a list of seconds by lane for receive, fold and send, and the
+    ``result_crc`` of the ring-order reference whatever the lanes."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import zlib
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    world, n = 2, 300_001
+    out = subprocess.run(
+        [sys.executable, os.path.join(repo, "benchmarks", "transport_bench.py"),
+         "--transport", "allreduce", "--world", str(world), "--elements",
+         str(n), "--donate", "--iters", "1", "--chunk-mb", "0.03125",
+         "--lanes", str(lanes), "--timeout", "60"],
+        capture_output=True, text=True, timeout=240, cwd=repo,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode == 0, (out.stderr or out.stdout)[-2000:]
+    row = json.loads(out.stdout.strip().splitlines()[-1])
+    assert (row["lanes"], row["inplace"], row["native_fold"],
+            row["native_frames"]) == (lanes, 1, 1, 1)
+    for key in ("recv_s", "fold_s", "send_s"):
+        assert len(row[key]) == lanes and all(v > 0 for v in row[key]), row
+    vals = [
+        (np.random.default_rng(r).standard_normal(n, np.float32) * 0.01)
+        .astype(ml_dtypes.bfloat16) for r in range(world)
+    ]
+    want = _ring_reference(vals, ReduceOp.SUM)
+    assert row["result_crc"] == "%08x" % zlib.crc32(
+        want.view(np.uint16).tobytes()[:1 << 24])
+
+
+class TestNativeFrames:
+    """native/net.cc's ``fd_send_all`` / ``fd_recv_all`` move a frame's run
+    over a Python socket's fd in one call, and say what Python's own calls
+    would have raised."""
+
+    @pytest.mark.parametrize("timeout", [None, 5.0], ids=["blocking", "timed"])
+    def test_a_whole_buffer_each_way(self, timeout):
+        import socket
+
+        native = pg_mod._native_ring()
+        a, b = socket.socketpair()
+        a.settimeout(timeout)
+        b.settimeout(timeout)
+        src = np.random.default_rng(0).integers(0, 256, 9_000_001).astype(np.uint8)
+        dst = np.zeros_like(src)
+        rcs = []
+        t = threading.Thread(target=lambda: rcs.append(native.fd_send_all(
+            a.fileno(), src.ctypes.data, src.size, pg_mod._idle_ms(a), 0)))
+        t.start()
+        assert native.fd_recv_all(
+            b.fileno(), dst.ctypes.data, dst.size, pg_mod._idle_ms(b)) == 0
+        t.join(10)
+        assert rcs == [0] and np.array_equal(src, dst)
+        a.close()
+        b.close()
+
+    def test_silence_a_closed_peer_and_a_closed_socket(self):
+        import errno
+        import socket
+
+        native = pg_mod._native_ring()
+        a, b = socket.socketpair()
+        buf = np.zeros(1024, np.uint8)
+        t0 = time.monotonic()
+        rc = native.fd_recv_all(b.fileno(), buf.ctypes.data, buf.size, 200)
+        assert rc == 1 and 0.15 < time.monotonic() - t0 < 2.0
+        with pytest.raises(socket.timeout):
+            pg_mod._raise_fd(rc, "recv")
+        a.sendall(b"x" * 100)  # part of a run, then the peer goes
+        a.close()
+        rc = native.fd_recv_all(b.fileno(), buf.ctypes.data, buf.size, 5000)
+        assert rc == 2
+        with pytest.raises(ConnectionError):
+            pg_mod._raise_fd(rc, "recv")
+        big = np.zeros(8 << 20, np.uint8)  # more than the buffers hold
+        rc = native.fd_send_all(b.fileno(), big.ctypes.data, big.size, 5000, 0)
+        assert rc in (2, -errno.ECONNRESET)
+        fd = b.fileno()
+        b.close()
+        rc = native.fd_recv_all(fd, buf.ctypes.data, buf.size, 100)
+        assert rc == -errno.EBADF
+        with pytest.raises(OSError) as err:
+            pg_mod._raise_fd(rc, "recv")
+        assert err.value.errno == errno.EBADF
+        pg_mod._raise_fd(0, "recv")  # done: nothing raised
+
+    def test_a_read_only_target_is_refused_before_any_byte(self, store):
+        pgs = make_pgs(store, 2, prefix="readonly")
+        target = np.frombuffer(b"\0" * 64, np.uint8)
+        sender = threading.Thread(
+            target=pgs[0]._gen.comm.send_raw, args=(1, np.ones(64, np.uint8)))
+        sender.start()
+        with pytest.raises(ValueError, match="read-only"):
+            pgs[1]._gen.comm.recv_raw_into(0, target)
+        sender.join(10)
+        assert bytes(target) == b"\0" * 64
+        for pg in pgs:
+            pg.shutdown()
+
+    def test_a_shutdown_under_a_waiting_call_ends_it(self):
+        """What ``abort`` does to a receiver that waits in the native
+        call: the socket is shut down from another thread."""
+        import socket
+
+        native = pg_mod._native_ring()
+        a, b = socket.socketpair()
+        buf = np.zeros(1024, np.uint8)
+        rcs = []
+        t = threading.Thread(target=lambda: rcs.append(native.fd_recv_all(
+            b.fileno(), buf.ctypes.data, buf.size, -1)))
+        t.start()
+        time.sleep(0.1)
+        b.shutdown(socket.SHUT_RDWR)
+        t.join(5)
+        assert rcs == [2]
+        a.close()
+        b.close()
+
+    @pytest.mark.parametrize("python_side", ["sender", "receiver"])
+    def test_frames_are_the_same_bytes_either_way(
+        self, store, monkeypatch, python_side
+    ):
+        """A rank that moves its frames with Python's calls and one that
+        moves them natively are on one wire: a list of runs of several
+        dtypes with an empty one among them, into a bytearray and arrays."""
+        real = pg_mod._native_ring
+        monkeypatch.setattr(
+            pg_mod, "_native_ring",
+            lambda: None if threading.current_thread().name == python_side
+            else real())
+        pgs = make_pgs(store, 2, prefix="mixedframes")
+        comms = [pg._gen.comm for pg in pgs]
+        runs = [np.arange(70_001, dtype=np.float32),
+                np.ones((3, 5), ml_dtypes.bfloat16), np.zeros((0,), np.int64),
+                np.frombuffer(b"0123456789abcdef", np.uint8)]
+        outs = [np.zeros_like(runs[0]), np.zeros_like(runs[1]),
+                np.zeros_like(runs[2]), bytearray(16)]
+        sender = threading.Thread(
+            target=comms[0].send_raw, args=(1, runs), name="sender")
+        receiver = threading.Thread(
+            target=comms[1].recv_raw_into, args=(0, outs), name="receiver")
+        sender.start()
+        receiver.start()
+        sender.join(10)
+        receiver.join(10)
+        for got, want in zip(outs[:3], runs):
+            assert np.array_equal(_bits(got), _bits(want))
+        assert bytes(outs[3]) == b"0123456789abcdef"
+        nbytes = sum(r.nbytes for r in runs) + 8
+        assert comms[0].bytes_sent == comms[1].bytes_recv == nbytes
+        for pg in pgs:
+            pg.shutdown()
+
+
+def _bf16(bits):
+    return np.asarray(bits, np.uint16).view(ml_dtypes.bfloat16)
+
+
+# NaNs of both signs, quiet and signalling, infinities, zeros, the smallest
+# and largest subnormals and normals, ties of the rounding, and a spread
+_BF16_OTHERS = np.unique(np.concatenate([
+    np.array([0x0000, 0x8000, 0x0001, 0x8001, 0x007f, 0x807f, 0x0080, 0x8080,
+              0x7f7f, 0xff7f, 0x7f80, 0xff80, 0x7f81, 0xff81, 0x7fc0, 0xffc0,
+              0x7fff, 0xffff, 0x3f80, 0xbf80, 0x3f81, 0x3c00, 0x4000, 0x7f00],
+             np.uint16),
+    np.random.default_rng(45).integers(0, 1 << 16, 48).astype(np.uint16),
+]))
+
+
+class TestNativeBf16Add:
+    """native/reduce.cc's add is ml_dtypes' ``dst += src`` bit for bit."""
+
+    @pytest.mark.parametrize("view", ["whole", "odd_start_odd_length",
+                                      "unaligned_bytes"])
+    @pytest.mark.parametrize("others_are", ["src", "dst"])
+    def test_every_bit_pattern_against_a_spread_of_others(
+        self, others_are, view
+    ):
+        assert pg_mod._native_ring() is not None, "the library exports it"
+        assert len(_BF16_OTHERS) >= 64
+        every = np.tile(np.arange(1 << 16, dtype=np.uint16), len(_BF16_OTHERS))
+        other = np.repeat(_BF16_OTHERS, 1 << 16)
+        dst_bits, src_bits = (
+            (every, other) if others_are == "src" else (other, every))
+        if view == "odd_start_odd_length":
+            dst_bits, src_bits = dst_bits[1:-2], src_bits[3:]
+        n = dst_bits.size
+        with np.errstate(all="ignore"):
+            want = _bf16(dst_bits.copy())
+            want += _bf16(src_bits)
+        if view == "unaligned_bytes":  # elements at odd addresses
+            raw = [bytearray(2 * n + 1), bytearray(2 * n + 1)]
+            dst, src = (np.frombuffer(r, np.uint16, n, offset=1) for r in raw)
+            assert dst.ctypes.data % 2 == 1 and not dst.flags.aligned
+            dst[:], src[:] = dst_bits, src_bits
+        else:
+            dst, src = dst_bits.copy(), src_bits.copy()
+        pg_mod._fold(ReduceOp.SUM, dst.view(ml_dtypes.bfloat16),
+                     src.view(ml_dtypes.bfloat16))
+        wrong = np.flatnonzero(dst != want.view(np.uint16))
+        assert wrong.size == 0, [
+            (hex(dst_bits[i]), hex(src_bits[i]), hex(dst[i]),
+             hex(want.view(np.uint16)[i])) for i in wrong[:5]]
+        assert np.array_equal(src, src_bits)  # src is read only
+
+    def test_the_fold_takes_the_native_add_only_where_it_is_the_same_sum(
+        self, monkeypatch
+    ):
+        calls = []
+        real = pg_mod._native_ring()
+        monkeypatch.setattr(pg_mod, "_NATIVE", types.SimpleNamespace(
+            bf16_add=lambda *a: (calls.append(a), real.bf16_add(*a))[1]))
+        b = np.ones(64, ml_dtypes.bfloat16)
+        for op, dst, native in [
+            (ReduceOp.SUM, b.copy(), True), (ReduceOp.AVG, b.copy(), True),
+            (ReduceOp.MAX, b.copy(), False), (ReduceOp.PRODUCT, b.copy(), False),
+            (ReduceOp.SUM, np.ones(64, np.float32), False),
+            (ReduceOp.SUM, np.ones(128, ml_dtypes.bfloat16)[::2], False),
+        ]:
+            calls.clear()
+            src = np.full(64, 2, dst.dtype)
+            want = dst.copy()
+            pg_mod._accum(op, want, src)
+            pg_mod._fold(op, dst, src)
+            assert bool(calls) == native, (op, dst.dtype)
+            assert np.array_equal(_bits(dst), _bits(want))
+
+    def test_without_the_symbol_the_ring_is_numpys_on_one_lane(
+        self, store, monkeypatch, caplog
+    ):
+        import torchft_tpu.coordination as coordination
+
+        monkeypatch.setattr(coordination, "native_ring", lambda: None)
+        monkeypatch.setattr(pg_mod, "_NATIVE", ())
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
+        world = 2
+        with caplog.at_level("WARNING", logger=pg_mod.__name__):
+            pgs = make_pgs(store, world, prefix="nosymbol")
+            again = make_pgs(store, world, quorum_id=2, prefix="nosymbol")
+        said = [r for r in caplog.records if "tft_bf16_add" in r.getMessage()
+                and "tft_fd_recv_all" in r.getMessage()]
+        assert len(said) == 1  # once, however many generations
+        assert all(pg._gen.comm.lanes == 1 for pg in pgs + again)
+        vals = _ring_values(ml_dtypes.bfloat16, 250_007, world)
+        want = _ring_reference(vals, ReduceOp.SUM)
+
+        def step(rank):
+            fut = pgs[rank].allreduce([vals[rank].copy()]).get_future()
+            return fut.wait(30)[0], fut.ring["lanes"]
+
+        for out, lanes in run_parallel(world, step):
+            assert lanes == 1
+            assert np.array_equal(_bits(out), _bits(want))
+        for pg in pgs + again:
+            pg.shutdown()
+
+    def test_a_rank_without_it_holds_every_rank_to_one_lane(
+        self, store, monkeypatch
+    ):
+        """The lanes are agreed through the store: a rank that can run
+        fewer (its library lacks the fold) sets the count for all, or the
+        connects would not pair up."""
+        real = pg_mod._native_ring
+
+        def per_thread():
+            if threading.current_thread().name == "poor":
+                return None
+            return real()
+
+        monkeypatch.setattr(pg_mod, "_native_ring", per_thread)
+        world = 3
+        pgs = [ProcessGroupHost(timeout=10.0) for _ in range(world)]
+        addr = f"127.0.0.1:{store.port}/mixed"
+        threads = [
+            threading.Thread(
+                target=pgs[r].configure, args=(addr, r, world, 1),
+                name="poor" if r == 1 else f"rich{r}")
+            for r in range(world)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(20)
+        assert [pg._gen.comm.lanes for pg in pgs] == [1, 1, 1]
         for pg in pgs:
             pg.shutdown()
 

@@ -52,6 +52,11 @@ DECLARED_TIMINGS: Dict[str, str] = {
         "device buckets of the last streamed allreduce whose collective "
         "resolved to the staging buffer it was given, over all of them"
     ),
+    "ring_lanes": (
+        "connections to a ring neighbour that the step's last host ring "
+        "rode: process_group._RING_LANES, or 1 under its floor, at a world "
+        "of one and where the native fold is missing"
+    ),
     "collective_reroute": "cumulative mid-collective link reroutes",
     # control plane (two-level)
     "via_aggregator": "1 when control RPCs ride the pod aggregator",

@@ -1027,6 +1027,7 @@ class _StepTally:
         self.stage_sums: Dict[str, float] = {}
         self.hidden_s = 0.0
         self.from_device = self.passed_through = 0
+        self.ring_lanes = 0  # of the newest ring among them; 0: none ran
         # the newest device capture of the step (the caller's thread sets
         # it): while ITS pieces are not ready, gradients are being computed
         self.newest: Optional[Pieces] = None
@@ -1708,6 +1709,14 @@ class BucketPipeline:
                 # the wire
                 stats["wire_passthrough_share"] = (
                     tally.passed_through / tally.from_device)
+            # the connections to a ring neighbour that the step's last ring
+            # rode (process_group._RING_LANES; 1 under its floor, at a
+            # world of one, or where the native fold is missing)
+            lanes = [r["lanes"] for r in op.wire_rings if "lanes" in r]
+            if lanes:
+                tally.ring_lanes = lanes[-1]
+            if tally.ring_lanes:
+                stats["ring_lanes"] = float(tally.ring_lanes)
         self._on_timings(stats)
         for i, mark in enumerate(op.marks):
             for name in ("pack", "wire", "unpack"):

@@ -16,9 +16,10 @@ import ctypes
 import json
 import os
 import subprocess
+import types
 from dataclasses import dataclass, field
 from datetime import timedelta
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .retry import RetryPolicy, retry_call
 
@@ -186,6 +187,37 @@ def _load() -> ctypes.CDLL:
         ]
         _lib = lib
     return _lib
+
+
+def native_ring() -> Optional[Any]:
+    """What the host ring runs outside the interpreter (ctypes lets go of
+    its lock for a call), as attributes, or None where the library cannot
+    be had or does not export them (one built before they existed,
+    installed without its sources):
+
+    - ``bf16_add(dst, src, n)``: native/reduce.cc, two addresses and an
+      element count;
+    - ``fd_send_all(fd, data, n, idle_ms, more)`` and ``fd_recv_all(fd,
+      data, n, idle_ms)``: native/net.cc, a whole buffer over a Python
+      socket's fd; 0 done, 1 no progress within ``idle_ms`` (negative: no
+      limit), 2 closed, else ``-errno``.
+    """
+    try:
+        lib = _load()
+        fns = types.SimpleNamespace(
+            bf16_add=lib.tft_bf16_add, fd_send_all=lib.tft_fd_send_all,
+            fd_recv_all=lib.tft_fd_recv_all)
+    except (AttributeError, OSError, RuntimeError,
+            subprocess.CalledProcessError):
+        return None
+    fns.bf16_add.argtypes = [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t]
+    fns.bf16_add.restype = None
+    fns.fd_send_all.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t,
+                                ctypes.c_int64, ctypes.c_int]
+    fns.fd_recv_all.argtypes = [ctypes.c_int, ctypes.c_void_p, ctypes.c_size_t,
+                                ctypes.c_int64]
+    fns.fd_send_all.restype = fns.fd_recv_all.restype = ctypes.c_int
+    return fns
 
 
 def _take_str(lib: ctypes.CDLL, ptr: "ctypes.c_char_p | int | None") -> str:

@@ -32,6 +32,7 @@ from __future__ import annotations
 import enum
 import itertools
 import logging
+import os
 import pickle
 import queue
 import socket
@@ -323,6 +324,79 @@ def _recv_msg(sock: socket.socket) -> bytes:
     return _recv_exact(sock, length)
 
 
+_NATIVE: Any = ()  # not asked yet; then coordination.native_ring()'s, or None
+
+
+def _native_ring() -> Optional[Any]:
+    """The native library's part of the ring (``bf16_add``, ``fd_send_all``,
+    ``fd_recv_all``: coordination.native_ring), or None where the library
+    or a symbol is missing, which is said once. ml_dtypes adds bfloat16 an
+    element at a time, and a frame through ``sendall`` / ``recv_into`` takes
+    the interpreter's lock again after every piece the kernel hands over:
+    with several lanes' threads in one process that hand-over, not the
+    copy, sets the pace."""
+    global _NATIVE
+    if _NATIVE == ():
+        from torchft_tpu.coordination import native_ring
+
+        _NATIVE = native_ring()
+        if _NATIVE is None:
+            logger.warning(
+                "native tft_bf16_add / tft_fd_send_all / tft_fd_recv_all are "
+                "missing: the host ring falls back to numpy's accumulate "
+                "and Python's socket calls on one lane (ring_lanes 1)"
+            )
+    return _NATIVE
+
+
+def _raise_fd(rc: int, what: str) -> None:
+    """A native socket call's status (coordination.native_ring) as the
+    exception Python's own call would have raised."""
+    if rc == 1:
+        raise socket.timeout(f"{what}: timed out")
+    if rc == 2:
+        raise ConnectionError(f"{what}: peer closed connection")
+    if rc:
+        raise OSError(-rc, f"{what}: {os.strerror(-rc)}")
+
+
+def _idle_ms(sock: socket.socket) -> int:
+    """The socket's timeout for a native call: how long its peer may make
+    no progress, in ms; negative for none."""
+    timeout = sock.gettimeout()
+    return -1 if timeout is None else int(timeout * 1000)
+
+
+def _send_all(sock: socket.socket, a: np.ndarray, more: bool = False) -> None:
+    """All of a flat uint8 array down a socket: one native call where the
+    library has it (``more``: the rest of the message follows at once),
+    else ``sendall``."""
+    native = _native_ring()
+    if native is None:
+        sock.sendall(memoryview(a))
+    else:
+        _raise_fd(native.fd_send_all(
+            sock.fileno(), a.ctypes.data, a.size, _idle_ms(sock), more), "send")
+
+
+def _recv_all(sock: socket.socket, a: np.ndarray) -> None:
+    """Fill a flat uint8 array from a socket: one native call where the
+    library has it, else ``recv_into`` until it is full."""
+    native = _native_ring()
+    if native is not None:
+        if not a.flags.writeable:  # as recv_into would refuse it
+            raise ValueError("cannot receive into a read-only buffer")
+        _raise_fd(native.fd_recv_all(
+            sock.fileno(), a.ctypes.data, a.size, _idle_ms(sock)), "recv")
+        return
+    mv, got = memoryview(a), 0
+    while got < len(mv):
+        n = sock.recv_into(mv[got:], min(len(mv) - got, 1 << 20))
+        if n == 0:
+            raise ConnectionError("peer closed connection")
+        got += n
+
+
 def _recv_exact(sock: socket.socket, n: int) -> bytes:
     chunks = []
     got = 0
@@ -353,10 +427,16 @@ class _Comm:
         self.aborted = False
         self._lock = threading.Lock()
         self.peers: Dict[int, socket.socket] = {}
-        # per-peer write serialization: collective writers (dispatch/ring
-        # threads) and async p2p writers must never interleave frames on
-        # one socket
-        self._send_locks: Dict[int, threading.Lock] = {}
+        # the connections by lane, then by rank: lane 0 is ``peers``, the
+        # mesh; a further lane of the plain ring holds one to the right and
+        # one from the left ring neighbour (one socket for both at a world
+        # of two)
+        self.lanes = 1
+        self.lane_socks: List[Dict[int, socket.socket]] = [self.peers]
+        # per-socket write serialization, by (lane, peer): collective
+        # writers (dispatch/ring threads) and async p2p writers must never
+        # interleave frames on one socket
+        self._send_locks: Dict[Tuple[int, int], threading.Lock] = {}
         self._p2p_queues: Dict[int, "queue.Queue"] = {}
         # persistent collective workers (lazily started), one queue and one
         # thread a lane. "collwr": ring hops and full-mesh exchanges need a
@@ -366,12 +446,14 @@ class _Comm:
         # streaming pipeline where a 16-bucket plan is 16 ops instead of
         # one. One long-lived worker fed by a queue keeps the same
         # concurrency at a queue-handoff price. "fold": the plain ring's
-        # accumulate, beside the dispatch thread's receive.
+        # accumulate, beside the dispatch thread's receive. "collwr<l>",
+        # "fold<l>", "recv<l>": the ring's lane l >= 1 (_ring_pass).
         self._coll_qs: Dict[str, "queue.Queue"] = {}
         # traffic accounting (benchmarks/transport_bench.py asserts the ring
         # path's world-size-independent per-rank bytes from these)
         self.bytes_sent = 0
         self.bytes_recv = 0
+        self._count_lock = threading.Lock()
         # send-side wire occupancy: seconds spent inside sendall pushing
         # frames into the link. Receive waits are deliberately NOT counted —
         # a recv blocked on a peer that is still computing would charge
@@ -393,8 +475,8 @@ class _Comm:
         # a topology that avoids them instead of re-discovering the failure
         # (a dead link stays avoided for the life of the generation)
         self.cring_dead: set = set()
-        # the plain ring's receive scratch (ring_scratch): two chunks, made
-        # by the first ring and warm for every one after it
+        # the plain ring's receive scratch (ring_scratch): two chunks a
+        # lane, made by the first ring and warm for every one after it
         self._ring_scratch: Optional[np.ndarray] = None
 
         # store_addr is "host:port/prefix"; the prefix (set per-quorum and
@@ -407,40 +489,72 @@ class _Comm:
         listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         listener.bind(("0.0.0.0", 0))
-        listener.listen(world)
+        listener.listen(world + _RING_LANES)
         port = listener.getsockname()[1]
         self._listener = listener
 
+        # what this rank can run: lanes pay only over a fold that lets go
+        # of the interpreter's lock (_native_ring says why, once)
+        mine = _RING_LANES if world > 1 and _native_ring() else 1
         my_host = socket.gethostname()
-        kv.set(f"{prefix}/addr_{rank}", f"{my_host}:{port}", timeout=timeout)
+        kv.set(f"{prefix}/addr_{rank}", f"{my_host}:{port}/{mine}",
+               timeout=timeout)
+        addrs: Dict[int, Tuple[str, int]] = {}
+        for j in range(world):
+            if j == rank:
+                continue
+            addr = kv.get(f"{prefix}/addr_{j}", timeout=timeout).decode()
+            addr, _, theirs = addr.partition("/")
+            host, _, p = addr.rpartition(":")
+            addrs[j] = (host, int(p))
+            # every rank rides the lanes the poorest of them has
+            mine = min(mine, int(theirs or 1))
+        self.lanes = mine
+        self.lane_socks += [{} for _ in range(1, self.lanes)]
+        right, left = (rank + 1) % world, (rank - 1) % world
+
+        def dial(j: int, lane: int) -> socket.socket:
+            s = socket.create_connection(addrs[j], timeout=timeout)
+            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            _send_msg(s, pickle.dumps(("hello", rank, lane)))
+            return s
 
         # Deterministic connection pattern: rank i dials every j < i and
-        # accepts from every j > i (with a hello byte carrying the dialer's
-        # rank so accepts can arrive in any order).
+        # accepts from every j > i (with a hello carrying the dialer's rank
+        # and lane so accepts can arrive in any order). That mesh is lane
+        # 0. For every further lane each rank dials its right ring
+        # neighbour and accepts from its left one; at a world of two rank
+        # 0 alone dials, and both directions share the socket as they
+        # share lane 0's.
         for j in range(rank):
-            addr = kv.get(f"{prefix}/addr_{j}", timeout=timeout).decode()
-            host, _, p = addr.rpartition(":")
-            s = socket.create_connection((host, int(p)), timeout=timeout)
-            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            _send_msg(s, pickle.dumps(("hello", rank)))
-            self.peers[j] = s
+            self.peers[j] = dial(j, 0)
+        if world > 2 or rank == 0:
+            for lane in range(1, self.lanes):
+                self.lane_socks[lane][right] = dial(right, lane)
+        accepts_lanes = world > 2 or rank == 1
         listener.settimeout(timeout)
-        for _ in range(world - 1 - rank):
+        for _ in range(world - 1 - rank
+                       + (self.lanes - 1 if accepts_lanes else 0)):
             s, _ = listener.accept()
             s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             # accepted sockets need the op timeout too — dialed ones carry
             # it from create_connection; without this, waits on accepted
             # sockets are unbounded and set_timeout has nothing to update
             s.settimeout(timeout)
-            tag, peer_rank = pickle.loads(_recv_msg(s))
+            tag, peer_rank, lane = pickle.loads(_recv_msg(s))
             assert tag == "hello"
-            self.peers[peer_rank] = s
-        for j in self.peers:
-            self._send_locks[j] = threading.Lock()
+            assert lane == 0 or peer_rank == left, (lane, peer_rank, left)
+            self.lane_socks[lane][peer_rank] = s
+        for lane, socks in enumerate(self.lane_socks):
+            for j in socks:
+                self._send_locks[lane, j] = threading.Lock()
+
+    def _all_socks(self) -> List[socket.socket]:
+        return [s for socks in self.lane_socks for s in socks.values()]
 
     def settimeout(self, timeout: float) -> None:
         with self._lock:
-            for s in self.peers.values():
+            for s in self._all_socks():
                 try:
                     s.settimeout(timeout)
                 except OSError:
@@ -448,75 +562,77 @@ class _Comm:
 
     def send_to(self, peer: int, obj: Any) -> None:
         payload = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-        with self._send_locks[peer]:
+        with self._send_locks[0, peer]:
             t0 = time.perf_counter()
             _send_msg(self.peers[peer], payload)
-            # counters guarded by the send lock: multiple writer threads
-            # (dispatch, ring, p2p) would race the read-modify-write
-            self.wire_busy_s += time.perf_counter() - t0
-            self.bytes_sent += len(payload) + _HDR.size
+            busy = time.perf_counter() - t0
+        self._count(sent=len(payload) + _HDR.size, busy_s=busy)
 
     def recv_from(self, peer: int) -> Any:
         payload = _recv_msg(self.peers[peer])
-        self.bytes_recv += len(payload) + _HDR.size
+        self._count(recv=len(payload) + _HDR.size)
         return pickle.loads(payload)
 
+    def _count(self, sent: int = 0, recv: int = 0, busy_s: float = 0.0) -> None:
+        """The traffic counters have writers on several threads (dispatch,
+        ring lanes, p2p): one lock, or a read-modify-write is lost."""
+        with self._count_lock:
+            self.bytes_sent += sent
+            self.bytes_recv += recv
+            self.wire_busy_s += busy_s
+
     @staticmethod
-    def _frame_views(buf: Any) -> List[memoryview]:
-        """The byte views of one frame's payload: a buffer, or a list of
-        them that travel end to end under one header. Typed ndarrays go
-        through a uint8 view — memoryview can't export extended dtypes like
-        ml_dtypes.bfloat16 (the dominant TPU gradient dtype)."""
+    def _frame_bytes(buf: Any) -> List[np.ndarray]:
+        """One frame's payload as flat uint8 arrays: a buffer, or a list of
+        them that travel end to end under one header. (Typed ndarrays go
+        through a uint8 view: memoryview can't export extended dtypes like
+        ml_dtypes.bfloat16, the dominant TPU gradient dtype.)"""
         return [
-            memoryview(
-                b.reshape(-1).view(np.uint8)  # reshape first: 0-d safe
-                if isinstance(b, np.ndarray) else b
-            ).cast("B")
+            b.reshape(-1).view(np.uint8)  # reshape first: 0-d safe
+            if isinstance(b, np.ndarray) else np.frombuffer(b, np.uint8)
             for b in (buf if isinstance(buf, list) else [buf])
         ]
 
-    def send_raw(self, peer: int, buf: Any) -> None:
+    def send_raw(self, peer: int, buf: Any, lane: int = 0) -> None:
         """Frame a raw buffer (no pickle, no concat copy): length header,
-        then the bytes straight from the caller's memory."""
-        mvs = self._frame_views(buf)
-        length = sum(len(mv) for mv in mvs)
-        sock = self.peers[peer]
-        with self._send_locks[peer]:
+        then the bytes straight from the caller's memory. ``lane``: which
+        of the connections to a ring neighbour."""
+        runs = self._frame_bytes(buf)
+        length = sum(a.size for a in runs)
+        sock = self.lane_socks[lane][peer]
+        with self._send_locks[lane, peer]:
             t0 = time.perf_counter()
-            sock.sendall(_HDR.pack(length))
-            for mv in mvs:
-                sock.sendall(mv)
-            self.wire_busy_s += time.perf_counter() - t0
-            self.bytes_sent += length + _HDR.size
+            _send_all(sock, np.frombuffer(_HDR.pack(length), np.uint8),
+                      more=length > 0)
+            for a in runs:
+                _send_all(sock, a)
+            busy = time.perf_counter() - t0
+        self._count(sent=length + _HDR.size, busy_s=busy)
 
-    def recv_raw_into(self, peer: int, out: Any) -> None:
+    def recv_raw_into(self, peer: int, out: Any, lane: int = 0) -> None:
         """Receive one frame directly into a writable buffer, or into a
         list of them in turn (zero staging copies on the receive side)."""
-        sock = self.peers[peer]
+        sock = self.lane_socks[lane][peer]
         (length,) = _HDR.unpack(_recv_exact(sock, _HDR.size))
-        mvs = self._frame_views(out)
-        if length != sum(len(mv) for mv in mvs):
+        runs = self._frame_bytes(out)
+        if length != sum(a.size for a in runs):
             raise ValueError(
                 f"frame size {length} != buffer size "
-                f"{sum(len(mv) for mv in mvs)}"
+                f"{sum(a.size for a in runs)}"
             )
-        for mv in mvs:
-            got = 0
-            while got < len(mv):
-                n = sock.recv_into(mv[got:], min(len(mv) - got, 1 << 20))
-                if n == 0:
-                    raise ConnectionError("peer closed connection")
-                got += n
-        self.bytes_recv += length + _HDR.size
+        for a in runs:
+            _recv_all(sock, a)
+        self._count(recv=length + _HDR.size)
 
-    def ring_scratch(self, nbytes: int) -> np.ndarray:
-        """This comm's two chunks of receive scratch, ``[2, >= nbytes]``
-        bytes: a ring hop's incoming frame waits in one to be accumulated
-        while the next arrives in the other. Asked once a ring pass, for
-        its largest frame; one op at a time."""
-        if self._ring_scratch is None or self._ring_scratch.shape[1] < nbytes:
-            self._ring_scratch = np.empty((2, nbytes), np.uint8)
-        return self._ring_scratch
+    def ring_scratch(self, lanes: int, nbytes: int) -> np.ndarray:
+        """This comm's receive scratch, ``[>= lanes, 2, >= nbytes]`` bytes:
+        two chunks a lane, where a ring hop's incoming frame waits to be
+        accumulated while the lane's next arrives in the other. Asked once
+        a ring pass, for its largest frame; one op at a time."""
+        have = self._ring_scratch
+        if have is None or have.shape[0] < lanes or have.shape[2] < nbytes:
+            self._ring_scratch = have = np.empty((lanes, 2, nbytes), np.uint8)
+        return have
 
     def check_link_fault(self, a: int, b: int, hop: int) -> None:
         """Raise ConnectionError if an injected fault covers link (a, b) at
@@ -546,7 +662,7 @@ class _Comm:
             if n == 0:
                 raise ConnectionError("peer closed connection")
             got += n
-        self.bytes_recv += length + _HDR.size
+        self._count(recv=length + _HDR.size)
         return length
 
     def _coll_writer_loop(self, q: "queue.Queue") -> None:
@@ -656,7 +772,7 @@ class _Comm:
                 q.put(None)
             for q in self._coll_qs.values():
                 q.put(None)
-            for s in self.peers.values():
+            for s in self._all_socks():
                 try:
                     s.shutdown(socket.SHUT_RDWR)
                 except OSError:
@@ -678,11 +794,35 @@ _RING_MIN_BYTES = 64 * 1024
 # bytes: the accumulate of one overlaps the wire of the next, and a hop can
 # forward a frame while the rest of its segment is still arriving.
 _RING_CHUNK_BYTES = 4 * 1024 * 1024
+# The frames of a segment travel on this many lanes: connections to each
+# ring neighbour, each with a receiver, a fold and a writer thread of its
+# own. One loopback stream carries what one thread pair's copies carry, and
+# streams add up (PERF.md section 6, PR 45: the sweep that set this).
+_RING_LANES = 4
+# Lanes pay where every lane gets at least two frames of a segment; a
+# smaller segment rides lane 0 alone.
+_RING_LANE_FLOOR_BYTES = 2 * _RING_LANES * _RING_CHUNK_BYTES
+
+def _fold(op: ReduceOp, dst: np.ndarray, src: np.ndarray) -> None:
+    """The ring's accumulate of one received run into its place:
+    :func:`_accum`, but for bfloat16 sums the native loop (same bits)."""
+    native = _native_ring()
+    if (native is not None and dst.dtype.name == "bfloat16"
+            and op in (ReduceOp.SUM, ReduceOp.AVG) and dst.shape == src.shape
+            and dst.dtype == src.dtype and dst.flags.writeable
+            and dst.flags.c_contiguous and src.flags.c_contiguous):
+        native.bf16_add(dst.ctypes.data, src.ctypes.data, dst.size)
+    else:
+        _accum(op, dst, src)
+
+
+class _RingFailed(ConnectionError):
+    """Raised to a ring thread that waited on a count another failed."""
 
 
 class _RingCount:
-    """A count of the ring's frames that one of its threads is done with,
-    in receive order, for another to wait on."""
+    """A count of the frames of a ring lane that one of its threads is done
+    with, in receive order, for another to wait on."""
 
     def __init__(self) -> None:
         self._cond = threading.Condition()
@@ -700,34 +840,41 @@ class _RingCount:
             self._cond.notify_all()
 
     def wait_past(self, frame: int) -> None:
-        """Until frame number ``frame`` is done. The receiving thread
-        always ends, by finishing or by its socket's timeout or the op's
-        watchdog closing the socket, and fails the counts when it fails:
-        this needs no clock."""
+        """Until frame number ``frame`` is done. A receiving thread always
+        ends, by finishing or by its socket's timeout or the op's watchdog
+        closing the socket, and fails every count when it fails: this needs
+        no clock."""
         with self._cond:
             while self._done <= frame and not self._failed:
                 self._cond.wait()
             if self._failed:
-                raise ConnectionError("the ring failed on another thread")
+                raise _RingFailed("the ring failed on another thread")
 
 
-def _ring_pass(comm: "_Comm", parts: List[np.ndarray], op: ReduceOp) -> int:
+def _ring_pass(
+    comm: "_Comm", parts: List[np.ndarray], op: ReduceOp
+) -> Tuple[int, int]:
     """Ring reduce-scatter + allgather over ``parts``, 1-D arrays of one
     dtype that are reduced where they lie, as if laid end to end. Returns
-    the frames of a hop.
+    the frames of a hop and the lanes they rode.
 
     The ``world`` segments are ranges of that concatenation at multiples of
     ``ceil(len / world)`` (the last ones shorter, or empty). Hop ``h`` of
     ``2 * (world - 1)`` sends segment ``rank - h`` and receives segment
-    ``rank - h - 1``, a frame of ``_RING_CHUNK_BYTES`` at a time. Three
-    threads share a pass. This one, the PG's dispatch thread, receives: for
-    the first ``world - 1`` hops into the two scratch chunks in turn, after
-    that straight into the segment. The comm's "fold" worker accumulates a
+    ``rank - h - 1``, a frame of ``_RING_CHUNK_BYTES`` at a time. Frame
+    ``k`` of every segment belongs to lane ``k % lanes``: all the comm's
+    lanes where a segment holds ``_RING_LANE_FLOOR_BYTES``, else lane 0
+    alone. Three threads run a lane. Its receiver (lane 0's is this one,
+    the PG's dispatch thread) takes the lane's frames off its socket: for
+    the first ``world - 1`` hops into the lane's two scratch chunks in
+    turn, after that straight into the segment. Its fold accumulates a
     scratch chunk into its segment while the next frame arrives in the
-    other. The comm's collective writer sends every hop's frames (both
-    sides send first: with synchronous sockets that would deadlock on full
-    TCP buffers), each as soon as the hop before is done with it: the hops
-    overlap each other, and the accumulate the wire.
+    other. Its writer sends every hop's frames (both sides send first:
+    with synchronous sockets that would deadlock on full TCP buffers), each
+    as soon as the hop before is done with it: the hops overlap each other,
+    the accumulate the wire, and the lanes one another. Lanes share nothing
+    but failure: the thread that fails fails every lane's counts, and this
+    thread raises once every other has ended.
     """
     world, rank = comm.world, comm.rank
     right, left = (rank + 1) % world, (rank - 1) % world
@@ -736,6 +883,8 @@ def _ring_pass(comm: "_Comm", parts: List[np.ndarray], op: ReduceOp) -> int:
     total = int(ends[-1])
     seg_len = -(-total // world)
     step = max(1, _RING_CHUNK_BYTES // dtype.itemsize)
+    lanes = (comm.lanes
+             if seg_len * dtype.itemsize >= _RING_LANE_FLOOR_BYTES else 1)
 
     def frames(seg: int) -> List[Tuple[int, int]]:
         lo, hi = min(seg * seg_len, total), min((seg + 1) * seg_len, total)
@@ -753,64 +902,96 @@ def _ring_pass(comm: "_Comm", parts: List[np.ndarray], op: ReduceOp) -> int:
             a, i = stop, i + 1
         return out
 
-    scratch = comm.ring_scratch(step * dtype.itemsize)
-
-    def waiting(k: int, a: int, b: int) -> np.ndarray:
-        """Where frame ``k`` of the reduce-scatter waits to be folded."""
-        return scratch[k % 2, :(b - a) * dtype.itemsize].view(dtype)
-
+    scratch = comm.ring_scratch(lanes, step * dtype.itemsize)
     hops = 2 * (world - 1)
-    recv_frames = [seg_frames[(rank - h - 1) % world] for h in range(hops)]
-    # the number, in receive order, of each hop's first frame
-    first = [0, *itertools.accumulate(len(f) for f in recv_frames)]
-    to_fold = [ab for f in recv_frames[:world - 1] for ab in f]
-    # frames in receive order: those that have arrived in the scratch, and
-    # those that are where they belong (folded, or received in place)
-    arrived, done = _RingCount(), _RingCount()
+    counts: List[_RingCount] = []
 
-    def _writes() -> None:
-        for h in range(hops):
-            for k, (a, b) in enumerate(seg_frames[(rank - h) % world]):
-                if h:
-                    done.wait_past(first[h - 1] + k)
-                comm.send_raw(right, views(a, b))
+    def fail_all() -> None:
+        for count in counts:  # whoever waits, on whichever lane
+            count.fail()
 
-    def _folds() -> None:
-        try:
+    def failing(job: Callable[[], None]) -> Callable[[], None]:
+        def run() -> None:
+            try:
+                job()
+            except BaseException:
+                fail_all()
+                raise
+        return run
+
+    def lane_jobs(lane: int) -> Tuple[Callable[[], None], ...]:
+        """Lane ``lane``'s receiver, fold and writer."""
+        mine = [f[lane::lanes] for f in seg_frames]
+        recv_frames = [mine[(rank - h - 1) % world] for h in range(hops)]
+        # the number, in the lane's receive order, of each hop's first frame
+        first = [0, *itertools.accumulate(len(f) for f in recv_frames)]
+        to_fold = [ab for f in recv_frames[:world - 1] for ab in f]
+        # frames in receive order: those that have arrived in the scratch,
+        # and those that are where they belong (folded, or received in place)
+        arrived, done = _RingCount(), _RingCount()
+        counts.extend((arrived, done))
+        # lane 0 is the comm's mesh socket and today's call
+        on = {"lane": lane} if lane else {}
+
+        def waiting(k: int, a: int, b: int) -> np.ndarray:
+            """Where frame ``k`` of the reduce-scatter waits to be folded."""
+            return scratch[lane, k % 2, :(b - a) * dtype.itemsize].view(dtype)
+
+        def receives() -> None:
+            for k, (a, b) in enumerate(to_fold):
+                if k >= 2:
+                    done.wait_past(k - 2)  # what waited in this chunk is folded
+                comm.recv_raw_into(left, waiting(k, a, b), **on)
+                arrived.advance()
+            done.wait_past(len(to_fold) - 1)
+            for h in range(world - 1, hops):
+                for a, b in recv_frames[h]:
+                    comm.recv_raw_into(left, views(a, b), **on)
+                    done.advance()
+
+        def folds() -> None:
             for k, (a, b) in enumerate(to_fold):
                 arrived.wait_past(k)
                 got = waiting(k, a, b)
                 for v in views(a, b):
-                    _accum(op, v, got[:v.size])
+                    _fold(op, v, got[:v.size])
                     got = got[v.size:]
                 done.advance()
-        except BaseException:
-            done.fail()  # the receiver and the writer may be waiting
-            raise
 
+        def writes() -> None:
+            for h in range(hops):
+                for k, (a, b) in enumerate(mine[(rank - h) % world]):
+                    if h:
+                        done.wait_past(first[h - 1] + k)
+                    comm.send_raw(right, views(a, b), **on)
+
+        return failing(receives), failing(folds), failing(writes)
+
+    jobs = [lane_jobs(lane) for lane in range(lanes)]
+    pending: List[Tuple[threading.Event, List[BaseException]]] = []
+    errors: List[BaseException] = []
     try:
-        wrote, write_err = comm.submit_write(_writes)
-        folded, fold_err = comm.submit_write(_folds, lane="fold")
-        for k, (a, b) in enumerate(to_fold):
-            if k >= 2:
-                done.wait_past(k - 2)  # what waited in this chunk is folded
-            comm.recv_raw_into(left, waiting(k, a, b))
-            arrived.advance()
-        folded.wait()
-        if fold_err:
-            raise fold_err[0]
-        for h in range(world - 1, hops):
-            for a, b in recv_frames[h]:
-                comm.recv_raw_into(left, views(a, b))
-                done.advance()
-    except BaseException:
-        arrived.fail()
-        done.fail()
-        raise
-    wrote.wait()
-    if write_err:
-        raise write_err[0]
-    return len(recv_frames[0])
+        for lane, (receives, folds, writes) in enumerate(jobs):
+            tag = str(lane) if lane else ""
+            pending.append(comm.submit_write(writes, lane="collwr" + tag))
+            pending.append(comm.submit_write(folds, lane="fold" + tag))
+            if lane:
+                pending.append(comm.submit_write(receives, lane="recv" + tag))
+        jobs[0][0]()
+    except BaseException as e:  # noqa: BLE001
+        fail_all()  # submit_write's own failure (aborted) had not yet
+        errors.append(e)
+    # no thread is left in the buffers when the op resolves: a failed count
+    # wakes who waits on one, the socket's timeout or the watchdog's abort
+    # ends who waits on a peer
+    for ended, err in pending:
+        ended.wait()
+        errors.extend(err)
+    if errors:
+        # the cause, not a thread that was only told of it
+        raise next((e for e in errors if not isinstance(e, _RingFailed)),
+                   errors[0])
+    return len(seg_frames[(rank - 1) % world]), lanes
 
 
 def _ring_allreduce(
@@ -837,7 +1018,8 @@ def _ring_allreduce(
     a single dtype, so this is one ring in practice). Matches
     ``_reduce_np``'s semantics: accumulate in the input dtype, AVG divides
     by world at the end. ``info`` receives ``inplace`` (1 when no leaf was
-    copied) and ``chunks`` (frames a hop).
+    copied), ``chunks`` (frames a hop) and ``lanes`` (the connections to a
+    neighbour that the last dtype's frames rode).
     """
     out: List[np.ndarray] = []
     for a in leaves:
@@ -851,14 +1033,15 @@ def _ring_allreduce(
             out.append(np.array(a, order="C", copy=True))
     info = {} if info is None else info
     info["inplace"] = int(all(o is a for o, a in zip(out, leaves)))
-    info["chunks"] = 0
+    info["chunks"], info["lanes"] = 0, 1
 
     groups: Dict[Any, List[int]] = {}
     for i, a in enumerate(out):
         groups.setdefault(a.dtype, []).append(i)
     for _dtype, idxs in sorted(groups.items(), key=lambda kv: str(kv[0])):
-        info["chunks"] += _ring_pass(
+        chunks, info["lanes"] = _ring_pass(
             comm, [out[i].reshape(-1) for i in idxs], op)
+        info["chunks"] += chunks
         if op == ReduceOp.AVG:
             for i in idxs:
                 if np.issubdtype(out[i].dtype, np.integer):
@@ -1683,6 +1866,7 @@ class ProcessGroupHost(ProcessGroup):
                 # allocates), and the degraded single-replica fleet honors
                 # the same contract. _copy_payload is tuple-safe (quantized
                 # wire), and tuples are always copied.
+                info["lanes"] = 1  # no neighbour: wire_run says so too
                 return [
                     h if donate and isinstance(h, np.ndarray)
                     else _copy_payload(h)

@@ -1,0 +1,152 @@
+"""The fifth kind of the one trainer's model (``models/ling.py``), its
+kernels and its router: the KDA kernels against the recurrence one position
+after another, and what ``moe_ffn``'s choice gained for it (the group limit,
+the scaling). The share, the pinned programs and the kind are
+``tests/test_ling.py``'s, the faults ``tests/test_ling_faults.py``'s."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ling_helpers import reference, rel as _rel
+from torchft_tpu.models import moe
+from torchft_tpu.models.ling import LING_CONFIGS
+from torchft_tpu.ops import kda as K
+
+
+def _qkvgb(B, T, H, d, seed, g=None, beta=None, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda m: m / jnp.linalg.norm(m, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (B, T, H, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, T, H, d)))
+    v = jax.random.normal(ks[2], (B, T, H, d))
+    g = -5 * jax.nn.sigmoid(2 * jax.random.normal(ks[3], (B, T, H, d))) if g is None else g
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H))) if beta is None else beta
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def _bounds(B, T, H, d):
+    """Decays at the bound in runs longer than a sub-block beside none at
+    all, and ``beta`` at 0, at 1 and next to both."""
+    at = jnp.arange(T)[None, :, None, None] // 24 + jnp.arange(d) // 5
+    g = jnp.broadcast_to(jnp.where(at % 3 == 0, -5.0, jnp.where(at % 3 == 1, 0.0, -0.7)),
+                         (B, T, H, d))
+    beta = jnp.broadcast_to(jnp.asarray([0.0, 1.0, 1e-4, 1 - 1e-4])[jnp.arange(T) % 4][
+        None, :, None], (B, T, H))
+    return g, beta
+
+
+@pytest.mark.parametrize("case", ["across_blocks", "one_short_block", "at_the_bounds",
+                                  "all_at_the_bound", "one_key"])
+def test_the_kernels_are_the_recurrence_forward_and_backward(case):
+    """``kda`` (interpreted here) against ``kda_reference``'s scan over
+    positions: the output and all five gradients, across chunk and block
+    borders, a sequence shorter than a block, with decays at -5 for whole
+    sub-blocks beside ``beta`` at 0 and 1, and with one key for every
+    position."""
+    B, T, H, d = {"across_blocks": (2, 300, 2, 32), "one_short_block": (1, 50, 1, 16),
+                  "at_the_bounds": (1, 150, 2, 16), "all_at_the_bound": (1, 70, 1, 16),
+                  "one_key": (1, 128, 1, 16)}[case]
+    g, beta = _bounds(B, T, H, d) if case == "at_the_bounds" else (None, None)
+    if case == "all_at_the_bound":
+        g = jnp.full((B, T, H, d), -5.0)
+    args = _qkvgb(B, T, H, d, seed=len(case), g=g, beta=beta)
+    if case == "one_key":
+        # every key the same vector, beta = 1, no decay: a chunk inverse in
+        # one step over 64 rows overflows float32 here (binomials to 1e18);
+        # in two steps over sub-blocks of 16 it is the recurrence's
+        q, k, v, _, _ = args
+        args = (q, jnp.broadcast_to(k[:, :1], k.shape), v, jnp.zeros_like(args[3]),
+                jnp.ones((B, T, H)))
+    w = jax.random.normal(jax.random.PRNGKey(9), (B, T, H, d))
+    got, want = K.kda(*args), K.kda_reference(*args)
+    assert _rel(got, want) < 5e-6
+    grads = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * w), argnums=range(5))(*args)
+    for name, a, b in zip("qkvgb", grads(K.kda), grads(K.kda_reference)):
+        if float(jnp.linalg.norm(b)):
+            # at decays of -5 everywhere the decay's own gradient is e^-5 of
+            # the others': what is left of it is rounding's by a larger share
+            # and one key for every position is as ill-conditioned as a chunk gets
+            loose = name == "g" or case == "one_key"
+            assert _rel(a, b) < (1e-3 if loose else 5e-5), (case, name)
+        else:  # nothing depends on a decay that is given
+            assert float(jnp.linalg.norm(a)) < 1e-6, (case, name)
+
+
+def test_the_kernels_take_bf16_and_keep_state_and_decays_in_float32(monkeypatch):
+    """bf16 q, k, v: the output is bf16 and within bf16 rounding of the
+    float32 recurrence on the same rounded inputs; with a state rounded to
+    bf16 after every chunk (the fault the chip check has to refuse) float32
+    inputs land a hundred times further off than with the float32 state."""
+    args = _qkvgb(1, 300, 2, 32, seed=3, dtype=jnp.bfloat16,
+                  g=-0.05 * jnp.ones((1, 300, 2, 32)))
+    want = K.kda_reference(*(a.astype(jnp.float32) for a in args))
+    got = K.kda(*args)
+    assert got.dtype == jnp.bfloat16 and _rel(got, want) < 4e-3
+    wide = tuple(a.astype(jnp.float32) for a in args)
+    assert _rel(K.kda(*wide), want) < 5e-6
+    monkeypatch.setattr(K, "STATE_DTYPE", jnp.bfloat16)
+    jax.clear_caches()
+    assert _rel(K.kda(*wide), want) > 2e-4
+    jax.clear_caches()
+
+
+def test_a_positions_output_is_unchanged_by_later_positions():
+    args = _qkvgb(1, 200, 1, 16, seed=1)
+    cut = tuple(a[:, :130] for a in args)
+    np.testing.assert_allclose(np.asarray(K.kda(*args))[:, :130], np.asarray(K.kda(*cut)),
+                               rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------- the router
+
+def _scores(T, E, seed=0):
+    return jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(seed), (T, E)))
+
+
+def test_one_group_is_the_plain_top_k_bit_for_bit():
+    cfg = dataclasses.replace(moe.MOE_CONFIGS["debug"], num_experts=16, top_k=4,
+                              router_score="sigmoid")
+    same = dataclasses.replace(cfg, n_group=1, topk_group=1)
+    bias = 0.01 * jax.random.normal(jax.random.PRNGKey(1), (16,))
+    a, b = moe._choose(_scores(64, 16), cfg, None, bias), moe._choose(
+        _scores(64, 16), same, None, bias)
+    for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert sorted(a[2]) == ["p_kth", "p_next", "routing"]
+
+
+def test_the_group_limit_differs_from_a_plain_top_k_on_a_stated_share():
+    """32 experts in 4 groups, 4 a token in 2 groups: every token's experts
+    lie in 2 groups, the choice is the reference's, and for 30 to 95% of
+    random tokens it is not the plain top-4 (whose four lie in three or four
+    groups more often than not)."""
+    cfg = dataclasses.replace(LING_CONFIGS["ling_debug"], num_experts=32, top_k=4,
+                              n_group=4, topk_group=2, held_experts=None)
+    s = _scores(512, 32, seed=2)
+    _, idx, free = moe._choose(s, cfg, None)
+    assert int(jnp.max(jnp.sum(jnp.any(
+        (idx // 8)[:, :, None] == jnp.arange(4), axis=1), axis=-1))) <= 2
+    ref_idx, _, p_k, p_n = reference.choose(s, jnp.zeros(32), {
+        "num_experts_per_tok": 4, "n_group": 4, "topk_group": 2,
+        "moe_router_enable_expert_bias": True, "norm_topk_prob": True,
+        "routed_scaling_factor": 2.5})
+    np.testing.assert_array_equal(np.sort(idx, -1), np.sort(ref_idx, -1))
+    np.testing.assert_allclose(free["p_kth"], p_k, rtol=1e-6)
+    np.testing.assert_allclose(free["p_next"], p_n, rtol=1e-6)
+    plain = jax.lax.top_k(s, 4)[1]
+    differ = float(jnp.mean(jnp.any(jnp.sort(idx, -1) != jnp.sort(plain, -1), axis=-1)))
+    assert 0.3 < differ < 0.95, differ
+    assert float(moe._groups_hit(idx, cfg)) <= 2.0 < float(moe._groups_hit(plain, cfg))
+
+
+def test_gates_are_the_unbiased_scores_renormalised_and_scaled():
+    cfg = dataclasses.replace(LING_CONFIGS["ling_debug"], held_experts=None)
+    s = _scores(64, 16, seed=3)
+    gates, idx, _ = moe._choose(s, cfg, None, 0.3 * jnp.ones(16))
+    at = jnp.take_along_axis(s, idx, axis=-1)
+    np.testing.assert_allclose(gates, 2.5 * at / at.sum(-1, keepdims=True), rtol=1e-6)

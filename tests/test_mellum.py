@@ -182,15 +182,15 @@ def test_the_shares_add_up_to_the_uncut_layer():
         jax.random.PRNGKey(2), whole)["layers"]["00_window"])
     h = jax.random.normal(jax.random.PRNGKey(3), (2, 48, whole.dim))
     table = M.rope_tables(whole, 48)["window"]
-    out, stats = M._layer_body(whole, "window", table, M._attention)(h, (w, None))
+    out, stats = M._layer_body(whole, "window", table, M._attention)(h, (w, None, None))
     # what every chip computes alike: the residual stream after attention
     no_experts = {**w, "w_down": jnp.zeros_like(w["w_down"])}
-    once = M._layer_body(whole, "window", table, M._attention)(h, (no_experts, None))[0]
+    once = M._layer_body(whole, "window", table, M._attention)(h, (no_experts, None, None))[0]
     parts, counts = [], []
     for first in range(0, 16, 4):
         share = dataclasses.replace(whole, held_experts=(first, 4), share_room=4.0)
         held = {**w, **{k: w[k][first:first + 4] for k in ("w_gate", "w_up", "w_down")}}
-        got, st = M._layer_body(share, "window", table, M._attention)(h, (held, None))
+        got, st = M._layer_body(share, "window", table, M._attention)(h, (held, None, None))
         assert int(st["overflow"]) == 0
         np.testing.assert_array_equal(np.asarray(st["routing"]), np.asarray(stats["routing"]))
         parts.append(got - once)

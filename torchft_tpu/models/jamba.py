@@ -24,12 +24,9 @@ has an untied head only).
 
 The layers are unlike, so the parameters are one stack per RUN of like
 layers (``layers["00_mamba"]`` [7, ...], ``layers["01_attn"]`` [1, ...],
-``layers["02_mamba"]`` [6, ...] for one period; the names sort in layer
-order) and the forward pass scans each run under one remat policy: one
-compiled body per kind whatever the depth. Two stacks, one per kind, cut into
-runs inside the step would cost a copy of every weight a step: a slice of a
-stacked leaf is a new buffer to XLA (2.7 GB at one period, which then does
-not fit a 16 GB chip; read from the compiled step, PERF.md section 6, PR 33).
+``layers["02_mamba"]`` [6, ...] for one period) and ``models/decoder.py``
+scans the runs: this module is the configuration, ``init``, the two layer
+bodies, the PartitionSpecs and the two counters, and declares them (``JAMBA``).
 Initialisation is Mamba's (``A_log = log(1..d_state)``, ``D = 1``,
 ``dt_bias = softplus^-1(dt0)``, ``dt0`` log-uniform in [1e-3, 1e-1]): with
 steps near 1e-3 the state remembers about a thousand positions.
@@ -44,8 +41,10 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from torchft_tpu.models.llama import LlamaConfig, _attention, _rmsnorm, head_loss
-from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
+from torchft_tpu.models.kinds import ModelFns, logged, register
+from torchft_tpu.models.llama import LlamaConfig, _attention, _rmsnorm
+from torchft_tpu.models.remat import ATTN_OUT_NAME
 from torchft_tpu.ops.selective_scan import selective_scan
 
 __all__ = [
@@ -101,15 +100,9 @@ class JambaConfig(LlamaConfig):
 
     def runs(self) -> List[Tuple[str, str, int]]:
         """Runs of like layers in order: (name of the run's stack under
-        ``params["layers"]``, kind, layers)."""
-        out: List[Tuple[str, str, int]] = []
-        for kind in self.layers_block_type:
-            if out and out[-1][1] == kind:
-                out[-1] = (out[-1][0], kind, out[-1][2] + 1)
-            else:
-                short = "attn" if kind == "attention" else kind
-                out.append((f"{len(out):02d}_{short}", kind, 1))
-        return out
+        ``params["layers"]``, kind, layers); neighbours of a kind merge."""
+        return runs_of(self.layers_block_type,
+                       name=lambda kind: "attn" if kind == "attention" else kind)
 
     def num_params(self) -> int:
         d, f, v = self.dim, self.ffn_hidden, self.vocab_size
@@ -198,13 +191,8 @@ def jamba_init(key: jax.Array, cfg: JambaConfig) -> Dict[str, Any]:
             **ffn(ks[4:7], L),
         }
 
-    runs = cfg.runs()
     make = {"mamba": mamba, "attention": attention}
-    params = {
-        "embed": dense(k_emb, (cfg.vocab_size, d), d),
-        "layers": {name: make[kind](k, L) for (name, kind, L), k
-                   in zip(runs, jax.random.split(k_layers, len(runs)))},
-        "final_norm": jnp.ones((d,), cfg.dtype)}
+    params = init_tree(k_emb, k_layers, cfg, lambda key, kind, L: make[kind](key, L))
     if not cfg.tie_word_embeddings:
         params["lm_head"] = dense(k_out, (d, cfg.vocab_size), d)
     return params
@@ -213,20 +201,6 @@ def jamba_init(key: jax.Array, cfg: JambaConfig) -> Dict[str, Any]:
 def _feed_forward(h: jax.Array, w: Dict[str, jax.Array], cfg: JambaConfig) -> jax.Array:
     x = _rmsnorm(h, w["ffn_norm"], cfg.norm_eps)
     return h + (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
-
-
-def _causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
-                 activation: Optional[Any] = jax.nn.silu) -> jax.Array:
-    """``activation`` (Mamba's silu; None: none, models/lfm2.py's short
-    convolution) of the depthwise causal convolution, as shifted
-    multiply-adds summed in float32. x [B,T,di], w [k,di] (``w[k-1]`` weighs
-    the current position), b [di] or None."""
-    k, T = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    out = sum(padded[:, j:j + T] * w[j].astype(jnp.float32) for j in range(k))
-    if b is not None:
-        out = out + b.astype(jnp.float32)
-    return (activation(out) if activation else out).astype(x.dtype)
 
 
 def _mamba_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: JambaConfig
@@ -259,14 +233,17 @@ def _mamba_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: JambaConfig
     return out, jax.lax.stop_gradient(stats)
 
 
-def _layer_bodies(cfg: JambaConfig, attention_fn: Optional[Any]):
+def _bodies(cfg: JambaConfig, seq: int, attention_fn: Optional[Any]):
+    """A Mamba layer's stats are its mixer's, an attention layer has none."""
     attention = attention_fn or _attention
 
-    def mamba_layer(h, w):
+    def mamba_layer(h, xs):
+        w = xs[0]
         out, stats = _mamba_mixer(_rmsnorm(h, w["norm"], cfg.norm_eps), w, cfg)
         return _feed_forward(h + out, w, cfg), stats
 
-    def attention_layer(h, w):
+    def attention_layer(h, xs):
+        w = xs[0]
         B, S = h.shape[0], h.shape[1]
         with jax.named_scope("attn/mixer"):
             x = _rmsnorm(h, w["norm"], cfg.norm_eps)
@@ -280,73 +257,23 @@ def _layer_bodies(cfg: JambaConfig, attention_fn: Optional[Any]):
             h = h + attn @ w["wo"]
         return _feed_forward(h, w, cfg), None
 
-    return {"mamba": mamba_layer, "attention": attention_layer}
+    return {"mamba": mamba_layer, "attention": attention_layer}.__getitem__
 
 
-def jamba_hidden(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: JambaConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim], the
-    Mamba layers' ``dt_max`` and ``y_absmax``, each [n_mamba])."""
-    bodies = {k: remat_wrap(f, remat) for k, f in
-              _layer_bodies(cfg, attention_fn).items()}
-    h = params["embed"][tokens]
-    stats = []
-    for name, kind, _ in cfg.runs():
-        h, out = jax.lax.scan(bodies[kind], h, params["layers"][name])
-        if out is not None:
-            stats.append(out)
-    stats = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
-    return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
-
-
-def _head(params: Dict[str, Any], cfg: JambaConfig) -> jax.Array:
-    return params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-
-
-def jamba_forward(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: JambaConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-) -> jax.Array:
-    """tokens int32 [B, S] -> logits f32 [B, S, vocab]."""
-    h, _ = jamba_hidden(params, tokens, cfg, attention_fn=attention_fn, remat=remat)
-    return (h @ _head(params, cfg)).astype(jnp.float32)
-
-
-def jamba_loss_and_stats(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    targets: jax.Array,
-    cfg: JambaConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    loss_chunk: int = 0,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mean next-token cross-entropy (``llama_loss``'s; ``loss_chunk`` as
-    there, and 0 takes the config's own where that divides the sequence)
-    and the two scalars a training loop logs: ``ssm_dt_max`` (the largest
-    step size) and ``ssm_y_absmax`` (the largest magnitude of the scan's
-    gated output) over the Mamba layers; Jamba's inner norms exist because
+def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: JambaConfig
+              ) -> Dict[str, jax.Array]:
+    """The two scalars a training loop logs, over the Mamba layers:
+    ``dt_max`` (the largest step size) and ``y_absmax`` (the largest
+    magnitude of the scan's gated output); Jamba's inner norms exist because
     these spike."""
-    h, stats = jamba_hidden(params, tokens, cfg, attention_fn=attention_fn, remat=remat)
-    S = tokens.shape[1]
-    if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
-        loss_chunk = cfg.loss_chunk
-    return head_loss(h, _head(params, cfg), targets, loss_chunk), {
-        "ssm_dt_max": jnp.max(stats["dt_max"]),
-        "ssm_y_absmax": jnp.max(stats["y_absmax"])}
+    return {"dt_max": jnp.max(stats["dt_max"]), "y_absmax": jnp.max(stats["y_absmax"])}
 
 
-def jamba_loss(*args: Any, **kw: Any) -> jax.Array:
-    """:func:`jamba_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
-    return jamba_loss_and_stats(*args, **kw)[0]
+# ``jamba_hidden`` -> (hidden states, the Mamba layers' ``dt_max`` and
+# ``y_absmax``, each [n_mamba]); the head is tied unless the tree has ``lm_head``
+JAMBA = Decoder(_bodies, _counters)
+jamba_hidden, jamba_forward = JAMBA.hidden, JAMBA.forward
+jamba_loss_and_stats, jamba_loss = JAMBA.loss_and_stats, JAMBA.loss
 
 
 def jamba_param_specs(cfg: JambaConfig) -> Dict[str, Any]:
@@ -370,10 +297,11 @@ def jamba_param_specs(cfg: JambaConfig) -> Dict[str, Any]:
     attn = {"norm": P(None, None), "wq": P(None, "fsdp", "tp"),
             "wk": P(None, "fsdp", "tp"), "wv": P(None, "fsdp", "tp"),
             "wo": P(None, "tp", "fsdp"), **ffn}
-    of = {"mamba": mamba, "attention": attn}
-    specs = {"embed": P("fsdp", "tp"),
-             "layers": {name: dict(of[kind]) for name, kind, _ in cfg.runs()},
-             "final_norm": P(None)}
+    specs = spec_tree(cfg, {"mamba": mamba, "attention": attn}.__getitem__)
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P("fsdp", "tp")
     return specs
+
+
+register(JambaConfig, JAMBA_CONFIGS, lambda: ModelFns(
+    jamba_init, logged(jamba_loss_and_stats, ssm=("dt_max", "y_absmax")), jamba_param_specs, None))

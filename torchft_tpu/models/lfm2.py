@@ -36,16 +36,13 @@ while it was pre-trained is in no public file, and nothing here moves it.
 It is initialised as ``0.01 * normal``, not zeros: a bias of zeros decides
 nothing, and a check could not tell whether the selection reads it.
 
-The layers are unlike, so, as in ``models/jamba.py``, the parameters are one
-stack per RUN of like layers (``layers["00_conv_dense"]``,
-``layers["01_attn_moe"]``, ``layers["02_conv_moe"]`` ...; the names sort in
-layer order) and the forward pass scans each run under one remat policy. An
-EXPERT layer is always a run of its own (leaves ``[1, ...]``): scanned over
-a stack, each layer's 235 MB expert matrices are copied out of it and their
-gradients written back into it slice by slice, 3.4 GiB of temporaries more at
-one period of the published widths (the compiled step: 15.37 GiB against
-11.93, which is the chip; PERF.md section 6, PR 35). The head is tied:
-``logits = h @ embed.T``.
+The layers are unlike, so the parameters are one stack per RUN of like
+layers (``layers["00_conv_dense"]``, ``layers["01_attn_moe"]``,
+``layers["02_conv_moe"]`` ...) and ``models/decoder.py`` scans the runs; an
+EXPERT layer is always a run of its own (leaves ``[1, ...]``;
+``decoder.runs_of`` says why). This module is the configuration, ``init``,
+the two mixers, the layer body, the PartitionSpecs and the counters, and
+declares them (``LFM2``). The head is tied: ``logits = h @ embed.T``.
 """
 
 from __future__ import annotations
@@ -57,10 +54,12 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from torchft_tpu.models.jamba import _causal_conv
-from torchft_tpu.models.llama import _attention, _rmsnorm, _rope, head_loss
-from torchft_tpu.models.moe import MoEConfig, moe_ffn
-from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
+from torchft_tpu.models.kinds import ModelFns, logged, register
+from torchft_tpu.models.llama import _attention, _rmsnorm, _rope
+from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
+                                    ffn_leaves, ffn_specs, moe_ffn)
+from torchft_tpu.models.remat import ATTN_OUT_NAME
 
 __all__ = [
     "Lfm2Config",
@@ -84,19 +83,6 @@ _PUBLISHED_LAYER_TYPES = tuple(
     "full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv" for i in range(24))
 
 
-def runs_of(kinds: List[Tuple[str, str]]) -> List[Tuple[str, Tuple[str, str], int]]:
-    """(mixer, feed-forward) of every layer -> the runs of like layers in
-    order: (the name of the run's stack, its kind, layers). Dense layers of
-    one mixer run together; an expert layer runs alone."""
-    out: List[Tuple[str, Tuple[str, str], int]] = []
-    for kind in kinds:
-        if out and out[-1][1] == kind and kind[1] == "dense":
-            out[-1] = (out[-1][0], kind, out[-1][2] + 1)
-        else:
-            out.append((f"{len(out):02d}_{kind[0]}_{kind[1]}", kind, 1))
-    return out
-
-
 @dataclasses.dataclass(frozen=True)
 class Lfm2Config(MoEConfig):
     # ``ffn_hidden`` is the dense layers' SwiGLU width (``intermediate_size``)
@@ -108,7 +94,6 @@ class Lfm2Config(MoEConfig):
     top_k: int = 4
     capacity_factor: Optional[float] = None  # dropless
     aux_loss_weight: float = 0.0
-    norm_topk_prob: bool = True
     router_score: str = "sigmoid"
     gate_eps: float = 1e-6
     # the loss over sequence chunks of this length where it divides the
@@ -117,19 +102,7 @@ class Lfm2Config(MoEConfig):
 
     def __post_init__(self) -> None:
         super().__post_init__()
-        if len(self.layer_types) != self.n_layers:
-            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
-                             f"n_layers is {self.n_layers}")
-        other = sorted(set(self.layer_types) - {"conv", "full_attention"})
-        if other:
-            raise ValueError(f"layer_types {other}: models/lfm2.py mixes with "
-                             "'conv' or 'full_attention'")
-        if self.capacity_factor is not None or self.aux_loss_weight:
-            raise ValueError("capacity_factor / aux_loss_weight: the family's "
-                             "expert block drops nothing and has no auxiliary loss")
-        if not 0 <= self.num_dense_layers <= self.n_layers:
-            raise ValueError(f"num_dense_layers={self.num_dense_layers} of "
-                             f"{self.n_layers} layers")
+        self._check_layer_types(("conv", "full_attention"), self.num_dense_layers)
 
     @property
     def n_moe_layers(self) -> int:
@@ -143,10 +116,9 @@ class Lfm2Config(MoEConfig):
                 for i, t in enumerate(self.layer_types)]
 
     def runs(self) -> List[Tuple[str, Tuple[str, str], int]]:
-        """Runs of like layers in order: (name of the run's stack under
-        ``params["layers"]``, its (mixer, feed-forward), layers). Dense layers
-        of one mixer run together; an expert layer runs alone."""
-        return runs_of(self.kinds())
+        """Runs of like layers in order (``decoder.runs_of``): dense layers of
+        one mixer run together, an expert layer runs alone."""
+        return runs_of(self.kinds(), name="_".join, merges=lambda kind: kind[1] == "dense")
 
     def num_params(self) -> int:
         """Every leaf, ``expert_bias`` among them; the tied embedding once."""
@@ -188,9 +160,8 @@ def lfm2_init(key: jax.Array, cfg: Lfm2Config) -> Dict[str, Any]:
     expert layers, ``expert_bias`` [expert layers, E] float32 (state:
     ``LFM2_FROZEN``)."""
     k_emb, k_bias, k_layers = jax.random.split(key, 3)
-    d, f, hd = cfg.dim, cfg.ffn_hidden, cfg.head_dim
+    d, hd = cfg.dim, cfg.head_dim
     kvd = cfg.n_kv_heads * hd
-    E, H = cfg.num_experts, cfg.moe_intermediate_size
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
@@ -208,31 +179,16 @@ def lfm2_init(key: jax.Array, cfg: Lfm2Config) -> Dict[str, Any]:
                 "q_norm": jnp.ones((L, hd), cfg.dtype),
                 "k_norm": jnp.ones((L, hd), cfg.dtype)}
 
-    def ffn(kind, keys, L):
-        if kind == "dense":
-            return {"w_gate": dense(keys[0], (L, d, f), d),
-                    "w_up": dense(keys[1], (L, d, f), d),
-                    "w_down": dense(keys[2], (L, f, d), f)}
-        return {  # router in f32: its scores drive routing decisions
-            "router": jax.random.normal(keys[3], (L, d, E), jnp.float32) / jnp.sqrt(d),
-            "w_gate": dense(keys[0], (L, E, d, H), d),
-            "w_up": dense(keys[1], (L, E, d, H), d),
-            "w_down": dense(keys[2], (L, E, H, d), H)}
-
     def run(key, kind, L):
         ks = jax.random.split(key, 8)
         return {"norm": jnp.ones((L, d), cfg.dtype), **mixer(kind[0], ks[:4], L),
-                "ffn_norm": jnp.ones((L, d), cfg.dtype), **ffn(kind[1], ks[4:], L)}
+                "ffn_norm": jnp.ones((L, d), cfg.dtype),
+                **ffn_init(ffn_leaves(cfg, kind[1]), ks[4:], L, cfg.dtype)}
 
-    runs = cfg.runs()
-    params = {
-        "embed": dense(k_emb, (cfg.vocab_size, d), d),
-        "layers": {name: run(k, kind, L) for (name, kind, L), k
-                   in zip(runs, jax.random.split(k_layers, len(runs)))},
-        "final_norm": jnp.ones((d,), cfg.dtype)}
+    params = init_tree(k_emb, k_layers, cfg, run)
     if cfg.n_moe_layers:
         params["expert_bias"] = BIAS_INIT_SCALE * jax.random.normal(
-            k_bias, (cfg.n_moe_layers, E), jnp.float32)
+            k_bias, (cfg.n_moe_layers, cfg.num_experts), jnp.float32)
     return params
 
 
@@ -288,86 +244,21 @@ def _layer_body(cfg: Lfm2Config, kind: Tuple[str, str], attention: Any):
     return layer
 
 
-def lfm2_hidden(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: Lfm2Config,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim],
-    ``moe_ffn``'s stats of the expert layers, each stacked over them).
-    ``routing`` [expert layers, B*S, k]: the experts to use (replay)."""
-    attention = attention_fn or _attention
-    h = params["embed"][tokens]
-    stats, at = [], 0  # ``at``: expert layers before this run
-    for name, kind, L in cfg.runs():
-        body = remat_wrap(_layer_body(cfg, kind, attention), remat)
-        rows = slice(at, at + L) if kind[1] == "moe" else None
-        xs = (params["layers"][name],
-              None if rows is None else params["expert_bias"][rows],
-              None if routing is None or rows is None else routing[rows])
-        h, out = jax.lax.scan(body, h, xs)
-        if rows is not None:
-            stats.append(out)
-            at += L
-    stats = (jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
-             if stats else {})
-    return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
+def _bodies(cfg: Lfm2Config, seq: int, attention_fn: Optional[Any]):
+    return lambda kind: _layer_body(cfg, kind, attention_fn or _attention)
 
 
-def lfm2_forward(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: Lfm2Config,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> jax.Array:
-    """tokens int32 [B, S] -> logits f32 [B, S, vocab]."""
-    h, _ = lfm2_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                       remat=remat, routing=routing)
-    return (h @ params["embed"].T).astype(jnp.float32)
+def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: Lfm2Config
+              ) -> Dict[str, jax.Array]:
+    """The expert layers' free routing with its margins (``routing``
+    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and ``moe.expert_scalars``' two for
+    this family: ``load_max_over_mean`` and ``bias_moved_share``."""
+    return expert_scalars(stats, tokens.size * cfg.top_k, mean_floor=None)
 
 
-def lfm2_loss_and_stats(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    targets: jax.Array,
-    cfg: Lfm2Config,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    loss_chunk: int = 0,
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mean next-token cross-entropy (``llama_loss``'s; ``loss_chunk`` as
-    there, and 0 takes the config's own where that divides the sequence)
-    and stats: the expert layers' free routing with its margins
-    (``routing`` [L,T,k], ``p_kth``, ``p_next`` [L,T]) and the two scalars a
-    training loop logs: ``load_max_over_mean`` (the busiest expert's pairs
-    over the mean, the maximum over layers) and ``bias_moved_share`` (the
-    share of (layer, token) pairs whose k experts under ``scores + bias``
-    are not the k under the scores alone: 0 says the bias does not reach
-    the selection, near 1 that it drowns the scores)."""
-    h, stats = lfm2_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                           remat=remat, routing=routing)
-    S = tokens.shape[1]
-    if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
-        loss_chunk = cfg.loss_chunk
-    loss = head_loss(h, params["embed"].T, targets, loss_chunk)
-    if stats:
-        counts = stats.pop("counts")
-        stats["load_max_over_mean"] = jnp.max(
-            jnp.max(counts, axis=1) / jnp.mean(counts, axis=1))
-        if "bias_moved" in stats:
-            stats["bias_moved_share"] = jnp.mean(stats.pop("bias_moved"))
-    return loss, stats
-
-
-def lfm2_loss(*args: Any, **kw: Any) -> jax.Array:
-    """:func:`lfm2_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
-    return lfm2_loss_and_stats(*args, **kw)[0]
+LFM2 = Decoder(_bodies, _counters, routed=lambda kind: kind[1] == "moe")
+lfm2_hidden, lfm2_forward = LFM2.hidden, LFM2.forward
+lfm2_loss_and_stats, lfm2_loss = LFM2.loss_and_stats, LFM2.loss
 
 
 def lfm2_param_specs(cfg: Lfm2Config, mesh: Optional[Any] = None) -> Dict[str, Any]:
@@ -378,8 +269,6 @@ def lfm2_param_specs(cfg: Lfm2Config, mesh: Optional[Any] = None) -> Dict[str, A
     leaves and ``expert_bias`` replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from torchft_tpu.models.moe import _refuse_dropless_ep
-
     if mesh is not None:
         _refuse_dropless_ep(cfg, [a for a, n in mesh.shape.items() if n > 1])
     mixer = {
@@ -388,18 +277,14 @@ def lfm2_param_specs(cfg: Lfm2Config, mesh: Optional[Any] = None) -> Dict[str, A
         "attn": {"wq": P(None, "fsdp", "tp"), "wk": P(None, "fsdp", "tp"),
                  "wv": P(None, "fsdp", "tp"), "wo": P(None, "tp", "fsdp"),
                  "q_norm": P(None, None), "k_norm": P(None, None)}}
-    ffn = {
-        "dense": {"w_gate": P(None, "fsdp", "tp"), "w_up": P(None, "fsdp", "tp"),
-                  "w_down": P(None, "tp", "fsdp")},
-        "moe": {"router": P(None, "fsdp", None),
-                "w_gate": P(None, "ep", "fsdp", "tp"),
-                "w_up": P(None, "ep", "fsdp", "tp"),
-                "w_down": P(None, "ep", "tp", "fsdp")}}
-    specs = {"embed": P("fsdp", "tp"),
-             "layers": {name: {"norm": P(None, None), **mixer[m],
-                               "ffn_norm": P(None, None), **ffn[f]}
-                        for name, (m, f), _ in cfg.runs()},
-             "final_norm": P(None)}
+    ffn = {f: ffn_specs(ffn_leaves(cfg, f)) for f in ("dense", "moe")}
+    specs = spec_tree(cfg, lambda kind: {"norm": P(None, None), **mixer[kind[0]],
+                                         "ffn_norm": P(None, None), **ffn[kind[1]]})
     if cfg.n_moe_layers:
         specs["expert_bias"] = P(None, None)
     return specs
+
+
+register(Lfm2Config, LFM2_CONFIGS, lambda: ModelFns(
+    lfm2_init, logged(lfm2_loss_and_stats, moe=("load_max_over_mean", "bias_moved_share")),
+    lfm2_param_specs, None, LFM2_FROZEN))

@@ -42,25 +42,29 @@ The vocabulary may be a slice too (``vocab_size`` rows of embedding and of
 head: ids, logits and loss over the slice); the head is untied. The family's
 multi-token-prediction layer is not built: its published loss weight is 0.
 
-As in ``models/lfm2.py`` the parameters are one stack per RUN of like layers
-(dense layers of one mixer together, an expert layer alone; the names sort
-in layer order) and the forward pass scans each run under one remat policy.
+The parameters are one stack per RUN of like layers (dense layers of one
+mixer together, an expert layer alone) and ``models/decoder.py`` scans the
+runs: this module is the configuration, ``init``, the two mixers, the layer
+body, the PartitionSpecs and the counters, and declares them (``LING``).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from torchft_tpu.models.lfm2 import runs_of
-from torchft_tpu.models.llama import _attention, _rmsnorm, _rope, head_loss
-from torchft_tpu.models.moe import MoEConfig, moe_ffn
-from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
+from torchft_tpu.models.kinds import ModelFns, logged, register
+from torchft_tpu.models.llama import _attention, _rmsnorm, _rope
+from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
+                                    ffn_leaves, ffn_specs, moe_ffn)
+from torchft_tpu.models.remat import ATTN_OUT_NAME
 from torchft_tpu.ops.kda import kda
 
 __all__ = [
@@ -103,26 +107,13 @@ class LingConfig(MoEConfig):
     routed_scaling: float = 2.5
     capacity_factor: Optional[float] = None  # dropless
     aux_loss_weight: float = 0.0
-    norm_topk_prob: bool = True
     router_score: str = "sigmoid"
     gate_eps: float = 1e-20
     loss_chunk: int = 0  # as ``Lfm2Config.loss_chunk``
 
     def __post_init__(self) -> None:
         super().__post_init__()
-        if len(self.layer_types) != self.n_layers:
-            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
-                             f"n_layers is {self.n_layers}")
-        other = sorted(set(self.layer_types) - {"kda", "mla"})
-        if other:
-            raise ValueError(f"layer_types {other}: models/ling.py mixes with "
-                             "'kda' or 'mla'")
-        if self.capacity_factor is not None or self.aux_loss_weight:
-            raise ValueError("capacity_factor / aux_loss_weight: the family's "
-                             "expert block drops nothing and has no auxiliary loss")
-        if not 0 <= self.num_dense_layers <= self.n_layers:
-            raise ValueError(f"num_dense_layers={self.num_dense_layers} of "
-                             f"{self.n_layers} layers")
+        self._check_layer_types(("kda", "mla"), self.num_dense_layers)
         if not -5.0 <= self.kda_lower_bound < 0:
             raise ValueError(f"kda_lower_bound={self.kda_lower_bound}: ops/kda.py "
                              "takes decays in [-5, 0)")
@@ -143,7 +134,7 @@ class LingConfig(MoEConfig):
 
     def runs(self) -> List[Tuple[str, Tuple[str, str], int]]:
         """Runs of like layers in order, as :meth:`Lfm2Config.runs`."""
-        return runs_of(self.kinds())
+        return runs_of(self.kinds(), name="_".join, merges=lambda kind: kind[1] == "dense")
 
     def num_params(self) -> int:
         """Every leaf this chip holds, ``expert_bias`` among them."""
@@ -198,8 +189,8 @@ def ling_init(key: jax.Array, cfg: LingConfig) -> Dict[str, Any]:
     where there are expert layers, ``expert_bias`` [expert layers,
     num_experts] float32 (state: ``LING_FROZEN``)."""
     k_emb, k_head, k_bias, k_layers = jax.random.split(key, 4)
-    d, f, H = cfg.dim, cfg.ffn_hidden, cfg.n_heads
-    kd, E, held, W = H * cfg.kda_head_dim, cfg.num_experts, cfg.n_held, cfg.moe_intermediate_size
+    d, H = cfg.dim, cfg.n_heads
+    kd = H * cfg.kda_head_dim
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, _F32) / jnp.sqrt(fan_in)).astype(cfg.dtype)
@@ -225,35 +216,17 @@ def ling_init(key: jax.Array, cfg: LingConfig) -> Dict[str, Any]:
                 "w_g": dense(keys[3], (L, d, H), d),
                 "wo": dense(keys[4], (L, hv, d), hv)}
 
-    def ffn(kind, keys, L):
-        if kind == "dense":
-            return {"w_gate": dense(keys[0], (L, d, f), d),
-                    "w_up": dense(keys[1], (L, d, f), d),
-                    "w_down": dense(keys[2], (L, f, d), f)}
-        return {  # router in f32: its scores drive routing decisions
-            "router": jax.random.normal(keys[3], (L, d, E), _F32) / jnp.sqrt(d),
-            "w_gate": dense(keys[0], (L, held, d, W), d),
-            "w_up": dense(keys[1], (L, held, d, W), d),
-            "w_down": dense(keys[2], (L, held, W, d), W),
-            "shared_gate": dense(keys[4], (L, d, W), d),
-            "shared_up": dense(keys[5], (L, d, W), d),
-            "shared_down": dense(keys[6], (L, W, d), W)}
-
     def run(key, kind, L):
         ks = jax.random.split(key, 20)
         return {"norm": jnp.ones((L, d), cfg.dtype), **mixer(kind[0], ks[:12], L),
-                "ffn_norm": jnp.ones((L, d), cfg.dtype), **ffn(kind[1], ks[12:], L)}
+                "ffn_norm": jnp.ones((L, d), cfg.dtype),
+                **ffn_init(ffn_leaves(cfg, kind[1], shared=True), ks[12:], L, cfg.dtype)}
 
-    runs = cfg.runs()
-    params = {
-        "embed": dense(k_emb, (cfg.vocab_size, d), d),
-        "layers": {name: run(k, kind, L) for (name, kind, L), k
-                   in zip(runs, jax.random.split(k_layers, len(runs)))},
-        "final_norm": jnp.ones((d,), cfg.dtype),
-        "lm_head": dense(k_head, (d, cfg.vocab_size), d)}
+    params = {**init_tree(k_emb, k_layers, cfg, run),
+              "lm_head": dense(k_head, (d, cfg.vocab_size), d)}
     if cfg.n_moe_layers:
         params["expert_bias"] = BIAS_INIT_SCALE * jax.random.normal(
-            k_bias, (cfg.n_moe_layers, E), _F32)
+            k_bias, (cfg.n_moe_layers, cfg.num_experts), _F32)
     return params
 
 
@@ -263,16 +236,9 @@ def _head_gate(o: jax.Array, u: jax.Array, w_g: jax.Array) -> jax.Array:
     return (o * gate.astype(o.dtype)[..., None]).reshape(*o.shape[:2], -1)
 
 
-def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """SiLU of the depthwise causal convolution, ``models/jamba.py``'s
-    ``_causal_conv`` with the sequence padded before it is widened to
-    float32: at 32k three float32 copies of [T, 4096] were 1.5 GB of a
-    layer's backward pass. x [B,T,di], w [k,di] (``w[k-1]`` weighs the
-    current position)."""
-    k, T = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    out = sum(padded[:, j:j + T].astype(_F32) * w[j].astype(_F32) for j in range(k))
-    return jax.nn.silu(out).astype(x.dtype)
+# SiLU of the depthwise causal convolution, x [B,T,di], w [k,di]: the
+# sequence padded BEFORE it is widened to float32 (``decoder._causal_conv``)
+_short_conv = partial(_causal_conv, b=None, widen_late=True)
 
 
 def _l2norm(x: jax.Array) -> jax.Array:
@@ -362,92 +328,22 @@ def _layer_body(cfg: LingConfig, kind: Tuple[str, str], attention: Any):
     return layer
 
 
-def ling_hidden(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: LingConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim],
-    ``moe_ffn``'s stats of the expert layers, each stacked over them).
-    ``routing`` [expert layers, B*S, k]: the experts to use (replay)."""
-    attention = attention_fn or _attention
-    h = params["embed"][tokens]
-    stats, at = [], 0  # ``at``: expert layers before this run
-    for name, kind, L in cfg.runs():
-        body = remat_wrap(_layer_body(cfg, kind, attention), remat)
-        rows = slice(at, at + L) if kind[1] == "moe" else None
-        xs = (params["layers"][name],
-              None if rows is None else params["expert_bias"][rows],
-              None if routing is None or rows is None else routing[rows])
-        h, out = jax.lax.scan(body, h, xs)
-        if rows is not None:
-            stats.append(out)
-            at += L
-    stats = (jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
-             if stats else {})
-    return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
+def _bodies(cfg: LingConfig, seq: int, attention_fn: Optional[Any]):
+    return lambda kind: _layer_body(cfg, kind, attention_fn or _attention)
 
 
-def ling_forward(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: LingConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> jax.Array:
-    """tokens int32 [B, S] -> logits f32 [B, S, vocab_size] (the slice's)."""
-    h, _ = ling_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                       remat=remat, routing=routing)
-    return (h @ params["lm_head"]).astype(_F32)
+def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: LingConfig
+              ) -> Dict[str, jax.Array]:
+    """The expert layers' free routing with its margins (``routing``
+    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and all five of
+    ``moe.expert_scalars``: ``load_max_over_mean``, ``bias_moved_share``,
+    ``groups_hit_mean``, ``held_pair_share`` and ``overflow_pairs``."""
+    return expert_scalars(stats, tokens.size * cfg.top_k)
 
 
-def ling_loss_and_stats(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    targets: jax.Array,
-    cfg: LingConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    loss_chunk: int = 0,
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mean next-token cross-entropy over the vocabulary this chip holds
-    (``loss_chunk`` as ``lfm2_loss_and_stats``') and stats: the expert
-    layers' free routing with its margins (``routing`` [L,T,k], ``p_kth``,
-    ``p_next`` [L,T]) and the scalars a training loop logs:
-    ``load_max_over_mean`` (over the HELD experts), ``bias_moved_share``,
-    ``held_pair_share`` (the pairs that reached a held expert over T * k:
-    the even share is held / num_experts), ``overflow_pairs`` (held pairs
-    that found the share's buffer full, summed over layers: computed by
-    nobody, so anything but 0 is a wrong step) and ``groups_hit_mean`` (the
-    groups a token's k experts lie in: at most ``topk_group``)."""
-    h, stats = ling_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                           remat=remat, routing=routing)
-    S = tokens.shape[1]
-    if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
-        loss_chunk = cfg.loss_chunk
-    loss = head_loss(h, params["lm_head"], targets, loss_chunk)
-    if stats:
-        counts = stats.pop("counts")
-        stats["load_max_over_mean"] = jnp.max(
-            jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts, axis=1), 1e-9))
-        stats["bias_moved_share"] = jnp.mean(stats.pop("bias_moved"))
-        if "groups_hit" in stats:
-            stats["groups_hit_mean"] = jnp.mean(stats.pop("groups_hit"))
-        if "held_pairs" in stats:
-            stats["held_pair_share"] = jnp.mean(
-                stats.pop("held_pairs").astype(_F32)) / (tokens.size * cfg.top_k)
-            stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
-    return loss, stats
-
-
-def ling_loss(*args: Any, **kw: Any) -> jax.Array:
-    """:func:`ling_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
-    return ling_loss_and_stats(*args, **kw)[0]
+LING = Decoder(_bodies, _counters, routed=lambda kind: kind[1] == "moe")
+ling_hidden, ling_forward = LING.hidden, LING.forward
+ling_loss_and_stats, ling_loss = LING.loss_and_stats, LING.loss
 
 
 def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, Any]:
@@ -457,8 +353,6 @@ def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, A
     ``ep`` > 1 is refused, a share is one chip's), the small leaves, the
     decay's and ``expert_bias`` replicated."""
     from jax.sharding import PartitionSpec as P
-
-    from torchft_tpu.models.moe import _refuse_dropless_ep
 
     if mesh is not None:
         _refuse_dropless_ep(cfg, [a for a, n in mesh.shape.items() if n > 1])
@@ -471,17 +365,15 @@ def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, A
                 "w_g": P(None, "fsdp", None), "wo": row},
         "mla": {"wq": col, "w_kva": P(None, "fsdp", None), "kv_norm": rep2,
                 "w_kvb": P(None, None, "tp"), "w_g": P(None, "fsdp", None), "wo": row}}
-    ffn = {
-        "dense": {"w_gate": col, "w_up": col, "w_down": row},
-        "moe": {"router": P(None, "fsdp", None),
-                "w_gate": P(None, "ep", "fsdp", "tp"),
-                "w_up": P(None, "ep", "fsdp", "tp"),
-                "w_down": P(None, "ep", "tp", "fsdp"),
-                "shared_gate": col, "shared_up": col, "shared_down": row}}
-    specs = {"embed": P("fsdp", "tp"),
-             "layers": {name: {"norm": rep2, **mixer[m], "ffn_norm": rep2, **ffn[f]}
-                        for name, (m, f), _ in cfg.runs()},
-             "final_norm": P(None), "lm_head": P("fsdp", "tp")}
+    ffn = {f: ffn_specs(ffn_leaves(cfg, f, shared=True)) for f in ("dense", "moe")}
+    specs = {**spec_tree(cfg, lambda kind: {"norm": rep2, **mixer[kind[0]], "ffn_norm": rep2,
+                                            **ffn[kind[1]]}), "lm_head": P("fsdp", "tp")}
     if cfg.n_moe_layers:
         specs["expert_bias"] = rep2
     return specs
+
+
+register(LingConfig, LING_CONFIGS, lambda: ModelFns(
+    ling_init, logged(ling_loss_and_stats, moe=(
+        "load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
+        "groups_hit_mean")), ling_param_specs, None, LING_FROZEN))

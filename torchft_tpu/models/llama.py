@@ -20,13 +20,13 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
+from torchft_tpu.models.kinds import ModelFns, logged, register
 from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
 from torchft_tpu.models.staged import Stages
 
@@ -334,3 +334,13 @@ def head_loss(
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h_c, t_c))
     return total / (B * S)
+
+
+def _model_fns() -> ModelFns:
+    from torchft_tpu.parallel.mesh import llama_param_specs  # it imports this module
+
+    return ModelFns(llama_init, logged(lambda *a, **kw: (llama_loss(*a, **kw), {})),
+                    llama_param_specs, llama_stages)
+
+
+register(LlamaConfig, {}, _model_fns)  # its presets are ``CONFIGS`` itself

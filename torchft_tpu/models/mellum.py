@@ -33,8 +33,9 @@ multi-token-prediction head has no key in the published configuration and
 is not built.
 
 The parameters are one stack per RUN of like layers (``00_window`` [3, ...],
-``01_full`` [1, ...], ...; the names sort in layer order) and the forward
-pass scans each run under one remat policy, as ``models/lfm2.py``'s.
+``01_full`` [1, ...], ...) and ``models/decoder.py`` scans the runs: this
+module is the configuration, the two rotary tables, ``init``, the layer
+body, the PartitionSpecs and the counters, and declares them (``MELLUM``).
 """
 
 from __future__ import annotations
@@ -47,9 +48,11 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from torchft_tpu.models.llama import _attention, _rmsnorm, head_loss
-from torchft_tpu.models.moe import MoEConfig, moe_ffn
-from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
+from torchft_tpu.models.decoder import Decoder, init_tree, runs_of
+from torchft_tpu.models.kinds import ModelFns, logged, register
+from torchft_tpu.models.llama import _attention, _rmsnorm
+from torchft_tpu.models.moe import MoEConfig, expert_scalars, moe_ffn, moe_param_specs
+from torchft_tpu.models.remat import ATTN_OUT_NAME
 from torchft_tpu.ops.attention import window_block_share
 
 __all__ = [
@@ -85,35 +88,18 @@ class MellumConfig(MoEConfig):
     top_k: int = 8
     capacity_factor: Optional[float] = None  # dropless
     aux_loss_weight: float = 0.0
-    norm_topk_prob: bool = True
-    router_score: str = "softmax"
     loss_chunk: int = 0  # as ``Lfm2Config.loss_chunk``
 
     def __post_init__(self) -> None:
         super().__post_init__()
-        if len(self.layer_types) != self.n_layers:
-            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
-                             f"n_layers is {self.n_layers}")
-        other = sorted(set(self.layer_types) - {"window", "full"})
-        if other:
-            raise ValueError(f"layer_types {other}: models/mellum.py attends over a "
-                             "'window' or in 'full'")
-        if self.capacity_factor is not None or self.aux_loss_weight:
-            raise ValueError("capacity_factor / aux_loss_weight: the family's "
-                             "expert block drops nothing and has no auxiliary loss")
+        self._check_layer_types(("window", "full"))
         if self.window < 1 or self.head_dim % 2:
             raise ValueError(f"window={self.window}, head_dim={self.head_dim}")
 
     def runs(self) -> List[Tuple[str, str, int]]:
         """The runs of like layers in order: (the name of the run's stack,
-        its kind, layers)."""
-        out: List[Tuple[str, str, int]] = []
-        for kind in self.layer_types:
-            if out and out[-1][1] == kind:
-                out[-1] = (out[-1][0], kind, out[-1][2] + 1)
-            else:
-                out.append((f"{len(out):02d}_{kind}", kind, 1))
-        return out
+        its kind, layers); neighbours of a kind merge."""
+        return runs_of(self.layer_types)
 
     def num_params(self) -> int:
         """Every leaf this chip holds."""
@@ -206,7 +192,7 @@ def mellum_init(key: jax.Array, cfg: MellumConfig) -> Dict[str, Any]:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, _F32) / jnp.sqrt(fan_in)).astype(cfg.dtype)
 
-    def run(key, L):
+    def run(key, kind, L):
         ks = jax.random.split(key, 8)
         return {
             "attn_norm": jnp.ones((L, d), cfg.dtype),
@@ -220,13 +206,8 @@ def mellum_init(key: jax.Array, cfg: MellumConfig) -> Dict[str, Any]:
             "w_up": dense(ks[6], (L, held, d, W), d),
             "w_down": dense(ks[7], (L, held, W, d), W)}
 
-    runs = cfg.runs()
-    return {
-        "embed": dense(k_emb, (cfg.vocab_size, d), d),
-        "layers": {name: run(k, L) for (name, _, L), k
-                   in zip(runs, jax.random.split(k_layers, len(runs)))},
-        "final_norm": jnp.ones((d,), cfg.dtype),
-        "lm_head": dense(k_head, (d, cfg.vocab_size), d)}
+    return {**init_tree(k_emb, k_layers, cfg, run),
+            "lm_head": dense(k_head, (d, cfg.vocab_size), d)}
 
 
 def _mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: MellumConfig, kind: str,
@@ -247,11 +228,11 @@ def _mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: MellumConfig, kind: str,
 
 def _layer_body(cfg: MellumConfig, kind: str, table: Tuple[jax.Array, jax.Array],
                 attention: Any):
-    """The scanned body of a run of ``kind``: ``(h, (w, replay)) -> (h,
-    moe_ffn's stats)``."""
+    """The scanned body of a run of ``kind``: ``(h, (w, None, replay)) ->
+    (h, moe_ffn's stats)`` (no selection bias in this family)."""
 
     def layer(h, xs):
-        w, replay = xs
+        w, _, replay = xs
         u = _rmsnorm(h, w["attn_norm"], cfg.norm_eps)
         with jax.named_scope(f"attn_{kind}/mixer"):
             h = h + _mixer(u, w, cfg, kind, table, attention)
@@ -264,90 +245,30 @@ def _layer_body(cfg: MellumConfig, kind: str, table: Tuple[jax.Array, jax.Array]
     return layer
 
 
-def mellum_hidden(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: MellumConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim],
-    ``moe_ffn``'s stats, each stacked over the layers). ``routing`` [layers,
-    B*S, k]: the experts to use (replay)."""
-    attention = attention_fn or _attention
-    tables = rope_tables(cfg, tokens.shape[1])
-    h = params["embed"][tokens]
-    stats, at = [], 0  # ``at``: layers before this run
-    for name, kind, L in cfg.runs():
-        body = remat_wrap(_layer_body(cfg, kind, tables[kind], attention), remat)
-        xs = (params["layers"][name], None if routing is None else routing[at:at + L])
-        h, out = jax.lax.scan(body, h, xs)
-        stats.append(out)
-        at += L
-    stats = jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
-    return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
+def _bodies(cfg: MellumConfig, seq: int, attention_fn: Optional[Any]):
+    attention, tables = attention_fn or _attention, rope_tables(cfg, seq)
+    return lambda kind: _layer_body(cfg, kind, tables[kind], attention)
 
 
-def mellum_forward(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    cfg: MellumConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    routing: Optional[jax.Array] = None,
-) -> jax.Array:
-    """tokens int32 [B, S] -> logits f32 [B, S, vocab_size] (the slice's)."""
-    h, _ = mellum_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                         remat=remat, routing=routing)
-    return (h @ params["lm_head"]).astype(_F32)
-
-
-def mellum_loss_and_stats(
-    params: Dict[str, Any],
-    tokens: jax.Array,
-    targets: jax.Array,
-    cfg: MellumConfig,
-    attention_fn: Optional[Any] = None,
-    remat: Any = "full",
-    loss_chunk: int = 0,
-    routing: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mean next-token cross-entropy over the vocabulary this chip holds
-    (``loss_chunk`` as ``lfm2_loss_and_stats``') and stats: the layers' free
-    routing with its margins (``routing`` [L,T,k], ``p_kth``, ``p_next``
-    [L,T]) and the scalars a training loop logs: ``load_max_over_mean`` (over
-    the HELD experts), under a share ``held_pair_share`` (the pairs that
-    reached a held expert over T * k: the even share is held / num_experts)
-    and ``overflow_pairs`` (held pairs that found the share's buffer full,
-    summed over layers: computed by nobody, so anything but 0 is a wrong
-    step), and what the attention kernels were built for:
+def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: MellumConfig
+              ) -> Dict[str, jax.Array]:
+    """The layers' free routing with its margins (``routing`` [L,T,k],
+    ``p_kth``, ``p_next`` [L,T]), ``moe.expert_scalars``' three for this
+    family (``load_max_over_mean`` and, under a share, ``held_pair_share``
+    and ``overflow_pairs``), and what the attention kernels were built for:
     ``window_layers``, ``full_layers`` and ``window_block_share``
-    (``ops.attention.window_block_share``: 1.0 means a window layer's kernel
-    skips nothing)."""
-    h, stats = mellum_hidden(params, tokens, cfg, attention_fn=attention_fn,
-                             remat=remat, routing=routing)
-    S = tokens.shape[1]
-    if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
-        loss_chunk = cfg.loss_chunk
-    loss = head_loss(h, params["lm_head"], targets, loss_chunk)
-    counts = stats.pop("counts")
-    stats["load_max_over_mean"] = jnp.max(
-        jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts, axis=1), 1e-9))
-    if "held_pairs" in stats:
-        stats["held_pair_share"] = jnp.mean(
-            stats.pop("held_pairs").astype(_F32)) / (tokens.size * cfg.top_k)
-        stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
+    (``ops.attention``'s: 1.0 means a window layer's kernel skips nothing)."""
+    stats = expert_scalars(stats, tokens.size * cfg.top_k)
     windows = sum(t == "window" for t in cfg.layer_types)
     stats["window_layers"] = jnp.asarray(windows, _F32)
     stats["full_layers"] = jnp.asarray(cfg.n_layers - windows, _F32)
-    stats["window_block_share"] = jnp.asarray(window_block_share(S, cfg.window), _F32)
-    return loss, stats
+    stats["window_block_share"] = jnp.asarray(window_block_share(tokens.shape[1], cfg.window), _F32)
+    return stats
 
 
-def mellum_loss(*args: Any, **kw: Any) -> jax.Array:
-    """:func:`mellum_loss_and_stats`' loss alone (``llama_loss``'s shape)."""
-    return mellum_loss_and_stats(*args, **kw)[0]
+MELLUM = Decoder(_bodies, _counters, routed=lambda kind: True)
+mellum_hidden, mellum_forward = MELLUM.hidden, MELLUM.forward
+mellum_loss_and_stats, mellum_loss = MELLUM.loss_and_stats, MELLUM.loss
 
 
 def mellum_param_specs(cfg: MellumConfig, mesh: Optional[Any] = None) -> Dict[str, Any]:
@@ -357,8 +278,12 @@ def mellum_param_specs(cfg: MellumConfig, mesh: Optional[Any] = None) -> Dict[st
     replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from torchft_tpu.models.moe import moe_param_specs
-
     specs = moe_param_specs(cfg, mesh)
     run = {**specs.pop("layers"), "q_norm": P(None, None), "k_norm": P(None, None)}
     return {**specs, "layers": {name: dict(run) for name, _, _ in cfg.runs()}}
+
+
+register(MellumConfig, MELLUM_CONFIGS, lambda: ModelFns(
+    mellum_init, logged(
+        mellum_loss_and_stats, moe=("load_max_over_mean", "held_pair_share", "overflow_pairs"),
+        attn=("window_layers", "full_layers", "window_block_share")), mellum_param_specs, None))

@@ -54,9 +54,9 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
-from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
-
+from torchft_tpu.models.kinds import ModelFns, logged, register
 from torchft_tpu.models.llama import LlamaConfig, _attention, _rmsnorm, _rope
+from torchft_tpu.models.remat import ATTN_OUT_NAME, remat_wrap
 from torchft_tpu.models.staged import Stages
 from torchft_tpu.ops.take_rows import take_rows
 
@@ -71,6 +71,10 @@ __all__ = [
     "moe_stages",
     "moe_ffn",
     "load_balancing_loss",
+    "expert_scalars",
+    "ffn_leaves",
+    "ffn_init",
+    "ffn_specs",
 ]
 
 
@@ -116,6 +120,22 @@ class MoEConfig(LlamaConfig):
                     or first + count > self.num_experts:
                 raise ValueError(f"held_experts={self.held_experts}: a share of the "
                                  f"{self.num_experts} experts, on the dropless path")
+
+    def _check_layer_types(self, known: Tuple[str, ...], dense_layers: int = 0) -> None:
+        """What a kind with ``layer_types`` over the dropless block refuses,
+        the key named (its ``__post_init__`` calls this; ``dense_layers``:
+        its ``num_dense_layers`` where it has leading dense layers)."""
+        if len(self.layer_types) != self.n_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, "
+                             f"n_layers is {self.n_layers}")
+        other = sorted(set(self.layer_types) - set(known))
+        if other:
+            raise ValueError(f"layer_types {other}: {type(self).__name__} mixes with {known}")
+        if self.capacity_factor is not None or self.aux_loss_weight:
+            raise ValueError("capacity_factor / aux_loss_weight: the family's "
+                             "expert block drops nothing and has no auxiliary loss")
+        if not 0 <= dense_layers <= self.n_layers:
+            raise ValueError(f"num_dense_layers={dense_layers} of {self.n_layers} layers")
 
     @property
     def n_held(self) -> int:
@@ -596,6 +616,84 @@ def load_balancing_loss(counts: jax.Array, prob_sum: jax.Array, tokens: int) -> 
     return counts.shape[1] * jnp.sum(jax.lax.stop_gradient(f) * p)
 
 
+def expert_scalars(stats: Dict[str, jax.Array], pairs: int,
+                   mean_floor: Optional[float] = 1e-9) -> Dict[str, jax.Array]:
+    """:func:`moe_ffn`'s stats stacked over the expert layers -> the same
+    dict, the per-layer counters a configuration gives replaced by the
+    scalars a loop logs: ``load_max_over_mean`` (the busiest expert's pairs
+    over the mean, the maximum over layers: 1 is even; over the HELD experts
+    under a share), ``bias_moved_share`` (the (layer, token) pairs whose k
+    experts under ``scores + bias`` are not the k under the scores alone: 0
+    says the bias does not reach the selection, near 1 that it drowns the
+    scores), ``groups_hit_mean`` (the groups a token's k experts lie in: at
+    most ``topk_group``), ``held_pair_share`` (the pairs that reached a held
+    expert over ``pairs`` = T * k: evenly, held / num_experts) and
+    ``overflow_pairs`` (held pairs that found the share's buffer full, over
+    all layers: computed by nobody, so anything but 0 is a wrong step).
+
+    ``mean_floor`` guards the mean of a share's counts, which may all be
+    zero; None divides by the mean as it is: LFM2's program (every expert
+    held: the mean is T * k / E). Another guard is another compiled step,
+    and LFM2's is not moved to it here."""
+    if not stats:  # no expert layer
+        return stats
+    counts = stats.pop("counts")
+    busiest, mean = jnp.max(counts, axis=1), jnp.mean(counts, axis=1)
+    stats["load_max_over_mean"] = jnp.max(
+        busiest / (mean if mean_floor is None else jnp.maximum(mean, mean_floor)))
+    if "bias_moved" in stats:
+        stats["bias_moved_share"] = jnp.mean(stats.pop("bias_moved"))
+    if "groups_hit" in stats:
+        stats["groups_hit_mean"] = jnp.mean(stats.pop("groups_hit"))
+    if "held_pairs" in stats:
+        stats["held_pair_share"] = jnp.mean(
+            stats.pop("held_pairs").astype(jnp.float32)) / pairs
+        stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
+    return stats
+
+
+def ffn_leaves(cfg: MoEConfig, kind: str, shared: bool = False
+               ) -> Dict[str, Tuple[int, Tuple[int, ...], int, Any]]:
+    """The feed-forward leaves of one layer that ends in a SwiGLU (``kind``
+    "dense", ``cfg.ffn_hidden`` wide) or in routed experts ("moe",
+    ``cfg.moe_intermediate_size`` wide, ``cfg.n_held`` of them here, beside
+    one ``shared`` expert where the family has one): leaf -> (which of the
+    layer's feed-forward keys draws it, its shape and its fan-in without the
+    layers' axis, its PartitionSpec with it). One table for :func:`ffn_init`
+    and :func:`ffn_specs`."""
+    from jax.sharding import PartitionSpec as P
+
+    d, f = cfg.dim, cfg.ffn_hidden
+    col, row = P(None, "fsdp", "tp"), P(None, "tp", "fsdp")
+    if kind == "dense":
+        return {"w_gate": (0, (d, f), d, col), "w_up": (1, (d, f), d, col),
+                "w_down": (2, (f, d), f, row)}
+    held, W = cfg.n_held, cfg.moe_intermediate_size
+    leaves = {"router": (3, (d, cfg.num_experts), d, P(None, "fsdp", None)),
+              "w_gate": (0, (held, d, W), d, P(None, "ep", "fsdp", "tp")),
+              "w_up": (1, (held, d, W), d, P(None, "ep", "fsdp", "tp")),
+              "w_down": (2, (held, W, d), W, P(None, "ep", "tp", "fsdp"))}
+    if shared:
+        leaves.update(shared_gate=(4, (d, W), d, col), shared_up=(5, (d, W), d, col),
+                      shared_down=(6, (W, d), W, row))
+    return leaves
+
+
+def ffn_init(leaves: Dict[str, Any], keys: jax.Array, L: int, dtype: Any
+             ) -> Dict[str, jax.Array]:
+    """:func:`ffn_leaves`' leaves stacked over ``L`` layers, normal over the
+    root of the fan-in; the router stays float32: its scores drive routing
+    decisions."""
+    return {name: (jax.random.normal(keys[at], (L, *shape), jnp.float32) / jnp.sqrt(fan_in)
+                   ).astype(jnp.float32 if name == "router" else dtype)
+            for name, (at, shape, fan_in, _) in leaves.items()}
+
+
+def ffn_specs(leaves: Dict[str, Any]) -> Dict[str, Any]:
+    """:func:`ffn_leaves`' PartitionSpecs."""
+    return {name: leaf[3] for name, leaf in leaves.items()}
+
+
 def _moe_layer(cfg, attention, positions, h, xs):
     """The ONE scanned MoE layer body (:func:`moe_forward`'s scan and the
     staged gradient's segment programs, :func:`moe_stages`): ``xs`` is
@@ -777,3 +875,17 @@ def moe_param_specs(cfg: MoEConfig, mesh: Optional[Any] = None) -> Dict[str, Any
         "final_norm": P(None),
         "lm_head": P("fsdp", "tp"),
     }
+
+
+def _model_fns() -> ModelFns:
+    scalars = ("load_max_over_mean", "aux_loss")
+
+    def stages(*args: Any, **kw: Any) -> Stages:
+        s = moe_stages(*args, **kw)
+        return s._replace(head=logged(s.head, moe=scalars))
+
+    return ModelFns(moe_init, logged(moe_loss_and_stats, moe=scalars), moe_param_specs, stages)
+
+
+register(MoEConfig, {"moe_debug" if name == "debug" else name: cfg  # the dense one is ``debug``
+                     for name, cfg in MOE_CONFIGS.items()}, _model_fns)

@@ -27,6 +27,9 @@ KINDS = {
         CONFIGS["moe_debug"], n_layers=5, num_experts=8, top_k=4,
         capacity_factor=None, norm_topk_prob=False, qk_norm=True),
     "jamba": dataclasses.replace(CONFIGS["jamba_debug"], dtype=jnp.float32),
+    # four passes over the five layers, the loss over the four exits in chunks
+    "ouro": dataclasses.replace(CONFIGS["ouro_debug"], n_layers=5, loss_chunk=16),
+    "ouro_one_pass": dataclasses.replace(CONFIGS["ouro_debug"], n_layers=5, total_ut_steps=1),
 }
 # layers a segment -> the parts the chain hands out (head, segments)
 SPLITS = {"one_segment": (5, 2), "one_layer_a_segment": (1, 6),
@@ -119,7 +122,7 @@ def test_bf16_chain_equals_one_value_and_grad(kind, split):
 @pytest.mark.parametrize("kind,split", [
     ("llama", "one_layer_a_segment"), ("llama", "uneven_last_segment"),
     ("moe_capacity", "one_layer_a_segment"), ("moe_dropless", "uneven_last_segment"),
-    ("jamba", "one_segment")])
+    ("jamba", "one_segment"), ("ouro", "uneven_last_segment")])
 def test_on_a_two_device_fsdp_mesh(kind, split):
     """In-group sharding runs the same chain: parameters over ``fsdp``, the
     trainer's attention function, every gradient leaf pinned to its
@@ -170,6 +173,77 @@ def test_the_parts_come_in_one_order_whatever_the_values(kind):
     assert "['embed']" in [name for name, _, _ in seen[0][-1]]
     # top layers first: the backward pass reaches them first
     assert [part[1][1][0] for part in seen[0][1:]] == [2, 2, 1]
+
+
+@pytest.mark.parametrize("kind", ["ouro", "ouro_one_pass"])
+def test_a_looped_kind_emits_its_layers_in_the_last_pass_of_the_backward(kind):
+    """Four passes over one stack: the head's part (the leaves whose gradient
+    is whole after program A: not the norm every pass boundary reads), NOTHING
+    while the backward goes through passes 4, 3 and 2 (three segment programs
+    each, adding into the segments' trees), then pass 1's segments, top
+    layers first, ``embed`` and ``final_norm`` with the last; one pass is the
+    dense chain's order. Told from the programs dispatched between the
+    emits."""
+    cfg, params, tokens, targets, loss_fn, stages = _setup(kind)
+    assert stages.loops == cfg.total_ut_steps
+    log, parts, real_jit = [], [], jax.jit
+
+    def jit(fn=None, **kw):  # the chain's programs, each call written down
+        if fn is None:
+            return lambda f: jit(f, **kw)
+        made = real_jit(fn, **kw)
+
+        def call(*a, **k):
+            log.append(fn.__name__)
+            return made(*a, **k)
+        return call
+
+    jax.jit = jit
+    try:
+        run, assemble = staged_value_and_grad(
+            stages, loss_fn, floor_bytes=2 * _layer_bytes(params))
+    finally:
+        jax.jit = real_jit
+
+    def emit(part):
+        log.append("emit:" + ",".join(sorted(part)))
+        parts.append(part)
+
+    run(params, tokens, targets, emit)
+    want = ["forward_and_exits", "emit:exit_gate,lm_head"]
+    for t in reversed(range(cfg.total_ut_steps)):
+        want.append("boundary_backward")
+        for s in range(3):
+            want.append("segment_backward")
+            if t == 0:
+                want += ["emit:layers"] if s < 2 else [
+                    "embed_backward", "emit:embed,final_norm,layers"]
+    assert log == want
+    assert [jax.tree_util.tree_leaves(p["layers"])[0].shape[0] for p in parts[1:]] == [2, 2, 1]
+    (_, _), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(params, tokens, targets)
+    assert _worst(jax.jit(assemble)(parts), grads) <= 1e-6
+
+
+def test_one_pass_of_a_looped_kind_is_a_plain_mean_cross_entropy():
+    """``total_ut_steps`` 1: the one exit takes all of the mass, the entropy
+    is 0, the gate gets no gradient, and the loss is the dense kind's mean
+    cross-entropy of the same states."""
+    from torchft_tpu.models.llama import head_loss
+    from torchft_tpu.models.ouro import ouro_exits
+
+    cfg, params, tokens, targets, loss_fn, _ = _setup("ouro_one_pass")
+    (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, tokens, targets)
+    h = ouro_exits(params, tokens, cfg)
+    assert h.shape[0] == 1
+    assert float(loss) == pytest.approx(float(head_loss(h[0], params["lm_head"], targets)),
+                                        rel=1e-6)
+    stats = stats["loop_stats"]
+    assert float(stats["loop_exit_step_mean"]) == float(stats["loop_p_last"]) == 1.0
+    assert float(stats["loop_exit_entropy"]) == 0.0
+    assert sorted(stats) == ["loop_ce_1", "loop_exit_entropy", "loop_exit_step_mean",
+                             "loop_p_last"]
+    assert all(float(jnp.max(jnp.abs(g))) == 0 for g in jax.tree_util.tree_leaves(
+        grads["exit_gate"]))
 
 
 @pytest.mark.parametrize("n_layers,layer_bytes,floor,want", [

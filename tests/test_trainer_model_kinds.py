@@ -57,7 +57,8 @@ def _lfm2_init(replica: int):
 
 @pytest.mark.parametrize("config,kind", [("moe_debug", "moe"), ("debug", "dense"),
                                          ("jamba_debug", "hybrid"),
-                                         ("lfm2_debug", "lfm2")])
+                                         ("lfm2_debug", "lfm2"),
+                                         ("ouro_debug", "looped")])
 def test_two_committed_steps_under_a_lighthouse(config, kind, tmp_path):
     s = _train(config, tmp_path)
     assert s["config"] == config and s["committed"] == 2 and s["discarded"] == 0
@@ -82,6 +83,18 @@ def test_two_committed_steps_under_a_lighthouse(config, kind, tmp_path):
         assert sorted(s["model_stats"]) == ["moe_aux_loss", "moe_load_max_over_mean"]
         assert all(len(v) == 2 for v in s["model_stats"].values())
         assert all(v >= 1.0 for v in s["model_stats"]["moe_load_max_over_mean"])
+    elif kind == "looped":
+        # four passes over one stack: the head's leaves, then (once the
+        # backward has been through every pass) the layers with the
+        # embedding and the norm between the passes: two ops all the same
+        assert sorted(s["model_stats"]) == [
+            "loop_ce_1", "loop_ce_2", "loop_ce_3", "loop_ce_4", "loop_exit_entropy",
+            "loop_exit_step_mean", "loop_p_last"]
+        assert all(len(v) == 2 for v in s["model_stats"].values())
+        assert all(2.0 < v < 3.0 for v in s["model_stats"]["loop_exit_step_mean"])
+        # a bucket an op: the debug preset is float32 throughout, so the gate
+        # travels in the head's bucket
+        assert s["timings"]["allreduce_buckets"] == 2
     elif kind == "hybrid":
         assert sorted(s["model_stats"]) == ["ssm_dt_max", "ssm_y_absmax"]
         assert all(len(v) == 2 and min(v) > 0 for v in s["model_stats"].values())
@@ -93,7 +106,7 @@ def test_two_committed_steps_under_a_lighthouse(config, kind, tmp_path):
         assert s["timings"]["allreduce_buckets"] == 2  # a bucket an op
 
 
-@pytest.mark.parametrize("config", ["debug", "moe_debug"])
+@pytest.mark.parametrize("config", ["debug", "moe_debug", "ouro_debug"])
 def test_two_groups_issue_their_ops_in_one_order(config, tmp_path):
     """The host exchange matches messages by arrival order: two groups that
     handed over a step's parts in different orders would reduce the head's

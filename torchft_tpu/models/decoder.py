@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from torchft_tpu.models.llama import _rmsnorm, head_loss
 from torchft_tpu.models.remat import remat_wrap
 
-__all__ = ["Decoder", "runs_of", "init_tree", "spec_tree"]
+__all__ = ["Decoder", "runs_of", "init_tree", "spec_tree", "loss_chunk_for"]
 
 _F32 = jnp.float32
 
@@ -95,6 +95,14 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
     if b is not None:
         out = out + b.astype(_F32)
     return (activation(out) if activation else out).astype(x.dtype)
+
+
+def loss_chunk_for(cfg: Any, seq: int, loss_chunk: int = 0) -> int:
+    """``loss_chunk``, or (0) the configuration's where that divides a
+    longer sequence."""
+    if not loss_chunk and cfg.loss_chunk and seq > cfg.loss_chunk and seq % cfg.loss_chunk == 0:
+        return cfg.loss_chunk
+    return loss_chunk
 
 
 def _head(params: Dict[str, Any]) -> jax.Array:
@@ -165,10 +173,8 @@ class Decoder:
         as there, and 0 takes ``cfg.loss_chunk`` where that divides a longer
         sequence: 8,192 x 65,536 float32 logits are 2 GiB) and ``counters``."""
         h, stats = self.hidden(params, tokens, cfg, attention_fn, remat, routing)
-        S = tokens.shape[1]
-        if not loss_chunk and cfg.loss_chunk and S > cfg.loss_chunk and S % cfg.loss_chunk == 0:
-            loss_chunk = cfg.loss_chunk
-        return head_loss(h, _head(params), targets, loss_chunk), self.counters(stats, tokens, cfg)
+        return (head_loss(h, _head(params), targets, loss_chunk_for(cfg, tokens.shape[1], loss_chunk)),
+                self.counters(stats, tokens, cfg))
 
     def loss(self, *args: Any, **kw: Any) -> jax.Array:
         """:meth:`loss_and_stats`' loss alone (``llama_loss``'s shape)."""

@@ -38,6 +38,8 @@ __all__ = [
     "llama_loss",
     "llama_stages",
     "head_loss",
+    "token_ce",
+    "sum_over_chunks",
     "CONFIGS",
 ]
 
@@ -192,7 +194,10 @@ def make_llama_layer_body(
     """The ONE scanned transformer layer body, shared by every execution
     path (dense scan here, GPipe stages in parallel/pipeline.py) so the
     layer math can never diverge between them. Signature matches lax.scan:
-    ``layer(h, layer_params) -> (h, None)`` with h [B, S, dim]."""
+    ``layer(h, layer_params) -> (h, None)`` with h [B, S, dim]. A stack that
+    has ``attn_post_norm`` / ``ffn_post_norm`` leaves is a sandwich-norm
+    layer (models/ouro.py): each branch is normalised once more before it
+    joins the residual stream."""
     attention = attention_fn or _attention
 
     def layer(h, layer_params):
@@ -207,10 +212,16 @@ def make_llama_layer_body(
         attn = jax.ad_checkpoint.checkpoint_name(
             attention(q, k, v, cfg), ATTN_OUT_NAME
         ).reshape(B, S, cfg.n_heads * cfg.head_dim)
-        h = h + attn @ layer_params["wo"]
+        mixed = attn @ layer_params["wo"]
+        if "attn_post_norm" in layer_params:
+            mixed = _rmsnorm(mixed, layer_params["attn_post_norm"], cfg.norm_eps)
+        h = h + mixed
         x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
         gated = jax.nn.silu(x @ layer_params["w_gate"]) * (x @ layer_params["w_up"])
-        h = h + gated @ layer_params["w_down"]
+        fed = gated @ layer_params["w_down"]
+        if "ffn_post_norm" in layer_params:
+            fed = _rmsnorm(fed, layer_params["ffn_post_norm"], cfg.norm_eps)
+        h = h + fed
         return h, None
 
     return layer
@@ -302,6 +313,26 @@ def llama_loss(
     return head_loss(h, params["lm_head"], targets, loss_chunk)
 
 
+def token_ce(h: jax.Array, lm_head: jax.Array, targets: jax.Array) -> jax.Array:
+    """The cross-entropy token by token, float32 [...]: logsumexp(logits) -
+    logits[target] of h [..., dim] under the head [dim, vocab]."""
+    logits = (h @ lm_head).astype(jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - tgt
+
+
+def sum_over_chunks(chunk_sum: Any, zero: jax.Array, chunks: Any) -> jax.Array:
+    """``zero`` + ``chunk_sum(*chunk)`` over the leading axis of ``chunks``,
+    each chunk rematerialised: the backward pass recomputes a chunk's logits
+    instead of keeping every chunk's resident."""
+    def body(acc, xs):
+        return acc + jax.checkpoint(chunk_sum)(*xs), None
+
+    total, _ = jax.lax.scan(body, zero, chunks)
+    return total
+
+
 def head_loss(
     h: jax.Array, lm_head: jax.Array, targets: jax.Array, loss_chunk: int = 0
 ) -> jax.Array:
@@ -309,10 +340,7 @@ def head_loss(
     h [B, S, dim] and a head matrix [dim, vocab] (a model with a tied head
     passes ``embed.T``: one leaf read twice, its gradient the sum of both)."""
     if loss_chunk <= 0:
-        logits = (h @ lm_head).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(lse - tgt)
+        return jnp.mean(token_ce(h, lm_head, targets))
 
     B, S = targets.shape
     if S % loss_chunk != 0:
@@ -321,18 +349,8 @@ def head_loss(
     # [n, B, chunk, ...]: scan over sequence chunks
     h_c = jnp.swapaxes(h.reshape(B, n, loss_chunk, -1), 0, 1)
     t_c = jnp.swapaxes(targets.reshape(B, n, loss_chunk), 0, 1)
-
-    def chunk_sum(hc, tc):
-        logits = (hc @ lm_head).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-        return jnp.sum(lse - tgt)
-
-    def body(acc, xs):
-        hc, tc = xs
-        return acc + jax.checkpoint(chunk_sum)(hc, tc), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h_c, t_c))
+    total = sum_over_chunks(lambda hc, tc: jnp.sum(token_ce(hc, lm_head, tc)),
+                            jnp.zeros((), jnp.float32), (h_c, t_c))
     return total / (B * S)
 
 

@@ -25,6 +25,18 @@ parameter layout ``{"embed": ..., "layers": <leaves stacked over layers>,
 - ``assemble``, for inside the caller's jitted update: the reduced parts
   back into one tree shaped like the parameters.
 
+A kind whose layers run several times over ONE set of weights
+(``Stages.loops`` > 1: models/ouro.py) has a layer's gradient whole only when
+the backward pass has gone through the stack once for every pass. Its chain
+keeps an input per layer APPLICATION, runs every exit's head in program A
+and emits there the head leaves whose gradient is whole then; the backward
+of the last passes adds into one gradient tree, segment by segment and in
+place (donated), and emits nothing; the backward of pass 1 emits each
+segment's accumulated sum as above. What stands between two passes
+(``Stages.between``: a final norm every pass boundary reads) is pulled back
+by a program of its own at each boundary, and the leaves it reads travel
+with the last part. One pass is the chain above, program for program.
+
 A kind without stages (the hybrid: runs of unlike layers and a tied head,
 whose gradient is whole only at the very end) is the degenerate chain of ONE
 program, ``value_and_grad`` of its loss function: the same calls, one part.
@@ -52,11 +64,21 @@ class Stages(NamedTuple):
     -> ``(h, emitted)``, the scanned body with whatever it hands the head
     per layer (None for nothing); ``head(head_params, h, emitted, targets)``
     -> ``(loss, stats)`` from the last hidden state, ``emitted`` stacked over
-    layers, and the parameters that are neither ``embed`` nor ``layers``."""
+    layers, and the parameters that are neither ``embed`` nor ``layers``.
+
+    ``loops`` > 1 or ``between``: a kind whose layers run ``loops`` times
+    over the same weights. ``between(late, u)`` -> what a pass's last hidden
+    state ``u`` becomes for the exit that reads it and the pass that follows,
+    ``late`` the head leaves named in ``between_reads``; ``head`` then gets
+    the other head leaves, every exit's state stacked ``[loops, B, S, dim]``
+    as ``h`` and None as ``emitted`` (such a kind's layers emit nothing)."""
 
     embed: Callable[..., Any]
     layer: Callable[..., Any]
     head: Callable[..., Any]
+    loops: int = 1
+    between: Optional[Callable[..., Any]] = None
+    between_reads: Tuple[str, ...] = ()
 
 
 def segments(
@@ -98,7 +120,10 @@ def staged_value_and_grad(
     segment's backward pass and runs under it. The parts come in one order
     whatever the values: the head leaves; each segment's
     ``{"layers": ...}`` (leaves ``[n, ...]``), the last one with
-    ``"embed"``. ``assemble(parts)``, the parts (or same-shaped stand-ins,
+    ``"embed"`` (and what ``Stages.between`` reads). A kind whose layers run
+    several times emits the head leaves, then nothing while the backward goes
+    through its last passes, then the segments.
+    ``assemble(parts)``, the parts (or same-shaped stand-ins,
     e.g. their reduced copies) in that order -> the gradient tree.
 
     ``loss_fn(params, tokens, targets) -> (loss, stats)``: what the chain
@@ -149,22 +174,96 @@ def staged_value_and_grad(
         g_head, g_h, g_emitted = pull(jnp.ones((), loss.dtype))
         return (loss, stats), pin(g_head, head_of), (g_h, kept, g_emitted)
 
-    @partial(jax.jit, static_argnames="n", donate_argnames="g_h")
-    def segment_backward(layers, kept, g_emitted, g_h, l0, n):
+    @partial(jax.jit, static_argnames="n", donate_argnames=("g_h", "acc"))
+    def segment_backward(layers, kept, g_emitted, g_h, l0, n, k0=None, acc=None):
+        """``k0``: where the segment's first kept input stands, if not at
+        ``l0`` (a later pass's); ``acc``: the segment's gradient from the
+        passes the backward has been through, added to in place."""
         def pull_layer(g_h, i):
             _, pull = jax.vjp(
-                stages.layer, _pick(kept, l0 + i), _pick(layers, l0 + i))
+                stages.layer, _pick(kept, (l0 if k0 is None else k0) + i),
+                _pick(layers, l0 + i))
             g_h, g_w = pull((g_h, _pick(g_emitted, l0 + i)))
             return g_h, g_w
 
         g_h, g_layers = jax.lax.scan(
             pull_layer, g_h, jnp.arange(n), reverse=True)
+        if acc is not None:
+            g_layers = jax.tree_util.tree_map(jnp.add, acc, g_layers)
         return g_h, pin(g_layers, lambda s: s["layers"])
 
     @jax.jit
     def embed_backward(embed, tokens, g_h):
         _, pull = jax.vjp(lambda e: stages.embed(e, tokens), embed)
         return pin(pull(g_h)[0], lambda s: s["embed"])
+
+    def late_of(params: Dict[str, Any]) -> Dict[str, Any]:
+        return {k: params[k] for k in stages.between_reads}
+
+    def early_of(params: Dict[str, Any]) -> Dict[str, Any]:
+        return {k: v for k, v in head_of(params).items()
+                if k not in stages.between_reads}
+
+    @jax.jit
+    def forward_and_exits(params, tokens, targets):
+        """Program A of a kind whose layers run ``loops`` times: the kept
+        inputs ``[loops * L, ...]`` pass by pass, what each pass's last layer
+        gave (``us``), every exit's head and loss and their backward."""
+        def keep_input(h, w):
+            return stages.layer(h, w)[0], h
+
+        def one_pass(h, _):
+            u, kept = jax.lax.scan(keep_input, h, params["layers"])
+            h = stages.between(late_of(params), u)
+            return h, (kept, u, h)
+
+        _, (kept, us, hs) = jax.lax.scan(
+            one_pass, stages.embed(params["embed"], tokens), None, length=stages.loops)
+        loss, pull, stats = jax.vjp(
+            lambda hp, hs: stages.head(hp, hs, None, targets),
+            early_of(params), hs, has_aux=True)
+        g_head, g_hs = pull(jnp.ones((), loss.dtype))
+        kept = jax.tree_util.tree_map(lambda x: x.reshape(-1, *x.shape[2:]), kept)
+        return (loss, stats), pin(g_head, early_of), (g_hs, kept, us)
+
+    @partial(jax.jit, donate_argnames=("g_h", "g_late"))
+    def boundary_backward(late, us, g_hs, t, g_h=None, g_late=None):
+        """The cotangent of what pass ``t`` handed on (its exit's, and the
+        next pass's ``g_h`` where there is one) pulled back through
+        ``between``: that of the pass's last layer's output, and ``g_late``
+        with this boundary's share of the late leaves' gradient."""
+        g = _pick(g_hs, t) if g_h is None else _pick(g_hs, t) + g_h
+        _, pull = jax.vjp(stages.between, late, _pick(us, t))
+        d_late, g_u = pull(g)
+        if g_late is not None:
+            d_late = jax.tree_util.tree_map(jnp.add, g_late, d_late)
+        return g_u, pin(d_late, late_of)
+
+    def run_looped(params, tokens, targets, emit):
+        """``run`` for ``stages.loops`` passes over one stack."""
+        layers, late = params["layers"], late_of(params)
+        n_layers = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        out, g_head, (g_hs, kept, us) = forward_and_exits(params, tokens, targets)
+        emit(g_head)
+        del g_head
+        jax.block_until_ready(jax.tree_util.tree_leaves(params)[:1])  # as in ``run``
+        plan = segments(n_layers, _nbytes(layers) // n_layers, floor_bytes)
+        acc: List[Any] = [None] * len(plan)  # a segment's gradient so far
+        g_h = g_late = None
+        for t in reversed(range(stages.loops)):
+            g_h, g_late = boundary_backward(late, us, g_hs, t, g_h=g_h, g_late=g_late)
+            for s, (l0, n) in enumerate(plan):
+                g_h, acc[s] = segment_backward(
+                    layers, kept, None, g_h, l0, n=n, k0=t * n_layers + l0, acc=acc[s])
+                if t == 0:  # whole: every pass has added its share
+                    part = {"layers": acc[s]}
+                    if l0 == 0:
+                        part["embed"] = embed_backward(params["embed"], tokens, g_h)
+                        part.update(g_late)
+                    emit(part)
+                    acc[s] = None
+                    del part
+        return out
 
     def run(params, tokens, targets, emit):
         layers = params["layers"]
@@ -196,6 +295,8 @@ def staged_value_and_grad(
         stacked = jax.tree_util.tree_map(
             lambda *xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs),
             *(seg["layers"] for seg in reversed(segs)))
-        return {**head, "layers": stacked, "embed": segs[-1]["embed"]}
+        return {**head, "layers": stacked,
+                **{k: v for k, v in segs[-1].items() if k != "layers"}}
 
-    return run, assemble
+    looped = stages.loops > 1 or stages.between is not None
+    return (run_looped if looped else run), assemble

@@ -835,10 +835,17 @@ class TestStagingThroughThePool:
 
         gates = []
 
-        def gated_token():
-            return lambda leaf: gates.append(Gate()) or gates[-1]
+        real_average = bucketing._average_on_device()
 
-        monkeypatch.setattr(bucketing, "_landed_token", gated_token)
+        def gated_average():
+            # the division's token is what the buffer is parked on: one a
+            # bucket
+            def average(xs, n):
+                gates.append(Gate())
+                return real_average(xs, n)[0], gates[-1]
+            return average
+
+        monkeypatch.setattr(bucketing, "_average_on_device", gated_average)
         a, b = _device_tree(seed=1), _device_tree(seed=2)
         m = make_manager(pg=host_of_one, quorum=make_quorum(),
                          bucket_cap_bytes=_CAP3)
@@ -846,7 +853,7 @@ class TestStagingThroughThePool:
         out_a = _reduce(m, a, streamed=True)
         m.should_commit()
         held_a = [buf for buf, _tokens in _parked(m)]
-        assert len(gates) == 6 and len(held_a) == 3 and _pooled(m) == 0
+        assert len(gates) == 3 and len(held_a) == 3 and _pooled(m) == 0
         out_b = _reduce(m, b, streamed=True)  # a's transfers "in flight"
         m.should_commit()
         assert (pool.hits, pool.misses) == (0, 6) and _pooled(m) == 0
@@ -858,7 +865,7 @@ class TestStagingThroughThePool:
                 assert not np.shares_memory(x, y)
         for k in a:
             assert np.array_equal(_bits(out_a[k]), _bits(np.asarray(a[k]) / 2))
-        for g in gates[:6]:  # a's leaves are ready, b's not yet
+        for g in gates[:3]:  # a's leaves are ready, b's not yet
             g.ready = True
         out_c = _reduce(m, a, streamed=True)
         m.should_commit()
@@ -1008,9 +1015,9 @@ class TestDeviceMilestonesOfAStep:
     @pytest.mark.parametrize("pg", [ProcessGroupDummy, CopyingPG])
     def test_forward_backward_and_a_landing_a_bucket(self, pg, monkeypatch):
         """Segment 0's last piece is ``device/forward``, a later segment's
-        ``device/backward``, and every landed bucket an instant: on the last
-        of the tokens that recycling the passed-through staging buffer made
-        anyway, or, where the result was a copy, on one token of its own."""
+        ``device/backward``, and every landed bucket an instant: on the
+        token of the bucket's one division, which recycling a passed-through
+        staging buffer parks it on too."""
         tokens = []
         real = bucketing._landed_token()
 
@@ -1039,10 +1046,9 @@ class TestDeviceMilestonesOfAStep:
             == [(0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (1, 2)]
         assert all(s["dur_us"] == 1 for s in landed)
         assert all({"waited_us", "late"} <= set(s["args"]) for s in dev)
-        passed_through = pg is ProcessGroupDummy
-        # six leaves an op: one token a leaf where the buffer is recycled
-        # and none more for the milestone; one a bucket where it is not
-        assert len(tokens) == (12 if passed_through else 6)
+        # under AVG the division's own token serves both the recycling of a
+        # passed-through buffer and the milestone: no token program at all
+        assert tokens == []
         # each hangs where its cause does: the op's allreduce span, the
         # bucket's unpack stage
         by_id = {s["id"]: s for s in spans}

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_allreduce_stream import CopyingPG
+from test_allreduce_stream import CopyingPG, world_of_two  # noqa: F401 (fixture)
 from test_manager import make_manager, make_quorum
 from torchft_tpu import bucketing
 from torchft_tpu.process_group import ProcessGroupDummy, ReduceOp
@@ -664,3 +664,338 @@ def test_glibc_is_told_to_keep_the_heap_through_a_trim(monkeypatch):
 
     monkeypatch.setattr(ctypes, "CDLL", missing)
     assert bucketing._keep_freed_blocks_mapped.__wrapped__() is False
+
+
+# ---------------------------------------------------------------------------
+# a host-plane op over device leaves moves in RUNS of whole leaves: its plan
+# is cut at bucketing.RUN_BYTES, and a run lands while the next is fetched
+
+_RUN = 1024  # bytes: RUN_BYTES for these tests
+
+
+def _run_leaves():
+    """bf16 leaves with one above a run and a float32 leaf among them, in
+    leaf order: 600 B, 2,400 B (above a run: its own), 4 x 400 B, a float32
+    of 200 B, 2 x 400 B."""
+    bf16 = _device_leaves(
+        [(300,), (1200,), (200,), (200,), (200,), (200,), (200,), (200,)],
+        jnp.bfloat16)
+    f32 = _device_leaves([(50,)], np.float32, seed=5)
+    return bf16[:6] + f32 + bf16[6:]
+
+
+def _pipeline(pg=None, tracer=None, stats=None):
+    from torchft_tpu.tracing import SpanRecorder, TraceConfig
+
+    return bucketing.BucketPipeline(
+        pg or CopyingPG(),
+        tracer or SpanRecorder("runs", TraceConfig(enabled=False)),
+        bucketing.BufferPool(),
+        (stats if stats is not None else {}).update,
+    )
+
+
+def _through(pipeline, leaves, plan, pg_op=ReduceOp.SUM, divisor=None,
+             place=None):
+    pipeline.begin_step()
+    op = pipeline.allreduce_buckets(
+        leaves, plan, pg_op, participating=True, divisor=divisor,
+        place=place or bucketing.leaf_placer(), timeout=30.0)
+    try:
+        return op, op.final.wait(30)
+    finally:
+        pipeline.record_timings(op)
+
+
+class TestRuns:
+    def test_the_cap_of_a_device_op_over_a_run_is_the_run(self, monkeypatch):
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        leaves = _run_leaves()
+        assert bucketing.run_cap(leaves, 1 << 30) == _RUN
+        assert bucketing.run_cap(leaves, 512) == 512  # "at most this" stands
+        assert bucketing.run_cap(leaves[2:4], 1 << 30) == 1 << 30  # under a run
+        host = [np.asarray(l) for l in leaves]
+        assert bucketing.run_cap(host, 1 << 30) == 1 << 30
+        assert bucketing.run_cap(leaves[:3] + host[3:], 1 << 30) == 1 << 30
+        plan = bucketing.build_plan(leaves, bucketing.run_cap(leaves, 1 << 30))
+        # whole leaves of one dtype, in leaf order; the large one by itself
+        assert plan.groups == [[0], [1], [2, 3], [4, 5], [7, 8], [6]]
+
+    @pytest.mark.parametrize("pg_op,divisor", [
+        (ReduceOp.SUM, None), (ReduceOp.SUM, 1), (ReduceOp.SUM, 4)],
+        ids=["sum", "avg_of_1", "avg_of_4"])
+    def test_runs_land_bit_for_bit_what_one_bucket_lands(
+        self, monkeypatch, pg_op, divisor
+    ):
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        leaves = _run_leaves()
+        whole = bucketing.build_plan(leaves, 1 << 30)
+        runs = bucketing.build_plan(leaves, bucketing.run_cap(leaves, 1 << 30))
+        assert (len(whole), len(runs)) == (2, 6)
+        pipeline = _pipeline()
+        try:
+            _, one = _through(pipeline, leaves, whole, pg_op, divisor)
+            _, many = _through(pipeline, leaves, runs, pg_op, divisor)
+        finally:
+            pipeline.shutdown(wait=True)
+        for leaf, a, b in zip(leaves, one, many):
+            assert isinstance(b, jax.Array) and b.dtype == leaf.dtype
+            assert b.shape == leaf.shape
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+            want = np.asarray(leaf)
+            if divisor:
+                want = (want / divisor).astype(want.dtype)
+            assert np.array_equal(np.asarray(b), want)
+
+    def test_a_run_lands_while_the_next_is_fetched_and_the_op_resolves_once(
+        self, monkeypatch
+    ):
+        """Run k's landing is handed to the unpack worker before run k+1's
+        fetch returns (both on the staging thread behind a PG that resolves
+        at dispatch), and the op's future resolves once, after the last
+        run has landed, with the leaves in leaf order."""
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        leaves = _run_leaves()
+        plan = bucketing.build_plan(leaves, bucketing.run_cap(leaves, 1 << 30))
+        log, lock = [], threading.Lock()
+
+        def note(*event):
+            with lock:
+                log.append(event)
+
+        real_stage = bucketing.stage
+
+        def stage(captured, plan, i, *args, **kwargs):
+            out = real_stage(captured, plan, i, *args, **kwargs)
+            note("fetched", i)
+            return out
+
+        monkeypatch.setattr(bucketing, "stage", stage)
+        pipeline = _pipeline(pg=ProcessGroupDummy())
+        real_submit = pipeline._unpack_executor.submit
+
+        def submit(fn, op, i, *args):
+            note("landing_submitted", i)
+            return real_submit(fn, op, i, *args)
+
+        pipeline._unpack_executor.submit = submit
+        real_place = bucketing.leaf_placer()
+
+        def place(orig, host):
+            note("placed", None)
+            return real_place(orig, host)
+
+        try:
+            pipeline.begin_step()
+            op = pipeline.allreduce_buckets(
+                leaves, plan, ReduceOp.SUM, participating=True, divisor=None,
+                place=place, timeout=30.0)
+            op.final.add_done_callback(
+                lambda f: note("resolved", len(f.value())))
+            out = op.final.wait(30)
+        finally:
+            pipeline.shutdown(wait=True)
+        n = len(plan)
+        for k in range(n - 1):
+            assert log.index(("landing_submitted", k)) < log.index(
+                ("fetched", k + 1)), log
+        assert [e for e in log if e[0] == "resolved"] == [("resolved", 9)]
+        # every leaf was placed before the one resolve
+        assert log.index(("resolved", 9)) > max(
+            j for j, e in enumerate(log) if e[0] == "placed")
+        assert sum(e[0] == "placed" for e in log) == 9
+        for leaf, got in zip(leaves, out):
+            assert got.shape == leaf.shape and got.dtype == leaf.dtype
+            assert np.array_equal(np.asarray(got), np.asarray(leaf))
+
+    def test_a_middle_run_that_fails_fails_the_op_and_hands_out_zeros(
+        self, monkeypatch
+    ):
+        """The third run's collective fails: the aggregate fails with it,
+        the Manager hands out the zeros of its error path and no leaf of
+        the runs that did land, and the step will not commit."""
+        from torchft_tpu.work import FutureWork, Future as TftFuture
+
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        monkeypatch.delenv("TORCHFT_BUCKET_CAP_MB", raising=False)
+
+        class FailsThird(CopyingPG):
+            def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
+                if len(self.inputs) == 2:
+                    self.inputs.extend(arrays)
+                    fut = TftFuture()
+                    fut.set_exception(ConnectionError("run 2 lost its peer"))
+                    return FutureWork(fut)
+                return super().allreduce(arrays, op, donate=donate)
+
+        leaves = _run_leaves()
+        tree = {f"l{i}": leaf for i, leaf in enumerate(leaves)}
+        pg = FailsThird()
+        m = make_manager(pg=pg, quorum=make_quorum())
+        try:
+            m.start_quorum()
+            out = m.allreduce(tree, reduce_op=ReduceOp.SUM).get_future().wait(30)
+            assert m.errored() is not None
+            assert len(pg.inputs) >= 3  # two runs went through before it
+            for i, leaf in enumerate(leaves):
+                got = out[f"l{i}"]
+                assert got.shape == leaf.shape and got.dtype == leaf.dtype
+                assert not np.asarray(got).any(), f"leaf {i} landed"
+        finally:
+            m.shutdown(wait=False)
+
+    @pytest.mark.parametrize("who", [
+        "numpy_tree", "device_native_pg", "compressed_wire", "one_leaf",
+        "callers_own_cap"])
+    def test_everything_else_gets_the_plan_it_got(self, monkeypatch, who):
+        """Only a host-plane op over device leaves is cut at a run: a numpy
+        tree, a device-native PG, the compressed wire, a caller's own cap
+        for the call (ddp.py) keep the plan of the cap, and a lone leaf has
+        none (``allreduce_leaves``)."""
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        monkeypatch.delenv("TORCHFT_BUCKET_CAP_MB", raising=False)
+        monkeypatch.delenv("TORCHFT_COMPRESS", raising=False)
+        caps = []
+        real = bucketing.plan_for
+
+        def plan_for(leaves, cap, treedef=None):
+            caps.append(cap)
+            return real(leaves, cap, treedef=treedef)
+
+        monkeypatch.setattr(bucketing, "plan_for", plan_for)
+        bf16 = _run_leaves()[:6]
+        tree = {f"l{i}": leaf for i, leaf in enumerate(bf16)}
+        kwargs, call = {}, {}
+        pg = CountingPG()
+        if who == "numpy_tree":
+            tree = {k: np.asarray(v, np.float32) for k, v in tree.items()}
+        elif who == "device_native_pg":
+            pg.device_native = True
+        elif who == "compressed_wire":
+            kwargs["compress"] = "fp8"
+        elif who == "one_leaf":
+            tree = {"l1": bf16[1]}
+        elif who == "callers_own_cap":
+            call["bucket_cap_bytes"] = 1 << 29
+        m = make_manager(pg=pg, quorum=make_quorum(), **kwargs)
+        try:
+            m.start_quorum()
+            m.allreduce_streamed(tree, reduce_op=ReduceOp.SUM, **call).wait()
+            timings = m.timings()
+        finally:
+            m.shutdown(wait=False)
+        if who == "one_leaf":
+            assert caps == [] and timings["allreduce_runs"] == 1.0
+            return
+        assert caps == [call.get("bucket_cap_bytes", 1 << 30)]
+        # 4,400 bytes of bf16 under a cap of a gigabyte: one bucket
+        assert pg.allreduce_calls == [1]
+        assert timings["allreduce_runs"] == 1.0
+
+    def test_a_device_tree_on_the_host_plane_is_cut_at_the_run(self, monkeypatch):
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        monkeypatch.delenv("TORCHFT_BUCKET_CAP_MB", raising=False)
+        leaves = _run_leaves()
+        tree = {f"l{i}": leaf for i, leaf in enumerate(leaves)}
+        pg = CopyingPG()
+        m = make_manager(pg=pg, quorum=make_quorum())
+        try:
+            for _ in range(2):
+                m.start_quorum()
+                out = m.allreduce(tree, reduce_op=ReduceOp.SUM).get_future().wait(30)
+                m.should_commit()
+            timings = m.timings()
+        finally:
+            m.shutdown(wait=False)
+        assert [a.nbytes for a in pg.inputs] == [600, 2400, 800, 800, 800, 200] * 2
+        assert timings["allreduce_ops"] == 1.0
+        assert timings["allreduce_runs"] == 6.0
+        assert timings["stage_pool_hit_share"] == 1.0
+        for i, leaf in enumerate(leaves):
+            assert np.array_equal(np.asarray(out[f"l{i}"]), np.asarray(leaf))
+
+    def test_land_under_fetch_share(self, monkeypatch):
+        """0.0 for an op of one run; in (0, 1] for an op of several whose
+        fetches and whose placer take their time, so that a run's landing
+        is still placing leaves while the next run's pieces arrive."""
+        monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+        monkeypatch.setattr(bucketing, "FETCH_PIECE_BYTES", 200)
+        leaves = _run_leaves()
+        real_capture = bucketing.capture
+
+        def capture(leaves, plan, pool):
+            captured = real_capture(leaves, plan, pool)
+            for cap in captured:
+                cap.arrays[:] = [
+                    _HostPiece(np.array(a), 0.004) for a in cap.arrays]
+            return captured
+
+        monkeypatch.setattr(bucketing, "capture", capture)
+        real_place = bucketing.leaf_placer()
+
+        def place(orig, host):
+            time.sleep(0.01)
+            return real_place(orig, host)
+
+        stats = {}
+        pipeline = _pipeline(stats=stats)
+        try:
+            whole = bucketing.build_plan(leaves[2:4], 1 << 30)
+            _through(pipeline, leaves[2:4], whole, place=place)
+            assert stats["allreduce_runs"] == 1.0
+            assert stats["land_under_fetch_share"] == 0.0
+            runs = bucketing.build_plan(
+                leaves, bucketing.run_cap(leaves, 1 << 30))
+            _through(pipeline, leaves, runs, place=place)
+        finally:
+            pipeline.shutdown(wait=True)
+        assert stats["allreduce_runs"] == 6.0
+        assert 0.0 < stats["land_under_fetch_share"] <= 1.0
+
+    def test_a_step_that_draws_many_equal_runs_finds_them_all_again(self):
+        """Six equal runs drawn before any comes back: the pool keeps six
+        free of that key from then on, where its own bound is four."""
+        pool = bucketing.BufferPool()
+        for n in range(1, 7):
+            pool.keep_at_least(100, np.float32, n)
+        bufs = [pool.acquire(100, np.float32) for _ in range(6)]
+        others = [pool.acquire(50, np.float32) for _ in range(6)]
+        for b in bufs + others:
+            pool.release(b)
+        assert len(pool._free[(np.dtype(np.float32).str, 100)]) == 6
+        assert len(pool._free[(np.dtype(np.float32).str, 50)]) == 4
+
+
+def test_two_groups_on_the_host_ring_cut_the_same_runs(
+    world_of_two, monkeypatch
+):
+    """A world of two on the real host ring: both groups cut the same runs
+    from the same tree and end with the same bits, the sum of the two."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    monkeypatch.setattr(bucketing, "RUN_BYTES", _RUN)
+    monkeypatch.delenv("TORCHFT_BUCKET_CAP_MB", raising=False)
+    ms = [world_of_two(rank) for rank in range(2)]  # a cap of 160,000
+
+    def tree(rank):
+        return {f"l{i}": leaf * (rank + 1)
+                for i, leaf in enumerate(_run_leaves())}
+
+    def run(rank):
+        m = ms[rank]
+        m.start_quorum()
+        out = m.allreduce(tree(rank), reduce_op=ReduceOp.SUM).get_future().wait(30)
+        m.should_commit()
+        return out, m.timings()["allreduce_runs"]
+
+    try:
+        with ThreadPoolExecutor(2) as ex:
+            (a, runs_a), (b, runs_b) = ex.map(run, range(2))
+    finally:
+        for m in ms:
+            m.shutdown(wait=False)
+    assert runs_a == runs_b == 6.0
+    t0, t1 = tree(0), tree(1)
+    for k in a:
+        assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
+        assert np.array_equal(np.asarray(a[k]), np.asarray(t0[k] + t1[k])), k

@@ -76,6 +76,8 @@ from torchft_tpu.work import Future, join_futures
 __all__ = [
     "DEFAULT_BUCKET_CAP_BYTES",
     "SEGMENT_FLOOR_BYTES",
+    "RUN_BYTES",
+    "run_cap",
     "BucketPlan",
     "BufferPool",
     "build_plan",
@@ -105,10 +107,34 @@ DEFAULT_BUCKET_CAP_BYTES = 1 << 30
 # The least gradient bytes a trainer hands ONE allreduce of a step's several
 # (models/staged.py: a segment of whole layers an op). Each op pays a fixed
 # toll beside its fetch: the capture's dispatch 3-10 ms, the landing's
-# enqueue 9-18 ms a bucket (PERF.md section 5). At the 6-9 GB/s a fetch runs
-# at, 128 MiB is 15-22 ms of fetch: under it the tolls are the op. A constant
-# and no option: a segment is whole layers, so most are several times this.
+# enqueue 9-18 ms a bucket (PERF.md section 5). At the 10-11 GB/s a fetch
+# runs at on four fetchers (6-9 when this was set, on one: PR 36), 128 MiB
+# is 12-13 ms of fetch: under it the tolls are the op. A constant and no
+# option: a segment is whole layers, so most are several times this.
 SEGMENT_FLOOR_BYTES = 1 << 27
+
+# The most bytes of a host-plane op over device leaves that move as ONE run
+# (run_cap): the op's plan is cut at this, and run k rides the wire and lands
+# (H2D and the division, unpack worker) while run k+1 is fetched (staging
+# thread and fetchers), where one bucket of 0.63-0.70 GB was fetched whole
+# (60-70 ms) and then landed whole (55-75 ms) with nothing to hide the step's
+# last op under. Reasoned like the floor above, against a run's tolls: one
+# capture dispatch (1-2 ms on the caller's thread), one collective, one
+# landing dispatch on the device's queue (_average_on_device), against
+# 12-13 ms of fetch at 128 MiB. What cannot hide is the first run's fetch
+# and the last run's landing, so smaller is better until the tolls are the
+# run. TPU v5e, one traced run each, tokens/s/chip against one bucket an op
+# (PERF.md section 6, PR 50): 128 MiB +7.3% / +4.0% / +10.8% (Mistral-7B at
+# four layers, InternLM2-1.8B at two, OLMoE-1B-7B at two), 256 MiB +6.0% /
+# +1.4% / +10.1%; under the per-leaf landing that stood before, 64 MiB read
+# -3.7% on Mistral (34 runs a step, a capture of 0.135 s) where 128 read
+# -0.6%. At a world of four a ring segment is a quarter of a run and rides
+# process_group._RING_LANES only from _RING_LANE_FLOOR_BYTES = 32 MiB: a
+# full run of 128 MiB is the least that keeps them, and the runs a greedy
+# cut leaves short of that ride one lane (the four-group cell read the same
+# rate at 128, at 256 and uncut: PERF.md section 7, PR 50). A constant and
+# no option: TORCHFT_BUCKET_CAP_MB under it still means "at most this".
+RUN_BYTES = 1 << 27
 
 # metas entry: (leaf_index, offset_elems, size_elems, shape)
 Meta = Tuple[int, int, int, Tuple[int, ...]]
@@ -154,6 +180,26 @@ def _leaf_shape(leaf: Any) -> Tuple[int, ...]:
     if shape is not None:
         return tuple(shape)
     return tuple(np.shape(leaf))
+
+
+def run_cap(leaves: Sequence[Any], cap_bytes: int) -> int:
+    """The cap at which the plan of a HOST-PLANE op over ``leaves`` is cut:
+    ``RUN_BYTES`` where every leaf lives on a device and together they are
+    more than one run, never above ``cap_bytes`` (whoever set a cap still
+    gets "at most this"); ``cap_bytes`` as it is for anything else (a host
+    tree is packed at the capture and has no fetch to land under). The
+    plan's buckets are then the op's runs, and the pipeline's three threads
+    do the rest: :class:`BucketPipeline`."""
+    import jax
+
+    if cap_bytes <= RUN_BYTES or not leaves:
+        return cap_bytes
+    nbytes = 0
+    for leaf in leaves:
+        if not isinstance(leaf, jax.Array):
+            return cap_bytes
+        nbytes += leaf.size * leaf.dtype.itemsize
+    return RUN_BYTES if nbytes > RUN_BYTES else cap_bytes
 
 
 class BucketPlan:
@@ -278,8 +324,21 @@ class BufferPool:
         self._lock = threading.Lock()
         self._free: Dict[Tuple[str, int], List[np.ndarray]] = {}
         self._max_per_key = max_per_key
+        # keys of which a caller draws more at a time (keep_at_least)
+        self._room: Dict[Tuple[str, int], int] = {}
         self.hits = 0
         self.misses = 0
+
+    def keep_at_least(self, size: int, dtype: Any, n: int) -> None:
+        """Retain up to ``n`` free buffers of this (dtype, size) where that
+        is more than the pool's own bound: whoever draws ``n`` of one key
+        before giving any back (a step's equal runs, parked while their
+        landings read them) finds them again at its next round."""
+        if n > self._max_per_key:
+            key = (np.dtype(dtype).str, int(size))
+            with self._lock:
+                if n > self._room.get(key, 0):
+                    self._room[key] = n
 
     def acquire(self, size: int, dtype: Any) -> np.ndarray:
         return self.acquire_hit(size, dtype)[0]
@@ -303,7 +362,7 @@ class BufferPool:
         key = (buf.dtype.str, buf.shape[0])
         with self._lock:
             bucket = self._free.setdefault(key, [])
-            if len(bucket) < self._max_per_key:
+            if len(bucket) < self._room.get(key, self._max_per_key):
                 bucket.append(buf)
 
 
@@ -748,21 +807,29 @@ def _payload_nbytes(payload: Any) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _average_on_device() -> Callable[[Any, Any], Any]:
-    """The AVG normalisation of one landed leaf as a jitted computation.
+    """The AVG normalisation of the landed device leaves of ONE bucket as a
+    jitted computation: ``(leaves, n) -> (quotients, token)``.
 
-    It donates its input, so the quotient reuses the buffer the H2D just
+    It donates its inputs, so each quotient reuses the buffer the H2D just
     filled and the step's HBM peak does not grow by a leaf; the divisor is a
     float32 runtime scalar, so a quorum that goes 4 -> 3 -> 4 compiles
-    nothing new (one executable per leaf geometry). Bit for bit numpy's
-    result, except that XLA flushes a subnormal input or quotient (under
-    2**-126) to zero where numpy keeps it. Built on first use: a process
-    that only moves host arrays never gets here."""
+    nothing new (one executable per bucket geometry). Bit for bit numpy's
+    result a leaf, except that XLA flushes a subnormal input or quotient
+    (under 2**-126) to zero where numpy keeps it. ONE dispatch a bucket and
+    not one a leaf: the runtime holds some thirty programs in flight, a
+    landing's wait behind the backward pass that is running, and a dispatch
+    past that blocks the unpack thread until the pass ends (TPU v5e, four
+    Mistral layers: the third op's ``divide`` stood 84 ms, the next op's
+    landing behind it; PERF.md section 6, PR 50). ``token`` is a scalar of
+    the program's own, ready when the leaves were read: what
+    :func:`_landed_token` would say of them, without a dispatch more. Built
+    on first use: a process that only moves host arrays never gets here."""
     import jax
 
-    def average_landed_leaf(x: Any, n: Any) -> Any:  # its name in a trace
-        return (x / n).astype(x.dtype)
+    def average_landed_leaves(xs: Any, n: Any) -> Any:  # its name in a trace
+        return [(x / n).astype(x.dtype) for x in xs], np.int32(0)
 
-    return jax.jit(average_landed_leaf, donate_argnums=0)
+    return jax.jit(average_landed_leaves, donate_argnums=0)
 
 
 def _average(x: Any, num_participants: int, landed: bool = False) -> Any:
@@ -775,8 +842,31 @@ def _average(x: Any, num_participants: int, landed: bool = False) -> Any:
     import jax
 
     if landed and isinstance(x, jax.Array):
-        return _average_on_device()(x, np.float32(num_participants))
+        return _average_landed([x], num_participants)[0][0]
     return (x / num_participants).astype(x.dtype)
+
+
+def _average_landed(
+    values: Sequence[Any], num_participants: int
+) -> Tuple[List[Any], Optional[Any]]:
+    """:func:`_average` of a bucket's landed leaves, each where it is: the
+    device leaves together in one donating dispatch
+    (:func:`_average_on_device`), a numpy leaf in numpy. Returns the
+    quotients in order and the dispatch's token (None: no device leaf)."""
+    import jax
+
+    out = [
+        v if isinstance(v, jax.Array) else _average(v, num_participants)
+        for v in values
+    ]
+    on_device = [k for k, v in enumerate(out) if isinstance(v, jax.Array)]
+    if not on_device:
+        return out, None
+    quotients, token = _average_on_device()(
+        [out[k] for k in on_device], np.float32(num_participants))
+    for k, q in zip(on_device, quotients):
+        out[k] = q
+    return out, token
 
 
 def leaf_placer() -> Callable[[Any, Any], Any]:
@@ -837,6 +927,7 @@ def land_reduced(
     divisor: Optional[int],
     place: Callable[[Any, Any], Any],
     span: Callable[..., Any] = _no_span,
+    tokens: Optional[List[Any]] = None,
 ) -> List[Tuple[int, Any]]:
     """One reduced array — the plan's bucket ``bucket``, or with no plan the
     lone leaf of that index — to ``(leaf_index, leaf)`` pairs: sliced
@@ -848,7 +939,8 @@ def land_reduced(
     and every bucket of the pipeline land through this one function, so
     they stay bit-identical on every backend. ``span(name, **args)``: the
     pipeline's allreduce/h2d and allreduce/divide; the no-plan path records
-    none."""
+    none. ``tokens``: a list that receives the division's token where device
+    leaves were divided (:func:`_average_landed`): ready once they are."""
     import jax
 
     idxs = [bucket] if plan is None else plan.groups[bucket]
@@ -874,39 +966,48 @@ def land_reduced(
             else "mixed" if on_device else "host"
         )
         with span("divide", where=where, **sized):
-            pairs = [(i, _average(v, divisor, landed=True)) for i, v in pairs]
+            quotients, token = _average_landed([v for _, v in pairs], divisor)
+            pairs = [(i, q) for (i, _), q in zip(pairs, quotients)]
+        if token is not None and tokens is not None:
+            tokens.append(token)
     return pairs
 
 
 @functools.lru_cache(maxsize=None)
 def _landed_token() -> Callable[[Any], Any]:
-    """``token(leaf)``: a device scalar that is ready once ``leaf`` is,
+    """``token(leaves)``: a device scalar that is ready once ``leaves`` (a
+    leaf, or a bucket's list of them: one dispatch) are,
     dispatched and never waited for. It is the pipeline's own, so it can
-    still be asked after the caller has donated ``leaf`` to its next step (a
-    deleted array says nothing of whether the transfer that filled it is
+    still be asked after the caller has donated the leaves to its next step
+    (a deleted array says nothing of whether the transfer that filled it is
     over). ``keep_unused``: the runtime starts a program once every argument
     it was handed is defined, read or not."""
     import jax
 
-    def landed_token(leaf: Any) -> Any:  # its name in a trace
+    def landed_token(leaves: Any) -> Any:  # its name in a trace
         return np.int32(0)
 
     return jax.jit(landed_token, keep_unused=True)
 
 
-def readers_of(buf: np.ndarray, leaves: Sequence[Any]) -> Optional[List[Any]]:
+def readers_of(
+    buf: np.ndarray, leaves: Sequence[Any],
+    tokens: Optional[List[Any]] = None,
+) -> Optional[List[Any]]:
     """What still reads the staging buffer ``buf`` after ``leaves`` landed
-    from it (the collective handed ``buf`` back as its own result): one
-    token (``is_ready()``) a device leaf, whose transfer out of ``buf`` may
-    be in flight; nothing for a numpy leaf in memory of its own (an AVG's
-    quotient). None where a leaf IS a slice of ``buf`` (a numpy leaf under
-    SUM; a CPU backend's ``device_put`` of aligned memory, which copies
-    nothing, with no divide after it): that buffer is the caller's now."""
+    from it (the collective handed ``buf`` back as its own result): a token
+    (``is_ready()``) for the device leaves, whose transfers out of ``buf``
+    may be in flight: ``tokens`` where the landing's division left one
+    (:func:`land_reduced`), else one dispatch for all of them; nothing for
+    numpy leaves in memory of their own (an AVG's quotient). None where a
+    leaf IS a slice of ``buf`` (a numpy leaf under SUM; a CPU backend's
+    ``device_put`` of aligned memory, which copies nothing, with no divide
+    after it): that buffer is the caller's now."""
     import jax
 
     lo = buf.ctypes.data
     hi = lo + buf.nbytes
-    tokens = []
+    on_device = []
     for leaf in leaves:
         if isinstance(leaf, jax.Array):
             if any(
@@ -915,10 +1016,12 @@ def readers_of(buf: np.ndarray, leaves: Sequence[Any]) -> Optional[List[Any]]:
                 for s in leaf.addressable_shards
             ):
                 return None
-            tokens.append(_landed_token()(leaf))
+            on_device.append(leaf)
         elif not isinstance(leaf, np.ndarray) or np.shares_memory(leaf, buf):
             return None
-    return tokens
+    if not on_device:
+        return []
+    return list(tokens) if tokens else [_landed_token()(on_device)]
 
 
 # ---------------------------------------------------------------------------
@@ -1020,6 +1123,9 @@ class _StepTally:
 
     def __init__(self) -> None:
         self.ops = 0  # allreduces begun (timings()["allreduce_ops"])
+        # the runs they were cut into (timings()["allreduce_runs"]): a
+        # plan's buckets, one for an op without a plan
+        self.runs = 0
         # of the ops resolved so far (record_timings, under the lock): the
         # stage sums, the wire seconds hidden behind an op's other buckets,
         # device buckets and those that came back as their staging buffer
@@ -1028,6 +1134,9 @@ class _StepTally:
         self.hidden_s = 0.0
         self.from_device = self.passed_through = 0
         self.ring_lanes = 0  # of the newest ring among them; 0: none ran
+        # seconds inside the landings' h2d and divide spans, and the part
+        # of them before their own op's last fetch had ended
+        self.land_s = self.land_under_fetch_s = 0.0
         # the newest device capture of the step (the caller's thread sets
         # it): while ITS pieces are not ready, gradients are being computed
         self.newest: Optional[Pieces] = None
@@ -1035,6 +1144,9 @@ class _StepTally:
         # a recycled buffer, each fetch's (start, end), their bytes, and the
         # seconds its fetchers spent inside a piece
         self.acquired = self.hits = 0
+        # how many buffers of one (dtype, size) the step has drawn so far:
+        # what the pool keeps free of that key (BufferPool.keep_at_least)
+        self.drawn: Dict[Tuple[str, int], int] = {}
         self.d2h: List[Tuple[float, float]] = []
         self.fetched_bytes = 0
         self.fetch_busy_s = 0.0
@@ -1122,6 +1234,11 @@ class _BucketOp:
         # (wire_passthrough_share)
         self.from_device = [False] * n
         self.passed_through = [False] * n
+        # when the op's last device bucket was on the host (the staging
+        # thread's), and the (start, end) of every h2d and divide span of
+        # its landings (the unpack thread's): land_under_fetch_share
+        self.fetched_at: Optional[float] = None
+        self.landings: List[Tuple[float, float]] = []
         self.bucket_futs: List[Future] = [Future() for _ in range(n)]
         self.final: Future = Future()
         join_futures(self.bucket_futs).then(self._assemble).add_done_callback(
@@ -1147,7 +1264,12 @@ class BucketPipeline:
     copies and never dispatches), wire (the PG's
     dispatch thread, or XLA), unpack (:func:`land_reduced` on the one unpack
     thread). Bucket i+1 packs while bucket i rides the wire and bucket i−1
-    unpacks; no stage ever waits for the LAST bucket's wire. A tree without
+    unpacks; no stage ever waits for the LAST bucket's wire. The plan of a
+    host-plane op over device leaves is cut at ``RUN_BYTES``
+    (:func:`run_cap`, the Manager's choice), so that such an op is several
+    buckets, its RUNS, and a run lands while the next is fetched where one
+    bucket an op was fetched whole and then landed whole
+    (``land_under_fetch_share``, ``allreduce_runs``). A tree without
     one (a single leaf, a cap of 0, the monolithic quantized exchange) takes
     :meth:`allreduce_leaves`: one collective carrying every leaf, landed by
     the same function. Numerics are bit-identical between the two:
@@ -1167,10 +1289,12 @@ class BucketPipeline:
 
     ``on_timings(stats)`` receives what ``Manager.timings()`` shows of the
     pipeline, each value over the ops of the step so far
-    (:meth:`begin_step`): ``allreduce_ops``; from the staging thread
+    (:meth:`begin_step`): ``allreduce_ops`` and ``allreduce_runs``; from
+    the staging thread
     ``stage_pool_hit_share``, ``d2h_under_backward_share``,
     ``d2h_concurrency`` and ``d2h_gb_s``; the stage
-    sums and ``wire_passthrough_share`` from :meth:`record_timings`."""
+    sums, ``wire_passthrough_share`` and ``land_under_fetch_share`` from
+    :meth:`record_timings`."""
 
     def __init__(
         self,
@@ -1241,6 +1365,13 @@ class BucketPipeline:
         tally.ops += 1
         self._on_timings({"allreduce_ops": float(tally.ops)})
         return tally.ops - 1
+
+    def _count_runs(self, n: int) -> None:
+        """``n`` more runs begun this step (``allreduce_runs``): an op's
+        buckets, each through fetch, wire and landing by itself."""
+        tally = self._tally
+        tally.runs += n
+        self._on_timings({"allreduce_runs": float(tally.runs)})
 
     # ------------------------------------------------------------ schedule
     def submit(
@@ -1354,6 +1485,7 @@ class BucketPipeline:
             leaves, plan, divisor, place, parent, self._tracer.new_id,
             self._tally, segment,
         )
+        self._count_runs(len(plan))
         if self.device_native:
             self._issue_on_device(op, pg_op, participating)
             return op
@@ -1482,6 +1614,13 @@ class BucketPipeline:
                     # what earlier landings have finished with goes back
                     # to the pool before this bucket draws from it
                     self._sweep_parked()
+                    if isinstance(captured[i], Pieces):
+                        # a step that draws several equal runs finds as
+                        # many free buffers at its next round
+                        key = (plan.dtypes[i].str, plan.sizes[i])
+                        tally.drawn[key] = tally.drawn.get(key, 0) + 1
+                        self._pool.keep_at_least(
+                            plan.sizes[i], plan.dtypes[i], tally.drawn[key])
                     # bucket 0 carries how long the staging worker took to
                     # get to this op
                     with tracer.span(
@@ -1500,7 +1639,8 @@ class BucketPipeline:
                         op.from_device[i] = True
                         tally.acquired += 1
                         tally.hits += info["pooled"]
-                        tally.d2h.append((t0d, time.perf_counter()))
+                        op.fetched_at = time.perf_counter()
+                        tally.d2h.append((t0d, op.fetched_at))
                         tally.fetched_bytes += info["bytes"]
                         tally.fetch_busy_s += info["busy_us"] / 1e6
                 payload: Any = host_flat
@@ -1592,16 +1732,21 @@ class BucketPipeline:
                 (t0u - op.marks[i]["wire"][1]) * 1e6
             )} if "wire" in op.marks[i] else {}
 
+            @contextlib.contextmanager
             def span(name: str, **args: Any) -> Any:
                 args.update(first)
                 first.clear()
                 if name == "h2d":
                     args["passed_through"] = int(passed_through)
-                return self._tracer.span(
+                t0 = time.perf_counter()
+                with self._tracer.span(
                     name, cat="allreduce",
                     parent=op.stage_ids[i]["unpack"], bucket=i,
                     segment=op.segment, **args,
-                )
+                ) as sp:
+                    yield sp
+                if name in ("h2d", "divide"):
+                    op.landings.append((t0, time.perf_counter()))
 
             if is_compressed_wire(flat):
                 # the bucket rode the wire compressed; the codes carry the
@@ -1610,8 +1755,10 @@ class BucketPipeline:
                 # expressions
                 with span("decode", bytes=_payload_nbytes(flat)):
                     flat = decompress_bucket(flat)
+            divided: List[Any] = []  # the division's token, if it ran
             pairs = land_reduced(
-                flat, op.leaves, op.plan, i, op.divisor, op.place, span
+                flat, op.leaves, op.plan, i, op.divisor, op.place, span,
+                divided,
             )
             if pooled_buf is not None and not op.final.done():
                 # recycle this bucket's staging buffer: on success only (an
@@ -1622,19 +1769,20 @@ class BucketPipeline:
                 if passed_through:
                     with span("recycle", leaves=len(pairs)):
                         tokens = self._recycle(
-                            pooled_buf, [v for _, v in pairs])
+                            pooled_buf, [v for _, v in pairs], divided)
                 else:
                     self._pool.release(pooled_buf)
             if self._tracer.enabled and not self.device_native:
                 # the device's milestone of this bucket (an instant: a link
                 # of the watcher's chain): its leaves, divided, are in HBM.
-                # Tokens are dispatched in leaf order, so the last answers
-                # for the bucket; where recycling made none, one of this
-                # line's own on the last landed device leaf (the caller's
-                # update donates the leaf itself)
-                token = tokens[-1] if tokens else next(
-                    (_landed_token()(v) for _, v in reversed(pairs)
-                     if lives_on_device(v)), None)
+                # The token that recycling parked the buffer on, or the
+                # division's; where there is neither (a SUM whose result
+                # was a copy), one of this line's own on the landed device
+                # leaves (the caller's update donates the leaves themselves)
+                on_device = [v for _, v in pairs if lives_on_device(v)]
+                token = (tokens or divided or [None])[-1]
+                if token is None and on_device:
+                    token = _landed_token()(on_device)
                 if token is not None:
                     self._tracer.when_ready(
                         "landed", "device", token, span=False,
@@ -1647,7 +1795,8 @@ class BucketPipeline:
             _settle(op.bucket_futs[i], exc=e)
 
     def _recycle(
-        self, buf: np.ndarray, landed: Sequence[Any]
+        self, buf: np.ndarray, landed: Sequence[Any],
+        divided: Optional[List[Any]] = None,
     ) -> Optional[List[Any]]:
         """``buf``, which its collective handed back as the result, has
         landed as ``landed``: into the pool now if nothing reads it any
@@ -1655,7 +1804,7 @@ class BucketPipeline:
         it returns), dropped if a landed leaf is a slice of it. Before the
         bucket's future settles, while the leaves are still the pipeline's
         alone; it dispatches and does not wait."""
-        tokens = readers_of(buf, landed)
+        tokens = readers_of(buf, landed, divided)
         if tokens is None:
             return None
         if not tokens:
@@ -1699,10 +1848,21 @@ class BucketPipeline:
             tally.from_device += sum(op.from_device)
             tally.passed_through += sum(
                 p for p, d in zip(op.passed_through, op.from_device) if d)
+            # of the landings' seconds, those before the op's own last
+            # fetch had ended: run k landing while run k+1 is fetched (an
+            # op of one run, or of host leaves: none)
+            fetched = op.fetched_at
+            tally.land_s += sum(t1 - t0 for t0, t1 in op.landings)
+            if fetched is not None:
+                tally.land_under_fetch_s += sum(
+                    max(0.0, min(t1, fetched) - t0) for t0, t1 in op.landings)
             stats = dict(tally.stage_sums)
             wire_s = stats["allreduce_wire_s"]
             stats["overlap_efficiency"] = (
                 tally.hidden_s / wire_s if wire_s > 0 else 0.0)
+            stats["land_under_fetch_share"] = (
+                tally.land_under_fetch_s / tally.land_s
+                if tally.land_s > 0 else 0.0)
             if tally.from_device:
                 # of the device buckets, those whose collective resolved to
                 # the staging buffer it was given: nothing was copied on
@@ -1816,6 +1976,7 @@ class BucketPipeline:
         and packing first would shift the fp8 rowwise-scale boundaries).
         Resolves to the landed leaves, in leaf order, each through
         :func:`land_reduced` as a bucket of the pipeline is."""
+        self._count_runs(1)
         if self.device_native:
             fut = self._issue_leaves_on_device(
                 leaves, pg_op, quantize, participating

@@ -79,7 +79,9 @@ REGISTRY: Dict[str, Knob] = {
            "Hostname the XLA store/transport advertises (multi-host fleets)."),
         # --------------------------------------------------- data plane
         _k("TORCHFT_BUCKET_CAP_MB", "float", "1024", "performance.md#bucketing", "tuning-env",
-           "Allreduce flat-bucket cap in MB; 0 disables bucketing."),
+           "Allreduce flat-bucket cap in MB (at most this a bucket); 0 disables "
+           "bucketing. A host-plane op over device leaves is cut at "
+           "bucketing.RUN_BYTES (128 MiB) where the cap is larger."),
         _k("TORCHFT_COMPRESS", "enum(off|fp8|int8)", "off",
            "performance.md#compressed-collectives", "compress-env",
            "Wire codec for streamed buckets, with per-bucket error feedback."),

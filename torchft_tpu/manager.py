@@ -1771,6 +1771,25 @@ class Manager:
             else int(bucket_cap_bytes)
         )
         device_native = pipeline.device_native
+        # wire compression: TORCHFT_COMPRESS / compress= knob, plus
+        # should_quantize callers who land on the host plane defaulting
+        # to fp8
+        compress = self._compress
+        if should_quantize and compress == "off":
+            compress = "fp8"
+        if (
+            bucket_cap_bytes is None
+            and not device_native
+            and compress == "off"
+        ):
+            # a host-plane op over device leaves moves in RUNS of whole
+            # leaves, so that a run lands while the next is fetched: its
+            # plan is cut at bucketing.RUN_BYTES (never above the cap).
+            # Every group sees the same tree and cuts the same runs. A
+            # caller's own cap for the call (ddp.py), the compressed wire
+            # (its residuals are a bucket's), a device-native PG and a
+            # host tree keep the plan of the cap alone
+            cap = bucketing.run_cap(leaves, cap)
         plan: Optional[bucketing.BucketPlan] = None
         if (
             not (should_quantize and device_native)
@@ -1838,12 +1857,6 @@ class Manager:
         try:
             op = None
             if plan is not None:
-                # wire compression: TORCHFT_COMPRESS / compress= knob, plus
-                # should_quantize callers who land here (host plane)
-                # defaulting to fp8
-                compress = self._compress
-                if should_quantize and compress == "off":
-                    compress = "fp8"
                 op = pipeline.allreduce_buckets(
                     leaves, plan, pg_reduce_op,
                     participating=self.is_participating(),
@@ -2125,6 +2138,13 @@ class Manager:
         ``allreduce_unpack_s`` / ``allreduce_buckets`` /
         ``overlap_efficiency`` (see
         ``bucketing.BucketPipeline.record_timings``) and, on the host plane,
+        ``allreduce_runs``: the runs the step's ops were cut into (an op
+        over device leaves of more than ``bucketing.RUN_BYTES`` goes as
+        several: each is a bucket of its plan, fetched, reduced and landed
+        by itself), ``land_under_fetch_share``: of the seconds the step's
+        landings spent in their ``h2d`` and ``divide`` spans, the part
+        before the same op's last fetch had ended (0.0 where every op is
+        one run: a landing then follows the whole fetch);
         ``stage_pool_hit_share``: of the device buckets the step's
         allreduces fetched, the share that went into a recycled buffer of
         the pool (pages mapped) rather than a new allocation; 1.0 from a

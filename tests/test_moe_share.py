@@ -1,0 +1,141 @@
+"""A share's grouped products visit the held pairs only (PR 51): the block
+against the parent's padded sizes at every load, on the interpreted kernels,
+whose NaN in every row they do not write is the test of the selects; and the
+counter that says what part of the buffer the products visit."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_ling import _program
+from torchft_tpu.models import CONFIGS, moe
+
+
+def _parents_share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
+    """``moe._share_ffn`` as it stood before PR 51: every free row of the
+    buffer is the LAST held expert's, a row of zeros that the grouped products
+    multiply, and the rows are weighed without a select."""
+    (T, d), k = flat.shape, idx.shape[1]
+    first, held = cfg.held_experts
+    rows_n = cfg.share_rows(T)
+    local = idx.reshape(T * k) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    counts = moe._counts(local[:, None], held)
+    pairs = jnp.sum(counts)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n]
+    valid = (jnp.arange(rows_n) < pairs)[:, None]
+    ends = jnp.minimum(jnp.cumsum(counts), rows_n).at[-1].set(rows_n)
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    weights = jnp.where(valid, gates.reshape(T * k, 1)[order], 0.0)
+    rows = jnp.where(valid, flat[order // k], 0)
+    h = jax.nn.silu(moe._grouped_matmul(rows, w_gate, sizes)) * moe._grouped_matmul(
+        rows, w_up, sizes)
+    rows = moe._grouped_matmul(h, w_down, sizes)
+    out = jnp.zeros((T, d), flat.dtype).at[order // k].add(rows * weights.astype(flat.dtype))
+    return out, {"counts": counts, "held_pairs": pairs,
+                 "overflow": jnp.maximum(pairs - rows_n, 0)}
+
+
+# 2,048 pairs, 4 of 16 experts held, a room of 3: a buffer of 1,536 rows, three
+# row tiles of 512 (``_grouped_matmul``'s): held pairs -> row tiles visited
+SHARE_LOADS = {"none": (0, 0), "far_under": (200, 1), "across_a_tile": (700, 2),
+               "the_room": (1536, 3), "overflow": (1800, 3)}
+
+
+def _share_case(held_pairs, dtype):
+    cfg = moe.MoEConfig(num_experts=16, top_k=4, capacity_factor=None, held_experts=(4, 4),
+                        share_room=3.0, dtype=dtype)
+    T, k, d, W = 512, 4, 32, 16
+    assert cfg.share_rows(T) == 1536
+    rng = np.random.default_rng(held_pairs)
+    absent = np.setdiff1d(np.arange(16), np.arange(4, 8))
+    expert = np.concatenate([rng.integers(4, 8, held_pairs),
+                             rng.choice(absent, T * k - held_pairs)])
+    idx = jnp.asarray(rng.permutation(expert).reshape(T, k), jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(held_pairs), 6)
+    n = lambda key, *shape: (jax.random.normal(key, shape) / np.sqrt(shape[-2])).astype(dtype)  # noqa: E731
+    args = (jax.random.normal(ks[0], (T, d)).astype(dtype), jax.random.uniform(ks[1], (T, k)),
+            n(ks[2], 4, d, W), n(ks[3], 4, d, W), n(ks[4], 4, W, d))
+    return cfg, idx, args, jax.random.normal(ks[5], (T, d)).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("load", sorted(SHARE_LOADS))
+def test_a_shares_products_visit_the_held_pairs_only_and_nothing_else_moves(load, dtype):
+    """The sizes a share hands its grouped products are the held pairs' own
+    (PR 51): the buffer's free rows belong to no group, the kernels' grid
+    ends with the last pair, and the rows past it hold what the kernel found
+    (the interpreter poisons them with NaN). The block's output, its stats
+    and all five gradients (tokens, gates, the three expert stacks) are what
+    the parent's padded sizes gave, bit for bit, and finite, at every load:
+    no held pair, far under the room, a tile's border crossed, the room
+    exactly, overflow. Finite is the test of the selects: a product of a
+    poisoned row with a zero weight or a mask is NaN."""
+    held_pairs, tiles = SHARE_LOADS[load]
+    cfg, idx, args, cot = _share_case(held_pairs, dtype)
+
+    def run(ffn):
+        def value(flat, gates, *stacks):
+            out, stats = jax.checkpoint(lambda *a: ffn(a[0], a[1], idx, cfg, *a[2:]))(
+                flat, gates, *stacks)
+            return jnp.sum((out * cot).astype(jnp.float32)), (out, stats)
+        return jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+    ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
+        run(moe._share_ffn), run(_parents_share_ffn))
+    for got, want in zip((out, *grads), (was_out, *was_grads)):
+        assert got.dtype == want.dtype and bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for key, want in was_stats.items():
+        np.testing.assert_array_equal(stats[key], want)
+    rows_n = cfg.share_rows(512)
+    assert int(stats["held_pairs"]) == held_pairs
+    assert int(stats["overflow"]) == max(held_pairs - rows_n, 0)
+    assert float(stats["visited"]) == pytest.approx(min(held_pairs, rows_n) / rows_n, rel=1e-6)
+    # what the kernels are told: sizes that end with the pairs, so `tiles` row
+    # tiles of the three are visited (megablox: ``num_active_tiles``)
+    sizes = moe._share_sizes(stats["counts"], rows_n)
+    assert int(sizes.sum()) == min(held_pairs, rows_n)
+    if held_pairs <= rows_n:
+        np.testing.assert_array_equal(sizes, stats["counts"])
+    assert -(-int(sizes.sum()) // 512) == tiles
+
+
+def test_the_interpreter_poisons_what_a_grouped_product_does_not_visit():
+    """What gives the finiteness above its teeth: under the interpreter a row
+    tile past the last group comes back as NaN (a chip leaves the bits it
+    found). Should that change, the test of the selects tests nothing."""
+    rows = jnp.ones((1024, 32), jnp.float32)
+    out = moe._grouped_matmul(rows, jnp.ones((2, 32, 16)), jnp.asarray([100, 200], jnp.int32))
+    assert bool(jnp.all(out[:300] == 32.0)) and bool(jnp.all(jnp.isnan(out[512:])))
+
+
+@pytest.mark.parametrize("room", [4.0, 0.5], ids=["room", "overflow"])
+def test_visited_row_share_counts_the_rows_the_products_visit(room):
+    """``expert_scalars``' ``visited_row_share``: ``min(pairs, rows) / rows``
+    a layer, the mean over layers; every layer at 1.0 once its share
+    overflows; and a trainer logs it where it logs ``held_pair_share``."""
+    from torchft_tpu.models import mellum as M
+
+    cfg = dataclasses.replace(CONFIGS["mellum_debug"], dtype=jnp.float32, share_room=room)
+    params = M.mellum_init(jax.random.PRNGKey(0), cfg)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0, 256)
+    rows = cfg.share_rows(96)
+    per_layer = M.mellum_hidden(params, tok, cfg)[1]
+    held = np.asarray(per_layer["held_pairs"])
+    want = np.minimum(held, rows) / rows
+    np.testing.assert_allclose(np.asarray(per_layer["visited"]), want, rtol=1e-6)
+    assert ((held > rows) == (want == 1.0)).all() and (held > rows).any() == (room < 1)
+    _, stats = M.mellum_loss_and_stats(params, tok, tok, cfg)
+    np.testing.assert_allclose(float(stats["visited_row_share"]), want.mean(), rtol=1e-6)
+    assert "visited" not in stats and "held_pair_share" in stats
+    for name in ("mellum_debug", "ling_debug"):
+        m, c, p, t = _program(name)
+        logged = jax.eval_shape(lambda p: m.loss(p, t, t, c)[1], p)["moe_stats"]  # noqa: B023
+        assert {"moe_visited_row_share", "moe_held_pair_share"} <= set(logged)
+    m, c, p, t = _program("olmoe_like")  # every row a pair: no share, no counter
+    assert "moe_visited_row_share" not in jax.eval_shape(
+        lambda p: m.loss(p, t, t, c)[1], p)["moe_stats"]

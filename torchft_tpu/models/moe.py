@@ -104,7 +104,9 @@ class MoEConfig(LlamaConfig):
     held_experts: Optional[Tuple[int, int]] = None
     # rows the share's buffer has, over the even share T * top_k * count /
     # num_experts; pairs beyond it are counted (``overflow``) and computed
-    # by nobody
+    # by nobody. A room costs memory and row traffic (every row of the
+    # buffer is gathered and scatter-added) and NO products: the grouped
+    # matmuls visit the held pairs only (``_share_sizes``)
     share_room: float = 1.5
 
     def __post_init__(self) -> None:
@@ -482,6 +484,18 @@ def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
         return _combine(rows, gates.astype(flat.dtype), inverse, order)
 
 
+def _share_sizes(counts: jax.Array, rows_n: int) -> jax.Array:
+    """counts [held] -> the group sizes of a share's grouped products: the
+    held pairs' own counts, clipped to the buffer's ``rows_n`` rows (they sum
+    to ``min(pairs, rows_n)``). The rows the pairs leave free belong to NO
+    group: ``megablox`` takes its grid from the sizes, so the row tiles past
+    the last pair are not visited (a tile the last pair shares is, at most one
+    more an expert where a group's border cuts a tile) and the products' time
+    follows the load, not the room."""
+    ends = jnp.minimum(jnp.cumsum(counts), rows_n)
+    return jnp.diff(ends, prepend=0).astype(jnp.int32)
+
+
 def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     """The dropless path of a chip that holds ``cfg.held_experts`` alone:
     of the T*k (token, choice) pairs those whose expert is held, sorted by
@@ -489,11 +503,21 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     room, never T*k); the pairs of absent experts are computed by nobody,
     and so are held pairs beyond the buffer (``overflow``). -> (the held
     experts' part of the block's output [T, d], stats: ``counts`` [held],
-    ``held_pairs``, ``overflow``).
+    ``held_pairs``, ``overflow``, ``visited``: the part of the buffer's rows
+    the grouped products visit, ``min(held_pairs, rows) / rows``).
 
     The rows leave and come back by XLA's gather and scatter-add of the
     buffer's rows (a sixteenth of T*k at the published cut): the custom
-    pullbacks of the whole-layer path gather T*k rows."""
+    pullbacks of the whole-layer path gather T*k rows. Those two move every
+    row of the buffer whatever it holds; the grouped products visit the
+    held pairs only (:func:`_share_sizes`).
+
+    What a grouped product leaves past the pairs is whatever its output
+    buffer held (NaN under the interpreter, any bits on a chip), so no
+    product with such a row may reach a sum: the rows are SELECTED by
+    ``valid`` before they are weighed, and a cotangent leaves the buffer's
+    tail through a select's pullback (a select), never through a product
+    with a mask (NaN x 0 is NaN)."""
     (T, d), k = flat.shape, idx.shape[1]
     first, held = cfg.held_experts
     rows_n = cfg.share_rows(T)
@@ -504,10 +528,7 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
         pairs = jnp.sum(counts)
         order = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n]
         valid = (jnp.arange(rows_n) < pairs)[:, None]
-        # every row of the buffer belongs to a group: what the held pairs
-        # leave free goes to the last expert as rows of zeros
-        ends = jnp.minimum(jnp.cumsum(counts), rows_n).at[-1].set(rows_n)
-        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        sizes = _share_sizes(counts, rows_n)
         weights = jnp.where(valid, gates.reshape(T * k, 1)[order], 0.0)
     with jax.named_scope("moe/dispatch"):
         rows = jnp.where(valid, flat[order // k], 0)
@@ -517,9 +538,10 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
         out = jnp.zeros((T, d), flat.dtype).at[order // k].add(
-            rows * weights.astype(flat.dtype))
+            jnp.where(valid, rows, 0) * weights.astype(flat.dtype))
     return out, {"counts": counts, "held_pairs": pairs,
-                 "overflow": jnp.maximum(pairs - rows_n, 0)}
+                 "overflow": jnp.maximum(pairs - rows_n, 0),
+                 "visited": jnp.minimum(pairs, rows_n).astype(jnp.float32) / rows_n}
 
 
 def _groups_hit(idx: jax.Array, cfg: MoEConfig) -> jax.Array:
@@ -561,8 +583,8 @@ def moe_ffn(
     and the decision over all ``num_experts``, the expert leaves
     ``[held, ...]``, the output the held experts' part alone
     (:func:`_share_ffn`; ``counts`` are then the held experts', beside
-    ``held_pairs`` and ``overflow``); ``shared`` (gate, up, down) is one
-    SwiGLU every token passes, added to the output ungated.
+    ``held_pairs``, ``overflow`` and ``visited``); ``shared`` (gate, up,
+    down) is one SwiGLU every token passes, added to the output ungated.
     """
     B, S, d = x.shape
     T = B * S
@@ -627,9 +649,13 @@ def expert_scalars(stats: Dict[str, jax.Array], pairs: int,
     says the bias does not reach the selection, near 1 that it drowns the
     scores), ``groups_hit_mean`` (the groups a token's k experts lie in: at
     most ``topk_group``), ``held_pair_share`` (the pairs that reached a held
-    expert over ``pairs`` = T * k: evenly, held / num_experts) and
+    expert over ``pairs`` = T * k: evenly, held / num_experts),
     ``overflow_pairs`` (held pairs that found the share's buffer full, over
-    all layers: computed by nobody, so anything but 0 is a wrong step).
+    all layers: computed by nobody, so anything but 0 is a wrong step) and
+    ``visited_row_share`` (the part of the share's buffer its grouped
+    products visit, ``min(held pairs, rows) / rows``, the mean over layers:
+    evenly about 1 / ``share_room``; 1.0 is every row multiplied, which is
+    what overflow costs).
 
     ``mean_floor`` guards the mean of a share's counts, which may all be
     zero; None divides by the mean as it is: LFM2's program (every expert
@@ -649,6 +675,7 @@ def expert_scalars(stats: Dict[str, jax.Array], pairs: int,
         stats["held_pair_share"] = jnp.mean(
             stats.pop("held_pairs").astype(jnp.float32)) / pairs
         stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
+        stats["visited_row_share"] = jnp.mean(stats.pop("visited"))
     return stats
 
 

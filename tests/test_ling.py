@@ -165,7 +165,7 @@ def test_the_staged_kinds_head_programs_are_the_parents(name):
 
 
 def _kinds():
-    from torchft_tpu.models import jamba, lfm2, llama, mellum, ouro
+    from torchft_tpu.models import jamba, lfm2, llama, mellum, nemotron_h, ouro
     from torchft_tpu.parallel.mesh import llama_param_specs
 
     # class -> (init, param_specs, whether the gradient is staged, frozen)
@@ -177,6 +177,8 @@ def _kinds():
         LingConfig: (L.ling_init, L.ling_param_specs, False, ("expert_bias",)),
         mellum.MellumConfig: (mellum.mellum_init, mellum.mellum_param_specs, False, ()),
         ouro.OuroConfig: (ouro.ouro_init, ouro.ouro_param_specs, True, ()),
+        nemotron_h.NemotronHConfig: (nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_param_specs,
+                                     False, ("expert_bias",)),
     }
 
 

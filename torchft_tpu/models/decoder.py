@@ -1,5 +1,5 @@
 """The one decoder over RUNS of like layers: what every kind whose layers
-are unlike (``jamba``, ``lfm2``, ``ling``, ``mellum``) repeated, once.
+are unlike (``jamba``, ``lfm2``, ``ling``, ``mellum``, ``nemotron_h``) repeated, once.
 
 Such a kind keeps one stack of parameters per run of like layers
 (``params["layers"]["00_mamba"]`` [7, ...], ``["01_attn"]`` [1, ...]; the
@@ -117,7 +117,7 @@ class Decoder:
 
     ``bodies(cfg, seq, attention_fn)`` -> ``body_of(kind)`` -> the scanned
     body of a run: ``(h, (w, bias, replay)) -> (h, stats)``, ``w`` one layer
-    of the run's stack, ``stats`` a dict of arrays or None. The kind's own
+    of the run's stack, ``stats`` a flat dict of arrays or None. The kind's own
     function, run at every call before the embedding is read: what it reads
     from its module's globals (the default attention, a table made once a
     step) it reads then. Where ``routed(kind)``, ``bias`` is the run's rows
@@ -153,8 +153,10 @@ class Decoder:
                 at += L
         if routing is not None and routing.shape[0] != at:
             raise ValueError(f"routing names {routing.shape[0]} layers, {at} choose experts")
-        stats = (jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *stats)
-                 if stats else {})
+        # each stat over the layers that emit it: runs of unlike kinds may
+        # emit unlike stats (a mixer's beside an expert block's)
+        stats = {k: jnp.concatenate([out[k] for out in stats if k in out])
+                 for k in sorted({k for out in stats for k in out})}
         return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
 
     def forward(self, params: Dict[str, Any], tokens: jax.Array, cfg: Any,

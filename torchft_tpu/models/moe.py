@@ -39,6 +39,11 @@ TPU-first design:
   makes the block use the given experts with this model's own scores
   at them as gates, so the router's gradient flows as in a free run; the
   routing the model would have chosen freely comes back beside it.
+- **The expert's form is the configuration's** (``MoEConfig.expert_act``):
+  SwiGLU, ``down(silu(gate(x)) * up(x))``, or the ungated
+  ``down(relu(up(x))^2)`` with no ``w_gate`` leaf anywhere (dropless path and
+  share; two grouped products forward where SwiGLU has three). A shared
+  expert has the same form and its own width (``shared_intermediate_size``).
 - Attention/norms/RoPE reuse the dense Llama blocks, including the Pallas
   flash-attention path.
 """
@@ -108,11 +113,21 @@ class MoEConfig(LlamaConfig):
     # buffer is gathered and scatter-added) and NO products: the grouped
     # matmuls visit the held pairs only (``_share_sizes``)
     share_room: float = 1.5
+    # an expert's form: "swiglu", ``down(silu(gate(x)) * up(x))``, three
+    # matrices; or "relu2", ungated, ``down(relu(up(x))^2)``, two matrices and
+    # no ``w_gate`` leaf anywhere (the dropless path and the share)
+    expert_act: str = "swiglu"
+    # the shared expert's width where the family has one; None: an expert's
+    shared_intermediate_size: Optional[int] = None
 
     def __post_init__(self) -> None:
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score={self.router_score!r}: models/moe.py "
                              "scores by 'softmax' or 'sigmoid'")
+        if self.expert_act not in ("swiglu", "relu2") or (
+                self.expert_act == "relu2" and self.capacity_factor is not None):
+            raise ValueError(f"expert_act={self.expert_act!r}: 'swiglu', or 'relu2' "
+                             "on the dropless path (capacity_factor=None)")
         if self.num_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"n_group={self.n_group}, topk_group={self.topk_group} "
                              f"of {self.num_experts} experts")
@@ -156,11 +171,16 @@ class MoEConfig(LlamaConfig):
         c = int(self.capacity_factor * tokens * self.top_k / self.num_experts)
         return max(c, self.top_k)
 
+    @property
+    def expert_matrices(self) -> int:
+        """Matrices an expert has: SwiGLU's three, the ungated form's two."""
+        return 2 if self.expert_act == "relu2" else 3
+
     def num_params(self) -> int:
         d, h, v, L = self.dim, self.ffn_hidden, self.vocab_size, self.n_layers
         kv = self.n_kv_heads * self.head_dim
         per_layer = (2 * d * d + 2 * d * kv + 2 * d + d * self.num_experts
-                     + 3 * self.num_experts * d * h
+                     + self.expert_matrices * self.num_experts * d * h
                      + (d + kv if self.qk_norm else 0))
         return L * per_layer + 2 * v * d + d
 
@@ -214,6 +234,8 @@ def moe_init(key: jax.Array, cfg: MoEConfig) -> Dict[str, Any]:
         "w_up": dense_init(ks[6], (L, E, d, H), d),
         "w_down": dense_init(ks[7], (L, E, H, d), H),
     }
+    if cfg.expert_act == "relu2":
+        del layers["w_gate"]
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, cfg.n_heads * hd), cfg.dtype)
         layers["k_norm"] = jnp.ones((L, kvd), cfg.dtype)
@@ -460,10 +482,21 @@ def _grouped_matmul(rows, weights, sizes):
                interpret=jax.default_backend() != "tpu")
 
 
-def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
+def _hidden(rows, w_gate, w_up, product, act):
+    """An expert's hidden activations in the configuration's form (``act``:
+    ``MoEConfig.expert_act``): SwiGLU's ``silu(gate) * up``, or, ungated
+    (``w_gate`` is None), ``relu(up)^2``. ``product(rows, matrix)``: the
+    grouped product of the routed experts, a plain one for the shared."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(product(rows, w_up)))
+    return jax.nn.silu(product(rows, w_gate)) * product(rows, w_up)
+
+
+def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down, act="swiglu"):
     """The dropless path: the T*k (token, choice) pairs sorted by expert
     (stable: an expert sees its tokens in order), one grouped matrix
-    multiplication per projection over the T*k rows with the per-expert
+    multiplication per projection (three for SwiGLU, two for the ungated
+    form) over the T*k rows with the per-expert
     counts as group sizes, unsorted, weighted by the gates and summed over
     the k choices. Every shape is static (T*k rows whatever the load); no
     pair is dropped, also when every token picks the same expert.
@@ -477,8 +510,7 @@ def _dropless_ffn(flat, gates, idx, sizes, w_gate, w_up, w_down):
     with jax.named_scope("moe/dispatch"):
         rows = _take_rows(flat, order // k, inverse, k)  # [T*k, d]
     with jax.named_scope("moe/experts"):
-        h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(
-            rows, w_up, sizes)
+        h = _hidden(rows, w_gate, w_up, lambda r, w: _grouped_matmul(r, w, sizes), act)
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
         return _combine(rows, gates.astype(flat.dtype), inverse, order)
@@ -533,8 +565,8 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     with jax.named_scope("moe/dispatch"):
         rows = jnp.where(valid, flat[order // k], 0)
     with jax.named_scope("moe/experts"):
-        h = jax.nn.silu(_grouped_matmul(rows, w_gate, sizes)) * _grouped_matmul(
-            rows, w_up, sizes)
+        h = _hidden(rows, w_gate, w_up, lambda r, w: _grouped_matmul(r, w, sizes),
+                    cfg.expert_act)
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
         out = jnp.zeros((T, d), flat.dtype).at[order // k].add(
@@ -554,15 +586,17 @@ def _groups_hit(idx: jax.Array, cfg: MoEConfig) -> jax.Array:
 def moe_ffn(
     x: jax.Array,
     router: jax.Array,
-    w_gate: jax.Array,
+    w_gate: Optional[jax.Array],
     w_up: jax.Array,
     w_down: jax.Array,
     cfg: MoEConfig,
     routing: Optional[jax.Array] = None,
     bias: Optional[jax.Array] = None,
-    shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    shared: Optional[Tuple[Optional[jax.Array], jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Sparse SwiGLU FFN. x: [B, S, d] -> ([B, S, d], stats).
+    """The sparse feed-forward block, its experts in the configuration's
+    form (``cfg.expert_act``: SwiGLU, or ungated relu^2, for which ``w_gate``
+    and the shared expert's gate are None). x: [B, S, d] -> ([B, S, d], stats).
 
     ``routing`` ([T, k] expert indices, T = B*S): replay these choices
     instead of the router's own (:func:`_choose`). ``bias`` ([E] f32): added
@@ -584,7 +618,8 @@ def moe_ffn(
     ``[held, ...]``, the output the held experts' part alone
     (:func:`_share_ffn`; ``counts`` are then the held experts', beside
     ``held_pairs``, ``overflow`` and ``visited``); ``shared`` (gate, up,
-    down) is one SwiGLU every token passes, added to the output ungated.
+    down) is one expert of the same form, as wide as its matrices are, that
+    every token passes, added to the output without a router's gate.
     """
     B, S, d = x.shape
     T = B * S
@@ -607,19 +642,20 @@ def moe_ffn(
             free["groups_hit"] = _groups_hit(idx, cfg)
         if cfg.held_experts is None:
             sizes = _counts(idx, cfg.num_experts)
-    payload = flat.astype(w_gate.dtype)  # the router saw x as it came
+    payload = flat.astype(w_up.dtype)  # the router saw x as it came
     if cfg.held_experts is not None:
         out, share = _share_ffn(payload, gates, idx, cfg, w_gate, w_up, w_down)
         sizes = share.pop("counts")
         free.update(share)
     elif cfg.capacity_factor is None:
-        out = _dropless_ffn(payload, gates, idx, sizes, w_gate, w_up, w_down)
+        out = _dropless_ffn(payload, gates, idx, sizes, w_gate, w_up, w_down,
+                            cfg.expert_act)
     else:
         out = _capacity_ffn(payload, gates, idx, w_gate, w_up, w_down, cfg.capacity(T))
     if shared is not None:
         with jax.named_scope("moe/shared"):
             s_gate, s_up, s_down = shared
-            out = out + (jax.nn.silu(payload @ s_gate) * (payload @ s_up)) @ s_down
+            out = out + _hidden(payload, s_gate, s_up, jnp.matmul, cfg.expert_act) @ s_down
     stats = {"counts": sizes.astype(jnp.float32), "prob_sum": jnp.sum(probs, axis=0),
              **free}
     return out.astype(x.dtype).reshape(B, S, d), stats
@@ -683,8 +719,11 @@ def ffn_leaves(cfg: MoEConfig, kind: str, shared: bool = False
                ) -> Dict[str, Tuple[int, Tuple[int, ...], int, Any]]:
     """The feed-forward leaves of one layer that ends in a SwiGLU (``kind``
     "dense", ``cfg.ffn_hidden`` wide) or in routed experts ("moe",
-    ``cfg.moe_intermediate_size`` wide, ``cfg.n_held`` of them here, beside
-    one ``shared`` expert where the family has one): leaf -> (which of the
+    ``cfg.moe_intermediate_size`` wide, ``cfg.n_held`` of them here, in the
+    configuration's form: no ``w_gate`` where ``cfg.expert_act`` is the
+    ungated "relu2"; beside one ``shared`` expert of that form where the
+    family has one, ``cfg.shared_intermediate_size`` wide where that is
+    given): leaf -> (which of the
     layer's feed-forward keys draws it, its shape and its fan-in without the
     layers' axis, its PartitionSpec with it). One table for :func:`ffn_init`
     and :func:`ffn_specs`."""
@@ -701,8 +740,11 @@ def ffn_leaves(cfg: MoEConfig, kind: str, shared: bool = False
               "w_up": (1, (held, d, W), d, P(None, "ep", "fsdp", "tp")),
               "w_down": (2, (held, W, d), W, P(None, "ep", "tp", "fsdp"))}
     if shared:
-        leaves.update(shared_gate=(4, (d, W), d, col), shared_up=(5, (d, W), d, col),
-                      shared_down=(6, (W, d), W, row))
+        S = cfg.shared_intermediate_size or W
+        leaves.update(shared_gate=(4, (d, S), d, col), shared_up=(5, (d, S), d, col),
+                      shared_down=(6, (S, d), S, row))
+    if cfg.expert_act == "relu2":
+        leaves = {name: leaf for name, leaf in leaves.items() if "gate" not in name}
     return leaves
 
 
@@ -745,7 +787,7 @@ def _moe_layer(cfg, attention, positions, h, xs):
     moe_out, stats = moe_ffn(
         x,
         layer_params["router"],
-        layer_params["w_gate"],
+        layer_params.get("w_gate"),
         layer_params["w_up"],
         layer_params["w_down"],
         cfg,
@@ -776,7 +818,7 @@ def moe_forward(
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     h = params["embed"][tokens]
-    _refuse_dropless_ep(cfg, _sharded_axes(params["layers"]["w_gate"]))
+    _refuse_dropless_ep(cfg, _sharded_axes(params["layers"]["w_up"]))
     layer = partial(_moe_layer, cfg, attention, positions)
     body = remat_wrap(layer, remat)
     h, stats = jax.lax.scan(body, h, (params["layers"], routing))
@@ -896,6 +938,8 @@ def moe_param_specs(cfg: MoEConfig, mesh: Optional[Any] = None) -> Dict[str, Any
     }
     if cfg.qk_norm:
         layers["q_norm"] = layers["k_norm"] = P(None, None)
+    if cfg.expert_act == "relu2":
+        del layers["w_gate"]
     return {
         "embed": P("fsdp", "tp"),
         "layers": layers,

@@ -119,8 +119,13 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # ling_debug and mellum_debug hold a share: pinned anew by PR 51 (the
     # share's group sizes are the held pairs' own, a select before the
     # scatter-add, ``visited_row_share`` beside the loss); the losses are the
-    # parent's to the bit, and so is every other line of this table
-    "ling_debug": ("57b9436427c37cbe", "814162833351e09e", "0x1.7f8a480000000p+2"),
+    # parent's to the bit, and so is every other line of this table.
+    # ling_debug's pair pinned anew by PR 53: the short convolution is ONE
+    # program for every kind (``ops/short_conv.py``; at the debug widths its
+    # ``jax.numpy`` form, which widens the padded sequence once where Ling's
+    # ``widen_late`` widened each shifted view); the loss is PR 51's to the
+    # bit, and jamba_debug and lfm2_debug lower to what they did
+    "ling_debug": ("86bbe1972d8cce99", "dcfa91c7504b6ea3", "0x1.7f8a480000000p+2"),
     "mellum_debug": ("8823a851ba1a0fa9", "e87bf60393be84ba", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),

@@ -286,8 +286,9 @@ def test_remat_loss_chunk_and_replay_work_as_for_the_other_kinds():
 PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 43
     "debug": "b960470496fcaaa9",  # the dense decoder (mistral-7b, internlm2-1.8b)
     "jamba_debug": "f85418fbe0152758",
-    # holds a share: pinned anew by PR 51 (tests/test_ling.py's table says what moved)
-    "ling_debug": "57b9436427c37cbe",
+    # pinned anew by PR 51 (it holds a share) and by PR 53 (one short convolution
+    # for every kind): tests/test_ling.py's table says what moved
+    "ling_debug": "86bbe1972d8cce99",
 }
 
 

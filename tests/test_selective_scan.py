@@ -227,3 +227,41 @@ def test_the_delta_rule_kernels_compile_for_a_v5e_at_lings_widths(one_chip, monk
     assert "kda_fwd" in text and "kda_bwd" in text
     states = H * (T // kda.BLOCK) * d * d * 4
     assert states == 2**28 and compiled.memory_analysis().temp_size_in_bytes < 8 * states
+
+
+@pytest.mark.parametrize("cell", ["nemotron", "jamba", "ling", "lfm2"])
+def test_the_short_convolution_kernels_compile_for_a_v5e_at_the_cells_widths(
+        cell, one_chip, monkeypatch):
+    """PR 53: Mosaic takes ``ops/short_conv.py``'s pair at every mixer's
+    shape (the sublane rotations, the sixteen-row block after a tile and the
+    VMEM of a tile's float32 ``dpre`` are what interpret mode cannot show),
+    and what the pair keeps in HBM beside its arguments and results is
+    nothing of ``[B, T, di]`` in float32: the step's temporaries stay under
+    ONE such array (the ``jax.numpy`` form kept three)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from torchft_tpu.ops import short_conv as sc
+
+    shape, k, bias, act = {"nemotron": ((2, 8192, 6144), 4, True, jax.nn.silu),
+                           "jamba": ((1, 8192, 5120), 4, True, jax.nn.silu),
+                           "ling": ((1, 32768, 4096), 4, False, jax.nn.silu),
+                           "lfm2": ((1, 8192, 2048), 3, False, None)}[cell]
+    monkeypatch.setattr(sc, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+
+    def both(x, w, b, dy):
+        y, pull = jax.vjp(lambda x, w, b: sc.short_conv(x, w, b, act), x, w, b)
+        return y, pull(dy)
+
+    try:
+        compiled = jax.jit(both).lower(
+            sd(shape), sd((k, shape[2])), sd(shape[2:]) if bias else None, sd(shape)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "short_conv_fwd" in text and "short_conv_bwd" in text
+    B, T, di = shape
+    assert compiled.memory_analysis().temp_size_in_bytes < B * T * di * 4

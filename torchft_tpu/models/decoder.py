@@ -12,7 +12,7 @@ new buffer to XLA; PERF.md section 6, PR 33.)
 Here: the fold of layer kinds into runs (:func:`runs_of`), the tree round
 the runs (:func:`init_tree`, :func:`spec_tree`), the scan of the runs with
 the logits head and the loss head (:class:`Decoder`), and the causal
-convolution three kinds mix with. A kind brings its configuration class with
+convolution four kinds mix with. A kind brings its configuration class with
 ``runs()``, ``init``, PartitionSpecs, one layer's scanned body and its
 counters. A staged gradient over runs (ROADMAP R8) has this scan to cut.
 """
@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from torchft_tpu.models.llama import _rmsnorm, head_loss
 from torchft_tpu.models.remat import remat_wrap
+from torchft_tpu.ops.short_conv import short_conv
 
 __all__ = ["Decoder", "runs_of", "init_tree", "spec_tree", "loss_chunk_for"]
 
@@ -75,26 +76,20 @@ def spec_tree(cfg: Any, run: Callable[[Any], Dict[str, Any]]) -> Dict[str, Any]:
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
-                 activation: Optional[Any] = jax.nn.silu,
-                 widen_late: bool = False) -> jax.Array:
-    """``activation`` (Mamba's and KDA's silu; None: LFM2's, none) of the
-    depthwise causal convolution, as shifted multiply-adds summed in
-    float32. x [B,T,di], w [k,di] (``w[k-1]`` weighs the current position),
-    b [di] or None.
+                 activation: Optional[Any] = jax.nn.silu) -> jax.Array:
+    """``activation`` (Mamba's, Mamba-2's and KDA's silu; None: LFM2's, none)
+    of the depthwise causal convolution, summed in float32. x [B,T,di],
+    w [k,di] (``w[k-1]`` weighs the current position), b [di] or None.
 
-    Two programs, kept apart on purpose: Jamba and LFM2 widen the padded
-    sequence to float32 ONCE; Ling (``widen_late``) widens each shifted view
-    of the narrow one, because at 32k three float32 copies of [T, 4096] were
-    1.5 GB of a layer's backward pass (PR 40). The same sums, another
-    compiled step: neither cell's program is moved to the other's here."""
-    k, T = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    if not widen_late:
-        padded = padded.astype(_F32)
-    out = sum(padded[:, j:j + T].astype(_F32) * w[j].astype(_F32) for j in range(k))
-    if b is not None:
-        out = out + b.astype(_F32)
-    return (activation(out) if activation else out).astype(x.dtype)
+    One program for every kind (``ops/short_conv.py``): where the shape
+    tiles (``di`` whole lanes of 128, ``T`` whole tiles of positions: every
+    cell's) a Pallas kernel pair that reads the narrow rows once and keeps
+    the float32 sums, the bias and the activation in VMEM, forward and
+    backward, so that no float32 copy of [B, T, di] reaches HBM (at 32k
+    three of [T, 4096] were 1.5 GB of one of Ling's layers' backward pass,
+    PR 40); otherwise (the debug configurations' widths) shifted
+    multiply-adds over the padded sequence in ``jax.numpy``."""
+    return short_conv(x, w, b, activation)
 
 
 def loss_chunk_for(cfg: Any, seq: int, loss_chunk: int = 0) -> int:

@@ -236,9 +236,9 @@ def _head_gate(o: jax.Array, u: jax.Array, w_g: jax.Array) -> jax.Array:
     return (o * gate.astype(o.dtype)[..., None]).reshape(*o.shape[:2], -1)
 
 
-# SiLU of the depthwise causal convolution, x [B,T,di], w [k,di]: the
-# sequence padded BEFORE it is widened to float32 (``decoder._causal_conv``)
-_short_conv = partial(_causal_conv, b=None, widen_late=True)
+# SiLU of the depthwise causal convolution, x [B,T,di], w [k,di], no bias:
+# the one program every kind's mixer runs (``decoder._causal_conv``)
+_short_conv = partial(_causal_conv, b=None)
 
 
 def _l2norm(x: jax.Array) -> jax.Array:

@@ -21,7 +21,12 @@
         --elements 189532160,315367424 --donate [--lanes 1|2|4|8]
 
    (``--lanes``: how many connections a ring neighbour the frames ride, the
-   sweep that sets ``process_group._RING_LANES``), and the two readings
+   sweep that sets ``process_group._RING_LANES``; the split it prints,
+   ``recv_wait_s`` / ``recv_s`` / ``fold_s`` / ``send_s`` / ``handoff_s`` /
+   ``slot_wait_s`` / ``recv_span_s`` as [the lanes' mean, the largest
+   lane's], is the ring's
+   own count, the one a traced trainer's ``allreduce/ring_stream`` spans
+   carry: nothing is patched), and the two readings
    under it, with no ring: what N loopback streams carry between the ranks,
    and what the fold's add does on several threads:
 
@@ -504,12 +509,22 @@ def _spawn_ranks(flag: str, spec: dict, world: int, timeout: float) -> list:
         store.shutdown()
 
 
+# what the ring counts of a lane's threads (process_group._RING_TERMS), as
+# this bench prints it: ``recv_s`` was one number, socket time with the
+# waits for the peer in it, while the ring was timed from outside; the
+# ring's own clock tells the header waits (``recv_wait_s``) from the
+# payloads (``recv_s``)
+_SPLIT = {"recv_wait_s": "recv_wait", "recv_s": "recv", "fold_s": "fold",
+          "send_s": "send", "handoff_s": "handoff",
+          "slot_wait_s": "slot_wait", "recv_span_s": "recv_span"}
+
+
 def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
                      chunk_mb: float, lanes: int, python_frames: bool,
                      dtype: str, timeout: float) -> None:
     """One step's buckets through the plain ring, ``world`` ranks as
-    processes on this host: the step's wall time and, per rank and lane,
-    where the ring's threads spent it (see :func:`_ring_child`)."""
+    processes on this host: the step's wall time and where the ring's
+    threads spent it, by the ring's own count (see :func:`_ring_child`)."""
     ranks = _spawn_ranks("--_ring-child", {
         "elements": elements, "donate": donate, "iters": iters,
         "chunk_mb": chunk_mb, "lanes": lanes, "python_frames": python_frames,
@@ -517,19 +532,21 @@ def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
     }, world, timeout * (iters + 2))
     assert len({r["crc"] for r in ranks}) == 1, "ranks disagree"
     nbytes = ranks[0]["itemsize"] * sum(elements)
-    by_lane = ("recv_s", "fold_s", "send_s")
     print(json.dumps({
         "transport": "allreduce", "algo": "ring_split", "world": world,
         "dtype": dtype, "elements": elements, "donate": donate,
         "chunk_mb": ranks[0]["chunk_mb"], "lanes": ranks[0]["lanes"],
         "native_fold": ranks[0]["native_fold"],
         "native_frames": ranks[0]["native_frames"], "iters": iters,
-        # each the median over iterations of one rank, then the slowest rank
-        **{k: round(max(r[k] for r in ranks), 4) for k in ("step_s", "ring_s")},
-        # seconds a step inside the socket receive, the fold and the socket
-        # send, a list by lane (a lane's three are three threads)
-        **{k: [round(max(r[k][lane] for r in ranks), 4)
-               for lane in range(len(ranks[0][k]))] for k in by_lane},
+        # each the median over iterations of one rank, then the slowest
+        # rank: the step; fn(comm) on the dispatch thread, summed over the
+        # step's ops; of that, until the first peer byte; and seconds a step
+        # in each of the ring's terms, [the lanes' mean, the largest lane's]
+        # (a lane's receive, fold and send are three threads)
+        **{k: round(max(r[k] for r in ranks), 4)
+           for k in ("step_s", "ring_s", "entry_wait_s")},
+        **{k: [round(max(r[k][i] for r in ranks), 4) for i in (0, 1)]
+           for k in _SPLIT},
         "gbit_per_s": round(
             nbytes * 8 / max(r["step_s"] for r in ranks) / 1e9, 2),
         "inplace": ranks[0]["inplace"],
@@ -538,14 +555,13 @@ def bench_ring_split(world: int, elements: list, donate: bool, iters: int,
 
 
 def _ring_child(spec: dict, rank: int) -> None:
-    """One rank of :func:`bench_ring_split`. The split is read off the
-    functions the ring's threads call: ``recv_s`` a lane's receiver inside
-    ``recv_raw_into`` (socket time, waiting for the peer included),
-    ``fold_s`` its fold inside ``_fold``, ``send_s`` its writer inside
-    ``send_raw``; ``ring_s`` the dispatch thread inside ``_ring_allreduce``.
+    """One rank of :func:`bench_ring_split`. Nothing of the library is
+    wrapped or timed here: the split is what the ring counted of itself and
+    left on each op's future (``fut.ring``: process_group._ring_allreduce),
+    ``ring_s`` and ``entry_wait_s`` come from the dispatch thread's stamps
+    beside it (``fut.stamps``), all summed over the step's ops.
     ``lanes`` and ``chunk_mb`` are the bench's own arguments: they set the
     module's constants in this process, the library takes none."""
-    import re
     import statistics
     import threading
     import zlib
@@ -561,44 +577,26 @@ def _ring_child(spec: dict, rank: int) -> None:
         pg_mod._RING_LANES = spec["lanes"]
     pg_mod._RING_LANE_FLOOR_BYTES = (
         2 * pg_mod._RING_LANES * pg_mod._RING_CHUNK_BYTES)
-    lanes = pg_mod._RING_LANES
-    spent = {"ring_s": 0.0, **{
-        (k, lane): 0.0 for k in ("recv_s", "fold_s", "send_s")
-        for lane in range(lanes)}}
-
-    real_native, in_frame = pg_mod._native_ring, threading.local()
-
-    def timed(fn, key):
-        """A key by lane has one thread writing it: the argument ``lane``,
-        or the digits before a ring worker's ``_r<rank>``."""
-        def wrapper(*args, **kwargs):
-            in_frame.on = key in ("recv_s", "send_s")
-            t0 = time.perf_counter()
-            try:
-                return fn(*args, **kwargs)
-            finally:
-                in_frame.on = False
-                k = key
-                if key != "ring_s":
-                    lane = kwargs.get("lane")
-                    if lane is None:
-                        lane = int(re.search(
-                            r"(\d*)_r\d+$",
-                            threading.current_thread().name).group(1) or 0)
-                    k = (key, lane)
-                spent[k] += time.perf_counter() - t0
-        return wrapper
+    real_native = pg_mod._native_ring
 
     if spec["python_frames"]:
-        # inside send_raw / recv_raw_into the library is told it has no
-        # native calls; the lanes and the fold keep theirs
+        # the two calls that move a frame's bytes are told the library has
+        # no native ones; the lanes and the fold keep theirs
+        in_frame = threading.local()
         pg_mod._native_ring = (
             lambda: None if getattr(in_frame, "on", False) else real_native())
 
-    pg_mod._ring_allreduce = timed(pg_mod._ring_allreduce, "ring_s")
-    pg_mod._fold = timed(pg_mod._fold, "fold_s")
-    pg_mod._Comm.recv_raw_into = timed(pg_mod._Comm.recv_raw_into, "recv_s")
-    pg_mod._Comm.send_raw = timed(pg_mod._Comm.send_raw, "send_s")
+        def in_python(fn):
+            def call(*args, **kwargs):
+                in_frame.on = True
+                try:
+                    return fn(*args, **kwargs)
+                finally:
+                    in_frame.on = False
+            return call
+
+        pg_mod._send_all = in_python(pg_mod._send_all)
+        pg_mod._recv_all = in_python(pg_mod._recv_all)
 
     pg = ProcessGroupHost(timeout=spec["timeout"])
     pg.configure(spec["addr"], rank, spec["world"], quorum_id=1)
@@ -615,22 +613,29 @@ def _ring_child(spec: dict, rank: int) -> None:
         for b, g in zip(bufs, grads):
             np.copyto(b, g)
         pg.barrier().wait(spec["timeout"])
-        for k in spent:
-            spent[k] = 0.0
         t0 = time.perf_counter()
         futs = [
             pg.allreduce([b], ReduceOp.SUM, donate=spec["donate"]).get_future()
             for b in bufs
         ]
         outs = [f.wait(spec["timeout"])[0] for f in futs]
-        rows.append({"step_s": time.perf_counter() - t0, **spent})
-        info = getattr(futs[-1], "ring", {})
+        row = {"step_s": time.perf_counter() - t0, "ring_s": 0.0,
+               "entry_wait_s": 0.0,
+               **{(k, end): 0.0 for k in _SPLIT for end in ("", "_max")}}
+        for f in futs:
+            info, (_, t_run0, t_run1) = f.ring, f.stamps
+            row["ring_s"] += t_run1 - t_run0
+            row["entry_wait_s"] += info["t_first"] - t_run0
+            for k, term in _SPLIT.items():
+                for end in ("", "_max"):
+                    row[k, end] += info[term + "_us" + end] / 1e6
+        rows.append(row)
     pg.shutdown()
     rows = rows[1:]
     med = {k: statistics.median(r[k] for r in rows) for k in rows[0]}
-    row = {"step_s": med["step_s"], "ring_s": med["ring_s"]}
-    for k in ("recv_s", "fold_s", "send_s"):
-        row[k] = [med[k, lane] for lane in range(lanes)]
+    row = {k: med[k] for k in ("step_s", "ring_s", "entry_wait_s")}
+    for k in _SPLIT:
+        row[k] = [med[k, ""], med[k, "_max"]]
     row["inplace"] = info.get("inplace", 0)
     row["lanes"] = info.get("lanes", 1)
     row["native_fold"] = int(real_native() is not None)

@@ -459,6 +459,7 @@ def train(args) -> None:
             "timings": {k: round(v, 3) for k, v in manager.timings().items()
                         if k.endswith("_s") or k.startswith("heal_")
                         or k in ("allreduce_buckets", "allreduce_ops",
+                                 "allreduce_runs", "land_under_fetch_share",
                                  "overlap_efficiency", "stage_pool_hit_share",
                                  "d2h_under_backward_share",
                                  "d2h_concurrency",

@@ -1228,5 +1228,187 @@ class TestTheRingWorksInTheStagingBuffer:
         assert all(not np.asarray(v).any() for v in out.values())
         assert not m.should_commit()
         time.sleep(0.2)  # whatever lands after the failure
+        # the ring that failed left no half account: its future carries no
+        # stamps, so no wire_run, no child of one, and no key of the step
+        assert not {"t_first", "fold_us"} & set(theirs.get_future().ring)
+        names = {s["name"] for s in m.tracer.export()["spans"]}
+        assert not names & {"wire_run", "ring_entry_wait", "ring_stream"}
+        assert not set(bucketing.RING_KEYS) & set(m.timings())
         m.shutdown(wait=False)
         assert _pooled(m) == 0 and _parked(m) == []
+
+
+_WIRE_RUN_ARGS = {"bucket", "segment", "bytes", "world", "queued_us"}
+_RING_TERMS = ("recv_wait", "recv", "slot_wait", "recv_span", "arrive_wait",
+               "fold", "ready_wait", "send", "handoff")
+
+
+def _by_name(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+class TestTheRingTellsItsOwnTime:
+    """A plain ring's ``wire_run`` has two children recorded from the
+    ring's own stamps, ``ring_entry_wait`` and ``ring_stream``, and the
+    step's ``timings()`` the six ``RING_KEYS``; whatever runs no plain ring
+    says what it said."""
+
+    @pytest.mark.parametrize("lanes", [1, 4])
+    def test_the_two_children_tile_their_wire_run_and_timings_sum_the_step(
+        self, world_of_two, monkeypatch, lanes
+    ):
+        import torchft_tpu.process_group as pg_mod
+
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 32 * 1024)
+        if lanes == 4:
+            monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 0)
+        ms = [world_of_two(rank) for rank in range(2)]
+        steps = 3
+
+        def run(rank):
+            m, seen = ms[rank], []
+            for step in range(steps):
+                _reduce(m, _ring_tree(rank, step), streamed=True)
+                seen.append(m.timings())
+                _let_landed_leaves_finish(m)
+                m.should_commit()
+            m.start_quorum()  # the next step has begun: its keys are its own
+            seen.append(m.timings())
+            return seen
+
+        with ThreadPoolExecutor(2) as ex:
+            results = list(ex.map(run, range(2)))
+        spans = [m.tracer.export()["spans"] for m in ms]
+        for m in ms:
+            m.shutdown(wait=False)
+        for rank, seen in enumerate(results):
+            runs = _by_name(spans[rank], "wire_run")
+            assert len(runs) == 3 * steps
+            by_parent = {}
+            for s in spans[rank]:
+                if s["name"] in ("ring_entry_wait", "ring_stream"):
+                    by_parent.setdefault(s["parent"], {})[s["name"]] = s
+            assert set(by_parent) == {r["id"] for r in runs}
+            for r in runs:
+                assert set(r["args"]) == _WIRE_RUN_ARGS | {
+                    "inplace", "chunks", "lanes"}
+                entry = by_parent[r["id"]]["ring_entry_wait"]
+                stream = by_parent[r["id"]]["ring_stream"]
+                for child in (entry, stream):
+                    assert child["cat"] == "allreduce"
+                    assert child["step"] == r["step"]
+                    assert (child["args"]["bucket"], child["args"]["segment"]
+                            ) == (r["args"]["bucket"], r["args"]["segment"])
+                assert set(entry["args"]) == {"bucket", "segment"}
+                # one set of stamps: the two tile the run, to a rounding
+                assert abs(entry["dur_us"] + stream["dur_us"]
+                           - r["dur_us"]) <= 3
+                assert abs(entry["ts_us"] - r["ts_us"]) <= 50
+                assert abs(stream["ts_us"] + stream["dur_us"]
+                           - r["ts_us"] - r["dur_us"]) <= 50
+                args = stream["args"]
+                assert set(args) == {
+                    "bucket", "segment", "bytes", "lanes", "chunks", "gb_s",
+                    *(t + "_us" for t in _RING_TERMS),
+                    *(t + "_us_max" for t in _RING_TERMS)}
+                assert (args["bytes"], args["lanes"], args["chunks"]) == (
+                    r["args"]["bytes"], lanes, r["args"]["chunks"])
+                assert args["gb_s"] == pytest.approx(
+                    args["bytes"] / 1e3 / stream["dur_us"], rel=0.05, abs=2e-3)
+                for t in _RING_TERMS:
+                    assert 0 <= args[t + "_us"] <= args[t + "_us_max"]
+                # the receiver's three terms lie inside the stream
+                assert (args["recv_wait_us"] + args["recv_us"]
+                        + args["slot_wait_us"]) <= args["recv_span_us"] + 3
+                assert args["recv_span_us_max"] <= stream["dur_us"] + 3
+            # timings() of a step: its runs' sums, to the SUMMARY's digits
+            for step in range(steps):
+                mine = [r for r in runs if r["step"] == runs[0]["step"] + step]
+                assert len(mine) == 3
+                t = seen[step]
+                assert t["ring_lanes"] == float(lanes)
+                assert t["ring_entry_wait_s"] == pytest.approx(sum(
+                    by_parent[r["id"]]["ring_entry_wait"]["dur_us"]
+                    for r in mine) / 1e6, abs=2e-5)
+                for term in ("recv_wait", "recv", "fold", "send", "handoff"):
+                    assert t[f"ring_{term}_s"] == pytest.approx(sum(
+                        by_parent[r["id"]]["ring_stream"]["args"][term + "_us"]
+                        for r in mine) / 1e6, abs=1e-9)
+                assert t["ring_entry_wait_s"] + t["ring_recv_s"] <= (
+                    t["allreduce_wire_s"])
+            # begin_step took the step's keys away
+            assert not {*bucketing.RING_KEYS, "ring_lanes"} & set(seen[-1])
+            assert "allreduce_wire_s" in seen[-1]  # the others stay, as before
+
+    def test_ring_lanes_is_the_fewest_any_ring_of_the_step_rode(
+        self, world_of_two, monkeypatch
+    ):
+        """The step's first run is under the lane floor and its second over
+        it: ``ring_lanes`` reads 1.0, where the last run's lanes hid it."""
+        import jax
+
+        import torchft_tpu.process_group as pg_mod
+
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 32 * 1024)
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES", 70_000)
+        ms = [world_of_two(rank) for rank in range(2)]
+
+        def run(rank):
+            rng = np.random.RandomState(rank)
+            tree = {f"p{i}": jax.device_put(rng.randn(n).astype(np.float32))
+                    for i, n in enumerate((17_001, 17_001, 20_000, 20_000))}
+            _reduce(ms[rank], tree, streamed=True)
+            return ms[rank].timings()["ring_lanes"]
+
+        with ThreadPoolExecutor(2) as ex:
+            assert list(ex.map(run, range(2))) == [1.0, 1.0]
+        for m in ms:
+            rode = [s["args"]["lanes"] for s in _by_name(
+                m.tracer.export()["spans"], "wire_run")]
+            m.shutdown(wait=False)
+            assert rode == [1, 4]
+
+    def test_a_world_of_one_says_what_it_said(self, host_of_one):
+        m = make_manager(pg=host_of_one, quorum=make_quorum(),
+                         bucket_cap_bytes=_CAP3)
+        _reduce(m, _device_tree(seed=0), streamed=True)
+        spans = m.tracer.export()["spans"]
+        timings = m.timings()
+        m.shutdown(wait=False)
+        runs = _by_name(spans, "wire_run")
+        assert runs and all(
+            set(r["args"]) == _WIRE_RUN_ARGS | {"lanes"} for r in runs)
+        assert not _by_name(spans, "ring_entry_wait") + _by_name(
+            spans, "ring_stream")
+        assert timings["ring_lanes"] == 1.0
+        assert not set(bucketing.RING_KEYS) & set(timings)
+
+    @pytest.mark.parametrize("how", ["mesh_exchange", "compressed_ring"])
+    def test_no_plain_ring_no_account(self, world_of_two, monkeypatch, how):
+        """Buckets under ``_RING_MIN_BYTES`` cross in the mesh exchange,
+        compressed ones ride the self-healing ring: neither fills ``info``,
+        and the spans and ``timings()`` are the parent commit's."""
+        import torchft_tpu.process_group as pg_mod
+
+        kwargs = {}
+        if how == "mesh_exchange":
+            monkeypatch.setattr(pg_mod, "_RING_MIN_BYTES", 1 << 30)
+        else:
+            kwargs["compress"] = "fp8"
+        ms = [world_of_two(rank, **kwargs) for rank in range(2)]
+
+        def run(rank):
+            _reduce(ms[rank], _ring_tree(rank, 0), streamed=True)
+            return ms[rank].timings()
+
+        with ThreadPoolExecutor(2) as ex:
+            timings = list(ex.map(run, range(2)))
+        for m, t in zip(ms, timings):
+            spans = m.tracer.export()["spans"]
+            m.shutdown(wait=False)
+            runs = _by_name(spans, "wire_run")
+            assert len(runs) == 3
+            assert all(set(r["args"]) == _WIRE_RUN_ARGS for r in runs)
+            assert not _by_name(spans, "ring_entry_wait") + _by_name(
+                spans, "ring_stream")
+            assert not {*bucketing.RING_KEYS, "ring_lanes"} & set(t)

@@ -511,6 +511,19 @@ def meshes():
 _CHUNK = 24 * 1024  # a test's frame: several a segment at these lengths
 
 
+def _ring_said(info):
+    """What a ring left on its op's future, less its account of its own
+    time, which is checked here: ``t_first`` a clock reading, every term
+    the lanes' mean and the largest lane's in whole microseconds, none
+    negative, the mean not above the largest."""
+    info = dict(info)
+    assert isinstance(info.pop("t_first"), float)
+    for term in pg_mod._RING_TERMS:
+        mean, most = info.pop(term + "_us"), info.pop(term + "_us_max")
+        assert isinstance(mean, int) and 0 <= mean <= most, (term, mean, most)
+    return info
+
+
 def _ring_len(dtype, length):
     if length == "min":  # exactly the ring's threshold
         return pg_mod._RING_MIN_BYTES // np.dtype(dtype).itemsize
@@ -550,8 +563,8 @@ class TestRingInPlaceAndStreamed:
         for rank, (out, info) in enumerate(outs):
             assert out.dtype == want.dtype and out.shape == want.shape
             assert np.array_equal(_bits(out), _bits(want)), rank
-            assert info == {"inplace": int(donate and keeps_dtype),
-                            "chunks": frames, "lanes": 1}
+            assert _ring_said(info) == {"inplace": int(donate and keeps_dtype),
+                                        "chunks": frames, "lanes": 1}
             if donate and keeps_dtype:
                 assert out is ins[rank]
             else:  # left as it was, and the result is memory of its own
@@ -764,8 +777,8 @@ class TestRingLanes:
         for rank, (out, info) in enumerate(run_parallel(world, step)):
             assert out.dtype == want.dtype and out.shape == want.shape
             assert np.array_equal(_bits(out), _bits(want)), rank
-            assert info == {"inplace": int(donate and keeps_dtype),
-                            "chunks": frames, "lanes": lanes}
+            assert _ring_said(info) == {"inplace": int(donate and keeps_dtype),
+                                        "chunks": frames, "lanes": lanes}
             if donate and keeps_dtype:
                 assert out is ins[rank]
             else:
@@ -931,12 +944,211 @@ class TestRingLanes:
         _wait_no_ring_threads(before)
 
 
+def _timed_ring(pgs, vals, before=lambda rank: None):
+    """One donated SUM through the ring on every rank at once (a barrier,
+    then ``before(rank)``): by rank, the ring's account in seconds, with
+    ``entry`` (the op's start on the dispatch thread to ``t_first``) and
+    ``stream`` (from there to the op's end)."""
+    gate = threading.Barrier(len(pgs))
+
+    def step(rank):
+        gate.wait(30)
+        before(rank)
+        fut = pgs[rank].allreduce(
+            [vals[rank].copy()], ReduceOp.SUM, donate=True).get_future()
+        fut.wait(30)
+        _, t_run0, t_run1 = fut.stamps
+        _ring_said(fut.ring)
+        said = {k[:-3]: v / 1e6 for k, v in fut.ring.items()
+                if k.endswith("_us")}
+        return {**said, "entry": fut.ring["t_first"] - t_run0,
+                "stream": t_run1 - fut.ring["t_first"],
+                "lanes": fut.ring["lanes"], "chunks": fut.ring["chunks"]}
+
+    return run_parallel(len(pgs), step)
+
+
+class TestTheRingTellsItsOwnTime:
+    """What ``_ring_allreduce`` leaves in ``info`` beside ``inplace``,
+    ``chunks`` and ``lanes``: ``t_first`` and the seconds of
+    ``_RING_TERMS`` (every parametrised ring above checks that each is
+    there, whole microseconds, none negative, the lanes' mean not above the
+    largest lane's: ``_ring_said``)."""
+
+    @pytest.mark.parametrize("floor", ["over", "under"])
+    @pytest.mark.parametrize("world", [2, 4])
+    def test_the_receivers_three_terms_are_the_stream(
+        self, lane_meshes, monkeypatch, world, floor
+    ):
+        """Buckets whose segments are over ``_RING_LANE_FLOOR_BYTES`` (four
+        lanes) and under it (lane 0 alone): header waits, payloads and slot
+        waits are what a receiver does from its first header to its last
+        payload, so their sum lies inside the stream and fills most of it
+        (what is missing is Python between frames and the op's end)."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", 256 * 1024)
+        n = 3_000_001
+        seg_bytes = -(-n // world) * 4
+        monkeypatch.setattr(pg_mod, "_RING_LANE_FLOOR_BYTES",
+                            seg_bytes if floor == "over" else seg_bytes + 1)
+        pgs = lane_meshes(world, 4)
+        vals = _ring_values(np.float32, n, world)
+        for said in _timed_ring(pgs, vals):
+            assert said["lanes"] == (4 if floor == "over" else 1)
+            got = said["recv_wait"] + said["recv"] + said["slot_wait"]
+            assert 0.6 * said["recv_span"] <= got <= said["recv_span"] + 1e-5
+            assert 0.5 * said["stream"] <= said["recv_span"] <= (
+                said["stream"] + 1e-5), said
+            # the fold and the writer wait and work inside the op too
+            assert said["arrive_wait"] + said["fold"] <= (
+                said["entry"] + said["stream"] + 1e-4)
+            assert said["handoff"] <= (said["slot_wait"] + said["arrive_wait"]
+                                       + said["ready_wait"] + 1e-4)
+
+    @pytest.mark.parametrize("world", [2, 4])
+    def test_a_late_rank_is_an_entry_wait_at_its_right_neighbour(
+        self, lane_meshes, monkeypatch, world
+    ):
+        """Rank 1 enters 0.2 s late. Its own first header is there at once;
+        its right neighbour has nothing until it enters: an entry wait, and
+        a stream that is the ring with everyone present. At a world of four
+        the other two get a first frame from a neighbour that was on time
+        and then starve at the next hop: a header wait inside the stream."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        pgs = lane_meshes(world, 1)
+        vals = _ring_values(np.float32, 250_007, world)
+        _timed_ring(pgs, vals)  # warm: scratch, threads
+        on_time = _timed_ring(pgs, vals)
+        late = _timed_ring(
+            pgs, vals, lambda rank: time.sleep(0.2) if rank == 1 else None)
+        assert max(s["entry"] + s["stream"] for s in on_time) < 0.15
+        assert late[1]["entry"] < 0.1
+        right = late[2 % world]
+        assert 0.15 < right["entry"] < 0.4
+        assert right["stream"] < 0.15 and right["recv_wait"] < 0.15
+        for rank in set(range(world)) - {1, 2 % world}:
+            assert late[rank]["entry"] < 0.1
+            assert late[rank]["recv_wait"] > 0.1
+            assert late[rank]["stream"] > 0.15
+
+    def test_a_slow_fold_is_fold_here_and_a_header_wait_to_the_right(
+        self, lane_meshes, monkeypatch
+    ):
+        """Rank 1's folds take 5 ms more each: its ``fold`` holds them, and
+        rank 2, whose next hop's frames are the ones rank 1 has to fold
+        first, waits for headers that long."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        world = 3
+        pgs = lane_meshes(world, 1)
+        vals = _ring_values(np.float32, 250_007, world)
+        _timed_ring(pgs, vals)
+        plain = _timed_ring(pgs, vals)
+        fold = pg_mod._fold
+
+        def slow_fold(op, dst, src):
+            if threading.current_thread().name.endswith("_r1"):
+                time.sleep(0.005)
+            fold(op, dst, src)
+
+        monkeypatch.setattr(pg_mod, "_fold", slow_fold)
+        slow = _timed_ring(pgs, vals)
+        added = (world - 1) * slow[1]["chunks"] * 0.005
+        assert slow[1]["fold"] >= plain[1]["fold"] + 0.9 * added
+        assert slow[0]["fold"] < 0.5 * added and slow[2]["fold"] < 0.5 * added
+        assert slow[2]["recv_wait"] >= plain[2]["recv_wait"] + 0.4 * added
+        assert slow[1]["recv_wait"] < slow[2]["recv_wait"]
+
+    def test_several_passes_an_op_add_up_behind_the_first_t_first(
+        self, lane_meshes, monkeypatch
+    ):
+        """Leaves of two dtypes are two passes: the terms add, the first
+        pass's first header is the op's ``t_first``, and the second pass's
+        first wait is a header wait like any other."""
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        world = 2
+        pgs = lane_meshes(world, 1)
+        seen = []
+        ring_pass = pg_mod._ring_pass
+
+        def watched(comm, parts, op):
+            out = ring_pass(comm, parts, op)
+            seen.append((comm.rank, parts[0].dtype.name, out[2]))
+            return out
+
+        monkeypatch.setattr(pg_mod, "_ring_pass", watched)
+
+        def step(rank):
+            fut = pgs[rank].allreduce(
+                [np.ones(60_001, np.float32), np.ones(50_001, np.int32)],
+                ReduceOp.SUM).get_future()
+            fut.wait(30)
+            return fut.ring
+
+        for rank, info in enumerate(run_parallel(world, step)):
+            assert _ring_said(info)["chunks"] == sum(
+                -(-(-(-n // world)) * 4 // _CHUNK) for n in (60_001, 50_001))
+            mine = [c for r, _, c in seen if r == rank]
+            assert [d for r, d, _ in seen if r == rank] == ["float32", "int32"]
+            (first,), (second,) = mine  # one lane a pass
+            assert info["t_first"] == first["first_at"] < second["first_at"]
+            assert info["recv_us"] == int(
+                (first["recv"] + second["recv"]) * 1e6)
+            assert info["recv_wait_us"] == int(
+                (first["hdr_wait"] - first["first_wait"]
+                 + second["hdr_wait"]) * 1e6)
+
+    @pytest.mark.parametrize("path", ["mesh_exchange", "compressed_ring",
+                                      "failed_ring"])
+    def test_what_is_no_plain_ring_that_ended_well_leaves_no_account(
+        self, store, monkeypatch, path
+    ):
+        from torchft_tpu.ops.quantization import compress_bucket
+
+        monkeypatch.setattr(pg_mod, "_RING_CHUNK_BYTES", _CHUNK)
+        world = 2
+        pgs = make_pgs(store, world, timeout=2.0, prefix="noacct_" + path)
+        x = np.ones(100_003, np.float32)
+        if path == "mesh_exchange":
+            monkeypatch.setattr(pg_mod, "_RING_MIN_BYTES", 1 << 30)
+        elif path == "failed_ring":
+            comm = pgs[1]._gen.comm
+            recv, seen = comm.recv_raw_into, []
+
+            def dying_recv(peer, out, lane=0):
+                seen.append(peer)
+                if len(seen) == 3:
+                    pgs[1].abort()
+                return recv(peer, out, lane)
+
+            comm.recv_raw_into = dying_recv
+
+        def step(rank):
+            arrays = ([compress_bucket(x, "fp8")]
+                      if path == "compressed_ring" else [x.copy()])
+            fut = pgs[rank].allreduce(arrays, ReduceOp.SUM).get_future()
+            if path == "failed_ring":
+                with pytest.raises(Exception):
+                    fut.wait(10)
+                assert getattr(fut, "stamps", None) is None
+            else:
+                fut.wait(10)
+            return fut.ring
+
+        for info in run_parallel(world, step):
+            # a failed ring said how it began, never how its time went
+            assert set(info) <= ({"inplace", "chunks", "lanes"}
+                                 if path == "failed_ring" else set())
+        for pg in pgs:
+            pg.shutdown()
+
+
 @pytest.mark.parametrize("lanes", [1, 2])
 def test_the_transport_bench_sweeps_lanes_and_splits_a_step_by_lane(lanes):
     """``transport_bench.py --transport allreduce --elements ... --lanes N``:
     ranks as processes, the bench's own argument sets the module's constant
-    in them; a list of seconds by lane for receive, fold and send, and the
-    ``result_crc`` of the ring-order reference whatever the lanes."""
+    in them; the seconds of each of the ring's own terms as the lanes' mean
+    and the largest lane's (nothing of the library is patched: the numbers
+    are those the ring left on its ops' futures), and the ``result_crc``
+    of the ring-order reference whatever the lanes."""
     import json
     import os
     import subprocess
@@ -958,7 +1170,16 @@ def test_the_transport_bench_sweeps_lanes_and_splits_a_step_by_lane(lanes):
     assert (row["lanes"], row["inplace"], row["native_fold"],
             row["native_frames"]) == (lanes, 1, 1, 1)
     for key in ("recv_s", "fold_s", "send_s"):
-        assert len(row[key]) == lanes and all(v > 0 for v in row[key]), row
+        mean, most = row[key]
+        assert 0 < mean <= most, row
+    for key in ("recv_wait_s", "handoff_s", "slot_wait_s"):
+        mean, most = row[key]
+        assert 0 <= mean <= most, row
+    assert 0 <= row["entry_wait_s"] <= row["ring_s"] <= row["step_s"], row
+    with open(os.path.join(repo, "benchmarks", "transport_bench.py")) as f:
+        bench = f.read()
+    # one clock on the ring, the program's
+    assert "timed(" not in bench and "_Comm." not in bench
     vals = [
         (np.random.default_rng(r).standard_normal(n, np.float32) * 0.01)
         .astype(ml_dtypes.bfloat16) for r in range(world)

@@ -51,3 +51,37 @@ def test_two_process_rss_guard(args):
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["size_mb"] == 256
     assert rec["seconds"] > 0
+
+
+def test_the_ring_split_is_the_rings_own_count_at_four_lanes():
+    """``--transport allreduce --elements``: four ranks, segments over the
+    lane floor, so every rank rides four lanes of three threads. The split
+    it prints is what the ring counted of itself (process_group
+    ._ring_allreduce's ``info``): no ``_Comm`` method, ``_fold`` or
+    ``_accum`` is wrapped in a timer, and the numbers hold the arithmetic
+    the ring's own clock promises."""
+    import json
+
+    bench = os.path.join(REPO, "benchmarks", "transport_bench.py")
+    with open(bench) as f:
+        text = f.read()
+    assert "timed(" not in text and "_Comm." not in text
+    assert "pg_mod._fold =" not in text and "pg_mod._accum =" not in text
+    out = subprocess.run(
+        [sys.executable, bench, "--transport", "allreduce", "--world", "4",
+         "--elements", "4200000,4200000", "--donate", "--iters", "2",
+         "--chunk-mb", "0.25", "--timeout", "120"],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+    )
+    assert out.returncode == 0, (out.stderr or out.stdout)[-2000:]
+    row = json.loads(out.stdout.strip().splitlines()[-1])
+    assert (row["lanes"], row["inplace"], row["native_frames"]) == (4, 1, 1)
+    for key in ("recv_wait_s", "recv_s", "fold_s", "send_s", "handoff_s",
+                "slot_wait_s", "recv_span_s"):
+        mean, most = row[key]
+        assert 0 <= mean <= most, (key, row)
+    assert min(row["recv_s"][0], row["fold_s"][0], row["send_s"][0]) > 0
+    # every number is the slowest rank's median, so sums hold to a margin
+    assert row["entry_wait_s"] <= row["ring_s"] <= row["step_s"] * 2 + 0.01
+    assert row["recv_span_s"][1] <= row["ring_s"] * 1.5 + 0.01

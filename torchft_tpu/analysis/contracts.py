@@ -53,10 +53,18 @@ DECLARED_TIMINGS: Dict[str, str] = {
         "resolved to the staging buffer it was given, over all of them"
     ),
     "ring_lanes": (
-        "connections to a ring neighbour that the step's last host ring "
-        "rode: process_group._RING_LANES, or 1 under its floor, at a world "
-        "of one and where the native fold is missing"
+        "fewest connections to a ring neighbour that any host ring of the "
+        "step rode: process_group._RING_LANES, or 1 under its floor, at a "
+        "world of one and where the native fold is missing"
     ),
+    # the plain host ring's own account, summed over the step's runs
+    # (bucketing.RING_KEYS; the five after the first are the lanes' means)
+    "ring_entry_wait_s": "run start on the dispatch thread to first peer byte",
+    "ring_recv_wait_s": "receivers blocked for a frame's header after that",
+    "ring_recv_s": "receivers taking payloads off their sockets",
+    "ring_fold_s": "folds adding a received frame into its segment",
+    "ring_send_s": "writers inside send_raw (back-pressure included)",
+    "ring_handoff_s": "a lane's threads waiting on each other past the frame",
     "collective_reroute": "cumulative mid-collective link reroutes",
     # control plane (two-level)
     "via_aggregator": "1 when control RPCs ride the pod aggregator",

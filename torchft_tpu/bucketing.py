@@ -1115,6 +1115,16 @@ def _pipeline_overlap_stats(marks: List[Dict[str, Any]]) -> Dict[str, float]:
     }
 
 
+# timings() keys of a step whose ops rode the plain host ring, each summed
+# over the step's runs: the wait from a run's start on the PG's dispatch
+# thread until the first peer byte, and the lanes' mean seconds blocked for
+# a header after it, copying payloads in, adding, inside the send, and in
+# the hand-offs between a lane's three threads. Absent (with ``ring_lanes``)
+# from a step in which no ring ran: begin_step drops them.
+RING_KEYS = ("ring_entry_wait_s", "ring_recv_wait_s", "ring_recv_s",
+             "ring_fold_s", "ring_send_s", "ring_handoff_s")
+
+
 class _StepTally:
     """What the allreduces of ONE step add up to (a step runs from one
     :meth:`BucketPipeline.begin_step` to the next: ``Manager.start_quorum``
@@ -1133,7 +1143,11 @@ class _StepTally:
         self.stage_sums: Dict[str, float] = {}
         self.hidden_s = 0.0
         self.from_device = self.passed_through = 0
-        self.ring_lanes = 0  # of the newest ring among them; 0: none ran
+        self.ring_lanes = 0  # the fewest any ring among them rode; 0: none ran
+        # what their rings said of their own time (process_group
+        # ._ring_allreduce's account), summed over the runs: RING_KEYS'
+        # seconds, the lanes' means
+        self.ring_s: Dict[str, float] = {}
         # seconds inside the landings' h2d and divide spans, and the part
         # of them before their own op's last fetch had ended
         self.land_s = self.land_under_fetch_s = 0.0
@@ -1293,15 +1307,16 @@ class BucketPipeline:
     the staging thread
     ``stage_pool_hit_share``, ``d2h_under_backward_share``,
     ``d2h_concurrency`` and ``d2h_gb_s``; the stage
-    sums, ``wire_passthrough_share`` and ``land_under_fetch_share`` from
-    :meth:`record_timings`."""
+    sums, ``wire_passthrough_share``, ``land_under_fetch_share``,
+    ``ring_lanes`` and ``RING_KEYS`` from :meth:`record_timings` (a value of
+    None, at :meth:`begin_step`: the key is gone until it is said again)."""
 
     def __init__(
         self,
         pg: Any,
         tracer: Any,
         pool: BufferPool,
-        on_timings: Callable[[Dict[str, float]], None] = lambda stats: None,
+        on_timings: Callable[[Dict[str, Any]], None] = lambda stats: None,
     ) -> None:
         self._pg = pg
         self._tracer = tracer
@@ -1355,8 +1370,11 @@ class BucketPipeline:
 
     def begin_step(self) -> None:
         """A new step: the next op is the step's segment 0, and what
-        ``on_timings`` hears from here on adds up over this step's ops."""
+        ``on_timings`` hears from here on adds up over this step's ops. The
+        ring's keys are the step's own: None takes a key away until a ring
+        of this step says it again."""
         self._tally = _StepTally()
+        self._on_timings(dict.fromkeys((*RING_KEYS, "ring_lanes")))
 
     def next_segment(self) -> int:
         """Count one more allreduce of this step (``allreduce_ops``) and
@@ -1869,14 +1887,25 @@ class BucketPipeline:
                 # the wire
                 stats["wire_passthrough_share"] = (
                     tally.passed_through / tally.from_device)
-            # the connections to a ring neighbour that the step's last ring
-            # rode (process_group._RING_LANES; 1 under its floor, at a
-            # world of one, or where the native fold is missing)
+            # the fewest connections to a ring neighbour that any ring of
+            # the step rode (process_group._RING_LANES; 1 under its floor,
+            # at a world of one, or where the native fold is missing)
             lanes = [r["lanes"] for r in op.wire_rings if "lanes" in r]
             if lanes:
-                tally.ring_lanes = lanes[-1]
+                tally.ring_lanes = min(tally.ring_lanes or lanes[0], *lanes)
             if tally.ring_lanes:
                 stats["ring_lanes"] = float(tally.ring_lanes)
+            # the rings' own account, where a plain ring ran: the wait from
+            # the run's start to its first peer byte, and the ring's terms
+            for run, ring in zip(op.wire_runs, op.wire_rings):
+                if run is None or "t_first" not in ring:
+                    continue
+                for key in RING_KEYS:
+                    term = key[len("ring_"):-len("_s")]
+                    tally.ring_s[key] = tally.ring_s.get(key, 0.0) + (
+                        ring["t_first"] - run[1] if term == "entry_wait"
+                        else ring[term + "_us"] / 1e6)
+            stats.update(tally.ring_s)
         self._on_timings(stats)
         for i, mark in enumerate(op.marks):
             for name in ("pack", "wire", "unpack"):
@@ -1898,13 +1927,38 @@ class BucketPipeline:
                 # many frames a hop); the time the op sat in its queue
                 # behind earlier buckets is an arg
                 t_enq, t_run0, t_run1 = run
+                ring = dict(op.wire_rings[i])
+                t_first = ring.pop("t_first", None)
+                account = {k: ring.pop(k) for k in list(ring)
+                           if k.endswith(("_us", "_us_max"))}
+                run_id = self._tracer.new_id() if t_first is not None else None
                 self._tracer.record_rel(
-                    "wire_run", "allreduce", t_run0, t_run1,
+                    "wire_run", "allreduce", t_run0, t_run1, id=run_id,
                     parent=op.stage_ids[i]["wire"], bucket=i,
                     segment=op.segment, bytes=op.bucket_bytes[i],
                     world=self._pg.size(),
                     queued_us=int((t_run0 - t_enq) * 1e6),
-                    **op.wire_rings[i],
+                    **ring,
+                )
+                if t_first is None:
+                    continue
+                # a plain ring's wire_run in two, from the ring's own
+                # stamps: until the first peer byte was here (the left
+                # neighbour had not entered), and from then to the end,
+                # with where the lanes' threads spent it (mean and largest
+                # lane, microseconds) and the rate the bytes imply
+                self._tracer.record_rel(
+                    "ring_entry_wait", "allreduce", t_run0, t_first,
+                    parent=run_id, bucket=i, segment=op.segment,
+                )
+                self._tracer.record_rel(
+                    "ring_stream", "allreduce", t_first, t_run1,
+                    parent=run_id, bucket=i, segment=op.segment,
+                    bytes=op.bucket_bytes[i], lanes=ring["lanes"],
+                    chunks=ring["chunks"],
+                    gb_s=round(op.bucket_bytes[i] / 1e9
+                               / max(t_run1 - t_first, 1e-9), 3),
+                    **account,
                 )
 
     # ------------------------------------------------------- compression

@@ -2116,11 +2116,16 @@ class Manager:
             group_rank=self._group_rank,
         )
 
-    def _update_timings(self, stats: Dict[str, float]) -> None:
+    def _update_timings(self, stats: Dict[str, Optional[float]]) -> None:
         """What the bucket pipeline reports of an allreduce (last values,
-        see :meth:`timings`); its thread, not the caller's."""
+        see :meth:`timings`; None: the key is gone until it is said again);
+        its thread, not the caller's."""
         with self._metrics_lock:
-            self._timings.update(stats)
+            for key, value in stats.items():
+                if value is None:
+                    self._timings.pop(key, None)
+                else:
+                    self._timings[key] = value
 
     def timings(self) -> Dict[str, float]:
         """Per-phase wall-clock of the most recent quorum cycle:
@@ -2157,7 +2162,18 @@ class Manager:
         inside a piece (1.0: one copy at a time; towards
         ``bucketing.FETCH_WIDTH`` when the buckets have pieces enough and
         the process cores enough), and ``d2h_gb_s``: the bytes fetched over
-        them, in GB/s. Keys appear once the phase has run.
+        them, in GB/s. Behind the plain host ring (a world of two or more,
+        buckets over ``process_group._RING_MIN_BYTES``, no compression)
+        the step also carries the ring's own account of its time, each key
+        summed over the step's runs and gone again when the next step
+        begins (``bucketing.RING_KEYS``, with ``ring_lanes``: the fewest
+        lanes any of the step's rings rode): ``ring_entry_wait_s``, from a
+        run's start on the PG's dispatch thread until the first peer byte
+        (the left ring neighbour had not entered), and the lanes' mean
+        seconds in ``ring_recv_wait_s`` (blocked for a frame's header after
+        that), ``ring_recv_s``, ``ring_fold_s``, ``ring_send_s`` and
+        ``ring_handoff_s`` (``docs/observability.md``, span
+        ``allreduce/ring_stream``). Keys appear once the phase has run.
 
         Also carries the CUMULATIVE resilience counters (present from
         construction, never reset): ``heal_attempts`` (initial heal tries

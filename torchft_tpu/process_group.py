@@ -593,10 +593,12 @@ class _Comm:
             for b in (buf if isinstance(buf, list) else [buf])
         ]
 
-    def send_raw(self, peer: int, buf: Any, lane: int = 0) -> None:
+    def send_raw(self, peer: int, buf: Any, lane: int = 0) -> float:
         """Frame a raw buffer (no pickle, no concat copy): length header,
         then the bytes straight from the caller's memory. ``lane``: which
-        of the connections to a ring neighbour."""
+        of the connections to a ring neighbour. Returns the seconds the
+        frame spent going into the link, the same it adds to
+        ``wire_busy_s`` (the ring's ``send``: one interval, one clock)."""
         runs = self._frame_bytes(buf)
         length = sum(a.size for a in runs)
         sock = self.lane_socks[lane][peer]
@@ -608,12 +610,17 @@ class _Comm:
                 _send_all(sock, a)
             busy = time.perf_counter() - t0
         self._count(sent=length + _HDR.size, busy_s=busy)
+        return busy
 
-    def recv_raw_into(self, peer: int, out: Any, lane: int = 0) -> None:
+    def recv_raw_into(self, peer: int, out: Any, lane: int = 0) -> float:
         """Receive one frame directly into a writable buffer, or into a
-        list of them in turn (zero staging copies on the receive side)."""
+        list of them in turn (zero staging copies on the receive side).
+        Returns the ``time.perf_counter()`` at which the frame's header
+        was in: before it the peer had nothing to send, after it the
+        payload is being copied (the ring tells the two apart)."""
         sock = self.lane_socks[lane][peer]
         (length,) = _HDR.unpack(_recv_exact(sock, _HDR.size))
+        t_header = time.perf_counter()
         runs = self._frame_bytes(out)
         if length != sum(a.size for a in runs):
             raise ValueError(
@@ -623,6 +630,7 @@ class _Comm:
         for a in runs:
             _recv_all(sock, a)
         self._count(recv=length + _HDR.size)
+        return t_header
 
     def ring_scratch(self, lanes: int, nbytes: int) -> np.ndarray:
         """This comm's receive scratch, ``[>= lanes, 2, >= nbytes]`` bytes:
@@ -822,16 +830,19 @@ class _RingFailed(ConnectionError):
 
 class _RingCount:
     """A count of the frames of a ring lane that one of its threads is done
-    with, in receive order, for another to wait on."""
+    with, in receive order, for another to wait on: the moment each was
+    done, as the thread that did it stamped it."""
 
     def __init__(self) -> None:
         self._cond = threading.Condition()
-        self._done = 0
+        self._at: List[float] = []
         self._failed = False
 
-    def advance(self) -> None:
+    def advance(self, at: float) -> None:
+        """One more frame is done, as of ``at``: the ``perf_counter()`` its
+        thread read when the work ended (no clock is read here)."""
         with self._cond:
-            self._done += 1
+            self._at.append(at)
             self._cond.notify_all()
 
     def fail(self) -> None:
@@ -839,24 +850,30 @@ class _RingCount:
             self._failed = True
             self._cond.notify_all()
 
-    def wait_past(self, frame: int) -> None:
+    def wait_past(self, frame: int) -> float:
         """Until frame number ``frame`` is done. A receiving thread always
         ends, by finishing or by its socket's timeout or the op's watchdog
         closing the socket, and fails every count when it fails: this needs
-        no clock."""
+        no clock. Returns the hand-off: where it had to block, the seconds
+        from that frame's ``advance`` stamp until this thread ran again (the
+        lock, the notify, the wake-up and the interpreter's lock between two
+        threads of a lane); 0.0 where the frame was done already."""
         with self._cond:
-            while self._done <= frame and not self._failed:
+            blocked = False
+            while len(self._at) <= frame and not self._failed:
+                blocked = True
                 self._cond.wait()
             if self._failed:
                 raise _RingFailed("the ring failed on another thread")
+            return time.perf_counter() - self._at[frame] if blocked else 0.0
 
 
 def _ring_pass(
     comm: "_Comm", parts: List[np.ndarray], op: ReduceOp
-) -> Tuple[int, int]:
+) -> Tuple[int, int, List[Dict[str, float]]]:
     """Ring reduce-scatter + allgather over ``parts``, 1-D arrays of one
     dtype that are reduced where they lie, as if laid end to end. Returns
-    the frames of a hop and the lanes they rode.
+    the frames of a hop, the lanes they rode, and each lane's clock.
 
     The ``world`` segments are ranges of that concatenation at multiples of
     ``ceil(len / world)`` (the last ones shorter, or empty). Hop ``h`` of
@@ -875,6 +892,22 @@ def _ring_pass(
     the accumulate the wire, and the lanes one another. Lanes share nothing
     but failure: the thread that fails fails every lane's counts, and this
     thread raises once every other has ended.
+
+    A lane's clock is where its three threads spent the pass, in seconds,
+    each a plain float that the one thread which owns it adds up around
+    what it does anyway and leaves in the lane's dict as it ends. The
+    receiver: ``hdr_wait`` blocked for a frame's header (the left neighbour
+    had nothing to send), of which ``first_wait`` for the lane's first,
+    which was in at ``first_at`` (``perf_counter()``; absent: the lane had
+    no frame) as its last payload was at ``last_at``; ``recv`` copying the
+    payload; ``slot_wait`` until its scratch chunk was folded. The fold:
+    ``arrive_wait`` with nothing arrived, ``fold`` inside :func:`_fold`.
+    The writer: ``ready_wait`` until the frame was reduced or received
+    here, ``send`` inside ``send_raw``. ``handoff_recv`` / ``_fold`` /
+    ``_send``: of each thread's waits on a count, the part after the frame
+    WAS done (:meth:`_RingCount.wait_past`): what a lane pays for being
+    three threads. Read only after a pass that ended well: a failed one
+    raises.
     """
     world, rank = comm.world, comm.rank
     right, left = (rank + 1) % world, (rank - 1) % world
@@ -905,6 +938,7 @@ def _ring_pass(
     scratch = comm.ring_scratch(lanes, step * dtype.itemsize)
     hops = 2 * (world - 1)
     counts: List[_RingCount] = []
+    clocks: List[Dict[str, float]] = []
 
     def fail_all() -> None:
         for count in counts:  # whoever waits, on whichever lane
@@ -937,33 +971,72 @@ def _ring_pass(
             """Where frame ``k`` of the reduce-scatter waits to be folded."""
             return scratch[lane, k % 2, :(b - a) * dtype.itemsize].view(dtype)
 
+        clock: Dict[str, float] = {}
+        clocks.append(clock)
+        now = time.perf_counter
+
         def receives() -> None:
+            hdr_wait = recv = slot_wait = handoff = 0.0
+            header0: Optional[Tuple[float, float]] = None  # (wait, in at)
+
+            def frame_into(out: Any, since: float) -> float:
+                """One frame off the socket; when it was all here."""
+                nonlocal hdr_wait, recv, header0
+                t_header = comm.recv_raw_into(left, out, **on)
+                t = now()
+                hdr_wait += t_header - since
+                recv += t - t_header
+                if header0 is None:
+                    header0 = (t_header - since, t_header)
+                return t
+
             for k, (a, b) in enumerate(to_fold):
-                if k >= 2:
-                    done.wait_past(k - 2)  # what waited in this chunk is folded
-                comm.recv_raw_into(left, waiting(k, a, b), **on)
-                arrived.advance()
-            done.wait_past(len(to_fold) - 1)
+                t0 = t = now()
+                if k >= 2:  # what waited in this chunk is folded
+                    handoff += done.wait_past(k - 2)
+                    t = now()
+                    slot_wait += t - t0
+                arrived.advance(frame_into(waiting(k, a, b), t))
+            t0 = now()
+            handoff += done.wait_past(len(to_fold) - 1)
+            slot_wait += now() - t0
             for h in range(world - 1, hops):
                 for a, b in recv_frames[h]:
-                    comm.recv_raw_into(left, views(a, b), **on)
-                    done.advance()
+                    done.advance(frame_into(views(a, b), now()))
+            clock.update(hdr_wait=hdr_wait, recv=recv, slot_wait=slot_wait,
+                         handoff_recv=handoff)
+            if header0 is not None:
+                clock["first_wait"], clock["first_at"] = header0
+                clock["last_at"] = now()
 
         def folds() -> None:
+            arrive_wait = fold = handoff = 0.0
             for k, (a, b) in enumerate(to_fold):
-                arrived.wait_past(k)
-                got = waiting(k, a, b)
-                for v in views(a, b):
+                got, into = waiting(k, a, b), views(a, b)
+                t0 = now()
+                handoff += arrived.wait_past(k)
+                t1 = now()
+                for v in into:
                     _fold(op, v, got[:v.size])
                     got = got[v.size:]
-                done.advance()
+                t2 = now()
+                arrive_wait += t1 - t0
+                fold += t2 - t1
+                done.advance(t2)
+            clock.update(arrive_wait=arrive_wait, fold=fold,
+                         handoff_fold=handoff)
 
         def writes() -> None:
+            ready_wait = send = handoff = 0.0
             for h in range(hops):
                 for k, (a, b) in enumerate(mine[(rank - h) % world]):
                     if h:
-                        done.wait_past(first[h - 1] + k)
-                    comm.send_raw(right, views(a, b), **on)
+                        t0 = now()
+                        handoff += done.wait_past(first[h - 1] + k)
+                        ready_wait += now() - t0
+                    send += comm.send_raw(right, views(a, b), **on)
+            clock.update(ready_wait=ready_wait, send=send,
+                         handoff_send=handoff)
 
         return failing(receives), failing(folds), failing(writes)
 
@@ -991,7 +1064,37 @@ def _ring_pass(
         # the cause, not a thread that was only told of it
         raise next((e for e in errors if not isinstance(e, _RingFailed)),
                    errors[0])
-    return len(seg_frames[(rank - 1) % world]), lanes
+    return len(seg_frames[(rank - 1) % world]), lanes, clocks
+
+
+# What a lane's threads do with a pass, by thread: the receiver (the header
+# waits after the pass's first header, the payloads, the waits for a scratch
+# chunk, and the interval these three lie in, from the first moment it
+# counts to its last payload: what they fall short of it is Python between
+# frames), the fold (nothing arrived, the add), the writer (nothing ready,
+# the link), and the hand-offs between the three.
+_RING_TERMS = ("recv_wait", "recv", "slot_wait", "recv_span", "arrive_wait",
+               "fold", "ready_wait", "send", "handoff")
+
+
+def _ring_terms(
+    clock: Dict[str, float], t_first: Optional[float]
+) -> Dict[str, float]:
+    """One lane's clock of one pass (:func:`_ring_pass`) as the seconds of
+    ``_RING_TERMS``. Of the lane's first header wait only what came after
+    ``t_first`` is a ``recv_wait``: before that moment nothing had reached
+    this rank on any lane, and the wait is the op's entry wait, which
+    ``t_first`` itself tells."""
+    terms = {term: clock.get(term, 0.0) for term in _RING_TERMS}
+    terms["recv_wait"] = clock["hdr_wait"]
+    if "first_at" in clock:
+        began = clock["first_at"] - clock["first_wait"]
+        entry = max(0.0, min(t_first, clock["first_at"]) - began)
+        terms["recv_wait"] -= entry
+        terms["recv_span"] = clock["last_at"] - began - entry
+    terms["handoff"] = (clock["handoff_recv"] + clock["handoff_fold"]
+                        + clock["handoff_send"])
+    return terms
 
 
 def _ring_allreduce(
@@ -999,7 +1102,7 @@ def _ring_allreduce(
     leaves: List[np.ndarray],
     op: ReduceOp,
     donate: bool = False,
-    info: Optional[Dict[str, int]] = None,
+    info: Optional[Dict[str, Any]] = None,
 ) -> List[np.ndarray]:
     """Bandwidth-optimal allreduce: ring reduce-scatter + ring allgather
     (:func:`_ring_pass`), in the leaves' own memory where the caller gave
@@ -1019,7 +1122,13 @@ def _ring_allreduce(
     ``_reduce_np``'s semantics: accumulate in the input dtype, AVG divides
     by world at the end. ``info`` receives ``inplace`` (1 when no leaf was
     copied), ``chunks`` (frames a hop) and ``lanes`` (the connections to a
-    neighbour that the last dtype's frames rode).
+    neighbour that the last dtype's frames rode), and once every pass has
+    ended well the ring's own account of its time (:func:`_ring_terms`):
+    ``t_first``, the ``perf_counter()`` at which the first header of the
+    first pass was in on any lane (before it no peer byte had reached this
+    rank), and for each of ``_RING_TERMS`` the mean over the lanes
+    (``<term>_us``) and the largest lane's (``<term>_us_max``), whole
+    microseconds, summed over the passes.
     """
     out: List[np.ndarray] = []
     for a in leaves:
@@ -1038,16 +1147,30 @@ def _ring_allreduce(
     groups: Dict[Any, List[int]] = {}
     for i, a in enumerate(out):
         groups.setdefault(a.dtype, []).append(i)
+    t_first: Optional[float] = None
+    spent = {term: [0.0, 0.0] for term in _RING_TERMS}  # lanes' mean, max
     for _dtype, idxs in sorted(groups.items(), key=lambda kv: str(kv[0])):
-        chunks, info["lanes"] = _ring_pass(
+        chunks, info["lanes"], clocks = _ring_pass(
             comm, [out[i].reshape(-1) for i in idxs], op)
         info["chunks"] += chunks
+        if t_first is None:  # a later pass's first wait is a recv_wait
+            t_first = min((c["first_at"] for c in clocks if "first_at" in c),
+                          default=None)
+        by_lane = [_ring_terms(c, t_first) for c in clocks]
+        for term, sums in spent.items():
+            sums[0] += sum(lane[term] for lane in by_lane) / len(by_lane)
+            sums[1] += max(lane[term] for lane in by_lane)
         if op == ReduceOp.AVG:
             for i in idxs:
                 if np.issubdtype(out[i].dtype, np.integer):
                     out[i] = out[i] / comm.world  # float, as _reduce_np
                 else:
                     out[i] /= comm.world
+    if t_first is not None:
+        info["t_first"] = t_first
+        for term, (mean, most) in spent.items():
+            info[term + "_us"] = int(mean * 1e6)
+            info[term + "_us_max"] = int(most * 1e6)
     return out
 
 
@@ -1810,7 +1933,7 @@ class ProcessGroupHost(ProcessGroup):
 
     def _submit(self, fn: Callable[["_Comm"], Any], name: str = "op",
                 mode: str = "collective",
-                ring: Optional[Dict[str, int]] = None) -> Work:
+                ring: Optional[Dict[str, Any]] = None) -> Work:
         _fr.recorder.record(
             "collective", op=name, rank=self._rank, world=self._world
         )
@@ -1834,7 +1957,12 @@ class ProcessGroupHost(ProcessGroup):
         from torchft_tpu.ops.quantization import CompressedWire
 
         host = [_to_host(a) for a in arrays]
-        info: Dict[str, int] = {}  # filled by the ring, if one runs
+        # filled by the plain ring, if one runs (_ring_allreduce: inplace,
+        # chunks, lanes, and its account of its own time). A world of one
+        # says ``lanes`` alone; the mesh exchange under _RING_MIN_BYTES and
+        # the compressed ring say nothing, and ProcessGroupBabyHost's ring
+        # runs in its child, whose ``info`` stays there
+        info: Dict[str, Any] = {}
 
         def _run(comm):
             # compressed buckets always ride the self-healing ring: it is

@@ -1,8 +1,9 @@
-"""A share's three grouped products alone on the chip (ISSUE 51, step 0), at
-the two share cells' shapes, bf16, tiles as ``models/moe._grouped_matmul``
-sets them: Mellum's buffer (131,072 rows x 2304, 16 matrices of 2304 x 896,
-an even share of 65,536 pairs) and Ling's (49,152 x 2560, 16 of 2560 x 768,
-even share 8,192).
+"""A share's grouped products (ISSUE 51, step 0) and its row moves (ISSUE 55,
+step 0) alone on the chip, at the three share cells' shapes, bf16, tiles as
+``models/moe._grouped_matmul`` sets them: Mellum's buffer (131,072 rows x
+2304, 16 matrices of 2304 x 896, an even share of 65,536 pairs), Ling's
+(49,152 x 2560, 16 of 2560 x 768, even share 8,192) and Nemotron's (24,576 x
+2688, 8 ungated experts of 2688 x 1856, even share 6,144).
 
 Part 1, ``products``: ``silu(rows @ w_gate) * (rows @ w_up) @ w_down`` forward
 and forward + backward (the rows' and the three stacks' cotangents: nine
@@ -21,24 +22,44 @@ Expected: kernel seconds proportional to the visited tiles, that is to
 ``load / room`` plus at most one tile of 512 rows an expert.
 
 Part 2, ``block``: ``moe._share_ffn`` itself (route, gather, products, select,
-scatter-add) under ``jax.checkpoint``, value and every gradient, with
-``_share_sizes`` as it is and replaced by the parent's padded sizes: seconds a
-call, whether everything that leaves the block is finite, and each leaf's
-largest difference between the two over the leaf's largest magnitude (0.0:
+scatter-add) under ``jax.checkpoint``, value and every gradient, as it is
+(``true``), with ``_share_sizes`` replaced by PR 50's padded sizes (``padded``)
+and with the row moves as PR 51 left them, one gather and one scatter-add of
+XLA's over every row of the buffer (``whole``): seconds a call, whether
+everything that leaves the block is finite, and each leaf's largest
+difference from the block as it is over the leaf's largest magnitude (0.0:
 equal bit for bit).
 
 Part 3, ``loads <cell> <seed> ...``: what a share cell's kernels are given
 under each seed: the cell's own model and batch as ``chipbench/jobs/bare.py``
 makes them from ``--seed``, one forward pass with the stats: ``held_pair_share``
-(over T*k), ``visited_row_share`` (of the buffer) and ``load_max_over_mean``.
-A bare run prints none of them, and since PR 51 the step's time follows them.
+(over T*k), ``visited_row_share`` and ``moved_row_share`` (of the buffer) and
+``load_max_over_mean``. A bare run prints none of them, and since PR 51 the
+step's time follows them.
 
-    chiprun -- python3 benchmarks/share_gmm_check.py [mellum|ling ...]
+Part 4, ``rows``: the two row moves alone (the dispatch's gather into the
+buffer, the combine's weighed scatter-add out of it, and both pullbacks; no
+product between them), value and the gradients of the tokens and the weights,
+at loads of 0, 0.7, 1.0 and 1.5 x the even share and at the room exactly: as
+XLA's one gather and one scatter-add over every row (``whole``: PR 51's form)
+and as ``moe._share_take`` / ``moe._share_add`` (the two gathers, the
+dispatch's and the combine's pullback's, loops over row tiles that end with
+the pairs; the two scatter-adds XLA's own over every row) at each of ``TILES``:
+wall and device-busy seconds a call, the tiles moved, and the largest
+difference from the whole form (0.0: the same sums in the same order).
+Expected: ``whole`` flat in the load, the looped gathers linear in the moved
+tiles over a small floor. (All four moves as loops, the first form tried, were
+linear too, but a tile's scatter-add costs 2.3 x a row of XLA's sorted one over
+the whole buffer; a ``lax.switch`` over static prefixes grows the program by
+0.6 GB: PERF.md section 6, PR 55.)
+
+    chiprun -- python3 benchmarks/share_gmm_check.py [products|block|rows ...] [<shape> ...]
     chiprun -- python3 benchmarks/share_gmm_check.py loads <cell> <seed> ...
 
 Writes one JSON line a measurement; exits 2 without a TPU.
 """
 
+import contextlib
 import json
 import os
 import sys
@@ -53,13 +74,14 @@ import numpy as np  # noqa: E402
 
 from torchft_tpu.models import moe  # noqa: E402
 
-HELD = 16
-# name -> (tokens, k, experts of the router, dim, expert width, share_room)
+# name -> (tokens, k, experts of the router, held, dim, expert width, share_room, form)
 SHAPES = {
-    "mellum": (32768, 8, 64, 2304, 896, 2.0),
-    "ling": (32768, 8, 512, 2560, 768, 6.0),
+    "mellum": (32768, 8, 64, 16, 2304, 896, 2.0, "swiglu"),
+    "ling": (32768, 8, 512, 16, 2560, 768, 6.0, "swiglu"),
+    "nemotron": (16384, 6, 128, 8, 2688, 1856, 4.0, "relu2"),
 }
 LOADS = (0.0, 0.7, 1.0, 1.5)
+TILES = (512, 2048)  # ``rows``: the gathers' row tile (``moe.MOVE_TILE``)
 CALLS = 5
 
 
@@ -73,11 +95,35 @@ def padded_sizes(counts, rows_n):
     return jnp.diff(ends, prepend=0).astype(jnp.int32)
 
 
-def draw_counts(rng, pairs):
-    """``pairs`` pairs over the held experts, as a router's first steps spread
-    them: multinomial over near-even probabilities."""
-    p = rng.dirichlet(np.full(HELD, 20.0))
+def draw_counts(rng, pairs, held):
+    """``pairs`` pairs over the ``held`` experts, as a router's first steps
+    spread them: multinomial over near-even probabilities."""
+    p = rng.dirichlet(np.full(held, 20.0))
     return jnp.asarray(rng.multinomial(pairs, p), jnp.int32)
+
+
+def share_of(name):
+    """A shape's (configuration, even share, expert stacks)."""
+    T, k, E, held, d, W, room, act = SHAPES[name]
+    cfg = moe.MoEConfig(num_experts=E, top_k=k, capacity_factor=None, expert_act=act,
+                        held_experts=(0, held), share_room=room)
+    key = jax.random.split(jax.random.PRNGKey(2), 3)
+    stacks = [jax.random.normal(key[0], (held, d, W), jnp.bfloat16) / d ** 0.5,
+              jax.random.normal(key[1], (held, d, W), jnp.bfloat16) / d ** 0.5,
+              jax.random.normal(key[2], (held, W, d), jnp.bfloat16) / W ** 0.5]
+    if act == "relu2":
+        stacks[0] = None  # ungated: no ``w_gate`` anywhere
+    return cfg, T * k * held // E, stacks
+
+
+def draw_idx(rng, name, pairs):
+    """[T, k] experts with ``pairs`` pairs on the held ones, the rest on absent
+    ones: a token's k experts need not differ for the block's arithmetic."""
+    T, k, E, held = SHAPES[name][:4]
+    counts = np.asarray(draw_counts(rng, pairs, held))
+    expert = np.concatenate([np.repeat(np.arange(held), counts),
+                             rng.integers(held, E, T * k - pairs)])
+    return jnp.asarray(rng.permutation(expert).reshape(T, k), jnp.int32)
 
 
 def visited_tiles(sizes, tm=512):
@@ -88,8 +134,8 @@ def visited_tiles(sizes, tm=512):
     return int(np.where(sizes == 0, 0, tiles).sum())
 
 
-def kernel_seconds(fn, args):
-    """Seconds a call in device operations named gmm / tgmm, from a trace."""
+def traced_events(fn, args):
+    """The device's operations (name, start, end in ns) over ``CALLS`` calls."""
     from chipbench import xplane
 
     with tempfile.TemporaryDirectory() as tmp:
@@ -98,7 +144,21 @@ def kernel_seconds(fn, args):
                 out = fn(*args)
             jax.block_until_ready(out)
         (events,) = xplane.read(xplane.find(tmp))["devices"].values()
-    return sum(end - start for name, start, end in events if "gmm" in name) / 1e9 / CALLS
+    return events
+
+
+def kernel_seconds(fn, args):
+    """Seconds a call in device operations named gmm / tgmm, from a trace."""
+    return sum(end - start for name, start, end in traced_events(fn, args)
+               if "gmm" in name) / 1e9 / CALLS
+
+
+def busy_seconds(fn, args):
+    """Seconds a call in which the device ran an operation, from a trace."""
+    from chipbench import xplane
+
+    return sum(end - start for start, end in xplane.busy_intervals(
+        traced_events(fn, args))) / 1e9 / CALLS
 
 
 def wall_seconds(fn, args):
@@ -111,21 +171,16 @@ def wall_seconds(fn, args):
 
 
 def products(name):
-    T, k, E, d, W, room = SHAPES[name]
-    even = T * k * HELD // E
-    cfg = moe.MoEConfig(num_experts=E, top_k=k, capacity_factor=None,
-                        held_experts=(0, HELD), share_room=room)
+    T, held, d = (SHAPES[name][i] for i in (0, 3, 4))
+    cfg, even, (w_gate, w_up, w_down) = share_of(name)
     rows_n = cfg.share_rows(T)
-    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    key = jax.random.split(jax.random.PRNGKey(0), 2)
     rows = jax.random.normal(key[0], (rows_n, d), jnp.bfloat16)
     cot = jax.random.normal(key[1], (rows_n, d), jnp.bfloat16)
-    w_gate = jax.random.normal(key[2], (HELD, d, W), jnp.bfloat16) / d ** 0.5
-    w_up = jax.random.normal(key[3], (HELD, d, W), jnp.bfloat16) / d ** 0.5
-    w_down = jax.random.normal(key[4], (HELD, W, d), jnp.bfloat16) / W ** 0.5
 
     def chain(rows, w_gate, w_up, w_down, sizes):
-        h = jax.nn.silu(moe._grouped_matmul(rows, w_gate, sizes)) * moe._grouped_matmul(
-            rows, w_up, sizes)
+        h = moe._hidden(rows, w_gate, w_up, lambda r, w: moe._grouped_matmul(r, w, sizes),
+                        cfg.expert_act)
         return moe._grouped_matmul(h, w_down, sizes)
 
     def value(rows, w_gate, w_up, w_down, sizes):
@@ -135,10 +190,11 @@ def products(name):
         return jnp.sum((out * cot).astype(jnp.float32))
 
     forward = jax.jit(chain)
-    both = jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3)))
+    both = jax.jit(jax.value_and_grad(value, argnums=(0, 2, 3) if w_gate is None
+                                      else (0, 1, 2, 3)))
     rng = np.random.default_rng(51)
     for load in LOADS:
-        counts = draw_counts(rng, int(load * even))
+        counts = draw_counts(rng, int(load * even), held)
         for kind, sizes in (("padded", padded_sizes(counts, rows_n)),
                             ("true", moe._share_sizes(counts, rows_n))):
             args = (rows, w_gate, w_up, w_down, sizes)
@@ -151,69 +207,141 @@ def products(name):
                      [jnp.all(jnp.isfinite(g.astype(jnp.float32))) for g in grads]))))
 
 
+def whole_take(flat, take, n, tokens):
+    """``moe._share_take`` as PR 51 left the dispatch: XLA's one gather over
+    every row of the buffer, selected after it."""
+    valid = (jnp.arange(take.shape[0]) < n)[:, None]
+    return jnp.where(valid, flat[take], 0)
+
+
+def whole_add(rows, weights, take, n, tokens):
+    """``moe._share_add`` as PR 51 left the combine: every row selected and
+    weighed, XLA's one scatter-add of them all."""
+    valid = (jnp.arange(take.shape[0]) < n)[:, None]
+    return jnp.zeros((tokens, rows.shape[1]), rows.dtype).at[take].add(
+        jnp.where(valid, rows, 0) * weights)
+
+
+@contextlib.contextmanager
+def patched(**fns):
+    """``models/moe.py`` with some of its names bound anew, for one trace."""
+    was = {name: getattr(moe, name) for name in fns}
+    for name, fn in fns.items():
+        setattr(moe, name, fn)
+    try:
+        yield
+    finally:
+        for name, fn in was.items():
+            setattr(moe, name, fn)
+
+
+KINDS = {"true": {}, "padded": {"_share_sizes": padded_sizes},
+         "whole": {"_share_take": whole_take, "_share_add": whole_add}}
+
+
+def worst_diff(got, want):
+    """Each leaf's largest difference over ``want``'s largest magnitude."""
+    worst = {}
+    for leaf in want:
+        a, b = (np.asarray(t[leaf].astype(jnp.float32)) for t in (got, want))
+        worst[leaf] = float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+    return worst
+
+
+def all_finite(tree):
+    return all(bool(jnp.all(jnp.isfinite(v.astype(jnp.float32)))) for v in tree.values())
+
+
 def block(name):
-    T, k, E, d, W, room = SHAPES[name]
-    key = jax.random.split(jax.random.PRNGKey(1), 6)
+    T, k, d, room = (SHAPES[name][i] for i in (0, 1, 4, 6))
+    base, even, stacks = share_of(name)
+    key = jax.random.split(jax.random.PRNGKey(1), 3)
     x = jax.random.normal(key[0], (T, d), jnp.bfloat16)
     cot = jax.random.normal(key[1], (T, d), jnp.bfloat16)
-    w_gate = jax.random.normal(key[2], (HELD, d, W), jnp.bfloat16) / d ** 0.5
-    w_up = jax.random.normal(key[3], (HELD, d, W), jnp.bfloat16) / d ** 0.5
-    w_down = jax.random.normal(key[4], (HELD, W, d), jnp.bfloat16) / W ** 0.5
-    gates = jax.random.uniform(key[5], (T, k), jnp.float32)
-    even = T * k * HELD // E
+    gates = jax.random.uniform(key[2], (T, k), jnp.float32)
     rng = np.random.default_rng(52)
-    base = moe.MoEConfig(num_experts=E, top_k=k, capacity_factor=None,
-                         held_experts=(0, HELD), share_room=room)
     rows_n = base.share_rows(T)
 
     def make():
         @jax.jit
-        def run(x, gates, idx, w_gate, w_up, w_down):
-            def value(x, gates, w_gate, w_up, w_down):
+        def run(x, gates, idx, *stacks):
+            def value(x, gates, *stacks):
                 out, stats = jax.checkpoint(
                     lambda *a: moe._share_ffn(a[0], a[1], idx, base, *a[2:]))(
-                        x, gates, w_gate, w_up, w_down)
+                        x, gates, *stacks)
                 return jnp.sum((out * cot).astype(jnp.float32)), (out, stats)
-            (v, (out, stats)), grads = jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4),
-                                                          has_aux=True)(
-                x, gates, w_gate, w_up, w_down)
-            return {"out": out, "x": grads[0], "gates": grads[1], "w_gate": grads[2],
-                    "w_up": grads[3], "w_down": grads[4]}, stats
+            leaves = ("x", "gates", "w_gate", "w_up", "w_down")
+            at = [i for i, leaf in enumerate((x, gates, *stacks)) if leaf is not None]
+            (v, (out, stats)), grads = jax.value_and_grad(value, argnums=at, has_aux=True)(
+                x, gates, *stacks)
+            return {"out": out, **{leaves[i]: g for i, g in zip(at, grads)}}, stats
         return run
 
+    runs = {}
+    for kind, fns in KINDS.items():
+        with patched(**fns):  # the functions are read at trace time: a jit a kind
+            runs[kind] = make()
+            jax.block_until_ready(runs[kind](x, gates, draw_idx(rng, name, 0), *stacks))
     for load in LOADS + (2.5 * room / 2.0,):  # the last: over the room
-        # ``held`` pairs on the held experts, the rest on absent ones: a
-        # token's k experts need not differ for the block's arithmetic
-        held = min(int(load * even), T * k)
-        counts = np.asarray(draw_counts(rng, held))
-        expert = np.concatenate([np.repeat(np.arange(HELD), counts),
-                                 rng.integers(HELD, E, T * k - held)])
-        idx = jnp.asarray(rng.permutation(expert).reshape(T, k), jnp.int32)
-        args = (x, gates, idx, w_gate, w_up, w_down)
-        got, seconds, gmm_s = {}, {}, {}
-        for kind in ("true", "padded"):
-            was = moe._share_sizes
-            if kind == "padded":
-                moe._share_sizes = padded_sizes
-            try:
-                run = make()  # a fresh jit a kind: the sizes are read at trace time
-                got[kind], stats = jax.block_until_ready(run(*args))
-                seconds[kind] = wall_seconds(run, args)
-                gmm_s[kind] = kernel_seconds(run, args)
-            finally:
-                moe._share_sizes = was
-        worst = {}
-        for leaf in got["true"]:
-            a, b = (np.asarray(got[s][leaf].astype(jnp.float32)) for s in ("true", "padded"))
-            worst[leaf] = float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+        args = (x, gates, draw_idx(rng, name, min(int(load * even), T * k)), *stacks)
+        got = {kind: run(*args) for kind, run in runs.items()}
+        stats = got["true"][1]
         emit(part="block", shape=name, rows=rows_n, even=even, load=load,
              held_pairs=int(stats["held_pairs"]), overflow=int(stats["overflow"]),
-             visited=float(stats["visited"]),
-             true_s=seconds["true"], padded_s=seconds["padded"],
-             true_gmm_s=gmm_s["true"], padded_gmm_s=gmm_s["padded"],
-             finite=all(bool(jnp.all(jnp.isfinite(v.astype(jnp.float32))))
-                        for v in got["true"].values()),
-             rel_diff=worst)
+             visited=float(stats["visited"]), moved=float(stats["moved"]),
+             **{f"{kind}_s": wall_seconds(run, args) for kind, run in runs.items()},
+             **{f"{kind}_gmm_s": kernel_seconds(run, args) for kind, run in runs.items()},
+             finite=all_finite(got["true"][0]),
+             rel_diff=worst_diff(got["true"][0], got["padded"][0]),
+             rel_diff_whole=worst_diff(got["true"][0], got["whole"][0]))
+
+
+def rows(name):
+    T, k, d, room = (SHAPES[name][i] for i in (0, 1, 4, 6))
+    cfg, even, _ = share_of(name)
+    rows_n = cfg.share_rows(T)
+    key = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(key[0], (T, d), jnp.bfloat16)
+    cot = jax.random.normal(key[1], (T, d), jnp.bfloat16)
+    weights = jax.random.uniform(key[2], (rows_n, 1), jnp.float32).astype(jnp.bfloat16)
+    rng = np.random.default_rng(55)
+
+    def make(take_rows, add_rows):
+        @jax.jit
+        def run(x, weights, take, n):
+            def value(x, weights):
+                # the barrier stands where the grouped products do: the buffer
+                # is written before it is read, both ways
+                buf = jax.lax.optimization_barrier(take_rows(x, take, n, T))
+                out = add_rows(buf, weights, take, n, T)
+                return jnp.sum((out * cot).astype(jnp.float32)), out
+            (_, out), (d_x, d_weights) = jax.value_and_grad(value, argnums=(0, 1),
+                                                            has_aux=True)(x, weights)
+            return {"out": out, "x": d_x, "weights": d_weights}
+        return run
+
+    tiles = [t for t in TILES if rows_n % t == 0]
+    # a form's tile is ``moe.MOVE_TILE`` as it stands when the form is traced
+    forms = {"whole": make(whole_take, whole_add),
+             **{t: make(moe._share_take, moe._share_add) for t in tiles}}
+    for load in LOADS + (room,):  # the last: the room exactly
+        idx = draw_idx(rng, name, min(int(load * even), T * k))
+        local = jnp.where(idx.reshape(-1) < cfg.n_held, idx.reshape(-1), cfg.n_held)
+        n = jnp.sum(local < cfg.n_held).astype(jnp.int32)
+        take = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n] // k
+        args = (x, weights, take, n)
+        want = forms["whole"](*args)
+        line = {"whole_s": wall_seconds(forms["whole"], args),
+                "whole_busy_s": busy_seconds(forms["whole"], args)}
+        for t in tiles:
+            with patched(MOVE_TILE=t):
+                got = forms[t](*args)
+                line[f"t{t}"] = {
+                    "s": wall_seconds(forms[t], args), "busy_s": busy_seconds(forms[t], args),
+                    "tiles": int(moe._moved_tiles(n, rows_n)), "of": rows_n // t,
+                    "finite": all_finite(got),
+                    "rel_diff": max(worst_diff(got, want).values())}
+        emit(part="rows", shape=name, rows=rows_n, even=even, load=load, pairs=int(n), **line)
 
 
 def loads(name, seeds):
@@ -235,7 +363,8 @@ def loads(name, seeds):
         stats = stats_of(init(seed % SEEDS), tokens)
         emit(part="loads", cell=name, seed=seed, **{
             k: float(stats[k]) for k in ("held_pair_share", "visited_row_share",
-                                         "load_max_over_mean", "overflow_pairs")})
+                                         "moved_row_share", "load_max_over_mean",
+                                         "overflow_pairs")})
 
 
 def main(argv):
@@ -246,9 +375,15 @@ def main(argv):
     if argv[:1] == ["loads"]:
         loads(argv[1], [int(x) for x in argv[2:]])
         return 0
-    for name in argv or list(SHAPES):
-        products(name)
-        block(name)
+    parts = {"products": products, "block": block, "rows": rows}
+    unknown = [a for a in argv if a not in parts and a not in SHAPES]
+    if unknown:
+        print(f"share_gmm_check: {unknown}: a part of {list(parts)}, a shape of "
+              f"{list(SHAPES)}, or 'loads <cell> <seed> ...'", file=sys.stderr)
+        return 2
+    for name in [a for a in argv if a in SHAPES] or list(SHAPES):
+        for part in [a for a in argv if a in parts] or list(parts):
+            parts[part](name)
     return 0
 
 

@@ -124,9 +124,16 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # program for every kind (``ops/short_conv.py``; at the debug widths its
     # ``jax.numpy`` form, which widens the padded sequence once where Ling's
     # ``widen_late`` widened each shifted view); the loss is PR 51's to the
-    # bit, and jamba_debug and lfm2_debug lower to what they did
-    "ling_debug": ("86bbe1972d8cce99", "dcfa91c7504b6ea3", "0x1.7f8a480000000p+2"),
-    "mellum_debug": ("8823a851ba1a0fa9", "e87bf60393be84ba", "0x1.73ce5a0000000p+2"),
+    # bit, and jamba_debug and lfm2_debug lower to what they did.
+    # Both pinned anew by PR 55: a share's two gathers (the dispatch's, the
+    # combine's pullback's) are loops over row tiles under the held pairs'
+    # count (``moe._share_take``, ``moe._share_add``), and
+    # ``moved_row_share`` stands beside the loss; the losses are the parent's
+    # to the bit, and every other line of this table and ``STAGED_HEAD`` are
+    # as they were: ``_dropless_ffn`` and ``_capacity_ffn`` lower to what
+    # they did
+    "ling_debug": ("edc9d9a918f943e5", "84f4010258aabb3d", "0x1.7f8a480000000p+2"),
+    "mellum_debug": ("d65c6f4355d79a33", "ca31160f100d2593", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),
 }

@@ -39,7 +39,8 @@ def test_presets_stand_in_the_registry_and_model_fns_knows_the_kind():
     assert 5.0 < float(value) < 7.0 and "expert_bias" not in grads
     assert sorted(stats["moe_stats"]) == [
         "moe_bias_moved_share", "moe_groups_hit_mean", "moe_held_pair_share",
-        "moe_load_max_over_mean", "moe_overflow_pairs", "moe_visited_row_share"]
+        "moe_load_max_over_mean", "moe_moved_row_share", "moe_overflow_pairs",
+        "moe_visited_row_share"]
     s = {k: float(v) for k, v in stats["moe_stats"].items()}
     assert s["moe_overflow_pairs"] == 0 and s["moe_groups_hit_mean"] <= 2
     assert 0 < s["moe_visited_row_share"] < 1  # the products leave the free rows out

@@ -21,7 +21,8 @@ def test_ten_committed_steps_under_the_manager_with_a_heal_that_carries_the_bias
         assert s["config"] == "ling_debug" and s["committed"] == 10 and s["discarded"] == 0, s
         assert sorted(s["model_stats"]) == [
             "moe_bias_moved_share", "moe_groups_hit_mean", "moe_held_pair_share",
-            "moe_load_max_over_mean", "moe_overflow_pairs", "moe_visited_row_share"]
+            "moe_load_max_over_mean", "moe_moved_row_share", "moe_overflow_pairs",
+            "moe_visited_row_share"]
         assert all(0 < v < 1 for v in s["model_stats"]["moe_visited_row_share"])
         assert all(v == 0 for v in s["model_stats"]["moe_overflow_pairs"])
         assert all(v <= 2 for v in s["model_stats"]["moe_groups_hit_mean"])

@@ -244,8 +244,8 @@ def test_presets_stand_in_the_registry_and_model_fns_knows_the_kind():
     assert 5.0 < float(value) < 7.0
     assert sorted(stats) == ["attn_stats", "moe_stats"]
     assert sorted(stats["moe_stats"]) == [
-        "moe_held_pair_share", "moe_load_max_over_mean", "moe_overflow_pairs",
-        "moe_visited_row_share"]
+        "moe_held_pair_share", "moe_load_max_over_mean", "moe_moved_row_share",
+        "moe_overflow_pairs", "moe_visited_row_share"]
     s = {k: float(v) for part in stats.values() for k, v in part.items()}
     assert s["moe_overflow_pairs"] == 0 and 0.05 < s["moe_held_pair_share"] < 0.6
     assert 0 < s["moe_visited_row_share"] < 1  # the products leave the free rows out
@@ -286,9 +286,10 @@ def test_remat_loss_chunk_and_replay_work_as_for_the_other_kinds():
 PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 43
     "debug": "b960470496fcaaa9",  # the dense decoder (mistral-7b, internlm2-1.8b)
     "jamba_debug": "f85418fbe0152758",
-    # pinned anew by PR 51 (it holds a share) and by PR 53 (one short convolution
-    # for every kind): tests/test_ling.py's table says what moved
-    "ling_debug": "86bbe1972d8cce99",
+    # pinned anew by PR 51 (it holds a share), by PR 53 (one short convolution
+    # for every kind) and by PR 55 (the share's gathers are loops over row
+    # tiles): tests/test_ling.py's table says what moved
+    "ling_debug": "edc9d9a918f943e5",
 }
 
 
@@ -321,8 +322,8 @@ def test_ten_committed_steps_under_the_manager_with_a_heal(tmp_path):
         assert s["config"] == "mellum_debug" and s["committed"] == 10 and s["discarded"] == 0, s
         assert sorted(s["model_stats"]) == [
             "attn_full_layers", "attn_window_block_share", "attn_window_layers",
-            "moe_held_pair_share", "moe_load_max_over_mean", "moe_overflow_pairs",
-            "moe_visited_row_share"]
+            "moe_held_pair_share", "moe_load_max_over_mean", "moe_moved_row_share",
+            "moe_overflow_pairs", "moe_visited_row_share"]
         assert all(v == 0 for v in s["model_stats"]["moe_overflow_pairs"])
         assert all(0 < v < 1 for v in s["model_stats"]["moe_visited_row_share"])
         assert s["model_stats"]["attn_window_layers"] == [2.0] * 10

@@ -1,9 +1,13 @@
-"""A share's grouped products visit the held pairs only (PR 51): the block
-against the parent's padded sizes at every load, on the interpreted kernels,
-whose NaN in every row they do not write is the test of the selects; and the
-counter that says what part of the buffer the products visit."""
+"""A share's grouped products visit the held pairs only (PR 51) and its
+gathers go as far as the pairs do (PR 55): the block against PR 50's padded
+sizes and against PR 51's whole-buffer gathers at every load, on the
+interpreted kernels, whose NaN in every row they do not write is the test of
+the selects; and the counters that say what part of the buffer the products
+visit and the gathers touch."""
 
 import dataclasses
+import importlib.util
+import os
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +16,8 @@ import pytest
 
 from test_ling import _program
 from torchft_tpu.models import CONFIGS, moe
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _parents_share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
@@ -39,10 +45,35 @@ def _parents_share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
                  "overflow": jnp.maximum(pairs - rows_n, 0)}
 
 
+@pytest.fixture(scope="module")
+def check():
+    spec = importlib.util.spec_from_file_location(
+        "share_gmm_check", os.path.join(ROOT, "benchmarks", "share_gmm_check.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _share_ffn_of_pr51(check):
+    """``moe._share_ffn`` as it stood from PR 51 to PR 54: round the grouped
+    products ONE gather and ONE scatter-add of XLA's over every row of the
+    buffer, whatever it holds, and autodiff's pullbacks of both: step 0's
+    ``whole`` form (``benchmarks/share_gmm_check.py``), the one copy kept."""
+    def ffn(*args):
+        with check.patched(**check.KINDS["whole"]):
+            return moe._share_ffn(*args)
+    return ffn
+
+
 # 2,048 pairs, 4 of 16 experts held, a room of 3: a buffer of 1,536 rows, three
 # row tiles of 512 (``_grouped_matmul``'s): held pairs -> row tiles visited
 SHARE_LOADS = {"none": (0, 0), "far_under": (200, 1), "across_a_tile": (700, 2),
                "the_room": (1536, 3), "overflow": (1800, 3)}
+# held pairs, given the row moves' tile: ``SHARE_LOADS``' and the moves' own
+# borders: the pairs end with a move tile, and a row past it
+MOVE_LOADS = {"a_tiles_border": lambda tile: 2 * tile, "a_row_past_it": lambda tile: 2 * tile + 1,
+              **{name: (lambda tile, pairs=pairs: pairs)
+                 for name, (pairs, _) in SHARE_LOADS.items()}}
 
 
 def _share_case(held_pairs, dtype):
@@ -62,6 +93,60 @@ def _share_case(held_pairs, dtype):
     return cfg, idx, args, jax.random.normal(ks[5], (T, d)).astype(dtype)
 
 
+def _value_and_grads(ffn, cfg, idx, args, cot):
+    """The block under ``jax.checkpoint``: ((value, (output, stats)), the five
+    gradients: tokens, gates, the three expert stacks)."""
+    def value(flat, gates, *stacks):
+        out, stats = jax.checkpoint(lambda *a: ffn(a[0], a[1], idx, cfg, *a[2:]))(
+            flat, gates, *stacks)
+        return jnp.sum((out * cot).astype(jnp.float32)), (out, stats)
+    return jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("tile", [2048, 128], ids=["tile_512", "tile_128"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("load", sorted(MOVE_LOADS))
+def test_a_shares_gathers_go_as_far_as_the_pairs_and_nothing_else_moves(
+        load, dtype, tile, monkeypatch, check):
+    """The dispatch's gather and the gather of the combine's pullback are
+    loops over row tiles that end with the tile the last held pair lies in
+    (PR 55): the tiles past it are gathered by nobody. The block's output,
+    its stats and all five gradients are what PR 51's ONE gather over every
+    row gave, bit for bit, and finite, at every load of ``SHARE_LOADS``,
+    where the pairs end exactly on a move tile's border and a row past it, at
+    the tile a buffer this short is given (512 of its 1,536 rows) and at 128.
+    Finite is the test of the selects INSIDE the last tile: past the pairs
+    the buffer holds what the grouped products found (NaN under the
+    interpreter). ``moved`` counts the rows of the tiles the loops ran."""
+    monkeypatch.setattr(moe, "MOVE_TILE", tile)
+    rows_n = _share_case(0, dtype)[0].share_rows(512)
+    tile = moe._move_tile(rows_n)
+    assert tile == min(tile, 512) and rows_n % tile == 0
+    held_pairs = MOVE_LOADS[load](tile)
+    cfg, idx, args, cot = _share_case(held_pairs, dtype)
+    ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
+        _value_and_grads(ffn, cfg, idx, args, cot)
+        for ffn in (moe._share_ffn, _share_ffn_of_pr51(check)))
+    for got, want in zip((out, *grads), (was_out, *was_grads)):
+        assert got.dtype == want.dtype and bool(jnp.all(jnp.isfinite(got)))
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for key, want in was_stats.items():
+        np.testing.assert_array_equal(stats[key], want)
+    tiles = -(-min(held_pairs, rows_n) // tile)
+    assert float(stats["moved"]) == pytest.approx(tiles * tile / rows_n, rel=1e-6)
+    assert 0 <= float(stats["moved"]) - float(stats["visited"]) < tile / rows_n
+    if load in ("a_tiles_border", "a_row_past_it") and 2 * tile < rows_n:
+        assert tiles == 2 + (load == "a_row_past_it")
+
+
+@pytest.mark.parametrize("rows_n,tile", [(131072, 2048), (49152, 2048), (24576, 2048),
+                                         (1536, 512), (24, 8)])
+def test_the_move_tile_divides_the_buffer(rows_n, tile):
+    """A buffer's rows are whole move tiles: ``MOVE_TILE`` at the three share
+    cells' buffers, the largest part of it that divides a shorter one."""
+    assert moe._move_tile(rows_n) == tile and rows_n % tile == 0
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("load", sorted(SHARE_LOADS))
 def test_a_shares_products_visit_the_held_pairs_only_and_nothing_else_moves(load, dtype):
@@ -76,16 +161,8 @@ def test_a_shares_products_visit_the_held_pairs_only_and_nothing_else_moves(load
     poisoned row with a zero weight or a mask is NaN."""
     held_pairs, tiles = SHARE_LOADS[load]
     cfg, idx, args, cot = _share_case(held_pairs, dtype)
-
-    def run(ffn):
-        def value(flat, gates, *stacks):
-            out, stats = jax.checkpoint(lambda *a: ffn(a[0], a[1], idx, cfg, *a[2:]))(
-                flat, gates, *stacks)
-            return jnp.sum((out * cot).astype(jnp.float32)), (out, stats)
-        return jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
-
     ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
-        run(moe._share_ffn), run(_parents_share_ffn))
+        _value_and_grads(ffn, cfg, idx, args, cot) for ffn in (moe._share_ffn, _parents_share_ffn))
     for got, want in zip((out, *grads), (was_out, *was_grads)):
         assert got.dtype == want.dtype and bool(jnp.all(jnp.isfinite(got)))
         np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
@@ -137,5 +214,18 @@ def test_visited_row_share_counts_the_rows_the_products_visit(room):
         logged = jax.eval_shape(lambda p: m.loss(p, t, t, c)[1], p)["moe_stats"]  # noqa: B023
         assert {"moe_visited_row_share", "moe_held_pair_share"} <= set(logged)
     m, c, p, t = _program("olmoe_like")  # every row a pair: no share, no counter
-    assert "moe_visited_row_share" not in jax.eval_shape(
-        lambda p: m.loss(p, t, t, c)[1], p)["moe_stats"]
+    assert not {"moe_visited_row_share", "moe_moved_row_share"} & set(jax.eval_shape(
+        lambda p: m.loss(p, t, t, c)[1], p)["moe_stats"])
+
+
+@pytest.mark.parametrize("name", ["ling_debug", "mellum_debug", "nemotron_h_debug"])
+def test_moved_row_share_is_logged_beside_visited_row_share(name):
+    """``expert_scalars``' ``moved_row_share`` (PR 55): the mean over the
+    layers of ``moved``, the visited part in whole move tiles, so never under
+    ``visited_row_share`` and less than a tile over it; every kind that holds
+    a share logs it where it logs ``visited_row_share``."""
+    m, cfg, p, tok = _program(name)
+    stats = jax.jit(lambda p: m.loss(p, tok, tok, cfg)[1])(p)["moe_stats"]
+    moved, visited = float(stats["moe_moved_row_share"]), float(stats["moe_visited_row_share"])
+    rows_n = cfg.share_rows(tok.size)
+    assert 0 < visited <= moved <= 1 and moved - visited < moe._move_tile(rows_n) / rows_n

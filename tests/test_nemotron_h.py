@@ -134,9 +134,9 @@ def test_presets_stand_in_the_registry_and_the_counters_ride_the_loss():
     assert 5.0 < float(value) < 7.0 and sorted(stats) == ["moe_stats", "ssd_stats"]
     s = {k: float(v) for part in stats.values() for k, v in part.items()}
     assert sorted(s) == ["attn_layers", "moe_bias_moved_share", "moe_held_pair_share",
-                         "moe_layers", "moe_load_max_over_mean", "moe_overflow_pairs",
-                         "moe_visited_row_share", "ssd_chunk_log_decay_min", "ssd_dt_mean",
-                         "ssd_layers"]
+                         "moe_layers", "moe_load_max_over_mean", "moe_moved_row_share",
+                         "moe_overflow_pairs", "moe_visited_row_share",
+                         "ssd_chunk_log_decay_min", "ssd_dt_mean", "ssd_layers"]
     assert (s["ssd_layers"], s["moe_layers"], s["attn_layers"]) == (3, 2, 1)
     assert 1e-3 < s["ssd_dt_mean"] < 0.2 and -87 < s["ssd_chunk_log_decay_min"] < 0
     assert s["moe_overflow_pairs"] == 0 and 0 < s["moe_bias_moved_share"] < 1
@@ -206,8 +206,8 @@ def test_committed_steps_under_the_manager_with_a_heal_that_carries_the_bias(tmp
         assert s["discarded"] == 0
         assert sorted(s["model_stats"]) == [
             "attn_layers", "moe_bias_moved_share", "moe_held_pair_share", "moe_layers",
-            "moe_load_max_over_mean", "moe_overflow_pairs", "moe_visited_row_share",
-            "ssd_chunk_log_decay_min", "ssd_dt_mean", "ssd_layers"]
+            "moe_load_max_over_mean", "moe_moved_row_share", "moe_overflow_pairs",
+            "moe_visited_row_share", "ssd_chunk_log_decay_min", "ssd_dt_mean", "ssd_layers"]
         assert all(v == 0 for v in s["model_stats"]["moe_overflow_pairs"])
         assert s["model_stats"]["ssd_layers"] == [3.0] * 4
         assert all(5.0 < x < 7.0 for x in s["losses"])

@@ -335,10 +335,10 @@ def _bodies(cfg: LingConfig, seq: int, attention_fn: Optional[Any]):
 def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: LingConfig
               ) -> Dict[str, jax.Array]:
     """The expert layers' free routing with its margins (``routing``
-    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and all six of
+    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and all seven of
     ``moe.expert_scalars``: ``load_max_over_mean``, ``bias_moved_share``,
-    ``groups_hit_mean``, ``held_pair_share``, ``overflow_pairs`` and
-    ``visited_row_share``."""
+    ``groups_hit_mean``, ``held_pair_share``, ``overflow_pairs``,
+    ``visited_row_share`` and ``moved_row_share``."""
     return expert_scalars(stats, tokens.size * cfg.top_k)
 
 
@@ -377,4 +377,4 @@ def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, A
 register(LingConfig, LING_CONFIGS, lambda: ModelFns(
     ling_init, logged(ling_loss_and_stats, moe=(
         "load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
-        "visited_row_share", "groups_hit_mean")), ling_param_specs, None, LING_FROZEN))
+        "visited_row_share", "moved_row_share", "groups_hit_mean")), ling_param_specs, None, LING_FROZEN))

@@ -253,12 +253,12 @@ def _bodies(cfg: MellumConfig, seq: int, attention_fn: Optional[Any]):
 def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: MellumConfig
               ) -> Dict[str, jax.Array]:
     """The layers' free routing with its margins (``routing`` [L,T,k],
-    ``p_kth``, ``p_next`` [L,T]), ``moe.expert_scalars``' four for this
+    ``p_kth``, ``p_next`` [L,T]), ``moe.expert_scalars``' five for this
     family (``load_max_over_mean`` and, under a share, ``held_pair_share``,
-    ``overflow_pairs`` and ``visited_row_share``), and what the attention
-    kernels were built for: ``window_layers``, ``full_layers`` and
-    ``window_block_share``
-    (``ops.attention``'s: 1.0 means a window layer's kernel skips nothing)."""
+    ``overflow_pairs``, ``visited_row_share`` and ``moved_row_share``), and
+    what the attention kernels were built for: ``window_layers``,
+    ``full_layers`` and ``window_block_share`` (``ops.attention``'s: 1.0
+    means a window layer's kernel skips nothing)."""
     stats = expert_scalars(stats, tokens.size * cfg.top_k)
     windows = sum(t == "window" for t in cfg.layer_types)
     stats["window_layers"] = jnp.asarray(windows, _F32)
@@ -287,5 +287,6 @@ def mellum_param_specs(cfg: MellumConfig, mesh: Optional[Any] = None) -> Dict[st
 register(MellumConfig, MELLUM_CONFIGS, lambda: ModelFns(
     mellum_init, logged(
         mellum_loss_and_stats,
-        moe=("load_max_over_mean", "held_pair_share", "overflow_pairs", "visited_row_share"),
+        moe=("load_max_over_mean", "held_pair_share", "overflow_pairs", "visited_row_share",
+             "moved_row_share"),
         attn=("window_layers", "full_layers", "window_block_share")), mellum_param_specs, None))

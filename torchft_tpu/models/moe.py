@@ -109,9 +109,10 @@ class MoEConfig(LlamaConfig):
     held_experts: Optional[Tuple[int, int]] = None
     # rows the share's buffer has, over the even share T * top_k * count /
     # num_experts; pairs beyond it are counted (``overflow``) and computed
-    # by nobody. A room costs memory and row traffic (every row of the
-    # buffer is gathered and scatter-added) and NO products: the grouped
-    # matmuls visit the held pairs only (``_share_sizes``)
+    # by nobody. A room costs memory and the two scatter-adds' row traffic
+    # (XLA's, over every row of the buffer) and NO products and no gather:
+    # the grouped matmuls visit the held pairs only (``_share_sizes``) and
+    # the gathers go as far as the pairs do (``_over_tiles``)
     share_room: float = 1.5
     # an expert's form: "swiglu", ``down(silu(gate(x)) * up(x))``, three
     # matrices; or "relu2", ungated, ``down(relu(up(x))^2)``, two matrices and
@@ -528,6 +529,117 @@ def _share_sizes(counts: jax.Array, rows_n: int) -> jax.Array:
     return jnp.diff(ends, prepend=0).astype(jnp.int32)
 
 
+# rows a step of a share's gathers (:func:`_over_tiles`): four tiles of the
+# grouped products' 512
+MOVE_TILE = 2048
+
+
+def _move_tile(rows_n: int) -> int:
+    """The row tile of a share's gathers for a buffer of ``rows_n`` rows: the
+    largest part of ``MOVE_TILE`` that divides them."""
+    return math.gcd(rows_n, MOVE_TILE)
+
+
+def _moved_tiles(n, rows_n: int):
+    """Row tiles the first ``min(n, rows_n)`` rows of a buffer reach."""
+    tile = _move_tile(rows_n)
+    return (jnp.minimum(n, rows_n) + tile - 1) // tile
+
+
+def _over_tiles(n, rows_n: int, body, init):
+    """``carry = body(cut, put, valid, carry)`` for each row tile that the
+    first ``n`` of a buffer's ``rows_n`` rows reach, in order, and for no
+    other: a loop whose trip count is a count the router already has, so a
+    gather's time follows the pairs and not the room. ``cut(x)`` is the tile
+    of a per-row array, ``put(x, rows)`` is ``x`` with ``rows`` in the tile's
+    place; ``valid`` [tile, 1]: the rows that hold a pair (in the last tile
+    the pairs reach, the rows before ``n``; every row once ``n`` passes the
+    buffer). Nobody differentiates through it: the moves bring pullbacks of
+    their own."""
+    tile = _move_tile(rows_n)
+
+    def step(i, carry):
+        at = i * tile
+        return body(lambda x: jax.lax.dynamic_slice_in_dim(x, at, tile),
+                    lambda x, rows: jax.lax.dynamic_update_slice_in_dim(x, rows, at, 0),
+                    (at + jnp.arange(tile) < n)[:, None], carry)
+
+    return jax.lax.fori_loop(0, _moved_tiles(n, rows_n), step, init)
+
+
+def _held(take, n):
+    """[rows_n, 1]: the rows of a share's buffer that hold a pair, the first ``n``."""
+    return (jnp.arange(take.shape[0]) < n)[:, None]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _share_take(flat, take, n, tokens):
+    """flat [tokens, d], take [rows_n] -> [rows_n, d]: ``flat[take]`` in the
+    first ``n`` rows and zeros after them, gathered tile by tile as far as
+    the ``n`` rows go (:func:`_over_tiles`). Its pullback is XLA's ONE
+    scatter-add of the cotangent's rows into ``[tokens, d]``, over every row
+    of the buffer: XLA sorts a whole scatter-add's indices and adds a token's
+    rows before it rounds, at under half a tile's cost a row (PERF.md section
+    6, PR 55). A row past ``n`` is SELECTED away both ways. (``tokens`` is
+    ``flat.shape[0]``, given apart because a pullback's residuals carry no
+    shape.)"""
+    def body(cut, put, valid, buf):
+        return put(buf, jnp.where(valid, flat[cut(take)], 0))
+
+    return _over_tiles(n, take.shape[0], body,
+                       jnp.zeros((take.shape[0], flat.shape[1]), flat.dtype))
+
+
+def _share_take_fwd(flat, take, n, tokens):
+    return _share_take(flat, take, n, tokens), (take, n)
+
+
+def _share_take_bwd(tokens, saved, g):
+    take, n = saved
+    return jnp.zeros((tokens, g.shape[1]), g.dtype).at[take].add(
+        jnp.where(_held(take, n), g, 0)), None, None
+
+
+_share_take.defvjp(_share_take_fwd, _share_take_bwd)
+
+
+def _weighed(valid, rows, weights):
+    """Rows of the buffer as the combine adds them: selected, then weighed."""
+    return jnp.where(valid, rows, 0) * weights
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _share_add(rows, weights, take, n, tokens):
+    """rows [rows_n, d], weights [rows_n, 1] -> [tokens, d]: the first ``n``
+    rows, each times its weight, added at ``take`` in the buffer's order by
+    XLA's ONE scatter-add over every row (see :func:`_share_take`). Its
+    pullback gathers the cotangent at ``take`` tile by tile as far as the
+    ``n`` rows go (:func:`_over_tiles`) and takes each tile through the
+    product's own pullback (the primitives autodiff emits for the whole
+    buffer); the cotangents of the rows and weights that no tile reaches are
+    zeros."""
+    return jnp.zeros((tokens, rows.shape[1]), rows.dtype).at[take].add(
+        _weighed(_held(take, n), rows, weights))
+
+
+def _share_add_fwd(rows, weights, take, n, tokens):
+    return _share_add(rows, weights, take, n, tokens), (rows, weights, take, n)
+
+
+def _share_add_bwd(tokens, saved, g):
+    rows, weights, take, n = saved
+
+    def body(cut, put, valid, carry):
+        _, pullback = jax.vjp(partial(_weighed, valid), cut(rows), cut(weights))
+        return tuple(map(put, carry, pullback(g[cut(take)])))
+
+    return (*_over_tiles(n, rows.shape[0], body,
+                         (jnp.zeros_like(rows), jnp.zeros_like(weights))), None, None)
+
+
+_share_add.defvjp(_share_add_fwd, _share_add_bwd)
+
+
 def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     """The dropless path of a chip that holds ``cfg.held_experts`` alone:
     of the T*k (token, choice) pairs those whose expert is held, sorted by
@@ -536,20 +648,26 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     and so are held pairs beyond the buffer (``overflow``). -> (the held
     experts' part of the block's output [T, d], stats: ``counts`` [held],
     ``held_pairs``, ``overflow``, ``visited``: the part of the buffer's rows
-    the grouped products visit, ``min(held_pairs, rows) / rows``).
+    the grouped products visit, ``min(held_pairs, rows) / rows``, ``moved``:
+    the part the gathers touch, the same in whole tiles of
+    :func:`_move_tile` rows).
 
-    The rows leave and come back by XLA's gather and scatter-add of the
-    buffer's rows (a sixteenth of T*k at the published cut): the custom
-    pullbacks of the whole-layer path gather T*k rows. Those two move every
-    row of the buffer whatever it holds; the grouped products visit the
-    held pairs only (:func:`_share_sizes`).
+    The rows leave by a gather that runs tile by tile as far as the held
+    pairs go, and so does the gather of the combine's pullback
+    (:func:`_share_take`, :func:`_share_add`); the grouped products visit
+    the held pairs only (:func:`_share_sizes`). What still follows the room
+    is the two scatter-adds into ``[T, d]`` (the combine, the dispatch's
+    pullback): XLA's own over every row of the buffer, which it sorts by
+    token first; a tile's scatter-add costs more than twice that a row. The
+    custom pullbacks of the whole-layer path gather T*k rows; a share's
+    buffer is a sixteenth of that at the published cut.
 
     What a grouped product leaves past the pairs is whatever its output
     buffer held (NaN under the interpreter, any bits on a chip), so no
-    product with such a row may reach a sum: the rows are SELECTED by
-    ``valid`` before they are weighed, and a cotangent leaves the buffer's
-    tail through a select's pullback (a select), never through a product
-    with a mask (NaN x 0 is NaN)."""
+    product with such a row may reach a sum: inside the last tile the pairs
+    reach the rows are SELECTED by ``valid`` before they are weighed, and a
+    cotangent leaves the buffer's tail through a select's pullback (a
+    select), never through a product with a mask (NaN x 0 is NaN)."""
     (T, d), k = flat.shape, idx.shape[1]
     first, held = cfg.held_experts
     rows_n = cfg.share_rows(T)
@@ -559,21 +677,22 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
         counts = _counts(local[:, None], held)
         pairs = jnp.sum(counts)
         order = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n]
-        valid = (jnp.arange(rows_n) < pairs)[:, None]
+        valid = _held(order, pairs)
         sizes = _share_sizes(counts, rows_n)
         weights = jnp.where(valid, gates.reshape(T * k, 1)[order], 0.0)
     with jax.named_scope("moe/dispatch"):
-        rows = jnp.where(valid, flat[order // k], 0)
+        rows = _share_take(flat, order // k, pairs, T)
     with jax.named_scope("moe/experts"):
         h = _hidden(rows, w_gate, w_up, lambda r, w: _grouped_matmul(r, w, sizes),
                     cfg.expert_act)
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
-        out = jnp.zeros((T, d), flat.dtype).at[order // k].add(
-            jnp.where(valid, rows, 0) * weights.astype(flat.dtype))
+        out = _share_add(rows, weights.astype(flat.dtype), order // k, pairs, T)
     return out, {"counts": counts, "held_pairs": pairs,
                  "overflow": jnp.maximum(pairs - rows_n, 0),
-                 "visited": jnp.minimum(pairs, rows_n).astype(jnp.float32) / rows_n}
+                 "visited": jnp.minimum(pairs, rows_n).astype(jnp.float32) / rows_n,
+                 "moved": (_moved_tiles(pairs, rows_n) * _move_tile(rows_n)).astype(jnp.float32)
+                 / rows_n}
 
 
 def _groups_hit(idx: jax.Array, cfg: MoEConfig) -> jax.Array:
@@ -617,7 +736,7 @@ def moe_ffn(
     and the decision over all ``num_experts``, the expert leaves
     ``[held, ...]``, the output the held experts' part alone
     (:func:`_share_ffn`; ``counts`` are then the held experts', beside
-    ``held_pairs``, ``overflow`` and ``visited``); ``shared`` (gate, up,
+    ``held_pairs``, ``overflow``, ``visited`` and ``moved``); ``shared`` (gate, up,
     down) is one expert of the same form, as wide as its matrices are, that
     every token passes, added to the output without a router's gate.
     """
@@ -687,11 +806,14 @@ def expert_scalars(stats: Dict[str, jax.Array], pairs: int,
     most ``topk_group``), ``held_pair_share`` (the pairs that reached a held
     expert over ``pairs`` = T * k: evenly, held / num_experts),
     ``overflow_pairs`` (held pairs that found the share's buffer full, over
-    all layers: computed by nobody, so anything but 0 is a wrong step) and
+    all layers: computed by nobody, so anything but 0 is a wrong step),
     ``visited_row_share`` (the part of the share's buffer its grouped
     products visit, ``min(held pairs, rows) / rows``, the mean over layers:
     evenly about 1 / ``share_room``; 1.0 is every row multiplied, which is
-    what overflow costs).
+    what overflow costs) and ``moved_row_share`` (the part its gathers move,
+    the dispatch's and the combine's pullback's: ``visited_row_share`` in
+    whole tiles of :func:`_move_tile` rows, so at most a tile above it; 1.0
+    is every row gathered).
 
     ``mean_floor`` guards the mean of a share's counts, which may all be
     zero; None divides by the mean as it is: LFM2's program (every expert
@@ -712,6 +834,7 @@ def expert_scalars(stats: Dict[str, jax.Array], pairs: int,
             stats.pop("held_pairs").astype(jnp.float32)) / pairs
         stats["overflow_pairs"] = jnp.sum(stats.pop("overflow"))
         stats["visited_row_share"] = jnp.mean(stats.pop("visited"))
+        stats["moved_row_share"] = jnp.mean(stats.pop("moved"))
     return stats
 
 

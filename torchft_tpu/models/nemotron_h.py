@@ -313,9 +313,10 @@ def _bodies(cfg: NemotronHConfig, seq: int, attention_fn: Optional[Any]):
 def _counters(stats: Dict[str, jax.Array], tokens: jax.Array, cfg: NemotronHConfig
               ) -> Dict[str, jax.Array]:
     """The expert layers' free routing with its margins (``routing``
-    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and ``moe.expert_scalars``' five for
+    [L,T,k], ``p_kth``, ``p_next`` [L,T]) and ``moe.expert_scalars``' six for
     this family (``load_max_over_mean``, ``bias_moved_share``,
-    ``held_pair_share``, ``overflow_pairs``, ``visited_row_share``); what
+    ``held_pair_share``, ``overflow_pairs``, ``visited_row_share``,
+    ``moved_row_share``); what
     ran (``ssd_layers``, ``attn_layers``, ``moe_layers``); and of the Mamba
     layers ``ssd_dt_mean`` (the mean step size: its inverse over -a is the
     state's memory in positions) and ``ssd_chunk_log_decay_min`` (the most
@@ -335,7 +336,7 @@ nemotron_h_hidden, nemotron_h_forward = NEMOTRON_H.hidden, NEMOTRON_H.forward
 nemotron_h_loss_and_stats, nemotron_h_loss = NEMOTRON_H.loss_and_stats, NEMOTRON_H.loss
 
 _LOGGED_MOE = ("load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
-               "visited_row_share")
+               "visited_row_share", "moved_row_share")
 _LOGGED_OWN = ("ssd_layers", "attn_layers", "moe_layers", "ssd_dt_mean",
                "ssd_chunk_log_decay_min")
 

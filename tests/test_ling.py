@@ -177,7 +177,7 @@ def test_the_staged_kinds_head_programs_are_the_parents(name):
 
 
 def _kinds():
-    from torchft_tpu.models import jamba, lfm2, llama, mellum, nemotron_h, ouro
+    from torchft_tpu.models import brumby, jamba, lfm2, llama, mellum, nemotron_h, ouro
     from torchft_tpu.parallel.mesh import llama_param_specs
 
     # class -> (init, param_specs, whether the gradient is staged, frozen)
@@ -191,6 +191,7 @@ def _kinds():
         ouro.OuroConfig: (ouro.ouro_init, ouro.ouro_param_specs, True, ()),
         nemotron_h.NemotronHConfig: (nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_param_specs,
                                      False, ("expert_bias",)),
+        brumby.BrumbyConfig: (brumby.brumby_init, brumby.brumby_param_specs, False, ()),
     }
 
 

@@ -103,7 +103,11 @@ def _value_and_grads(ffn, cfg, idx, args, cot):
     return jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
 
 
-@pytest.mark.parametrize("tile", [2048, 128], ids=["tile_512", "tile_128"])
+# the larger tile is ``slow``: the small one walks the same loops over more
+# tiles at every load and both dtypes (ROADMAP D11: 12 cases, 156 of this
+# file's 486 test-seconds; PR 56)
+@pytest.mark.parametrize("tile", [pytest.param(2048, marks=pytest.mark.slow), 128],
+                         ids=["tile_512", "tile_128"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("load", sorted(MOVE_LOADS))
 def test_a_shares_gathers_go_as_far_as_the_pairs_and_nothing_else_moves(

@@ -18,6 +18,7 @@ Design for the TPU:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from typing import Any, Dict, Optional
@@ -40,6 +41,7 @@ __all__ = [
     "head_loss",
     "token_ce",
     "sum_over_chunks",
+    "swiglu",
     "CONFIGS",
 ]
 
@@ -188,8 +190,28 @@ def _attention(
     return causal_attention(q, k, v, cfg, window=window)
 
 
+def swiglu(x: jax.Array, layer_params: Dict[str, Any], block: int = 0) -> jax.Array:
+    """``(silu(x w_gate) * (x w_up)) w_down`` of x [B, S, dim]. ``block`` > 0
+    (a kind at the HBM edge: models/brumby.py's ``ffn_block``) runs it over
+    blocks of that many positions, each rematerialised, so that the three
+    [S, ffn_hidden] temporaries exist a block at a time, forward and backward;
+    a position's result is the same products either way."""
+    def whole(x):
+        gated = jax.nn.silu(x @ layer_params["w_gate"]) * (x @ layer_params["w_up"])
+        return gated @ layer_params["w_down"]
+
+    B, S, _ = x.shape
+    if block <= 0 or S <= block:
+        return whole(x)
+    if S % block != 0:
+        raise ValueError(f"ffn_block {block} must divide seq len {S}")
+    blocks = jnp.swapaxes(x.reshape(B, S // block, block, -1), 0, 1)
+    fed = jax.lax.map(jax.checkpoint(whole), blocks)
+    return jnp.swapaxes(fed, 0, 1).reshape(B, S, -1)
+
+
 def make_llama_layer_body(
-    cfg: LlamaConfig, attention_fn: Optional[Any] = None
+    cfg: LlamaConfig, attention_fn: Optional[Any] = None, mixer: Optional[Any] = None
 ):
     """The ONE scanned transformer layer body, shared by every execution
     path (dense scan here, GPipe stages in parallel/pipeline.py) so the
@@ -197,32 +219,54 @@ def make_llama_layer_body(
     ``layer(h, layer_params) -> (h, None)`` with h [B, S, dim]. A stack that
     has ``attn_post_norm`` / ``ffn_post_norm`` leaves is a sandwich-norm
     layer (models/ouro.py): each branch is normalised once more before it
-    joins the residual stream."""
+    joins the residual stream; one that has ``q_norm`` / ``k_norm`` leaves
+    [head_dim] normalises every head's queries and keys before the rotary
+    turn.
+
+    ``mixer``: the seam for a kind whose layers mix positions by something
+    other than attention over q, k, v alone (models/brumby.py: a retention
+    under a gate computed from the layer's normalised input). ``mixer.mix(q,
+    k, v, x, layer_params) -> (mixed [B, S, n_heads, head_dim], emitted)``
+    stands where attention does and ``emitted`` is what the layer hands out
+    beside ``h``; ``mixer.scope(part)`` names the part of the layer that
+    follows ("in_proj", "qk_norm_rope", "out_proj", "ffn") in the compiled
+    step; ``mixer.ffn_block`` is :func:`swiglu`'s ``block``."""
     attention = attention_fn or _attention
+    scope = mixer.scope if mixer is not None else (lambda part: contextlib.nullcontext())
+    ffn_block = mixer.ffn_block if mixer is not None else 0
 
     def layer(h, layer_params):
         B, S = h.shape[0], h.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
         x = _rmsnorm(h, layer_params["attn_norm"], cfg.norm_eps)
-        q = (x @ layer_params["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
-        k = (x @ layer_params["wk"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        v = (x @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
-        attn = jax.ad_checkpoint.checkpoint_name(
-            attention(q, k, v, cfg), ATTN_OUT_NAME
-        ).reshape(B, S, cfg.n_heads * cfg.head_dim)
-        mixed = attn @ layer_params["wo"]
+        with scope("in_proj"):
+            q = (x @ layer_params["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
+            k = (x @ layer_params["wk"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+            v = (x @ layer_params["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        with scope("qk_norm_rope"):
+            if "q_norm" in layer_params:
+                q = _rmsnorm(q, layer_params["q_norm"], cfg.norm_eps)
+                k = _rmsnorm(k, layer_params["k_norm"], cfg.norm_eps)
+            q = _rope(q, cfg.rope_theta, positions)
+            k = _rope(k, cfg.rope_theta, positions)
+        if mixer is None:
+            attn, emitted = attention(q, k, v, cfg), None
+        else:
+            attn, emitted = mixer.mix(q, k, v, x, layer_params)
+        attn = jax.ad_checkpoint.checkpoint_name(attn, ATTN_OUT_NAME).reshape(
+            B, S, cfg.n_heads * cfg.head_dim)
+        with scope("out_proj"):
+            mixed = attn @ layer_params["wo"]
         if "attn_post_norm" in layer_params:
             mixed = _rmsnorm(mixed, layer_params["attn_post_norm"], cfg.norm_eps)
         h = h + mixed
-        x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
-        gated = jax.nn.silu(x @ layer_params["w_gate"]) * (x @ layer_params["w_up"])
-        fed = gated @ layer_params["w_down"]
+        with scope("ffn"):
+            x = _rmsnorm(h, layer_params["ffn_norm"], cfg.norm_eps)
+            fed = swiglu(x, layer_params, ffn_block)
         if "ffn_post_norm" in layer_params:
             fed = _rmsnorm(fed, layer_params["ffn_post_norm"], cfg.norm_eps)
         h = h + fed
-        return h, None
+        return h, emitted
 
     return layer
 

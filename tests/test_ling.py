@@ -131,8 +131,16 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # ``moved_row_share`` stands beside the loss; the losses are the parent's
     # to the bit, and every other line of this table and ``STAGED_HEAD`` are
     # as they were: ``_dropless_ffn`` and ``_capacity_ffn`` lower to what
-    # they did
-    "ling_debug": ("edc9d9a918f943e5", "84f4010258aabb3d", "0x1.7f8a480000000p+2"),
+    # they did.
+    # ling_debug's pair pinned anew by PR 58: the lowered program holds the
+    # delta-rule kernels' bodies. ``kda_bwd``'s is now a chunk's two halves
+    # each linearised once (``ops/kda.py``: ``_state_free`` for the block's
+    # four chunks as one batch, ``_through_state`` chunk by chunk) with the
+    # inverse's pullback in closed form (``_inverse``); ``kda_fwd``'s holds a
+    # ``custom_vjp_call`` round the same ten products and reads its block
+    # whole before cutting it into chunks. The loss is the parent's to the
+    # bit, and no other line moved
+    "ling_debug": ("318e97cd85d7635f", "d8f63d1838707a99", "0x1.7f8a480000000p+2"),
     "mellum_debug": ("d65c6f4355d79a33", "ca31160f100d2593", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),

@@ -39,6 +39,12 @@ def _bounds(B, T, H, d):
     return g, beta
 
 
+def _one_key(args):
+    """Every key the same vector, ``beta`` = 1, no decay."""
+    q, k, v, g, beta = args
+    return q, jnp.broadcast_to(k[:, :1], k.shape), v, jnp.zeros_like(g), jnp.ones_like(beta)
+
+
 @pytest.mark.parametrize("case", ["across_blocks", "one_short_block", "at_the_bounds",
                                   "all_at_the_bound", "one_key"])
 def test_the_kernels_are_the_recurrence_forward_and_backward(case):
@@ -58,9 +64,7 @@ def test_the_kernels_are_the_recurrence_forward_and_backward(case):
         # every key the same vector, beta = 1, no decay: a chunk inverse in
         # one step over 64 rows overflows float32 here (binomials to 1e18);
         # in two steps over sub-blocks of 16 it is the recurrence's
-        q, k, v, _, _ = args
-        args = (q, jnp.broadcast_to(k[:, :1], k.shape), v, jnp.zeros_like(args[3]),
-                jnp.ones((B, T, H)))
+        args = _one_key(args)
     w = jax.random.normal(jax.random.PRNGKey(9), (B, T, H, d))
     got, want = K.kda(*args), K.kda_reference(*args)
     assert _rel(got, want) < 5e-6
@@ -75,6 +79,101 @@ def test_the_kernels_are_the_recurrence_forward_and_backward(case):
             assert _rel(a, b) < (1e-3 if loose else 5e-5), (case, name)
         else:  # nothing depends on a decay that is given
             assert float(jnp.linalg.norm(a)) < 1e-6, (case, name)
+
+
+@pytest.mark.parametrize("case", ["random", "at_the_bounds", "one_key"])
+def test_the_inverses_closed_form_pullback_is_autodiff_through_its_products(case, monkeypatch):
+    """PR 58: ``_inverse``'s pullback, ``-M^T dM M^T``, against ``jax.vjp``
+    through the ten products of ``_products_inverse`` and against the same
+    formula in float64, on the ``X`` the state-free half builds from a chunk
+    of random inputs, of decays at -5 and 0 beside ``beta`` at 0 and 1, and
+    of one key for every position (the ``X`` whose one-step inverse is not
+    finite). On and above the diagonal the two differ by right (off the
+    strictly lower triangle the products are no inverse) and the mask that
+    made ``X`` drops both, so the strictly lower part is compared. Limits,
+    as shares of the answer's norm: the closed form within 1e-6 of float64
+    (read 4e-8 to 1e-7: two products of 64 terms at ``Precision.HIGHEST``),
+    autodiff within 1e-6 of the closed form (read 7e-8 and 8e-8) but 1e-4
+    for the one key, where it read 2.3e-5: the chain of ten pullbacks
+    multiplies by powers of ``X`` that reach 1e4 over 16 rows and rounds at
+    every link, so there the closed form is the better gradient by three
+    digits, and the kernels' gradients moved by that much."""
+    C, d = K.CHUNK, 16
+    g, beta = _bounds(1, C, 1, d) if case == "at_the_bounds" else (None, None)
+    args = _qkvgb(1, C, 1, d, seed=len(case), g=g, beta=beta)
+    q, k, _, g, beta = (a[0, :, 0] for a in (_one_key(args) if case == "one_key" else args))
+    seen = []
+    monkeypatch.setattr(K, "_inverse", lambda x: seen.append(x) or K._products_inverse(x))
+    K._state_free(q, k, g, beta[:, None])
+    monkeypatch.undo()
+    (x,) = seen
+    lower = np.tril(np.ones((C, C)), -1)
+    assert float(jnp.max(jnp.abs(x * (1 - lower)))) == 0 and float(jnp.max(jnp.abs(x))) > 0.1
+    dm = jax.random.normal(jax.random.PRNGKey(4), (C, C))
+    inv, closed = jax.vjp(K._inverse, x)
+    products, auto = jax.vjp(K._products_inverse, x)
+    np.testing.assert_array_equal(np.asarray(inv), np.asarray(products))
+    m = np.linalg.inv(np.eye(C) + np.asarray(x, np.float64))
+    assert _rel(inv, m) < 1e-6
+    want = -m.T @ np.asarray(dm, np.float64) @ m.T * lower
+    closed, auto = np.asarray(closed(dm)[0]) * lower, np.asarray(auto(dm)[0]) * lower
+    assert _rel(closed, want) < 1e-6 and _rel(auto, closed) < (
+        1e-4 if case == "one_key" else 1e-6), (
+        case, _rel(closed, want), _rel(auto, want))
+
+
+def test_a_block_takes_each_chunks_inverse_once_forward_and_once_backward():
+    """PR 58, counted in the traced kernels: the products of 64 squared by 64
+    squared are the inverse's and nobody else's. A forward block holds ten a
+    chunk; forward and backward together hold those, the backward kernel's
+    own ten a chunk (the state-free half linearised ONCE, its four chunks as
+    one batch, shared by the states pass and the walk) and two a chunk for
+    the closed form, where autodiff through the products would hold twenty
+    a chunk and a second linearisation of a half its products again."""
+    args = _qkvgb(1, K.BLOCK, 1, 16, seed=2)
+    square = (K.CHUNK, K.CHUNK)
+
+    def inverses(f):
+        count = 0
+        for e in _equations(jax.make_jaxpr(f)(*args).jaxpr):
+            shapes = [tuple(v.aval.shape) for v in e.invars]
+            if e.primitive.name == "dot_general" and [s[-2:] for s in shapes] == [square] * 2:
+                count += int(np.prod(shapes[0][:-2]))  # a batch of chunks counts each
+        return count
+
+    grad = jax.grad(lambda *a: jnp.sum(K.kda(*a)), argnums=range(5))
+    assert inverses(K.kda) == 4 * 10 and inverses(grad) == 4 * 10 + 4 * (10 + 2)
+
+
+def _equations(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _equations(sub)
+
+
+def test_a_cotangent_that_arrives_through_the_state_alone_is_the_recurrences():
+    """A loss on the last block only: ``do`` is zero in the two blocks
+    before it, so what they give back (``dk``, ``dv``, ``dg``, ``dbeta``;
+    ``dq`` is zero there, exactly) came through ``dst_scr``'s carry from
+    block to block and through the chunks' state cotangent inside a block,
+    and through nothing else."""
+    B, T, H, d = 1, 2 * K.BLOCK + 70, 2, 16
+    # little decay and small writes: under the usual ones a state of 16
+    # channels is gone, decayed or overwritten, within a block
+    slow = -0.02 * jax.random.uniform(jax.random.PRNGKey(6), (B, T, H, d))
+    faint = 0.05 * jax.random.uniform(jax.random.PRNGKey(7), (B, T, H))
+    args = _qkvgb(B, T, H, d, seed=5, g=slow, beta=faint)
+    early = slice(0, 2 * K.BLOCK)
+    w = jax.random.normal(jax.random.PRNGKey(9), (B, T, H, d)).at[:, early].set(0.0)
+    grads = lambda f: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) * w), argnums=range(5))(*args)
+    got, want = grads(K.kda), grads(K.kda_reference)
+    assert float(jnp.max(jnp.abs(got[0][:, early]))) == 0.0
+    for name, a, b in zip("kvgb", got[1:], want[1:]):
+        for block in (slice(0, K.BLOCK), slice(K.BLOCK, 2 * K.BLOCK)):
+            assert float(jnp.linalg.norm(b[:, block])) > 0.03 * float(jnp.linalg.norm(b)), name
+            assert _rel(a[:, block], b[:, block]) < (1e-3 if name == "g" else 5e-5), name
 
 
 def test_the_kernels_take_bf16_and_keep_state_and_decays_in_float32(monkeypatch):

@@ -288,8 +288,10 @@ PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 4
     "jamba_debug": "f85418fbe0152758",
     # pinned anew by PR 51 (it holds a share), by PR 53 (one short convolution
     # for every kind) and by PR 55 (the share's gathers are loops over row
-    # tiles): tests/test_ling.py's table says what moved
-    "ling_debug": "edc9d9a918f943e5",
+    # tiles) and by PR 58 (``kda_bwd``'s body: a chunk's two halves once each,
+    # the inverse's pullback in closed form): tests/test_ling.py's table says
+    # what moved
+    "ling_debug": "318e97cd85d7635f",
 }
 
 

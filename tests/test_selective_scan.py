@@ -201,8 +201,9 @@ def test_the_expert_blocks_small_gathers_find_their_source_in_vmem(one_chip, mon
 def test_the_delta_rule_kernels_compile_for_a_v5e_at_lings_widths(one_chip, monkeypatch):
     """PR 40: Mosaic takes ``ops/kda.py``'s pair at batch 1, T 32,768, 32
     heads of 128 (the sub-block slices, the in-kernel pullback's transposed
-    products and the 17 MB of its unrolled temporaries are what interpret
-    mode cannot show), and what the pair keeps in HBM beside its arguments
+    products, since PR 58 the batched ones of ``jax.vmap`` over a block's
+    chunks, and the 12 MB of its unrolled temporaries are what interpret mode
+    cannot show), and what the pair keeps in HBM beside its arguments
     and results is the state at every block's start, 256 MB, not one a
     position (69 GB) nor a [T, 1] column padded to 128 lanes."""
     from jax.experimental.compilation_cache import compilation_cache

@@ -17,11 +17,23 @@ The recurrence is a Pallas kernel pair under ``jax.custom_vjp`` (``kda_fwd``
 sequential, the state in VMEM scratch across blocks, so the ``[T, d_k,
 d_v]`` states never reach HBM: the forward pass writes the state at every
 block's start (256 MB a layer at 32k and 32 heads), the backward pass walks
-the blocks in reverse with the state's cotangent in scratch, computes the
-states inside a block again and differentiates the one chunk function
-(:func:`_chunk`) inside the kernel, so forward and backward cannot drift
-apart. Off the TPU the same kernels
-run interpreted, as ``ops/selective_scan.py``'s do.
+the blocks in reverse with the state's cotangent in scratch. A chunk is ONE
+definition in two halves, :func:`_state_free` (decays, ``A``, ``P``, the
+chunk inverse: what no state enters) and :func:`_through_state`; the forward
+kernel runs their composition (:func:`_chunk`) and the backward kernel
+differentiates the same two inside the kernel, so forward and backward
+cannot drift apart. In a block's backward pass every piece is computed once:
+the state-free half is linearised once for the block's four chunks together
+(``jax.vmap``: four independent chains of small dependent products, which
+the MXU overlaps where one chain leaves it waiting), the state half once a
+chunk from the saved state on, which also gives the states the later chunks
+start from; the walk back uses both linearisations and nothing is run a
+second time. The inverse's derivative is taken in closed form,
+``dX = -M^T dM M^T`` for ``M = (I + X)^-1`` (:func:`_inverse`): exact,
+because the product form below IS the inverse (the powers it drops are
+zero), two products where autodiff through them takes twenty, and closer to
+the float64 answer where a chunk's keys are nearly one vector. Off the TPU
+the same kernels run interpreted, as ``ops/selective_scan.py``'s do.
 
 A chunk (``C`` positions, ``G`` the running sum of ``g`` inside it, ``S0``
 the state it starts from) in matrix products: with
@@ -87,10 +99,54 @@ def _dot(a, b, dims=_NN):
                                preferred_element_type=_F32)
 
 
-def _chunk(q, k, v, g, beta, st):
-    """One chunk of one head. q, k, g [C, dk]; v [C, dv]; beta [C, 1]; st
-    [dv, dk], the state TRANSPOSED (its decay is then a row broadcast over
-    sublanes); all float32 -> (o [C, dv], the state after the chunk)."""
+def _products_inverse(x):
+    """``(I + x)^-1`` of a strictly lower triangular ``x`` [C, C] in the two
+    steps of the product form the module docstring describes."""
+    C = x.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = (rows == cols).astype(_F32)
+
+    def inverse(y, order):  # (I + y)^-1 for y^order = 0: (I - y)(I + y^2)(I + y^4)...
+        inv, power, n = eye - y, y, 2
+        while n < order:
+            power = _dot(power, power)
+            inv = inv + _dot(inv, power)
+            n *= 2
+        return inv
+
+    # I + X = (I + D)(I + (I + D)^-1 L), D the diagonal sub-blocks of X
+    d = jnp.where(rows // SUB == cols // SUB, x, 0.0)
+    of_blocks = inverse(d, SUB)
+    return _dot(inverse(_dot(of_blocks, x - d), C // SUB), of_blocks)
+
+
+@jax.custom_vjp
+def _inverse(x):
+    """:func:`_products_inverse` with the inverse's own derivative: the
+    products ARE ``M = (I + x)^-1`` (every power they drop is zero), so
+    ``dx = -M^T dM M^T``, two products where autodiff through the ten takes
+    twenty."""
+    return _products_inverse(x)
+
+
+def _inverse_fwd(x):
+    inv = _products_inverse(x)
+    return inv, inv
+
+
+def _inverse_bwd(inv, dinv):
+    return (-_dot(_dot(inv, dinv, _TN), inv, _NT),)
+
+
+_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _state_free(q, k, g, beta):
+    """The half of a chunk that no state enters. q, k, g [C, dk]; beta [C,
+    1]; float32 -> (``P`` masked [C, C], the chunk inverse [C, C], ``K
+    exp(G)``, ``Q exp(G)``, ``K exp(G_C - G)`` [C, dk], ``exp(G_C)`` [1,
+    dk])."""
     C = q.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
@@ -105,29 +161,29 @@ def _chunk(q, k, v, g, beta, st):
         both = _dot(jnp.concatenate([k[at] * up, q[at] * up]), down, _NT)
         a_rows.append(both[:SUB])
         p_rows.append(both[SUB:])
-    x = beta * jnp.where(rows > cols, jnp.concatenate(a_rows), 0.0)
+    inv = _inverse(beta * jnp.where(rows > cols, jnp.concatenate(a_rows), 0.0))
     p = jnp.where(rows >= cols, jnp.concatenate(p_rows), 0.0)
-    eye = (rows == cols).astype(_F32)
-
-    def inverse(y, order):  # (I + y)^-1 for y^order = 0: (I - y)(I + y^2)(I + y^4)...
-        inv, power, n = eye - y, y, 2
-        while n < order:
-            power = _dot(power, power)
-            inv = inv + _dot(inv, power)
-            n *= 2
-        return inv
-
-    # I + X = (I + D)(I + (I + D)^-1 L), D the diagonal sub-blocks of X
-    d = jnp.where(rows // SUB == cols // SUB, x, 0.0)
-    of_blocks = inverse(d, SUB)
-    inv = _dot(inverse(_dot(of_blocks, x - d), C // SUB), of_blocks)
     decay = jnp.exp(G)
-    from_state = _dot(jnp.concatenate([k * decay, q * decay]), st, _NT)  # [2C, dv]
+    g_end = jnp.sum(g, axis=0, keepdims=True)  # [1, dk]
+    return p, inv, k * decay, q * decay, k * jnp.exp(g_end - G), jnp.exp(g_end)
+
+
+def _through_state(p, inv, k_in, q_in, k_out, decay, v, beta, st):
+    """The half that the state enters: :func:`_state_free`'s six, v [C, dv],
+    beta [C, 1] and st [dv, dk], the state TRANSPOSED (its decay is then a
+    row broadcast over sublanes) -> (o [C, dv], the state after the
+    chunk)."""
+    C = v.shape[0]
+    from_state = _dot(jnp.concatenate([k_in, q_in]), st, _NT)  # [2C, dv]
     u = _dot(inv, beta * (v - from_state[:C]))
     o = from_state[C:] + _dot(p, u)
-    g_end = jnp.sum(g, axis=0, keepdims=True)  # [1, dk]
-    st = st * jnp.exp(g_end) + _dot(u, k * jnp.exp(g_end - G), _TN)
+    st = st * decay + _dot(u, k_out, _TN)
     return o, st.astype(STATE_DTYPE).astype(_F32)
+
+
+def _chunk(q, k, v, g, beta, st):
+    """One chunk of one head, all float32: the two halves composed."""
+    return _through_state(*_state_free(q, k, g, beta), v, beta, st)
 
 
 def _turned(x, axis):
@@ -141,13 +197,11 @@ def _turned(x, axis):
 
 
 def _chunks(q_ref, k_ref, v_ref, g_ref, b_ref):
-    """The block's chunks in order, each as :func:`_chunk`'s first five
-    arguments."""
-    beta = _turned(b_ref[...], 1)  # [BLOCK, 1]
-    for lo in range(0, BLOCK, CHUNK):
-        at = slice(lo, lo + CHUNK)
-        yield at, (q_ref[at, :].astype(_F32), k_ref[at, :].astype(_F32),
-                   v_ref[at, :].astype(_F32), g_ref[at, :], beta[at])
+    """The block as its chunks, float32: q, k, g [n, CHUNK, dk]; v [n, CHUNK,
+    dv]; beta [n, CHUNK, 1]."""
+    return tuple(m.reshape(BLOCK // CHUNK, CHUNK, m.shape[-1]) for m in (
+        q_ref[...].astype(_F32), k_ref[...].astype(_F32), v_ref[...].astype(_F32),
+        g_ref[...], _turned(b_ref[...], 1)))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, hs_ref, st_scr):
@@ -157,36 +211,41 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, hs_ref, st_scr):
 
     st = st_scr[...]
     hs_ref[...] = st  # the state this block starts from
-    for at, args in _chunks(q_ref, k_ref, v_ref, g_ref, b_ref):
+    for i, args in enumerate(zip(*_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))):
         o, st = _chunk(*args, st)
-        o_ref[at, :] = o.astype(o_ref.dtype)
+        o_ref[i * CHUNK:(i + 1) * CHUNK, :] = o.astype(o_ref.dtype)
     st_scr[...] = st
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, hs_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dst_scr):
-    """One block, the blocks in reverse: the states its later chunks start
-    from are computed again from the saved one, then the chunks are walked
-    backwards; ``dst_scr`` carries the cotangent of the state a block ends
-    with into the block before."""
+    """One block, the blocks in reverse, each half of a chunk linearised
+    ONCE: the state-free half of the block's chunks together (``vmap``: four
+    independent chains of small products, side by side where the MXU can
+    overlap them), then the state half chunk by chunk from the saved state on,
+    which is the states pass (no ``o`` is taken). The walk goes back through
+    the state halves, ``dst_scr`` carrying the cotangent of the state a block
+    ends with into the block before, and hands what it gathered to the
+    state-free half's one pullback."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dst_scr[...] = jnp.zeros_like(dst_scr)
 
-    chunks = list(_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))
-    starts = [hs_ref[...]]
-    for _, args in chunks[:-1]:
-        starts.append(_chunk(*args, starts[-1])[1])
-    dst, dbs = dst_scr[...], []
-    for (at, args), st in zip(reversed(chunks), reversed(starts)):
-        _, pullback = jax.vjp(_chunk, *args, st)
-        dq, dk, dv, dg, db, dst = pullback((do_ref[at, :].astype(_F32), dst))
-        dq_ref[at, :] = dq.astype(dq_ref.dtype)
-        dk_ref[at, :] = dk.astype(dk_ref.dtype)
-        dv_ref[at, :] = dv.astype(dv_ref.dtype)
-        dg_ref[at, :] = dg
-        dbs.append(db)
-    db_ref[...] = _turned(jnp.concatenate(dbs[::-1]), 0)
+    q, k, v, g, beta = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref)
+    free, into_free = jax.vjp(jax.vmap(_state_free), q, k, g, beta)
+    st, into_states = hs_ref[...], []
+    for i in range(len(v)):
+        (_, st), into = jax.vjp(_through_state, *(m[i] for m in free), v[i], beta[i], st)
+        into_states.append(into)
+    dst, back = dst_scr[...], []
+    for i in reversed(range(len(v))):
+        *rest, dst = into_states[i]((do_ref[i * CHUNK:(i + 1) * CHUNK, :].astype(_F32), dst))
+        back.append(rest)
+    *dfree, dv, db = (jnp.stack(m[::-1]) for m in zip(*back))
+    dq, dk, dg, db_free = into_free(tuple(dfree))
+    for ref, m in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+        ref[...] = m.reshape(ref.shape).astype(ref.dtype)
+    db_ref[...] = _turned((db + db_free).reshape(BLOCK, 1), 0)
     dst_scr[...] = dst
 
 
@@ -199,8 +258,8 @@ def _params():
         return {"interpret": True}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        # the backward kernel's unrolled chunks keep 17 MB of temporaries;
-        # the default scope is 16 of the v5e's 128
+        # the backward kernel's unrolled chunks keep 12 MB of temporaries
+        # under a default scope of 16 of the v5e's 128: room, not need
         vmem_limit_bytes=64 * 2**20)}
 
 

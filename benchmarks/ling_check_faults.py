@@ -54,10 +54,11 @@ def _faults(pc):
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.models import ling, moe
+    from torchft_tpu.models import ling, mla, moe
     from torchft_tpu.ops import kda as kda_ops
 
-    scan, conv, rope, norm = ling.kda, ling._short_conv, ling._rope, ling._rmsnorm
+    # the latent's norm is the shared mixer's (models/mla.py)
+    scan, conv, rope, norm = ling.kda, ling._short_conv, ling._rope, mla._rmsnorm
     ffn, choose, gmm, dot = ling.moe_ffn, moe._choose, moe._grouped_matmul, kda_ops._dot
 
     def ungrouped(decide, cfg):
@@ -73,7 +74,7 @@ def _faults(pc):
             ling, "_short_conv", lambda x, w: conv(x, w.at[0].set(0))),
         "no_rope": lambda: _patched(ling, "_rope", lambda x, theta, positions: x),
         "no_latent_norm": lambda: _patched(
-            ling, "_rmsnorm", lambda x, w, eps: x if x.shape[-1] == pc.kv_lora_rank
+            mla, "_rmsnorm", lambda x, w, eps: x if x.shape[-1] == pc.kv_lora_rank
             else norm(x, w, eps)),
         "no_group_limit": lambda: _patched(moe, "_within_groups", ungrouped),
         "no_shared": lambda: _patched(

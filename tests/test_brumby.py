@@ -92,11 +92,13 @@ def test_the_counters_ride_the_loss_under_the_names_a_trainer_logs(both):
 
 
 def test_the_kind_is_the_registrys_only_new_entry():
-    """Nine configuration classes, each its own kind; a BrumbyConfig is a
-    LlamaConfig and is Brumby's; its preset stands in ``CONFIGS``."""
+    """The configuration classes, each its own kind (PR 59 added the
+    tenth); a BrumbyConfig is a LlamaConfig and is Brumby's; its preset
+    stands in ``CONFIGS``."""
     names = sorted(c.__name__ for c in kinds._KINDS)
-    assert names == ["BrumbyConfig", "JambaConfig", "Lfm2Config", "LingConfig", "LlamaConfig",
-                     "MellumConfig", "MoEConfig", "NemotronHConfig", "OuroConfig"]
+    assert names == ["BrumbyConfig", "DeepseekConfig", "JambaConfig", "Lfm2Config",
+                     "LingConfig", "LlamaConfig", "MellumConfig", "MoEConfig",
+                     "NemotronHConfig", "OuroConfig"]
     m = model_fns(DEBUG)
     assert m.init is M.brumby_init and m.stages is None and m.frozen == ()
     assert model_fns(CONFIGS["debug"]).init is llama.llama_init

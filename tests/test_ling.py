@@ -185,7 +185,8 @@ def test_the_staged_kinds_head_programs_are_the_parents(name):
 
 
 def _kinds():
-    from torchft_tpu.models import brumby, jamba, lfm2, llama, mellum, nemotron_h, ouro
+    from torchft_tpu.models import (brumby, deepseek, jamba, lfm2, llama, mellum, nemotron_h,
+                                    ouro)
     from torchft_tpu.parallel.mesh import llama_param_specs
 
     # class -> (init, param_specs, whether the gradient is staged, frozen)
@@ -200,6 +201,8 @@ def _kinds():
         nemotron_h.NemotronHConfig: (nemotron_h.nemotron_h_init, nemotron_h.nemotron_h_param_specs,
                                      False, ("expert_bias",)),
         brumby.BrumbyConfig: (brumby.brumby_init, brumby.brumby_param_specs, False, ()),
+        deepseek.DeepseekConfig: (deepseek.deepseek_init, deepseek.deepseek_param_specs,
+                                  False, ()),
     }
 
 

@@ -16,8 +16,8 @@ def _register_presets() -> None:
     stand in it beside the dense ones under their own names. Importing a
     kind's module is what registers the kind (``models/kinds.py``) and its
     presets: a new kind adds its module to this line and nothing else here."""
-    from torchft_tpu.models import (brumby, jamba, lfm2, ling, mellum, moe,  # noqa: F401
-                                    nemotron_h, ouro)
+    from torchft_tpu.models import (brumby, deepseek, jamba, lfm2, ling, mellum,  # noqa: F401
+                                    moe, nemotron_h, ouro)
 
     for _, presets in _KINDS.values():
         for name, cfg in presets.items():

@@ -1,5 +1,6 @@
 """The one decoder over RUNS of like layers: what every kind whose layers
-are unlike (``jamba``, ``lfm2``, ``ling``, ``mellum``, ``nemotron_h``) repeated, once.
+are unlike (``jamba``, ``lfm2``, ``ling``, ``mellum``, ``nemotron_h``, ``deepseek``)
+repeated, once.
 
 Such a kind keeps one stack of parameters per run of like layers
 (``params["layers"]["00_mamba"]`` [7, ...], ``["01_attn"]`` [1, ...]; the
@@ -119,7 +120,8 @@ class Decoder:
     of ``params["expert_bias"]`` (if the tree has it) and ``replay`` its rows
     of ``routing`` (if given); else None. ``counters(stats, tokens, cfg)``
     -> what ``loss_and_stats`` hands out beside the loss, from the runs'
-    stats stacked over the layers that have any."""
+    stats stacked over the layers that have any (beside ``aux_loss``, where
+    the expert layers emit the sequence-wise balance term)."""
 
     bodies: Callable[..., Callable[[Any], Callable[..., Any]]]
     counters: Callable[..., Dict[str, jax.Array]]
@@ -168,10 +170,16 @@ class Decoder:
                        ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Mean next-token cross-entropy (``llama_loss``'s; ``loss_chunk``
         as there, and 0 takes ``cfg.loss_chunk`` where that divides a longer
-        sequence: 8,192 x 65,536 float32 logits are 2 GiB) and ``counters``."""
+        sequence: 8,192 x 65,536 float32 logits are 2 GiB), plus
+        ``cfg.aux_loss_weight`` x the expert layers' sequence-wise balance
+        terms summed where they emit one (``cfg.seq_aux``; the sum rides the
+        counters as ``aux_loss``), and ``counters``."""
         h, stats = self.hidden(params, tokens, cfg, attention_fn, remat, routing)
-        return (head_loss(h, _head(params), targets, loss_chunk_for(cfg, tokens.shape[1], loss_chunk)),
-                self.counters(stats, tokens, cfg))
+        loss = head_loss(h, _head(params), targets, loss_chunk_for(cfg, tokens.shape[1], loss_chunk))
+        if "seq_aux" in stats:
+            stats["aux_loss"] = jnp.sum(stats.pop("seq_aux"))
+            loss = loss + cfg.aux_loss_weight * stats["aux_loss"]
+        return loss, self.counters(stats, tokens, cfg)
 
     def loss(self, *args: Any, **kw: Any) -> jax.Array:
         """:meth:`loss_and_stats`' loss alone (``llama_loss``'s shape)."""

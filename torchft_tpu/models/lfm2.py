@@ -286,5 +286,6 @@ def lfm2_param_specs(cfg: Lfm2Config, mesh: Optional[Any] = None) -> Dict[str, A
 
 
 register(Lfm2Config, LFM2_CONFIGS, lambda: ModelFns(
-    lfm2_init, logged(lfm2_loss_and_stats, moe=("load_max_over_mean", "bias_moved_share")),
+    lfm2_init, logged(lfm2_loss_and_stats,
+                      moe=("aux_loss", "load_max_over_mean", "bias_moved_share")),
     lfm2_param_specs, None, LFM2_FROZEN))

@@ -19,7 +19,8 @@ The ``kda`` mixer (H heads of ``kda_head_dim``)::
     o       = kda(q, k, v, g, beta)                             # ops/kda.py
     out     = (rmsnorm_head(o) * sigmoid(u @ w_g)) @ wo         # gate: one a head
 
-The ``mla`` mixer: ``q = u @ wq`` split a head into 128 without position
+The ``mla`` mixer (``models/mla.py``'s, which ``models/deepseek.py`` calls
+too): ``q = u @ wq`` split a head into 128 without position
 and 64 rotary; ``c, k_r = split(u @ w_kva)``, ``c`` RMS-normalised, ``k_n, v
 = split(c @ w_kvb)``; RoPE on ``q``'s 64 and on ``k_r`` (which all heads
 share), their stored pairs interleaved (``rope_interleave``); causal
@@ -51,20 +52,18 @@ body, the PartitionSpecs and the counters, and declares them (``LING``).
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
 from torchft_tpu.models.kinds import ModelFns, logged, register
 from torchft_tpu.models.llama import _attention, _rmsnorm, _rope
+from torchft_tpu.models.mla import _head_gate, mla_mixer
 from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
                                     ffn_leaves, ffn_specs, moe_ffn)
-from torchft_tpu.models.remat import ATTN_OUT_NAME
 from torchft_tpu.ops.kda import kda
 
 __all__ = [
@@ -230,12 +229,6 @@ def ling_init(key: jax.Array, cfg: LingConfig) -> Dict[str, Any]:
     return params
 
 
-def _head_gate(o: jax.Array, u: jax.Array, w_g: jax.Array) -> jax.Array:
-    """o [B,S,H,dv] times the sigmoid of one value a head -> [B,S,H*dv]."""
-    gate = jax.nn.sigmoid(jnp.matmul(u, w_g, preferred_element_type=_F32))
-    return (o * gate.astype(o.dtype)[..., None]).reshape(*o.shape[:2], -1)
-
-
 # SiLU of the depthwise causal convolution, x [B,T,di], w [k,di], no bias:
 # the one program every kind's mixer runs (``decoder._causal_conv``)
 _short_conv = partial(_causal_conv, b=None)
@@ -269,40 +262,14 @@ def _kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig) -> jax.Ar
         return _head_gate(_rmsnorm(o, w["o_norm"], cfg.norm_eps), u, w["w_g"]) @ w["wo"]
 
 
-def _pairs_apart(x: jax.Array) -> jax.Array:
-    """``rope_interleave``: the stored rotary values pair (0, 1), (2, 3)...;
-    -> the first of every pair, then the second, which is how ``_rope``
-    pairs them."""
-    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-
-
 def _mla_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig,
                attention: Any) -> jax.Array:
-    (B, S, _), H = u.shape, cfg.n_heads
-    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-                     cfg.kv_lora_rank)
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    rope = lambda m: _rope(_pairs_apart(m), cfg.rope_theta, positions)  # noqa: E731
-    with jax.named_scope("mla/q"):
-        q = (u @ w["wq"]).reshape(B, S, H, dn + dr)
-        q_r = rope(q[..., dn:])
-    with jax.named_scope("mla/kv"):
-        ckr = u @ w["w_kva"]
-        c = _rmsnorm(ckr[..., :r], w["kv_norm"], cfg.norm_eps)
-        k_r = rope(ckr[..., None, r:])  # [B,S,1,dr]: one for all heads
-        kv = (c @ w["w_kvb"]).reshape(B, S, H, dn + dv)
-    with jax.named_scope("mla/attn"):
-        # the dispatcher scales by 1 / sqrt(the width it is given)
-        width = next(n for n in (64, 128, 256) if n >= dn + dr)  # what the kernels tile
-        zeros = jnp.zeros((B, S, H, width - dn - dr), u.dtype)
-        scale = jnp.asarray(math.sqrt(width / (dn + dr)), u.dtype)
-        qq = jnp.concatenate([q[..., :dn], q_r, zeros], axis=-1) * scale
-        kk = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr)), zeros], axis=-1)
-        attn = jax.ad_checkpoint.checkpoint_name(
-            attention(qq, kk, kv[..., dn:], cfg), ATTN_OUT_NAME)
-    with jax.named_scope("mla/out"):
-        return _head_gate(attn, u, w["w_g"]) @ w["wo"]
+    """``models/mla.py``'s mixer as this family has it: every head, the
+    plain rotary table, the softmax scale as it is; ``w`` has no queries'
+    latent and has the head-wise gate."""
+    positions = jnp.broadcast_to(jnp.arange(u.shape[1]), u.shape[:2])
+    return mla_mixer(u, w, cfg, attention,
+                     lambda m: _rope(m, cfg.rope_theta, positions), cfg.n_heads)
 
 
 def _layer_body(cfg: LingConfig, kind: Tuple[str, str], attention: Any):
@@ -376,5 +343,5 @@ def ling_param_specs(cfg: LingConfig, mesh: Optional[Any] = None) -> Dict[str, A
 
 register(LingConfig, LING_CONFIGS, lambda: ModelFns(
     ling_init, logged(ling_loss_and_stats, moe=(
-        "load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
+        "aux_loss", "load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
         "visited_row_share", "moved_row_share", "groups_hit_mean")), ling_param_specs, None, LING_FROZEN))

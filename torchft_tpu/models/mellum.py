@@ -136,19 +136,22 @@ MELLUM_CONFIGS: Dict[str, MellumConfig] = {
 }
 
 
-def _plain_inv_freq(cfg: MellumConfig) -> jax.Array:
-    """The plain rotary frequencies ``theta^(-2i / head_dim)`` [head_dim / 2]."""
-    hd = cfg.head_dim
+def _plain_inv_freq(cfg: Any, dim: Optional[int] = None) -> jax.Array:
+    """The plain rotary frequencies ``theta^(-2i / hd)`` [hd / 2], ``hd`` the
+    rotary width: ``dim``, or the configuration's ``head_dim``."""
+    hd = dim or cfg.head_dim
     return 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, dtype=_F32) / hd))
 
 
-def yarn_inv_freq(cfg: MellumConfig) -> jax.Array:
-    """The full layers' rotary frequencies [head_dim / 2], float32: pair
-    ``i`` of the plain table ``theta^(-2i / head_dim)`` kept below ``low``,
-    divided by ``yarn_factor`` above ``high``, blended between, where
-    ``low`` and ``high`` are the pairs that turn ``beta_fast`` and
-    ``beta_slow`` times over ``yarn_original_max`` positions."""
-    hd = cfg.head_dim
+def yarn_inv_freq(cfg: Any, dim: Optional[int] = None) -> jax.Array:
+    """YaRN's rotary frequencies [hd / 2], float32 (the full layers' here;
+    ``models/deepseek.py``'s over its ``dim`` = 64 rotary dimensions, from
+    the same ``yarn_*`` fields): pair ``i`` of the plain table ``theta^(-2i
+    / hd)`` kept below ``low``, divided by ``yarn_factor`` above ``high``,
+    blended between, where ``low`` and ``high`` are the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over ``yarn_original_max``
+    positions."""
+    hd = dim or cfg.head_dim
 
     def pair_of(turns: float) -> float:
         return (hd * math.log(cfg.yarn_original_max / (turns * 2 * math.pi))
@@ -156,7 +159,7 @@ def yarn_inv_freq(cfg: MellumConfig) -> jax.Array:
 
     low = max(math.floor(pair_of(cfg.yarn_beta_fast)), 0)
     high = min(math.ceil(pair_of(cfg.yarn_beta_slow)), hd - 1)
-    plain = _plain_inv_freq(cfg)
+    plain = _plain_inv_freq(cfg, hd)
     ramp = jnp.clip((jnp.arange(hd // 2, dtype=_F32) - low) / max(high - low, 1e-3), 0, 1)
     return (1 - ramp) * plain + ramp * plain / cfg.yarn_factor
 
@@ -287,6 +290,6 @@ def mellum_param_specs(cfg: MellumConfig, mesh: Optional[Any] = None) -> Dict[st
 register(MellumConfig, MELLUM_CONFIGS, lambda: ModelFns(
     mellum_init, logged(
         mellum_loss_and_stats,
-        moe=("load_max_over_mean", "held_pair_share", "overflow_pairs", "visited_row_share",
-             "moved_row_share"),
+        moe=("aux_loss", "load_max_over_mean", "held_pair_share", "overflow_pairs",
+             "visited_row_share", "moved_row_share"),
         attn=("window_layers", "full_layers", "window_block_share")), mellum_param_specs, None))

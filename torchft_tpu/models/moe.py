@@ -76,6 +76,7 @@ __all__ = [
     "moe_stages",
     "moe_ffn",
     "load_balancing_loss",
+    "sequence_balance_loss",
     "expert_scalars",
     "ffn_leaves",
     "ffn_init",
@@ -98,10 +99,18 @@ class MoEConfig(LlamaConfig):
     router_score: str = "softmax"  # or "sigmoid": each expert scored by itself
     gate_eps: float = 1e-9  # beside the chosen gates' sum where renormalised
     # group-limited choice: the experts lie in ``n_group`` equal groups, a
-    # token keeps the ``topk_group`` best (a group scored by the sum of its
-    # best two) and chooses inside them; 1 group: a plain top-k
+    # token keeps the ``topk_group`` best and chooses inside them; 1 group: a
+    # plain top-k. ``topk_method`` (the published key) is how a group is
+    # scored: "noaux_tc" by the sum of its best two, "group_limited_greedy"
+    # by its best one
     n_group: int = 1
     topk_group: int = 1
+    topk_method: str = "noaux_tc"
+    # the auxiliary loss of the kinds over the dropless block (the published
+    # key): ``aux_loss_weight`` x :func:`sequence_balance_loss` of every
+    # expert layer, each sequence balanced by itself, which
+    # ``decoder.Decoder.loss_and_stats`` adds for every such kind
+    seq_aux: bool = False
     routed_scaling: float = 1.0  # a factor on every gate
     # (first, count): this chip holds experts first .. first + count - 1 of
     # the router's ``num_experts`` and computes their part alone (the
@@ -132,6 +141,14 @@ class MoEConfig(LlamaConfig):
         if self.num_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
             raise ValueError(f"n_group={self.n_group}, topk_group={self.topk_group} "
                              f"of {self.num_experts} experts")
+        if self.topk_method not in ("noaux_tc", "group_limited_greedy"):
+            raise ValueError(f"topk_method={self.topk_method!r}: a group is scored by the "
+                             "sum of its best two ('noaux_tc') or by its best "
+                             "('group_limited_greedy')")
+        if self.seq_aux and type(self) is MoEConfig:
+            raise ValueError("seq_aux: the sequence-wise term is the kinds' over "
+                             "models/decoder.py; this kind's auxiliary loss is "
+                             "load_balancing_loss over all layers at once")
         if self.held_experts is not None:
             first, count = self.held_experts
             if self.capacity_factor is not None or first < 0 or count < 1 \
@@ -149,9 +166,16 @@ class MoEConfig(LlamaConfig):
         other = sorted(set(self.layer_types) - set(known))
         if other:
             raise ValueError(f"layer_types {other}: {type(self).__name__} mixes with {known}")
-        if self.capacity_factor is not None or self.aux_loss_weight:
+        self._check_dropless_block(dense_layers)
+
+    def _check_dropless_block(self, dense_layers: int = 0) -> None:
+        """What every kind over the dropless block refuses, whatever its
+        mixers: a capacity, an auxiliary loss other than the sequence-wise
+        one (``seq_aux``), more leading dense layers than layers."""
+        if self.capacity_factor is not None or (self.aux_loss_weight and not self.seq_aux):
             raise ValueError("capacity_factor / aux_loss_weight: the family's "
-                             "expert block drops nothing and has no auxiliary loss")
+                             "expert block drops nothing and has no auxiliary loss "
+                             "but the sequence-wise one (seq_aux)")
         if not 0 <= dense_layers <= self.n_layers:
             raise ValueError(f"num_dense_layers={dense_layers} of {self.n_layers} layers")
 
@@ -271,12 +295,16 @@ def _within_groups(decide: jax.Array, cfg: MoEConfig):
     """The group limit. decide [T, E] -> (``decide`` with the experts of
     every group but the token's ``topk_group`` best at -inf, the score of
     its last kept group, that of its best dropped one (equal where no group
-    is dropped)). A group's score is the sum of its best two."""
+    is dropped)). A group's score is ``cfg.topk_method``'s: the sum of its
+    best two, or its best one."""
     T, E = decide.shape
     groups = cfg.n_group
-    best_two = jax.lax.top_k(decide.reshape(T, groups, E // groups), 2)[0]
-    top_g, top_i = jax.lax.top_k(jnp.sum(best_two, axis=-1),
-                                 min(cfg.topk_group + 1, groups))
+    grouped = decide.reshape(T, groups, E // groups)
+    if cfg.topk_method == "group_limited_greedy":
+        score = jnp.max(grouped, axis=-1)
+    else:
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    top_g, top_i = jax.lax.top_k(score, min(cfg.topk_group + 1, groups))
     kept = jnp.any(top_i[:, :cfg.topk_group, None]
                    == jnp.arange(groups, dtype=top_i.dtype), axis=1)  # [T, groups]
     kept = jnp.repeat(kept, E // groups, axis=1)
@@ -725,7 +753,9 @@ def moe_ffn(
     over tokens: the differentiable half of the auxiliary loss), the
     free routing with its margins (``routing``, ``p_kth``, ``p_next``: of
     ``scores + bias``, what decided) and, under a bias, ``bias_moved``: the
-    share of tokens whose k experts would be others without it.
+    share of tokens whose k experts would be others without it; where
+    ``cfg.seq_aux``, ``seq_aux``: the layer's :func:`sequence_balance_loss`
+    under the routing in effect (differentiable: the caller's loss adds it).
 
     What the configuration may add, each absent unless it says so:
     ``cfg.n_group`` > 1 limits a token's choice to its ``topk_group`` best
@@ -761,6 +791,10 @@ def moe_ffn(
             free["groups_hit"] = _groups_hit(idx, cfg)
         if cfg.held_experts is None:
             sizes = _counts(idx, cfg.num_experts)
+    if cfg.seq_aux:
+        with jax.named_scope("moe/aux"):
+            free["seq_aux"] = sequence_balance_loss(
+                probs.reshape(B, S, -1), idx.reshape(B, S, -1))
     payload = flat.astype(w_up.dtype)  # the router saw x as it came
     if cfg.held_experts is not None:
         out, share = _share_ffn(payload, gates, idx, cfg, w_gate, w_up, w_down)
@@ -788,9 +822,27 @@ def load_balancing_loss(counts: jax.Array, prob_sum: jax.Array, tokens: int) -> 
     (sums to k) and P_e the mean probability of e. counts, prob_sum: [L, E]
     per layer; ``tokens`` per layer. Even load gives k."""
     n = counts.shape[0] * tokens
-    f = jnp.sum(counts, axis=0) / n
-    p = jnp.sum(prob_sum, axis=0) / n
-    return counts.shape[1] * jnp.sum(jax.lax.stop_gradient(f) * p)
+    return _balance(jnp.sum(counts, axis=0) / n, jnp.sum(prob_sum, axis=0) / n)
+
+
+def _balance(f: jax.Array, p: jax.Array) -> jax.Array:
+    """E * sum_e f_e * P_e over the last axis, ``f`` (the load) a constant:
+    what the two auxiliary losses share."""
+    return f.shape[-1] * jnp.sum(jax.lax.stop_gradient(f) * p, axis=-1)
+
+
+def sequence_balance_loss(probs: jax.Array, idx: jax.Array) -> jax.Array:
+    """DeepSeek-V2's ``seq_aux`` term of one expert layer: probs [B, S, E]
+    (the router's scores over ALL its outputs), idx [B, S, k] (the experts in
+    effect) -> the mean over sequences of ``sum_e f_e P_e``, ``f_e`` = (the
+    sequence's tokens that chose e among their k) x E / (k S), no gradient;
+    ``P_e`` = e's mean score over the sequence. Even load gives 1. A chip
+    that holds a share of the experts has the whole router, so the term is
+    the deployment's own."""
+    (B, S, E), k = probs.shape, idx.shape[-1]
+    hot = idx.reshape(B, S * k, 1) == jnp.arange(E, dtype=idx.dtype)
+    f = jnp.sum(hot, axis=1, dtype=jnp.float32) / (S * k)
+    return jnp.mean(_balance(f, jnp.mean(probs, axis=1)))
 
 
 def expert_scalars(stats: Dict[str, jax.Array], pairs: int,

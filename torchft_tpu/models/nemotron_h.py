@@ -119,9 +119,7 @@ class NemotronHConfig(MoEConfig):
                              "feed-forward alone, which it has not")
         if not self.use_conv_bias:
             raise ValueError("use_conv_bias=False: the convolution has a bias here")
-        if self.capacity_factor is not None or self.aux_loss_weight:
-            raise ValueError("capacity_factor / aux_loss_weight: the family's "
-                             "expert block drops nothing and has no auxiliary loss")
+        self._check_dropless_block()
         if self.mamba_num_heads % self.mamba_n_groups:
             raise ValueError(f"mamba_n_groups={self.mamba_n_groups} of "
                              f"{self.mamba_num_heads} heads")
@@ -335,8 +333,8 @@ NEMOTRON_H = Decoder(_bodies, _counters, routed=lambda kind: kind == "moe")
 nemotron_h_hidden, nemotron_h_forward = NEMOTRON_H.hidden, NEMOTRON_H.forward
 nemotron_h_loss_and_stats, nemotron_h_loss = NEMOTRON_H.loss_and_stats, NEMOTRON_H.loss
 
-_LOGGED_MOE = ("load_max_over_mean", "bias_moved_share", "held_pair_share", "overflow_pairs",
-               "visited_row_share", "moved_row_share")
+_LOGGED_MOE = ("aux_loss", "load_max_over_mean", "bias_moved_share", "held_pair_share",
+               "overflow_pairs", "visited_row_share", "moved_row_share")
 _LOGGED_OWN = ("ssd_layers", "attn_layers", "moe_layers", "ssd_dt_mean",
                "ssd_chunk_log_decay_min")
 

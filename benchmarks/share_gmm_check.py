@@ -1,9 +1,10 @@
-"""A share's grouped products (ISSUE 51, step 0) and its row moves (ISSUE 55,
-step 0) alone on the chip, at the three share cells' shapes, bf16, tiles as
-``models/moe._grouped_matmul`` sets them: Mellum's buffer (131,072 rows x
+"""A share's grouped products (ISSUE 51, step 0) and its row moves (ISSUEs 55
+and 60, step 0) alone on the chip, at the four share cells' shapes, bf16, tiles
+as ``models/moe._grouped_matmul`` sets them: Mellum's buffer (131,072 rows x
 2304, 16 matrices of 2304 x 896, an even share of 65,536 pairs), Ling's
-(49,152 x 2560, 16 of 2560 x 768, even share 8,192) and Nemotron's (24,576 x
-2688, 8 ungated experts of 2688 x 1856, even share 6,144).
+(49,152 x 2560, 16 of 2560 x 768, even share 8,192), Nemotron's (24,576 x
+2688, 8 ungated experts of 2688 x 1856, even share 6,144) and DeepSeek-V2's
+(15,360 x 5120, 10 of 5120 x 1536, even share 6,144).
 
 Part 1, ``products``: ``silu(rows @ w_gate) * (rows @ w_up) @ w_down`` forward
 and forward + backward (the rows' and the three stacks' cotangents: nine
@@ -38,20 +39,34 @@ makes them from ``--seed``, one forward pass with the stats: ``held_pair_share``
 step's time follows them.
 
 Part 4, ``rows``: the two row moves alone (the dispatch's gather into the
-buffer, the combine's weighed scatter-add out of it, and both pullbacks; no
-product between them), value and the gradients of the tokens and the weights,
-at loads of 0, 0.7, 1.0 and 1.5 x the even share and at the room exactly: as
-XLA's one gather and one scatter-add over every row (``whole``: PR 51's form)
-and as ``moe._share_take`` / ``moe._share_add`` (the two gathers, the
-dispatch's and the combine's pullback's, loops over row tiles that end with
-the pairs; the two scatter-adds XLA's own over every row) at each of ``TILES``:
-wall and device-busy seconds a call, the tiles moved, and the largest
-difference from the whole form (0.0: the same sums in the same order).
-Expected: ``whole`` flat in the load, the looped gathers linear in the moved
-tiles over a small floor. (All four moves as loops, the first form tried, were
-linear too, but a tile's scatter-add costs 2.3 x a row of XLA's sorted one over
-the whole buffer; a ``lax.switch`` over static prefixes grows the program by
-0.6 GB: PERF.md section 6, PR 55.)
+buffer, the combine's weighed add out of it, and both pullbacks; no product
+between them), value and the gradients of the tokens and the weights, at
+loads of 0, 0.7, 1.0 and 1.5 x the even share and at the room exactly, in
+four forms: ``whole`` (PR 51's: XLA's one gather and one scatter-add over
+every row), ``token_order`` (``moe._share_take`` / ``moe._share_add`` as they
+are: the two gathers loops over row tiles that end with the pairs, PR 55; the
+two adds in token order, PR 60: one sort of the held pairs' tokens, the rows
+gathered into that order, XLA's scatter-add of them sorted, in column blocks
+of ``moe.ADD_COLUMNS`` at most) and ``token_order_one_call`` (the same as ONE
+scatter-add whatever the width: what ISSUE 60 asked for first) and
+``token_order_kernel`` (the same with ``benchmarks/share_add_kernel.py`` for
+the scatter-add: measured, and not in the program for its rounding). Wall and
+device-busy seconds a call, each leaf's largest difference from the whole
+form, and at the even share the device's operations one by one (instruction,
+the tail of its ``op_name``, seconds a call). What step 0 of PR 60 found
+(PERF.md section 6): XLA sorts and gathers before EVERY one of these
+scatter-adds, at DeepSeek's shape too, and ``indices_are_sorted=True`` changes
+nothing there; what is slow is its sorted scatter-add at a width of 5,120:
+30.3 ms for 15,360 rows, 32.2 for 32,768 and 35.8 for 65,536, so 28.5 ms
+whatever the rows (its pass over the OPERAND's 16,384 rows, 1.7 us each) and
+110 ns a row, where rows of 2,304 to 2,688 take 60 to 115 ns. In column blocks
+it is fast again (two blocks of 2,560: 4.0 ms, five of 1,024: 2.7). The
+kernel, which adds a token's run as a one-hot product, took 0.75 ms there,
+1.42 on Mellum (XLA 7.90), 0.69 on Ling (4.61), 0.41 on Nemotron (2.82).
+(Earlier, PR 55: all four moves as loops were linear in
+the load too, but a tile's scatter-add costs 2.3 x a row of XLA's sorted one
+over the whole buffer; a ``lax.switch`` over static prefixes grows the program
+by 0.6 GB.)
 
     chiprun -- python3 benchmarks/share_gmm_check.py [products|block|rows ...] [<shape> ...]
     chiprun -- python3 benchmarks/share_gmm_check.py loads <cell> <seed> ...
@@ -67,6 +82,7 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(1, os.path.dirname(os.path.abspath(__file__)))  # share_add_kernel
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -79,9 +95,9 @@ SHAPES = {
     "mellum": (32768, 8, 64, 16, 2304, 896, 2.0, "swiglu"),
     "ling": (32768, 8, 512, 16, 2560, 768, 6.0, "swiglu"),
     "nemotron": (16384, 6, 128, 8, 2688, 1856, 4.0, "relu2"),
+    "deepseek": (16384, 6, 160, 10, 5120, 1536, 2.5, "swiglu"),
 }
 LOADS = (0.0, 0.7, 1.0, 1.5)
-TILES = (512, 2048)  # ``rows``: the gathers' row tile (``moe.MOVE_TILE``)
 CALLS = 5
 
 
@@ -207,14 +223,14 @@ def products(name):
                      [jnp.all(jnp.isfinite(g.astype(jnp.float32))) for g in grads]))))
 
 
-def whole_take(flat, take, n, tokens):
+def whole_take(flat, take, by_token, n, tokens):
     """``moe._share_take`` as PR 51 left the dispatch: XLA's one gather over
     every row of the buffer, selected after it."""
     valid = (jnp.arange(take.shape[0]) < n)[:, None]
     return jnp.where(valid, flat[take], 0)
 
 
-def whole_add(rows, weights, take, n, tokens):
+def whole_add(rows, weights, take, by_token, n, tokens):
     """``moe._share_add`` as PR 51 left the combine: every row selected and
     weighed, XLA's one scatter-add of them all."""
     valid = (jnp.arange(take.shape[0]) < n)[:, None]
@@ -296,6 +312,29 @@ def block(name):
              rel_diff_whole=worst_diff(got["true"][0], got["whole"][0]))
 
 
+def kernel_add_in_token_order(rows, by_token, tokens):
+    """``moe._add_in_token_order`` with the Pallas kernel of
+    ``benchmarks/share_add_kernel.py`` for XLA's scatter-add: measured by PR 60
+    and not in the program (its docstring says why)."""
+    from share_add_kernel import share_add
+
+    key_s, perm = by_token
+    return share_add(rows[perm], key_s, tokens)
+
+
+def by_operation(fn, args, least_s=5e-5):
+    """[(instruction, its ``op_name``'s tail, seconds a call)] of the device's
+    operations over ``CALLS`` calls, a parent's time less its children's, the
+    longest first, those under ``least_s`` a call left out."""
+    from chipbench import xplane
+    from chipbench.jobs.bare_routed import scopes_of
+
+    took = xplane.self_times(traced_events(fn, args))
+    scope = scopes_of(fn.lower(*args).compile().as_text(), took)
+    return [(name, "/".join(scope.get(name, "").split("/")[-3:]), round(s / CALLS, 6))
+            for name, s in sorted(took.items(), key=lambda kv: -kv[1]) if s / CALLS >= least_s]
+
+
 def rows(name):
     T, k, d, room = (SHAPES[name][i] for i in (0, 1, 4, 6))
     cfg, even, _ = share_of(name)
@@ -309,39 +348,43 @@ def rows(name):
     def make(take_rows, add_rows):
         @jax.jit
         def run(x, weights, take, n):
+            by_token = moe._token_order(take, n, T)  # dead code in the whole form
+
             def value(x, weights):
                 # the barrier stands where the grouped products do: the buffer
                 # is written before it is read, both ways
-                buf = jax.lax.optimization_barrier(take_rows(x, take, n, T))
-                out = add_rows(buf, weights, take, n, T)
+                buf = jax.lax.optimization_barrier(take_rows(x, take, by_token, n, T))
+                out = add_rows(buf, weights, take, by_token, n, T)
                 return jnp.sum((out * cot).astype(jnp.float32)), out
             (_, out), (d_x, d_weights) = jax.value_and_grad(value, argnums=(0, 1),
                                                             has_aux=True)(x, weights)
             return {"out": out, "x": d_x, "weights": d_weights}
         return run
 
-    tiles = [t for t in TILES if rows_n % t == 0]
-    # a form's tile is ``moe.MOVE_TILE`` as it stands when the form is traced
-    forms = {"whole": make(whole_take, whole_add),
-             **{t: make(moe._share_take, moe._share_add) for t in tiles}}
+    # the functions are read at trace time: a jit a form, first called as patched
+    forms = {"whole": (make(whole_take, whole_add), {}),
+             "token_order": (make(moe._share_take, moe._share_add), {}),
+             "token_order_one_call": (make(moe._share_take, moe._share_add),
+                                      {"_add_block": lambda d: d}),
+             "token_order_kernel": (make(moe._share_take, moe._share_add),
+                                    {"_add_in_token_order": kernel_add_in_token_order})}
     for load in LOADS + (room,):  # the last: the room exactly
         idx = draw_idx(rng, name, min(int(load * even), T * k))
         local = jnp.where(idx.reshape(-1) < cfg.n_held, idx.reshape(-1), cfg.n_held)
         n = jnp.sum(local < cfg.n_held).astype(jnp.int32)
         take = jnp.argsort(local, stable=True).astype(jnp.int32)[:rows_n] // k
         args = (x, weights, take, n)
-        want = forms["whole"](*args)
-        line = {"whole_s": wall_seconds(forms["whole"], args),
-                "whole_busy_s": busy_seconds(forms["whole"], args)}
-        for t in tiles:
-            with patched(MOVE_TILE=t):
-                got = forms[t](*args)
-                line[f"t{t}"] = {
-                    "s": wall_seconds(forms[t], args), "busy_s": busy_seconds(forms[t], args),
-                    "tiles": int(moe._moved_tiles(n, rows_n)), "of": rows_n // t,
-                    "finite": all_finite(got),
-                    "rel_diff": max(worst_diff(got, want).values())}
-        emit(part="rows", shape=name, rows=rows_n, even=even, load=load, pairs=int(n), **line)
+        line, want = {}, None
+        for form, (run, fns) in forms.items():
+            with patched(**fns):
+                got = run(*args)
+                want = want or got
+                line[form] = {"s": wall_seconds(run, args), "busy_s": busy_seconds(run, args),
+                              "finite": all_finite(got), "rel_diff": worst_diff(got, want)}
+                if load == 1.0:
+                    line[form]["by_operation"] = by_operation(run, args)
+        emit(part="rows", shape=name, rows=rows_n, even=even, load=load, pairs=int(n),
+             moved_tiles=int(moe._moved_tiles(n, rows_n)), **line)
 
 
 def loads(name, seeds):

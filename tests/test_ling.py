@@ -140,8 +140,18 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # ``custom_vjp_call`` round the same ten products and reads its block
     # whole before cutting it into chunks. The loss is the parent's to the
     # bit, and no other line moved
-    "ling_debug": ("318e97cd85d7635f", "d8f63d1838707a99", "0x1.7f8a480000000p+2"),
-    "mellum_debug": ("d65c6f4355d79a33", "ca31160f100d2593", "0x1.73ce5a0000000p+2"),
+    # Both pinned anew by PR 60: a share's two adds into ``[T, d]`` (the
+    # combine's, the dispatch's pullback's) go in token order: one sort of the
+    # held pairs' tokens a layer (``moe._token_order``), the rows gathered into
+    # that order and XLA's scatter-add of them sorted, in column blocks where
+    # the rows are wider than ``moe.ADD_COLUMNS`` (``moe._add_in_token_order``).
+    # The losses are the parent's to the bit (a stable sort keeps a token's
+    # rows in the buffer's order and the CPU adds them one by one either
+    # way), and every other line of this table and ``STAGED_HEAD`` are as
+    # they were: ``_dropless_ffn`` and ``_capacity_ffn`` lower to what they
+    # did.
+    "ling_debug": ("1af4c5bdba152d88", "3165255fb41f647c", "0x1.7f8a480000000p+2"),
+    "mellum_debug": ("ef928f8d611def70", "aad2023958af691a", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),
 }

@@ -289,9 +289,10 @@ PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 4
     # pinned anew by PR 51 (it holds a share), by PR 53 (one short convolution
     # for every kind) and by PR 55 (the share's gathers are loops over row
     # tiles) and by PR 58 (``kda_bwd``'s body: a chunk's two halves once each,
-    # the inverse's pullback in closed form): tests/test_ling.py's table says
-    # what moved
-    "ling_debug": "318e97cd85d7635f",
+    # the inverse's pullback in closed form) and by PR 60 (the share's two
+    # adds into ``[T, d]`` in token order, ``moe._add_in_token_order``):
+    # tests/test_ling.py's table says what moved
+    "ling_debug": "1af4c5bdba152d88",
 }
 
 

@@ -3,7 +3,8 @@ gathers go as far as the pairs do (PR 55): the block against PR 50's padded
 sizes and against PR 51's whole-buffer gathers at every load, on the
 interpreted kernels, whose NaN in every row they do not write is the test of
 the selects; and the counters that say what part of the buffer the products
-visit and the gathers touch."""
+visit and the gathers touch; and a share's two adds into ``[T, d]`` in token
+order (PR 60) against XLA's one scatter-add in the buffer's order."""
 
 import dataclasses
 import importlib.util
@@ -103,6 +104,14 @@ def _value_and_grads(ffn, cfg, idx, args, cot):
     return jax.jit(jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
 
 
+def _assert_the_blocks_agree(got, want):
+    """(output, the five gradients) of two forms of the block: bit for bit,
+    and finite."""
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
 # the larger tile is ``slow``: the small one walks the same loops over more
 # tiles at every load and both dtypes (ROADMAP D11: 12 cases, 156 of this
 # file's 486 test-seconds; PR 56)
@@ -131,9 +140,7 @@ def test_a_shares_gathers_go_as_far_as_the_pairs_and_nothing_else_moves(
     ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
         _value_and_grads(ffn, cfg, idx, args, cot)
         for ffn in (moe._share_ffn, _share_ffn_of_pr51(check)))
-    for got, want in zip((out, *grads), (was_out, *was_grads)):
-        assert got.dtype == want.dtype and bool(jnp.all(jnp.isfinite(got)))
-        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    _assert_the_blocks_agree((out, *grads), (was_out, *was_grads))
     for key, want in was_stats.items():
         np.testing.assert_array_equal(stats[key], want)
     tiles = -(-min(held_pairs, rows_n) // tile)
@@ -141,6 +148,68 @@ def test_a_shares_gathers_go_as_far_as_the_pairs_and_nothing_else_moves(
     assert 0 <= float(stats["moved"]) - float(stats["visited"]) < tile / rows_n
     if load in ("a_tiles_border", "a_row_past_it") and 2 * tile < rows_n:
         assert tiles == 2 + (load == "a_row_past_it")
+
+
+# the even share of ``_share_case`` is 512 pairs (2,048 pairs, 4 of 16 experts
+# held): held pairs at 0, 0.7, 1.0 and 1.5 x it, and over the buffer's 1,536
+TOKEN_ORDER_LOADS = {"none": 0, "x0.7": 358, "even": 512, "x1.5": 768, "overflow": 1800}
+
+
+@pytest.mark.parametrize("columns", [None, 8], ids=["one_block", "four_blocks"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("load", sorted(TOKEN_ORDER_LOADS))
+def test_a_shares_rows_come_home_in_token_order(load, dtype, columns, check, monkeypatch):
+    """The combine's add and the add of the dispatch's pullback sort the held
+    pairs by token, gather the rows into that order and scatter-add them
+    sorted, in column blocks (PR 60: ``moe._token_order``,
+    ``moe._add_in_token_order``; here the rows' 32 columns as one block and
+    as four of 8, the bound patched). The sort is stable, so a token's rows
+    are added in the buffer's order, and the CPU adds them one by one either
+    way: the block's output and all five gradients are what XLA's ONE
+    scatter-add in the buffer's order gave
+    (``benchmarks/share_gmm_check.py``'s ``whole``), bit for bit, at every
+    load; and finite, though every row past the pairs is gathered too (NaN
+    under the interpreter): they are selected away before the gather and
+    dropped by their key after it."""
+    if columns:
+        monkeypatch.setattr(moe, "_add_block", lambda d: columns)
+    cfg, idx, args, cot = _share_case(TOKEN_ORDER_LOADS[load], dtype)
+    ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
+        _value_and_grads(ffn, cfg, idx, args, cot)
+        for ffn in (moe._share_ffn, _share_ffn_of_pr51(check)))
+    assert int(stats["held_pairs"]) == TOKEN_ORDER_LOADS[load]
+    _assert_the_blocks_agree((out, *grads), (was_out, *was_grads))
+    for key, want in was_stats.items():
+        np.testing.assert_array_equal(stats[key], want)
+
+
+@pytest.mark.parametrize("d,block", [(5120, 2560), (2304, 2304), (2560, 2560), (2688, 2688),
+                                     (4096, 2048), (7168, 1792), (32, 32)])
+def test_the_adds_column_block_is_the_widest_that_xla_takes_at_speed(d, block):
+    """``moe._add_block``: the widest multiple of 128 lanes under
+    ``ADD_COLUMNS`` that divides the width: the three older share cells'
+    rows go as ONE block (XLA's own program), DeepSeek's 5,120 as two of
+    2,560; a debug width that is no multiple of 128 goes whole."""
+    assert moe._add_block(d) == block and d % block == 0
+
+
+@pytest.mark.parametrize("pairs", [0, 1, 700, 1536, 1800])
+def test_the_token_order_is_a_stable_permutation_with_the_free_rows_last(pairs):
+    """``moe._token_order``: ``perm`` is a permutation of the buffer's rows;
+    the first ``min(pairs, rows_n)`` keys are the held rows' tokens ascending,
+    a token's rows in the buffer's order (the sort is stable), and every key
+    after them is ``T``, the key the scatter-add drops."""
+    T, rows_n = 512, 1536
+    rng = np.random.default_rng(pairs)
+    take = jnp.asarray(rng.integers(0, T, rows_n), jnp.int32)
+    key_s, perm = jax.jit(moe._token_order, static_argnums=2)(take, jnp.int32(pairs), T)
+    key_s, perm, n = np.asarray(key_s), np.asarray(perm), min(pairs, rows_n)
+    assert key_s.dtype == perm.dtype == np.int32
+    np.testing.assert_array_equal(np.sort(perm), np.arange(rows_n))
+    np.testing.assert_array_equal(perm[:n], np.argsort(np.asarray(take)[:n], kind="stable"))
+    np.testing.assert_array_equal(key_s[:n], np.asarray(take)[perm[:n]])
+    assert (np.diff(key_s[:n]) >= 0).all() and (key_s[n:] == T).all()
+    np.testing.assert_array_equal(perm[n:], np.arange(n, rows_n))
 
 
 @pytest.mark.parametrize("rows_n,tile", [(131072, 2048), (49152, 2048), (24576, 2048),
@@ -167,9 +236,7 @@ def test_a_shares_products_visit_the_held_pairs_only_and_nothing_else_moves(load
     cfg, idx, args, cot = _share_case(held_pairs, dtype)
     ((_, (out, stats)), grads), ((_, (was_out, was_stats)), was_grads) = (
         _value_and_grads(ffn, cfg, idx, args, cot) for ffn in (moe._share_ffn, _parents_share_ffn))
-    for got, want in zip((out, *grads), (was_out, *was_grads)):
-        assert got.dtype == want.dtype and bool(jnp.all(jnp.isfinite(got)))
-        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    _assert_the_blocks_agree((out, *grads), (was_out, *was_grads))
     for key, want in was_stats.items():
         np.testing.assert_array_equal(stats[key], want)
     rows_n = cfg.share_rows(512)

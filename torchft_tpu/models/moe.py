@@ -600,17 +600,60 @@ def _held(take, n):
     return (jnp.arange(take.shape[0]) < n)[:, None]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _share_take(flat, take, n, tokens):
+def _token_order(take, n, tokens: int):
+    """take [rows_n]: the token of each row of a share's buffer, of which the
+    first ``n`` hold a pair -> (``key_s``, ``perm``), both [rows_n] int32: the
+    rows' tokens ascending (``tokens`` for a row that holds no pair: they sort
+    last) and the rows in that order. The sort is stable: a token's rows keep
+    the buffer's order."""
+    key = jnp.where(_held(take, n)[:, 0], take, tokens)
+    return tuple(jax.lax.sort_key_val(key, jnp.arange(take.shape[0], dtype=jnp.int32)))
+
+
+# the widest rows XLA's sorted scatter-add was read on its fast side at
+# (Nemotron's; 60-115 ns a row from 2,304 up to here): wider rows go in blocks
+ADD_COLUMNS = 2688
+
+
+def _add_block(d: int) -> int:
+    """The columns a scatter-add of rows of ``d`` takes at once: the widest
+    multiple of 128 lanes under ``ADD_COLUMNS`` that divides ``d``; ``d``
+    itself where it is no multiple of 128 (a debug width)."""
+    if d % 128:
+        return d
+    return max(c for c in range(128, min(d, ADD_COLUMNS) + 1, 128) if d % c == 0)
+
+
+def _add_in_token_order(rows, by_token, tokens: int):
+    """rows [rows_n, d] -> [tokens, d]: each row added at its token by XLA's
+    scatter-add, the rows brought into token order first (``by_token``:
+    :func:`_token_order`'s pair), in column blocks of ``ADD_COLUMNS`` at
+    most (the widest multiple of 128 lanes under it that divides ``d``).
+    Sorted, a token's rows lie side by side and XLA adds the run in float32
+    before it rounds; XLA sorts and gathers like this by itself, so at the
+    widths up to ``ADD_COLUMNS`` (one block) this is its own program
+    written out, sums and all. The blocks are for what it does NOT do by
+    itself: at a width of 5,120 its pass over the ``[tokens, d]`` operand
+    alone takes 28.5 ms whatever the rows, and the same rows in two blocks
+    of 2,560 take 4.0 (PERF.md section 6, PR 60). A row that holds no pair
+    has the key ``tokens`` and is dropped; the callers select it away all
+    the same, because it is gathered."""
+    key_s, perm = by_token
+    rows, d, dc = rows[perm], rows.shape[1], _add_block(rows.shape[1])
+    blocks = [jnp.zeros((tokens, dc), rows.dtype).at[key_s].add(
+        rows[:, at:at + dc], indices_are_sorted=True, mode="drop") for at in range(0, d, dc)]
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _share_take(flat, take, by_token, n, tokens):
     """flat [tokens, d], take [rows_n] -> [rows_n, d]: ``flat[take]`` in the
     first ``n`` rows and zeros after them, gathered tile by tile as far as
-    the ``n`` rows go (:func:`_over_tiles`). Its pullback is XLA's ONE
-    scatter-add of the cotangent's rows into ``[tokens, d]``, over every row
-    of the buffer: XLA sorts a whole scatter-add's indices and adds a token's
-    rows before it rounds, at under half a tile's cost a row (PERF.md section
-    6, PR 55). A row past ``n`` is SELECTED away both ways. (``tokens`` is
-    ``flat.shape[0]``, given apart because a pullback's residuals carry no
-    shape.)"""
+    the ``n`` rows go (:func:`_over_tiles`). Its pullback adds the
+    cotangent's rows into ``[tokens, d]`` in token order
+    (:func:`_add_in_token_order`), over every row of the buffer. A row past
+    ``n`` is SELECTED away both ways. (``tokens`` is ``flat.shape[0]``, given
+    apart because a pullback's residuals carry no shape.)"""
     def body(cut, put, valid, buf):
         return put(buf, jnp.where(valid, flat[cut(take)], 0))
 
@@ -618,14 +661,14 @@ def _share_take(flat, take, n, tokens):
                        jnp.zeros((take.shape[0], flat.shape[1]), flat.dtype))
 
 
-def _share_take_fwd(flat, take, n, tokens):
-    return _share_take(flat, take, n, tokens), (take, n)
+def _share_take_fwd(flat, take, by_token, n, tokens):
+    return _share_take(flat, take, by_token, n, tokens), (take, by_token, n)
 
 
 def _share_take_bwd(tokens, saved, g):
-    take, n = saved
-    return jnp.zeros((tokens, g.shape[1]), g.dtype).at[take].add(
-        jnp.where(_held(take, n), g, 0)), None, None
+    take, by_token, n = saved
+    return (_add_in_token_order(jnp.where(_held(take, n), g, 0), by_token, tokens),
+            None, None, None)
 
 
 _share_take.defvjp(_share_take_fwd, _share_take_bwd)
@@ -636,22 +679,22 @@ def _weighed(valid, rows, weights):
     return jnp.where(valid, rows, 0) * weights
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _share_add(rows, weights, take, n, tokens):
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _share_add(rows, weights, take, by_token, n, tokens):
     """rows [rows_n, d], weights [rows_n, 1] -> [tokens, d]: the first ``n``
-    rows, each times its weight, added at ``take`` in the buffer's order by
-    XLA's ONE scatter-add over every row (see :func:`_share_take`). Its
-    pullback gathers the cotangent at ``take`` tile by tile as far as the
-    ``n`` rows go (:func:`_over_tiles`) and takes each tile through the
-    product's own pullback (the primitives autodiff emits for the whole
-    buffer); the cotangents of the rows and weights that no tile reaches are
-    zeros."""
-    return jnp.zeros((tokens, rows.shape[1]), rows.dtype).at[take].add(
-        _weighed(_held(take, n), rows, weights))
+    rows, each times its weight, added at ``take`` in token order
+    (:func:`_add_in_token_order`: a token's rows in the buffer's order, the
+    run's sum rounded once on the chip). Its pullback gathers the cotangent
+    at ``take`` tile by tile as far as the ``n`` rows go (:func:`_over_tiles`)
+    and takes each tile through the product's own pullback (the primitives
+    autodiff emits for the whole buffer); the cotangents of the rows and
+    weights that no tile reaches are zeros."""
+    return _add_in_token_order(_weighed(_held(take, n), rows, weights), by_token, tokens)
 
 
-def _share_add_fwd(rows, weights, take, n, tokens):
-    return _share_add(rows, weights, take, n, tokens), (rows, weights, take, n)
+def _share_add_fwd(rows, weights, take, by_token, n, tokens):
+    return (_share_add(rows, weights, take, by_token, n, tokens),
+            (rows, weights, take, n))
 
 
 def _share_add_bwd(tokens, saved, g):
@@ -662,7 +705,8 @@ def _share_add_bwd(tokens, saved, g):
         return tuple(map(put, carry, pullback(g[cut(take)])))
 
     return (*_over_tiles(n, rows.shape[0], body,
-                         (jnp.zeros_like(rows), jnp.zeros_like(weights))), None, None)
+                         (jnp.zeros_like(rows), jnp.zeros_like(weights))),
+            None, None, None)
 
 
 _share_add.defvjp(_share_add_fwd, _share_add_bwd)
@@ -683,12 +727,15 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
     The rows leave by a gather that runs tile by tile as far as the held
     pairs go, and so does the gather of the combine's pullback
     (:func:`_share_take`, :func:`_share_add`); the grouped products visit
-    the held pairs only (:func:`_share_sizes`). What still follows the room
-    is the two scatter-adds into ``[T, d]`` (the combine, the dispatch's
-    pullback): XLA's own over every row of the buffer, which it sorts by
-    token first; a tile's scatter-add costs more than twice that a row. The
-    custom pullbacks of the whole-layer path gather T*k rows; a share's
-    buffer is a sixteenth of that at the published cut.
+    the held pairs only (:func:`_share_sizes`). The two adds into ``[T, d]``
+    (the combine, the dispatch's pullback) go in TOKEN order
+    (:func:`_token_order`, once a layer; :func:`_add_in_token_order`): the
+    held pairs sorted by token, the rows gathered into that order and
+    scatter-added sorted, in column blocks where the rows are wider than
+    XLA's scatter-add takes at speed. They follow the room still: every row
+    of the buffer is gathered and added. The custom pullbacks of the
+    whole-layer path gather T*k rows; a share's buffer is a sixteenth of
+    that at the published cut.
 
     What a grouped product leaves past the pairs is whatever its output
     buffer held (NaN under the interpreter, any bits on a chip), so no
@@ -708,14 +755,15 @@ def _share_ffn(flat, gates, idx, cfg, w_gate, w_up, w_down):
         valid = _held(order, pairs)
         sizes = _share_sizes(counts, rows_n)
         weights = jnp.where(valid, gates.reshape(T * k, 1)[order], 0.0)
+        by_token = _token_order(order // k, pairs, T)
     with jax.named_scope("moe/dispatch"):
-        rows = _share_take(flat, order // k, pairs, T)
+        rows = _share_take(flat, order // k, by_token, pairs, T)
     with jax.named_scope("moe/experts"):
         h = _hidden(rows, w_gate, w_up, lambda r, w: _grouped_matmul(r, w, sizes),
                     cfg.expert_act)
         rows = _grouped_matmul(h, w_down, sizes)
     with jax.named_scope("moe/combine"):
-        out = _share_add(rows, weights.astype(flat.dtype), order // k, pairs, T)
+        out = _share_add(rows, weights.astype(flat.dtype), order // k, by_token, pairs, T)
     return out, {"counts": counts, "held_pairs": pairs,
                  "overflow": jnp.maximum(pairs - rows_n, 0),
                  "visited": jnp.minimum(pairs, rows_n).astype(jnp.float32) / rows_n,

@@ -1,6 +1,8 @@
 """Checkpoint transport tests (reference pattern: http_transport_test.py,
 pg_transport_test.py)."""
 
+import contextlib
+import pickle
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -16,8 +18,14 @@ from torchft_tpu.checkpointing._serialization import (
     split_chunks,
     unflatten_state,
 )
+from torchft_tpu.checkpointing.transport import plan_wire_ranges
 from torchft_tpu.coordination import KvStoreServer
-from torchft_tpu.process_group import ProcessGroupHost
+from torchft_tpu.process_group import (
+    ErrorSwallowingProcessGroupWrapper,
+    ProcessGroupDummy,
+    ProcessGroupHost,
+)
+from torchft_tpu.process_group_xla import ProcessGroupXLA
 
 
 def make_state():
@@ -196,6 +204,61 @@ class TestHTTPRestageAtomicity:
             src.shutdown()
 
 
+@contextlib.contextmanager
+def host_pg_pair(tag, timeout=10.0):
+    """Two ProcessGroupHost ranks configured against one store: [0] sends,
+    [1] receives."""
+    store = KvStoreServer("127.0.0.1:0")
+    pgs = [ProcessGroupHost(timeout=timeout) for _ in range(2)]
+    try:
+        addr = f"127.0.0.1:{store.port}/{tag}"
+        with ThreadPoolExecutor(max_workers=2) as ex:
+            list(ex.map(lambda r: pgs[r].configure(addr, r, 2, 51), range(2)))
+        yield pgs
+    finally:
+        for pg in pgs:
+            pg.shutdown()
+        store.shutdown()
+
+
+def heal_over(pgs, state, template=None, step=4, timeout=10.0):
+    """One heal from pgs[0] to pgs[1]; what the receiver got."""
+    sender = PGTransport(pgs[0], timeout=timeout)
+    receiver = PGTransport(
+        pgs[1], timeout=timeout,
+        state_dict_template=None if template is None else (lambda: template),
+    )
+    with ThreadPoolExecutor(max_workers=2) as ex:
+        fs = ex.submit(sender.send_checkpoint, [1], step, state, timeout)
+        fr = ex.submit(
+            receiver.recv_checkpoint, 0, "<pg_transport>", step, timeout
+        )
+        fs.result(timeout=30)
+        return fr.result(timeout=30)
+
+
+def spy_on_recv_into(pg):
+    """For each recv_into on ``pg``: how many buffers it was handed and how
+    many of them came back as themselves (the frames landed in them)."""
+    calls = []
+    real = pg.recv_into
+
+    def spy(buffers, src, tag=0):
+        work = real(buffers, src, tag)
+
+        def note(fut):
+            got = [] if fut.exception() is not None else fut.value()
+            calls.append(
+                (len(buffers), sum(g is b for g, b in zip(got, buffers)))
+            )
+
+        work.get_future().add_done_callback(note)
+        return work
+
+    pg.recv_into = spy
+    return calls
+
+
 class _NoRecvInto:
     """Proxy hiding recv_into (a wrapper PG without the raw-frame surface)."""
 
@@ -240,129 +303,170 @@ class TestPGTransport:
                 pg.shutdown()
             store.shutdown()
 
-    def test_windowed_wire_over_baby_pgs(self):
-        """Baby PGs have no recv_into, so the header declares batched=False
-        and the per-leaf windowed wire runs on both sides (the backpressure
-        path that caps the child's per-message buffering)."""
-        from torchft_tpu.multiprocessing_dummy_context import DummyContext
-        from torchft_tpu.process_group import ProcessGroupBabyHost
+    @pytest.mark.parametrize("pg", [
+        "dummy", "xla_local", "proxy", "error_swallowing",
+    ])
+    def test_a_pg_without_recv_into_is_refused_in_words(self, pg):
+        """The one wire lands raw frames in the receiver's buffers, so the
+        recovery PG has to have recv_into: one that has not is refused
+        where it is handed in, not on the first heal."""
+        made = {
+            "dummy": ProcessGroupDummy,
+            "xla_local": lambda: ProcessGroupXLA(mode="local"),
+            "proxy": lambda: _NoRecvInto(ProcessGroupHost()),
+            "error_swallowing": lambda: ErrorSwallowingProcessGroupWrapper(
+                ProcessGroupHost()
+            ),
+        }[pg]()
+        with pytest.raises(TypeError, match="recv_into") as refused:
+            PGTransport(made, timeout=1.0)
+        assert type(made).__name__ in str(refused.value)
 
-        store = KvStoreServer("127.0.0.1:0")
-        pgs = [
-            ProcessGroupBabyHost(timeout=20.0, ctx=DummyContext())
-            for _ in range(2)
-        ]
-        try:
-            addr = f"127.0.0.1:{store.port}/ckpt_baby"
-
-            def cfg(rank):
-                pgs[rank].configure(addr, rank, 2, quorum_id=11)
-
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                list(ex.map(cfg, range(2)))
-
-            assert not hasattr(pgs[0], "recv_into")
-            state = make_state()
-            sender = PGTransport(pgs[0], timeout=20.0)
-            receiver = PGTransport(pgs[1], timeout=20.0)
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                fs = ex.submit(sender.send_checkpoint, [1], 4, state, 20.0)
-                fr = ex.submit(
-                    receiver.recv_checkpoint, 0, "<pg_transport>", 4, 20.0
+    @pytest.mark.parametrize("form", [
+        "per_leaf", "batched", "other", "ranged_without_crcs",
+    ])
+    def test_a_header_that_is_not_ranged_is_refused_in_words(self, form):
+        """The header comes from another process. One that is not (step,
+        spec, "ranged", ranges, crcs) is answered with what came, well
+        inside the timeout, and not by waiting for frames."""
+        state = {"w": np.arange(8, dtype=np.float32)}
+        spec, _ = flatten_state(state)
+        ranges = plan_wire_ranges([m.nbytes for m in spec.leaves], 1 << 20)
+        header = {
+            "per_leaf": (4, spec),
+            "batched": (4, spec, True),
+            "other": (4, spec, "other", ranges, [0]),
+            "ranged_without_crcs": (4, spec, "ranged", ranges),
+        }[form]
+        with host_pg_pair(f"header_{form}") as pgs:
+            pgs[0].send(
+                [np.frombuffer(pickle.dumps(header), np.uint8)], 1, tag=1
+            ).wait(5.0)
+            t0 = time.monotonic()
+            with pytest.raises(RuntimeError, match="not the ranged wire") as said:
+                PGTransport(pgs[1], timeout=10.0).recv_checkpoint(
+                    0, "<pg_transport>", 4, 10.0
                 )
-                fs.result(timeout=60)
-                out = fr.result(timeout=60)
-            assert_state_equal(state, out)
-        finally:
-            for pg in pgs:
-                pg.shutdown()
-            store.shutdown()
+            assert time.monotonic() - t0 < 5.0
+        assert "TreeSpecPayload" in str(said.value)
+        if form != "per_leaf":
+            assert repr(header[2]) in str(said.value)
 
-    def test_batched_sender_plain_recv_receiver(self):
-        """A batched sender against a receiver whose PG lacks recv_into:
-        the receiver consumes each wire group with one plain recv (the
-        mixed-capability path the header negotiation exists for)."""
-        store = KvStoreServer("127.0.0.1:0")
-        pgs = [ProcessGroupHost(timeout=10.0) for _ in range(2)]
-        try:
-            addr = f"127.0.0.1:{store.port}/ckpt_mixed"
-
-            def cfg(rank):
-                pgs[rank].configure(addr, rank, 2, quorum_id=12)
-
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                list(ex.map(cfg, range(2)))
-
-            state = make_state()
-            sender = PGTransport(pgs[0], timeout=10.0)  # batched (recv_into)
-            receiver = PGTransport(pgs[1], timeout=10.0)
-            # simulate a recv_into-less receiver PG (e.g. a wrapper): the
-            # transport must fall back to plain per-group recv
-            receiver._pg = _NoRecvInto(pgs[1])
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                fs = ex.submit(sender.send_checkpoint, [1], 4, state, 10.0)
-                fr = ex.submit(
-                    receiver.recv_checkpoint, 0, "<pg_transport>", 4, 10.0
-                )
-                fs.result(timeout=30)
-                out = fr.result(timeout=30)
-            assert_state_equal(state, out)
-        finally:
-            for pg in pgs:
-                pg.shutdown()
-            store.shutdown()
-
-    def test_multi_group_batched_wire(self, monkeypatch):
-        """Payloads above BATCH_GROUP_BYTES split into several deterministic
-        wire messages; roundtrip and in-place absorption must hold across
-        the group boundaries."""
-        # leaves must clear the host PG's 64 KiB raw-frame threshold or
-        # every group rides the pickled path and the in-place absorb
-        # branch is never driven; cap = one 128 KiB leaf per group
-        monkeypatch.setattr(PGTransport, "BATCH_GROUP_BYTES", 128 * 1024)
-        store = KvStoreServer("127.0.0.1:0")
-        pgs = [ProcessGroupHost(timeout=10.0) for _ in range(2)]
-        try:
-            addr = f"127.0.0.1:{store.port}/ckpt_groups"
-
-            def cfg(rank):
-                pgs[rank].configure(addr, rank, 2, quorum_id=13)
-
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                list(ex.map(cfg, range(2)))
-
-            n = 32 * 1024  # 128 KiB per f32 leaf: raw-frame wire
-            state = {
-                f"w{i}": np.full(n, float(i), np.float32) for i in range(5)
-            }
-            spec, _ = flatten_state(state)
-            groups = PGTransport._wire_groups(spec)
-            assert len(groups) == 5, groups  # one leaf per group
-
-            template = {
-                f"w{i}": np.zeros(n, np.float32) for i in range(5)
-            }
-            sender = PGTransport(pgs[0], timeout=10.0)
-            receiver = PGTransport(
-                pgs[1], timeout=10.0,
-                state_dict_template=lambda: template,
+    def test_a_payload_above_the_chunk_splits_and_lands_in_place(
+        self, monkeypatch
+    ):
+        """Chunk borders that fall inside leaves: the round trip holds and
+        every range's frame lands in the template's own memory. Every
+        chunk clears the host PG's 64 KiB raw-frame threshold, or it would
+        ride the pickled path and be copied in."""
+        chunk = 96 * 1024
+        monkeypatch.setenv("TORCHFT_STREAM_CHUNK_BYTES", str(chunk))
+        n = 32 * 1024  # 128 KiB an f32 leaf
+        state = {f"w{i}": np.full(n, float(i), np.float32) for i in range(5)}
+        template = {f"w{i}": np.zeros(n, np.float32) for i in range(5)}
+        plan = plan_wire_ranges([4 * n] * 5, chunk)
+        assert len(plan) == 7 and any(len(c) == 2 for c in plan), plan
+        with host_pg_pair("ckpt_chunks") as pgs:
+            calls = spy_on_recv_into(pgs[1])
+            out = heal_over(pgs, state, template)
+        for i in range(5):
+            np.testing.assert_array_equal(out[f"w{i}"], state[f"w{i}"])
+            assert out[f"w{i}"] is template[f"w{i}"], (
+                f"leaf w{i} not absorbed in place across a chunk border"
             )
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                fs = ex.submit(sender.send_checkpoint, [1], 4, state, 10.0)
-                fr = ex.submit(
-                    receiver.recv_checkpoint, 0, "<pg_transport>", 4, 10.0
+        # the header's recv has no buffers; each chunk's ranges all landed
+        assert calls == [(0, 0)] + [(len(c), len(c)) for c in plan]
+
+    def test_the_sender_leaves_at_most_a_window_of_chunks_unwaited(
+        self, monkeypatch
+    ):
+        """Back-pressure: the issue loop runs SEND_WINDOW chunks ahead of
+        the wire and no further, however long the plan."""
+        monkeypatch.setenv("TORCHFT_STREAM_CHUNK_BYTES", str(64 * 1024))
+        state = {"w": np.arange(262_144, dtype=np.float32)}  # 16 chunks
+        with host_pg_pair("ckpt_window") as pgs:
+            real_send = pgs[0].send
+            book = {"issued": 0, "waited": 0, "most": 0}
+
+            class Counted:
+                def __init__(self, work):
+                    self._work = work
+
+                def wait(self, timeout=None):
+                    done = self._work.wait(timeout)
+                    book["waited"] += 1
+                    return done
+
+            def send(arrays, dst, tag=0):
+                work = real_send(arrays, dst, tag)
+                if tag != 2:
+                    return work
+                book["issued"] += 1
+                book["most"] = max(
+                    book["most"], book["issued"] - book["waited"]
                 )
-                fs.result(timeout=30)
-                out = fr.result(timeout=30)
-            for i in range(5):
-                np.testing.assert_array_equal(out[f"w{i}"], state[f"w{i}"])
-                assert out[f"w{i}"] is template[f"w{i}"], (
-                    f"leaf w{i} not absorbed in place across group boundary"
-                )
-        finally:
-            for pg in pgs:
-                pg.shutdown()
-            store.shutdown()
+                return Counted(work)
+
+            pgs[0].send = send
+            out = heal_over(pgs, state)
+        np.testing.assert_array_equal(out["w"], state["w"])
+        assert book["issued"] == book["waited"] == 16
+        assert book["most"] == PGTransport.SEND_WINDOW
+
+    _CHUNK = 64 * 1024
+    # trees the ranged tests of TestChunkedStreaming do not send
+    _TREES = {
+        "empty": lambda: {},
+        "zero_size_leaf": lambda: {
+            "a": np.arange(20_000, dtype=np.float32),
+            "b": np.zeros((0, 4), np.float32),
+            "c": np.arange(6, dtype=np.int32),
+        },
+        "pickled_leaf_between_arrays": lambda: {
+            "a": np.arange(20_000, dtype=np.float32),
+            "b": "a leaf that is no array",
+            "c": np.arange(20_000, dtype=np.float64),
+        },
+        "bfloat16_leaf": lambda: {
+            "w": np.arange(40_000).astype(jnp.bfloat16),
+        },
+        "many_small_leaves_in_one_chunk": lambda: {
+            f"w{i:02d}": np.full(16, float(i), np.float32) for i in range(50)
+        },
+        "a_leaf_of_exactly_a_chunk": lambda: {
+            "w": (np.arange(TestPGTransport._CHUNK) % 251).astype(np.uint8),
+            "tail": np.arange(3, dtype=np.float32),
+        },
+        "a_leaf_of_a_chunk_and_a_byte": lambda: {
+            "w": (np.arange(TestPGTransport._CHUNK + 1) % 251).astype(np.uint8),
+            "tail": np.arange(3, dtype=np.float32),
+        },
+    }
+
+    @pytest.mark.parametrize("templated", [False, True],
+                             ids=["fresh", "into_template"])
+    @pytest.mark.parametrize("tree", sorted(_TREES))
+    def test_round_trip_on_the_ranged_wire(self, monkeypatch, tree, templated):
+        """What the per-leaf and the batched wire's tests held, on the wire
+        that stays: the tree comes back leaf for leaf, dtype for dtype, and
+        with a template every array leaf IS the template's."""
+        monkeypatch.setenv("TORCHFT_STREAM_CHUNK_BYTES", str(self._CHUNK))
+        state = self._TREES[tree]()
+        template = None
+        if templated:
+            template = jax.tree_util.tree_map(
+                lambda x: np.zeros_like(x) if isinstance(x, np.ndarray) else x,
+                state,
+            )
+        with host_pg_pair(f"rt_{tree}_{int(templated)}") as pgs:
+            out = heal_over(pgs, state, template)
+        assert_state_equal(state, out)
+        want, got = (jax.tree_util.tree_leaves(t) for t in (state, out))
+        held = jax.tree_util.tree_leaves(template) if templated else got
+        for w, g, t in zip(want, got, held):
+            if isinstance(w, np.ndarray):
+                assert g.dtype == w.dtype and g.shape == w.shape
+                assert not templated or g is t
 
     def test_inplace_recv_places_on_template_sharding(self):
         store = KvStoreServer("127.0.0.1:0")
@@ -402,28 +506,8 @@ class TestInplaceDegradedPaths:
     back to the wire buffer — never die mid-stream or silently coerce."""
 
     def _roundtrip(self, state, template, tag):
-        store = KvStoreServer("127.0.0.1:0")
-        pgs = [ProcessGroupHost(timeout=10.0) for _ in range(2)]
-        try:
-            addr = f"127.0.0.1:{store.port}/{tag}"
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                list(ex.map(lambda r: pgs[r].configure(addr, r, 2, 31),
-                            range(2)))
-            sender = PGTransport(pgs[0], timeout=10.0)
-            receiver = PGTransport(
-                pgs[1], timeout=10.0, state_dict_template=lambda: template
-            )
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                fs = ex.submit(sender.send_checkpoint, [1], 0, state, 10.0)
-                fr = ex.submit(
-                    receiver.recv_checkpoint, 0, "<pg_transport>", 0, 10.0
-                )
-                fs.result(timeout=30)
-                return fr.result(timeout=30)
-        finally:
-            for pg in pgs:
-                pg.shutdown()
-            store.shutdown()
+        with host_pg_pair(tag) as pgs:
+            return heal_over(pgs, state, template, step=0)
 
     def test_host_template_absorbs_in_place(self):
         state = {"w": np.arange(64, dtype=np.float32)}
@@ -438,63 +522,16 @@ class TestInplaceDegradedPaths:
         memory. The fallback (recv + copyto) would produce identical
         outputs, so the fast path is pinned by SPYING on recv_into —
         identity alone can't detect its regression."""
-        from torchft_tpu.checkpointing.pg_transport import PGTransport
-        from torchft_tpu.coordination import KvStoreServer
-        from torchft_tpu.process_group import ProcessGroupHost
-
         n = 64 * 1024  # 256 KiB of f32: raw-frame path on the host PG
         state = {"w": np.arange(n, dtype=np.float32)}
         template = {"user": {"w": np.zeros(n, dtype=np.float32)}}
-        store = KvStoreServer("127.0.0.1:0")
-        pgs = [ProcessGroupHost(timeout=10.0) for _ in range(2)]
-        absorbed = []
-        real_recv_into = pgs[1].recv_into
-
-        def spy_recv_into(buffers, src, tag=0):
-            work = real_recv_into(buffers, src, tag)
-            fut = work.get_future()
-            orig_wait = fut.wait
-
-            def wait(timeout=None):
-                got = orig_wait(timeout)
-                absorbed.append(
-                    bool(buffers) and got and got[0] is buffers[0]
-                )
-                return got
-
-            fut.wait = wait
-
-            class W:
-                def get_future(self):
-                    return fut
-
-            return W()
-
-        pgs[1].recv_into = spy_recv_into
-        try:
-            addr = f"127.0.0.1:{store.port}/inplace-raw"
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                list(ex.map(lambda r: pgs[r].configure(addr, r, 2, 41),
-                            range(2)))
-            sender = PGTransport(pgs[0], timeout=10.0)
-            receiver = PGTransport(
-                pgs[1], timeout=10.0, state_dict_template=lambda: template
-            )
-            with ThreadPoolExecutor(max_workers=2) as ex:
-                fs = ex.submit(sender.send_checkpoint, [1], 0,
-                               {"user": state}, 10.0)
-                fr = ex.submit(receiver.recv_checkpoint, 0,
-                               "<pg_transport>", 0, 10.0)
-                fs.result(timeout=30)
-                out = fr.result(timeout=30)
-        finally:
-            for pg in pgs:
-                pg.shutdown()
-            store.shutdown()
+        with host_pg_pair("inplace-raw") as pgs:
+            calls = spy_on_recv_into(pgs[1])
+            out = heal_over(pgs, {"user": state}, template, step=0)
         assert out["user"]["w"] is template["user"]["w"]
         np.testing.assert_array_equal(out["user"]["w"], state["w"])
         # the big leaf went through recv_into AND was absorbed in place
-        assert any(absorbed), absorbed
+        assert (1, 1) in calls, calls
 
     def test_recv_into_identity_contract(self):
         """ProcessGroupHost.recv_into: a matching buffer IS the returned
@@ -832,13 +869,8 @@ class TestChunkedStreaming:
         """Sender dies after the first ranged chunk: the pipelined receiver
         must surface an error within its timeout, not hang or return torn
         state."""
-        import pickle
-
-        from torchft_tpu.checkpointing._serialization import (
-            flatten_state,
-            payload_memoryview,
-        )
-        from torchft_tpu.checkpointing.transport import plan_wire_ranges
+        from torchft_tpu.checkpointing._serialization import payload_memoryview
+        from torchft_tpu.checkpointing.pg_transport import _chunk_crc
 
         store = KvStoreServer("127.0.0.1:0")
         pgs = [ProcessGroupHost(timeout=3.0) for _ in range(2)]
@@ -850,7 +882,9 @@ class TestChunkedStreaming:
             spec, payloads = flatten_state(state)
             wire = payload_memoryview(payloads[0])
             ranges = plan_wire_ranges([len(wire)], 64 * 1024)
-            header = pickle.dumps((6, spec, "ranged", ranges))
+            wires = [np.frombuffer(wire, np.uint8)]
+            crcs = [_chunk_crc(wires, chunk) for chunk in ranges]
+            header = pickle.dumps((6, spec, "ranged", ranges, crcs))
 
             def half_send():
                 # the real wire: header on tag=1, chunk payloads on tag=2

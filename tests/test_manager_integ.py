@@ -88,7 +88,6 @@ class Runner:
             )
         elif self.transport.startswith("pg"):
             from torchft_tpu.checkpointing import PGTransport
-            from torchft_tpu.process_group import ProcessGroupBabyHost
 
             template = None
             if self.transport == "pg-inplace":
@@ -98,14 +97,8 @@ class Runner:
                 def template():
                     return manager.state_dict_template()
 
-            # "pg-baby": recovery PG in a killable child process — a
-            # wedged heal can be aborted without losing the trainer
-            recovery_cls = (
-                ProcessGroupBabyHost if self.transport == "pg-baby"
-                else ProcessGroupHost
-            )
             transport = PGTransport(
-                recovery_cls(timeout=10.0),  # dedicated recovery PG
+                ProcessGroupHost(timeout=10.0),  # dedicated recovery PG
                 timeout=10.0,
                 state_dict_template=template,
             )
@@ -377,20 +370,6 @@ class TestPGTransportHealing:
         results = run_replicas(
             [Runner(i, addr, injector, min_replica_size=1,
                     transport="pg-inplace")
-             for i in range(2)]
-        )
-        assert injector.count == 1
-        assert_params_equal(results)
-        assert all(r["steps"] == NUM_STEPS for r in results)
-
-    @pytest.mark.slow  # spawns a child process per replica
-    def test_crash_and_rejoin_over_baby_recovery_pg(self, lighthouse):
-        """The recovery PG in a killable child (ProcessGroupBabyHost): the
-        heal path that can be aborted without losing the trainer."""
-        injector = EventInjector().fail_at(replica=1, step=2)
-        addr = f"127.0.0.1:{lighthouse.port}"
-        results = run_replicas(
-            [Runner(i, addr, injector, min_replica_size=1, transport="pg-baby")
              for i in range(2)]
         )
         assert injector.count == 1

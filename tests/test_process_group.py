@@ -341,6 +341,79 @@ class TestProcessGroupHost:
             pg.shutdown()
 
 
+    # What the child-process groups' tests held of isolation, on the
+    # mechanism that stays: a host communicator that is wedged is ended by
+    # its timeouts, abort() and shutdown(), from any thread.
+    _P2P = {
+        "send": lambda pg, rank, world: pg.send(
+            [np.ones(4, np.float32)], (rank + 1) % world
+        ),
+        "recv": lambda pg, rank, world: pg.recv((rank + 1) % world),
+    }
+
+    @pytest.mark.parametrize("op", sorted(_COLLECTIVES) + sorted(_P2P))
+    def test_an_op_after_a_failure_raises_the_failure_at_once(self, store, op):
+        """The peer dies and an allreduce fails on it. Whatever is issued
+        on that generation afterwards is refused with that very error where
+        it is issued: nothing is queued behind a dead mesh to wait out a
+        timeout."""
+        issue = {**self._COLLECTIVES, **self._P2P}[op]
+        pgs = make_pgs(store, 2, timeout=5.0, prefix=f"after_{op}")
+        pgs[1].abort()
+        with pytest.raises(Exception):
+            pgs[0].allreduce([np.ones(4, np.float32)]).get_future().wait(10)
+        first = pgs[0].errored()
+        assert first is not None
+        t0 = time.monotonic()
+        with pytest.raises(type(first)) as again:
+            issue(pgs[0], 0, 2)
+        assert again.value is first
+        assert time.monotonic() - t0 < 1.0
+        for pg in pgs:
+            pg.shutdown()
+
+    # ops that can never complete: the peer is configured and stays silent
+    _NEVER_FED = {
+        "allreduce": lambda pg: [pg.allreduce([np.ones(4, np.float32)])],
+        # a second op queued behind the first is failed too
+        "allreduce_and_one_queued": lambda pg: [
+            pg.allreduce([np.ones(4, np.float32)]), pg.barrier()
+        ],
+        "recv": lambda pg: [pg.recv(1)],
+        # more than the loopback's socket buffers take: the writer thread
+        # blocks in its send
+        "send": lambda pg: [pg.send([np.zeros(64 << 20, np.uint8)], 1)],
+    }
+
+    @pytest.mark.parametrize("how", ["abort", "shutdown"])
+    @pytest.mark.parametrize("op", sorted(_NEVER_FED))
+    def test_abort_and_shutdown_fail_what_is_outstanding(self, store, op, how):
+        """Called from another thread under an op that would wait out a 60 s
+        timeout, both close the sockets: every outstanding future fails
+        within a second. After abort() the PG says so; after shutdown() it
+        is unconfigured."""
+        pgs = make_pgs(store, 2, timeout=60.0, prefix=f"{how}_{op}")
+        works = self._NEVER_FED[op](pgs[0])
+        time.sleep(0.1)  # let the op reach its socket
+        assert not any(w.get_future().done() for w in works)
+        t0 = time.monotonic()
+        getattr(pgs[0], how)()
+        for w in works:
+            with pytest.raises(Exception):
+                w.get_future().wait(timeout=10)
+        assert time.monotonic() - t0 < 2.0
+        if how == "abort":
+            assert pgs[0].errored() is not None
+            with pytest.raises(type(pgs[0].errored())):
+                pgs[0].barrier()
+        else:
+            assert pgs[0].errored() is None
+            with pytest.raises(RuntimeError, match="not configured"):
+                pgs[0].barrier()
+        for pg in pgs:
+            pg.shutdown()
+
+
 class TestRingAllreduce:
     """The bandwidth-optimal path: payloads >= _RING_MIN_BYTES ride a ring
     reduce-scatter + allgather with raw frames; results must match the

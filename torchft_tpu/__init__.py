@@ -16,7 +16,6 @@ _EXPORTS = {
     "Manager": "torchft_tpu.manager",
     "WorldSizeMode": "torchft_tpu.manager",
     "ProcessGroupHost": "torchft_tpu.process_group",
-    "ProcessGroupBabyHost": "torchft_tpu.process_group",
     "ProcessGroupDummy": "torchft_tpu.process_group",
     "ManagedProcessGroup": "torchft_tpu.process_group",
     "ProcessGroupXLA": "torchft_tpu.process_group_xla",

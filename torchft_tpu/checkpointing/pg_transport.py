@@ -8,6 +8,11 @@ interleaves with training collectives. Supports in-place receive into an
 existing state pytree: leaves are rebuilt with the template's dtype/sharding
 (``jax.device_put`` to the template leaf's sharding), the JAX analog of the
 reference's HBM-to-HBM in-place recv (pg_transport.py:235-305).
+
+One wire (docs/protocol.md): a header ``(step, spec, "ranged", ranges,
+crcs)`` on tag 1, then one tag-2 message per chunk of byte ranges. The
+recovery PG must stream raw frames into caller buffers (``recv_into``:
+:class:`ProcessGroupHost`); ``__init__`` refuses one that cannot.
 """
 
 from __future__ import annotations
@@ -87,6 +92,15 @@ class PGTransport(CheckpointTransport[Any]):
         of writing in place). An async-quorum host-plane trainer that
         mutates numpy state in place (EMA buffers, running stats) must
         keep the default or a heal can read a torn leaf."""
+        if not callable(getattr(pg, "recv_into", None)):
+            # checked where the PG is handed in: a wrapper that hides the
+            # raw-frame receive would otherwise fail on the first heal
+            raise TypeError(
+                f"PGTransport needs a recovery process group with "
+                f"recv_into(buffers, src, tag) (raw frames received into "
+                f"the caller's buffers, as ProcessGroupHost has); "
+                f"{type(pg).__name__} has none"
+            )
         self._pg = pg
         self._snapshot_send = snapshot_send
         self._timeout = (
@@ -121,32 +135,9 @@ class PGTransport(CheckpointTransport[Any]):
             store_addr, replica_rank, replica_world_size, quorum_id=quorum_id
         )
 
+    # most chunks handed to the PG and not yet waited for: the writer
+    # thread stays fed and the plan is never queued whole ahead of the wire
     SEND_WINDOW = 4
-    # Batched-wire message cap: bounds how much one tag-2 message can
-    # buffer in a ProcessGroupBaby child (which pickles whole messages
-    # through its pipe) while still amortizing per-message control
-    # round-trips ~leaves-per-group times. Both sides derive the SAME
-    # grouping from the spec, so the protocol needs no extra negotiation.
-    BATCH_GROUP_BYTES = 256 << 20
-
-    @classmethod
-    def _wire_groups(cls, spec) -> List[List[int]]:
-        """Deterministic partition of leaf indices into wire messages:
-        consecutive leaves packed up to BATCH_GROUP_BYTES per message
-        (always at least one leaf). Derived identically by sender and
-        receiver from the spec that rides the header."""
-        groups: List[List[int]] = []
-        cur: List[int] = []
-        cur_bytes = 0
-        for i, meta in enumerate(spec.leaves):
-            if cur and cur_bytes + meta.nbytes > cls.BATCH_GROUP_BYTES:
-                groups.append(cur)
-                cur, cur_bytes = [], 0
-            cur.append(i)
-            cur_bytes += meta.nbytes
-        if cur:
-            groups.append(cur)
-        return groups
 
     def send_checkpoint(
         self, dst_ranks: List[int], step: int, state_dict: Any, timeout
@@ -157,69 +148,32 @@ class PGTransport(CheckpointTransport[Any]):
         spec, payloads = flatten_state(
             state_dict, snapshot=self._snapshot_send
         )
-        # Ranged wire when the PG streams raw frames (direct
-        # ProcessGroupHost — recv_into is the capability marker): each
-        # message carries a chunk of BYTE RANGES (leaf_idx, offset, nbytes)
-        # planned by plan_wire_ranges, so a single multi-GB leaf splits
-        # across messages and the receiver overlaps the recv of chunk i+1
-        # with the device placement of chunk i (pipelined heal). The plan
-        # rides the header — no cross-host determinism requirement on the
-        # chunk-size knob. The header tells the receiver which protocol is
-        # on the wire; the non-ranged header stays a 2-tuple for pre-split
-        # receivers, and the legacy batched protocol is still understood
-        # on receive for mixed-version heals.
-        ranged = hasattr(self._pg, "recv_into")
-        ranges: Optional[List[Any]] = None
+        # Each message carries a chunk of BYTE RANGES (leaf_idx, offset,
+        # nbytes) planned by plan_wire_ranges, so a single multi-GB leaf
+        # splits across messages and the receiver overlaps the recv of
+        # chunk i+1 with the device placement of chunk i (pipelined heal).
+        # The plan rides the header: no cross-host determinism requirement
+        # on the chunk-size knob. So does a crc32 per chunk, over its
+        # concatenated range payloads.
         wires = [
             buf.reshape(-1).view(np.uint8)
             if isinstance(buf, np.ndarray)
             else np.frombuffer(buf, dtype=np.uint8)
             for buf in payloads
         ]
-        if ranged:
-            chunk_bytes = min(self.BATCH_GROUP_BYTES, stream_chunk_bytes())
-            ranges = plan_wire_ranges(
-                [m.nbytes for m in spec.leaves], chunk_bytes
-            )
-            # per-chunk crc32 over the concatenated range payloads rides the
-            # header as a 5th element: pre-crc receivers unpack tolerantly
-            # and skip verification, pre-crc senders ship a 4-tuple and the
-            # receiver sees crcs=None — both directions interop
-            crcs = [
-                _chunk_crc(wires, chunk) for chunk in ranges
-            ]
-            header = pickle.dumps((step, spec, "ranged", ranges, crcs))
-        else:
-            header = pickle.dumps((step, spec))
+        ranges = plan_wire_ranges(
+            [m.nbytes for m in spec.leaves], stream_chunk_bytes()
+        )
+        crcs = [_chunk_crc(wires, chunk) for chunk in ranges]
+        header = pickle.dumps((step, spec, "ranged", ranges, crcs))
         for dst in dst_ranks:
             self._pg.send([np.frombuffer(header, dtype=np.uint8)], dst, tag=1).wait(
                 self._timeout
             )
-            if ranged:
-                assert ranges is not None
-                # windowed like the per-leaf path: bounds in-flight chunk
-                # copies on a buffering peer while keeping the wire busy
-                pending: List[Any] = []
-                for chunk in ranges:
-                    bufs = [wires[j][off : off + ln] for (j, off, ln) in chunk]
-                    pending.append(self._pg.send(bufs, dst, tag=2))
-                    if len(pending) >= self.SEND_WINDOW:
-                        pending.pop(0).wait(self._timeout)
-                for work in pending:
-                    work.wait(self._timeout)
-                continue
-            # Windowed per-leaf sends: keep at most SEND_WINDOW leaves in
-            # flight. The window is not about caller overlap — it is
-            # BACKPRESSURE: with a ProcessGroupBaby recovery PG each
-            # in-flight send is a pickled full-leaf copy buffered in the
-            # child process, and an unbounded issue loop would materialize
-            # a checkpoint-sized pile of copies there (12GB-class state
-            # dicts → host OOM during healing). The reference's per-leaf
-            # blocking wait (pg_transport.py:202-233) is the window=1
-            # special case.
             pending: List[Any] = []
-            for wire in wires:
-                pending.append(self._pg.send([wire], dst, tag=2))
+            for chunk in ranges:
+                bufs = [wires[j][off : off + ln] for (j, off, ln) in chunk]
+                pending.append(self._pg.send(bufs, dst, tag=2))
                 if len(pending) >= self.SEND_WINDOW:
                     pending.pop(0).wait(self._timeout)
             for work in pending:
@@ -230,11 +184,27 @@ class PGTransport(CheckpointTransport[Any]):
             timeout.total_seconds() if isinstance(timeout, timedelta) else timeout
         )
         header = self._pg.recv(src_rank, tag=1).get_future().wait(timeout_s)
-        # tolerant unpack: a pre-batching peer sends (step, spec), a
-        # batching peer (step, spec, True), a ranged peer
-        # (step, spec, "ranged", ranges) — mixed-version heals still work
-        got_step, spec, *rest = pickle.loads(bytes(header[0]))
-        proto = rest[0] if rest else False
+        # the header comes from another process: anything but the ranged
+        # form is refused in words, not waited on for frames that this
+        # receiver could not place
+        got = pickle.loads(bytes(header[0]))
+        if not (
+            isinstance(got, tuple) and len(got) == 5 and got[2] == "ranged"
+        ):
+            # spec and ranges are long: their types say enough
+            came = (
+                [
+                    x if isinstance(x, (int, str)) else type(x).__name__
+                    for x in got
+                ]
+                if isinstance(got, tuple)
+                else type(got).__name__
+            )
+            raise RuntimeError(
+                f"checkpoint header from rank {src_rank} is not the ranged "
+                f'wire (step, spec, "ranged", ranges, crcs): got {came}'
+            )
+        got_step, spec, _, ranges, crcs = got
         if got_step != step:
             raise RuntimeError(f"expected checkpoint step {step}, got {got_step}")
 
@@ -246,116 +216,18 @@ class PGTransport(CheckpointTransport[Any]):
             template_leaves = template_leaves_for(
                 spec, self._template_fn(), logger
             )
-
-        # direct-into-template receive (feature-detected: beyond the torch
-        # PG surface; Baby PGs fall back to the recv+place path): a host
-        # template leaf that can absorb gets the raw frame streamed into
-        # its own memory — no wire allocation, no copy
-        recv_into = getattr(self._pg, "recv_into", None)
-
-        def _absorb_target(i: int, meta) -> Optional[np.ndarray]:
-            if (
-                recv_into is not None
-                and template_leaves is not None
-                and meta.kind == "array"
-                and can_absorb(template_leaves[i], meta.shape, meta.dtype,
-                               require_contiguous=True)
-            ):
-                return template_leaves[i]
-            return None
-
-        def _finish_leaf(i: int, meta, wire_buf) -> Any:
-            # pass the received ndarray straight through: leaf_from_bytes's
-            # ndarray path re-views it with zero copies (bytes() would cost
-            # two extra full-leaf copies)
-            leaf = leaf_from_bytes(meta, wire_buf)
-            if template_leaves is not None and meta.kind == "array":
-                leaf = place_leaf_like(leaf, template_leaves[i], logger)
-            return leaf
-
-        payload_leaves: List[Any] = []
-        if proto == "ranged":
-            return self._recv_ranged(
-                src_rank, spec, rest[1], template_leaves, timeout_s,
-                crcs=rest[2] if len(rest) > 2 else None,
-            )
-        if proto:
-            # one message per wire group (same deterministic grouping as
-            # the sender derives from this spec). Absorb-capable template
-            # leaves ride as preallocated views so their raw frames stream
-            # straight into the template's memory; the rest land in wire
-            # buffers and are placed after.
-            targets = [_absorb_target(i, m) for i, m in enumerate(spec.leaves)]
-            views = [
-                t.reshape(-1).view(np.uint8) if t is not None else None
-                for t in targets
-            ]
-            for group in self._wire_groups(spec):
-                gviews = [views[i] for i in group]
-                if recv_into is not None:
-                    got = self._pg.recv_into(gviews, src_rank, tag=2) \
-                        .get_future().wait(timeout_s)
-                else:
-                    got = self._pg.recv(src_rank, tag=2).get_future().wait(
-                        timeout_s
-                    )
-                n_got = len(got) if got else 0
-                if n_got != len(group):
-                    err = self._pg.errored()
-                    raise RuntimeError(
-                        f"batched recv from rank {src_rank} returned "
-                        f"{n_got} of {len(group)} leaves (pg errored: "
-                        f"{err})"
-                    )
-                for j, i in enumerate(group):
-                    meta = spec.leaves[i]
-                    if views[i] is not None and got[j] is views[i]:
-                        payload_leaves.append(targets[i])
-                    else:
-                        payload_leaves.append(_finish_leaf(i, meta, got[j]))
-        else:
-            for i, meta in enumerate(spec.leaves):
-                target = _absorb_target(i, meta)
-                if target is not None:
-                    # the wire carries the leaf as one flat uint8 frame;
-                    # hand recv_into the template's flat view so the frame
-                    # lands in the template's buffer (identity of the
-                    # returned entry is the absorbed/fallback signal)
-                    view = target.reshape(-1).view(np.uint8)
-                    got = self._pg.recv_into([view], src_rank, tag=2) \
-                        .get_future().wait(timeout_s)
-                    if got and got[0] is view:
-                        payload_leaves.append(target)
-                        continue
-                    buf = got  # pickled path or wire/buffer mismatch
-                else:
-                    buf = self._pg.recv(src_rank, tag=2).get_future().wait(
-                        timeout_s
-                    )
-                if not buf:
-                    # an aborted/errored receive resolves to an empty
-                    # result; indexing it would mask the transport failure
-                    # with an IndexError
-                    err = self._pg.errored()
-                    raise RuntimeError(
-                        f"recv of leaf {i} from rank {src_rank} returned no "
-                        f"buffer (pg errored: {err})"
-                    )
-                payload_leaves.append(_finish_leaf(i, meta, buf[0]))
-
-        import jax
-
-        treedef = pickle.loads(spec.treedef_bytes)
-        return jax.tree_util.tree_unflatten(treedef, payload_leaves)
+        return self._recv_ranged(
+            src_rank, spec, ranges, crcs, template_leaves, timeout_s
+        )
 
     def _recv_ranged(
         self,
         src_rank: int,
         spec: TreeSpecPayload,
         ranges: List[List[Any]],
+        crcs: List[int],
         template_leaves: Optional[List[Any]],
         timeout_s: float,
-        crcs: Optional[List[int]] = None,
     ) -> Any:
         """Receive the ranged wire: one message per chunk of byte ranges
         (the plan rode the header). The recv of chunk i+1 runs on a worker
@@ -363,23 +235,18 @@ class PGTransport(CheckpointTransport[Any]):
         chunk i completed — the pipelining that hides placement behind the
         wire for multi-chunk heals.
 
-        ``crcs`` (when the sender's header carries them) are verified per
-        chunk after the copy into the destination views — detection only on
-        this push-based wire: a mismatch raises, the Manager's
-        discard-and-retry heal protocol re-requests the transfer, and the
-        corrupt bytes are never finalized into leaves."""
-        recv_into = getattr(self._pg, "recv_into", None)
-
+        ``crcs`` are verified per chunk after the copy into the destination
+        views — detection only on this push-based wire: a mismatch raises,
+        the Manager's discard-and-retry heal protocol re-requests the
+        transfer, and the corrupt bytes are never finalized into leaves."""
         # flat uint8 destination per leaf: absorb-capable template leaves
         # expose their own memory (frames stream straight in), the rest
         # get a wire buffer reused across that leaf's ranges
         dests: List[np.ndarray] = []
         absorbed: List[bool] = []
         for i, meta in enumerate(spec.leaves):
-            target = None
-            if (
-                recv_into is not None
-                and template_leaves is not None
+            absorbs = (
+                template_leaves is not None
                 and meta.kind == "array"
                 and can_absorb(
                     template_leaves[i],
@@ -387,14 +254,13 @@ class PGTransport(CheckpointTransport[Any]):
                     meta.dtype,
                     require_contiguous=True,
                 )
-            ):
-                target = template_leaves[i]
-            if target is not None:
-                dests.append(target.reshape(-1).view(np.uint8))
-                absorbed.append(True)
-            else:
-                dests.append(np.empty(meta.nbytes, np.uint8))
-                absorbed.append(False)
+            )
+            dests.append(
+                template_leaves[i].reshape(-1).view(np.uint8)
+                if absorbs
+                else np.empty(meta.nbytes, np.uint8)
+            )
+            absorbed.append(absorbs)
 
         payloads: List[Optional[Any]] = [None] * len(spec.leaves)
         remaining: List[int] = [m.nbytes for m in spec.leaves]
@@ -413,13 +279,8 @@ class PGTransport(CheckpointTransport[Any]):
         def transfer(item: Any) -> List[Any]:
             ci, chunk = item
             gviews = [dests[j][off : off + ln] for (j, off, ln) in chunk]
-            if recv_into is not None:
-                got = self._pg.recv_into(gviews, src_rank, tag=2) \
-                    .get_future().wait(timeout_s)
-            else:
-                got = self._pg.recv(src_rank, tag=2).get_future().wait(
-                    timeout_s
-                )
+            got = self._pg.recv_into(gviews, src_rank, tag=2) \
+                .get_future().wait(timeout_s)
             n_got = len(got) if got else 0
             if n_got != len(chunk):
                 err = self._pg.errored()
@@ -442,16 +303,15 @@ class PGTransport(CheckpointTransport[Any]):
                         f"{buf.size} bytes, plan says {ln}"
                     )
                 np.copyto(gviews[k], buf)
-            if crcs is not None:
-                got_crc = 0
-                for gv in gviews:
-                    got_crc = zlib.crc32(gv, got_crc)
-                if got_crc & 0xFFFFFFFF != crcs[ci] & 0xFFFFFFFF:
-                    raise RuntimeError(
-                        f"ranged recv: chunk {ci} crc32 mismatch "
-                        f"(got {got_crc & 0xFFFFFFFF:#010x}, header says "
-                        f"{crcs[ci] & 0xFFFFFFFF:#010x}); discarding heal"
-                    )
+            got_crc = 0
+            for gv in gviews:
+                got_crc = zlib.crc32(gv, got_crc)
+            if got_crc & 0xFFFFFFFF != crcs[ci] & 0xFFFFFFFF:
+                raise RuntimeError(
+                    f"ranged recv: chunk {ci} crc32 mismatch "
+                    f"(got {got_crc & 0xFFFFFFFF:#010x}, header says "
+                    f"{crcs[ci] & 0xFFFFFFFF:#010x}); discarding heal"
+                )
             return chunk
 
         def finish(chunk: List[Any]) -> None:
@@ -486,5 +346,3 @@ class PGTransport(CheckpointTransport[Any]):
 
     def shutdown(self, wait: bool = True) -> None:
         pass  # the PG is owned by the caller
-
-

@@ -58,8 +58,6 @@ __all__ = [
     "ProcessGroup",
     "ProcessGroupDummy",
     "ProcessGroupHost",
-    "ProcessGroupBaby",
-    "ProcessGroupBabyHost",
     "ErrorSwallowingProcessGroupWrapper",
     "FakeProcessGroupWrapper",
     "ManagedProcessGroup",
@@ -1960,8 +1958,7 @@ class ProcessGroupHost(ProcessGroup):
         # filled by the plain ring, if one runs (_ring_allreduce: inplace,
         # chunks, lanes, and its account of its own time). A world of one
         # says ``lanes`` alone; the mesh exchange under _RING_MIN_BYTES and
-        # the compressed ring say nothing, and ProcessGroupBabyHost's ring
-        # runs in its child, whose ``info`` stays there
+        # the compressed ring say nothing
         info: Dict[str, Any] = {}
 
         def _run(comm):
@@ -2131,8 +2128,8 @@ class ProcessGroupHost(ProcessGroup):
         """Like :meth:`recv` (which delegates here with no buffers), but
         raw-frame payloads land DIRECTLY in the caller's preallocated
         ``buffers`` — no wire allocation and no copy (the in-place
-        checkpoint receive's hot path; beyond the torch PG surface, so
-        transports feature-detect it with ``getattr``).
+        checkpoint receive's hot path; beyond the torch PG surface, and
+        what ``PGTransport`` requires of its recovery PG).
 
         The returned Work's value is the list of received arrays: entry i
         IS ``buffers[i]`` when the wire used a raw frame and the buffer
@@ -2162,425 +2159,6 @@ class ProcessGroupHost(ProcessGroup):
             return out
 
         return self._submit(_run, "recv", mode="p2p")
-
-
-# ---------------------------------------------------------------------------
-# Subprocess-isolated ("Baby") process groups
-# ---------------------------------------------------------------------------
-
-
-def _call_quietly(fn: Any) -> None:
-    try:
-        fn()
-    except Exception:  # noqa: BLE001 - best-effort abort path
-        pass
-
-
-def _baby_worker(
-    pg_class: type,
-    store_addr: str,
-    rank: int,
-    world: int,
-    quorum_id: int,
-    timeout: float,
-    req_conn: Any,
-    fut_conn: Any,
-    abort_cell: Optional[list] = None,
-) -> None:
-    """Child-side loop of a Baby process group.
-
-    Runs the real PG inside the child (reference `_worker`,
-    process_group.py:1565-1695): configures it, then serves
-    ``("func", op_id, name, args, kwargs)`` requests from the parent, posting
-    each op's result or exception to the future pipe as it completes. Module
-    top-level so the spawn start method can pickle it.
-    """
-    fut_lock = threading.Lock()
-
-    def _post(op_id: Any, payload: Any, kind: str) -> None:
-        with fut_lock:
-            try:
-                fut_conn.send((op_id, kind, payload))
-            except (OSError, EOFError, BrokenPipeError):
-                pass  # parent is gone; the loop will exit on the next recv
-            except Exception as e:  # noqa: BLE001 - e.g. unpicklable payload
-                # Never lose the op: degrade to a picklable error so the
-                # parent future resolves instead of hanging to timeout.
-                try:
-                    fut_conn.send(
-                        (op_id, "exception",
-                         RuntimeError(f"baby worker could not ship {kind}: {e!r}"))
-                    )
-                except (OSError, EOFError, BrokenPipeError):
-                    pass
-
-    try:
-        pg = pg_class(timeout=timeout)
-        pg.configure(store_addr, rank, world, quorum_id=quorum_id)
-    except Exception as e:  # noqa: BLE001
-        _post("init", e, "exception")
-        return
-    if abort_cell is not None:
-        # Parent-side abort hook. Only effective with the thread-backed
-        # DummyContext (shared memory): kill() is a no-op for threads and
-        # closing the request pipe only unblocks this recv loop, not an op
-        # wedged inside the inner PG — the hook lets the parent's abort()
-        # reach pg.abort() directly. Under a spawn context this appends to
-        # the child's pickled copy, which the parent never sees (and never
-        # needs: kill() works there).
-        abort_cell.append(pg.abort)
-    _post("init", None, "result")
-
-    while True:
-        try:
-            cmd = req_conn.recv()
-        except (EOFError, OSError):
-            break
-        if cmd is None:
-            break
-        if cmd[0] == "func":
-            _, op_id, name, args, kwargs = cmd
-            try:
-                work = getattr(pg, name)(*args, **kwargs)
-            except Exception as e:  # noqa: BLE001
-                _post(op_id, e, "exception")
-                continue
-
-            def _done(f: Future, op_id: Any = op_id) -> None:
-                exc = f.exception()
-                if exc is not None:
-                    if not isinstance(exc, Exception):
-                        exc = RuntimeError(str(exc))
-                    _post(op_id, exc, "exception")
-                else:
-                    _post(op_id, f.value(), "result")
-
-            work.get_future().add_done_callback(_done)
-    pg.shutdown()
-
-
-class ProcessGroupBaby(ProcessGroup):
-    """Runs the real PG in a spawned child process so a hung or wedged
-    communicator can be killed without killing the trainer.
-
-    Reference: ProcessGroupBaby, process_group.py:1445-1923. On TPU this
-    isolation matters doubly: the trainer process owns the (expensive,
-    stateful) JAX/TPU runtime, so a stuck DCN socket or host collective must
-    never require restarting it. Arrays cross the pipe as numpy — the host
-    staging the cross-replica-group plane already requires — rather than the
-    reference's shared-memory tensors.
-
-    ``ctx`` defaults to the ``spawn`` multiprocessing context; pass
-    :class:`torchft_tpu.multiprocessing_dummy_context.DummyContext` to run the
-    child threaded in-process (reference multiprocessing_dummy_context
-    pattern, used by the fast test matrix).
-    """
-
-    PG_CLASS: type = None  # type: ignore[assignment]  # set by subclasses
-
-    class _Gen:
-        """One configure() generation: child process, pipes, outstanding ops."""
-
-        def __init__(
-            self,
-            proc: Any,
-            req: "_MonitoredPipe",
-            fut: "_MonitoredPipe",
-            abort_cell: Optional[list] = None,
-        ):
-            self.proc = proc
-            self.req = req
-            self.fut_pipe = fut
-            self.futures: Dict[int, Future] = {}
-            self.lock = threading.Lock()
-            self.error: Optional[Exception] = None
-            self.stopped = False
-            # child-side pg.abort hook; populated only under DummyContext
-            self.abort_cell: list = [] if abort_cell is None else abort_cell
-
-    def __init__(self, timeout: "float | timedelta" = 60.0, ctx: Any = None) -> None:
-        super().__init__()
-        self.set_timeout(timeout)
-        self._ctx = ctx
-        self._gen: Optional[ProcessGroupBaby._Gen] = None
-        self._rank = 0
-        self._world = 1
-        self._next_op_id = 0
-        self._lock = threading.Lock()
-
-    # -- lifecycle --------------------------------------------------------
-    def configure(self, store_addr, replica_rank, replica_world_size, quorum_id=0):
-        from torchft_tpu.multiprocessing import _MonitoredPipe
-
-        self._teardown(terminal=False)
-
-        if self._ctx is None:
-            import multiprocessing as mp
-
-            ctx = mp.get_context("spawn")
-        else:
-            ctx = self._ctx
-        req_local, req_remote = ctx.Pipe()
-        fut_local, fut_remote = ctx.Pipe()
-        abort_cell: list = []
-        proc = ctx.Process(
-            target=_baby_worker,
-            args=(
-                type(self).PG_CLASS,
-                store_addr,
-                replica_rank,
-                replica_world_size,
-                quorum_id,
-                self._timeout,
-                req_remote,
-                fut_remote,
-                abort_cell,
-            ),
-            daemon=True,
-            name=f"baby_pg_r{replica_rank}",
-        )
-        proc.start()
-        # With real mp Connections, drop the parent's copies of the child ends
-        # so a dead child reads as EOF on the local ends. (The dummy context's
-        # close() signals the peer instead, so leave those open.)
-        import multiprocessing.connection as _mpc
-
-        for remote in (req_remote, fut_remote):
-            if isinstance(remote, _mpc.Connection):
-                remote.close()
-
-        gen = ProcessGroupBaby._Gen(
-            proc, _MonitoredPipe(req_local), _MonitoredPipe(fut_local), abort_cell
-        )
-        # Init ack: the child's configure() rendezvouses with its peers, so
-        # give it the full op timeout plus slack for process startup. On any
-        # failure (timeout, child init error) reap the child and pipes — a
-        # trainer reconfigures every quorum, so a failed configure must not
-        # orphan a live child holding sockets and KV entries.
-        try:
-            op_id, kind, payload = gen.fut_pipe.recv(self._timeout + 30.0)  # type: ignore[misc]
-            assert op_id == "init", op_id
-            if kind == "exception":
-                raise payload
-        except BaseException:
-            gen.stopped = True
-            gen.req.close()
-            gen.fut_pipe.close()
-            if hasattr(proc, "kill"):
-                proc.kill()
-            proc.join(5.0)
-            raise
-
-        with self._lock:
-            self._gen = gen
-            self._rank = replica_rank
-            self._world = replica_world_size
-        threading.Thread(
-            target=self._future_handler,
-            args=(gen,),
-            daemon=True,
-            name=f"baby_pg_futures_r{replica_rank}",
-        ).start()
-
-    def _future_handler(self, gen: "ProcessGroupBaby._Gen") -> None:
-        """Parent-side pump: resolves parent futures from the future pipe
-        (reference `_future_handler`, process_group.py:1697-1730)."""
-        while True:
-            if gen.stopped:
-                return
-            try:
-                if not gen.fut_pipe.poll(0.1):
-                    continue
-                op_id, kind, payload = gen.fut_pipe.recv(0)  # type: ignore[misc]
-            except TimeoutError:
-                continue
-            except (EOFError, OSError):
-                err = gen.error or RuntimeError("baby process group child died")
-                self._fail_gen(gen, err)
-                return
-            with gen.lock:
-                fut = gen.futures.pop(op_id, None)
-            if fut is None:
-                continue
-            try:
-                if kind == "exception":
-                    gen.error = payload
-                    fut.set_exception(payload)
-                else:
-                    fut.set_result(payload)
-            except RuntimeError:
-                pass  # future already resolved (e.g. by abort)
-
-    def _fail_gen(self, gen: "ProcessGroupBaby._Gen", err: Exception) -> None:
-        gen.error = gen.error or err
-        with gen.lock:
-            outstanding, gen.futures = dict(gen.futures), {}
-        for fut in outstanding.values():
-            try:
-                fut.set_exception(err)
-            except RuntimeError:
-                pass
-
-    def _teardown(self, terminal: bool) -> None:
-        with self._lock:
-            gen, self._gen = self._gen, None
-        if gen is None:
-            return
-        gen.stopped = True
-        try:
-            gen.req.send(None)  # polite shutdown for thread-backed children
-        except (OSError, EOFError, BrokenPipeError):
-            pass
-        gen.req.close()
-        gen.fut_pipe.close()
-        if hasattr(gen.proc, "kill"):
-            gen.proc.kill()
-        gen.proc.join(5.0)
-        self._fail_gen(
-            gen,
-            RuntimeError(
-                "process group shut down"
-                if terminal
-                else "process group torn down for reconfiguration"
-            ),
-        )
-
-    def abort(self) -> None:
-        with self._lock:
-            gen = self._gen
-        if gen is None:
-            return
-        gen.error = gen.error or RuntimeError("process group aborted")
-        gen.stopped = True
-        if hasattr(gen.proc, "kill"):
-            gen.proc.kill()
-        gen.req.close()
-        gen.fut_pipe.close()
-        # Under DummyContext the "child" is a thread: kill() was a no-op and
-        # closing the pipes only unblocks its recv loop, not an op wedged
-        # inside the inner PG. Invoke the child's pg.abort() hook directly —
-        # on a daemon thread, because abort() must return promptly even if
-        # the inner abort itself wedges.
-        for hook in list(gen.abort_cell):
-            threading.Thread(
-                target=lambda h=hook: _call_quietly(h),
-                daemon=True,
-                name="baby_pg_inner_abort",
-            ).start()
-        self._fail_gen(gen, gen.error)
-        # Parent-side postmortem: the child (and its inner PG's abort-time
-        # dump) was just killed, so the dump must happen here (reference:
-        # abort-triggered FR dump, process_group.py:875-883).
-        from torchft_tpu.observability import log_error_event
-
-        log_error_event(
-            source="baby_process_group",
-            event="abort",
-            replica_rank=self._rank,
-            replica_world_size=self._world,
-        )
-        _fr.recorder.record("baby_pg_abort", rank=self._rank, world=self._world)
-        _fr.recorder.dump(reason="baby_pg_abort")
-
-    def shutdown(self) -> None:
-        self._teardown(terminal=True)
-
-    def errored(self) -> Optional[Exception]:
-        with self._lock:
-            gen = self._gen
-        if gen is None:
-            return None
-        if gen.error is None and not gen.proc.is_alive() and not gen.stopped:
-            gen.error = RuntimeError(
-                f"baby process group child exited (exitcode={gen.proc.exitcode})"
-            )
-        return gen.error
-
-    def size(self) -> int:
-        return self._world
-
-    def rank(self) -> int:
-        return self._rank
-
-    def num_active_work(self) -> int:
-        """Outstanding ops not yet resolved (reference introspection,
-        process_group.py:1801-1804)."""
-        with self._lock:
-            gen = self._gen
-        if gen is None:
-            return 0
-        with gen.lock:
-            return len(gen.futures)
-
-    # -- dispatch ---------------------------------------------------------
-    def _submit(self, name: str, *args: Any, **kwargs: Any) -> Work:
-        with self._lock:
-            gen = self._gen
-            if gen is None:
-                raise RuntimeError("process group is not configured")
-            if gen.error is not None:
-                raise gen.error
-            op_id = self._next_op_id
-            self._next_op_id += 1
-        fut: Future = Future()
-        with gen.lock:
-            gen.futures[op_id] = fut
-        _fr.recorder.record("collective", op=name, rank=self._rank, world=self._world)
-        try:
-            gen.req.send(("func", op_id, name, list(args), kwargs))
-        except (OSError, EOFError, BrokenPipeError) as e:
-            err = RuntimeError(f"baby process group pipe broken: {e}")
-            self._fail_gen(gen, err)
-            raise err from e
-        # Close the register/fail race: _fail_gen swaps gen.futures under
-        # gen.lock and fails only the swapped-out set, so a future registered
-        # after the swap would never resolve (with the thread-backed
-        # DummyContext the send above lands silently in an un-drained queue
-        # and the caller would hang to its wait timeout). _fail_gen sets
-        # gen.error *before* the swap, so if neither stopped nor error is
-        # visible here, our future was registered in time and is covered.
-        if gen.stopped or gen.error is not None:
-            with gen.lock:
-                orphan = gen.futures.pop(op_id, None)
-            if orphan is not None:
-                try:
-                    orphan.set_exception(
-                        gen.error or RuntimeError("process group stopped")
-                    )
-                except RuntimeError:
-                    pass  # resolved concurrently
-        return FutureWork(fut)
-
-    # -- collectives ------------------------------------------------------
-    def allreduce(self, arrays, op=ReduceOp.SUM, donate=False):
-        # the arrays cross a pipe to the child: nothing to hand back
-        return self._submit("allreduce", [_to_host(a) for a in arrays], op)
-
-    def allgather(self, arrays):
-        return self._submit("allgather", [_to_host(a) for a in arrays])
-
-    def broadcast(self, arrays, root=0):
-        return self._submit("broadcast", [_to_host(a) for a in arrays], root)
-
-    def reduce_scatter(self, input_chunks, op=ReduceOp.SUM):
-        host = [[_to_host(a) for a in chunk] for chunk in input_chunks]
-        return self._submit("reduce_scatter", host, op)
-
-    def alltoall(self, input_chunks):
-        return self._submit("alltoall", [_to_host(a) for a in input_chunks])
-
-    def send(self, arrays, dst, tag=0):
-        return self._submit("send", [_to_host(a) for a in arrays], dst, tag)
-
-    def recv(self, src, tag=0):
-        return self._submit("recv", src, tag)
-
-
-class ProcessGroupBabyHost(ProcessGroupBaby):
-    """Baby PG running :class:`ProcessGroupHost` in the child (the reference's
-    ProcessGroupBabyGloo, process_group.py:1978-2038)."""
-
-    PG_CLASS = ProcessGroupHost
 
 
 # ---------------------------------------------------------------------------

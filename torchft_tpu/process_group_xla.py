@@ -880,8 +880,8 @@ class ProcessGroupXLA(ProcessGroup):
         slots it can see under world.lock, so a slot created (or deposited
         into) after that snapshot would hang its future to the wait timeout.
         world.error is set before the snapshot is taken — if it is not
-        visible after our deposit, abort() will see our slot. Same shape as
-        the ProcessGroupBaby._submit re-check."""
+        visible after our deposit, abort() will see our slot (the re-check
+        after enqueue that the reference's ProcessGroupBaby._submit makes)."""
         fut, last = slot.deposit(rank, leaves)
         if world.error is not None:
             slot.fail(world.error)

@@ -6,10 +6,12 @@ positions in chunks of 16 and blocks of 32, four query heads over two
 key/value heads."""
 
 import functools
+import hashlib
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from torchft_tpu.ops import power_retention as R
@@ -171,6 +173,113 @@ def test_bf16_inputs_keep_a_float32_state(monkeypatch):
     assert _rel(R.power_retention(*args, **SIZES), want) > 100 * kept
 
 
+# -- what the forward pass saves for the backward pass
+
+
+def _phi_tiles(x):
+    """phi of the columns of ``x`` [d, n] in the kernels' layout, [(d / 2 + 1)
+    d, n] (the module's text), by plain indexing."""
+    d = x.shape[0]
+    r = jnp.arange(d)
+    weight = jnp.where((r == d // 2 - 1) | (r == d - 1), 1.0, math.sqrt(2.0))[:, None]
+    tiles = [jnp.where(r[:, None] < d // 2, x[a], x[a + d // 2]) * x[(r + a + 1) % d] * weight
+             for a in range(d // 2)]
+    return jnp.concatenate(tiles + [x * x])
+
+
+def test_the_saved_read_is_what_every_chunk_reads_from_the_state_it_starts_from():
+    """Two blocks of two chunks: the forward rule's new output is ``phi(s q)^T
+    S0`` with the normaliser's row, before the decay and before the chunk's
+    own pairs, where ``S0`` is the saved state of the block's start for its
+    first chunk and that state advanced by plain ``jax.numpy`` for its
+    second; the rows under the normaliser's are zero. The plain call, which
+    no gradient follows, has no such output."""
+    q, k, v, g = _args("near_one")
+    C, block = SIZES["chunk"], SIZES["block"]
+    rep, rows, scale = HQ // H, D + R.PAD, D ** -0.5
+    g6 = jnp.swapaxes(g, 1, 2).reshape(B, H, T // block, block // C, 1, C)
+    flat = (q.reshape(B, T, -1), k.reshape(B, T, -1), v.reshape(B, T, -1), g6)
+    cfg = (H, scale, C, block, T)
+    assert len(R._forward(*flat, cfg, save_reads=False)) == 3
+    y, hs, _, reads = jax.jit(lambda *a: R._forward(*a, cfg, save_reads=True))(*flat)
+    assert reads.shape == (B, H, T // block, block // C, rows, rep * C) and reads.dtype == jnp.float32
+    assert _rel(y.reshape(B, T, HQ, D), _answers("kernel", "near_one")["y"]) == 0.0
+    assert float(jnp.max(jnp.abs(reads[..., D + 1:, :]))) == 0.0
+    want = np.zeros(reads.shape, np.float32)
+    with jax.default_matmul_precision("highest"):
+        for b, h, n in np.ndindex(B, H, T // block):
+            state = jnp.concatenate(list(hs[b, h, n]), axis=1)  # [rows, tiles * d]
+            for c in range(block // C):
+                at = slice(n * block + c * C, n * block + (c + 1) * C)
+                x = jnp.concatenate([q[b, at, h * rep + i].T for i in range(rep)], axis=1) * scale
+                want[b, h, n, c] = state @ _phi_tiles(x)
+                G = jnp.cumsum(g[b, at, h])
+                vz = jnp.zeros((rows, C)).at[:D].set(v[b, at, h].T).at[D].set(1.0)
+                state = jnp.exp(G[-1]) * state + (vz * jnp.exp(G[-1] - G)) @ _phi_tiles(k[b, at, h].T).T
+    assert float(jnp.max(jnp.abs(reads[:, :, 0, 0]))) == 0.0  # the first chunk finds a zero state
+    assert _rel(reads, want) < 2e-5
+
+
+# sha256 over the float32 bytes of (dq, dk, dv, dg) through the kernels as they
+# stood BEFORE the forward pass saved the reads (PR 61's tree, this machine's
+# CPU, interpreted): the backward kernel now loads what it computed then, from
+# the same float32 state by the same operations in the same order, so no bit
+# of any gradient may move. A digest moves only with the kernels' arithmetic;
+# a PR that means to change that rewrites the pins.
+PARENT = {1: "afc8e4374da30e97f496586d800a601088f22e342c9b4e667e069a45f5d09e59",
+          5: "c5329e3a05e87ea8124b71fa8c72cfe337c995f9d7fb2283082c745ed3550cdd"}
+
+
+@pytest.mark.parametrize("rep", sorted(PARENT))
+def test_the_gradients_are_the_parents_to_the_bit(rep):
+    ks = jax.random.split(jax.random.PRNGKey(62 + rep), 5)
+    q, w = (jax.random.normal(key, (B, T, H * rep, D)) for key in (ks[0], ks[4]))
+    k, v = (jax.random.normal(key, (B, T, H, D)) for key in ks[1:3])
+    g = jax.nn.log_sigmoid(1.5 * jax.random.normal(ks[3], (B, T, H)) + 2.0)
+    _, pull = jax.vjp(jax.jit(functools.partial(R.power_retention, **SIZES)), q, k, v, g)
+    digest = hashlib.sha256()
+    for grad in pull(w):
+        digest.update(np.asarray(grad, np.float32).tobytes())
+    assert digest.hexdigest() == PARENT[rep]
+
+
+def _equations(jaxpr):
+    """Every equation in ``jaxpr`` and under it; a loop's body is one jaxpr
+    and counts once."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_the_backward_kernel_multiplies_no_tile_of_the_state_by_the_queries():
+    """Five query heads a group, so that the queries' tile [d, rep C] is no
+    key's [d, C]. A float32 product is three ``dot_general`` (``_dot``), a
+    running sum of log-decays one. The forward kernel reads the state in two
+    places (the loop over tiles, the last tile); the backward kernel holds
+    the advance (``again``: 1 product a tile), the four products a tile a
+    backward pass requires (``back``: u, dt, dw, dx), six of the chunk's own
+    pairs and three running sums: 51 where it was 57 with the read, and none
+    of [d_v + PAD, d] by [d, rep C]."""
+    rep, C = 5, SIZES["chunk"]
+    q, k, v, g = (jnp.zeros(s) for s in ((1, T, H * rep, D), (1, T, H, D), (1, T, H, D), (1, T, H)))
+    grad = jax.grad(lambda *a: jnp.sum(R.power_retention(*a, **SIZES)), argnums=(0, 1, 2, 3))
+    kernels = {e.params["name"]: e.params["jaxpr"]
+               for e in _equations(jax.make_jaxpr(grad)(q, k, v, g).jaxpr)
+               if e.primitive.name == "pallas_call"}
+    assert sorted(kernels) == ["power_retention_bwd", "power_retention_fwd"]
+
+    def products(name):
+        return [(tuple(x.aval.shape for x in e.invars), e.params["dimension_numbers"][0])
+                for e in _equations(kernels[name]) if e.primitive.name == "dot_general"]
+
+    read = (((D + R.PAD, D), (D, rep * C)), ((1,), (0,)))
+    assert products("power_retention_fwd").count(read) == 2 * 3
+    backward = products("power_retention_bwd")
+    assert read not in backward
+    assert len(backward) == 3 * (2 * (1 + 4) + 6) + 3
+
+
 # -- the kernels at the published widths, compiled for the chip that is described
 
 
@@ -200,3 +309,7 @@ def test_the_kernels_compile_for_a_v5e_at_the_published_widths(monkeypatch, one_
     text = jax.jit(f).lower(*args).compile().as_text()
     assert "power_retention_fwd" in text
     assert ("power_retention_bwd" in text) == (passes == "backward")
+    # the saved reads (0.38 GB: 64 chunks x 8 heads x 144 x 1,280 float32) leave
+    # the forward kernel and enter the backward kernel, 2.9 MB a block in VMEM
+    # twice over beside what each held; the plain call writes none
+    assert ("f32[1,8,16,4,144,1280]" in text) == (passes == "backward")

@@ -40,11 +40,15 @@ blocks (4.8 MB a head at ``d`` = 128), the ``rep`` query heads of a group
 side by side against the one state. ``phi`` exists in VMEM only, a tile of
 ``d`` features at a time: written to HBM ``phi(K)`` would be 16.5 KB a token
 a head. The forward pass writes the state every BLOCK starts from (not every
-chunk: 0.6 GB a layer at 16k and 8 heads); the backward pass walks the blocks
-in reverse with the state's cotangent in scratch, computes the states inside
-a block again from the saved one and takes the chunk apart with the SAME
-functions the forward pass is made of (:func:`_prepare`, :func:`_intra`,
-:func:`_tile`), so forward and backward cannot drift apart. The state, the
+chunk: 0.6 GB a layer at 16k and 8 heads) and, where a gradient follows,
+what every chunk READS from the state it starts from (``phi(Q) S0`` with the
+normaliser's row, before the decay: 0.38 GB a layer); the backward pass walks
+the blocks in reverse with the state's cotangent in scratch, computes the
+states inside a block again from the saved one, loads each chunk's read
+where it would multiply the state's tiles by the queries' a second time, and
+takes the chunk apart with the SAME functions the forward pass is made of
+(:func:`_prepare`, :func:`_intra`, :func:`_tile`), so forward and backward
+cannot drift apart. The state, the
 normaliser, the decays and the division are float32; a product of float32
 operands is three passes of the MXU over their bfloat16 halves
 (:func:`_dot`), the running sums of the log-decays six. Off the TPU the same
@@ -248,7 +252,11 @@ def _advance(w_from, w_to, kx_ref, vzw, gamma):
 
 
 def _fwd_kernel(rep, C, scale, valid, q_ref, k_ref, v_ref, g_ref,
-                y_ref, hs_ref, dmin_ref, w_scr, x_scr, kx_scr, out_scr):
+                y_ref, hs_ref, dmin_ref, *rest):
+    """One block. ``rest``: ``reads_ref`` [block / C, d_v + PAD, rep * C],
+    what each chunk reads from the state it starts from, where the backward
+    pass follows (the plain call has no such output), then the scratch."""
+    *reads_ref, w_scr, x_scr, kx_scr, out_scr = rest
     block = q_ref.shape[0]
     d, dv = k_ref.shape[1], v_ref.shape[1]
 
@@ -284,6 +292,8 @@ def _fwd_kernel(rep, C, scale, valid, q_ref, k_ref, v_ref, g_ref,
         jax.lax.fori_loop(0, d // 2, tile, 0)
         x, kx, w = x_scr[...], kx_scr[...], w_scr[d // 2]
         from_state = out_scr[...] + _dot(w, x * x)
+        for ref in reads_ref:
+            ref[c] = from_state
         w_scr[d // 2] = _rounded(gamma * w + _dot(vzw, kx * kx, _NT))
         out = _tiled(jnp.exp(G), rep) * from_state + _dot(vz, _intra(kf, x, dec, rep)[1])
         den = out[dv:dv + 1]
@@ -297,13 +307,14 @@ def _fwd_kernel(rep, C, scale, valid, q_ref, k_ref, v_ref, g_ref,
     dmin_ref[...] = jnp.broadcast_to(jnp.min(dmin, axis=1, keepdims=True), dmin_ref.shape)
 
 
-def _bwd_kernel(rep, C, scale, q_ref, k_ref, v_ref, g_ref, hs_ref, dy_ref,
+def _bwd_kernel(rep, C, scale, q_ref, k_ref, v_ref, g_ref, hs_ref, reads_ref, dy_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref,
-                wc_scr, dw_scr, x_scr, kx_scr, out_scr, dx_scr, dkx_scr, u_scr, gam_scr):
+                wc_scr, dw_scr, x_scr, kx_scr, dx_scr, dkx_scr, u_scr, gam_scr):
     """One block, the blocks in reverse: the states its later chunks start
     from are computed again from the saved one, then the chunks are walked
-    backwards; ``dw_scr`` carries the cotangent of the state a block ends
-    with into the block before."""
+    backwards, each with what the forward pass read from the state for it
+    (``reads_ref``); ``dw_scr`` carries the cotangent of the state a block
+    ends with into the block before."""
     block = q_ref.shape[0]
     d, dv = k_ref.shape[1], v_ref.shape[1]
     n_chunks, tiles = block // C, d // 2 + 1
@@ -342,15 +353,7 @@ def _bwd_kernel(rep, C, scale, q_ref, k_ref, v_ref, g_ref, hs_ref, dy_ref,
         vzw = _halves(vz * omega)
 
         # the chunk again, as the forward pass computed it
-        out_scr[...] = jnp.zeros_like(out_scr)
-
-        def read(a, _):
-            sel, win = _tile(x_scr, a, wq)
-            out_scr[...] += _dot(wc_scr[c, a], sel * win)
-            return 0
-
-        jax.lax.fori_loop(0, d // 2, read, 0)
-        from_state = out_scr[...] + _dot(wc_scr[c, d // 2], x * x)
+        from_state = reads_ref[c]
         p, a_mat = _intra(kf, x, dec, rep)
         grew = _tiled(jnp.exp(G), rep)
         out = grew * from_state + _dot(vz, a_mat)
@@ -433,8 +436,9 @@ def _params():
 def _specs(rep, d, dv, block, C, nb, reverse):
     """Block specs of (q [B, T, Hq*d], k [B, T, H*d], v [B, T, H*dv], g [B, H,
     T/block, block/C, 1, C], the saved states [B, H, T/block, tiles, dv + PAD,
-    d]) on the grid (batch, key/value head, block), the blocks in reverse for
-    the backward pass."""
+    d], the saved reads [B, H, T/block, block/C, dv + PAD, rep*C]) on the grid
+    (batch, key/value head, block), the blocks in reverse for the backward
+    pass."""
     at = (lambda c: nb - 1 - c) if reverse else (lambda c: c)
     return (pl.BlockSpec((None, block, rep * d), lambda b, h, c: (b, at(c), h)),
             pl.BlockSpec((None, block, d), lambda b, h, c: (b, at(c), h)),
@@ -442,43 +446,49 @@ def _specs(rep, d, dv, block, C, nb, reverse):
             pl.BlockSpec((None, None, None, block // C, 1, C),
                          lambda b, h, c: (b, h, at(c), 0, 0, 0)),
             pl.BlockSpec((None, None, None, d // 2 + 1, dv + PAD, d),
+                         lambda b, h, c: (b, h, at(c), 0, 0, 0)),
+            pl.BlockSpec((None, None, None, block // C, dv + PAD, rep * C),
                          lambda b, h, c: (b, h, at(c), 0, 0, 0)))
 
 
-def _forward(q, k, v, g, cfg):
+def _forward(q, k, v, g, cfg, save_reads):
     """q [B, T, Hq*d]; k [B, T, H*d]; v [B, T, H*dv]; g [B, H, T/block,
     block/C, 1, C] f32; ``cfg`` = (H, scale, C, block, valid positions) ->
     (y [B, T, Hq*dv] in v's dtype, the state at every block's start, the
-    smallest normaliser of every block [B, H, T/block, 1, 128])."""
+    smallest normaliser of every block [B, H, T/block, 1, 128], and with
+    ``save_reads`` what every chunk reads from the state it starts from)."""
     H, scale, C, block, valid = cfg
     B, T = q.shape[:2]
     d, dv, rep = k.shape[2] // H, v.shape[2] // H, q.shape[2] // k.shape[2]
     nb, tiles, rows = T // block, d // 2 + 1, dv + PAD
-    qs, ks, vs, gs, hs = _specs(rep, d, dv, block, C, nb, reverse=False)
+    qs, ks, vs, gs, hs, rs = _specs(rep, d, dv, block, C, nb, reverse=False)
+    outs = [(pl.BlockSpec((None, block, rep * dv), lambda b, h, c: (b, c, h)),
+             jax.ShapeDtypeStruct((B, T, rep * H * dv), v.dtype)),
+            (hs, jax.ShapeDtypeStruct((B, H, nb, tiles, rows, d), _F32)),
+            (pl.BlockSpec((None, None, None, 1, 128), lambda b, h, c: (b, h, c, 0, 0)),
+             jax.ShapeDtypeStruct((B, H, nb, 1, 128), _F32)),
+            (rs, jax.ShapeDtypeStruct((B, H, nb, block // C, rows, rep * C), _F32))]
+    outs = outs if save_reads else outs[:-1]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, rep, C, scale, valid), grid=(B, H, nb),
         in_specs=[qs, ks, vs, gs],
-        out_specs=[pl.BlockSpec((None, block, rep * dv), lambda b, h, c: (b, c, h)), hs,
-                   pl.BlockSpec((None, None, None, 1, 128), lambda b, h, c: (b, h, c, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B, T, rep * H * dv), v.dtype),
-                   jax.ShapeDtypeStruct((B, H, nb, tiles, rows, d), _F32),
-                   jax.ShapeDtypeStruct((B, H, nb, 1, 128), _F32)],
+        out_specs=[spec for spec, _ in outs], out_shape=[shape for _, shape in outs],
         scratch_shapes=[pltpu.VMEM((tiles, rows, d), _F32), pltpu.VMEM((d, rep * C), _F32),
                         pltpu.VMEM((d, C), _F32), pltpu.VMEM((rows, rep * C), _F32)],
         name="power_retention_fwd", **_params(),
     )(q, k, v, g)
 
 
-def _backward(q, k, v, g, hs, dy, cfg):
+def _backward(q, k, v, g, hs, reads, dy, cfg):
     H, scale, C, block, _ = cfg
     B, T = q.shape[:2]
     d, dv, rep = k.shape[2] // H, v.shape[2] // H, q.shape[2] // k.shape[2]
     nb, tiles, rows = T // block, d // 2 + 1, dv + PAD
-    qs, ks, vs, gs, st = _specs(rep, d, dv, block, C, nb, reverse=True)
+    qs, ks, vs, gs, st, rs = _specs(rep, d, dv, block, C, nb, reverse=True)
     ys = pl.BlockSpec((None, block, rep * dv), lambda b, h, c: (b, nb - 1 - c, h))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, rep, C, scale), grid=(B, H, nb),
-        in_specs=[qs, ks, vs, gs, st, ys], out_specs=[qs, ks, vs, gs],
+        in_specs=[qs, ks, vs, gs, st, rs, ys], out_specs=[qs, ks, vs, gs],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -486,22 +496,22 @@ def _backward(q, k, v, g, hs, dy, cfg):
         scratch_shapes=[pltpu.VMEM((block // C, tiles, rows, d), _F32),
                         pltpu.VMEM((tiles, rows, d), _F32),
                         pltpu.VMEM((d, rep * C), _F32), pltpu.VMEM((d, C), _F32),
-                        pltpu.VMEM((rows, rep * C), _F32), pltpu.VMEM((d, rep * C), _F32),
+                        pltpu.VMEM((d, rep * C), _F32),
                         pltpu.VMEM((d, C), _F32), pltpu.VMEM((rows, C), _F32),
                         pltpu.VMEM((rows, d), _F32)],
         name="power_retention_bwd", **_params(),
-    )(q, k, v, g, hs, dy)
+    )(q, k, v, g, hs, reads, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _retention(q, k, v, g, cfg):
-    y, _, dmin = _forward(q, k, v, g, cfg)
+    y, _, dmin = _forward(q, k, v, g, cfg, save_reads=False)
     return y, dmin
 
 
 def _retention_fwd(q, k, v, g, cfg):
-    y, hs, dmin = _forward(q, k, v, g, cfg)
-    return (y, dmin), (q, k, v, g, hs)
+    y, hs, dmin, reads = _forward(q, k, v, g, cfg, save_reads=True)
+    return (y, dmin), (q, k, v, g, hs, reads)
 
 
 def _retention_bwd(cfg, saved, cotangents):

@@ -54,11 +54,12 @@ def _faults(pc):
     import jax
     import jax.numpy as jnp
 
-    from torchft_tpu.models import ling, mla, moe
+    from torchft_tpu.models import kda, ling, mla, moe
     from torchft_tpu.ops import kda as kda_ops
 
-    # the latent's norm is the shared mixer's (models/mla.py)
-    scan, conv, rope, norm = ling.kda, ling._short_conv, ling._rope, mla._rmsnorm
+    # the delta rule, the taps and the latent's norm are the shared mixers'
+    # (models/kda.py, models/mla.py)
+    scan, conv, rope, norm = kda.kda, kda._short_conv, ling._rope, mla._rmsnorm
     ffn, choose, gmm, dot = ling.moe_ffn, moe._choose, moe._grouped_matmul, kda_ops._dot
 
     def ungrouped(decide, cfg):
@@ -67,11 +68,12 @@ def _faults(pc):
 
     return {
         "no_decay": lambda: _patched(
-            ling, "kda", lambda q, k, v, g, beta: scan(q, k, v, 0 * g, beta)),
+            kda, "kda", lambda q, k, v, g, beta, **kw: scan(q, k, v, 0 * g, beta, **kw)),
         "beta_one": lambda: _patched(
-            ling, "kda", lambda q, k, v, g, beta: scan(q, k, v, g, jnp.ones_like(beta))),
+            kda, "kda", lambda q, k, v, g, beta, **kw: scan(
+                q, k, v, g, jnp.ones_like(beta), **kw)),
         "lost_tap": lambda: _patched(
-            ling, "_short_conv", lambda x, w: conv(x, w.at[0].set(0))),
+            kda, "_short_conv", lambda x, w: conv(x, w.at[0].set(0))),
         "no_rope": lambda: _patched(ling, "_rope", lambda x, theta, positions: x),
         "no_latent_norm": lambda: _patched(
             mla, "_rmsnorm", lambda x, w, eps: x if x.shape[-1] == pc.kv_lora_rank

@@ -196,7 +196,7 @@ def test_the_staged_kinds_head_programs_are_the_parents(name):
 
 def _kinds():
     from torchft_tpu.models import (brumby, deepseek, jamba, lfm2, llama, mellum, nemotron_h,
-                                    ouro)
+                                    ouro, solar)
     from torchft_tpu.parallel.mesh import llama_param_specs
 
     # class -> (init, param_specs, whether the gradient is staged, frozen)
@@ -213,6 +213,7 @@ def _kinds():
         brumby.BrumbyConfig: (brumby.brumby_init, brumby.brumby_param_specs, False, ()),
         deepseek.DeepseekConfig: (deepseek.deepseek_init, deepseek.deepseek_param_specs,
                                   False, ()),
+        solar.SolarConfig: (solar.solar_init, solar.solar_param_specs, False, ("expert_bias",)),
     }
 
 

@@ -17,7 +17,7 @@ def _register_presets() -> None:
     kind's module is what registers the kind (``models/kinds.py``) and its
     presets: a new kind adds its module to this line and nothing else here."""
     from torchft_tpu.models import (brumby, deepseek, jamba, lfm2, ling, mellum,  # noqa: F401
-                                    moe, nemotron_h, ouro)
+                                    moe, nemotron_h, ouro, solar)
 
     for _, presets in _KINDS.values():
         for name, cfg in presets.items():

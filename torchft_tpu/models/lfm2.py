@@ -57,8 +57,8 @@ import jax.numpy as jnp
 from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
 from torchft_tpu.models.kinds import ModelFns, logged, register
 from torchft_tpu.models.llama import _attention, _rmsnorm, _rope
-from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
-                                    ffn_leaves, ffn_specs, moe_ffn)
+from torchft_tpu.models.moe import (BIAS_INIT_SCALE, MoEConfig, _refuse_dropless_ep,
+                                    expert_scalars, ffn_init, ffn_leaves, ffn_specs, moe_ffn)
 from torchft_tpu.models.remat import ATTN_OUT_NAME
 
 __all__ = [
@@ -75,7 +75,6 @@ __all__ = [
 
 # the top-level leaves that are state and not parameters
 LFM2_FROZEN = ("expert_bias",)
-BIAS_INIT_SCALE = 0.01
 
 
 # LFM2-8B-A1B's 24 layers: attention at 2, 6, 10, 14, 18 and 21

@@ -10,7 +10,8 @@ shared expert.
 
 Every layer: ``h = x + mixer(rmsnorm(x))``, then ``h + ffn(rmsnorm(h))``.
 
-The ``kda`` mixer (H heads of ``kda_head_dim``)::
+The ``kda`` mixer (``models/kda.py``'s, which ``models/solar.py`` calls too;
+H heads of ``kda_head_dim``)::
 
     q, k, v = silu(conv4(u @ wq)), silu(conv4(u @ wk)), silu(conv4(u @ wv))
     q, k    = l2norm(q) / sqrt(d_k), l2norm(k)                  # a head at a time
@@ -52,19 +53,19 @@ body, the PartitionSpecs and the counters, and declares them (``LING``).
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
+from torchft_tpu.models.decoder import Decoder, init_tree, runs_of, spec_tree
+from torchft_tpu.models.kda import kda_mixer
 from torchft_tpu.models.kinds import ModelFns, logged, register
 from torchft_tpu.models.llama import _attention, _rmsnorm, _rope
-from torchft_tpu.models.mla import _head_gate, mla_mixer
-from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
-                                    ffn_leaves, ffn_specs, moe_ffn)
-from torchft_tpu.ops.kda import kda
+from torchft_tpu.models.mla import mla_mixer
+from torchft_tpu.models.moe import (BIAS_INIT_SCALE, MoEConfig, _refuse_dropless_ep,
+                                    expert_scalars, ffn_init, ffn_leaves, ffn_specs, moe_ffn)
+from torchft_tpu.ops.kda import BOUNDED_FLOOR
 
 __all__ = [
     "LingConfig",
@@ -80,8 +81,6 @@ __all__ = [
 
 # the top-level leaves that are state and not parameters
 LING_FROZEN = ("expert_bias",)
-BIAS_INIT_SCALE = 0.01
-L2_EPS = 1e-6
 _F32 = jnp.float32
 
 
@@ -93,7 +92,7 @@ class LingConfig(MoEConfig):
     num_dense_layers: int = 1
     kda_head_dim: int = 128  # d_k = d_v of a KDA head; n_heads of them
     kda_conv: int = 4  # taps of the short convolutions
-    kda_lower_bound: float = -5.0  # ops/kda.py is finite down to -5
+    kda_lower_bound: float = -5.0  # at -5 or above ops/kda.py runs its bounded body
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -113,9 +112,13 @@ class LingConfig(MoEConfig):
     def __post_init__(self) -> None:
         super().__post_init__()
         self._check_layer_types(("kda", "mla"), self.num_dense_layers)
-        if not -5.0 <= self.kda_lower_bound < 0:
-            raise ValueError(f"kda_lower_bound={self.kda_lower_bound}: ops/kda.py "
-                             "takes decays in [-5, 0)")
+        if not BOUNDED_FLOOR <= self.kda_lower_bound < 0:
+            raise ValueError(
+                f"kda_lower_bound={self.kda_lower_bound}: this family's decay is "
+                f"kda_lower_bound * sigmoid(.), and the bound the family publishes is "
+                f"{BOUNDED_FLOOR:g}; ops/kda.py takes every decay <= 0, so a lower bound "
+                "is another model's form (models/solar.py has the unbounded one), not a "
+                "limit of the kernel")
 
     @property
     def n_moe_layers(self) -> int:
@@ -229,37 +232,11 @@ def ling_init(key: jax.Array, cfg: LingConfig) -> Dict[str, Any]:
     return params
 
 
-# SiLU of the depthwise causal convolution, x [B,T,di], w [k,di], no bias:
-# the one program every kind's mixer runs (``decoder._causal_conv``)
-_short_conv = partial(_causal_conv, b=None)
-
-
-def _l2norm(x: jax.Array) -> jax.Array:
-    x32 = x.astype(_F32)
-    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS)
-
-
 def _kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig) -> jax.Array:
-    (B, S, _), H, dk = u.shape, cfg.n_heads, cfg.kda_head_dim
-    heads = lambda m: m.reshape(B, S, H, dk)  # noqa: E731
-    with jax.named_scope("kda/in_proj"):
-        q, k, v = u @ w["wq"], u @ w["wk"], u @ w["wv"]
-    with jax.named_scope("kda/conv"):
-        q, k, v = (_short_conv(m, w[c])
-                   for m, c in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
-    with jax.named_scope("kda/gate"):
-        # the decay sits in an exponent and sums over positions: float32
-        # from the product on, as the selective scan's step size
-        f = jnp.matmul(u, w["w_f"], preferred_element_type=_F32) + w["dt_bias"]
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(
-            heads(f) * jnp.exp(w["A_log"])[:, None])
-        beta = jax.nn.sigmoid(jnp.matmul(u, w["w_beta"], preferred_element_type=_F32))
-        q = (_l2norm(heads(q)) * dk ** -0.5).astype(u.dtype)
-        k = _l2norm(heads(k)).astype(u.dtype)
-    with jax.named_scope("kda/scan"):
-        o = kda(q, k, heads(v), g, beta)
-    with jax.named_scope("kda/out"):
-        return _head_gate(_rmsnorm(o, w["o_norm"], cfg.norm_eps), u, w["w_g"]) @ w["wo"]
+    """``models/kda.py``'s mixer as this family has it: the decay above
+    ``kda_lower_bound``, its projection one full matrix, ``beta`` up to 1,
+    the gate one value a head."""
+    return kda_mixer(u, w, cfg, decay_floor=cfg.kda_lower_bound, beta_max=1.0)[0]
 
 
 def _mla_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: LingConfig,

@@ -85,6 +85,10 @@ __all__ = [
 
 
 ROUTER_PRECISION = jax.lax.Precision.HIGHEST
+# the standard deviation a kind gives its frozen selection bias at the start
+# (``expert_bias``: LFM2's, Ling's, Nemotron-H's, Solar's), so that a check
+# sees it read
+BIAS_INIT_SCALE = 0.01
 
 
 @dataclasses.dataclass(frozen=True)

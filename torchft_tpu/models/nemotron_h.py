@@ -55,8 +55,8 @@ import jax.numpy as jnp
 from torchft_tpu.models.decoder import Decoder, _causal_conv, init_tree, runs_of, spec_tree
 from torchft_tpu.models.kinds import ModelFns, register
 from torchft_tpu.models.llama import _attention, _rmsnorm
-from torchft_tpu.models.moe import (MoEConfig, _refuse_dropless_ep, expert_scalars, ffn_init,
-                                    ffn_leaves, ffn_specs, moe_ffn)
+from torchft_tpu.models.moe import (BIAS_INIT_SCALE, MoEConfig, _refuse_dropless_ep,
+                                    expert_scalars, ffn_init, ffn_leaves, ffn_specs, moe_ffn)
 from torchft_tpu.models.remat import ATTN_OUT_NAME
 from torchft_tpu.ops.ssd import CHUNK, ssd
 
@@ -74,7 +74,6 @@ __all__ = [
 
 # the top-level leaves that are state and not parameters
 NEMOTRON_H_FROZEN = ("expert_bias",)
-BIAS_INIT_SCALE = 0.01
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 _F32 = jnp.float32
 

@@ -150,7 +150,13 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # way), and every other line of this table and ``STAGED_HEAD`` are as
     # they were: ``_dropless_ffn`` and ``_capacity_ffn`` lower to what they
     # did.
-    "ling_debug": ("1af4c5bdba152d88", "3165255fb41f647c", "0x1.7f8a480000000p+2"),
+    # ling_debug's pair pinned anew by PR 65: ``kda_fwd``'s body takes the
+    # state-free half of its block's four chunks as one batch before its
+    # loop (``jax.vmap`` of ``_state_free_bounded``, as ``kda_bwd``'s has
+    # since PR 58) and walks ``_through_state`` chunk by chunk from there;
+    # ``kda_bwd``'s body is the parent's. The loss is the parent's to the
+    # bit, and no other line moved
+    "ling_debug": ("57a8157fc4dc4720", "edce02c55e4baf46", "0x1.7f8a480000000p+2"),
     "mellum_debug": ("ef928f8d611def70", "aad2023958af691a", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),

@@ -131,15 +131,9 @@ def test_a_block_takes_each_chunks_inverse_once_forward_and_once_backward():
     the closed form, where autodiff through the products would hold twenty
     a chunk and a second linearisation of a half its products again."""
     args = _qkvgb(1, K.BLOCK, 1, 16, seed=2)
-    square = (K.CHUNK, K.CHUNK)
 
-    def inverses(f):
-        count = 0
-        for e in _equations(jax.make_jaxpr(f)(*args).jaxpr):
-            shapes = [tuple(v.aval.shape) for v in e.invars]
-            if e.primitive.name == "dot_general" and [s[-2:] for s in shapes] == [square] * 2:
-                count += int(np.prod(shapes[0][:-2]))  # a batch of chunks counts each
-        return count
+    def inverses(f):  # a batch of chunks counts each
+        return sum(int(np.prod(shapes[0][:-2])) for shapes, _ in _square_products(f, args))
 
     grad = jax.grad(lambda *a: jnp.sum(K.kda(*a)), argnums=range(5))
     assert inverses(K.kda) == 4 * 10 and inverses(grad) == 4 * 10 + 4 * (10 + 2)
@@ -150,6 +144,78 @@ def _equations(jaxpr):
         yield e
         for sub in jax.core.jaxprs_in_params(e.params):
             yield from _equations(sub)
+
+
+def _square_products(f, args):
+    """(operand shapes, batch dimensions) of every ``dot_general`` of
+    ``[CHUNK, CHUNK] x [CHUNK, CHUNK]`` in ``f``'s traced kernels: at 16
+    channels the inverse's and nobody else's."""
+    for e in _equations(jax.make_jaxpr(f)(*args).jaxpr):
+        shapes = [tuple(v.aval.shape) for v in e.invars]
+        if e.primitive.name == "dot_general" and [s[-2:] for s in shapes] == [
+                (K.CHUNK, K.CHUNK)] * 2:
+            yield shapes, e.params["dimension_numbers"][1]
+
+
+BODIES = {"bounded": ({"decay_floor": -5.0, "beta_max": 1.0}, K._state_free_bounded),
+          "general": ({}, K._state_free)}
+
+
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_the_forward_kernel_takes_a_blocks_inverses_as_one_batch(body):
+    """PR 65, read in the traced FORWARD kernel: the state-free half of a
+    block's chunks is one batch there too (``jax.vmap``), so each of the
+    inverse's ten products of 64 squared by 64 squared is ONE ``dot_general``
+    with the block's chunks as its leading batch dimension, four independent
+    chains side by side, and none is left that waits on the chunk before."""
+    kw, _ = BODIES[body]
+    args = _qkvgb(1, K.BLOCK, 1, 16, seed=2)
+    found = list(_square_products(lambda *a: K.kda(*a, **kw), args))
+    assert len(found) == 10
+    a_batch = (K.BLOCK // K.CHUNK, K.CHUNK, K.CHUNK)
+    for shapes, batch in found:
+        assert shapes == [a_batch] * 2 and batch == ((0,), (0,)), (shapes, batch)
+
+
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_the_forward_kernel_is_the_two_halves_composed_chunk_by_chunk(body):
+    """``kda_fwd``'s output and the states it saves at every block's start
+    against the definition of a chunk, ``_through_state(*free(...))``, walked
+    one chunk after the other outside any kernel: the batch moved when the
+    state-free half runs, not what it computes. Not to the bit on a CPU,
+    whose batched product adds in another order than its plain one: read
+    2.5e-7 to 2.9e-7 of the norm in ``o`` and 4.2e-7 to 5.3e-7 in the states,
+    under decays of at most 0.1 a step (at Ling's -5 a step the running sum
+    of a chunk reaches 160 and its rounding, 6e-8 of that in the exponents,
+    puts parent and change alike 1e-5 to 3e-5 from float64 in the states)."""
+    _, free = BODIES[body]
+    B, T, H, d = 1, 2 * K.BLOCK, 2, 16
+    g = -0.1 * jax.random.uniform(jax.random.PRNGKey(11), (B, T, H, d))
+    beta = None
+    if body == "general":  # what only the general body may be given: beta to 2
+        beta = 2 * jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(12), (B, T, H)))
+    q, k, v, g, beta = _qkvgb(B, T, H, d, seed=7, g=g, beta=beta)
+    flat = lambda m: m.reshape(B, T, -1)  # noqa: E731
+    o, hs = K._forward(flat(q), flat(k), flat(v), flat(g),
+                       jnp.swapaxes(beta, 1, 2).reshape(B, H, -1, 1, K.BLOCK), H, free)
+    o = o.reshape(B, T, H, d)
+
+    @jax.jit
+    def walk(q, k, v, g, beta):  # one head: [T, d] and beta [T, 1]
+        st, outs, states = jnp.zeros((d, d), jnp.float32), [], []
+        for lo in range(0, T, K.CHUNK):
+            if lo % K.BLOCK == 0:
+                states.append(st)
+            at = slice(lo, lo + K.CHUNK)
+            out, st = K._through_state(*free(q[at], k[at], g[at], beta[at]), v[at], beta[at], st)
+            outs.append(out)
+        return jnp.concatenate(outs), jnp.stack(states)
+
+    for h in range(H):
+        want_o, want_hs = walk(*(m[0, :, h] for m in (q, k, v, g)), beta[0, :, h, None])
+        assert float(jnp.linalg.norm(want_hs[1])) > 0.1
+        for got, want in ((o[0, :, h], want_o), (hs[0, h], want_hs)):
+            assert _rel(got, want) < 1e-6, (body, h, _rel(got, want))
 
 
 def test_a_cotangent_that_arrives_through_the_state_alone_is_the_recurrences():

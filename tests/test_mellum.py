@@ -290,9 +290,10 @@ PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 4
     # for every kind) and by PR 55 (the share's gathers are loops over row
     # tiles) and by PR 58 (``kda_bwd``'s body: a chunk's two halves once each,
     # the inverse's pullback in closed form) and by PR 60 (the share's two
-    # adds into ``[T, d]`` in token order, ``moe._add_in_token_order``):
-    # tests/test_ling.py's table says what moved
-    "ling_debug": "1af4c5bdba152d88",
+    # adds into ``[T, d]`` in token order, ``moe._add_in_token_order``) and by
+    # PR 65 (``kda_fwd``'s body: a block's four state-free halves as one
+    # batch): tests/test_ling.py's table says what moved
+    "ling_debug": "57a8157fc4dc4720",
 }
 
 

@@ -28,15 +28,19 @@ block's start (256 MB a layer at 32k and 32 heads), the backward pass walks
 the blocks in reverse with the state's cotangent in scratch. A chunk is ONE
 definition in two halves, :func:`_state_free` (decays, ``A``, ``P``, the
 chunk inverse: what no state enters) and :func:`_through_state`; the forward
-kernel runs their composition (:func:`_chunk`) and the backward kernel
-differentiates the same two inside the kernel, so forward and backward
-cannot drift apart. In a block's backward pass every piece is computed once:
-the state-free half is linearised once for the block's four chunks together
-(``jax.vmap``: four independent chains of small dependent products, which
-the MXU overlaps where one chain leaves it waiting), the state half once a
-chunk from the saved state on, which also gives the states the later chunks
-start from; the walk back uses both linearisations and nothing is run a
-second time. The inverse's derivative is taken in closed form,
+kernel runs the two and the backward kernel differentiates the same two
+inside the kernel, so forward and backward cannot drift apart. Both kernels
+take the state-free half of a block's four chunks together, as ONE batch
+before their walk over the chunks (``jax.vmap``: four independent chains of
+small dependent products, which the MXU overlaps where one chain leaves it
+waiting; these kernels are bound by the latency of such products and not by
+their FLOPs), and only the state half, three dependent products a chunk,
+goes chunk by chunk from the block's state on. In a block's backward pass
+every piece is computed once: the state-free half is linearised once for the
+batch, the state half once a chunk from the saved state on, which also gives
+the states the later chunks start from; the walk back uses both
+linearisations and nothing is run a second time. The inverse's derivative is
+taken in closed form,
 ``dX = -M^T dM M^T`` for ``M = (I + X)^-1`` (:func:`_inverse`): exact,
 because the products below ARE the inverse (either body's), two products
 where autodiff through them takes twenty, and closer to
@@ -102,29 +106,33 @@ where the doubling reads 1.2e-6. At ``beta`` = 2 they reach 256 x C(14, 7) =
 9e5 and the product form is off by a fifth (``tests/test_solar_kernels.py``),
 which is why this body is held to ``beta <= 1``.
 
-**Why two, and why the bounded body keeps an inverse of its own.** On a v5e
-at 32 heads x 32,768 positions (Ling's shape; the same at 64 x 16,384) one
-jitted call of ``kda``, timed on the host's clock round the whole call with
-its layout turns inside (``benchmarks/kda_bodies.py``, my chip run, PR 64,
-review call 1: a standalone timing, not a step's; in the Solar cell's device
-trace ``kda_fwd`` alone reads 0.0611 s where that script read 0.0692 at 64
-heads), takes forward 0.04634 s bounded and 0.06713 s general, and forward
-with backward 0.11551 s and 0.16333 s: the pair-by-pair exponents and the
-doubling inverse together cost 1.45 x and 1.41 x. The bounded body's
-exponents over the DOUBLING inverse read 0.05210 s and 0.12128 s: the same
-ten products of 64 cubed, but all ten in one chain where the product form's
-longest chain is eight (a power's square and the sum it enters do not wait
-on each other), and the forward kernel, one chunk at a time, waits on each:
-the likely cause, not a measured one (the backward kernel runs four chunks'
-chains side by side and reads the same for both). That is 5.8 ms a forward pass,
-twice a layer under remat full, six KDA layers: 69 ms of
-``ling-3.0-flash.bare-kda-32k``'s step of 3.06 s, 2.3% against its bound of
-1%. So ``beta_max`` stays beside ``decay_floor``: Ling (60% of its device
-time in these kernels) keeps the body AND the inverse it was measured with,
-byte for byte in its lowered step, and the product form stays held to the
-``beta <= 1`` at which it is right. Whether a configuration bounds its decay
-and its ``beta`` is a property of the model known when the step is traced,
-not an option of the program.
+**Why two, and what the bounded body's own inverse is still worth.** On a
+v5e at 32 heads x 32,768 positions (Ling's shape; the same to three digits at
+64 x 16,384) one jitted call of ``kda``, timed on the host's clock round the
+whole call with its layout turns inside (``benchmarks/kda_bodies.py``, my chip
+run, PR 65, call A, parent and change back to back: a standalone timing, not
+a step's; in Ling's cell's device trace ``kda_fwd`` reads 0.0270 s a call
+where that script reads 0.0340), takes forward 0.03398 s bounded and 0.05011
+s general, and forward with backward 0.10334 s and 0.14641 s: the
+pair-by-pair exponents cost 1.47 x and 1.42 x, which is why there are two
+bodies. Before the forward kernel took a block's state-free halves as one
+batch (PR 65) the same calls read 0.04632 / 0.06697 forward and 0.11559 /
+0.16337 with backward: the batch took 12.3 ms off the bounded forward pass
+and 16.9 ms off the general one, the chains of small dependent products that
+each chunk had waited on. The INVERSES no longer differ: the bounded body's
+exponents over the DOUBLING inverse read 0.03395 s forward and 0.10308 s with
+backward, the product form's 0.03398 and 0.10334. One chunk at a time the
+doubling cost the forward kernel 5.8 ms a pass (0.05217 against 0.04632: ten
+products in one chain where the product form's longest is eight), 2.3% of
+Ling's step; with four chunks' chains side by side the longer chain is hidden
+as it always was in the backward kernel. So ``_power_inverse``,
+``_inverse_bounded`` and ``beta_max`` are no longer paid for by a reading,
+and a ``simplicity`` PR may take them out (ROADMAP D14): the bounded body
+would then be the general body's inverse under its own exponents, right for
+``beta`` to 2, and ``decay_floor`` alone would choose the body. They stay
+here because Ling's lowered step would change with them. Whether a
+configuration bounds its decay is a property of the model known when the
+step is traced, not an option of the program.
 
 The state, the decays and every product are float32 (``Precision.HIGHEST`` on
 the MXU).
@@ -307,11 +315,6 @@ def _through_state(p, inv, k_in, q_in, k_out, decay, v, beta, st):
     return o, st.astype(STATE_DTYPE).astype(_F32)
 
 
-def _chunk(q, k, v, g, beta, st, free):
-    """One chunk of one head, all float32: the two halves composed."""
-    return _through_state(*free(q, k, g, beta), v, beta, st)
-
-
 def _turned(x, axis):
     """A [1, n] row as an [n, 1] column (``axis`` 1) or back (``axis`` 0):
     ``beta`` lies along lanes in HBM, where a [T, 1] column would be padded
@@ -337,8 +340,10 @@ def _fwd_kernel(free, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, hs_ref, st_scr):
 
     st = st_scr[...]
     hs_ref[...] = st  # the state this block starts from
-    for i, args in enumerate(zip(*_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))):
-        o, st = _chunk(*args, st, free)
+    q, k, v, g, beta = _chunks(q_ref, k_ref, v_ref, g_ref, b_ref)
+    six = jax.vmap(free)(q, k, g, beta)  # no state enters: the block's chunks side by side
+    for i in range(len(v)):
+        o, st = _through_state(*(m[i] for m in six), v[i], beta[i], st)
         o_ref[i * CHUNK:(i + 1) * CHUNK, :] = o.astype(o_ref.dtype)
     st_scr[...] = st
 

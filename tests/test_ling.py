@@ -156,7 +156,16 @@ PARENT = {  # at the parent commit of PR 48 (the tree of PR 45): sha256 of the
     # since PR 58) and walks ``_through_state`` chunk by chunk from there;
     # ``kda_bwd``'s body is the parent's. The loss is the parent's to the
     # bit, and no other line moved
-    "ling_debug": ("57a8157fc4dc4720", "edce02c55e4baf46", "0x1.7f8a480000000p+2"),
+    # ling_debug's pair pinned anew by PR 66: the same operations in another
+    # order. The mixer's element-wise passes round the delta rule are
+    # ``ops/kda_passes.py``'s two entries (at the debug widths their
+    # ``jax.numpy`` forms, the parent's arithmetic and roundings): ``beta``
+    # is traced after q's and k's norms where it stood before them, the
+    # gate's logits before the head-wise norm where they stood after it, and
+    # ``g`` and ``o`` reach and leave ``kda`` through reshapes of
+    # ``[B, S, H d_k]``. The loss is the parent's to the bit, and no other
+    # line moved
+    "ling_debug": ("e6aaa4cc9693283d", "ed4503029526adb0", "0x1.7f8a480000000p+2"),
     "mellum_debug": ("ef928f8d611def70", "aad2023958af691a", "0x1.73ce5a0000000p+2"),
     "moe_debug": ("5edda971e37627ff", "e04dd041fd034fbf", "0x1.91db320000000p+2"),
     "olmoe_like": ("8f3d99d1a380ce09", "fe422e374d02371d", "0x1.8c5e1e0000000p+2"),

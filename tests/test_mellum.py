@@ -292,8 +292,10 @@ PARENT = {  # sha256 of the lowered value-and-grad program at the parent of PR 4
     # the inverse's pullback in closed form) and by PR 60 (the share's two
     # adds into ``[T, d]`` in token order, ``moe._add_in_token_order``) and by
     # PR 65 (``kda_fwd``'s body: a block's four state-free halves as one
-    # batch): tests/test_ling.py's table says what moved
-    "ling_debug": "57a8157fc4dc4720",
+    # batch) and by PR 66 (the KDA mixer's element-wise passes through
+    # ``ops/kda_passes.py``'s entries: the same operations in another order):
+    # tests/test_ling.py's table says what moved
+    "ling_debug": "e6aaa4cc9693283d",
 }
 
 

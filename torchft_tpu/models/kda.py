@@ -28,7 +28,10 @@ where the step is traced and runs the body that may rely on them.
 
 The scopes ``kda/in_proj``, ``kda/conv``, ``kda/gate``, ``kda/scan`` and
 ``kda/out`` are what ``kda.mixer_s`` reads in a device trace; the low-rank
-pairs lie under ``kda/gate`` and ``kda/out``.
+pairs lie under ``kda/gate`` and ``kda/out``. The element-wise passes of
+the second and the last line round ``kda`` are ``ops/kda_passes.py``'s two
+kernel pairs where the shape tiles (``kda_qkg_*`` under ``kda/gate``,
+``kda_gate_*`` under ``kda/out``), its ``jax.numpy`` forms otherwise.
 """
 
 from __future__ import annotations
@@ -40,23 +43,16 @@ import jax
 import jax.numpy as jnp
 
 from torchft_tpu.models.decoder import _causal_conv
-from torchft_tpu.models.llama import _rmsnorm
-from torchft_tpu.models.mla import _head_gate
 from torchft_tpu.ops.kda import BOUNDED_FLOOR, kda
+from torchft_tpu.ops.kda_passes import kda_gate, kda_qkg, tiles
 
 __all__ = ["kda_mixer"]
 
-L2_EPS = 1e-6
 _F32 = jnp.float32
 
 # SiLU of the depthwise causal convolution, x [B,T,di], w [k,di], no bias:
 # the one program every kind's mixer runs (``decoder._causal_conv``)
 _short_conv = partial(_causal_conv, b=None)
-
-
-def _l2norm(x: jax.Array) -> jax.Array:
-    x32 = x.astype(_F32)
-    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + L2_EPS)
 
 
 def kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: Any,
@@ -74,10 +70,12 @@ def kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     decay is under ``ops.kda.BOUNDED_FLOOR`` (what the bounded body could not
     have taken), and ``beta_over_one_share``, the (position, head) pairs
     whose transition has a negative eigenvalue; else None. ``out_block`` > 0
-    (a kind at the HBM edge, as ``llama.swiglu``'s ``block``): the norm and
-    the gate over blocks of that many positions, each rematerialised, so
-    that their float32 temporaries (three of [S, H d_k]) exist a block at a
-    time, forward and backward; a position's result is the same."""
+    (a kind at the HBM edge, as ``llama.swiglu``'s ``block``): where the
+    norm and the gate run in their ``jax.numpy`` form, over blocks of that
+    many positions, each rematerialised, so that their float32 temporaries
+    (three of [S, H d_k]) exist a block at a time, forward and backward; a
+    position's result is the same. The kernels have no such temporaries and
+    run whole."""
     (B, S, _), H, dk = u.shape, cfg.n_heads, cfg.kda_head_dim
     heads = lambda m: m.reshape(B, S, H, dk)  # noqa: E731
     with jax.named_scope("kda/in_proj"):
@@ -92,27 +90,26 @@ def kda_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: Any,
             f = jnp.matmul(u @ w["w_fa"], w["w_fb"], preferred_element_type=_F32)
         else:
             f = jnp.matmul(u, w["w_f"], preferred_element_type=_F32)
-        f, rate = heads(f + w["dt_bias"]), jnp.exp(w["A_log"])[:, None]
-        g = (-rate * jax.nn.softplus(f) if decay_floor is None
-             else decay_floor * jax.nn.sigmoid(f * rate))
+        q, k, g = kda_qkg(q, k, f, w["dt_bias"], w["A_log"], decay_floor)
         beta = jax.nn.sigmoid(jnp.matmul(u, w["w_beta"], preferred_element_type=_F32))
         if beta_max != 1.0:
             beta = beta_max * beta
-        q = (_l2norm(heads(q)) * dk ** -0.5).astype(u.dtype)
-        k = _l2norm(heads(k)).astype(u.dtype)
     with jax.named_scope("kda/scan"):
-        o = kda(q, k, heads(v), g, beta, decay_floor=decay_floor, beta_max=beta_max)
+        o = kda(heads(q), heads(k), heads(v), heads(g), beta,
+                decay_floor=decay_floor, beta_max=beta_max)
     with jax.named_scope("kda/out"):
         def gated(o, u):  # the head-wise norm times the gate: [B, s, H dk]
-            o = _rmsnorm(o, w["o_norm"], cfg.norm_eps)
             if "w_ga" not in w:
-                return _head_gate(o, u, w["w_g"])
-            # in the activations' dtype: a float32 gate is 0.5 GB a layer at
-            # 16k x 8,192, beside its sigmoid's
-            return o.reshape(*o.shape[:2], H * dk) * jax.nn.sigmoid(
-                (u @ w["w_ga"]) @ w["w_gb"] + w["b_g"])
+                logits = jnp.matmul(u, w["w_g"], preferred_element_type=_F32)
+            else:
+                # the leaves' product in the activations' dtype, as it rounds;
+                # ``kda_gate`` widens it for its kernels (its module says why)
+                logits = (u @ w["w_ga"]) @ w["w_gb"] + w["b_g"]
+            return kda_gate(o, logits, w["o_norm"], cfg.norm_eps)
 
-        if out_block and S > out_block:
+        o = o.reshape(B, S, H * dk)
+        # the kernels' tile is their own block of positions
+        if out_block and S > out_block and not tiles(o, dk):
             if S % out_block:
                 raise ValueError(f"kda out_block {out_block} must divide seq len {S}")
             blocks = lambda m: jnp.swapaxes(  # noqa: E731

@@ -214,7 +214,8 @@ def _kinds():
                                     ouro, solar)
     from torchft_tpu.parallel.mesh import llama_param_specs
 
-    # class -> (init, param_specs, whether the gradient is staged, frozen)
+    # class -> (init, param_specs, whether the gradient is staged, frozen: the
+    # keys, or what gives them from the configuration)
     return {
         llama.LlamaConfig: (llama.llama_init, llama_param_specs, True, ()),
         moe.MoEConfig: (moe.moe_init, moe.moe_param_specs, True, ()),
@@ -227,7 +228,7 @@ def _kinds():
                                      False, ("expert_bias",)),
         brumby.BrumbyConfig: (brumby.brumby_init, brumby.brumby_param_specs, False, ()),
         deepseek.DeepseekConfig: (deepseek.deepseek_init, deepseek.deepseek_param_specs,
-                                  False, ()),
+                                  False, deepseek.frozen_keys),
         solar.SolarConfig: (solar.solar_init, solar.solar_param_specs, False, ("expert_bias",)),
     }
 
@@ -236,13 +237,16 @@ def _kinds():
 def test_model_fns_finds_every_presets_kind_by_class_most_derived_first(name):
     """A LingConfig is an MoEConfig is a LlamaConfig: it gets Ling's
     functions, and so does a class derived from it that registers nothing;
-    ``frozen`` and ``stages`` as they were when ``model_fns`` was a ladder."""
+    ``frozen`` and ``stages`` as they were when ``model_fns`` was a ladder
+    (since PR 67 a kind's ``frozen`` may follow the configuration: DeepSeek's
+    is nothing for V2 and the whole trunk in V3.2's warm-up stage)."""
     cfg = CONFIGS[name]
     init, specs, staged, frozen = _kinds()[type(cfg)]
     for c in (cfg, dataclasses.make_dataclass("Derived", [], bases=(type(cfg),), frozen=True)(
             **dataclasses.asdict(cfg))):
         m = model_fns(c)
-        assert m.init is init and m.param_specs is specs and m.frozen == frozen
+        assert m.init is init and m.param_specs is specs
+        assert m.frozen == (frozen(c) if callable(frozen) else frozen)
         assert (m.stages is not None) == staged
     assert len({type(c) for c in CONFIGS.values()}) == len(_kinds())
     with pytest.raises(TypeError, match="no kind of model"):

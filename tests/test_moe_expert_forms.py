@@ -16,6 +16,8 @@ from torchft_tpu.models import CONFIGS, model_fns, moe
 
 def _leaves(cfg):
     tree = jax.eval_shape(lambda k: model_fns(cfg).init(k, cfg), jax.random.PRNGKey(0))
+    if getattr(cfg, "dsa_stage", None):  # that stage counts parameters: its bias, state, apart
+        tree = {k: v for k, v in tree.items() if k != "expert_bias"}
     return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(tree))
 
 

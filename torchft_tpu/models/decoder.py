@@ -131,7 +131,8 @@ class Decoder:
                attention_fn: Optional[Any] = None, remat: Any = "full",
                routing: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim], the
+        """tokens int32 [B, S] -> (final-norm hidden states [B, S, dim] (the
+        last layer's output where the tree has no ``final_norm``), the
         layers' stats, each stacked over the layers that emit any).
         ``routing`` [routed layers, B*S, k]: the experts to use (replay)."""
         body_of = self.bodies(cfg, tokens.shape[1], attention_fn)
@@ -154,7 +155,11 @@ class Decoder:
         # emit unlike stats (a mixer's beside an expert block's)
         stats = {k: jnp.concatenate([out[k] for out in stats if k in out])
                  for k in sorted({k for out in stats for k in out})}
-        return _rmsnorm(h, params["final_norm"], cfg.norm_eps), stats
+        # a tree without ``final_norm`` (a stage that has no head) gets the
+        # last layer's output as it is
+        if "final_norm" in params:
+            h = _rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        return h, stats
 
     def forward(self, params: Dict[str, Any], tokens: jax.Array, cfg: Any,
                 attention_fn: Optional[Any] = None, remat: Any = "full",

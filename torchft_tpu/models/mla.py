@@ -61,18 +61,37 @@ def _pairs_apart(x: jax.Array) -> jax.Array:
 
 def mla_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: Any, attention: Any,
               rotate: Callable[[jax.Array], jax.Array], heads: int,
-              softmax_factor: float = 1.0) -> jax.Array:
+              softmax_factor: float = 1.0, hand_out: bool = False,
+              exact_scale: bool = False) -> Any:
     """One layer's latent attention from its normalised input ``u`` [B,S,d]
     to ``wo``'s output: the part of it that the ``heads`` heads of ``w``
     give. ``rotate``: x [B,S,h,dr], its halves paired -> turned by the
-    layer's rotary table; ``softmax_factor``: on ``1 / sqrt(dn + dr)``."""
+    layer's rotary table; ``softmax_factor``: on ``1 / sqrt(dn + dr)``.
+
+    ``hand_out``: -> (the output, what a loss over the attention's own
+    probabilities reads: ``cq`` [B,S,q_lora_rank], the queries' normalised
+    latent (None without ``w_dq``); ``q``, ``k`` [B,S,H,width], the queries
+    and keys exactly as the attention kernel was given them (rotated, padded,
+    the softmax factor on ``q``) and ``scale``, what the dispatcher multiplies
+    their product by). The compiled mixer is the same either way.
+
+    ``exact_scale``: the factor on the queries (``sqrt(width / (dn + dr)) x
+    softmax_factor``) is applied in float32 and the PRODUCT rounded to
+    ``u``'s dtype. False, what every kind that stood before the flag keeps
+    (their lowered steps are pinned), rounds the CONSTANT to ``u``'s dtype
+    first: +0.02% of every score at DeepSeek-V2's constants, +0.13% at
+    Ling's, -0.35% at DeepSeek-V3.2's (2.16374 -> 2.15625 in bfloat16), a
+    softmax temperature that a loss over the attention's own probabilities
+    reads as 0.56% of itself (PERF.md section 6, PR 67)."""
     (B, S, _), H = u.shape, heads
     dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
                      cfg.kv_lora_rank)
     rope = lambda m: rotate(_pairs_apart(m))  # noqa: E731
+    cq = None
     with jax.named_scope("mla/q"):
         if "w_dq" in w:
-            q = _rmsnorm(u @ w["w_dq"], w["q_norm"], cfg.norm_eps) @ w["w_uq"]
+            cq = _rmsnorm(u @ w["w_dq"], w["q_norm"], cfg.norm_eps)
+            q = cq @ w["w_uq"]
         else:
             q = u @ w["wq"]
         q = q.reshape(B, S, H, dn + dr)
@@ -86,13 +105,16 @@ def mla_mixer(u: jax.Array, w: Dict[str, jax.Array], cfg: Any, attention: Any,
         # the dispatcher scales by 1 / sqrt(the width it is given)
         width = next(n for n in (64, 128, 256) if n >= dn + dr)  # what the kernels tile
         zeros = jnp.zeros((B, S, H, width - dn - dr), u.dtype)
-        scale = jnp.asarray(math.sqrt(width / (dn + dr)) * softmax_factor, u.dtype)
-        qq = jnp.concatenate([q[..., :dn], q_r, zeros], axis=-1) * scale
+        scale = jnp.asarray(math.sqrt(width / (dn + dr)) * softmax_factor,
+                            _F32 if exact_scale else u.dtype)
+        qq = (jnp.concatenate([q[..., :dn], q_r, zeros], axis=-1) * scale).astype(u.dtype)
         kk = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr)), zeros], axis=-1)
         attn = jax.ad_checkpoint.checkpoint_name(
             attention(qq, kk, kv[..., dn:], cfg), ATTN_OUT_NAME)
     with jax.named_scope("mla/out"):
-        if "w_g" in w:
-            return _head_gate(attn, u, w["w_g"]) @ w["wo"]
-        return attn.reshape(B, S, H * dv) @ w["wo"]
+        out = (_head_gate(attn, u, w["w_g"]) if "w_g" in w
+               else attn.reshape(B, S, H * dv)) @ w["wo"]
+    if hand_out:
+        return out, {"cq": cq, "q": qq, "k": kk, "scale": 1.0 / math.sqrt(width)}
+    return out
